@@ -60,14 +60,23 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import contextlib
 import sys
 
 
-async def _serve(args) -> None:
+@contextlib.asynccontextmanager
+async def serving(data_dir, host: str = "127.0.0.1", port: int = 4566,
+                  parallelism: int = 1):
+    """The single-process server, started: state store (hummock-lite
+    on the local FS under ``data_dir``, in-memory when None),
+    Frontend, DDL-log recovery, pgwire listener and the 0.25 s barrier
+    heartbeat. Yields ``(frontend, pg_server, heartbeat_task)``; leaving
+    the block stops the heartbeat and the listener. ``serve`` /
+    ``playground`` and ``chip_smoke.py`` all start through here."""
     from risingwave_tpu.frontend import Frontend
     from risingwave_tpu.frontend.pgwire import PgServer
 
-    if args.data_dir:
+    if data_dir:
         from risingwave_tpu.storage.hummock import HummockLite
         from risingwave_tpu.storage.object_store import (
             LocalFsObjectStore, RetryingObjectStore,
@@ -75,27 +84,33 @@ async def _serve(args) -> None:
         # serving deployments absorb transient PUT/GET faults in place
         # (jittered-backoff retries) instead of failing a barrier round
         store = HummockLite(
-            RetryingObjectStore(LocalFsObjectStore(args.data_dir)))
+            RetryingObjectStore(LocalFsObjectStore(data_dir)))
     else:
         from risingwave_tpu.state.store import MemoryStateStore
         store = MemoryStateStore()
-    fe = Frontend(store)
+    fe = Frontend(store, parallelism=parallelism)
     replayed = await fe.recover()
     if replayed:
         print(f"recovered {replayed} DDL statements", file=sys.stderr)
     srv = PgServer(fe)
-    await srv.serve(args.host, args.port)
-    print(f"listening on {args.host}:{srv.port} "
-          f"(psql -h {args.host} -p {srv.port})", file=sys.stderr)
+    await srv.serve(host, port)
+    print(f"listening on {host}:{srv.port} "
+          f"(psql -h {host} -p {srv.port})", file=sys.stderr)
     hb = asyncio.ensure_future(fe.run_heartbeat())
     try:
+        yield fe, srv, hb
+    finally:
+        hb.cancel()
+        await srv.close()
+
+
+async def _serve(args) -> None:
+    async with serving(args.data_dir, args.host, args.port) \
+            as (_fe, _srv, hb):
         # serve until the heartbeat dies — a failed heartbeat means
         # checkpoints stopped; better to crash than serve stale MVs
         await asyncio.wait({hb}, return_when=asyncio.FIRST_COMPLETED)
         hb.result()
-    finally:
-        hb.cancel()
-        await srv.close()
 
 
 async def _serve_cluster(args) -> None:

@@ -186,7 +186,7 @@ def fuse_fragments(root, dist_parallelism: int = 1
     computed by a non-trivial projection cannot be dispatched on and
     the run stays interpretive. Value equality makes the raw-column
     hash partition the post-stage keys consistently."""
-    from risingwave_tpu.ops.fused import FusedStages
+    from risingwave_tpu.ops.fused import FusedStages, agg_image_cols
     from risingwave_tpu.stream.coalesce import CoalesceExecutor
     from risingwave_tpu.stream.executors.fused import (
         FusedFragmentExecutor,
@@ -209,6 +209,12 @@ def fuse_fragments(root, dist_parallelism: int = 1
             return None
         fs = FusedStages(base.schema, stages)
         reason = fs.fusable_reason()
+        if reason is not None:
+            details.append(f"agg run NOT fused ({reason})")
+            return None
+        reason = fs.bit_image_reason(
+            agg_image_cols(agg.group_indices, agg.agg_calls,
+                           agg.specs), "group key / MIN-MAX argument")
         if reason is not None:
             details.append(f"agg run NOT fused ({reason})")
             return None
@@ -271,6 +277,14 @@ def fuse_fragments(root, dist_parallelism: int = 1
                 continue
             fs = FusedStages(base.schema, stages)
             reason = fs.fusable_reason()
+            if reason is not None:
+                details.append(
+                    f"join side {s} run NOT fused ({reason})")
+                continue
+            reason = fs.bit_image_reason(
+                list(join.sides[s].key_indices)
+                + list(join.sides[s].pay_indices),
+                "join key / stored join column")
             if reason is not None:
                 details.append(
                     f"join side {s} run NOT fused ({reason})")

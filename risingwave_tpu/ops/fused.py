@@ -127,19 +127,37 @@ def traceable_reason(e, schema: Schema) -> Optional[str]:
 # -- traced twins of the host key/lane codecs ------------------------------
 # Identical bit semantics to ops/lanes.py + executors/keys.py, running
 # on xp (numpy OR traced jnp). The integer paths of lanes.py are already
-# xp-generic and are called directly; only the float normalizations
-# needed get_xp (see lanes._order_u64_from_f64 / keys.to_i64).
+# xp-generic and are called directly. A FLOAT column never gives up its
+# bits in-trace (the TPU compiler has no f64→int64 bitcast): its key,
+# order and payload lanes are built from the int64 image it was
+# uploaded as (float_image / lanes.float_key_image and friends), which
+# is why a float key / MIN-MAX argument / join column must be a plain
+# column reference to fuse (FusedStages.bit_image_reason).
+
+
+def _is_float(data_type) -> bool:
+    return np.issubdtype(np.dtype(data_type.np_dtype), np.floating)
+
+
+def key_i64_traced(col: Column, image) -> object:
+    """One device-typed key column → the int64 KeyCodec hashes:
+    integers widen, floats take their uploaded bit image with -0.0
+    folded into 0.0 (keys.to_i64's exact image)."""
+    from risingwave_tpu.ops import lanes as _lanes
+    from risingwave_tpu.stream.executors.keys import to_i64
+    if _is_float(col.data_type):
+        return _lanes.float_key_image(image)
+    return to_i64(col.values)
 
 
 def key_lanes_traced(cols: Sequence[Tuple[object, Optional[object]]],
                      xp) -> object:
-    """Device-typed key columns → int32[N, 3k] lanes, the exact
-    KeyCodec.build_arrays image (hi, lo, valid per column)."""
+    """(int64 key image, validity) per key column → int32[N, 3k]
+    lanes, the exact KeyCodec.build_arrays image (hi, lo, valid per
+    column)."""
     from risingwave_tpu.ops import lanes as _lanes
-    from risingwave_tpu.stream.executors.keys import to_i64
     out = []
-    for vals, ok in cols:
-        v64 = to_i64(vals)
+    for v64, ok in cols:
         if ok is not None:
             v64 = xp.where(ok, v64, xp.int64(0))
         hi, lo = _lanes.split_i64(v64)
@@ -614,6 +632,43 @@ class FusedStages:
                 return None
         return out
 
+    # -- float bit images (the TPU has no f64→int64 bitcast) ---------------
+    def image_source(self, col: int) -> Optional[int]:
+        """RAW input column whose uploaded int64 bit image IS output
+        column ``col``'s, or None when the column is computed."""
+        pos = self.input_positions([col])
+        return None if pos is None else pos[0]
+
+    def bit_image_reason(self, cols, what: str) -> Optional[str]:
+        """None iff every FLOAT column among output ``cols`` is a plain
+        reference to an input column. The consumer (group key, MIN/MAX
+        argument, join key or stored join column) needs the float's
+        64-bit image; a referenced column brings the image it was
+        uploaded with, a computed one would have to be bitcast
+        f64→int64 inside the trace, which the TPU compiler refuses —
+        so the run stays on the host path, on every platform."""
+        for c in cols:
+            dt = self.out_schema[c].data_type
+            if _is_float(dt) and self.image_source(c) is None:
+                return (f"computed {dt.value} {what} "
+                        f"{self.out_schema[c].name!r}: its bit image "
+                        "would need an f64->int64 bitcast in-trace, "
+                        "which the TPU compiler refuses")
+        return None
+
+    def float_image(self, raw, col: int, xp):
+        """Traced: output column ``col``'s int64 bit image, straight
+        from the raw upload (tiled like every column under an absorbed
+        hop)."""
+        src = self.image_source(col)
+        if src is None:
+            raise ValueError(
+                f"output column {col} is computed: no uploaded bit "
+                "image (bit_image_reason should have refused the run)")
+        img = raw[:, RAW_META_COLS + 2 * self.ref_cols.index(src)]
+        return img if self.hop is None \
+            else xp.tile(img, self.hop.units)
+
     # -- watermark path (host, per message) --------------------------------
     def derive_watermarks(self, msg) -> List:
         """Watermark(s) in OUTPUT column space, composing each stage's
@@ -888,15 +943,19 @@ def build_agg_prelude(fs: FusedStages, group_indices: Sequence[int],
     group = list(group_indices)
 
     def prelude(raw):
+        from risingwave_tpu.ops import lanes as _lanes
         cols, vis, ops = decode_raw_cols(raw, in_schema, ref, jnp)
         out_cols, vis2, ops2, stage_rows = fs.chain_body(
             cols, vis, ops, jnp)
         signs = ops_to_signs(ops2)
-        gcols = []
-        for i in group:
-            c = out_cols[i]
-            gcols.append((c.values, c.validity))
-        key_lanes = key_lanes_traced(gcols, jnp)
+
+        def image(i):
+            return fs.float_image(raw, i, jnp) \
+                if _is_float(out_cols[i].data_type) else None
+
+        key_lanes = key_lanes_traced(
+            [(key_i64_traced(out_cols[i], image(i)),
+              out_cols[i].validity) for i in group], jnp)
         call_inputs = []
         for call, spec in zip(agg_calls, specs):
             if call.input_idx is None:          # count(*)
@@ -905,26 +964,32 @@ def build_agg_prelude(fs: FusedStages, group_indices: Sequence[int],
             c = out_cols[call.input_idx]
             ok = (jnp.ones(vis2.shape[0], dtype=bool)
                   if c.validity is None else c.validity)
-            # THE per-kind encoding — AggSpec.encode_input, same as
-            # the executor's interpretive _inputs path; the lane
-            # codecs it calls are xp-generic, so one implementation
-            # serves both (no drifting twin)
-            call_inputs.append((spec.encode_input(c.values), ok))
+            if spec.orders_by_lanes and _is_float(c.data_type):
+                # float MIN/MAX: order lanes from the uploaded image
+                in_lanes = _lanes.order_lanes_from_image(
+                    image(call.input_idx))
+            else:
+                # THE per-kind encoding — AggSpec.encode_input, same
+                # as the executor's interpretive _inputs path; the
+                # lane codecs it calls are xp-generic, so one
+                # implementation serves both (no drifting twin)
+                in_lanes = spec.encode_input(c.values)
+            call_inputs.append((in_lanes, ok))
         return key_lanes, signs, vis2, tuple(call_inputs), stage_rows
 
     return prelude
 
 
+def agg_image_cols(group_indices: Sequence[int], agg_calls,
+                   specs) -> List[int]:
+    """Output columns whose 64-bit image the agg prelude takes when
+    they are FLOAT: the group keys and the MIN/MAX arguments."""
+    return list(group_indices) + [
+        call.input_idx for call, spec in zip(agg_calls, specs)
+        if call.input_idx is not None and spec.orders_by_lanes]
+
+
 # -- the join input prelude (inlined into hash_join's epoch jits) ----------
-
-
-def payload_lanes_traced(cols: Sequence[Column], xp) -> object:
-    """Device-typed payload columns → int32[N, 3p] lanes: the ONE
-    encode in ops/lanes.py (bit-preserving payload_i64 — NOT the key
-    normalization, which would fold -0.0 into 0.0 on the emit path),
-    here traced under jit (xp=jnp) — same bytes as the host paths."""
-    from risingwave_tpu.ops.lanes import payload_lanes
-    return payload_lanes([(c.values, c.validity) for c in cols], xp)
 
 
 def build_join_prelude(fs: FusedStages, key_indices: Sequence[int],
@@ -939,6 +1004,8 @@ def build_join_prelude(fs: FusedStages, key_indices: Sequence[int],
     composed chain run on numpy — bit-identical by the fusion
     contract, so the device never needs to re-decide them."""
     import jax.numpy as jnp
+
+    from risingwave_tpu.ops.lanes import payload_lanes_i64
 
     assert fs.hop is None, \
         "hop expansion changes cardinality — join preludes refuse it"
@@ -959,13 +1026,22 @@ def build_join_prelude(fs: FusedStages, key_indices: Sequence[int],
             # emitting them keeps the jaxpr small)
             out_cols = [e.eval(chunk) if j in need else None
                         for j, e in enumerate(fs.out_exprs)]
+        def image(i):
+            return fs.float_image(raw, i, jnp) \
+                if _is_float(out_cols[i].data_type) else None
+
         key_lanes = key_lanes_traced(
-            [(out_cols[i].values, out_cols[i].validity)
-             for i in keys], jnp)
+            [(key_i64_traced(out_cols[i], image(i)),
+              out_cols[i].validity) for i in keys], jnp)
         if not pays:
             return key_lanes
-        pay_lanes = payload_lanes_traced([out_cols[i] for i in pays],
-                                         jnp)
+        # stored columns keep their bits exactly (NOT the key
+        # normalization, which would fold -0.0 into 0.0 on the emit
+        # path): a float's uploaded image as is, anything else widened
+        pay_lanes = payload_lanes_i64(
+            [(image(i) if _is_float(out_cols[i].data_type)
+              else out_cols[i].values.astype(jnp.int64),
+              out_cols[i].validity) for i in pays], jnp)
         return jnp.concatenate([key_lanes, pay_lanes], axis=1)
 
     return prelude

@@ -168,6 +168,11 @@ class AggSpec:
         return (self.kind == AggKind.SUM and self.in_dtype is not None
                 and np.issubdtype(self.in_dtype, np.floating))
 
+    @property
+    def orders_by_lanes(self) -> bool:
+        """MIN/MAX: the input encodes as order-preserving lanes."""
+        return self.kind in (AggKind.MIN, AggKind.MAX)
+
     # device-array layout of this call's accumulators: [(dtype, fill)]
     def dev_layout(self) -> List[Tuple[np.dtype, object]]:
         i32 = np.dtype(np.int32)

@@ -80,36 +80,37 @@ def merge_limbs(*limbs: np.ndarray) -> np.ndarray:
 # path has to be indistinguishable from a host arena gather.
 
 
-def payload_i64(v, xp=np):
-    """Column values → int64, bit-preserving (xp-generic: the fused
-    join prelude traces this exact implementation under jit)."""
+def payload_i64(v: np.ndarray) -> np.ndarray:
+    """Column values → int64, bit-preserving (host numpy; a traced
+    program takes a float column's image as it was uploaded — see
+    "float columns from their int64 bit image" below)."""
     dt = np.dtype(v.dtype)
     if dt == np.float64:
-        return v.view(xp.int64) if xp is np else _jax_bitcast_i64(v)
+        return v.view(np.int64)
     if dt == np.float32:
-        w = v.astype(xp.float64)
-        return w.view(xp.int64) if xp is np else _jax_bitcast_i64(w)
-    return v.astype(xp.int64)
+        return v.astype(np.float64).view(np.int64)
+    return v.astype(np.int64)
 
 
-def _jax_bitcast_i64(a):
-    import jax
-    return jax.lax.bitcast_convert_type(a, np.int64)
+def payload_lanes(pairs):
+    """[(values, validity | None)] → int32[N, 3p] payload lanes on the
+    host (_JoinSide payload_rows / payload_from_arena)."""
+    return payload_lanes_i64(
+        [(payload_i64(vals), ok) for vals, ok in pairs], np)
 
 
-def payload_lanes(pairs, xp=np):
-    """[(values, validity | None)] → int32[N, 3p] payload lanes —
-    (hi, lo, valid) per column, NULL values zeroed. THE one encode
-    serving the host paths (_JoinSide payload_rows / payload_from_
-    arena, xp=numpy) and the traced join prelude (xp=jnp) — the
-    device scatter and the emit decode both depend on this exact
-    layout, so there is exactly one copy of it."""
+def payload_lanes_i64(pairs, xp=np):
+    """[(int64 bit image, validity | None)] → int32[N, 3p] payload
+    lanes — (hi, lo, valid) per column, NULL values zeroed. THE one
+    layout serving the host paths (through payload_lanes) and the
+    traced join prelude (xp=jnp, images straight from the raw upload)
+    — the device scatter and the emit decode both depend on it, so
+    there is exactly one copy."""
     out = []
-    for vals, ok in pairs:
-        n = vals.shape[0]
+    for v64, ok in pairs:
+        n = v64.shape[0]
         okm = xp.ones(n, dtype=bool) if ok is None else ok
-        v64 = xp.where(okm, payload_i64(vals, xp), xp.int64(0))
-        hi, lo = split_i64(v64)
+        hi, lo = split_i64(xp.where(okm, v64, xp.int64(0)))
         out.append(hi)
         out.append(lo)
         out.append(okm.astype(xp.int32))
@@ -131,6 +132,32 @@ def decode_payload_i64(v64: np.ndarray, dtype: np.dtype) -> np.ndarray:
     return v64.astype(dtype)
 
 
+# -- float columns from their int64 bit image -------------------------------
+# The TPU compiler's 64-bit rewrite has no f64 → s64/u64 bitcast (the
+# other direction, f64 arithmetic and integer bitcasts compile), so a
+# traced program never takes a float's bits. The host ships every float
+# column as the int64 image of its f64 value (ops/fused.encode_raw_chunk)
+# and the traced key / order / payload codecs work on that image with
+# integer ops — bit-identical to the numpy float codecs, which are
+# written in terms of the same functions.
+
+_I64_MIN = np.int64(-(1 << 63))         # the bit image of -0.0
+
+
+def float_key_image(img):
+    """f64 bit image → the key image: -0.0 folds into 0.0 so the two
+    group (and order) together; every other value keeps its bits."""
+    from risingwave_tpu.common.chunk import get_xp
+    xp = get_xp(img)
+    return xp.where(img == _I64_MIN, xp.int64(0), img)
+
+
+def order_lanes_from_image(img) -> Tuple[np.ndarray, np.ndarray]:
+    """f64 bit image → order-preserving (hi, lo) int32 — order_lanes of
+    the float values, without touching a float."""
+    return _lanes_from_u64(_order_u64_from_image(img))
+
+
 # -- order-preserving lanes for MIN/MAX -------------------------------------
 
 def _order_u64_from_i64(v: np.ndarray) -> np.ndarray:
@@ -141,14 +168,22 @@ def _order_u64_from_i64(v: np.ndarray) -> np.ndarray:
 
 def _order_u64_from_f64(v: np.ndarray) -> np.ndarray:
     """float64 → uint64 total order (IEEE bit trick; -0.0 == 0.0).
-
-    xp-generic (get_xp): the fused-stage prelude traces this exact
-    implementation under jit — one drifting twin would silently break
-    fused-vs-unfused bit identity for float MIN/MAX."""
+    Host numpy; its traced twin is order_lanes_from_image."""
     from risingwave_tpu.common.chunk import get_xp
-    xp = get_xp(v)
-    v = xp.where(v == 0, xp.zeros((), dtype=v.dtype), v)
-    bits = v.astype(xp.float64).view(xp.uint64)
+    if get_xp(v) is not np:
+        raise TypeError(
+            "float order lanes under jit: take the column's uploaded "
+            "bit image (order_lanes_from_image), not a bitcast")
+    return _order_u64_from_image(
+        v.astype(np.float64, copy=False).view(np.int64))
+
+
+def _order_u64_from_image(img):
+    """The same total order from the f64 value's int64 bit image:
+    integer ops only, xp-generic."""
+    from risingwave_tpu.common.chunk import get_xp
+    xp = get_xp(img)
+    bits = float_key_image(img).astype(xp.uint64)
     neg = (bits >> np.uint64(63)) == 1
     return xp.where(neg, ~bits, bits | (np.uint64(1) << np.uint64(63)))
 

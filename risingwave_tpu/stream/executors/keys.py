@@ -220,13 +220,18 @@ def to_i64(vals: np.ndarray) -> np.ndarray:
     """Column values → int64, bijective per distinct key.
 
     Floats are bit-cast (1.2 and 1.7 are distinct keys) with -0.0
-    normalized so it groups with 0.0. xp-generic (get_xp): the fused
-    key-lane prelude traces this exact implementation under jit."""
+    normalized so it groups with 0.0 — on the host only: a traced
+    program builds a float key from the column's uploaded bit image
+    (lanes.float_key_image), since the TPU compiler has no f64→int64
+    bitcast. The integer path is xp-generic (get_xp) and the fused
+    key-lane prelude traces it."""
     from risingwave_tpu.common.chunk import get_xp
     xp = get_xp(vals)
     if np.issubdtype(np.dtype(vals.dtype), np.floating):
-        vals = xp.where(vals == 0, xp.zeros((), dtype=vals.dtype), vals)
-        return vals.astype(xp.float64).view(xp.int64)
+        if xp is not np:
+            raise TypeError(
+                "float key under jit: take the column's uploaded bit "
+                "image (ops/fused.py), not a bitcast")
+        return lanes.float_key_image(
+            vals.astype(np.float64, copy=False).view(np.int64))
     return vals.astype(xp.int64)
-
-

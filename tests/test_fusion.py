@@ -245,8 +245,11 @@ def test_filter_only_fused_agg_front_door_oracle():
 
 def test_key_lanes_traced_match_keycodec():
     """Traced key-lane builder == KeyCodec.build_arrays, including
-    float bitcast keys with -0.0 normalization and NULLs."""
+    float keys with -0.0 normalization and NULLs. The float column
+    enters the trace as the int64 bit image the raw upload carries
+    (the TPU compiler has no f64→int64 bitcast)."""
     import jax
+    from risingwave_tpu.ops.fused import key_i64_traced
     from risingwave_tpu.stream.executors.keys import KeyCodec
     rng = np.random.default_rng(7)
     k = rng.integers(-9, 9, size=64).astype(np.int64)
@@ -256,8 +259,16 @@ def test_key_lanes_traced_match_keycodec():
     import jax.numpy as jnp
     codec = KeyCodec([DataType.INT64, DataType.FLOAT64])
     want = codec.build_arrays([(k, None), (f, ok)])
-    got = jax.jit(lambda a, b, m: key_lanes_traced(
-        [(a, None), (b, m)], jnp))(k, f, ok)
+
+    def traced(a, img, m):
+        fcol = Column(DataType.FLOAT64,
+                      jax.lax.bitcast_convert_type(img, jnp.float64), m)
+        return key_lanes_traced(
+            [(key_i64_traced(Column(DataType.INT64, a, None), None),
+              None),
+             (key_i64_traced(fcol, img), m)], jnp)
+
+    got = jax.jit(traced)(k, f.view(np.int64), ok)
     assert np.array_equal(np.asarray(got), want)
 
 
@@ -269,12 +280,19 @@ def test_lane_codecs_trace_bit_identical():
     v = np.array([0, 1, -1, 2**40, -(2**40), 2**62, -(2**62)],
                  dtype=np.int64)
     f = np.array([0.0, -0.0, 1.5, -3.25, 1e300, -1e-300, 7.0])
-    for arr, fn in ((v, lanes.sum_limbs), (v, lanes.order_lanes),
-                    (f, lanes.order_lanes)):
+    # a float's order lanes trace from its uploaded int64 bit image
+    # (no f64→int64 bitcast in-trace); the numpy codec is the oracle
+    for arr, fn, traced, targ in (
+            (v, lanes.sum_limbs, lanes.sum_limbs, v),
+            (v, lanes.order_lanes, lanes.order_lanes, v),
+            (f, lanes.order_lanes, lanes.order_lanes_from_image,
+             f.view(np.int64))):
         want = fn(arr)
-        got = jax.jit(fn)(arr)
+        got = jax.jit(traced)(targ)
         for a, b in zip(got, want):
             assert np.array_equal(np.asarray(a), b), fn.__name__
+    with pytest.raises(TypeError, match="bit image"):
+        jax.jit(lanes.order_lanes)(f)
 
 
 # -- fused agg oracle + dispatch budget (hand-built q7) --------------------
