@@ -4,7 +4,7 @@ A from-scratch re-design of the capabilities of RisingWave (reference:
 /root/reference, racevedoo/risingwave) for TPU hardware:
 
 - columnar ``DataChunk``/``StreamChunk`` batches living as JAX device arrays
-- stateful stream operators (hash join, hash agg) as jit/XLA/Pallas kernels
+- stateful stream operators (hash join, hash agg) as jit/XLA kernels
   over device-resident hash tables
 - consistent-hash (256-vnode) data parallelism mapped onto a
   ``jax.sharding.Mesh``; hash dispatch rides ICI collectives

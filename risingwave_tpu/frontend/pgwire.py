@@ -289,8 +289,8 @@ class PgServer:
                  if schema is not None else None)
         end = len(rows) if max_rows <= 0 else min(len(rows),
                                                   pos + max_rows)
-        for row in rows[pos:end]:
-            writer.write(_data_row(row, types))
+        writer.write(b"".join(_data_row(row, types)
+                              for row in rows[pos:end]))
         p[3] = end
         if end < len(rows):
             writer.write(_msg(b"s", b""))            # PortalSuspended
@@ -355,10 +355,24 @@ class PgServer:
             writer.write(_row_description(result, schema))
             types = ([f.data_type for f in schema]
                      if schema is not None else None)
-            for row in result:
-                writer.write(_data_row(row, types))
+            await _write_rows(writer, result, types)
             writer.write(_msg(b"C", _cstr(f"SELECT {len(result)}")))
         writer.write(_ready())
+        await writer.drain()
+
+
+_ROWS_PER_WRITE = 1024
+
+
+async def _write_rows(writer, rows, types) -> None:
+    """DataRow messages, a slab per write with a drain in between: one
+    write per row makes asyncio's transport re-sum its whole backlog on
+    every call (quadratic in the result size), and without the drain a
+    large result sits in memory until the last row is formatted."""
+    for at in range(0, len(rows), _ROWS_PER_WRITE):
+        writer.write(b"".join(
+            _data_row(row, types)
+            for row in rows[at:at + _ROWS_PER_WRITE]))
         await writer.drain()
 
 

@@ -1,10 +1,12 @@
 """Loader for the native (C++) runtime kernels.
 
 Compiles native/rw_native.cpp with g++ on first use (cached as a .so
-next to the source) and exposes ctypes wrappers. Every entry point has
-a pure-Python fallback in risingwave_tpu/storage/sst.py — `lib()`
-returns None when no toolchain is available and callers fall back
-transparently; outputs are byte-identical either way (tested).
+next to the source, untracked) and exposes ctypes wrappers. Every entry
+point has a byte-identical pure-Python twin in
+risingwave_tpu/storage/sst.py (tested): `lib()` returns None when the
+library cannot be had and callers take the twin. That is never silent:
+a build or load that was tried and failed prints one line to stderr
+with the compiler's (or loader's) message.
 """
 
 from __future__ import annotations
@@ -12,6 +14,7 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
+import sys
 import threading
 from typing import Optional
 
@@ -25,14 +28,25 @@ _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
+def _say_python(why: str) -> None:
+    print(f"rw_native: SST codec falls back to Python: {why}",
+          file=sys.stderr)
+
+
 def _compile() -> bool:
     try:
         subprocess.run(
             ["g++", "-O2", "-shared", "-fPIC", "-o", _SO, _SRC],
             check=True, capture_output=True, timeout=120)
         return True
-    except (OSError, subprocess.SubprocessError):
-        return False
+    except subprocess.CalledProcessError as e:
+        lines = e.stderr.decode(errors="replace").strip().splitlines()
+        _say_python("g++ failed: " + next(
+            (ln for ln in lines if "error" in ln),
+            lines[-1] if lines else f"exit code {e.returncode}"))
+    except (OSError, subprocess.SubprocessError) as e:
+        _say_python(f"could not run g++: {e}")
+    return False
 
 
 def lib() -> Optional[ctypes.CDLL]:
@@ -52,7 +66,8 @@ def lib() -> Optional[ctypes.CDLL]:
                 if not _compile():
                     return None
             l = ctypes.CDLL(_SO)
-        except OSError:
+        except OSError as e:
+            _say_python(f"could not load {_SO}: {e}")
             return None
         l.rw_block_encode.restype = ctypes.c_long
         l.rw_block_encode.argtypes = [

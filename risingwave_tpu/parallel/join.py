@@ -323,7 +323,7 @@ class ShardedJoinKernel:
                     jax.tree.map(lambda a: a[None], nc))
 
         tspec, cspec = self._specs()
-        mapped = jaxtools.shard_map(
+        mapped = jax.shard_map(
             local, mesh=self.mesh, in_specs=(tspec, cspec),
             out_specs=(tspec, cspec), check_vma=False)
         step = jax.jit(mapped, donate_argnums=(0, 1))
@@ -435,7 +435,7 @@ class ShardedJoinKernel:
                     out[None], ovf[None])
 
         tspec, cspec = self._specs()
-        mapped = jaxtools.shard_map(
+        mapped = jax.shard_map(
             local, mesh=self.mesh,
             in_specs=(tspec, cspec, tspec, cspec, P(AXIS), P(AXIS),
                       P(AXIS), P(AXIS), P(AXIS), P(AXIS), P(AXIS),
@@ -475,7 +475,7 @@ class ShardedJoinKernel:
             return out[None], ovf[None]
 
         tspec, cspec = self._specs()
-        mapped = jaxtools.shard_map(
+        mapped = jax.shard_map(
             local, mesh=self.mesh,
             in_specs=(tspec, cspec, P(AXIS), P(AXIS), P(AXIS), P(),
                       P()),
@@ -501,7 +501,7 @@ class ShardedJoinKernel:
             return jax.tree.map(lambda a: a[None], ch), ovf[None]
 
         tspec, cspec = self._specs()
-        mapped = jaxtools.shard_map(
+        mapped = jax.shard_map(
             local, mesh=self.mesh,
             in_specs=(cspec, P(AXIS), P(AXIS), P(AXIS), P(), P()),
             out_specs=(cspec, P(AXIS)),
@@ -531,7 +531,7 @@ class ShardedJoinKernel:
                     jax.tree.map(lambda a: a[None], ch), ovf[None])
 
         tspec, cspec = self._specs()
-        mapped = jaxtools.shard_map(
+        mapped = jax.shard_map(
             local, mesh=self.mesh,
             in_specs=(tspec, cspec, P(AXIS), P(AXIS), P(AXIS), P(),
                       P()),
@@ -679,7 +679,7 @@ class ShardedJoinKernel:
                     jax.tree.map(lambda a: a[None], ch), ovf[None])
 
         tspec, cspec = self._specs()
-        mapped = jaxtools.shard_map(
+        mapped = jax.shard_map(
             local, mesh=self.mesh,
             in_specs=(tspec, cspec, P(AXIS), P(AXIS), P()),
             out_specs=(tspec, cspec, P(AXIS)),
@@ -764,7 +764,7 @@ class ShardedJoinKernel:
             return jnp.concatenate(parts, axis=0)[None], ovf[None]
 
         tspec, cspec = self._specs()
-        mapped = jaxtools.shard_map(
+        mapped = jax.shard_map(
             local, mesh=self.mesh,
             in_specs=(tspec, cspec, P(AXIS), P(AXIS), P()),
             out_specs=(P(AXIS), P(AXIS)),

@@ -490,8 +490,8 @@ class PhaseLedger:
             s = rec.seconds.get(name, 0.0)
             if s <= 0:
                 continue
-            # phase lanes: stacked from the interval start in taxonomy
-            # order — a share view, not a literal timeline (phases
+            # phase lanes: stacked from the interval start in the
+            # phase table's order — a share view, not a literal timeline (phases
             # interleave within the epoch)
             _spans.EPOCH_TRACER.record(
                 f"phase.{name}", "phase", epoch=rec.epoch, start_s=at,
