@@ -81,6 +81,9 @@ class RewriteReport:
         self.fired: Dict[str, int] = {}
         self.details: List[Tuple[str, str]] = []   # (rule, detail)
         self.fallbacks: List[Tuple[str, str]] = []  # (rule, reason)
+        # rule → why it declined (a rule that rewrote nothing but had
+        # something to say, e.g. fusion's "run NOT fused (...)")
+        self.declined: Dict[str, str] = {}
 
     @property
     def total_fired(self) -> int:
@@ -156,6 +159,8 @@ def rewrite_stream_plan(root, spec: Optional[str] = "all",
             try:
                 new_root, fired, detail = registry[name](root)
                 if not fired:
+                    if detail and name == FUSION_RULE_NAME:
+                        report.declined[name] = detail
                     continue
                 _checker.check(new_root, baseline)
             except Exception as e:          # noqa: BLE001 — fallback
@@ -229,6 +234,9 @@ def explain_with_rewrite(consumer, spec: Optional[str],
         rows.append((f"--   rule {rule}: {detail}",))
     for rule, reason in report.fallbacks:
         rows.append((f"--   rule {rule}: FELL BACK ({reason})",))
+    for rule, why in report.declined.items():
+        if rule not in report.fired:
+            rows.append((f"--   rule {rule}: nothing rewritten: {why}",))
     rows += [(line,) for line in explain_tree(new_consumer)]
     rows.append(stats_line("post-rewrite", new_consumer))
     # compiled-kernel cost footer (utils/jaxtools.KERNELS): programs

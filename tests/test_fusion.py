@@ -651,6 +651,71 @@ def test_explain_shows_fusion_group_annotation():
     run(main())
 
 
+# -- float columns: the uploaded bit image, or the host path ---------------
+
+FLOAT_SOURCES = [
+    "CREATE SOURCE ticks WITH (connector='datagen', "
+    "datagen.event.num=3000, "
+    "fields.k.type='bigint', fields.k.kind='sequence', "
+    "fields.k.start=0, fields.k.end=11, "
+    "fields.level.type='double', fields.level.kind='sequence', "
+    "fields.level.start='-3', fields.level.end=4, "
+    "fields.px.type='double', fields.px.kind='random', "
+    "fields.px.min='-50', fields.px.max=50)",
+    "CREATE SOURCE fees WITH (connector='datagen', "
+    "datagen.event.num=11, "
+    "fields.k.type='bigint', fields.k.kind='sequence', "
+    "fields.fee.type='double', fields.fee.kind='random', "
+    "fields.fee.min='-1', fields.fee.max=1)"]
+
+FLOAT_QUERIES = {
+    # plain column references: fused, lanes from the uploaded image
+    "key": ("SELECT level, COUNT(*) AS n FROM ticks GROUP BY level",
+            "agg absorbed"),
+    "minmax": ("SELECT k, MIN(px) AS lo, MAX(px) AS hi FROM ticks "
+               "GROUP BY k", "agg absorbed"),
+    "payload": ("SELECT t.k, t.px, f.fee FROM ticks AS t "
+                "JOIN fees AS f ON t.k = f.k", "join side 0 absorbed"),
+    # computed floats: refused at plan time, with the reason
+    "computed_key": ("SELECT level * 2 AS l2, COUNT(*) AS n FROM ticks "
+                     "GROUP BY level * 2",
+                     "NOT fused (computed double precision group key"),
+    "computed_minmax": ("SELECT k, MAX(px + 1) AS hi FROM ticks "
+                        "GROUP BY k",
+                        "NOT fused (computed double precision group key"),
+    "computed_payload": ("SELECT t.k, t.p2, f.fee FROM "
+                         "(SELECT k, px * 2 AS p2 FROM ticks) AS t "
+                         "JOIN fees AS f ON t.k = f.k",
+                         "NOT fused (computed double precision join key"),
+}
+
+
+@pytest.mark.parametrize("name", list(FLOAT_QUERIES))
+def test_float_columns_fuse_by_image_or_stay_on_the_host(name):
+    """A DOUBLE group key, MIN/MAX argument or join column fuses when
+    it is a plain column reference (its lanes come from the int64 image
+    the raw upload carries — no f64→int64 bitcast in-trace, which the
+    TPU compiler refuses); a COMPUTED one is refused by the rule with a
+    reason EXPLAIN shows, on every platform. Either way the result is
+    bit-identical to the unfused plan."""
+    select, expect = FLOAT_QUERIES[name]
+
+    async def explain():
+        fe = Frontend()
+        for s in FLOAT_SOURCES:
+            await fe.execute(s)
+        rows = await fe.execute("EXPLAIN " + select)
+        await fe.close()
+        return "\n".join(r[0] for r in rows)
+
+    text = run(explain())
+    assert expect in text, text
+    mv = "CREATE MATERIALIZED VIEW q AS " + select
+    rows_on = _front_door_rows(FLOAT_SOURCES, mv, True, steps=6)
+    rows_off = _front_door_rows(FLOAT_SOURCES, mv, False, steps=6)
+    assert rows_on == rows_off and rows_on
+
+
 def test_ddl_log_replays_create_time_fusion_setting(tmp_path):
     """SET stream_fusion rides the DDL log: a recovery replays the
     CREATE under the recorded setting, not the current default."""
