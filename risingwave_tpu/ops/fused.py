@@ -150,6 +150,13 @@ def key_i64_traced(col: Column, image) -> object:
     return to_i64(col.values)
 
 
+def _float_images(fs: "FusedStages", raw, out_cols, cols, xp) -> dict:
+    """{output column: its uploaded int64 bit image} for the FLOAT
+    columns among ``cols`` (each image read once per trace)."""
+    return {i: fs.float_image(raw, i, xp) for i in set(cols)
+            if _is_float(out_cols[i].data_type)}
+
+
 def key_lanes_traced(cols: Sequence[Tuple[object, Optional[object]]],
                      xp) -> object:
     """(int64 key image, validity) per key column → int32[N, 3k]
@@ -941,6 +948,7 @@ def build_agg_prelude(fs: FusedStages, group_indices: Sequence[int],
     in_schema = fs.ext_schema
     ref = list(fs.ref_cols)
     group = list(group_indices)
+    image_cols = agg_image_cols(group, agg_calls, specs)
 
     def prelude(raw):
         from risingwave_tpu.ops import lanes as _lanes
@@ -948,13 +956,9 @@ def build_agg_prelude(fs: FusedStages, group_indices: Sequence[int],
         out_cols, vis2, ops2, stage_rows = fs.chain_body(
             cols, vis, ops, jnp)
         signs = ops_to_signs(ops2)
-
-        def image(i):
-            return fs.float_image(raw, i, jnp) \
-                if _is_float(out_cols[i].data_type) else None
-
+        images = _float_images(fs, raw, out_cols, image_cols, jnp)
         key_lanes = key_lanes_traced(
-            [(key_i64_traced(out_cols[i], image(i)),
+            [(key_i64_traced(out_cols[i], images.get(i)),
               out_cols[i].validity) for i in group], jnp)
         call_inputs = []
         for call, spec in zip(agg_calls, specs):
@@ -967,7 +971,7 @@ def build_agg_prelude(fs: FusedStages, group_indices: Sequence[int],
             if spec.orders_by_lanes and _is_float(c.data_type):
                 # float MIN/MAX: order lanes from the uploaded image
                 in_lanes = _lanes.order_lanes_from_image(
-                    image(call.input_idx))
+                    images[call.input_idx])
             else:
                 # THE per-kind encoding — AggSpec.encode_input, same
                 # as the executor's interpretive _inputs path; the
@@ -1026,12 +1030,9 @@ def build_join_prelude(fs: FusedStages, key_indices: Sequence[int],
             # emitting them keeps the jaxpr small)
             out_cols = [e.eval(chunk) if j in need else None
                         for j, e in enumerate(fs.out_exprs)]
-        def image(i):
-            return fs.float_image(raw, i, jnp) \
-                if _is_float(out_cols[i].data_type) else None
-
+        images = _float_images(fs, raw, out_cols, keys + pays, jnp)
         key_lanes = key_lanes_traced(
-            [(key_i64_traced(out_cols[i], image(i)),
+            [(key_i64_traced(out_cols[i], images.get(i)),
               out_cols[i].validity) for i in keys], jnp)
         if not pays:
             return key_lanes
@@ -1039,7 +1040,7 @@ def build_join_prelude(fs: FusedStages, key_indices: Sequence[int],
         # normalization, which would fold -0.0 into 0.0 on the emit
         # path): a float's uploaded image as is, anything else widened
         pay_lanes = payload_lanes_i64(
-            [(image(i) if _is_float(out_cols[i].data_type)
+            [(images[i] if i in images
               else out_cols[i].values.astype(jnp.int64),
               out_cols[i].validity) for i in pays], jnp)
         return jnp.concatenate([key_lanes, pay_lanes], axis=1)

@@ -161,7 +161,7 @@ def _join_sides(fe, name):
 
 def _serve_on_cpu(statements, steps):
     """Run the statements and `steps` checkpoint barriers in a session
-    on the CPU; hand the live Frontend to the caller's `harvest`."""
+    on the CPU; yields the live Frontend (a fixture body)."""
     async def run():
         fe = Frontend(rate_limit=4, min_chunks=4)
         for sql in statements:
@@ -415,12 +415,10 @@ def test_float_columns_compile(served_floats, one_chip, case):
             table, chains, pay, _deg = _join_state(one_chip, side.kernel)
             assert side.kernel.payload_width > 0
             raw_w = apply_jit._args[3].shape[1]
-            text = _compile(
-                apply_jit, table, chains, pay,
-                _sds(one_chip, (CHUNK_ROWS, raw_w), jnp.int64),
-                _sds(one_chip, (CHUNK_ROWS, 4), jnp.int32),
-                side.kernel.key_width).as_text()
-            assert text
+            _compile(apply_jit, table, chains, pay,
+                     _sds(one_chip, (CHUNK_ROWS, raw_w), jnp.int64),
+                     _sds(one_chip, (CHUNK_ROWS, 4), jnp.int32),
+                     side.kernel.key_width)
         return
     k = _agg_kernel(fe, {"key": "fk", "minmax": "fm"}[case])
     assert k._prelude is not None, "the aggregate did not fuse"
