@@ -42,16 +42,18 @@ from risingwave_tpu.utils import jaxtools
 
 import chip_smoke
 
-# the sizes chip_smoke.py reaches: `pairs` ends in a 2^21-slot table,
-# one barrier carries 32 chunks of 4096 rows per source
+# the sizes chip_smoke.py reaches: q7's join side ends in 2^21 key slots
+# and 2^22 row slots, `pairs` in a 2^20-slot table (2^21 here: the rung
+# 4M events would reach), and one barrier carries RATE_LIMIT chunks of
+# CHUNK_ROWS rows per source
 AGG_CAPACITY = 1 << 21
 AGG_BATCH = hash_agg.GroupedAggKernel.BATCH_ROWS
-FLUSH_ROWS = 1 << 17
-EPOCH_ROWS = 1 << 17
-JOIN_KEYS = 1 << 18
-JOIN_ROWS = 1 << 18
-PROBE_OUT = 1 << 17
 CHUNK_ROWS = chip_smoke.CHUNK_ROWS
+EPOCH_ROWS = chip_smoke.RATE_LIMIT * CHUNK_ROWS
+FLUSH_ROWS = EPOCH_ROWS
+JOIN_KEYS = 1 << 21
+JOIN_ROWS = 1 << 22
+PROBE_OUT = EPOCH_ROWS
 
 
 # -- the described chip -------------------------------------------------------
