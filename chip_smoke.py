@@ -75,13 +75,9 @@ REHEARSE_EVENTS = 250_000         # 25 s of event time: three 10 s windows
 FLOAT_ROWS = 20_000
 FLOAT_KEYS = 37
 CHUNK_ROWS = 4096
-# chunks per barrier per source, the one SET: 4 x 4096 rows keeps a join
-# side's epoch matrix at 2^14 rows, whose apply/probe programs compile for
-# the v5e in about 6 s each here; at 2^15 rows and up they take 26-30 s,
-# once per growth rung of the join state (compiles for a described chip
-# in the builder's sandbox, PR 22: not chip runs). The rehearsal's is
-# smaller still so that its few rows span several barriers.
-RATE_LIMIT, REHEARSE_RATE_LIMIT = 4, 2
+# chunks per barrier per source, the one SET (the rehearsal's is small so
+# that its few rows still span several barriers)
+RATE_LIMIT, REHEARSE_RATE_LIMIT = 32, 2
 MIN_DEVICE_KEYS = 1_000_000      # one device hash table must hold this many
 LOAD_DEADLINE_S = 900.0
 

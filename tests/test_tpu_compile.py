@@ -44,13 +44,17 @@ import chip_smoke
 
 # the sizes chip_smoke.py reaches: q7's join side ends in 2^21 key slots
 # and 2^22 row slots, `pairs` in a 2^20-slot table (2^21 here: the rung
-# 4M events would reach), and one barrier carries RATE_LIMIT chunks of
-# CHUNK_ROWS rows per source
+# 4M events would reach). One size is NOT the smoke's: its join epochs
+# carry 2^17 rows (32 chunks of 4096), and these programs' compile time
+# grows steeply with that dimension (epoch_apply: 6 s at 2^14 rows, 26 s
+# at 2^15, 30 s at 2^17, compiled here for the described chip) while
+# what the compiler refuses does not depend on it, so the join tests use
+# 2^14 and the suite stays light.
 AGG_CAPACITY = 1 << 21
 AGG_BATCH = hash_agg.GroupedAggKernel.BATCH_ROWS
 CHUNK_ROWS = chip_smoke.CHUNK_ROWS
-EPOCH_ROWS = chip_smoke.RATE_LIMIT * CHUNK_ROWS
-FLUSH_ROWS = EPOCH_ROWS
+EPOCH_ROWS = 1 << 14
+FLUSH_ROWS = 1 << 17
 JOIN_KEYS = 1 << 21
 JOIN_ROWS = 1 << 22
 PROBE_OUT = EPOCH_ROWS
