@@ -217,11 +217,14 @@ def enable_compilation_cache() -> str:
         cache_dir = _DEFAULT_DIR
         os.makedirs(cache_dir, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", cache_dir)
-    # cache EVERY program: the kernel zoo is many sub-100ms compiles
-    # (probe/link/flush per shape bucket) whose first-run total is the
-    # difference between a cold start and a warm one
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    # cache what costs a quarter of a second or more to compile (JAX's
+    # own floor is a second; the kernels of the served path compile for
+    # seconds each on the chip). NOT every program: JAX writes a cache
+    # entry in place, not atomically, so the more tiny programs a dozen
+    # processes sharing one directory write (pytest workers and their
+    # cluster children), the likelier one of them reads another's
+    # half-written entry.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.25)
     return cache_dir
 
 
