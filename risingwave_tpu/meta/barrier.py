@@ -31,7 +31,9 @@ from risingwave_tpu.stream.message import Barrier, BarrierKind, Mutation
 from risingwave_tpu.utils import ledger as _ledger
 from risingwave_tpu.utils import spans as _spans
 from risingwave_tpu.utils.failpoint import fail_point
-from risingwave_tpu.utils.metrics import STREAMING, exact_quantile
+from risingwave_tpu.utils.metrics import (
+    HISTORY, STREAMING, exact_quantile,
+)
 from risingwave_tpu.utils.trace import GLOBAL_AWAITS
 
 
@@ -243,6 +245,51 @@ class EpochProfiler:
         }
 
 
+def record_checkpoint_tail(profs: List[EpochProfile],
+                           committed_epoch: int, upload_s: float,
+                           stages: List[tuple]) -> None:
+    """The uploader's commit callback, shared by the loop and the
+    plane: patch ``upload_s`` into the profile(s) of the barrier(s)
+    that sealed the checkpoint, and file the stages the uploader cut
+    it into (storage/uploader.py, "Stages") under the same barrier:
+    spans below its ``checkpoint.upload`` in the epoch trace, and the
+    ``ckpt.*`` names of its ``rw_metrics_history`` row."""
+    for prof in profs:
+        prof.upload_s = upload_s
+    if not profs:
+        return
+    if stages:
+        values = dict.fromkeys(
+            ("ckpt.queue_s", "ckpt.build_s", "ckpt.put_s",
+             "ckpt.commit_s", "ckpt.compact_s", "ckpt.sst_bytes",
+             "ckpt.compact_read_bytes", "ckpt.compact_write_bytes"), 0.0)
+        for name, _start, dur, counts in stages:
+            key = "ckpt." + name.partition(".")[2]
+            values[key + "_s"] = values.get(key + "_s", 0.0) + dur
+            if name == "checkpoint.build":
+                values["ckpt.sst_bytes"] = float(counts["sst_bytes"])
+            elif name == "checkpoint.compact":
+                values["ckpt.compact_read_bytes"] = \
+                    float(counts["read_bytes"])
+                values["ckpt.compact_write_bytes"] = \
+                    float(counts["write_bytes"])
+        for prof in profs:
+            HISTORY.amend(prof.epoch, values)
+    if _spans.enabled():
+        # the async checkpoint tail (seal→durable commit), overlapped
+        # with younger barriers — traced under the barrier that SEALED
+        # it so the overlap is visible, its stages below it
+        epoch = profs[0].epoch
+        tail = _spans.EPOCH_TRACER.record(
+            "checkpoint.upload", "upload", epoch=epoch,
+            start_s=time.time() - upload_s, dur_s=upload_s,
+            committed_epoch=committed_epoch)
+        for name, start, dur, counts in stages:
+            _spans.EPOCH_TRACER.record(
+                name, "upload", epoch=epoch, start_s=start, dur_s=dur,
+                parent=tail, **counts)
+
+
 class VirtualClock:
     """Deterministic time source (the madsim stance, SURVEY §4:
     replace time, keep the program): `sleep` advances virtual time and
@@ -399,21 +446,14 @@ class BarrierLoop:
         window alongside in_flight)."""
         return self.uploader.depth
 
-    def _on_epoch_committed(self, epoch: int, upload_s: float) -> None:
+    def _on_epoch_committed(self, epoch: int, upload_s: float,
+                            stages: List[tuple]) -> None:
         """Uploader commit callback — epochs arrive strictly in order,
         so committed_epoch never skips past an unfinished older one."""
         self._committed_epoch = epoch
         prof = self._upload_profiles.pop(epoch, None)
-        if prof is not None:
-            prof.upload_s = upload_s
-            if _spans.enabled():
-                # the async checkpoint tail (seal→durable commit),
-                # overlapped with younger barriers — traced under the
-                # barrier that SEALED it so the overlap is visible
-                _spans.EPOCH_TRACER.record(
-                    "checkpoint.upload", "upload", epoch=prof.epoch,
-                    start_s=time.time() - upload_s, dur_s=upload_s,
-                    committed_epoch=epoch)
+        record_checkpoint_tail([prof] if prof is not None else [],
+                               epoch, upload_s, stages)
 
     # -- one step -------------------------------------------------------
     def _next_kind(self, force_checkpoint: bool) -> BarrierKind:
@@ -473,13 +513,14 @@ class BarrierLoop:
         STREAMING.barrier_in_flight.set(len(self._in_flight))
         if kind.is_checkpoint:
             self._barriers_since_checkpoint = 0
-        if self._plane is not None:
-            sender_ids, expected = self._plane.scope(self.domain)
-            await self.local.send_barrier(barrier,
-                                          sender_ids=sender_ids,
-                                          expected=expected)
-        else:
-            await self.local.send_barrier(barrier)
+        with _spans.annotation("barrier.inject", curr.value):
+            if self._plane is not None:
+                sender_ids, expected = self._plane.scope(self.domain)
+                await self.local.send_barrier(barrier,
+                                              sender_ids=sender_ids,
+                                              expected=expected)
+            else:
+                await self.local.send_barrier(barrier)
         return barrier
 
     def advance_epoch_to(self, value: int) -> None:
@@ -540,8 +581,19 @@ class BarrierLoop:
         # a failed upload fails the barrier here, after its retries
         self.uploader.raise_if_failed()
         epoch = self._in_flight.pop(0)
-        barrier = await self._await_complete_or_upload_failure(epoch)
+        with _spans.annotation("barrier.collect", epoch):
+            barrier = await self._await_complete_or_upload_failure(epoch)
         t_collect = self.monotonic()
+        with _spans.annotation("barrier.commit", epoch):
+            await self._seal_and_submit(epoch, barrier, t_collect)
+        self.stats.completed_epochs.append(epoch)
+        return barrier
+
+    async def _seal_and_submit(self, epoch: int, barrier: Barrier,
+                               t_collect: float) -> None:
+        """The commit half of a collection: seal the store and the
+        epoch's books, hand a checkpoint to the uploader, run the
+        checkpoint-time sweeps."""
         # ledger-test seam: a sleep spec here lands inside the commit
         # half of the measured interval as wall time NO phase can
         # claim — the conservation residual must surface it as
@@ -622,8 +674,8 @@ class BarrierLoop:
                     # wall time, already on its books).
                     t_true = max(collect_times.values(),
                                  default=t_collect)
-                    start = t0 if self._last_seal_stamp is None \
-                        else max(t0, self._last_seal_stamp)
+                    last = self._last_seal_stamp
+                    start = t0 if last is None else max(t0, last)
                     interval = max(0.0, t_true - start) \
                         + prof.collect_to_commit_s
                     # the next epoch's books open where this one's
@@ -632,9 +684,15 @@ class BarrierLoop:
                     # land on two epochs' books)
                     self._last_seal_stamp = \
                         t_true + prof.collect_to_commit_s
-                    seal_interval = interval
                     seal_rec = _ledger.LEDGER.seal(
                         epoch, interval, prof.kind,
+                        # the wake gap is the next epoch's wall time:
+                        # LOOP-phase sections in it wait for its seal
+                        wake_gap=(t_true, t_collect),
+                        # and what held the loop between the last
+                        # books closing and this inject is this epoch's
+                        between=(last, t0)
+                        if last is not None and t0 > last else None,
                         # remote pseudo-actors ⇒ actor work ran in
                         # other processes: conservation defers to the
                         # drain_ledger merge (auto-detected so bare
@@ -645,6 +703,8 @@ class BarrierLoop:
                         # do topology work no phase claims — exempt
                         warmup=barrier.mutation is not None,
                         domain=self.domain)
+                    seal_interval = seal_rec.interval_s \
+                        if seal_rec is not None else interval
                 else:
                     _ledger.LEDGER.discard(epoch)
             # bottleneck walk (ISSUE 14): one candidate per domain per
@@ -694,18 +754,19 @@ class BarrierLoop:
             STREAMING.checkpoint_count.inc()
             # host-memory accounting/eviction sweep piggybacks on the
             # checkpoint (memory_manager.rs watermark-loop analog)
-            from risingwave_tpu.utils.memory import GLOBAL as _MEM
-            _MEM.tick()
-            # topology two-book recount (armed by the tier-1 gate
-            # fixture only — a no-op in production) and the per-MV
-            # state-bytes gauge refresh both ride the checkpoint:
-            # state only moves at checkpoints
             from risingwave_tpu.state.topology import TOPOLOGY
             from risingwave_tpu.stream.costs import COSTS
-            TOPOLOGY.checkpoint_verify()
-            COSTS.publish_state_bytes()
-        self.stats.completed_epochs.append(epoch)
-        return barrier
+            from risingwave_tpu.utils.memory import GLOBAL as _MEM
+            # synchronous on the loop, in no actor: the ledger's LOOP
+            # phase `checkpoint`, out of the books of whoever is parked
+            with _ledger.LEDGER.phase("checkpoint"):
+                _MEM.tick()
+                # topology two-book recount (armed by the tier-1 gate
+                # fixture only — a no-op in production) and the per-MV
+                # state-bytes gauge refresh both ride the checkpoint:
+                # state only moves at checkpoints
+                TOPOLOGY.checkpoint_verify()
+                COSTS.publish_state_bytes()
 
     async def inject_and_collect(
             self, mutation: Optional[Mutation] = None,

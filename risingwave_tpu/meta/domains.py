@@ -48,6 +48,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
 from risingwave_tpu.common.epoch import Epoch
 from risingwave_tpu.meta.barrier import (
     BarrierLoop, BarrierStats, EpochProfile, EpochProfiler,
+    record_checkpoint_tail,
 )
 from risingwave_tpu.storage.uploader import CheckpointUploader
 from risingwave_tpu.stream.message import Barrier, Mutation, StopMutation
@@ -465,16 +466,10 @@ class BarrierPlane:
         aligned round (called from its loop's collect path)."""
         self._pending_ckpt[domain] = (prev, prof)
 
-    def _on_epoch_committed(self, epoch: int, upload_s: float) -> None:
-        profs = self._upload_profiles.pop(epoch, [])
-        for prof in profs:
-            prof.upload_s = upload_s
-        from risingwave_tpu.utils import spans as _spans
-        if _spans.enabled() and profs:
-            _spans.EPOCH_TRACER.record(
-                "checkpoint.upload", "upload", epoch=profs[0].epoch,
-                start_s=time.time() - upload_s, dur_s=upload_s,
-                committed_epoch=epoch)
+    def _on_epoch_committed(self, epoch: int, upload_s: float,
+                            stages: List[tuple]) -> None:
+        record_checkpoint_tail(self._upload_profiles.pop(epoch, []),
+                               epoch, upload_s, stages)
 
     async def _maybe_submit(self) -> None:
         """Submit the durability floor to the shared uploader once a

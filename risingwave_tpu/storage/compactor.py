@@ -24,10 +24,12 @@ resurrect.
 from __future__ import annotations
 
 import heapq
+import time
 from typing import List, Optional
 
 from risingwave_tpu.storage.object_store import ObjectStore
 from risingwave_tpu.storage.sst import Sst, SstBuilder, split_full_key
+from risingwave_tpu.utils import spans as _spans
 from risingwave_tpu.utils.failpoint import fail_point
 from risingwave_tpu.utils.metrics import STORAGE as _METRICS
 
@@ -48,7 +50,27 @@ def execute_task(obj: ObjectStore, task: dict) -> dict:
     key stay in one run (the L1 disjoint-run binary search depends on
     it). Exhausting the id block raises (the manager aborts and
     requeues with a bigger grant) rather than minting unreserved ids.
+
+    The merge leaves the same ``checkpoint.compact`` span the inline
+    arm leaves (``HummockLite.compact``), with ``mode=dedicated``: it
+    runs off the serving loop, so no ledger phase claims it.
     """
+    t0 = time.time()
+    with _spans.annotation("checkpoint.compact"):
+        result = _execute_task(obj, task)
+    _spans.EPOCH_TRACER.record(
+        "checkpoint.compact", "upload", start_s=t0,
+        dur_s=time.time() - t0, mode="dedicated",
+        ssts_read=len(task.get("inputs_l0") or [])
+        + len(task.get("inputs_l1") or []),
+        read_bytes=result["bytes_read"],
+        ssts_written=len(result["outputs"]),
+        write_bytes=result["bytes_written"],
+        entries_dropped=result.pop("entries_dropped"))
+    return result
+
+
+def _execute_task(obj: ObjectStore, task: dict) -> dict:
     fail_point("compactor.execute")
     inputs_l0: List[dict] = list(task.get("inputs_l0") or [])
     inputs_l1: List[dict] = list(task.get("inputs_l1") or [])
@@ -109,7 +131,9 @@ def execute_task(obj: ObjectStore, task: dict) -> dict:
     seen_fk: Optional[bytes] = None
     last_tu: Optional[bytes] = None
     kept_le_safe = False
+    entries_in = entries_out = 0
     for fk, _r, tomb, row in merged:
+        entries_in += 1
         if fk == seen_fk:
             continue               # same key+epoch: newer layer wins
         seen_fk = fk
@@ -119,6 +143,7 @@ def execute_task(obj: ObjectStore, task: dict) -> dict:
             last_tu = tu
             kept_le_safe = False
         if e > safe:
+            entries_out += 1
             out(fk, tomb, row)
             continue
         if kept_le_safe:
@@ -128,11 +153,13 @@ def execute_task(obj: ObjectStore, task: dict) -> dict:
             continue               # newest ≤ safe is a delete: gone
         # non-bottom merges KEEP a ≤-safe tombstone: levels below the
         # destination may still hold the key it deletes
+        entries_out += 1
         out(fk, tomb, row)
     flush()
     bytes_read = sum(i.get("size", 0) for i in inputs_l0 + inputs_l1)
     return {"outputs": outputs, "bytes_read": bytes_read,
-            "bytes_written": bytes_written}
+            "bytes_written": bytes_written,
+            "entries_dropped": entries_in - entries_out}
 
 
 class InProcessCompactor:

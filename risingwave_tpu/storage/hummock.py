@@ -36,11 +36,14 @@ from __future__ import annotations
 
 import heapq
 import json
+import time
 from collections import OrderedDict
 from typing import Dict, Iterable, Iterator, List, Optional, Tuple
 
 from risingwave_tpu.state.store import StateStore, Value
+from risingwave_tpu.utils import spans as _spans
 from risingwave_tpu.utils.failpoint import fail_point
+from risingwave_tpu.utils.ledger import LEDGER as _LEDGER
 from risingwave_tpu.utils.metrics import STORAGE as _METRICS
 from risingwave_tpu.storage.object_store import ObjectStore
 from risingwave_tpu.storage.sst import (
@@ -198,8 +201,10 @@ class HummockLite(StateStore):
         calls the three phases separately so only the build mutates
         loop-confined state and the upload runs off the event loop."""
         payloads = self.build_ssts(epoch)
-        for p in payloads:
-            self.upload_payload(p)
+        with _LEDGER.phase("checkpoint"):
+            # inline, the PUT holds the caller's loop like the rest
+            for p in payloads:
+                self.upload_payload(p)
         return self.commit_ssts(epoch, payloads)
 
     def build_ssts(self, epoch: int) -> List[dict]:
@@ -211,12 +216,22 @@ class HummockLite(StateStore):
 
         Builds MUST run in epoch order (the imm drain is cumulative:
         a younger epoch's build would swallow an older epoch's imms) —
-        the CheckpointUploader chains them."""
+        the CheckpointUploader chains them.
+
+        Synchronous on the caller's event loop: ledger phase
+        ``checkpoint``. The payload carries ``entries`` and ``tables``
+        (state tables touched) for the ``checkpoint.build`` span."""
+        with _LEDGER.phase("checkpoint"):
+            return self._build_ssts(epoch)
+
+    def _build_ssts(self, epoch: int) -> List[dict]:
         fail_point("hummock.sync")
         take = [im for im in self._imms if im[0] <= epoch]
         self._imms = [im for im in self._imms if im[0] > epoch]
         entries: List[Tuple[bytes, bool, bytes]] = []
+        touched = set()
         for e, tables in take:
+            touched.update(tables)
             for table_id, kv in tables.items():
                 for key, value in kv.items():
                     fk = full_key(table_id, key, e)
@@ -229,7 +244,8 @@ class HummockLite(StateStore):
         sst_id = self._next_sst_id
         self._next_sst_id += 1
         data, info = build_sst(sst_id, entries)
-        payload = {"epoch": epoch, "sst": info, "data": data}
+        payload = {"epoch": epoch, "sst": info, "data": data,
+                   "entries": len(entries), "tables": len(touched)}
         self._uploading.append(payload)
         return [payload]
 
@@ -247,7 +263,16 @@ class HummockLite(StateStore):
         version (or the durable staged manifest in two-phase mode) and
         advance the committed epoch. Must be called in epoch order,
         only after every payload's upload durably landed — the
-        version must never reference an object that may not exist."""
+        version must never reference an object that may not exist.
+
+        Synchronous on the caller's event loop: ledger phase
+        ``checkpoint``, less the inline compaction where this commit
+        triggers one (phase ``compaction``, nested; its counts come
+        back under ``"compaction"``)."""
+        with _LEDGER.phase("checkpoint"):
+            return self._commit_ssts(epoch, payloads)
+
+    def _commit_ssts(self, epoch: int, payloads: List[dict]) -> dict:
         ids = {p["sst"]["id"] for p in payloads}
         self._uploading = [u for u in self._uploading
                            if u["sst"]["id"] not in ids]
@@ -263,12 +288,13 @@ class HummockLite(StateStore):
                 self._persist_staged()
             return {"sst": info}
         self._committed_epoch = max(self._committed_epoch, epoch)
+        compaction = None
         if (self.compaction_mode == "inline"
                 and len(self._l0) >= L0_COMPACT_THRESHOLD):
-            self.compact()
+            compaction = self.compact()
         else:
             self._commit_version()
-        return {"sst": info}
+        return {"sst": info, "compaction": compaction}
 
     # -- two-phase commit plane (coordinator-driven) ----------------------
     def commit_through(self, epoch: int) -> None:
@@ -723,7 +749,7 @@ class HummockLite(StateStore):
                 yield uk, value
 
     # -- compaction -------------------------------------------------------
-    def compact(self) -> None:
+    def compact(self) -> Optional[dict]:
         """Leveled compaction (level picker): merge L0 with ONLY the
         L1 runs whose user-key range overlaps L0's — untouched runs
         carry over unread (manager/compaction picker analog; the r3
@@ -736,7 +762,23 @@ class HummockLite(StateStore):
         is the newest surviving version drops with its key. Replaced
         objects retire into the pin-gated vacuum (an in-flight scan
         that pinned an older version keeps them readable).
+
+        Synchronous on the caller's event loop: ledger phase
+        ``compaction`` and the ``checkpoint.compact`` annotation.
+        Returns what it read, wrote and dropped with its wall-clock
+        start and duration (None where there was nothing to merge).
         """
+        t0 = time.time()
+        with _spans.annotation("checkpoint.compact"), \
+                _LEDGER.phase("compaction"):
+            fail_point("hummock.compact")
+            counts = self._compact()
+        if counts is not None:
+            counts.update(start_s=t0, dur_s=time.time() - t0,
+                          mode="inline")
+        return counts
+
+    def _compact(self) -> Optional[dict]:
         # key range of the L0 files being absorbed (user-key compare:
         # the inverted-epoch suffix would mis-order full keys)
         if self._l0:
@@ -756,7 +798,7 @@ class HummockLite(StateStore):
         olds = list(self._l0) + overlap
         if not olds:
             self._commit_version()
-            return
+            return None
         safe = self._committed_epoch
 
         def source(info: dict, r: int):
@@ -798,7 +840,9 @@ class HummockLite(StateStore):
             builder.add(fk, tomb, row)
 
         seen_fk: Optional[bytes] = None
+        entries_in = entries_out = 0
         for fk, _r, tomb, row in merged:
+            entries_in += 1
             if fk == seen_fk:
                 continue               # same key+epoch: newer layer wins
             seen_fk = fk
@@ -808,6 +852,7 @@ class HummockLite(StateStore):
                 last_tu = tu
                 kept_le_safe = False
             if e > safe:
+                entries_out += 1
                 out(fk, tomb, row)
                 continue
             if kept_le_safe:
@@ -815,6 +860,7 @@ class HummockLite(StateStore):
             kept_le_safe = True
             if tomb:
                 continue               # newest ≤ safe is a delete: gone
+            entries_out += 1
             out(fk, tomb, row)
         if builder is not None:
             data, info = builder.finish()
@@ -831,12 +877,16 @@ class HummockLite(StateStore):
         # under the new version; the vacuum frees each only once no
         # pinned reader (in-flight scan) predates the swap
         self._retire(olds, self._version_id)
-        _METRICS.compaction_bytes_read.inc(
-            sum(i.get("size", 0) for i in olds), arm="inline")
-        _METRICS.compaction_bytes_written.inc(
-            sum(i.get("size", 0) for i in new_infos), arm="inline")
+        read_bytes = sum(i.get("size", 0) for i in olds)
+        write_bytes = sum(i.get("size", 0) for i in new_infos)
+        _METRICS.compaction_bytes_read.inc(read_bytes, arm="inline")
+        _METRICS.compaction_bytes_written.inc(write_bytes, arm="inline")
         self.maybe_vacuum()
         self._update_space_amp()
+        return {"ssts_read": len(olds), "read_bytes": read_bytes,
+                "ssts_written": len(new_infos),
+                "write_bytes": write_bytes,
+                "entries_dropped": entries_in - entries_out}
 
     # -- test/debug helpers ----------------------------------------------
     def table_size(self, table_id: int, epoch: int) -> int:

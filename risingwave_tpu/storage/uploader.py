@@ -34,6 +34,35 @@ next ``submit``/``drain``/``raise_if_failed``.
 Stores without the build/commit split (MemoryStateStore, the cluster
 coordinator's epoch shim) take the inline ``sync()`` fallback — same
 ordering and callbacks, no overlap.
+
+**Stages.** ``upload_s`` (seal → durable commit) is cut where it
+happens into stages that add up to it, handed to ``on_commit`` as
+``(name, wall-clock start, seconds, counts)`` and entered as
+``TraceAnnotation``s of the same names while they run:
+
+  ``checkpoint.queue``       waiting behind the older epoch's build and
+                             commit (two stretches, one name)
+  ``checkpoint.build``       ``build_ssts``, synchronous on the loop;
+                             entries, sst_bytes, tables
+  ``checkpoint.put``         the object-store PUTs, off the loop;
+                             objects, bytes, retries
+  ``checkpoint.sink_stage``  sink staging, where a sink is attached
+  ``checkpoint.commit``      ``commit_ssts`` less its compaction
+  ``checkpoint.compact``     the inline ``compact()`` this commit
+                             triggered, else absent; ssts_read,
+                             read_bytes, ssts_written, write_bytes,
+                             entries_dropped
+
+The barrier plane (``meta/barrier.record_checkpoint_tail``) turns them
+into spans under the sealing barrier's ``checkpoint.upload`` and into
+the ``ckpt.*`` names of that barrier's ``rw_metrics_history`` row:
+``ckpt.queue_s``, ``ckpt.build_s``, ``ckpt.put_s``, ``ckpt.commit_s``,
+``ckpt.compact_s`` (0 where the commit did not compact),
+``ckpt.sst_bytes``, ``ckpt.compact_read_bytes``,
+``ckpt.compact_write_bytes``, and ``ckpt.sink_stage_s`` with a sink.
+The synchronous stages are also the ledger's LOOP phases
+(``checkpoint``, ``compaction``: utils/ledger.py, "Stolen loop time"),
+booked where they run, in storage/hummock.py.
 """
 
 from __future__ import annotations
@@ -41,8 +70,9 @@ from __future__ import annotations
 import asyncio
 import time
 from collections import OrderedDict, deque
-from typing import Callable, Deque, Optional
+from typing import Callable, Deque, List, Optional
 
+from risingwave_tpu.utils import spans as _spans
 from risingwave_tpu.utils.metrics import (
     STORAGE as _STORAGE, STREAMING as _STREAMING,
 )
@@ -57,7 +87,8 @@ class CheckpointUploader:
                  upload_retries: int = 2,
                  retry_backoff_s: float = 0.05,
                  monotonic: Callable[[], float] = time.monotonic,
-                 on_commit: Optional[Callable[[int, float], None]] = None):
+                 on_commit: Optional[
+                     Callable[[int, float, List[tuple]], None]] = None):
         self.store = store
         self._split = (hasattr(store, "build_ssts")
                        and hasattr(store, "commit_ssts"))
@@ -146,7 +177,7 @@ class CheckpointUploader:
             if self.sinks is not None:
                 self.sinks.stage_upto_sync(epoch)
             self.store.sync(epoch)
-            self._note_commit(epoch, self.monotonic() - t0)
+            self._note_commit(epoch, self.monotonic() - t0, [])
             if self.sinks is not None:
                 self.sinks.commit_upto(epoch)
             return True
@@ -176,32 +207,70 @@ class CheckpointUploader:
                          prev_committed: Optional[asyncio.Future],
                          committed: asyncio.Future) -> None:
         t0 = self.monotonic()
+        # stage boundaries: each stage ends where the next begins, so
+        # the stages add up to upload_s by construction
+        edge = [t0, time.time()]
+        stages: List[tuple] = []
+
+        def mark(name: str, **counts) -> float:
+            now = self.monotonic()
+            dur = now - edge[0]
+            stages.append((name, edge[1], dur, counts))
+            edge[0], edge[1] = now, time.time()
+            return dur
+
         try:
-            if prev_built is not None:
-                await prev_built
+            with _spans.annotation("checkpoint.queue", epoch):
+                if prev_built is not None:
+                    await prev_built
+            mark("checkpoint.queue")
             if self._failure is not None:
                 # an older epoch died mid-build: draining imms past it
                 # could orphan its data — abort before touching state
                 raise self._failure
             try:
-                payloads = self.store.build_ssts(epoch)
+                with _spans.annotation("checkpoint.build", epoch):
+                    payloads = self.store.build_ssts(epoch)
             finally:
                 if not built.done():
                     built.set_result(None)
-            for p in payloads:
-                await self._upload(p)
+            nbytes = sum(len(p["data"]) for p in payloads)
+            mark("checkpoint.build",
+                 entries=sum(p.get("entries", 0) for p in payloads),
+                 sst_bytes=nbytes,
+                 tables=sum(p.get("tables", 0) for p in payloads))
+            retries = 0
+            with _spans.annotation("checkpoint.put", epoch):
+                for p in payloads:
+                    retries += await self._upload(p)
+            mark("checkpoint.put", objects=len(payloads), bytes=nbytes,
+                 retries=retries)
             if self.sinks is not None:
                 # sink staging is part of the epoch's durability set:
                 # it must land before the commit below advances the
                 # floor, and it rides the same async tail the SST
                 # uploads do (upload_s, never barrier_wait)
-                await self.sinks.stage_upto(epoch)
-            if prev_committed is not None:
-                await prev_committed
+                with _spans.annotation("checkpoint.sink_stage", epoch):
+                    await self.sinks.stage_upto(epoch)
+                mark("checkpoint.sink_stage")
+            with _spans.annotation("checkpoint.queue", epoch):
+                if prev_committed is not None:
+                    await prev_committed
+            mark("checkpoint.queue")
             if self._failure is not None:
                 raise self._failure      # NEVER commit past a failure
-            self.store.commit_ssts(epoch, payloads)
-            self._note_commit(epoch, self.monotonic() - t0)
+            with _spans.annotation("checkpoint.commit", epoch):
+                done = self.store.commit_ssts(epoch, payloads)
+            whole = mark("checkpoint.commit")
+            counts = dict((done or {}).get("compaction") or {})
+            if counts:
+                # the compaction ran inside the commit: give it its
+                # own stage and take it out of the commit's
+                at = counts.pop("start_s")
+                took = min(counts.pop("dur_s"), whole)
+                stages[-1] = stages[-1][:2] + (whole - took, {})
+                stages.append(("checkpoint.compact", at, took, counts))
+            self._note_commit(epoch, edge[0] - t0, stages)
             if self.sinks is not None:
                 await asyncio.to_thread(self.sinks.commit_upto, epoch)
         except asyncio.CancelledError:
@@ -221,17 +290,18 @@ class CheckpointUploader:
             self._tasks.pop(epoch, None)
             self._set_depth()
 
-    async def _upload(self, payload: dict) -> None:
+    async def _upload(self, payload: dict) -> int:
         """One payload's durable upload: thread-offloaded PUT under the
         concurrency bound, retried with exponential backoff before the
-        failure poisons the pipeline (fails the barrier)."""
+        failure poisons the pipeline (fails the barrier). Returns the
+        retries it took."""
         delay = self.retry_backoff_s
         for attempt in range(self.upload_retries + 1):
             async with self._sem:
                 try:
                     await asyncio.to_thread(self.store.upload_payload,
                                             payload)
-                    return
+                    return attempt
                 except asyncio.CancelledError:
                     raise
                 except BaseException:
@@ -241,11 +311,12 @@ class CheckpointUploader:
             await asyncio.sleep(delay)
             delay *= 2
 
-    def _note_commit(self, epoch: int, upload_s: float) -> None:
+    def _note_commit(self, epoch: int, upload_s: float,
+                     stages: List[tuple]) -> None:
         assert epoch > self.committed_epoch, \
             (epoch, self.committed_epoch)    # ordered, never skips
         self.committed_epoch = epoch
         self.commit_log.append(epoch)
         _STREAMING.barrier_upload.observe(upload_s)
         if self.on_commit is not None:
-            self.on_commit(epoch, upload_s)
+            self.on_commit(epoch, upload_s, stages)

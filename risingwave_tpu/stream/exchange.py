@@ -21,6 +21,7 @@ from typing import AsyncIterator, List, Optional, Tuple
 
 from risingwave_tpu.common.chunk import StreamChunk
 from risingwave_tpu.stream.message import Barrier, Message, Watermark
+from risingwave_tpu.utils.ledger import actor_clock
 from risingwave_tpu.utils.metrics import STREAMING as _METRICS
 
 
@@ -135,33 +136,33 @@ class Sender:
                     # wall time is backpressure, not processing — meter
                     # it per channel and charge the context's tricolor
                     # accumulator (the fast path pays only this branch)
-                    park0 = time.perf_counter()
+                    park0 = actor_clock()
                     await s.cond.wait_for(
                         lambda: s.closed or s.chunk_permits >= cost)
                 if s.closed:
                     if park0:
-                        note_backpressure(time.perf_counter() - park0,
+                        note_backpressure(actor_clock() - park0,
                                           s.edge)
                     raise ChannelClosed
                 s.chunk_permits -= cost
             if park0:
-                note_backpressure(time.perf_counter() - park0, s.edge)
+                note_backpressure(actor_clock() - park0, s.edge)
             s.queue.put_nowait(("chunk", cost, msg))
         elif isinstance(msg, Barrier):
             park0 = 0.0
             async with s.cond:
                 if not (s.closed or s.barrier_permits >= 1):
-                    park0 = time.perf_counter()
+                    park0 = actor_clock()
                     await s.cond.wait_for(
                         lambda: s.closed or s.barrier_permits >= 1)
                 if s.closed:
                     if park0:
-                        note_backpressure(time.perf_counter() - park0,
+                        note_backpressure(actor_clock() - park0,
                                           s.edge)
                     raise ChannelClosed
                 s.barrier_permits -= 1
             if park0:
-                note_backpressure(time.perf_counter() - park0, s.edge)
+                note_backpressure(actor_clock() - park0, s.edge)
             s.queue.put_nowait(("barrier", 1, msg))
         else:  # watermarks are control-plane: unmetered
             if s.closed:

@@ -24,6 +24,7 @@ from risingwave_tpu.common.types import Field, Schema
 from risingwave_tpu.expr.expr import Expression
 from risingwave_tpu.stream.exchange import ChannelClosed, Receiver
 from risingwave_tpu.stream.executor import Executor, ExecutorInfo
+from risingwave_tpu.utils.ledger import actor_clock
 from risingwave_tpu.stream.message import (
     Message, Watermark, is_barrier, is_chunk,
 )
@@ -44,20 +45,19 @@ class ReceiverExecutor(Executor):
         self.idle_wait_s = 0.0
 
     async def execute(self) -> AsyncIterator[Message]:
-        import time as _time
         # NOTE: no rx.close() on teardown here — the chain edge may
         # still be attached to a live upstream dispatcher (a close
         # would turn its next dispatch into ChannelClosed and kill the
         # healthy upstream); the session's _stop_job closes the rx via
         # close_receivers AFTER detaching the edge
         while True:
-            t0 = _time.monotonic()
+            t0 = actor_clock()
             try:
                 msg = await self.rx.recv()
             except ChannelClosed:
                 return
             finally:
-                self.idle_wait_s += _time.monotonic() - t0
+                self.idle_wait_s += actor_clock() - t0
             yield msg
             if is_barrier(msg) and msg.is_stop(self.actor_id):
                 return

@@ -25,6 +25,7 @@ from risingwave_tpu.stream.executor import Executor, ExecutorInfo
 from risingwave_tpu.stream.message import (
     Barrier, Message, SourceChangeSplitMutation, is_barrier,
 )
+from risingwave_tpu.utils.ledger import LEDGER, actor_clock
 from risingwave_tpu.utils.metrics import STREAMING as _METRICS
 
 
@@ -79,7 +80,8 @@ class SourceExecutor(Executor):
         self.min_chunks = min_chunks_per_barrier
         self.paused = False
         # cumulative wall time parked on the barrier channel with
-        # nothing to generate. The monitor subtracts this from the
+        # nothing to generate (on the actors' clock: less the loop time
+        # a checkpoint build, commit or compaction held meanwhile). The monitor subtracts this from the
         # source's exclusive busy time: a source waiting out a slow
         # downstream epoch is IDLE, and counting the park as busy
         # would crown every source the straggler (trace diagnosis)
@@ -148,9 +150,9 @@ class SourceExecutor(Executor):
         # instead of waiting on async-generator finalization)
         # protocol: first message is the init barrier (source_executor.rs
         # waits for the first barrier before opening the reader)
-        t0 = time.monotonic()
+        t0 = actor_clock()
         first = await self.barrier_rx.recv()
-        self.idle_wait_s += time.monotonic() - t0
+        self.idle_wait_s += actor_clock() - t0
         assert is_barrier(first), f"source got {first!r} before init barrier"
         if self.split_state is not None:
             self.split_state.init_epoch(first.epoch)
@@ -178,13 +180,13 @@ class SourceExecutor(Executor):
                 self.rate_limit is not None
                 and chunks_this_epoch >= self.rate_limit))
             if not can_generate:
-                t0 = time.monotonic()
+                t0 = actor_clock()
                 try:
                     barrier = await self.barrier_rx.recv()  # blocking
                 except ChannelClosed:
                     return
                 finally:
-                    self.idle_wait_s += time.monotonic() - t0
+                    self.idle_wait_s += actor_clock() - t0
             elif chunks_this_epoch > 0 and (
                     self.min_chunks is None
                     or chunks_this_epoch >= self.min_chunks):
@@ -201,7 +203,10 @@ class SourceExecutor(Executor):
                 if barrier.is_stop(self.actor_id):
                     return
                 continue
-            chunk = self.reader.next_chunk()
+            # a scope, so that generating is on the profiler's clock too
+            # (what the connector's own scopes leave is the same phase)
+            with LEDGER.phase("host_ingest"):
+                chunk = self.reader.next_chunk()
             if chunk is None:
                 if getattr(self.reader, "unbounded", False):
                     # log-style source with no complete records yet:

@@ -22,6 +22,7 @@ delays a control message (stream/coalesce.py contract).
 from __future__ import annotations
 
 import asyncio
+from contextvars import ContextVar
 from typing import AsyncIterator, Dict, List, Optional
 
 from risingwave_tpu.common.chunk import StreamChunk
@@ -30,6 +31,7 @@ from risingwave_tpu.stream.coalesce import (
 )
 from risingwave_tpu.stream.exchange import ChannelClosed, Receiver
 from risingwave_tpu.stream.executor import Executor, ExecutorInfo
+from risingwave_tpu.utils.ledger import actor_clock
 from risingwave_tpu.stream.message import (
     Barrier, Message, Watermark, is_barrier,
 )
@@ -264,6 +266,24 @@ class MergeExecutors(Executor):
                 yield msg
 
 
+# Seconds an aligning executor (join, union) spent parked for its
+# inputs, on the actors' clock. Its inputs are pulled CONCURRENTLY, in
+# tasks of their own, so their busy times overlap each other and the
+# monitor cannot take their sum out of the aligner's: it pushes a cell
+# here around each pull of the aligner (like the exchange's park cell)
+# and takes the aligner's own wait out instead.
+_ALIGN_WAIT: ContextVar[Optional[List[float]]] = ContextVar(
+    "align_wait_cell", default=None)
+
+
+def push_align_cell(cell: List[float]):
+    return _ALIGN_WAIT.set(cell)
+
+
+def pop_align_cell(token) -> None:
+    _ALIGN_WAIT.reset(token)
+
+
 async def barrier_align_n(inputs: List[AsyncIterator[Message]]
                           ) -> AsyncIterator[tuple]:
     """N-way alignment over executor streams (barrier_align.rs:34 analog).
@@ -292,8 +312,12 @@ async def barrier_align_n(inputs: List[AsyncIterator[Message]]
                 futs = [asyncio.ensure_future(nxt(it)) for it in inputs]
                 continue
             waits = {futs[i] for i in range(n) if parked[i] is None}
+            t0 = actor_clock()
             done, _ = await asyncio.wait(
                 waits, return_when=asyncio.FIRST_COMPLETED)
+            cell = _ALIGN_WAIT.get()
+            if cell is not None:
+                cell[0] += actor_clock() - t0
             for i in range(n):
                 if parked[i] is not None or futs[i] not in done:
                     continue

@@ -18,11 +18,13 @@ consumer awaits it).
 
 from __future__ import annotations
 
+import re
 import threading
 import time
 from typing import AsyncIterator, Dict, List, Optional, Tuple
 
 from risingwave_tpu.stream import exchange as _xchg
+from risingwave_tpu.stream import merge as _merge
 from risingwave_tpu.stream.executor import (
     Executor, ExecutorInfo, executor_children,
 )
@@ -251,9 +253,19 @@ class MonitoredExecutor(Executor):
         # backpressure share
         self._park_cell = [0.0]
         self._mark_park = 0.0
+        # time THIS node's pulls spent parked in barrier_align_n for
+        # inputs it pulls concurrently (stream/merge.py): what comes
+        # out of its busy time in place of the inputs' overlapping sum
+        self._align_cell = [0.0]
+        self._mark_align = 0.0
         self._mark_meter = 0.0      # actor-loop meter mark (root only)
         self._last_flush_pc: Optional[float] = None
         self._who = f"actor-{actor_id}/{node}:{inner.identity}"
+        # executor kind: the identity up to its first non-letter
+        # ("HashAggExecutor(actor=7)" → "HashAggExecutor"), the key of
+        # the per-epoch exec_s.<Kind> history names
+        self._kind = re.match(r"[A-Za-z]*", inner.identity).group() \
+            or type(inner).__name__
         # phase-ledger attribution cell: named phases recorded during
         # THIS executor's pulls land here (asyncio-context scoped, so
         # interleaved actors never cross-charge); the barrier flush
@@ -276,9 +288,13 @@ class MonitoredExecutor(Executor):
         epoch = barrier.epoch.curr.value
         own = self.total_busy_s
         kids = sum(c.total_busy_s for c in self.children)
-        excl = max(0.0, (own - self._mark_own)
-                   - (kids - self._mark_kids))
+        # an aligner's inputs ran while it waited in barrier_align_n;
+        # anyone else's, one at a time, inside its own pulls
+        waited = (self._align_cell[0] - self._mark_align) \
+            or (kids - self._mark_kids)
+        excl = max(0.0, (own - self._mark_own) - waited)
         self._mark_own, self._mark_kids = own, kids
+        self._mark_align = self._align_cell[0]
         # sources (no wrapped inputs to subtract) expose the time they
         # spent PARKED on the barrier channel — idle, not processing:
         # without this, a source waiting out a slow downstream epoch
@@ -331,6 +347,7 @@ class MonitoredExecutor(Executor):
             # reassembly/state writes/dispatch (host_emit); the barrier
             # park is barrier_wait
             named = self._cell.named_total()
+            _ledger.LEDGER.attribute_exec(self._kind, excl, epoch)
             if _costs.enabled():
                 # per-MV split of the SAME cell the ledger is about to
                 # commit: the fragment label is the MV/job name, and
@@ -394,7 +411,11 @@ class MonitoredExecutor(Executor):
         it = self.inner.execute()
         try:
             while True:
-                t0 = time.perf_counter()
+                # loop time a foreign synchronous section (checkpoint
+                # build, commit, compaction) holds while this pull is
+                # parked in an await is not this executor's: the
+                # stolen-time rule of utils/ledger.py
+                t0 = _ledger.actor_clock()
                 _AWAITS.enter(self._who, "poll_next")
                 # ledger cell: scopes fired while the INNER executor
                 # works (pack/h2d/dispatch/d2h inside this pull) are
@@ -414,11 +435,15 @@ class MonitoredExecutor(Executor):
                 # mirroring the ledger cells)
                 ptok = _xchg.push_park_cell(self._park_cell) \
                     if TRICOLOR else None
+                atok = _merge.push_align_cell(self._align_cell) \
+                    if self.children else None
                 try:
                     msg = await it.__anext__()
                 except StopAsyncIteration:
                     break
                 finally:
+                    if atok is not None:
+                        _merge.pop_align_cell(atok)
                     if ptok is not None:
                         _xchg.pop_park_cell(ptok)
                     if mtok is not None:
@@ -426,7 +451,7 @@ class MonitoredExecutor(Executor):
                     if ctok is not None:
                         _ledger.LEDGER.pop_cell(ctok)
                     _AWAITS.exit(self._who)
-                    self.total_busy_s += time.perf_counter() - t0
+                    self.total_busy_s += _ledger.actor_clock() - t0
                 if is_chunk(msg):
                     card = msg.cardinality()
                     if card == 0:

@@ -36,6 +36,7 @@ from risingwave_tpu.stream.message import (
     Barrier, BarrierKind, Message, PauseMutation, ResumeMutation,
     StopMutation, Watermark, is_barrier, is_chunk,
 )
+from risingwave_tpu.utils.ledger import actor_clock
 from risingwave_tpu.stream.trace_ctx import (
     barrier_trailer, record_remote_transfer,
 )
@@ -326,13 +327,12 @@ class RemoteOutputQueue:
             if self._credits.locked():
                 # credit-starved: the wire peer is behind — park time
                 # is backpressure, not the sending executor's work
-                import time as _time
                 from risingwave_tpu.stream.exchange import (
                     note_backpressure,
                 )
-                t0 = _time.perf_counter()
+                t0 = actor_clock()
                 await self._credits.acquire()
-                note_backpressure(_time.perf_counter() - t0, self.label)
+                note_backpressure(actor_clock() - t0, self.label)
             else:
                 await self._credits.acquire()
             if self._broken:
@@ -381,7 +381,6 @@ class RemoteInput(Executor):
         self.idle_wait_s = 0.0
 
     async def execute(self) -> AsyncIterator[Message]:
-        import time as _time
         reader, writer = await asyncio.open_connection(self.host,
                                                        self.port)
         writer.write(_frame(b"H", struct.pack(
@@ -390,13 +389,13 @@ class RemoteInput(Executor):
         consumed = 0
         try:
             while True:
-                t0 = _time.monotonic()
+                t0 = actor_clock()
                 try:
                     tag, payload = await _read_frame(reader)
                 except asyncio.IncompleteReadError:
                     return                      # upstream closed
                 finally:
-                    self.idle_wait_s += _time.monotonic() - t0
+                    self.idle_wait_s += actor_clock() - t0
                 if tag == b"D":
                     consumed += 1
                     if consumed >= self.credit_batch:

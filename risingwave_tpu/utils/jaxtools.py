@@ -18,6 +18,8 @@ trace before it is kept or dropped (ROADMAP D1).
 from __future__ import annotations
 
 import os
+import re
+import zlib
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -165,6 +167,20 @@ def publish_kernel_costs() -> int:
     return len(rows)
 
 
+def program_name(label: str) -> str:
+    """The name a kernel's program goes by in the device trace and in
+    the compile cache's file names: the label's stem with every
+    non-word character an underscore, and, where the label carries a
+    bracketed variant (a fused prelude's signature, which can run to
+    hundreds of characters; a cache file's name may not), eight hex
+    digits of it. The same label gives the same name in every run."""
+    stem, bracket, variant = label.partition("[")
+    name = re.sub(r"\W", "_", stem)[:64]
+    if bracket:
+        name += "_%08x" % zlib.crc32(variant.encode())
+    return name
+
+
 def instrumented_jit(fn, label: str | None = None, **jit_kw):
     """``jax.jit`` with (re)trace visibility: the wrapper's Python body
     runs only while jax TRACES it — once per new input shape bucket —
@@ -195,6 +211,10 @@ def instrumented_jit(fn, label: str | None = None, **jit_kw):
                 inst_box[0].mark_stale()
         return fn(*a, **k)
 
+    # the program carries the kernel's label: the profiler's `XLA
+    # Modules` line and the compile cache read jit_hash_join_epoch_apply
+    # where they read jit_ap, the inner function's name
+    traced.__name__ = traced.__qualname__ = program_name(name)
     inst = InstrumentedJit(jax.jit(traced, **jit_kw), name)
     inst_box.append(inst)
     return inst

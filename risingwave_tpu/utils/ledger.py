@@ -26,11 +26,20 @@ attributable, continuously, not in one-off cProfile runs):
                       recorded during its pulls (the residue that is
                       provably host work but not pack/transfer).
 - ``barrier_wait``  — source executors parked on the barrier channel
-                      (idle, not processing).
+                      while nothing else of the epoch worked: the park
+                      (across-source max) fills only what the other
+                      phases leave of the interval.
 - ``backpressure_wait`` — senders parked for exchange credits (a slow
                       consumer's wall time, subtracted from the parking
                       executor's busy share — stream/monitor.py's
                       utilization tricolor carries the per-actor view).
+- ``checkpoint``    — synchronous checkpoint work on the event loop,
+                      outside any actor: the SST build
+                      (``HummockLite.build_ssts``), the manifest commit
+                      (``commit_ssts`` less the compaction) and the
+                      checkpoint-time sweeps of ``collect_next``.
+- ``compaction``    — the inline ``HummockLite.compact()`` a commit
+                      triggers, synchronous on the event loop.
 
 Two disciplines keep the ledger honest:
 
@@ -40,6 +49,22 @@ Two disciplines keep the ledger honest:
   second. Executor-level residue subtracts the named time recorded
   during that executor's own pulls (an asyncio-context cell, so
   interleaved actors never cross-charge).
+- **Stolen loop time.** ``checkpoint`` and ``compaction`` are the LOOP
+  phases: synchronous sections that block the event loop from a task
+  that is no actor (the uploader's, the barrier loop's). While one
+  runs, every actor parked in an ``await`` still has its wall clock
+  running. Each loop scope therefore adds its exclusive time to one
+  process-wide monotone accumulator (``stolen_s()``), and every
+  wall-clock interval taken around an await (``MonitoredExecutor``'s
+  pulls, the ``idle_wait_s`` parks of sources and channel receivers,
+  the senders' credit parks) is taken with ``actor_clock()``, which
+  subtracts the accumulator's delta over that interval. On one event
+  loop a synchronous foreign section cannot overlap actor code, so the
+  subtraction is exact. The seconds are booked on the epoch in whose
+  interval they ran: they wait in ``_loop_pending`` with their wall
+  stamps and the next ``seal`` takes what lies before its interval's
+  end (the newest INJECTED epoch may be several barriers ahead of the
+  one being collected, or already sealed).
 - **Conservation.** At barrier collection the loop seals the epoch
   against its measured interval; the uncovered remainder is published
   as ``unattributed`` — and gated in tier-1 strict mode (conftest), so
@@ -52,11 +77,16 @@ uses); scopes outside any executor attribute to the newest injected
 epoch (the utils/spans approximation).
 
 Output surfaces: ``stream_epoch_phase_seconds{phase,query}`` and
-``stream_transfer_bytes_total{dir,kernel}`` Prometheus families, phase
-lanes + byte counter tracks in the Perfetto export (utils/spans), the
-``rw_metrics_history`` per-barrier ring (utils/metrics.HISTORY — the
-feed the elastic-serving control loop reads), the per-query
-``phase_breakdown`` block in bench rounds, and ``ctl phases``.
+``stream_transfer_bytes_total{dir,kernel}`` Prometheus families; a
+``phase.<name>`` span where and for as long as a scope ran (from
+``SPAN_MIN_S`` up) + byte counter tracks in the Perfetto export
+(utils/spans); every scope as a ``TraceAnnotation`` in a running
+``jax.profiler`` trace; the ``rw_metrics_history`` per-barrier ring
+(utils/metrics.HISTORY — the feed the elastic-serving control loop
+reads: ``phase.<name>`` seconds, and ``exec_s.<Kind>``, each executor
+kind's exclusive busy seconds of the epoch, a second cut of the same
+wall time); the per-query ``phase_breakdown`` block in bench rounds,
+and ``ctl phases``.
 """
 
 from __future__ import annotations
@@ -66,11 +96,22 @@ import threading
 import time
 from collections import OrderedDict, deque
 from contextvars import ContextVar
-from typing import Deque, Dict, Iterable, List, Optional
+from typing import Deque, Dict, Iterable, List, Optional, Tuple
+
+from risingwave_tpu.utils import spans as _spans
 
 PHASES = ("host_ingest", "host_pack", "h2d", "device_compute", "d2h",
-          "host_emit", "barrier_wait", "backpressure_wait")
+          "host_emit", "barrier_wait", "backpressure_wait",
+          "checkpoint", "compaction")
+# synchronous on the event loop, outside any actor (module docstring,
+# "Stolen loop time")
+LOOP_PHASES = frozenset(("checkpoint", "compaction"))
 UNATTRIBUTED = "unattributed"
+
+# scoped phases shorter than this keep their seconds in the books and
+# leave no span of their own in the epoch trace (the profiler's trace
+# has every one of them as a TraceAnnotation)
+SPAN_MIN_S = 0.001
 
 # open-epoch accumulators kept (epochs are injected faster than sealed
 # only up to the in-flight window; the bound guards leaks on epochs
@@ -91,6 +132,24 @@ _CELL: ContextVar[Optional["AttributionCell"]] = ContextVar(
     "ledger_cell", default=None)
 # current kernel identity for transfer/compute attribution
 _KERNEL: ContextVar[str] = ContextVar("ledger_kernel", default="")
+
+
+# seconds the LOOP phases have held the event loop so far: monotone,
+# process-wide, written from the loop's thread only
+_STOLEN = [0.0]
+
+
+def stolen_s() -> float:
+    """The stolen-loop-time accumulator (module docstring)."""
+    return _STOLEN[0]
+
+
+def actor_clock() -> float:
+    """``time.perf_counter`` less the stolen loop time: a clock that
+    stands still while a LOOP phase holds the event loop. A wall-clock
+    interval taken with it around an ``await`` is free of the foreign
+    sections that ran inside the wait."""
+    return time.perf_counter() - _STOLEN[0]
 
 
 def enabled() -> bool:
@@ -166,10 +225,13 @@ class AttributionCell:
 class _EpochAcc:
     """Open accumulator for one epoch (pre-seal)."""
 
-    __slots__ = ("seconds", "h2d_bytes", "d2h_bytes", "warmup", "idle")
+    __slots__ = ("seconds", "h2d_bytes", "d2h_bytes", "warmup", "idle",
+                 "exec_s")
 
     def __init__(self) -> None:
         self.seconds: Dict[str, float] = {}
+        # exclusive busy seconds by executor kind (stream/monitor.py)
+        self.exec_s: Dict[str, float] = {}
         self.h2d_bytes = 0
         self.d2h_bytes = 0
         self.warmup = False     # saw a kernel (re)compile this epoch
@@ -197,7 +259,7 @@ class LedgerRecord:
 
     __slots__ = ("epoch", "kind", "interval_s", "seconds", "h2d_bytes",
                  "d2h_bytes", "warmup", "distributed", "workers",
-                 "idle_max", "domain")
+                 "idle_max", "domain", "exec_s")
 
     def __init__(self, epoch: int, kind: str, interval_s: float,
                  seconds: Dict[str, float], h2d_bytes: int,
@@ -213,6 +275,10 @@ class LedgerRecord:
         self.domain = domain
         self.interval_s = interval_s
         self.seconds = seconds          # includes UNATTRIBUTED
+        # executor kind → exclusive busy seconds of the epoch: a second
+        # cut of the same wall time the phases partition, never added
+        # to them
+        self.exec_s: Dict[str, float] = {}
         self.h2d_bytes = h2d_bytes
         self.d2h_bytes = d2h_bytes
         self.warmup = warmup
@@ -254,6 +320,7 @@ class LedgerRecord:
                 "domain": self.domain,
                 "interval_s": self.interval_s,
                 "seconds": dict(self.seconds),
+                "exec_s": dict(self.exec_s),
                 "h2d_bytes": self.h2d_bytes,
                 "d2h_bytes": self.d2h_bytes,
                 "warmup": self.warmup,
@@ -284,6 +351,14 @@ class PhaseLedger:
         self.query = ""
         # cell commits race the uploader's worker threads' scopes
         self._lock = threading.Lock()
+        # LOOP-phase sections not yet on an epoch's books, with their
+        # time.monotonic stamps: [start, end, phase, exclusive seconds]
+        self._loop_pending: List[list] = []
+        # epochs sealed lately, and the books of scopes that named no
+        # epoch after the newest injected one had sealed: the next
+        # seal takes them (books opened for a sealed epoch never close)
+        self._sealed: Deque[int] = deque(maxlen=OPEN_WINDOW)
+        self._carry = _EpochAcc()
 
     # module-level kernel-context scope, re-exported on the instance
     # (call sites hold LEDGER, not the module)
@@ -292,8 +367,9 @@ class PhaseLedger:
     # -- recording -----------------------------------------------------
     def _acc(self, epoch: Optional[int] = None) -> _EpochAcc:
         if epoch is None:
-            from risingwave_tpu.utils import spans as _spans
             epoch = _spans.current_epoch()
+            if epoch in self._sealed:
+                return self._carry
         acc = self._open.get(epoch)
         if acc is None:
             acc = self._open[epoch] = _EpochAcc()
@@ -306,31 +382,57 @@ class PhaseLedger:
         """Scoped timer: the block's EXCLUSIVE wall time (minus nested
         scopes) lands in `name` — in the active executor cell when one
         is set (epoch-exact flush at the barrier), else directly in the
-        newest injected epoch's accumulator."""
-        if not _ENABLED:
+        newest injected epoch's accumulator. A LOOP phase instead adds
+        to the stolen-time accumulator and waits for the next seal
+        (module docstring). The scope is a ``TraceAnnotation`` for its
+        duration, and a ``phase.<name>`` span of the epoch trace where
+        it lasts ``SPAN_MIN_S`` or more."""
+        loop = name in LOOP_PHASES
+        if not _ENABLED and not loop:
             yield
             return
         parent = _SCOPE.get()
         mine = [0.0]
         tok = _SCOPE.set(mine)
         ktok = _KERNEL.set(kernel) if kernel else None
+        ann = _spans.annotation("phase." + name)
+        ann.__enter__()
         t0 = time.perf_counter()
         try:
             yield
         finally:
             dur = time.perf_counter() - t0
+            ann.__exit__(None, None, None)
             _SCOPE.reset(tok)
             if ktok is not None:
                 _KERNEL.reset(ktok)
             if parent is not None:
                 parent[0] += dur
             excl = max(0.0, dur - mine[0])
-            cell = _CELL.get()
-            if cell is not None:
-                cell.seconds[name] = cell.seconds.get(name, 0.0) + excl
+            if loop:
+                _STOLEN[0] += excl
+                if _ENABLED:
+                    end = time.monotonic()
+                    with self._lock:
+                        self._loop_pending.append(
+                            [end - dur, end, name, excl])
+                        if len(self._loop_pending) > 4096:
+                            # a process that never seals (a cluster
+                            # worker): nothing reads these
+                            del self._loop_pending[:2048]
             else:
-                with self._lock:
-                    self._acc().add(name, excl)
+                cell = _CELL.get()
+                if cell is not None:
+                    cell.seconds[name] = cell.seconds.get(name, 0.0) \
+                        + excl
+                else:
+                    with self._lock:
+                        self._acc().add(name, excl)
+            if dur >= SPAN_MIN_S and _ENABLED:
+                _spans.EPOCH_TRACER.record(
+                    "phase." + name, "phase",
+                    start_s=time.time() - dur, dur_s=dur,
+                    exclusive_s=round(excl, 6))
 
     def attribute(self, name: str, seconds: float,
                   epoch: Optional[int] = None) -> None:
@@ -353,6 +455,16 @@ class PhaseLedger:
             return
         with self._lock:
             self._acc(epoch).add_idle(source, seconds)
+
+    def attribute_exec(self, kind: str, seconds: float,
+                       epoch: int) -> None:
+        """One executor node's exclusive busy seconds of the epoch,
+        folded by executor kind (published as ``exec_s.<Kind>``)."""
+        if not _ENABLED or seconds <= 0:
+            return
+        with self._lock:
+            ex = self._acc(epoch).exec_s
+            ex[kind] = ex.get(kind, 0.0) + seconds
 
     def add_bytes(self, direction: str, nbytes: int,
                   kernel: Optional[str] = None) -> None:
@@ -415,7 +527,10 @@ class PhaseLedger:
     def seal(self, epoch: int, interval_s: float, kind: str = "barrier",
              distributed: bool = False,
              warmup: bool = False,
-             domain: str = "") -> Optional[LedgerRecord]:
+             domain: str = "",
+             wake_gap: Optional[Tuple[float, float]] = None,
+             between: Optional[Tuple[float, float]] = None
+             ) -> Optional[LedgerRecord]:
         """Close the epoch's books against its measured barrier
         interval: residual → ``unattributed``, publish the Prometheus
         phase family, the trace phase lanes + counter tracks, and the
@@ -425,26 +540,54 @@ class PhaseLedger:
         ``domain`` keys the record (and its history row) by the barrier
         domain that ran the epoch — overlapped domains each partition
         their OWN wall timeline, so per-record conservation survives
-        the compute/ingest overlap."""
+        the compute/ingest overlap. ``wake_gap`` (time.monotonic
+        stamps) is the stretch between the last actor's collect and
+        the collecting coroutine's wake-up: the caller's interval
+        leaves it to the NEXT epoch, so LOOP-phase sections that ran in
+        it stay pending for that epoch's seal. ``between`` is the
+        stretch from the previous epoch's books closing to this one's
+        inject, where the two do not touch: it lies in no epoch's
+        interval, and the LOOP-phase seconds that ran in it (a
+        checkpoint built right after the seal holds up the next
+        inject) are added to this interval with their phase."""
         if not _ENABLED:
-            self._open.pop(epoch, None)
+            self.discard(epoch)
             return None
         with self._lock:
             acc = self._open.pop(epoch, None) or _EpochAcc()
+            self._sealed.append(epoch)
+            carry, self._carry = self._carry, _EpochAcc()
+            stolen, outside = self._take_loop_sections(wake_gap,
+                                                       between)
+        interval_s = float(interval_s) + outside
+        for name, s in list(carry.seconds.items()) + list(stolen.items()):
+            acc.add(name, s)
+        for key, s in carry.idle.items():
+            acc.add_idle(key, s)
+        acc.h2d_bytes += carry.h2d_bytes
+        acc.d2h_bytes += carry.d2h_bytes
+        acc.warmup = acc.warmup or carry.warmup
         seconds = dict(acc.seconds)
         idle = acc.idle_max()
         if idle > 0:
             # across-source MAX (concurrent parks overlap), capped at
-            # the interval — idle can never exceed the epoch it's in
+            # what the epoch's other phases leave of its interval: a
+            # source parks WHILE the actors downstream work its rows
+            # and a checkpoint holds the loop, and a second of wall
+            # clock that has a worker belongs to the work, not to
+            # whoever waited meanwhile
             if interval_s > 0:
-                idle = min(idle, float(interval_s))
-            seconds["barrier_wait"] = seconds.get("barrier_wait",
-                                                  0.0) + idle
+                idle = min(idle, max(
+                    0.0, float(interval_s) - sum(seconds.values())))
+            if idle > 0:
+                seconds["barrier_wait"] = seconds.get(
+                    "barrier_wait", 0.0) + idle
         rec = LedgerRecord(epoch, kind, float(interval_s),
                            seconds, acc.h2d_bytes,
                            acc.d2h_bytes, acc.warmup or warmup,
                            distributed, domain=domain)
         rec.idle_max = idle
+        rec.exec_s = acc.exec_s
         rec.recompute_unattributed()
         self.records.append(rec)
         self._publish(rec)
@@ -456,15 +599,44 @@ class PhaseLedger:
         phases can never cover)."""
         with self._lock:
             self._open.pop(epoch, None)
+            self._loop_pending.clear()
+
+    def _take_loop_sections(self, wake_gap, between=None):
+        """Pending LOOP-phase seconds by phase, less what ran inside
+        `wake_gap`, which stays pending, and how many of the seconds
+        taken ran inside `between` (a section across an edge of
+        either stretch is split by wall time). Caller holds the
+        lock."""
+        def share(start, end, stretch) -> float:
+            if stretch is None or end <= start:
+                return 0.0
+            return max(0.0, min(end, stretch[1])
+                       - max(start, stretch[0])) / (end - start)
+
+        taken: Dict[str, float] = {}
+        outside = 0.0
+        keep = []
+        for start, end, name, excl in self._loop_pending:
+            inside = share(start, end, wake_gap)
+            if inside > 0:
+                keep.append([max(start, wake_gap[0]),
+                             min(end, wake_gap[1]), name, excl * inside])
+            if inside < 1:
+                taken[name] = taken.get(name, 0.0) \
+                    + excl * (1.0 - inside)
+                outside += excl * share(start, end, between)
+        self._loop_pending = keep
+        return taken, outside
 
     def _publish(self, rec: LedgerRecord) -> None:
-        from risingwave_tpu.utils import spans as _spans
         from risingwave_tpu.utils.metrics import HISTORY, STREAMING
         q = self.query
         for name, s in rec.seconds.items():
             STREAMING.epoch_phase_seconds.inc(s, phase=name, query=q)
         extra = {f"phase.{p}": rec.seconds.get(p, 0.0)
                  for p in PHASES + (UNATTRIBUTED,)}
+        for kind, s in rec.exec_s.items():
+            extra["exec_s." + kind] = s
         extra["coverage"] = rec.coverage()
         extra["epoch_h2d_bytes"] = float(rec.h2d_bytes)
         extra["epoch_d2h_bytes"] = float(rec.d2h_bytes)
@@ -485,20 +657,6 @@ class PhaseLedger:
         if not _spans.enabled():
             return
         now = time.time()
-        at = now - rec.interval_s
-        for name in PHASES + (UNATTRIBUTED,):
-            s = rec.seconds.get(name, 0.0)
-            if s <= 0:
-                continue
-            # phase lanes: stacked from the interval start in the
-            # phase table's order — a share view, not a literal timeline (phases
-            # interleave within the epoch)
-            _spans.EPOCH_TRACER.record(
-                f"phase.{name}", "phase", epoch=rec.epoch, start_s=at,
-                dur_s=s, share=round(s / rec.interval_s, 4)
-                if rec.interval_s > 0 else 0.0,
-                **({"domain": rec.domain} if rec.domain else {}))
-            at += s
         # counter-track sample (export_chrome renders 'C' events)
         _spans.EPOCH_TRACER.record(
             "ledger.counters", "counter", epoch=rec.epoch, start_s=now,
@@ -533,6 +691,11 @@ class PhaseLedger:
         coordinator: workers never seal — the coordinator owns the
         barrier interval)."""
         with self._lock:
+            stolen, _outside = self._take_loop_sections(None)
+            if stolen:
+                acc = self._acc(_spans.current_epoch())
+                for name, secs in stolen.items():
+                    acc.add(name, secs)
             out = [{"epoch": e, "seconds": dict(a.seconds),
                     "h2d_bytes": a.h2d_bytes, "d2h_bytes": a.d2h_bytes,
                     "warmup": a.warmup, "idle_max": a.idle_max()}
@@ -679,6 +842,9 @@ class PhaseLedger:
         with self._lock:
             self._open.clear()
             self.records.clear()
+            self._loop_pending.clear()
+            self._sealed.clear()
+            self._carry = _EpochAcc()
 
 
 # the process-global ledger (worker processes drain to the coordinator)

@@ -639,6 +639,17 @@ class MetricsHistory:
             self._ring.append((self._seq, int(epoch), time.time(),
                                float(interval_s), values, domain))
 
+    def amend(self, epoch: int, values: Dict[str, float]) -> None:
+        """Add names to the row(s) of an epoch already in the ring:
+        what is known only after the seal (the checkpoint's stage
+        times land with its durable commit). An epoch the ring has
+        rolled past is left alone."""
+        with self._lock:
+            for row in reversed(self._ring):
+                if row[1] == epoch:     # epochs are unique in the ring
+                    row[4].update(values)
+                    return
+
     def rows(self) -> List[tuple]:
         """(seq, epoch, ts, interval_s, name, value, domain)
         long-format rows — the rw_metrics_history system-table

@@ -30,6 +30,10 @@ Design:
   flight ring has rolled past the epoch.
 - Export: `export_chrome()` renders Chrome trace-event JSON (Perfetto
   loads it directly); `rows()` backs the rw_epoch_trace system table.
+- **One clock with the device.** The barrier plane, the ledger's
+  phases, the dispatches and the checkpoint's stages also enter
+  ``annotation(name)`` for their duration, so a ``jax.profiler`` trace
+  holds them in its host plane beside the device's operations.
 
 Span ids embed the process id in their high bits so traces drained
 from worker processes merge without collisions.
@@ -45,6 +49,8 @@ import time
 from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Dict, Iterable, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 EPOCH_WINDOW = 64          # epochs kept in the flight ring
 MAX_SPANS_PER_EPOCH = 2048  # per-epoch span cap (overflow is counted)
@@ -128,6 +134,16 @@ def set_current_epoch(value: int) -> None:
 
 def current_epoch() -> int:
     return _CURRENT_EPOCH
+
+
+def annotation(name: str, epoch: Optional[int] = None) -> TraceAnnotation:
+    """The program's spans on the profiler's clock: a context manager
+    that puts `name` into the host plane of a running
+    ``jax.profiler`` trace, on the same clock as the device's ``XLA
+    Ops``, nested with the other annotations of its thread as they ran.
+    With no profiler session it is a flag check in C++."""
+    return TraceAnnotation(
+        name, epoch=_CURRENT_EPOCH if epoch is None else epoch)
 
 
 class EpochTracer:
@@ -426,7 +442,8 @@ def dispatch_span(kernel: str, rows: float, **args):
         return
     t0 = time.time()
     try:
-        with _ledger.LEDGER.phase("device_compute", kernel=kernel) \
+        with annotation(kernel), \
+                _ledger.LEDGER.phase("device_compute", kernel=kernel) \
                 if _ledger.enabled() else nullcontext():
             # ledger-test seam: a sleep spec here is wall time INSIDE
             # one kernel's dispatch — it must land in the dispatching

@@ -1,69 +1,16 @@
-"""Tracing: lightweight spans + an actor await-state registry.
+"""The actor await-state registry.
 
-Reference parity: the tracing-crate spans threaded through the
-reference (barrier TracingContext, src/stream/src/executor/mod.rs:253)
-and the await-tree actor stack dumps exposed by MonitorService
-(src/compute/src/rpc/service/monitor_service.rs:72) — reduced to a
-ring buffer of spans plus a per-actor "currently awaiting" table that
-a debugger (or test) can dump when a barrier stalls.
+Reference parity: the await-tree actor stack dumps exposed by
+MonitorService (src/compute/src/rpc/service/monitor_service.rs:72) —
+reduced to a per-actor "currently awaiting" table that a debugger (or
+test) can dump when a barrier stalls. Spans live in utils/spans.py
+(the epoch trace) and, on the profiler's clock, in its annotations.
 """
 
 from __future__ import annotations
 
-import contextvars
 import time
-from collections import deque
-from contextlib import contextmanager
-from dataclasses import dataclass, field
-from typing import Deque, Dict, List, Optional, Tuple
-
-
-@dataclass
-class Span:
-    name: str
-    start_s: float
-    end_s: float = 0.0
-    attrs: Dict[str, object] = field(default_factory=dict)
-    parent: Optional[str] = None
-
-    @property
-    def duration_s(self) -> float:
-        return max(0.0, self.end_s - self.start_s)
-
-
-# per-task span stack: concurrent actors must not see each other's
-# frames (a shared list would cross-attribute parents under asyncio)
-_SPAN_STACK: contextvars.ContextVar[Tuple[str, ...]] = \
-    contextvars.ContextVar("rw_span_stack", default=())
-
-
-class Tracer:
-    """Ring buffer of completed spans (OTLP-export seam)."""
-
-    def __init__(self, capacity: int = 4096,
-                 clock=time.monotonic) -> None:
-        self.spans: Deque[Span] = deque(maxlen=capacity)
-        self.clock = clock
-
-    @contextmanager
-    def span(self, name: str, **attrs):
-        stack = _SPAN_STACK.get()
-        s = Span(name, self.clock(),
-                 attrs=attrs,
-                 parent=stack[-1] if stack else None)
-        token = _SPAN_STACK.set(stack + (name,))
-        try:
-            yield s
-        finally:
-            _SPAN_STACK.reset(token)
-            s.end_s = self.clock()
-            self.spans.append(s)
-
-    def find(self, name: str) -> List[Span]:
-        return [s for s in self.spans if s.name == name]
-
-
-GLOBAL_TRACER = Tracer()
+from typing import Dict
 
 
 class AwaitRegistry:

@@ -5,7 +5,7 @@ import asyncio
 from risingwave_tpu.utils.metrics import (
     Counter, Gauge, Histogram, MetricsRegistry, STREAMING,
 )
-from risingwave_tpu.utils.trace import AwaitRegistry, Tracer
+from risingwave_tpu.utils.trace import AwaitRegistry
 
 
 def test_counter_gauge_histogram_render():
@@ -245,14 +245,8 @@ def test_actor_count_series_track_deploy_and_drop():
 
 
 def test_tracer_spans_and_await_registry():
-    t = Tracer()
-    with t.span("barrier", epoch=7):
-        with t.span("flush"):
-            pass
-    spans = t.find("flush")
-    assert len(spans) == 1 and spans[0].parent == "barrier"
-    assert t.find("barrier")[0].attrs == {"epoch": 7}
-
+    # (the span half went with utils/trace.Tracer, which nothing but
+    # this test built; spans are utils/spans.py's)
     a = AwaitRegistry()
     a.enter("actor-1", "barrier_align(left)")
     a.enter("actor-2", "state_table.commit")

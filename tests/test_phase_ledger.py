@@ -201,7 +201,11 @@ def test_seal_emits_phase_lanes_and_counter_tracks():
     EPOCH_TRACER.clear()
     spans_mod.set_enabled(True)
     spans_mod.set_current_epoch(33)
-    LEDGER.attribute("device_compute", 0.004, epoch=33)
+    t_before = time.time()
+    with LEDGER.phase("device_compute"):
+        time.sleep(0.004)
+    with LEDGER.phase("host_pack"):
+        pass                    # under SPAN_MIN_S: books only, no span
     LEDGER.add_bytes("h2d", 4096, kernel="unit-track")
     LEDGER.seal(33, 0.01, warmup=True)
     out = json.loads(json.dumps(EPOCH_TRACER.export_chrome(
@@ -212,10 +216,15 @@ def test_seal_emits_phase_lanes_and_counter_tracks():
     assert "uploader_queue_depth" in names
     [h2d] = [e for e in cs if e["name"] == "transfer_h2d_bytes"]
     assert h2d["args"]["value"] == 4096.0
-    # phase lanes ride as ordinary X spans under cat=phase
+    # a scoped phase rides as an ordinary X span under cat=phase, where
+    # and for as long as it ran (no lane stacked from the interval's
+    # start at the seal any more)
     xs = [e for e in out["traceEvents"]
           if e["ph"] == "X" and e["cat"] == "phase"]
-    assert any(e["name"] == "phase.device_compute" for e in xs)
+    [dc] = [e for e in xs if e["name"] == "phase.device_compute"]
+    assert dc["dur"] >= 4000.0
+    assert t_before * 1e6 <= dc["ts"] <= time.time() * 1e6
+    assert not any(e["name"] == "phase.host_pack" for e in xs)
     EPOCH_TRACER.clear()
 
 
