@@ -163,6 +163,19 @@ void rw_bloom_build(const uint8_t* items, const int32_t* lens,
     }
 }
 
+// Copy n slices blob[offs[i] .. offs[i] + lens[i]) into out, back to
+// back (the writer's bloom items: each distinct table ++ user key of a
+// block, out of the block's key blob). Returns the bytes written.
+long rw_gather(const uint8_t* blob, const int64_t* offs,
+               const int32_t* lens, long n, uint8_t* out) {
+    long pos = 0;
+    for (long i = 0; i < n; i++) {
+        memcpy(out + pos, blob + offs[i], (size_t)lens[i]);
+        pos += lens[i];
+    }
+    return pos;
+}
+
 // Bloom probe for one item (same hash family). Returns 0/1.
 int32_t rw_bloom_may_contain(const uint8_t* item, int32_t len,
                              const uint8_t* bits, long nbits,
@@ -174,6 +187,149 @@ int32_t rw_bloom_may_contain(const uint8_t* item, int32_t len,
         if (!((bits[bit >> 3] >> (7 - (bit & 7))) & 1)) return 0;
     }
     return 1;
+}
+
+// ---- compaction: columnar runs ------------------------------------
+//
+// A run is one decoded SST in four arrays: keys blob, key lengths,
+// values blob, value lengths (what rw_block_decode writes and
+// rw_block_encode reads). The Python twin of everything below is the
+// row-at-a-time loop in risingwave_tpu/storage/merge.py, which is the
+// specification: same survivors, same order, byte-identical SSTs.
+
+// Per entry of an ordered key column: the epoch (the inverted last 8
+// bytes of the full key) and whether its table ++ user key differs
+// from the entry before it (`prev` stands before entry 0; prev_len 0
+// = nothing does). Returns 0, or -1 if a key is shorter than 8 bytes.
+long rw_key_columns(const uint8_t* keys, const int32_t* key_lens,
+                    long n, const uint8_t* prev, long prev_len,
+                    uint64_t* epochs, uint8_t* new_user) {
+    const uint8_t* kp = keys;
+    for (long i = 0; i < n; i++) {
+        long kl = key_lens[i];
+        if (kl < 8) return -1;
+        uint64_t inv = 0;
+        for (int b = 0; b < 8; b++) inv = (inv << 8) | kp[kl - 8 + b];
+        epochs[i] = ~inv;
+        new_user[i] = !(prev_len == kl
+                        && memcmp(prev, kp, (size_t)(kl - 8)) == 0);
+        prev = kp;
+        prev_len = kl;
+        kp += kl;
+    }
+    return 0;
+}
+
+// k-way merge of runs in rank order (rank = position in the arrays,
+// lowest = newest layer) with the compaction GC rule, survivors
+// gathered into the four output arrays in output order.
+//
+//   order   bytewise full key, ties by rank
+//   rule    an equal full key in a later rank is dropped; every
+//           version above `safe` is kept; of the versions at or below
+//           `safe` only the newest per table ++ user key is kept, and
+//           that one is dropped too if it is a tombstone and `bottom`
+//
+// Run r is read from entry pos[r] (updated on return) up to counts[r],
+// and only while its key is bytewise below `bound` (bound_len 0 = no
+// bound). `bound` is a table ++ escaped user key: the escape is
+// prefix-free, so a full key compares with it as its user key does and
+// a window cut there never splits the versions of one key.
+// Returns the entries written and sets *entries_in to the entries
+// read; -1 if an output array is too small, -2 on a key shorter than
+// 8 bytes or an empty value.
+long rw_merge_gc(int32_t k,
+                 const uint8_t* const* keys, const int64_t* const* koff,
+                 const uint8_t* const* vals, const int64_t* const* voff,
+                 int64_t* pos, const int64_t* counts,
+                 const uint8_t* bound, long bound_len,
+                 uint64_t safe, int32_t bottom,
+                 uint8_t* out_keys, long out_keys_cap, int32_t* out_klens,
+                 uint8_t* out_vals, long out_vals_cap, int32_t* out_vlens,
+                 long out_max, int64_t* entries_in) {
+    struct Head { const uint8_t* key; long len; int32_t run; };
+    // heads in merge order, the smallest LAST: the run just advanced
+    // goes back in by one insertion step (k is a handful: the L0 runs
+    // and one L1 run)
+    Head* heads = new Head[k > 0 ? k : 1];
+    int32_t nheads = 0;
+    auto less = [](const Head& a, const Head& b) {
+        long m = a.len < b.len ? a.len : b.len;
+        int c = memcmp(a.key, b.key, (size_t)m);
+        if (c != 0) return c < 0;
+        if (a.len != b.len) return a.len < b.len;
+        return a.run < b.run;
+    };
+    auto load = [&](int32_t r, Head* h) {
+        if (pos[r] >= counts[r]) return false;
+        h->key = keys[r] + koff[r][pos[r]];
+        h->len = (long)(koff[r][pos[r] + 1] - koff[r][pos[r]]);
+        h->run = r;
+        if (bound_len > 0) {
+            long m = h->len < bound_len ? h->len : bound_len;
+            int c = memcmp(h->key, bound, (size_t)m);
+            if (c > 0 || (c == 0 && h->len >= bound_len)) return false;
+        }
+        return true;
+    };
+    auto insert = [&](const Head& h) {
+        int32_t i = nheads++;
+        while (i > 0 && less(heads[i - 1], h)) {
+            heads[i] = heads[i - 1];
+            i--;
+        }
+        heads[i] = h;
+    };
+    for (int32_t r = 0; r < k; r++) {
+        Head h;
+        if (load(r, &h)) insert(h);
+    }
+    long n_in = 0, n_out = 0, kpos = 0, vpos = 0, rc = 0;
+    const uint8_t* seen = nullptr;      // last distinct full key
+    long seen_len = 0;
+    bool kept_le_safe = false;
+    while (nheads > 0) {
+        Head h = heads[--nheads];
+        int32_t r = h.run;
+        int64_t i = pos[r]++;
+        Head next;
+        if (load(r, &next)) insert(next);
+        n_in++;
+        if (h.len < 8) { rc = -2; break; }
+        if (seen != nullptr && seen_len == h.len
+                && memcmp(seen, h.key, (size_t)h.len) == 0)
+            continue;                   // same key+epoch: newer layer wins
+        bool same_user = seen != nullptr && seen_len == h.len
+            && memcmp(seen, h.key, (size_t)(h.len - 8)) == 0;
+        seen = h.key;
+        seen_len = h.len;
+        if (!same_user) kept_le_safe = false;
+        const uint8_t* val = vals[r] + voff[r][i];
+        long vl = (long)(voff[r][i + 1] - voff[r][i]);
+        if (vl < 1) { rc = -2; break; }
+        uint64_t inv = 0;
+        for (int b = 0; b < 8; b++) inv = (inv << 8) | h.key[h.len - 8 + b];
+        if (~inv <= safe) {
+            if (kept_le_safe) continue; // older shadowed version: drop
+            kept_le_safe = true;
+            // newest <= safe is a delete: gone, but only at the bottom
+            // (levels below may still hold the key it deletes)
+            if (val[0] == 1 && bottom) continue;
+        }
+        if (n_out >= out_max || kpos + h.len > out_keys_cap
+                || vpos + vl > out_vals_cap) { rc = -1; break; }
+        memcpy(out_keys + kpos, h.key, (size_t)h.len);
+        out_klens[n_out] = (int32_t)h.len;
+        kpos += h.len;
+        memcpy(out_vals + vpos, val, (size_t)vl);
+        out_vals[vpos] = val[0] == 1;   // as SstBuilder.add writes it
+        out_vlens[n_out] = (int32_t)vl;
+        vpos += vl;
+        n_out++;
+    }
+    delete[] heads;
+    *entries_in = n_in;
+    return rc < 0 ? rc : n_out;
 }
 
 }  // extern "C"

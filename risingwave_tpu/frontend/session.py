@@ -418,7 +418,10 @@ class Frontend:
         (GlobalBarrierManager::run analog; serialized with DDL). A
         failure is loud: it propagates out of this task — the server
         entry point watches it and dies rather than serving a cluster
-        whose checkpoints silently stopped."""
+        whose checkpoints silently stopped. A cancelled heartbeat ends
+        cancelled, as the cluster's does: whoever owns the task can
+        tell the pause it asked for (``task.cancelled()``) from a
+        heartbeat that stopped by itself."""
         import sys
         import traceback
         try:
@@ -432,7 +435,7 @@ class Frontend:
                 # their durable (draining) semantics.
                 await self._barrier(drain_uploader=False)
         except asyncio.CancelledError:
-            pass
+            raise
         except BaseException:
             print("barrier heartbeat failed:", file=sys.stderr)
             traceback.print_exc()

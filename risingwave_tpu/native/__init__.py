@@ -2,11 +2,23 @@
 
 Compiles native/rw_native.cpp with g++ on first use (cached as a .so
 next to the source, untracked) and exposes ctypes wrappers. Every entry
-point has a byte-identical pure-Python twin in
-risingwave_tpu/storage/sst.py (tested): `lib()` returns None when the
-library cannot be had and callers take the twin. That is never silent:
-a build or load that was tried and failed prints one line to stderr
-with the compiler's (or loader's) message.
+point has a byte-identical pure-Python twin (tested): `lib()` returns
+None when the library cannot be had and callers take the twin. That is
+never silent: a build or load that was tried and failed prints one
+line to stderr with the compiler's (or loader's) message.
+
+  rw_block_encode, rw_block_decode,      twins in storage/sst.py
+  rw_bloom_build, rw_bloom_may_contain   (_BlockBuilder, _iter_block_py,
+                                         _BloomBuilder, bloom_may_contain)
+  rw_merge_gc, rw_key_columns,           the compaction merge over
+  rw_gather                              columnar runs (keys blob, key
+                                         lengths, values blob, value
+                                         lengths) and its run-at-a-time
+                                         writer; one twin for the three:
+                                         storage/merge._merge_python, the
+                                         row-at-a-time loop over
+                                         SstBuilder (byte-identical SSTs,
+                                         tests/test_compaction_merge.py)
 """
 
 from __future__ import annotations
@@ -34,10 +46,15 @@ def _say_python(why: str) -> None:
 
 
 def _compile() -> bool:
+    # link under a name of this process's own, then rename: processes
+    # that start together (pytest workers, a cluster's roles) each
+    # load a whole library, never one another's half-written file
+    tmp = f"{_SO}.{os.getpid()}.tmp"
     try:
         subprocess.run(
-            ["g++", "-O2", "-shared", "-fPIC", "-o", _SO, _SRC],
+            ["g++", "-O2", "-shared", "-fPIC", "-o", tmp, _SRC],
             check=True, capture_output=True, timeout=120)
+        os.replace(tmp, _SO)
         return True
     except subprocess.CalledProcessError as e:
         lines = e.stderr.decode(errors="replace").strip().splitlines()
@@ -69,27 +86,42 @@ def lib() -> Optional[ctypes.CDLL]:
         except OSError as e:
             _say_python(f"could not load {_SO}: {e}")
             return None
-        l.rw_block_encode.restype = ctypes.c_long
-        l.rw_block_encode.argtypes = [
-            ctypes.c_char_p, ctypes.POINTER(ctypes.c_int32),
-            ctypes.c_char_p, ctypes.POINTER(ctypes.c_int32),
-            ctypes.c_int32, ctypes.c_int32,
-            ctypes.c_char_p, ctypes.c_long]
-        l.rw_block_decode.restype = ctypes.c_long
-        l.rw_block_decode.argtypes = [
-            ctypes.c_char_p, ctypes.c_long,
-            ctypes.c_char_p, ctypes.c_long,
-            ctypes.POINTER(ctypes.c_int32),
-            ctypes.c_char_p, ctypes.c_long,
-            ctypes.POINTER(ctypes.c_int32), ctypes.c_long]
-        l.rw_bloom_build.restype = None
-        l.rw_bloom_build.argtypes = [
-            ctypes.c_char_p, ctypes.POINTER(ctypes.c_int32),
-            ctypes.c_int32, ctypes.c_int32,
-            ctypes.c_char_p, ctypes.c_long]
-        l.rw_bloom_may_contain.restype = ctypes.c_int32
-        l.rw_bloom_may_contain.argtypes = [
-            ctypes.c_char_p, ctypes.c_int32,
-            ctypes.c_char_p, ctypes.c_long, ctypes.c_int32]
+        try:
+            # pointer arguments are c_void_p: bytes, ctypes buffers and
+            # plain addresses (a numpy buffer at an offset: the compaction
+            # merge, storage/merge.py) all pass
+            vp = ctypes.c_void_p
+            l.rw_block_encode.restype = ctypes.c_long
+            l.rw_block_encode.argtypes = [
+                vp, vp, vp, vp, ctypes.c_int32, ctypes.c_int32,
+                vp, ctypes.c_long]
+            l.rw_block_decode.restype = ctypes.c_long
+            l.rw_block_decode.argtypes = [
+                vp, ctypes.c_long, vp, ctypes.c_long, vp,
+                vp, ctypes.c_long, vp, ctypes.c_long]
+            l.rw_bloom_build.restype = None
+            l.rw_bloom_build.argtypes = [
+                vp, vp, ctypes.c_int32, ctypes.c_int32, vp,
+                ctypes.c_long]
+            l.rw_bloom_may_contain.restype = ctypes.c_int32
+            l.rw_bloom_may_contain.argtypes = [
+                ctypes.c_char_p, ctypes.c_int32,
+                ctypes.c_char_p, ctypes.c_long, ctypes.c_int32]
+            l.rw_gather.restype = ctypes.c_long
+            l.rw_gather.argtypes = [vp, vp, vp, ctypes.c_long, vp]
+            l.rw_key_columns.restype = ctypes.c_long
+            l.rw_key_columns.argtypes = [
+                vp, vp, ctypes.c_long, vp, ctypes.c_long, vp, vp]
+            l.rw_merge_gc.restype = ctypes.c_long
+            l.rw_merge_gc.argtypes = [
+                ctypes.c_int32, vp, vp, vp, vp, vp, vp,
+                vp, ctypes.c_long, ctypes.c_uint64, ctypes.c_int32,
+                vp, ctypes.c_long, vp, vp, ctypes.c_long, vp,
+                ctypes.c_long, ctypes.POINTER(ctypes.c_int64)]
+        except AttributeError as e:
+            # a library built from an older source that the mtime
+            # check took for current
+            _say_python(f"{_SO} is stale ({e}): delete it to rebuild")
+            return None
         _lib = l
         return _lib

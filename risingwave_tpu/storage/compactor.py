@@ -13,25 +13,28 @@ while serving commits keep landing new L0 runs concurrently — the
 arxiv 1904.03800 concurrent-state stance: the merge reads an immutable
 snapshot, reconciliation is a single atomic swap.
 
-Merge semantics mirror ``HummockLite.compact`` exactly (the inline arm
-is the oracle): newest layer wins per (key, epoch); versions shadowed
-below the task's safe epoch drop; a tombstone that is the newest
-surviving version ≤ safe drops ONLY on bottom-level merges (``bottom``
-flag) — a non-bottom merge must keep it or data in lower levels would
-resurrect.
+The merge is ``storage/merge.merge_runs``, the same function the
+inline arm (``HummockLite.compact``) calls: newest layer wins per
+(key, epoch); versions shadowed below the task's safe epoch drop; a
+tombstone that is the newest surviving version ≤ safe drops ONLY on
+bottom-level merges (``bottom`` flag) — a non-bottom merge must keep
+it or data in lower levels would resurrect. Where the native library
+is loaded it merges whole columnar runs inside one call that holds no
+GIL, so a merge on the compactor thread leaves the serving loop
+running; its row-at-a-time Python twin would not. What is this
+module's own: reading the task, the reserved id block and its
+overflow error, the uploads, the span.
 """
 
 from __future__ import annotations
 
-import heapq
 import time
-from typing import List, Optional
+from typing import List
 
+from risingwave_tpu.storage.merge import merge_runs
 from risingwave_tpu.storage.object_store import ObjectStore
-from risingwave_tpu.storage.sst import Sst, SstBuilder, split_full_key
 from risingwave_tpu.utils import spans as _spans
 from risingwave_tpu.utils.failpoint import fail_point
-from risingwave_tpu.utils.metrics import STORAGE as _METRICS
 
 # default output cut size — re-declared (not imported from hummock) so
 # this module has no import cycle with the store it serves
@@ -66,7 +69,9 @@ def execute_task(obj: ObjectStore, task: dict) -> dict:
         read_bytes=result["bytes_read"],
         ssts_written=len(result["outputs"]),
         write_bytes=result["bytes_written"],
-        entries_dropped=result.pop("entries_dropped"))
+        entries_in=result.pop("entries_in"),
+        entries_dropped=result.pop("entries_dropped"),
+        merge=result.pop("merge"))
     return result
 
 
@@ -80,86 +85,29 @@ def _execute_task(obj: ObjectStore, task: dict) -> dict:
     cap = int(task.get("output_cap", 16))
     target = int(task.get("target_bytes", TARGET_SST_BYTES))
 
-    def source(info: dict, r: int):
-        # one-shot sequential scan: whole-bytes read, no cache churn
-        sst = Sst(obj.read(f"data/{info['id']}.sst"), info)
-        for fk, tomb, row in sst.iter_from(b""):
-            yield (fk, r, tomb, row)
-
-    # rank order mirrors HummockLite.compact: L0 newest first (newest
-    # is LAST in the level list), then the overlapping L1 runs
-    ranked = [source(info, r)
-              for r, info in enumerate(reversed(inputs_l0))]
-    ranked += [source(info, len(inputs_l0) + r)
-               for r, info in enumerate(inputs_l1)]
-    merged = heapq.merge(*ranked, key=lambda t: (t[0], t[1]))
-
-    outputs: List[dict] = []
     next_id = base
-    builder: Optional[SstBuilder] = None
-    bytes_written = 0
 
-    def flush() -> None:
-        nonlocal builder, bytes_written
-        if builder is None:
-            return
-        data, info = builder.finish()
-        obj.upload(f"data/{info['id']}.sst", data)
-        _METRICS.sst_upload_count.inc(source="compact")
-        _METRICS.sst_upload_bytes.inc(len(data), source="compact")
-        bytes_written += len(data)
-        outputs.append(info)
-        builder = None
+    def new_sst_id() -> int:
+        nonlocal next_id
+        if next_id >= base + cap:
+            raise RuntimeError(
+                f"compaction output overflow: reserved id block "
+                f"[{base}, {base + cap}) exhausted")
+        next_id += 1
+        return next_id - 1
 
-    def out(fk: bytes, tomb: bool, row: bytes) -> None:
-        nonlocal builder, next_id
-        # cut ONLY at user-key boundaries (see docstring)
-        if (builder is not None
-                and builder._off + builder.block.size() >= target
-                and builder.largest is not None
-                and builder.largest[:-8] != fk[:-8]):
-            flush()
-        if builder is None:
-            if next_id >= base + cap:
-                raise RuntimeError(
-                    f"compaction output overflow: reserved id block "
-                    f"[{base}, {base + cap}) exhausted")
-            builder = SstBuilder(next_id)
-            next_id += 1
-        builder.add(fk, tomb, row)
-
-    seen_fk: Optional[bytes] = None
-    last_tu: Optional[bytes] = None
-    kept_le_safe = False
-    entries_in = entries_out = 0
-    for fk, _r, tomb, row in merged:
-        entries_in += 1
-        if fk == seen_fk:
-            continue               # same key+epoch: newer layer wins
-        seen_fk = fk
-        tu = fk[:-8]
-        _t, _u, e = split_full_key(fk)
-        if tu != last_tu:
-            last_tu = tu
-            kept_le_safe = False
-        if e > safe:
-            entries_out += 1
-            out(fk, tomb, row)
-            continue
-        if kept_le_safe:
-            continue               # older shadowed version: drop
-        kept_le_safe = True
-        if tomb and bottom:
-            continue               # newest ≤ safe is a delete: gone
-        # non-bottom merges KEEP a ≤-safe tombstone: levels below the
-        # destination may still hold the key it deletes
-        entries_out += 1
-        out(fk, tomb, row)
-    flush()
+    # rank order as in HummockLite.compact: L0 newest first (newest is
+    # LAST in the level list), then the overlapping L1 runs
+    outputs, merged = merge_runs(
+        obj, inputs_l0[::-1], inputs_l1, safe_epoch=safe, bottom=bottom,
+        target_bytes=target, new_sst_id=new_sst_id)
     bytes_read = sum(i.get("size", 0) for i in inputs_l0 + inputs_l1)
     return {"outputs": outputs, "bytes_read": bytes_read,
-            "bytes_written": bytes_written,
-            "entries_dropped": entries_in - entries_out}
+            "bytes_written": sum(i["size"] for i in outputs),
+            "entries_in": merged["entries_in"],
+            "entries_dropped": (merged["entries_in"]
+                                - merged["entries_out"]),
+            "merge": merged["merge"]}
 
 
 class InProcessCompactor:

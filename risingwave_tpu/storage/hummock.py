@@ -16,6 +16,9 @@ Reference parity (semantics, not format):
 - compaction: when L0 grows past a threshold, a merge of L0 with the
   overlapping L1 runs rewrites key-disjoint L1 runs, dropping versions
   shadowed below the committed epoch (compactor/compactor_runner.rs).
+  The merge is ``storage/merge.merge_runs`` for both arms: whole
+  columnar runs through the native library, or its row-at-a-time
+  Python twin where the library cannot be had, byte-identical.
   Two arms, ``compaction_mode``:
     * ``"inline"`` (default): ``commit_ssts``/``commit_through`` call
       ``compact()`` synchronously — the single-process/test arm.
@@ -45,22 +48,16 @@ from risingwave_tpu.utils import spans as _spans
 from risingwave_tpu.utils.failpoint import fail_point
 from risingwave_tpu.utils.ledger import LEDGER as _LEDGER
 from risingwave_tpu.utils.metrics import STORAGE as _METRICS
+from risingwave_tpu.storage.merge import merge_runs
 from risingwave_tpu.storage.object_store import ObjectStore
 from risingwave_tpu.storage.sst import (
-    EPOCH_MASK, LazySst, Sst, SstBuilder, build_sst, full_key,
-    split_full_key,
+    EPOCH_MASK, LazySst, Sst, build_sst, full_key, split_full_key,
+    user_prefix,
 )
 from risingwave_tpu.storage.value_codec import decode_row, encode_row
 
 L0_COMPACT_THRESHOLD = 4
 L1_TARGET_SST_BYTES = 4 * 1024 * 1024
-
-
-def _user_prefix(hex_key: str) -> bytes:
-    """SST-info boundary (hex) → table+user-key prefix: strips the
-    8-byte inverted-epoch suffix, which would mis-order comparisons
-    (shared by the level picker and the L1 binary search)."""
-    return bytes.fromhex(hex_key)[:-8]
 
 
 class HummockLite(StateStore):
@@ -497,9 +494,9 @@ class HummockLite(StateStore):
                 f"{sorted(inset - have)} no longer current")
         keep = [i for i in self._l1 if i["id"] not in inset]
         merged = sorted(keep + [dict(i) for i in outputs],
-                        key=lambda i: _user_prefix(i["smallest"]))
+                        key=lambda i: user_prefix(i["smallest"]))
         for a, b in zip(merged, merged[1:]):
-            if _user_prefix(a["largest"]) >= _user_prefix(b["smallest"]):
+            if user_prefix(a["largest"]) >= user_prefix(b["smallest"]):
                 self._reservations.pop(inset, None)
                 raise ValueError(
                     f"version delta conflict: outputs overlap L1 run "
@@ -551,12 +548,6 @@ class HummockLite(StateStore):
         if s is None:
             s = entry["handle"] = Sst(entry["data"], entry["sst"])
         return s
-
-    def _sst_once(self, info: dict) -> Sst:
-        """Whole-bytes read for one-shot sequential scans (compaction
-        consumes every block exactly once — caching would only evict
-        the hot read path)."""
-        return Sst(self.obj.read(f"data/{info['id']}.sst"), info)
 
     # -- read path --------------------------------------------------------
     def get(self, table_id: int, key: bytes, epoch: int) -> Value:
@@ -617,7 +608,7 @@ class HummockLite(StateStore):
         lo, hi, ans = 0, len(self._l1) - 1, None
         while lo <= hi:
             mid = (lo + hi) // 2
-            if _user_prefix(self._l1[mid]["smallest"]) <= target:
+            if user_prefix(self._l1[mid]["smallest"]) <= target:
                 ans = mid
                 lo = mid + 1
             else:
@@ -625,7 +616,7 @@ class HummockLite(StateStore):
         if ans is None:
             return None
         # key beyond this run's largest user key ⇒ in no run (disjoint)
-        if _user_prefix(self._l1[ans]["largest"]) < target:
+        if user_prefix(self._l1[ans]["largest"]) < target:
             return None
         return ans
 
@@ -763,9 +754,16 @@ class HummockLite(StateStore):
         objects retire into the pin-gated vacuum (an in-flight scan
         that pinned an older version keeps them readable).
 
+        The merge itself is ``storage/merge.merge_runs``, shared with
+        the dedicated arm (``compactor.execute_task``): this method
+        picks the inputs, hands out ids from ``_next_sst_id``, swaps
+        the version and retires what it replaced.
+
         Synchronous on the caller's event loop: ledger phase
         ``compaction`` and the ``checkpoint.compact`` annotation.
-        Returns what it read, wrote and dropped with its wall-clock
+        Returns what it read, wrote and dropped, ``entries_in`` and
+        which merge ran (``merge``: ``"native"`` over columnar runs,
+        ``"python"`` the row-at-a-time twin), with its wall-clock
         start and duration (None where there was nothing to merge).
         """
         t0 = time.time()
@@ -782,13 +780,13 @@ class HummockLite(StateStore):
         # key range of the L0 files being absorbed (user-key compare:
         # the inverted-epoch suffix would mis-order full keys)
         if self._l0:
-            lo = min(_user_prefix(i["smallest"]) for i in self._l0)
-            hi = max(_user_prefix(i["largest"]) for i in self._l0)
+            lo = min(user_prefix(i["smallest"]) for i in self._l0)
+            hi = max(user_prefix(i["largest"]) for i in self._l0)
             overlap, keep_lo, keep_hi = [], [], []
             for info in self._l1:
-                if _user_prefix(info["largest"]) < lo:
+                if user_prefix(info["largest"]) < lo:
                     keep_lo.append(info)
-                elif _user_prefix(info["smallest"]) > hi:
+                elif user_prefix(info["smallest"]) > hi:
                     keep_hi.append(info)
                 else:
                     overlap.append(info)
@@ -799,75 +797,18 @@ class HummockLite(StateStore):
         if not olds:
             self._commit_version()
             return None
-        safe = self._committed_epoch
 
-        def source(info: dict, r: int):
-            for fk, tomb, row in self._sst_once(info).iter_from(b""):
-                yield (fk, r, tomb, row)
+        def new_sst_id() -> int:
+            self._next_sst_id += 1
+            return self._next_sst_id - 1
 
-        merged = heapq.merge(
-            *[source(info, r)
-              for r, info in enumerate(reversed(list(self._l0)))] +
-            [source(info, len(self._l0) + r)
-             for r, info in enumerate(overlap)],
-            key=lambda t: (t[0], t[1]))
-
-        new_infos: List[dict] = []
-        builder: Optional[SstBuilder] = None
-        last_tu: Optional[bytes] = None
-        kept_le_safe = False
-
-        def out(fk: bytes, tomb: bool, row: bytes) -> None:
-            nonlocal builder
-            # cut SSTs ONLY at user-key boundaries: all versions of one
-            # key must live in one run or _l1_candidate's disjoint-run
-            # binary search would find the wrong (stale) run
-            if (builder is not None
-                    and builder._off + builder.block.size()
-                    >= L1_TARGET_SST_BYTES
-                    and builder.largest is not None
-                    and builder.largest[:-8] != fk[:-8]):
-                data, info = builder.finish()
-                self.obj.upload(f"data/{info['id']}.sst", data)
-                _METRICS.sst_upload_count.inc(source="compact")
-                _METRICS.sst_upload_bytes.inc(len(data),
-                                              source="compact")
-                new_infos.append(info)
-                builder = None
-            if builder is None:
-                builder = SstBuilder(self._next_sst_id)
-                self._next_sst_id += 1
-            builder.add(fk, tomb, row)
-
-        seen_fk: Optional[bytes] = None
-        entries_in = entries_out = 0
-        for fk, _r, tomb, row in merged:
-            entries_in += 1
-            if fk == seen_fk:
-                continue               # same key+epoch: newer layer wins
-            seen_fk = fk
-            tu = fk[:-8]
-            _t, _u, e = split_full_key(fk)
-            if tu != last_tu:
-                last_tu = tu
-                kept_le_safe = False
-            if e > safe:
-                entries_out += 1
-                out(fk, tomb, row)
-                continue
-            if kept_le_safe:
-                continue               # older shadowed version: drop
-            kept_le_safe = True
-            if tomb:
-                continue               # newest ≤ safe is a delete: gone
-            entries_out += 1
-            out(fk, tomb, row)
-        if builder is not None:
-            data, info = builder.finish()
-            self.obj.upload(f"data/{info['id']}.sst", data)
-            _METRICS.sst_upload_count.inc(source="compact")
-            _METRICS.sst_upload_bytes.inc(len(data), source="compact")
-            new_infos.append(info)
+        # the one compaction merge (storage/merge.py): rank order is
+        # L0 newest first (newest is LAST in the level list), then the
+        # overlapping L1 runs
+        new_infos, merged = merge_runs(
+            self.obj, self._l0[::-1], overlap,
+            safe_epoch=self._committed_epoch, bottom=True,
+            target_bytes=L1_TARGET_SST_BYTES, new_sst_id=new_sst_id)
         self._l0 = []
         # splice: untouched runs below + rewritten range + above stays
         # key-disjoint and sorted (the picker chose by range)
@@ -886,7 +827,10 @@ class HummockLite(StateStore):
         return {"ssts_read": len(olds), "read_bytes": read_bytes,
                 "ssts_written": len(new_infos),
                 "write_bytes": write_bytes,
-                "entries_dropped": entries_in - entries_out}
+                "entries_in": merged["entries_in"],
+                "entries_dropped": (merged["entries_in"]
+                                    - merged["entries_out"]),
+                "merge": merged["merge"]}
 
     # -- test/debug helpers ----------------------------------------------
     def table_size(self, table_id: int, epoch: int) -> int:

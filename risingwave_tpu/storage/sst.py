@@ -18,13 +18,22 @@ hummock_sdk/src/key.rs:48-79 — same *semantics*, smaller format:
 
 Builders take entries pre-sorted (the LSM merge guarantees it);
 everything is write-once (object-store friendly).
+
+Two ways in and out of the format. Row at a time: ``SstBuilder.add``
+and ``iter_from`` (the checkpoint build, the read path, and the
+compaction merge's Python twin). A run at a time, where the native
+library is loaded: ``decode_run`` turns an SST into a columnar ``Run``
+(keys blob, key lengths, values blob, value lengths: the four arrays
+the native block codec reads and writes) and ``RunWriter`` cuts the
+same blocks and SSTs out of ordered ``Run``s that ``SstBuilder`` cuts
+out of the same rows, byte for byte, with no Python object per row.
 """
 
 from __future__ import annotations
 
 import struct
 import zlib
-from typing import Iterator, List, Optional, Tuple
+from typing import Callable, Iterator, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -67,6 +76,14 @@ def split_full_key(fk: bytes) -> Tuple[int, bytes, int]:
     return table_id, _unesc_user(fk[4:-8]), epoch
 
 
+def user_prefix(hex_key: str) -> bytes:
+    """SST-info boundary (hex) → table+user-key prefix: strips the
+    8-byte inverted-epoch suffix, which would mis-order comparisons
+    (shared by the level picker, the L1 binary search and the
+    compaction merge's windows)."""
+    return bytes.fromhex(hex_key)[:-8]
+
+
 def _bloom_hashes(data: bytes) -> Tuple[int, int]:
     h1 = zlib.crc32(data) & 0xFFFFFFFF
     h2 = zlib.crc32(data, 0x9E3779B9) & 0xFFFFFFFF
@@ -86,20 +103,25 @@ class _BloomBuilder:
         nbits = (nbits + 7) // 8 * 8
         nat = _native.lib()
         if nat is not None and self.items:
-            import ctypes
-            blob = b"".join(self.items)
-            lens = (ctypes.c_int32 * len(self.items))(
-                *[len(i) for i in self.items])
-            bits = ctypes.create_string_buffer(nbits // 8)
-            nat.rw_bloom_build(blob, lens, len(self.items), BLOOM_K,
-                               bits, nbits)
-            return bits.raw
+            return _bloom_native(
+                nat, np.frombuffer(b"".join(self.items), dtype=np.uint8),
+                np.array([len(i) for i in self.items], dtype=np.int32))
         bits = np.zeros(nbits, dtype=bool)
         for item in self.items:
             h1, h2 = _bloom_hashes(item)
             for i in range(BLOOM_K):
                 bits[(h1 + i * h2) % nbits] = True
         return np.packbits(bits).tobytes()
+
+
+def _bloom_native(nat, blob: np.ndarray, lens: np.ndarray) -> bytes:
+    """The filter over ``len(lens)`` (>= 1) items laid back to back
+    in the uint8 array ``blob``."""
+    nbits = (max(64, len(lens) * BLOOM_BITS_PER_KEY) + 7) // 8 * 8
+    bits = np.zeros(nbits // 8, dtype=np.uint8)
+    nat.rw_bloom_build(blob.ctypes.data, lens.ctypes.data, len(lens),
+                       BLOOM_K, bits.ctypes.data, nbits)
+    return bits.tobytes()
 
 
 def bloom_may_contain(filter_bytes: bytes, data: bytes) -> bool:
@@ -285,36 +307,50 @@ class SstBuilder:
 
     def finish(self) -> Tuple[bytes, dict]:
         self._flush_block()
-        out = bytearray()
-        for b in self.blocks:
-            out.extend(b)
-        bloom = self.bloom.finish() if self.count else b""
-        meta = bytearray()
-        write_uvarint(meta, len(self.index))
-        for first, off, ln in self.index:
-            write_uvarint(meta, len(first))
-            meta.extend(first)
-            write_uvarint(meta, off)
-            write_uvarint(meta, ln)
-        write_uvarint(meta, len(bloom))
-        meta.extend(bloom)
-        meta_off = len(out)
-        out.extend(meta)
-        out.extend(struct.pack(">Q", meta_off))
-        out.extend(MAGIC)
-        info = {
-            "id": self.sst_id,
-            "smallest": (self.smallest or b"").hex(),
-            "largest": (self.largest or b"").hex(),
-            "count": self.count,
-            # tombstone density feeds the reclaim picker; older
-            # manifests lack the field — readers .get(, 0)
-            "tombstones": self.tombstones,
-            "min_epoch": self.min_epoch if self.count else 0,
-            "max_epoch": self.max_epoch,
-            "size": len(out),
-        }
-        return bytes(out), info
+        return _seal_sst(
+            self.sst_id, self.blocks, self.index,
+            self.bloom.finish() if self.count else b"",
+            self.smallest or b"", self.largest or b"", self.count,
+            self.tombstones, self.min_epoch if self.count else 0,
+            self.max_epoch)
+
+
+def _seal_sst(sst_id: int, blocks: List[bytes],
+              index: List[Tuple[bytes, int, int]], bloom: bytes,
+              smallest: bytes, largest: bytes, count: int,
+              tombstones: int, min_epoch: int, max_epoch: int
+              ) -> Tuple[bytes, dict]:
+    """Encoded blocks + what was gathered about them → one SST's
+    (bytes, info): blocks, meta (index, filter), footer."""
+    out = bytearray()
+    for b in blocks:
+        out.extend(b)
+    meta = bytearray()
+    write_uvarint(meta, len(index))
+    for first, off, ln in index:
+        write_uvarint(meta, len(first))
+        meta.extend(first)
+        write_uvarint(meta, off)
+        write_uvarint(meta, ln)
+    write_uvarint(meta, len(bloom))
+    meta.extend(bloom)
+    meta_off = len(out)
+    out.extend(meta)
+    out.extend(struct.pack(">Q", meta_off))
+    out.extend(MAGIC)
+    info = {
+        "id": sst_id,
+        "smallest": smallest.hex(),
+        "largest": largest.hex(),
+        "count": count,
+        # tombstone density feeds the reclaim picker; older
+        # manifests lack the field — readers .get(, 0)
+        "tombstones": tombstones,
+        "min_epoch": min_epoch,
+        "max_epoch": max_epoch,
+        "size": len(out),
+    }
+    return bytes(out), info
 
 
 def _parse_meta(buf: bytes, pos: int
@@ -454,3 +490,229 @@ class LazySst(_SstOps):
         return self.cache.get_or_load(
             (sst_id, i),
             lambda: self.obj.read_range(self.path, off, ln))
+
+
+# -- a run at a time (native library only) ----------------------------------
+
+
+class Run(NamedTuple):
+    """Sorted entries in columnar form: keys and values laid back to
+    back in two uint8 blobs, with their int32 lengths. A value is the
+    stored one (tombstone flag byte, then the row bytes)."""
+
+    keys: np.ndarray
+    key_lens: np.ndarray
+    vals: np.ndarray
+    val_lens: np.ndarray
+
+
+def offsets(lens: np.ndarray) -> np.ndarray:
+    """Lengths → the n + 1 int64 offsets of the items in their blob."""
+    off = np.zeros(len(lens) + 1, dtype=np.int64)
+    np.cumsum(lens, out=off[1:])
+    return off
+
+
+def concat_runs(runs: List[Run]) -> Run:
+    return Run(*(np.concatenate(col) for col in zip(*runs)))
+
+
+def decode_run(nat, data: bytes) -> Run:
+    """Every block of one SST through ``rw_block_decode``, straight
+    into the four arrays. A block the native decoder refuses (a key
+    over its 4 KiB window) goes through the Python decoder, as in
+    ``iter_block``."""
+    index = Sst(data).index
+    if not index:
+        empty = np.empty(0, dtype=np.uint8)
+        return Run(empty, np.empty(0, dtype=np.int32), empty,
+                   np.empty(0, dtype=np.int32))
+    longest = max(ln for _first, _off, ln in index)
+    # the caps iter_block uses: prefix compression rarely expands 4x,
+    # and an entry takes at least one byte
+    cap = longest * 4 + 65536
+    keys = np.empty(cap, dtype=np.uint8)
+    vals = np.empty(cap, dtype=np.uint8)
+    key_lens = np.empty(longest, dtype=np.int32)
+    val_lens = np.empty(longest, dtype=np.int32)
+    base = np.frombuffer(data, dtype=np.uint8).ctypes.data
+    addr = (keys.ctypes.data, key_lens.ctypes.data, vals.ctypes.data,
+            val_lens.ctypes.data)
+    blocks: List[Run] = []
+    for _first, off, ln in index:
+        n = nat.rw_block_decode(base + off, ln, addr[0], cap, addr[1],
+                                addr[2], cap, addr[3], longest)
+        if n < 0:
+            pairs = list(_iter_block_py(data[off:off + ln]))
+            blocks.append(Run(
+                np.frombuffer(b"".join(k for k, _v in pairs),
+                              dtype=np.uint8),
+                np.array([len(k) for k, _v in pairs], dtype=np.int32),
+                np.frombuffer(b"".join(v for _k, v in pairs),
+                              dtype=np.uint8),
+                np.array([len(v) for _k, v in pairs], dtype=np.int32)))
+            continue
+        kl, vl = key_lens[:n].copy(), val_lens[:n].copy()
+        blocks.append(Run(keys[:int(kl.sum())].copy(), kl,
+                          vals[:int(vl.sum())].copy(), vl))
+    return concat_runs(blocks)
+
+
+class _Columns(NamedTuple):
+    """One fed run with what ``RunWriter`` derives from it per entry."""
+
+    run: Run
+    koff: np.ndarray           # key offsets, n + 1
+    voff: np.ndarray           # value offsets, n + 1
+    epochs: np.ndarray         # uint64
+    tombs: np.ndarray          # bool
+    est: np.ndarray            # running size estimate, n + 1
+    user_starts: np.ndarray    # entries that open a table ++ user key
+
+
+class RunWriter:
+    """SSTs out of ordered ``Run``s, a block at a time: what feeding
+    the same entries to ``SstBuilder.add`` one by one builds, byte for
+    byte (same block cuts by the same size estimate, same filter, same
+    info), with no Python object per entry.
+
+    An SST is cut as the compaction merge cuts it: before the first
+    entry of a new ``table ++ user key`` once the blocks written plus
+    the open block's estimate reach ``target_bytes`` (all versions of
+    one key stay in one SST). Each finished SST goes to ``emit(data,
+    info)`` as soon as it is cut, under the id ``new_sst_id()`` gave
+    when its first block was written. ``feed`` keeps the entries of
+    the block still open, so memory is one block beyond the run fed.
+    """
+
+    def __init__(self, nat, target_bytes: int,
+                 new_sst_id: Callable[[], int],
+                 emit: Callable[[bytes, dict], None]) -> None:
+        self.nat = nat
+        self.target = target_bytes
+        self.new_sst_id = new_sst_id
+        self.emit = emit
+        self.entries = 0
+        self._open: Optional[Run] = None   # entries of the open block
+        self._last_key = b""               # last key in a written block
+        self._out = np.empty(BLOCK_TARGET * 2, dtype=np.uint8)
+        self._reset()
+
+    def _reset(self) -> None:
+        self._sst_id: Optional[int] = None
+        self._blocks: List[bytes] = []
+        self._index: List[Tuple[bytes, int, int]] = []
+        self._items: List[np.ndarray] = []       # filter items, blobs
+        self._item_lens: List[np.ndarray] = []
+        self._smallest = b""
+        self._off = 0
+        self._count = 0
+        self._tombstones = 0
+        self._min_epoch = EPOCH_MASK
+        self._max_epoch = 0
+
+    def feed(self, run: Run, last: bool = False) -> None:
+        """Write every block (and cut every SST) that ``run``, after
+        the entries still open, completes; ``last`` closes the open
+        block and the open SST too."""
+        if self._open is not None:
+            run = concat_runs([self._open, run])
+            self._open = None
+        keys, key_lens, vals, val_lens = run
+        n = len(key_lens)
+        if n:
+            koff, voff = offsets(key_lens), offsets(val_lens)
+            epochs = np.empty(n, dtype=np.uint64)
+            new_user = np.empty(n, dtype=np.uint8)
+            if self.nat.rw_key_columns(
+                    keys.ctypes.data, key_lens.ctypes.data, n,
+                    self._last_key, len(self._last_key),
+                    epochs.ctypes.data, new_user.ctypes.data) < 0:
+                raise ValueError("full key shorter than its epoch suffix")
+            cols = _Columns(
+                run, koff, voff, epochs, vals[voff[:-1]] == 1,
+                # SstBuilder's size estimate, running
+                offsets(key_lens.astype(np.int64) + val_lens + 6),
+                np.flatnonzero(new_user))
+            s = self._write_blocks(cols, n, last)
+            if s < n:
+                self._open = Run(keys[koff[s]:].copy(), key_lens[s:].copy(),
+                                 vals[voff[s]:].copy(), val_lens[s:].copy())
+        if last and self._count:
+            self._finish_sst()
+
+    def _write_blocks(self, c: _Columns, n: int, last: bool) -> int:
+        """Returns the first entry left in the open block."""
+        s = 0
+        while s < n:
+            # the block closes after the entry that takes its estimate
+            # to BLOCK_TARGET: entries [s, x)
+            x = int(np.searchsorted(c.est, c.est[s] + BLOCK_TARGET))
+            if x > n:
+                if not last:
+                    break
+                x = n
+            # an SST cut falls before the first new user key j at which
+            # written blocks + open estimate reach the target
+            j = max(int(np.searchsorted(
+                c.est, c.est[s] + self.target - self._off)),
+                s if self._count else s + 1)
+            p = int(np.searchsorted(c.user_starts, j))
+            if p < len(c.user_starts) and c.user_starts[p] < x:
+                j = int(c.user_starts[p])
+                if j > s:
+                    self._write_block(c, s, j)
+                self._finish_sst()
+                s = j
+                continue
+            self._write_block(c, s, x)
+            s = x
+        return s
+
+    def _write_block(self, c: _Columns, s: int, e: int) -> None:
+        keys, key_lens, vals, val_lens = c.run
+        m = e - s
+        cap = int(c.est[e] - c.est[s]) + 30 * m
+        if cap > len(self._out):
+            self._out = np.empty(cap, dtype=np.uint8)
+        k0, v0 = int(c.koff[s]), int(c.voff[s])
+        size = self.nat.rw_block_encode(
+            keys.ctypes.data + k0, key_lens.ctypes.data + 4 * s,
+            vals.ctypes.data + v0, val_lens.ctypes.data + 4 * s,
+            m, RESTART_INTERVAL, self._out.ctypes.data, cap)
+        assert size >= 0, "block encode overran its own estimate"
+        first = keys[k0:int(c.koff[s + 1])].tobytes()
+        if self._sst_id is None:
+            self._sst_id = self.new_sst_id()
+            self._smallest = first
+        self._index.append((first, self._off, size))
+        self._blocks.append(self._out[:size].tobytes())
+        self._off += size
+        self._last_key = keys[int(c.koff[e - 1]):int(c.koff[e])].tobytes()
+        self._count += m
+        self._tombstones += int(np.count_nonzero(c.tombs[s:e]))
+        self._min_epoch = min(self._min_epoch, int(c.epochs[s:e].min()))
+        self._max_epoch = max(self._max_epoch, int(c.epochs[s:e].max()))
+        # filter items: each distinct table ++ user key of the block
+        a, b = np.searchsorted(c.user_starts, (s, e))
+        if b > a:
+            idx = c.user_starts[a:b]
+            offs = c.koff[idx]
+            lens = key_lens[idx] - 8
+            items = np.empty(int(lens.sum()), dtype=np.uint8)
+            self.nat.rw_gather(keys.ctypes.data, offs.ctypes.data,
+                               lens.ctypes.data, len(idx),
+                               items.ctypes.data)
+            self._items.append(items)
+            self._item_lens.append(lens)
+
+    def _finish_sst(self) -> None:
+        data, info = _seal_sst(
+            self._sst_id, self._blocks, self._index,
+            _bloom_native(self.nat, np.concatenate(self._items),
+                          np.concatenate(self._item_lens)),
+            self._smallest, self._last_key, self._count,
+            self._tombstones, self._min_epoch, self._max_epoch)
+        self.entries += self._count
+        self._reset()
+        self.emit(data, info)

@@ -51,7 +51,8 @@ happens into stages that add up to it, handed to ``on_commit`` as
   ``checkpoint.compact``     the inline ``compact()`` this commit
                              triggered, else absent; ssts_read,
                              read_bytes, ssts_written, write_bytes,
-                             entries_dropped
+                             entries_in, entries_dropped, merge
+                             (native | python: storage/merge.py)
 
 The barrier plane (``meta/barrier.record_checkpoint_tail``) turns them
 into spans under the sealing barrier's ``checkpoint.upload`` and into

@@ -555,6 +555,11 @@ class StorageMetrics:
             "compaction_bytes_written",
             "bytes of SST output written by compaction merges, by arm "
             "(inline/dedicated); written/ingested = write amplification")
+        self.compaction_merge_entries = r.counter(
+            "compaction_merge_entries",
+            "SST entries read by compaction merges, by path "
+            "(native: whole columnar runs inside the native library; "
+            "python: the row-at-a-time twin, storage/merge.py)")
         self.compaction_pending_tasks = r.gauge(
             "compaction_pending_tasks",
             "compaction tasks currently pending or running in the "

@@ -152,6 +152,77 @@ def test_dedicated_compactor_leaves_the_same_span_off_the_loop():
     assert ledger_mod.stolen_s() == stolen
 
 
+def _merge_entries():
+    from risingwave_tpu.utils.metrics import STORAGE
+    return {path: STORAGE.compaction_merge_entries.get(path=path)
+            for path in ("native", "python")}
+
+
+def test_compact_says_which_merge_ran_and_what_it_read():
+    """ISSUE 26: `compact()`'s counts and the `checkpoint.compact` span
+    carry `merge` (which path: storage/merge.py) and `entries_in`, and
+    `compaction_merge_entries{path}` adds up to the spans' entries."""
+    from risingwave_tpu import native
+
+    path = "native" if native.lib() is not None else "python"
+    spans_mod.EPOCH_TRACER.clear()
+    before = _merge_entries()
+
+    async def run():
+        fe = _frontend()
+        await fe.execute(BID_SOURCE)
+        await fe.execute(MV)
+        await fe.step(9)
+        direct = fe.store.compact()             # a manual pass on top
+        trace = spans_mod.EPOCH_TRACER.rows()
+        await fe.close()
+        return direct, trace
+
+    direct, trace = asyncio.run(run())
+    details = [json.loads(r[10]) for r in trace
+               if r[3] == "checkpoint.compact"]
+    assert len(details) >= 2
+    for d in details:
+        assert d["merge"] == path and d["mode"] == "inline"
+        assert d["entries_in"] >= d["entries_dropped"] >= 0
+        assert d["entries_in"] > 0
+    assert direct["merge"] == path and direct["entries_in"] > 0
+    after = _merge_entries()
+    other = "python" if path == "native" else "native"
+    assert after[other] == before[other]
+    assert after[path] - before[path] \
+        == sum(d["entries_in"] for d in details) + direct["entries_in"]
+
+
+def test_dedicated_span_carries_merge_and_entries_in():
+    from risingwave_tpu import native
+    from risingwave_tpu.storage.compactor import execute_task
+    from risingwave_tpu.storage.hummock import HummockLite
+    from risingwave_tpu.storage.object_store import MemObjectStore
+
+    obj = MemObjectStore()
+    h = HummockLite(obj)
+    h.compaction_mode = "dedicated"
+    for e in range(1, 5):
+        h.ingest_batch(1, [(b"k%d" % i, (i, e)) for i in range(20)], e)
+        h.seal_epoch(e)
+        h.sync(e)
+    snap = h.level_snapshot()
+    grant = h.reserve_task([i["id"] for i in snap["l0"]], 4)
+    spans_mod.EPOCH_TRACER.clear()
+    before = _merge_entries()
+    result = execute_task(obj, {"inputs_l0": snap["l0"], "inputs_l1": [],
+                                "safe_epoch": h.committed_epoch(),
+                                "bottom": True, **grant})
+    assert "merge" not in result and "entries_in" not in result
+    [span] = [r for r in spans_mod.EPOCH_TRACER.rows()
+              if r[3] == "checkpoint.compact"]
+    detail = json.loads(span[10])
+    path = "native" if native.lib() is not None else "python"
+    assert detail["merge"] == path and detail["entries_in"] == 80
+    assert _merge_entries()[path] - before[path] == 80
+
+
 # -- 2. stolen loop time -----------------------------------------------------
 
 
@@ -385,6 +456,27 @@ def test_instrumented_jit_names_the_program_after_its_label(label, module):
     text = jitted._jit.lower(jnp.ones(4)).as_text()
     assert f"module @{module} " in text.splitlines()[0]
     assert float(jitted(jnp.ones(4))[0]) == 2.0
+
+
+def test_a_cancelled_heartbeat_ends_cancelled():
+    """The benchmark pauses the heartbeat by cancelling it under the
+    barrier lock and takes a task that is done but NOT cancelled for a
+    heartbeat that died (`benchmark/run.py` `Heartbeat.check`). Its
+    traced run keeps checking for the 12 s it profiles, so once q7's
+    window closes before that span does (PR 26: 10 s), a heartbeat
+    that swallowed its cancellation failed every traced run."""
+    async def run():
+        fe = _frontend()
+        hb = asyncio.ensure_future(fe.run_heartbeat(0.01))
+        await asyncio.sleep(0.05)
+        async with fe._barrier_lock:
+            hb.cancel()
+        await asyncio.gather(hb, return_exceptions=True)
+        await fe.close()
+        return hb
+
+    hb = asyncio.run(run())
+    assert hb.done() and hb.cancelled()
 
 
 # -- the benchmark's readers -------------------------------------------------
