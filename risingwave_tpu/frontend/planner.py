@@ -1619,6 +1619,20 @@ def _system_catalog_rows(name: str, catalog: Catalog, profiler=None):
                       Field("rows", DataType.INT64),
                       Field("bytes", DataType.INT64)])
         return sch, TOPOLOGY.rows()
+    if n == "rw_mesh_tables":
+        # per-shard occupancy and capacity of the sharded kernels'
+        # device tables (parallel/exchange.py): beside
+        # rw_state_topology, whose table_id it shares — a mesh plan's
+        # growth rungs are per shard, and skew shows here first
+        from risingwave_tpu.parallel.exchange import mesh_table_rows
+        sch = Schema([Field("table_id", DataType.INT64),
+                      Field("mv", DataType.VARCHAR),
+                      Field("kernel", DataType.VARCHAR),
+                      Field("part", DataType.VARCHAR),
+                      Field("shard", DataType.INT64),
+                      Field("occupied", DataType.INT64),
+                      Field("capacity", DataType.INT64)])
+        return sch, mesh_table_rows()
     if n == "rw_plan_rewrites":
         # plan-rewrite firing log (frontend/opt engine): one row per
         # (job, rule) application, FALLBACK rows record checker trips

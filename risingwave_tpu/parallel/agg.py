@@ -36,8 +36,9 @@ from risingwave_tpu.ops.hash_agg import (
     n_input_lanes, pack_chunk, packed_layout, retire_state,
 )
 from risingwave_tpu.parallel.exchange import (
-    bucketize_by_owner, exchange, owners_host, skew_bucket,
-    vnodes_from_lanes,
+    MESH_KERNELS, bucketize_by_owner, exchange, note_launch,
+    note_routed, owners_host, received_by_shard, route_label,
+    route_phase, skew_bucket, vnodes_from_lanes,
 )
 from risingwave_tpu.utils import jaxtools, spans
 from risingwave_tpu.utils.ledger import LEDGER
@@ -80,31 +81,42 @@ class _ShardedCounters:
 
     def __init__(self, n_dev: int):
         self._count = np.zeros(n_dev, dtype=np.int64)
-        self._pending: List[tuple] = []   # (ins[n_dev], overflow, rows)
+        # ((ins[n_dev], overflow[, received[n_dev]]), rows)
+        self._pending: List[tuple] = []
         self._rows = 0
 
-    def push(self, ins, overflow, n_rows: int) -> None:
-        jaxtools.start_fetch(ins, overflow)
-        self._pending.append((ins, overflow, n_rows))
+    def push(self, ins, overflow, n_rows: int, received=None) -> None:
+        """``received`` is the step's per-shard count of routed rows,
+        where only the device knows it (a fused prelude filters and
+        keys the rows in-trace): it lands with the insert counts and
+        goes to the exchange's books then."""
+        arrays = (ins, overflow) if received is None \
+            else (ins, overflow, received)
+        jaxtools.start_fetch(*arrays)
+        self._pending.append((arrays, n_rows))
         self._rows += n_rows
 
-    def _fold(self, ins, overflow, n_rows: int) -> None:
+    def _fold(self, arrays, n_rows: int) -> None:
+        ins, overflow = arrays[:2]
         if bool(np.asarray(overflow).any()):
             raise RuntimeError(
                 "bucket overflow: routed rows dropped — raise `bucket`")
         self._count += np.asarray(ins, dtype=np.int64)
         self._rows -= n_rows
+        if len(arrays) > 2:
+            got = np.asarray(arrays[2], dtype=np.int64)
+            note_routed(int(got.sum()), got)
 
     def drain_ready(self) -> None:
-        while self._pending and self._pending[0][0].is_ready() \
-                and self._pending[0][1].is_ready():
+        while self._pending and all(
+                a.is_ready() for a in self._pending[0][0]):
             self._fold(*self._pending.pop(0))
 
     def drain_all(self) -> None:
         pending, self._pending = self._pending, []
-        for entry in pending:
-            jaxtools.fetch(entry[0], entry[1])
-            self._fold(*entry)
+        for arrays, n_rows in pending:
+            jaxtools.fetch(*arrays)
+            self._fold(arrays, n_rows)
 
     def bound(self) -> int:
         """Upper bound on the FULLEST shard's occupancy: every pending
@@ -187,6 +199,11 @@ class ShardedAggKernel:
         self.metrics_label: Optional[str] = None
         self._span_label = "ShardedAggKernel"
         self._touched = False
+        # the state table this kernel's groups persist to (the
+        # executor sets it): names the kernel in the exchange's books
+        # and in rw_mesh_tables
+        self.table_id: Optional[int] = None
+        MESH_KERNELS.add(self)
         # vnode → owning shard: contiguous even split (VnodeMapping)
         owners = np.repeat(np.arange(self.n_dev, dtype=np.int32),
                            VNODE_COUNT // self.n_dev)
@@ -216,6 +233,25 @@ class ShardedAggKernel:
         return (tuple(int(d.id) for d in self.mesh.devices.flat),
                 self.key_width, self.specs) + parts
 
+    def _prog_label(self, stem: str, *variant) -> str:
+        """A program's label: `stem[signature]`, the signature being
+        what `_prog_key` shares a compiled program by (key width, agg
+        specs, and a fused prelude's key). `jaxtools.program_name`
+        turns it into `jit_<stem>_<8 hex>`, so two aggregates of one
+        view go by different names in the device trace."""
+        return f"{stem}[{(self.key_width, self.specs) + variant!r}]"
+
+    @property
+    def route_label(self) -> str:
+        return route_label("sharded_agg", self.table_id)
+
+    def shard_tables(self) -> List[tuple]:
+        """[(part, occupied per shard, capacity per shard)] of the
+        device tables (rw_mesh_tables); one blocking read."""
+        occ = np.asarray(jnp.sum(self.state.table.occ, axis=1,
+                                 dtype=jnp.int32))
+        return [("groups", occ, self.capacity)]
+
     def _shardwise(self, fn, donate: bool, out_spec=None,
                    extra_specs=(), cache_key=None):
         """Wrap a single-chip traced state transform in shard_map: each
@@ -242,7 +278,7 @@ class ShardedAggKernel:
             else self._state_spec,
             check_vma=False)
         step = jaxtools.instrumented_jit(
-            mapped, "parallel_agg.sharded",
+            mapped, self._prog_label("parallel_agg.sharded"),
             donate_argnums=(0,) if donate else ())
         if key is not None:
             _PROG_CACHE[key] = step
@@ -332,7 +368,8 @@ class ShardedAggKernel:
             out_specs=(state_spec, P(AXIS), P(AXIS)),
             check_vma=False)
         return jaxtools.instrumented_jit(
-            mapped, "parallel_agg.step", donate_argnums=(0,))
+            mapped, self._prog_label("parallel_agg.step"),
+            donate_argnums=(0,))
 
     def _build_raw_step(self, bucket: int):
         """The prelude (fused) twin: raw int64 rows → the absorbed
@@ -384,17 +421,23 @@ class ShardedAggKernel:
                            state.emitted_valid, state.emitted_rows,
                            state.emitted_accs)
             new = jax.tree.map(lambda a: a[None], new)
+            # rows this shard received: the prelude filters and keys
+            # the rows in-trace, so the host cannot count them
+            received = jnp.sum(rvis, dtype=jnp.int32)
             return (new, ins[None], overflow[None],
-                    stage_rows[None])
+                    stage_rows[None], received[None])
 
         state_spec = jax.tree.map(lambda _: P(AXIS), self.state)
         mapped = jax.shard_map(
             local_step, mesh=self.mesh,
             in_specs=(state_spec, P(AXIS), P()),
-            out_specs=(state_spec, P(AXIS), P(AXIS), P(AXIS)),
+            out_specs=(state_spec, P(AXIS), P(AXIS), P(AXIS),
+                       P(AXIS)),
             check_vma=False)
         return jaxtools.instrumented_jit(
-            mapped, "parallel_agg.step_fused", donate_argnums=(0,))
+            mapped, self._prog_label("parallel_agg.step_fused",
+                                     self._prelude_key),
+            donate_argnums=(0,))
 
     def apply(self, key_lanes: np.ndarray, signs: np.ndarray,
               vis: np.ndarray,
@@ -438,7 +481,8 @@ class ShardedAggKernel:
         per-row owners back for the skew-exact bucket on the fused
         path, where the trace alone holds the derived lanes) — the
         shared exchange helper, one copy with the join kernel."""
-        return owners_host(key_lanes, self._owner_map_host)
+        with route_phase(self._span_label):
+            return owners_host(key_lanes, self._owner_map_host)
 
     def apply_raw(self, raw: np.ndarray, n_visible: int,
                   owners: Optional[np.ndarray] = None) -> None:
@@ -493,8 +537,9 @@ class ShardedAggKernel:
         # because _update_call slices the batch and carry-normalizes
         # per slab (the single-chip 32K backlog rides the same path)
         self._reserve(n)
-        # epoch staging + routing-bucket computation is host_pack (the
-        # ledger's phase classes); the sharded upload below is h2d
+        # epoch staging is host_pack (the ledger's phase classes); the
+        # routing in front of the exchange is exchange_route; the
+        # sharded upload below is h2d
         with LEDGER.phase("host_pack", kernel=self._span_label):
             # pow2-bucketed batch shape (the join epoch path's
             # convention): steady-state epochs repeat a handful of
@@ -510,27 +555,38 @@ class ShardedAggKernel:
             for m_ in mats:
                 packed[at:at + m_.shape[0]] = m_
                 at += m_.shape[0]
-            local = cap_rows // self.n_dev
-            bucket = self.bucket or local
-            if raw_mode and self.bucket is None and owner_chunks and \
+        local = cap_rows // self.n_dev
+        bucket = self.bucket or local
+        # rows each shard will receive, where the host can tell: the
+        # packed path always can; the fused raw path only where the
+        # executor fed the owners back (else its lanes only exist
+        # in-trace and the step counts what it received)
+        received = None
+        with route_phase(self._span_label):
+            owner = None
+            if not raw_mode:
+                kw_ = self.key_width
+                routed = packed[:, kw_ + 1] != 0
+                owner = owners_host(packed[:, :kw_],
+                                    self._owner_map_host)
+            elif owner_chunks and \
                     all(o is not None for o in owner_chunks):
-                ow = np.full(cap_rows, -1, dtype=np.int64)
-                ow[:n] = np.concatenate(owner_chunks)
-                bucket = skew_bucket(ow, ow >= 0, self.n_dev, local)
-            if not raw_mode and self.bucket is None:
+                owner = np.full(cap_rows, -1, dtype=np.int64)
+                owner[:n] = np.concatenate(owner_chunks)
+                routed = owner >= 0
+            if owner is not None and self.bucket is None:
                 # skew-exact routing bucket (the join's stage_epoch
                 # scheme): the default (= local rows) makes every shard
                 # process the WHOLE batch post-exchange — n_dev× the
                 # single-chip compute; exact per-(sender, target)
                 # counts from the host key lanes collapse it to the
-                # real skew, pow2-quantized for shape stability. The
-                # fused raw path keeps the worst case (its lanes only
-                # exist in-trace).
-                kw_ = self.key_width
-                vis_col = packed[:, kw_ + 1] != 0
-                owner = owners_host(packed[:, :kw_],
-                                    self._owner_map_host)
-                bucket = skew_bucket(owner, vis_col, self.n_dev, local)
+                # real skew, pow2-quantized for shape stability. A
+                # fused raw path without owners keeps the worst case.
+                bucket = skew_bucket(owner, routed, self.n_dev, local)
+            if not raw_mode:
+                # (fused: `owner` is a PRE-filter superset, good for a
+                # bound and no count; the step's own count is taken)
+                received = received_by_shard(owner, routed, self.n_dev)
         key = (cap_rows, bucket, raw_mode)
         step = self._step_cache.get(key)
         if step is None:
@@ -557,10 +613,12 @@ class ShardedAggKernel:
         up = jaxtools.upload(packed, NamedSharding(self.mesh, P(AXIS)),
                              kernel=self._span_label)
         _note_dispatch(n_vis if raw_mode else n)
+        note_launch(self.route_label, self.n_dev, bucket)
+        got = None
         if raw_mode:
             with spans.dispatch_span(self._span_label, n_vis,
                                      batch_rows=n):
-                self.state, ins, overflow, stage_rows = step(
+                self.state, ins, overflow, stage_rows, got = step(
                     self.state, up, self.owner_map)
             jaxtools.start_fetch(stage_rows)
             self._stage_pending.append(stage_rows)
@@ -569,9 +627,11 @@ class ShardedAggKernel:
                                      batch_rows=n):
                 self.state, ins, overflow = step(self.state, up,
                                                  self.owner_map)
-        # overflow/insert counters fold in asynchronously instead of
-        # one blocking read per dispatch
-        self._counters.push(ins, overflow, n)
+            note_routed(int(routed.sum()), received)
+        # overflow/insert counters (and the fused step's count of the
+        # rows it received) fold in asynchronously instead of one
+        # blocking read per dispatch
+        self._counters.push(ins, overflow, n, received=got)
 
     def drain_stage_rows(self) -> Optional[np.ndarray]:
         """Sum of per-stage visible-row counts since the last drain
@@ -881,10 +941,13 @@ class ShardedAggKernel:
             check_vma=False)
         step = jax.jit(mapped, donate_argnums=(0,))
         new_state, received = step(self.state, new_map)
+        note_launch(self.route_label, n_dev, cap)
+        received = np.asarray(received, dtype=np.int64)
+        note_routed(int(received.sum()), received)
         # destination-table contract: probe_insert needs a free slot
         # per routed row; an overfull shard would silently corrupt
         # accumulators — fail loudly instead
-        worst = int(np.asarray(received).max())
+        worst = int(received.max())
         if worst > ht.MAX_LOAD * cap:
             raise RuntimeError(
                 f"reshard overfills a shard: {worst} live groups vs "

@@ -13,11 +13,24 @@ construction; a caller shrinking it trades bandwidth for a fatal-on-skew
 contract — the overflow flag fires AFTER the step has applied the
 surviving rows, so it is an assertion, not a retry point. All lanes are
 int32 (ops/lanes.py rationale).
+
+The exchange keeps books (ISSUE 27). The HOST routing in front of it
+(`owners_host`, `skew_bucket`, the bucket choice) runs under the ledger
+phase ``exchange_route`` (`route_phase`), and every SPMD launch that
+holds an exchange is counted where it is launched (`note_launch`,
+`note_routed`): launches, the row slots its all_to_alls carried
+(n_dev x n_dev x bucket, rows plus padding), the rows the senders
+routed, the rows each shard received, and the bucket in use per
+kernel. They reach `rw_metrics_history` per barrier as
+``mesh_exchange.*`` (utils/metrics.MetricsHistory). Every live sharded
+kernel is in `MESH_KERNELS`; `mesh_table_rows` is the payload of
+`rw_mesh_tables` (per-shard occupancy and capacity of its tables).
 """
 
 from __future__ import annotations
 
-from typing import List, Sequence, Tuple
+import weakref
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +39,68 @@ import numpy as np
 from risingwave_tpu.common.chunk import next_pow2
 from risingwave_tpu.common.hash import VNODE_COUNT
 from risingwave_tpu.ops.hash_table import hash_key_lanes
+from risingwave_tpu.utils.ledger import LEDGER
+
+# every live sharded kernel (they add themselves when built): what
+# `rw_mesh_tables` reads
+MESH_KERNELS: "weakref.WeakSet" = weakref.WeakSet()
+
+
+def route_phase(kernel: str):
+    """Ledger scope of the host routing in front of an exchange: the
+    owner of every row, the skew-exact bucket, the bucket choice."""
+    return LEDGER.phase("exchange_route", kernel=kernel)
+
+
+def route_label(kind: str, table_id: Optional[int]) -> str:
+    """What a sharded kernel's exchanges go by in the books: its kind
+    and the state table it serves (`rw_state_topology`'s table_id),
+    which tells two aggregates, or two join sides, of one view apart."""
+    return kind if table_id is None else f"{kind}.t{table_id}"
+
+
+def note_launch(kernel: str, n_dev: int, bucket: int) -> None:
+    """One SPMD launch holding an exchange, counted at the launch."""
+    from risingwave_tpu.utils.metrics import STREAMING as S
+    S.mesh_exchange_launches.inc(1, kernel=kernel)
+    S.mesh_exchange_slots.inc(float(n_dev * n_dev * bucket),
+                              kernel=kernel)
+    S.mesh_exchange_bucket.set(float(bucket), kernel=kernel)
+
+
+def note_routed(routed: int, received: np.ndarray) -> None:
+    """The rows the senders of one launch routed, and the rows each
+    shard received of them. Two counts from two sides of the same
+    exchange: they add up to the same number, or rows were lost."""
+    from risingwave_tpu.utils.metrics import STREAMING as S
+    S.mesh_exchange_rows_routed.inc(float(routed))
+    for shard, n in enumerate(np.asarray(received).tolist()):
+        if n:
+            S.mesh_exchange_rows_received.inc(float(n),
+                                              shard=str(shard))
+
+
+def received_by_shard(owner: np.ndarray, valid: np.ndarray,
+                      n_dev: int) -> np.ndarray:
+    """Rows each shard receives of a staged batch, from the host twin
+    of the device routing."""
+    return np.bincount(np.asarray(owner)[valid], minlength=n_dev)
+
+
+def mesh_table_rows() -> List[tuple]:
+    """`rw_mesh_tables` payload: (table_id, mv, kernel, part, shard,
+    occupied, capacity) per shard of every live sharded kernel's
+    device tables. Reading blocks on the device once per table."""
+    from risingwave_tpu.state.topology import TOPOLOGY
+    rows = []
+    for k in list(MESH_KERNELS):
+        tid = -1 if k.table_id is None else int(k.table_id)
+        mv = TOPOLOGY.mv_of(tid) if tid >= 0 else ""
+        for part, occupied, capacity in k.shard_tables():
+            for shard, occ in enumerate(np.asarray(occupied).tolist()):
+                rows.append((tid, mv, k.route_label, part, shard,
+                             int(occ), int(capacity)))
+    return sorted(rows)
 
 
 def vnodes_from_lanes(key_lanes: jnp.ndarray) -> jnp.ndarray:

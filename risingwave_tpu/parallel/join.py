@@ -36,8 +36,9 @@ from risingwave_tpu.ops.hash_join import (
     probe_pairs, tombstone_rows,
 )
 from risingwave_tpu.parallel.exchange import (
-    bucketize_by_owner, exchange, owners_host, skew_bucket,
-    vnodes_from_lanes,
+    MESH_KERNELS, bucketize_by_owner, exchange, note_launch,
+    note_routed, owners_host, received_by_shard, route_label,
+    route_phase, skew_bucket, vnodes_from_lanes,
 )
 from risingwave_tpu.utils import jaxtools
 from risingwave_tpu.utils.ledger import LEDGER
@@ -245,10 +246,36 @@ class ShardedJoinKernel:
         self._preludes: Dict[str, object] = {}
         # epoch-trace identity stamped on dispatch metrics
         self._span_label = "ShardedJoinKernel"
+        # the state table this side's rows persist to (the executor
+        # sets it): names the side in the exchange's books and in
+        # rw_mesh_tables
+        self.table_id: Optional[int] = None
+        # what stage_epoch counted for the batch it staged last, by the
+        # staged aux array: its apply (this kernel) and its probe (the
+        # OTHER side's kernel) each book a launch from it
+        self._staged: Optional[tuple] = None
+        MESH_KERNELS.add(self)
 
     @property
     def row_capacity(self) -> int:
         return self._row_capacity
+
+    @property
+    def route_label(self) -> str:
+        return route_label("sharded_join", self.table_id)
+
+    def shard_tables(self) -> list:
+        """[(part, occupied per shard, capacity per shard)] of the
+        device tables (rw_mesh_tables): the key table, and the row
+        chains (indexed by GLOBAL ref; a shard links its own keys'
+        rows only, tombstoned ones included until a rebuild)."""
+        keys = np.asarray(jnp.sum(self.table.occ, axis=1,
+                                  dtype=jnp.int32))
+        rows = np.asarray(jnp.sum(
+            self.chains.ins_seq != jnp.int32(I32_MAX), axis=1,
+            dtype=jnp.int32))
+        return [("keys", keys, self.key_capacity),
+                ("rows", rows, self._row_capacity)]
 
     def _stack(self, a):
         return jax.device_put(
@@ -576,7 +603,8 @@ class ShardedJoinKernel:
     def owners_of(self, key_lanes: np.ndarray) -> np.ndarray:
         """Host twin of the device routing, public (the executor
         computes per-epoch owner counts for the skew-exact bucket)."""
-        return self._owners_host(np.asarray(key_lanes))
+        with route_phase("sharded_join"):
+            return self._owners_host(np.asarray(key_lanes))
 
     def stage_epoch(self, up: np.ndarray, aux: np.ndarray, total: int,
                     max_ins_ref: int,
@@ -609,8 +637,9 @@ class ShardedJoinKernel:
             self._guard_keys(up[:, :self.key_width], ins_mask)
         if max_ins_ref >= 0:
             self.reserve_rows(max_ins_ref)
-        # mesh-width padding + the skew-exact routing bucket are epoch
-        # staging (host_pack); the row-sharded upload below is h2d
+        # mesh-width padding is epoch staging (host_pack); the
+        # skew-exact bucket is exchange_route; the row-sharded upload
+        # below is h2d
         with LEDGER.phase("host_pack", kernel="sharded_join"):
             m = max(n, self.n_dev)
             if m % self.n_dev:
@@ -621,19 +650,52 @@ class ShardedJoinKernel:
                 aux2 = np.zeros((m, 4), dtype=np.int32)
                 aux2[:n] = aux
                 up, aux = up2, aux2
-            local = m // self.n_dev
-            bucket = local
+        local = m // self.n_dev
+        bucket = local
+        with route_phase("sharded_join"):
+            flags = aux[:, AUX_FLAGS]
+            ow = None
             if owners is not None:
                 ow = np.full(m, -1, dtype=np.int64)
-                routed = aux[:total, AUX_FLAGS] != 0
-                ow[:total][routed] = np.asarray(owners)[:total][routed]
+                ow[:total] = np.asarray(owners)[:total]
+                ow[flags == 0] = -1
                 bucket = skew_bucket(ow, ow >= 0, self.n_dev, local)
+            # what this batch's two launches route: its apply (this
+            # kernel) and its probe (against the OTHER side's kernel).
+            # A caller without per-row owners keeps the worst-case
+            # bucket and books its launches without rows.
+            counts = tuple(
+                None if ow is None else
+                (int(v.sum()), received_by_shard(ow, v, self.n_dev))
+                for v in ((flags & (FLAG_INS | FLAG_DEL)) != 0,
+                          (flags & FLAG_PROBE) != 0))
         from risingwave_tpu.utils.ledger import note_backlog
         note_backlog("sharded_join", total)
-        return (jaxtools.upload(up, self._sharding,
-                                kernel="sharded_join"),
-                jaxtools.upload(aux, self._sharding,
-                                kernel="sharded_join"), bucket)
+        up_dev = jaxtools.upload(up, self._sharding,
+                                 kernel="sharded_join")
+        aux_dev = jaxtools.upload(aux, self._sharding,
+                                  kernel="sharded_join")
+        self._staged = (aux_dev, counts)
+        return up_dev, aux_dev, bucket
+
+    def _book_staged(self, aux_dev, which: int, bucket: int) -> None:
+        """Book one exchange launch over a batch THIS kernel staged
+        (`which`: 0 its apply, 1 its probe of the other side)."""
+        note_launch(self.route_label, self.n_dev, bucket)
+        staged = self._staged
+        if staged is not None and staged[0] is aux_dev \
+                and staged[1][which] is not None:
+            note_routed(*staged[1][which])
+
+    def _book_host(self, lanes: np.ndarray, valid: np.ndarray,
+                   bucket: int) -> None:
+        """Book one exchange launch of the per-chunk and recovery
+        paths, whose key lanes are on the host."""
+        note_launch(self.route_label, self.n_dev, bucket)
+        with route_phase("sharded_join"):
+            valid = np.asarray(valid, dtype=bool)
+            note_routed(int(valid.sum()), received_by_shard(
+                self._owners_host(lanes), valid, self.n_dev))
 
     def _prelude_for(self, prelude, prelude_key: str):
         """Pin the prelude under its key so cached steps stay valid
@@ -707,6 +769,7 @@ class ShardedJoinKernel:
             bucket, int(up_dev.shape[1]), up_dev.dtype == jnp.int64,
             prelude=prelude, prelude_key=prelude_key)
         _note_dispatch(m, "sharded_join")
+        self._book_staged(aux_dev, 0, bucket)
         with LEDGER.phase("device_compute", kernel="sharded_join"):
             self.table, self.chains, ovf = step(
                 self.table, self.chains, up_dev, aux_dev,
@@ -778,13 +841,14 @@ class ShardedJoinKernel:
                     sink=None, prelude=None, prelude_key: str = "",
                     bucket=None) -> "ShardedPendingEpochProbe":
         """Probe a whole epoch's rows against THIS side — each row at
-        its aux sequence — in one SPMD dispatch. `sink` is accepted for
-        JoinSideKernel API parity and unused: the sharded path keeps
-        degrees host-side (the executor's replay arrays), so the probe
-        only RETURNS per-row degrees, it maintains no device store.
+        its aux sequence — in one SPMD dispatch. `sink` is the PROBING
+        side's kernel (JoinSideKernel API parity): the sharded path
+        keeps degrees host-side (the executor's replay arrays), so the
+        probe only RETURNS per-row degrees and maintains no device
+        store; the sink staged the batch, so the launch goes into the
+        exchange's books under its name.
         ``bucket`` is the PROBING side's stage_epoch bound (the same
         rows route by the same keys)."""
-        del sink
         prelude = self._prelude_for(prelude, prelude_key)
         m = int(up_dev.shape[0])
         if bucket is None:
@@ -797,6 +861,7 @@ class ShardedJoinKernel:
                 bucket, width, cap, with_degrees,
                 prelude=prelude, prelude_key=prelude_key)
             _note_dispatch(m, "sharded_join")
+            (sink or self)._book_staged(aux_dev, 1, bucket)
             with LEDGER.phase("device_compute",
                               kernel="sharded_join"):
                 mats, ovf = step(self.table, self.chains, up_dev,
@@ -855,6 +920,7 @@ class ShardedJoinKernel:
         out_cap = other.probe_capacity
         step = self._build_apply_probe(bucket, out_cap)
         _note_dispatch(m, "sharded_join")
+        self._book_host(lanes, pv | im | dm, bucket)
         with LEDGER.phase("device_compute", kernel="sharded_join"):
             self.table, self.chains, mats, overflow = step(
                 self.table, self.chains, other.table, other.chains,
@@ -872,6 +938,7 @@ class ShardedJoinKernel:
         bucket = m // self.n_dev
         step = self._build_probe_only(bucket, out_cap)
         _note_dispatch(m, "sharded_join")
+        self._book_host(lanes, vis, bucket)
         with LEDGER.phase("device_compute", kernel="sharded_join"):
             mats, overflow = step(self.table, self.chains,
                                   jnp.asarray(lanes),
@@ -911,6 +978,7 @@ class ShardedJoinKernel:
         bucket = m // self.n_dev
         step = self._build_insert(bucket)
         _note_dispatch(m, "sharded_join")
+        self._book_host(lanes, mask, bucket)
         self.table, self.chains, overflow = step(
             self.table, self.chains, jnp.asarray(lanes),
             jnp.asarray(refs_), jnp.asarray(mask), jnp.int32(seq),
@@ -933,6 +1001,7 @@ class ShardedJoinKernel:
         bucket = m // self.n_dev
         step = self._build_delete(bucket)
         _note_dispatch(m, "sharded_join")
+        self._book_host(lanes, dm, bucket)
         self.chains, overflow = step(
             self.chains, jnp.asarray(lanes), jnp.asarray(drefs),
             jnp.asarray(dm), jnp.int32(seq), self.owner_map)

@@ -360,6 +360,10 @@ class HashAggExecutor(Executor):
         if flush_capacity is not None:
             self._kern_kw["flush_capacity"] = flush_capacity
         self._kernel = kernel
+        if kernel is not None and hasattr(kernel, "table_id"):
+            # an injected sharded kernel goes by its state table in
+            # the exchange's books and in rw_mesh_tables
+            kernel.table_id = table.table_id
         # watermark-driven state cleaning (state_table.rs:894 analog):
         # latest watermark seen on the FIRST group column (the state
         # tables' pk prefix — the only position a range delete covers,

@@ -269,6 +269,7 @@ class _JoinSide:
                     self._mesh,
                     key_width=LANES_PER_KEY * len(self.key_indices),
                     **self._shard_opts)
+                self._kernel.table_id = self.table.table_id
             else:
                 # capacity presize hints ride in shard_opts for the
                 # single-chip kernel too: every growth doubling costs

@@ -7,8 +7,13 @@ attributable, continuously, not in one-off cProfile runs):
 
 - ``host_ingest``   — connector decode (JsonRowParser/CsvRowParser) and
                       source-side chunk building.
-- ``host_pack``     — chunk codecs, epoch staging (backlog assembly),
-                      routing-bucket computation for the sharded kernels.
+- ``host_pack``     — chunk codecs, epoch staging (backlog assembly).
+- ``exchange_route``— the sharded kernels' host routing in front of
+                      their all_to_all (parallel/exchange.py): every
+                      row's owner shard, the skew-exact bucket, the
+                      bucket choice. Only a mesh plan has it: a row of
+                      ``rw_metrics_history`` carries the name only
+                      where the epoch routed.
 - ``h2d``           — host→device upload of packed/raw matrices
                       (``jaxtools.upload``), with exact byte counts.
 - ``device_compute``— the real ``instrumented_jit``/``shard_map``
@@ -100,9 +105,11 @@ from typing import Deque, Dict, Iterable, List, Optional, Tuple
 
 from risingwave_tpu.utils import spans as _spans
 
-PHASES = ("host_ingest", "host_pack", "h2d", "device_compute", "d2h",
-          "host_emit", "barrier_wait", "backpressure_wait",
-          "checkpoint", "compaction")
+PHASES = ("host_ingest", "host_pack", "exchange_route", "h2d",
+          "device_compute", "d2h", "host_emit", "barrier_wait",
+          "backpressure_wait", "checkpoint", "compaction")
+# published per barrier only where the epoch has seconds of them
+MESH_PHASES = frozenset(("exchange_route",))
 # synchronous on the event loop, outside any actor (module docstring,
 # "Stolen loop time")
 LOOP_PHASES = frozenset(("checkpoint", "compaction"))
@@ -634,7 +641,8 @@ class PhaseLedger:
         for name, s in rec.seconds.items():
             STREAMING.epoch_phase_seconds.inc(s, phase=name, query=q)
         extra = {f"phase.{p}": rec.seconds.get(p, 0.0)
-                 for p in PHASES + (UNATTRIBUTED,)}
+                 for p in PHASES + (UNATTRIBUTED,)
+                 if p in rec.seconds or p not in MESH_PHASES}
         for kind, s in rec.exec_s.items():
             extra["exec_s." + kind] = s
         extra["coverage"] = rec.coverage()

@@ -457,6 +457,25 @@ class StreamingMetrics:
             "dispatch (set at each backlog flush; sampled at every "
             "epoch seal as the Perfetto backlog counter track — the "
             "per-epoch staging volume, not a live queue depth)")
+        self.mesh_exchange_launches = r.counter(
+            "mesh_exchange_launch_count",
+            "SPMD launches that hold an all_to_all exchange "
+            "(parallel/exchange.py), by sharded kernel")
+        self.mesh_exchange_slots = r.counter(
+            "mesh_exchange_slots_total",
+            "row slots the exchanges' all_to_alls carried: n_dev x "
+            "n_dev x bucket per launch, rows plus padding, by kernel")
+        self.mesh_exchange_rows_routed = r.counter(
+            "mesh_exchange_rows_routed_total",
+            "valid rows the senders put into an exchange")
+        self.mesh_exchange_rows_received = r.counter(
+            "mesh_exchange_rows_received_total",
+            "rows a shard received from the exchanges, by shard")
+        self.mesh_exchange_bucket = r.gauge(
+            "mesh_exchange_bucket_rows",
+            "routing bucket (rows per sender and target) of the "
+            "kernel's latest exchange launch; a new value is a new "
+            "compiled program")
         self.kernel_flops = r.gauge(
             "device_kernel_flops",
             "XLA cost-analysis flops of the last-compiled program per "
@@ -626,17 +645,53 @@ class MetricsHistory:
             ("backlog_rows", lambda: csum(S.backlog_rows), "gauge"),
         )
 
+    @staticmethod
+    def _mesh_exchange():
+        """(series name, value now, kind) of the mesh exchange's books
+        (parallel/exchange.py): none in a process that never launched
+        an exchange, so a single-chip deployment's rows carry no such
+        column. Beside the totals, one counter per shard (the rows it
+        received) and one gauge per kernel (its routing bucket)."""
+        def total(metric):
+            return sum(v for _l, v in metric.series())
+
+        S = STREAMING
+        if not S.mesh_exchange_launches.series():
+            return []
+        pre = "mesh_exchange."
+        out = [(pre + "launches", total(S.mesh_exchange_launches),
+                "counter"),
+               (pre + "slots_carried", total(S.mesh_exchange_slots),
+                "counter"),
+               (pre + "rows_routed", total(S.mesh_exchange_rows_routed),
+                "counter")]
+        out += [(pre + "shard_rows." + labels.get("shard", "?"), v,
+                 "counter")
+                for labels, v in S.mesh_exchange_rows_received.series()]
+        out += [(pre + "bucket." + labels.get("kernel", "?"), v, "gauge")
+                for labels, v in S.mesh_exchange_bucket.series()]
+        return out
+
     def observe(self, epoch: int, interval_s: float,
                 extra: Optional[Dict[str, float]] = None,
                 domain: str = "") -> None:
         values: Dict[str, float] = {}
-        for name, fn, kind in self._tracked():
-            v = float(fn())
+        readings = [(name, float(fn()), kind)
+                    for name, fn, kind in self._tracked()]
+        for name, v, kind in readings + self._mesh_exchange():
             if kind == "counter":
                 values[name] = v - self._last.get(name, 0.0)
                 self._last[name] = v
             else:
                 values[name] = v
+        shard_rows = [v for k, v in values.items()
+                      if k.startswith("mesh_exchange.shard_rows.")]
+        if shard_rows:
+            # what the fullest shard received of the epoch's exchanges,
+            # and what a shard received on average
+            values["mesh_exchange.rows_max_shard"] = max(shard_rows)
+            values["mesh_exchange.rows_mean_shard"] = \
+                sum(shard_rows) / len(shard_rows)
         if extra:
             values.update(extra)
         with self._lock:

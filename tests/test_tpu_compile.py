@@ -382,6 +382,63 @@ def test_parallel_join_epoch_apply(mesh):
     assert "all-to-all" in compiled.as_text()
 
 
+@pytest.fixture(scope="module")
+def served_q8_mesh4():
+    """The benchmark's `nexmark-q8-mesh4` DDL in a session at
+    parallelism 4 on the CPU's virtual devices, two barriers deep: the
+    deployed sharded kernels carry the real fused prelude."""
+    import json
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "nexmark-q8-mesh4.json")) as f:
+        config = json.load(f)
+
+    async def run():
+        fe = Frontend(rate_limit=1, min_chunks=1, parallelism=4)
+        for ddl in config["ddl"]:
+            await fe.execute(ddl.format(seed=7))
+        await fe.step(2)
+        return fe
+
+    loop = asyncio.new_event_loop()
+    try:
+        fe = loop.run_until_complete(run())
+        yield fe
+        loop.run_until_complete(fe.close())
+    finally:
+        loop.close()
+
+
+def test_parallel_agg_step_fused_q8(served_q8_mesh4, mesh):
+    """parallel_agg.step_fused with q8's seller-side prelude (TUMBLE,
+    GROUP BY seller, window_start) traced ahead of the routing, at the
+    cell's epoch: 4 chunks of 4,096 rows and their separator rows pad
+    to 2^15, and without host owners the bucket is the worst case."""
+    from risingwave_tpu.common.hash import VNODE_COUNT
+    from risingwave_tpu.parallel.agg import ShardedAggKernel
+    (live,) = {id(k): k for ex in _executors(served_q8_mesh4, "q8")
+               for k in chip_smoke.kernels_of(ex)
+               if isinstance(k, ShardedAggKernel)
+               and k._prelude is not None}.values()
+    n_dev, shard_cap, rows = mesh.devices.size, 1 << 16, 1 << 15
+    sharded = NamedSharding(mesh, P("d"))
+    one = jax.eval_shape(lambda: hash_agg.make_agg_state(
+        shard_cap, live.key_width, live.specs))
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct((n_dev,) + a.shape, a.dtype,
+                                       sharding=sharded), one)
+    k = _bare(ShardedAggKernel, mesh=mesh, n_dev=n_dev,
+              specs=live.specs, key_width=live.key_width, state=state,
+              _prelude=live._prelude, _prelude_key=live._prelude_key)
+    step = k._build_raw_step(bucket=rows // n_dev)
+    assert jaxtools.program_name(step.label).startswith(
+        "parallel_agg_step_fused_")
+    compiled = _compile(
+        step, state, _sds(sharded, (rows, live._raw_width), jnp.int64),
+        _sds(NamedSharding(mesh, P()), (VNODE_COUNT,), jnp.int32))
+    assert "all-to-all" in compiled.as_text()
+
+
 # -- the three float cases the 64-bit rewrite used to refuse --------------------
 
 
