@@ -80,7 +80,7 @@ def _hash_partition(chunk: DataChunk, key_indices: Sequence[int],
                     n: int) -> List[List[tuple]]:
     """Rows → n buckets by the vnode hash of the keys — the typed
     lane-building of the streaming dispatch (dispatch.py _route /
-    state_table._encode_pks_bulk pattern: branch on the column TYPE,
+    state_table._encode_key_columns pattern: branch on the column TYPE,
     hash the numpy arrays directly, NULLs as the zero lane)."""
     rows = chunk.to_pylist()
     if not rows:

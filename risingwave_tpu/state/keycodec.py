@@ -7,8 +7,9 @@ SQL values they encode. Re-designed minimal: we encode host python values
 
 Values are PHYSICAL: DECIMAL is its scaled-int64 payload, timestamps are µs
 ints — the same representation device kernels and state-table rows use, so
-the vectorized bulk encoder (state_table._encode_pks_bulk) and this scalar
-codec produce identical bytes. Logical→physical normalization happens once,
+the columnar encoder (state_table._encode_key_columns, over this module's
+encode_fixed_column / encode_host_column) and the scalar codec produce
+identical bytes. Logical→physical normalization happens once,
 at chunk ingest (chunk._make_column / types.decimal_to_scaled).
 
 Layout per value:
@@ -28,9 +29,12 @@ from __future__ import annotations
 import struct
 from typing import Iterable, List, Optional, Sequence, Tuple
 
+import numpy as np
+
 from risingwave_tpu.common.types import DataType
 
 _NULL = b"\x00"
+NULL_KEY = _NULL      # a NULL is its tag and no payload, whatever the type
 _NONNULL = b"\x01"
 _STR_TERM = b"\x00\x00"
 
@@ -98,6 +102,43 @@ def encode_value(v, dt: DataType) -> bytes:
         return _NONNULL + _encode_bytes(bytes(v))
     # all remaining device types are integral (ints, dates, timestamps)
     return _NONNULL + _encode_int(int(v))
+
+
+def encode_fixed_column(vals: np.ndarray, dt: DataType) -> np.ndarray:
+    """``encode_value`` over the non-null values of one fixed-width
+    (device) column → uint8 [n, 1 + width]: the non-null tag and the
+    order-preserving payload, as whole-column arithmetic."""
+    n = len(vals)
+    if dt == DataType.BOOLEAN:
+        m = np.ones((n, 2), dtype=np.uint8)
+        m[:, 1] = vals.astype(np.uint8)
+        return m
+    with np.errstate(over="ignore"):
+        if dt in (DataType.FLOAT32, DataType.FLOAT64):
+            f = vals.astype(np.float64)
+            f = np.where(f == 0, 0.0, f)  # -0.0 → 0.0
+            bits = f.view(np.uint64)
+            neg = (bits >> np.uint64(63)) == 1
+            bits = np.where(neg, ~bits, bits | np.uint64(1 << 63))
+        else:
+            bits = vals.astype(np.int64).view(np.uint64) \
+                + np.uint64(1 << 63)
+    m = np.ones((n, 9), dtype=np.uint8)
+    m[:, 1:] = bits.astype(">u8").view(np.uint8).reshape(n, 8)
+    return m
+
+
+def encode_host_column(values: Iterable, dt: DataType) -> List[bytes]:
+    """``encode_value`` over one host-typed column (None is NULL): the
+    type is dispatched once for the column, not once per value."""
+    if dt == DataType.VARCHAR:
+        return [_NULL if v is None else
+                _NONNULL + _encode_bytes(str(v).encode("utf-8"))
+                for v in values]
+    if dt == DataType.BYTEA:
+        return [_NULL if v is None else _NONNULL + _encode_bytes(bytes(v))
+                for v in values]
+    return [encode_value(v, dt) for v in values]
 
 
 def decode_value(buf: bytes, pos: int, dt: DataType):

@@ -30,11 +30,24 @@ from risingwave_tpu.common.hash import (
 )
 from risingwave_tpu.common.types import DataType, Schema, scaled_to_decimal
 from risingwave_tpu.state.keycodec import (
-    decode_memcomparable, encode_memcomparable, encode_vnode_prefix,
+    NULL_KEY, decode_memcomparable, encode_fixed_column,
+    encode_host_column, encode_memcomparable, encode_vnode_prefix,
 )
 from risingwave_tpu.state.mem_table import KeyOp, MemTable
 from risingwave_tpu.state.store import StateStore
 from risingwave_tpu.state import topology as _topology
+from risingwave_tpu.utils.metrics import STREAMING as _METRICS
+
+# which encoder made each state-table key: the columnar one (every bulk
+# entry point, counted by the batch) or the scalar one (point operations)
+_PK_COLUMNAR = _METRICS.state_pk_keys.labeled(path="columnar")
+_PK_ROW = _METRICS.state_pk_keys.labeled(path="row")
+
+# One pk column of a batch as the key encoder takes it: the values (an
+# array of the type's np_dtype for a device type, float64 also for the
+# floats; any sequence of Python objects for a host type) and their
+# validity (None: every value is valid; an invalid slot may hold anything).
+KeyColumn = Tuple[Sequence, Optional[np.ndarray]]
 
 # barrier-domain mode (meta/domains.py flips this on when a
 # BarrierPlane exists in the process; workers flip it on the first
@@ -68,6 +81,9 @@ class StateTable:
         for i in self.dist_key_indices:
             assert i in self.pk_indices, \
                 "dist key must be part of the state-table pk"
+        # where each dist key sits in the pk
+        self._dist_pos = [self.pk_indices.index(i)
+                          for i in self.dist_key_indices]
         self.store = store
         self.mem_table = MemTable(sanity_check=sanity_check)
         # staged all-insert chunk batches (write_chunk(defer=True) —
@@ -155,16 +171,16 @@ class StateTable:
 
     # -- key helpers ----------------------------------------------------
     def _vnode_of_pk(self, pk_values: Sequence) -> int:
-        if not self.dist_key_indices:
+        if not self._dist_pos:
             return 0  # singleton distribution (VirtualNode::ZERO analog)
-        lanes: List[np.ndarray] = []
-        for i in self.dist_key_indices:
-            dt = self.schema[i].data_type
-            v = pk_values[self.pk_indices.index(i)]
-            lanes.append(_key_lane(v, dt))
+        lanes = [_key_lane(pk_values[p], self.pk_types[p])
+                 for p in self._dist_pos]
         return int(vnodes_of_host(lanes)[0])
 
     def _encode_pk(self, pk_values: Sequence) -> bytes:
+        """One key by the scalar codec: the point operations' encoder,
+        and the reference the columnar encoder is held to."""
+        _PK_ROW.inc()
         vnode = self._vnode_of_pk(pk_values)
         return (encode_vnode_prefix(vnode) +
                 encode_memcomparable(pk_values, self.pk_types))
@@ -230,31 +246,48 @@ class StateTable:
         return deleted
 
     # -- bulk row API (barrier-flush hot path for device operators) -----
-    def insert_rows(self, rows: Sequence[Sequence]) -> None:
-        """Batch insert: pk encoding + vnode hashing vectorized over all
-        rows (one numpy pass per pk column instead of per-row hashing —
-        the r3 profile spent half of q8 in per-row ``_encode_pk``)."""
+    # ``pk_cols``, where a caller gives it, is the rows' pk columns as it
+    # already holds them (one ``KeyColumn`` per pk column, in pk order,
+    # equal to the rows' own values); otherwise they are taken from the rows.
+    def insert_rows(self, rows: Sequence[Sequence],
+                    pk_cols: Optional[Sequence[KeyColumn]] = None) -> None:
+        """Batch insert: vnodes and keys by the column, whatever the
+        pk's types (one numpy pass per fixed-width column, one pass of
+        the codec per varchar column)."""
         self._spill_staged()
         mt = self.mem_table
-        keys = self._encode_pk_rows(rows)
+        keys = self._encode_pk_rows(rows, pk_cols)
         rows_t = [tuple(r) for r in rows]
         if mt.insert_batch(keys, rows_t):
             return
         for key, row in zip(keys, rows_t):
             mt.insert(key, row)
 
-    def delete_rows(self, rows: Sequence[Sequence]) -> None:
+    def delete_rows(self, rows: Sequence[Sequence],
+                    pk_cols: Optional[Sequence[KeyColumn]] = None) -> None:
         self._spill_staged()
         mt = self.mem_table
-        for key, row in zip(self._encode_pk_rows(rows), rows):
+        for key, row in zip(self._encode_pk_rows(rows, pk_cols), rows):
             mt.delete(key, tuple(row))
 
     def update_rows(self, old_rows: Sequence[Sequence],
-                    new_rows: Sequence[Sequence]) -> None:
+                    new_rows: Sequence[Sequence],
+                    pk_cols: Optional[Sequence[KeyColumn]] = None) -> None:
+        """Batch update. With ``pk_cols`` every pair keeps its pk (they
+        are the columns of both sides); without, the keys are encoded
+        once where the two sides' pk columns are equal, else per side."""
         self._spill_staged()
         mt = self.mem_table
-        ok_keys = self._encode_pk_rows(old_rows)
-        nk_keys = self._encode_pk_rows(new_rows)
+        if pk_cols is None:
+            old_pk, new_pk = self._pk_lists(old_rows), self._pk_lists(new_rows)
+            ok_keys = self._encode_key_columns(
+                self._key_columns_of_lists(old_pk), len(old_rows))
+            nk_keys = ok_keys if new_pk == old_pk else \
+                self._encode_key_columns(
+                    self._key_columns_of_lists(new_pk), len(new_rows))
+        else:
+            ok_keys = nk_keys = self._encode_key_columns(pk_cols,
+                                                         len(old_rows))
         for ok, nk, old, new in zip(ok_keys, nk_keys, old_rows, new_rows):
             old, new = tuple(old), tuple(new)
             if ok == nk:
@@ -263,32 +296,35 @@ class StateTable:
                 mt.delete(ok, old)
                 mt.insert(nk, new)
 
-    def _encode_pk_rows(self, rows: Sequence[Sequence]) -> List[bytes]:
-        """Vectorized vnode-prefixed pk keys from row tuples."""
-        n = len(rows)
-        if n == 0:
-            return []
-        pk_cols: List[Tuple[np.ndarray, DataType]] = []
-        bulk_ok = True
-        for i in self.pk_indices:
-            dt = self.schema[i].data_type
-            col = [r[i] for r in rows]
-            if dt not in self._BULK_OK or any(v is None for v in col):
-                bulk_ok = False
-                break
-            pk_cols.append((np.asarray(col, dtype=dt.np_dtype), dt))
-        if not bulk_ok:          # rare: varchar/NULL pks → per-row codec
-            return [self._encode_pk(self.pk_of(r)) for r in rows]
-        if not self.dist_key_indices:
-            vnodes = np.zeros(n, dtype=np.int64)
-        else:
-            # dist keys are a pk subset (asserted in __init__) and the
-            # bulk path excludes NULLs/varchar — reuse the arrays the pk
-            # pass just built instead of re-extracting per row
-            lanes = [pk_cols[self.pk_indices.index(i)][0]
-                     for i in self.dist_key_indices]
-            vnodes = vnodes_of_host(lanes).astype(np.int64)
-        return self._pack_keys(vnodes, pk_cols)
+    def _encode_pk_rows(self, rows: Sequence[Sequence],
+                        pk_cols: Optional[Sequence[KeyColumn]] = None
+                        ) -> List[bytes]:
+        """Vnode-prefixed pk keys of row tuples."""
+        if pk_cols is None:
+            pk_cols = self._key_columns_of_lists(self._pk_lists(rows))
+        return self._encode_key_columns(pk_cols, len(rows))
+
+    def _pk_lists(self, rows: Sequence[Sequence]) -> List[list]:
+        return [[r[i] for r in rows] for i in self.pk_indices]
+
+    def _key_columns_of_lists(self, pk_lists: Sequence[list]
+                              ) -> List[KeyColumn]:
+        """Physical pk values by column (None is NULL) → key columns."""
+        cols: List[KeyColumn] = []
+        for col, dt in zip(pk_lists, self.pk_types):
+            valid = None
+            if dt.is_device:
+                if None in col:
+                    valid = np.fromiter((v is not None for v in col),
+                                        dtype=bool, count=len(col))
+                    col = [0 if v is None else v for v in col]
+                # a row's float is a Python float whatever the column's
+                # width: float64 keeps the bits the scalar codec packs
+                floating = dt in (DataType.FLOAT32, DataType.FLOAT64)
+                col = np.asarray(
+                    col, dtype=np.float64 if floating else dt.np_dtype)
+            cols.append((col, valid))
+        return cols
 
     def write_chunk(self, chunk: StreamChunk,
                     defer: bool = False) -> None:
@@ -328,100 +364,85 @@ class StateTable:
             else:
                 mt.delete(key, row)
 
-    # fixed-width device pk types eligible for the bulk encoder
-    _BULK_OK = frozenset({
-        DataType.INT16, DataType.INT32, DataType.INT64, DataType.SERIAL,
-        DataType.DECIMAL, DataType.DATE, DataType.TIME, DataType.TIMESTAMP,
-        DataType.TIMESTAMPTZ, DataType.FLOAT32, DataType.FLOAT64,
-        DataType.BOOLEAN,
-    })
-
     def _encode_pks_bulk(self, chunk: StreamChunk,
                          idx: np.ndarray) -> List[bytes]:
-        """Vectorized vnode-prefixed memcomparable keys for visible rows."""
-        n = len(idx)
-        # vnodes (vectorized, same math as device dispatch)
-        if not self.dist_key_indices:
+        """Vnode-prefixed pk keys of a chunk's visible rows."""
+        cols: List[KeyColumn] = []
+        for i in self.pk_indices:
+            c = chunk.columns[i]
+            cols.append((np.asarray(c.values)[idx],
+                         None if c.validity is None
+                         else np.asarray(c.validity)[idx]))
+        return self._encode_key_columns(cols, len(idx))
+
+    def _encode_key_columns(self, cols: Sequence[KeyColumn],
+                            n: int) -> List[bytes]:
+        """The one bulk key encoder: pk columns → vnode-prefixed
+        memcomparable keys, byte for byte what ``_encode_pk`` gives row
+        by row (the keys are the on-disk format, the scan order and the
+        vnode partition). What it does with a column depends on the
+        column alone: its type and which of its values are NULL.
+
+        Vnodes: one ``vnodes_of_host`` over the dist-key columns, the
+        same math as the device dispatch; a device-typed column hashes
+        as it is, a NULL as the zero lane (``_key_lane``'s rule), a
+        host-typed column through ``hash_strings_host``.
+
+        Bytes: runs of non-null fixed-width columns are packed as one
+        byte matrix, the vnode prefix leading the first; a fixed-width
+        column with NULLs gets ``0x00`` and no payload in those rows; a
+        host-typed column goes through the scalar codec once, as a
+        column. A key that is not one run is one join per row."""
+        if n == 0:
+            return []
+        _PK_COLUMNAR.inc(n)
+        norm: List[KeyColumn] = []
+        for (vals, valid), dt in zip(cols, self.pk_types):
+            if valid is not None and valid.all():
+                valid = None
+            if dt.is_device:
+                vals = np.asarray(vals)
+                if valid is not None:
+                    vals = np.where(valid, vals,
+                                    np.zeros((), dtype=vals.dtype))
+            elif valid is not None:
+                vals = np.array(vals, dtype=object)
+                vals[~valid] = None
+                valid = None
+            norm.append((vals, valid))
+
+        if not self._dist_pos:
             vnodes = np.zeros(n, dtype=np.int64)
         else:
-            lanes = []
-            for i in self.dist_key_indices:
-                c = chunk.columns[i]
-                vals = np.asarray(c.values)[idx]
-                if c.data_type.is_device:
-                    if c.validity is not None:
-                        # NULL dist-key values hash as the zero lane (same
-                        # rule as _key_lane(None)) regardless of buffer fill
-                        vals = np.where(np.asarray(c.validity)[idx], vals,
-                                        np.zeros((), dtype=vals.dtype))
-                    lanes.append(vals)
-                else:
-                    lanes.append(hash_strings_host(vals, n))
+            lanes = [norm[p][0] if self.pk_types[p].is_device
+                     else hash_strings_host(norm[p][0], n)
+                     for p in self._dist_pos]
             vnodes = vnodes_of_host(lanes).astype(np.int64)
 
-        pk_cols = [chunk.columns[i] for i in self.pk_indices]
-        bulk_ok = all(
-            c.data_type in self._BULK_OK and
-            (c.validity is None or bool(np.asarray(c.validity)[idx].all()))
-            for c in pk_cols)
-        if not bulk_ok:  # rare path: varchar/null pks — per-row codec
-            out = []
-            host_pk = [(np.asarray(c.values)[idx],
-                        None if c.validity is None
-                        else np.asarray(c.validity)[idx]) for c in pk_cols]
-            for j in range(n):
-                pk = tuple(
-                    None if (val is not None and not val[j])
-                    else (vals[j].item() if hasattr(vals[j], "item")
-                          else vals[j])
-                    for vals, val in host_pk)
-                out.append(encode_vnode_prefix(int(vnodes[j]))
-                           + encode_memcomparable(pk, self.pk_types))
-            return out
-
-        typed = [(np.asarray(c.values)[idx], c.data_type)
-                 for c in pk_cols]
-        return self._pack_keys(vnodes, typed)
-
-    @staticmethod
-    def _pack_keys(vnodes: np.ndarray,
-                   cols: Sequence[Tuple[np.ndarray, DataType]]
-                   ) -> List[bytes]:
-        """Non-null fixed-width pk columns → memcomparable key matrix.
-
-        Layout: [2B vnode][per col: 0x01 + payload]."""
-        n = len(vnodes)
-        widths = [2] + [1 + (1 if dt == DataType.BOOLEAN else 8)
-                        for _v, dt in cols]
-        total = sum(widths)
-        m = np.empty((n, total), dtype=np.uint8)
-        m[:, 0] = (vnodes >> 8).astype(np.uint8)
-        m[:, 1] = (vnodes & 0xFF).astype(np.uint8)
-        off = 2
-        for vals, dt in cols:
-            m[:, off] = 1  # non-null tag
-            off += 1
-            if dt == DataType.BOOLEAN:
-                m[:, off] = vals.astype(np.uint8)
-                off += 1
+        prefix = np.empty((n, 2), dtype=np.uint8)
+        prefix[:, 0] = vnodes >> 8
+        prefix[:, 1] = vnodes & 0xFF
+        pieces: List[List[bytes]] = []   # per-row bytes, left to right
+        run: List[np.ndarray] = [prefix]
+        for (vals, valid), dt in zip(norm, self.pk_types):
+            if dt.is_device and valid is None:
+                run.append(encode_fixed_column(vals, dt))
                 continue
-            if dt in (DataType.FLOAT32, DataType.FLOAT64):
-                with np.errstate(over="ignore"):
-                    f = vals.astype(np.float64)
-                    f = np.where(f == 0, 0.0, f)  # -0.0 → 0.0
-                    bits = f.view(np.uint64)
-                    neg = (bits >> np.uint64(63)) == 1
-                    bits = np.where(neg, ~bits,
-                                    bits | np.uint64(1 << 63))
+            if run:
+                pieces.append(_matrix_rows(run))
+                run = []
+            if dt.is_device:
+                piece = _matrix_rows([encode_fixed_column(vals, dt)])
+                for j in np.flatnonzero(~valid).tolist():
+                    piece[j] = NULL_KEY
             else:
-                with np.errstate(over="ignore"):
-                    bits = vals.astype(np.int64).view(np.uint64) \
-                        + np.uint64(1 << 63)
-            be = bits.astype(">u8").view(np.uint8).reshape(n, 8)
-            m[:, off:off + 8] = be
-            off += 8
-        flat = m.tobytes()
-        return [flat[i * total:(i + 1) * total] for i in range(n)]
+                piece = encode_host_column(vals, dt)
+            pieces.append(piece)
+        if run:
+            pieces.append(_matrix_rows(run))
+        if len(pieces) == 1:
+            return pieces[0]
+        return list(map(b"".join, zip(*pieces)))
 
     # -- read API --------------------------------------------------------
     def _read_epoch(self) -> int:
@@ -522,6 +543,14 @@ def _next_prefix(b: bytes) -> Optional[bytes]:
             return bytes(arr)
         arr.pop()
     return None
+
+
+def _matrix_rows(parts: Sequence[np.ndarray]) -> List[bytes]:
+    """uint8 matrices of equal height, side by side → one bytes per row."""
+    m = parts[0] if len(parts) == 1 else np.concatenate(parts, axis=1)
+    n, width = m.shape
+    flat = m.tobytes()
+    return [flat[i * width:(i + 1) * width] for i in range(n)]
 
 
 def _key_lane(v, dt: DataType) -> np.ndarray:

@@ -1268,12 +1268,19 @@ class HashAggExecutor(Executor):
         return list(zip(*cols)) if cols else []
 
     def _persist(self, fr, gk, ins_i, upd_i, del_i) -> None:
-        # bulk row APIs: one vectorized pk-encode pass per flush class
-        # instead of per-row vnode hashing (the r3 q8 profile's top cost)
-        self.table.insert_rows(self._state_rows(fr, gk, ins_i, prev=False))
+        # bulk row APIs, one key-encode pass per flush class; the state
+        # table's pk is the group key, which ``gk`` already holds by the
+        # column, so the table need not take the rows apart again
+        def pk_cols(idx):
+            return [(vals[idx], ok[idx]) for vals, ok in gk]
+
+        self.table.insert_rows(self._state_rows(fr, gk, ins_i, prev=False),
+                               pk_cols(ins_i))
         self.table.update_rows(self._state_rows(fr, gk, upd_i, prev=True),
-                               self._state_rows(fr, gk, upd_i, prev=False))
-        self.table.delete_rows(self._state_rows(fr, gk, del_i, prev=True))
+                               self._state_rows(fr, gk, upd_i, prev=False),
+                               pk_cols(upd_i))
+        self.table.delete_rows(self._state_rows(fr, gk, del_i, prev=True),
+                               pk_cols(del_i))
 
     # -- recovery --------------------------------------------------------
     def _recover(self) -> None:

@@ -286,6 +286,11 @@ class StreamingMetrics:
         self.agg_rows_cleaned = r.counter(
             "stream_agg_state_rows_cleaned",
             "state rows deleted by watermark cleaning")
+        self.state_pk_keys = r.counter(
+            "stream_state_pk_keys",
+            "state-table keys encoded, by path (columnar: the bulk "
+            "encoder, counted by the batch; row: the scalar codec of "
+            "the point operations, one key a call)")
         self.actor_count = r.gauge("stream_actor_count", "live actors")
         self.checkpoint_count = r.counter(
             "meta_checkpoint_count", "committed checkpoints")
@@ -643,6 +648,10 @@ class MetricsHistory:
             ("barrier_in_flight",
              lambda: S.barrier_in_flight.get(), "gauge"),
             ("backlog_rows", lambda: csum(S.backlog_rows), "gauge"),
+            ("state_pk.columnar",
+             lambda: S.state_pk_keys.get(path="columnar"), "counter"),
+            ("state_pk.row",
+             lambda: S.state_pk_keys.get(path="row"), "counter"),
         )
 
     @staticmethod

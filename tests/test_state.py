@@ -265,14 +265,25 @@ def test_bulk_and_scalar_key_encoding_agree():
     keys_a = sorted(k for k, _ in ta.mem_table.iter_ops())
     keys_b = sorted(k for k, _ in tb.mem_table.iter_ops())
     assert keys_a == keys_b
-    # and a varchar pk falls back to the scalar codec with the same result
-    tc = StateTable(23, schema, pk_indices=[4, 0], store=store)
-    td = StateTable(24, schema, pk_indices=[4, 0], store=store)
-    tc.write_chunk(chunk)
-    for row in rows:
-        td.insert(row)
-    assert sorted(k for k, _ in tc.mem_table.iter_ops()) == \
-        sorted(k for k, _ in td.mem_table.iter_ops())
+    # a varchar pk takes the same columnar encoder: the string column
+    # through the codec once, the fixed-width ones as byte matrices on
+    # either side of it, the vnode from the string's hash and the int
+    for tid, (pk, dist) in enumerate([([4, 0], None), ([0, 4, 1], [4, 0]),
+                                      ([4], [4])]):
+        tc = StateTable(23 + 2 * tid, schema, pk_indices=pk, store=store,
+                        dist_key_indices=dist)
+        td = StateTable(24 + 2 * tid, schema, pk_indices=pk, store=store,
+                        dist_key_indices=dist)
+        te = StateTable(40 + tid, schema, pk_indices=pk, store=store,
+                        dist_key_indices=dist)
+        tc.write_chunk(chunk)
+        te.insert_rows(rows)
+        for row in rows:
+            td.insert(row)
+        want = [td._encode_pk(td.pk_of(row)) for row in rows]
+        assert tc._encode_pk_rows(rows) == want
+        assert dict(tc.mem_table.iter_ops()) == \
+            dict(td.mem_table.iter_ops()) == dict(te.mem_table.iter_ops())
 
 
 def test_negative_zero_and_null_distkey_key_consistency():
