@@ -565,8 +565,7 @@ async def _ctl_cost(obj, args) -> int:
                       f"{imb.get(mv, 1.0):>5.2f}  {mv}"
                       + (f" ({dom})" if dom else ""))
             if not rows:
-                print("(no attributed epochs yet — is stream_costs "
-                      "off?)")
+                print("(no attributed epochs yet)")
             hot = HOTKEYS.rows()
             if hot:
                 print("== hot keys (top rank per input) ==")

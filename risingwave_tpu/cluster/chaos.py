@@ -181,8 +181,8 @@ _STAGE_KILL_AFTER_S = 0.4
 
 @dataclass
 class ChaosReport:
-    """What a chaos run produced — the bench-snapshot payload and the
-    determinism assertion's subject."""
+    """What a chaos run produced — the determinism assertion's
+    subject."""
 
     seed: int
     events: List[tuple] = field(default_factory=list)    # applied
@@ -419,7 +419,7 @@ async def run_chaos(fe, seed: int, steps: int = 24,
                     settle_steps: int = 40,
                     kinds: Optional[List[str]] = None,
                     rescale_mv: Optional[str] = None) -> ChaosReport:
-    """Generate + replay one seeded schedule (the bench entry point).
+    """Generate + replay one seeded schedule.
     Wall-clock MTTR is recorded per recovery by the supervisor.
     ``rescale_mv`` names the job the mid-rescale fault kinds drive
     their guarded ALTER against."""

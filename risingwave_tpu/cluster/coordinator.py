@@ -36,13 +36,13 @@ CONTROL_LINE_LIMIT = 1 << 24
 CONTROL_PAGE_BYTES = 4 << 20
 
 # verbs safe to RE-SEND after a reconnect: each is a pure read or an
-# absolute-state write (recover_store/set_trace/arm_failpoints set a
-# target state, so applying twice equals applying once). inject /
+# absolute-state write (recover_store/arm_failpoints set a target
+# state, so applying twice equals applying once). inject /
 # deploy_plan / ingest_table / drain_trace are NOT here — replaying
 # them changes cluster state, and their failures belong to the
 # recovery supervisor, not a silent retry.
 _IDEMPOTENT_VERBS = frozenset({
-    "ping", "scan_table", "recover_store", "set_trace", "set_ledger",
+    "ping", "scan_table", "recover_store",
     "arm_failpoints", "metrics", "reset",
     # pure reads: the autoscaler signal snapshot (tricolor + walker)
     # and the wedge-diagnostic await dump
@@ -234,13 +234,12 @@ class WorkerClient:
             # worker must never seal to its own prev eagerly)
             cmd.update(extras)
         from risingwave_tpu.utils import spans as _spans
-        if _spans.enabled():
-            # span context rides the injection: worker-side spans of
-            # this barrier round parent to the coordinator's inject
-            # span — the cross-process causal edge
-            cmd["trace"] = {
-                "span": _spans.EPOCH_TRACER.root_id(
-                    barrier.epoch.curr.value)}
+        # span context rides the injection: worker-side spans of
+        # this barrier round parent to the coordinator's inject
+        # span — the cross-process causal edge
+        cmd["trace"] = {
+            "span": _spans.EPOCH_TRACER.root_id(
+                barrier.epoch.curr.value)}
         return await self.call(cmd)
 
     async def ping(self, io_timeout: float = 2.0,

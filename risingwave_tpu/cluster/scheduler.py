@@ -557,35 +557,6 @@ class Cluster:
         for _ in range(n):
             await self.loop.inject_and_collect(force_checkpoint=True)
 
-    # -- epoch-causal tracing ---------------------------------------------
-    async def set_trace(self, on: bool) -> None:
-        """Fan the tracing toggle out to every worker process (the
-        coordinator's own tracer is the caller's to flip). Remembered
-        so a respawned worker rejoins with the operator's setting,
-        not the module default."""
-        self._trace_on = bool(on)
-        await asyncio.gather(*(
-            c.call({"cmd": "set_trace", "on": bool(on)})
-            for c in self.clients if c is not None))
-
-    async def set_ledger(self, on: bool) -> None:
-        """Fan the phase-ledger toggle out to every worker process
-        (same on/off everywhere, or a drained merge would have
-        per-process holes). Remembered for respawns like set_trace."""
-        self._ledger_on = bool(on)
-        await asyncio.gather(*(
-            c.call({"cmd": "set_ledger", "on": bool(on)})
-            for c in self.clients if c is not None))
-
-    async def set_costs(self, on: bool) -> None:
-        """Fan the cost/skew-attribution toggle out to every worker
-        (per-MV cost books, topology upkeep and hot-key sketches flip
-        together). Remembered for respawns like set_ledger."""
-        self._costs_on = bool(on)
-        await asyncio.gather(*(
-            c.call({"cmd": "set_costs", "on": bool(on)})
-            for c in self.clients if c is not None))
-
     # -- dedicated compaction (ISSUE 19) ----------------------------------
     async def set_compaction(self, mode: str) -> None:
         """Fan the compaction arm to every worker namespace and
@@ -593,8 +564,7 @@ class Cluster:
         compactor subprocess plus a CompactionManager with one
         namespace per worker slot; 'inline' drains in-flight tasks,
         reverts workers to commit-path compaction and stops the
-        compactor. Remembered across respawns/recoveries like
-        set_trace."""
+        compactor. Remembered across respawns/recoveries."""
         from risingwave_tpu.meta.compaction import parse_compaction
         mode = parse_compaction(mode)
         self._compaction_mode = mode
@@ -883,18 +853,6 @@ class Cluster:
         if self.handles[k] is not None:
             self.handles[k].kill()       # reap the corpse (idempotent)
         await self._start_slot(k)
-        # a fresh process boots with the MODULE defaults — re-apply
-        # the operator's trace/ledger toggles or the respawned worker
-        # punches a per-process hole in every later drain/merge
-        for verb, on in (("set_trace", getattr(self, "_trace_on",
-                                               None)),
-                         ("set_ledger", getattr(self, "_ledger_on",
-                                                None)),
-                         ("set_costs", getattr(self, "_costs_on",
-                                               None))):
-            if on is not None:
-                await self.clients[k].call_idempotent(
-                    {"cmd": verb, "on": on}, io_timeout=20.0)
         if self._compaction_mode != "inline":
             # a fresh process boots inline — without this re-apply the
             # respawned worker would compact on its own commit path,
@@ -1142,7 +1100,7 @@ class Cluster:
         # ANY domain would have its next buffered flush rejected under
         # that fence (write at epoch ≤ sealed). Stop-the-world is the
         # scale.rs-parity mechanism; the stall is bounded and recorded
-        # (the autoscaler ledger's duration / bench rescale_stall).
+        # (the autoscaler ledger's duration).
         cohort = list(self.jobs.values())
         moved_log: List[tuple] = []
         phase = "stop"

@@ -108,11 +108,6 @@ class DistFrontend:
         from risingwave_tpu.meta.compaction import (
             parse_compaction as _parse_compaction,
         )
-        from risingwave_tpu.stream.costs import (
-            parse_costs as _parse_costs,
-        )
-        from risingwave_tpu.utils.ledger import parse_ledger
-        from risingwave_tpu.utils.spans import parse_trace
         self.session_vars = SessionVars(
             self, {"streaming_rate_limit": "rate_limit",
                    "streaming_min_chunks": "min_chunks",
@@ -135,35 +130,19 @@ class DistFrontend:
              # back through the absorbed run; runs whose keys don't
              # map to raw refs stay interpretive (rule-side refusal)
              "stream_fusion": "on",
-             # epoch-causal tracing: the SET fans out to every worker
-             # over the control channel (same on/off everywhere, or a
-             # drained trace would have holes per process)
-             "stream_trace": "on",
-             # epoch phase ledger (utils/ledger.py): fans out like
-             # stream_trace — a cross-process merge must be all-on or
-             # all-off
-             "stream_ledger": "on",
-             # cost & skew attribution (ISSUE 16): per-MV cost books,
-             # topology upkeep and hot-key sketches; fans out like
-             # stream_ledger
-             "stream_costs": "on",
              # compaction arm (ISSUE 19): 'dedicated' provisions the
              # compactor role + CompactionManager (one namespace per
              # worker slot) and moves every merge off the serving path
              "storage_compaction": "inline"},
             validators={"stream_rewrite_rules": parse_rules,
                         "stream_fusion": parse_fusion,
-                        "stream_trace": parse_trace,
-                        "stream_ledger": parse_ledger,
-                        "stream_costs": _parse_costs,
                         "storage_compaction": _parse_compaction,
                         "stream_autoscale": parse_autoscale})
         # the elastic control loop (created lazily on SET
         # stream_autoscale=on; ticked by run_heartbeat while on)
         self.autoscaler = None
         # fragment-graph stats of the last deployed job (exchange
-        # hops, exchanged lane widths) — bench + tests read this to
-        # see what the rewrite engine bought
+        # hops, exchanged lane widths): what the rewrite engine bought
         self.last_plan_stats: Optional[dict] = None
         # serializes barrier rounds between DDL, step(), SELECT
         # snapshots and the background heartbeat (inject_and_collect
@@ -299,24 +278,6 @@ class DistFrontend:
             return await self._drop_sink(stmt)
         if isinstance(stmt, ast.SetVar):
             self.session_vars.set(stmt.name, stmt.value)
-            if stmt.name == "stream_trace":
-                from risingwave_tpu.utils import spans as _spans
-                on = _spans.parse_trace(
-                    self.session_vars.get("stream_trace"))
-                _spans.set_enabled(on)
-                await self.cluster.set_trace(on)
-            if stmt.name == "stream_ledger":
-                from risingwave_tpu.utils import ledger as _ledger
-                on = _ledger.parse_ledger(
-                    self.session_vars.get("stream_ledger"))
-                _ledger.set_enabled(on)
-                await self.cluster.set_ledger(on)
-            if stmt.name == "stream_costs":
-                from risingwave_tpu.stream import costs as _mvcosts
-                on = _mvcosts.parse_costs(
-                    self.session_vars.get("stream_costs"))
-                _mvcosts.set_enabled(on)
-                await self.cluster.set_costs(on)
             if stmt.name == "storage_compaction":
                 # fans to every worker + (de)provisions the compactor
                 # role; serialized with barrier rounds so the flip

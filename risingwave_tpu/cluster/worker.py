@@ -155,14 +155,6 @@ class WorkerServer:
             # coordinator)
             from risingwave_tpu.utils.metrics import GLOBAL
             return {"ok": True, "text": GLOBAL.render()}
-        if verb == "set_trace":
-            from risingwave_tpu.utils import spans as _spans
-            _spans.set_enabled(bool(cmd.get("on", True)))
-            return {"ok": True}
-        if verb == "set_ledger":
-            from risingwave_tpu.utils import ledger as _ledger
-            _ledger.set_enabled(bool(cmd.get("on", True)))
-            return {"ok": True}
         if verb == "drain_trace":
             # pop this process's recorded spans for the coordinator to
             # merge (tagged with the worker slot on the other side)
@@ -197,10 +189,6 @@ class WorkerServer:
                 out["topology"] = TOPOLOGY.drain_rows()
                 out["hot_keys"] = HOTKEYS.drain_rows()
             return out
-        if verb == "set_costs":
-            from risingwave_tpu.stream import costs as _costs
-            _costs.set_enabled(bool(cmd.get("on", True)))
-            return {"ok": True}
         if verb == "drain_freshness":
             # pop this process's raw freshness parts (ingest hwms,
             # epoch frontiers, visibility events) — the coordinator
@@ -499,16 +487,15 @@ class WorkerServer:
         barrier = Barrier(pair, kind, mutation)
         from risingwave_tpu.utils import spans as _spans
         _spans.set_current_epoch(pair.curr.value)
-        if _spans.enabled():
-            # worker-side inject marker, parented to the coordinator's
-            # inject span when the injection shipped one: every span
-            # this process records for the epoch links under it
-            parent = (cmd.get("trace") or {}).get("span")
-            wroot = _spans.EPOCH_TRACER.record(
-                "barrier.inject.worker", "barrier",
-                epoch=pair.curr.value, parent=parent,
-                kind=kind.value)
-            _spans.EPOCH_TRACER.set_root(pair.curr.value, wroot)
+        # worker-side inject marker, parented to the coordinator's
+        # inject span when the injection shipped one: every span
+        # this process records for the epoch links under it
+        parent = (cmd.get("trace") or {}).get("span")
+        wroot = _spans.EPOCH_TRACER.record(
+            "barrier.inject.worker", "barrier",
+            epoch=pair.curr.value, parent=parent,
+            kind=kind.value)
+        _spans.EPOCH_TRACER.set_root(pair.curr.value, wroot)
         actors = cmd.get("actors")
         if "seal" in cmd:
             # domain-protocol marker: a coordinator-side domain merge
@@ -569,12 +556,10 @@ class WorkerServer:
         # epoch work — they neither tick nor reset the streaks.
         dom = cmd.get("domain")
         if dom is not None:
-            from risingwave_tpu.stream import monitor as _monitor
             now = time.monotonic()
             last = self._domain_stamp.get(dom)
             self._domain_stamp[dom] = now
-            if (mutation is None and last is not None
-                    and _monitor.TRICOLOR):
+            if mutation is None and last is not None:
                 from risingwave_tpu.stream.bottleneck import BOTTLENECKS
                 BOTTLENECKS.observe(
                     domain=dom, epoch=pair.curr.value,

@@ -134,9 +134,6 @@ class FileLogSplitReader:
         self.parser: RowParser = make_parser(fmt, schema, options)
         self.max_chunk_size = int(max_chunk_size)
         self.offset = int(offset)
-        # exact emitted-row counter: the offset is BYTES (the recovery
-        # cursor); throughput accounting needs rows
-        self.rows_read = 0
 
     @property
     def split_id(self) -> str:
@@ -169,7 +166,6 @@ class FileLogSplitReader:
         # advance past malformed records too (they are counted by the
         # parser) — re-reading them forever would wedge the split
         self.offset += consumed
-        self.rows_read += chunk.cardinality()
         return chunk
 
 
@@ -213,10 +209,6 @@ class FileLogMultiReader:
         """Aggregate byte position (throughput accounting only — the
         recovery cursors are the PER-SPLIT offsets)."""
         return sum(r.offset for r in self.readers)
-
-    @property
-    def rows_read(self) -> int:
-        return sum(r.rows_read for r in self.readers)
 
     # -- the per-split offset contract ---------------------------------
     def splits(self) -> List[tuple]:

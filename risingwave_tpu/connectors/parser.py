@@ -44,7 +44,7 @@ def _parse_timestamp(v) -> int:
     """ISO-8601 string or epoch number → µs since epoch."""
     if isinstance(v, (int, float)):
         # heuristic: values up to ~2100 in seconds; larger ones are
-        # already µs (matches the bench generators' physical encoding)
+        # already µs (the generators' physical encoding)
         return int(v * _USECS) if abs(v) < 5_000_000_000 else int(v)
     import datetime
     s = str(v).replace("Z", "+00:00")
@@ -364,8 +364,8 @@ class JsonRowParser(RowParser):
     format) maps to the chunk op so retractions survive the wire."""
 
     # per-type coercers BOUND AT CONSTRUCTION: _coerce's type-dispatch
-    # chain ran per field per record (1.3M calls in one ad-ctr bench
-    # window — the r10 ingestion profile); a prebuilt (name, coercer)
+    # chain ran per field per record (1.3M calls in one ad-ctr
+    # window); a prebuilt (name, coercer)
     # list keeps the per-record work at one dict.get + one call per
     # field, with the common int/float cases as bare builtins
     _FAST = {DataType.INT16: int, DataType.INT32: int,

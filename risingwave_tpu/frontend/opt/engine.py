@@ -257,8 +257,8 @@ def explain_with_rewrite(consumer, spec: Optional[str],
 
 def plan_lane_stats(root) -> Dict[str, float]:
     """Carried-lane stats over an executor tree: how many column lanes
-    the plan moves between executors (EXPLAIN + bench surface them so
-    a rewrite's narrowing is visible next to events/sec)."""
+    the plan moves between executors (EXPLAIN surfaces them so a
+    rewrite's narrowing is visible)."""
     from risingwave_tpu.stream.executor import executor_children
     widths: List[int] = []
 

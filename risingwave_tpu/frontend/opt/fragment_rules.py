@@ -289,7 +289,7 @@ def rewrite_fragment_graph(graph, spec: Optional[str] = "all",
 
 def fragment_plan_stats(graph) -> dict:
     """Exchange-hop and exchanged-lane-width stats for one fragment
-    graph (bench + tests compare these with rewrites on vs off)."""
+    graph (tests compare these with rewrites on vs off)."""
     hops = 0
     lanes = 0
     for frag in graph.fragments:

@@ -23,6 +23,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
+from risingwave_tpu.common.errors import PlanError  # re-exported
 from risingwave_tpu.common.types import DataType, Field, Interval, Schema
 from risingwave_tpu.expr.expr import (
     BinaryOp, Cast, Expression, InputRef, tumble_start,
@@ -49,10 +50,6 @@ from risingwave_tpu.stream.executors.source import SourceExecutor
 
 SPLIT_STATE_SCHEMA = Schema([Field("split_id", DataType.VARCHAR),
                              Field("offset", DataType.INT64)])
-
-
-class PlanError(ValueError):
-    pass
 
 
 @dataclass
@@ -1580,7 +1577,7 @@ def _system_catalog_rows(name: str, catalog: Catalog, profiler=None):
         # barrier-interval device/transfer split by owning MV, joined
         # at read time with state bytes (topology), compile-cache
         # attribution and recovery/rescale charge-back — `ctl cost`
-        # and the marginal-cost bench read this
+        # reads this
         from risingwave_tpu.stream.costs import COSTS
         sch = Schema([Field("mv", DataType.VARCHAR),
                       Field("domain", DataType.VARCHAR),

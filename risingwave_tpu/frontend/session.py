@@ -28,6 +28,12 @@ from risingwave_tpu.stream.message import (
 
 Rows = List[tuple]
 
+# the recorders' on/off options of earlier builds. The first rode the
+# DDL log, so an old data dir may hold a SET of it: replay skips the
+# statement; live, the names are unknown like any other.
+_RETIRED_VARS = ("stream_trace", "stream_ledger", "stream_tricolor",
+                 "stream_costs")
+
 
 class Frontend:
     """One session over one in-process cluster.
@@ -95,14 +101,6 @@ class Frontend:
         # are pg-compatibility strings (shared impl: session_vars.py)
         from risingwave_tpu.frontend.opt import parse_fusion, parse_rules
         from risingwave_tpu.frontend.session_vars import SessionVars
-        from risingwave_tpu.stream.costs import (
-            parse_costs as _parse_costs,
-        )
-        from risingwave_tpu.stream.monitor import (
-            parse_tricolor as _parse_tricolor,
-        )
-        from risingwave_tpu.utils.ledger import parse_ledger
-        from risingwave_tpu.utils.spans import parse_trace
         self.session_vars = SessionVars(
             self, {"streaming_rate_limit": "rate_limit",
                    "streaming_min_chunks": "min_chunks",
@@ -127,31 +125,11 @@ class Frontend:
              # jitted step (one dispatch, donated state); 'off'
              # restores the interpretive chain
              "stream_fusion": "on",
-             # epoch-causal tracing (utils/spans.py): always-on
-             # bounded flight recorder; 'off' reduces every hook to a
-             # predicate check (and keeps remote barrier frames free
-             # of the span-context trailer)
-             "stream_trace": "on",
-             # epoch phase ledger (utils/ledger.py): per-epoch
-             # host/device time-and-bytes accounting with the
-             # conservation gate; 'off' reduces every hook to a
-             # predicate check (the ledger-on-vs-off bench arm)
-             "stream_ledger": "on",
              # barrier domains (meta/domains.py): 'off' restores one
              # global BarrierLoop — today's lockstep, bit-identical
              # (the oracle arm). Only changeable with no live jobs.
              "stream_epoch_pipeline":
                  "on" if self._epoch_pipeline else "off",
-             # freshness & bottleneck attribution (ISSUE 14): the
-             # utilization tricolor, per-MV freshness sampling and
-             # the bottleneck walker; 'off' reduces every hook to a
-             # predicate check (the q7_tricolor_off bench arm)
-             "stream_tricolor": "on",
-             # cost & skew attribution (ISSUE 16): per-MV resource
-             # ledger, state topology upkeep and hot-key sketches;
-             # 'off' reduces every hook to a predicate check (the
-             # q7_costs_off bench arm)
-             "stream_costs": "on",
              # compaction arm (ISSUE 19): 'inline' compacts on the
              # commit path (oracle arm); 'dedicated' moves every merge
              # off-path through the CompactionManager + a background
@@ -159,10 +137,6 @@ class Frontend:
              "storage_compaction": "inline"},
             validators={"stream_rewrite_rules": parse_rules,
                         "stream_fusion": parse_fusion,
-                        "stream_trace": parse_trace,
-                        "stream_ledger": parse_ledger,
-                        "stream_tricolor": _parse_tricolor,
-                        "stream_costs": _parse_costs,
                         "storage_compaction":
                             self._validate_compaction,
                         "stream_epoch_pipeline":
@@ -358,7 +332,6 @@ class Frontend:
             if isinstance(stmt, ast.SetVar) and \
                     stmt.name in ("stream_rewrite_rules",
                                   "stream_fusion",
-                                  "stream_trace",
                                   "state_tier_cap",
                                   "state_tier_soft_limit_mb") and \
                     not self._replaying:
@@ -409,7 +382,7 @@ class Frontend:
         return r
 
     async def step(self, n: int = 1) -> None:
-        """Drive n checkpoint barriers (deterministic test/bench mode)."""
+        """Drive n checkpoint barriers (deterministic test mode)."""
         for _ in range(n):
             await self._barrier(force_checkpoint=True)
 
@@ -506,34 +479,9 @@ class Frontend:
         if isinstance(stmt, ast.Update):
             return await self._update(stmt)
         if isinstance(stmt, ast.SetVar):
+            if self._replaying and stmt.name in _RETIRED_VARS:
+                return "SET"
             self.session_vars.set(stmt.name, stmt.value)
-            if stmt.name == "stream_trace":
-                # runtime toggle, not a CREATE-time knob: flips the
-                # process-global tracer right away (TO DEFAULT → on)
-                from risingwave_tpu.utils import spans as _spans
-                _spans.set_enabled(_spans.parse_trace(
-                    self.session_vars.get("stream_trace")))
-            if stmt.name == "stream_ledger":
-                from risingwave_tpu.utils import ledger as _ledger
-                _ledger.set_enabled(_ledger.parse_ledger(
-                    self.session_vars.get("stream_ledger")))
-            if stmt.name == "stream_tricolor":
-                # one knob for the whole attribution subsystem: the
-                # tricolor bookkeeping AND freshness sampling flip
-                # together (the bench off-arm measures both)
-                from risingwave_tpu.stream import freshness as _fresh
-                from risingwave_tpu.stream import monitor as _monitor
-                on = _monitor.parse_tricolor(
-                    self.session_vars.get("stream_tricolor"))
-                _monitor.set_tricolor(on)
-                _fresh.set_enabled(on)
-            if stmt.name == "stream_costs":
-                # flips the per-MV cost rollup, topology upkeep and
-                # hot-key sketches together (stream/costs.py owns the
-                # fan-out to its sibling flags)
-                from risingwave_tpu.stream import costs as _mvcosts
-                _mvcosts.set_enabled(_mvcosts.parse_costs(
-                    self.session_vars.get("stream_costs")))
             if stmt.name == "storage_compaction":
                 # runtime arm flip (validated above): wires/tears the
                 # dedicated compactor — never rides the DDL log

@@ -18,7 +18,7 @@ autoscaler; concurrent-state discipline per arxiv 1904.03800):
   against the live tricolor (the target fragment's actors must
   actually be busy-dominated) and the per-MV freshness-lag trend (a
   lag already recovering on its own is not scaled). Healthy domains
-  produce zero decisions — the bench's q7 neighbor proof.
+  produce zero decisions.
 - **Per-MV cooldown.** After any completed action (applied OR rolled
   back) the MV is untouchable for ``cooldown_s`` — scaling decisions
   must observe their own consequences before acting again.
@@ -49,6 +49,7 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Callable, Deque, Dict, List, Optional, Tuple
 
+from risingwave_tpu.common.errors import PlanError
 from risingwave_tpu.utils.metrics import CLUSTER as _METRICS
 
 # outcomes recorded in the decision ledger
@@ -65,7 +66,6 @@ def parse_autoscale(spec: str) -> bool:
         return True
     if s in ("off", "false", "0"):
         return False
-    from risingwave_tpu.frontend.planner import PlanError
     raise PlanError(f"stream_autoscale must be on|off, got {spec!r}")
 
 
@@ -253,9 +253,6 @@ class Autoscaler:
         # serving round closes the storm window only after a SUCCESS —
         # a rollback keeps the backoff armed (note_healthy contract)
         self._last_outcome = ""
-        # wall durations of completed actions (the serving stall each
-        # rescale cost — the bench lane's p99-during-rescale source)
-        self.action_durations_s: List[float] = []
 
     # -- serving-loop hooks --------------------------------------------
     def note_healthy(self) -> None:
@@ -534,7 +531,6 @@ class Autoscaler:
     def _finish(self, d: dict, outcome: str, floor: int, t0: float,
                 detail: str) -> AutoscaleEvent:
         dur = self.monotonic() - t0
-        self.action_durations_s.append(dur)
         self._cooldown_until[d["mv"]] = \
             self.monotonic() + self.cfg.cooldown_s
         self._last_outcome = outcome

@@ -49,7 +49,7 @@ class BarrierWedgedError(RuntimeError):
 class BarrierStats:
     """Collected per-epoch latencies (meta barrier_latency metric
     analog). A multi-domain plane shares ONE stats object so the
-    aggregate list keeps its historical meaning (bench warm-trims
+    aggregate list keeps its historical meaning (warm-up trims
     assign it in place); per-domain p99 lives on the PROFILER
     (``EpochProfiler.p99_by_domain`` — ``drop_first`` trims it in
     step with the aggregate), never here, so the two views cannot
@@ -140,8 +140,8 @@ class EpochProfiler:
         self.slow_threshold_s = slow_threshold_s
         # bounded: profiles carry dicts and await dumps, and a 250ms
         # heartbeat would append ~345k/day unbounded. 64k epochs keep
-        # rw_barrier_latency 1:1 with BarrierStats for any bench or
-        # test run (they trim warmup from the front of both) while a
+        # rw_barrier_latency 1:1 with BarrierStats for any test run
+        # (they trim warmup from the front of both) while a
         # long-lived server just loses the oldest profiles.
         self.profiles: Deque[EpochProfile] = deque(maxlen=capacity)
         # baseline at profiler birth: the registry is process-global,
@@ -192,7 +192,7 @@ class EpochProfiler:
         return prof
 
     def drop_first(self, n: int) -> None:
-        """Discard the oldest n profiles (bench warmup epochs: the
+        """Discard the oldest n profiles (warmup epochs: the
         trace-compile outliers must not masquerade as the steady-state
         p99 the same result line reports)."""
         for _ in range(min(n, len(self.profiles))):
@@ -211,9 +211,9 @@ class EpochProfiler:
                 for p in self.profiles]
 
     def p99_by_domain(self) -> Dict[str, float]:
-        """Per-domain p99 barrier total over the retained profiles —
-        the multi-MV bench lane's per-domain breakdown source (the
-        warmup trim via ``drop_first`` applies to this view too)."""
+        """Per-domain p99 barrier total over the retained profiles
+        (the warmup trim via ``drop_first`` applies to this view
+        too)."""
         by: Dict[str, List[float]] = {}
         for p in self.profiles:
             by.setdefault(p.domain, []).append(p.total_s)
@@ -225,10 +225,9 @@ class EpochProfiler:
 
     def p99_breakdown(self) -> Dict[str, float]:
         """Per-phase p99 over the profiled barriers. An EMPTY deque —
-        a fresh loop, or a bench whose warmup trim consumed every
-        profile (drop_first(n) with n ≥ len) — yields all-zero phases,
-        never an exception: bench snapshot assembly runs after exactly
-        that trim and must not die on a short run."""
+        a fresh loop, or one whose warmup trim consumed every profile
+        (drop_first(n) with n ≥ len) — yields all-zero phases, never
+        an exception."""
         profs = list(self.profiles)
         if not profs:
             return {"inject_to_collect_s": 0.0,
@@ -275,19 +274,18 @@ def record_checkpoint_tail(profs: List[EpochProfile],
                     float(counts["write_bytes"])
         for prof in profs:
             HISTORY.amend(prof.epoch, values)
-    if _spans.enabled():
-        # the async checkpoint tail (seal→durable commit), overlapped
-        # with younger barriers — traced under the barrier that SEALED
-        # it so the overlap is visible, its stages below it
-        epoch = profs[0].epoch
-        tail = _spans.EPOCH_TRACER.record(
-            "checkpoint.upload", "upload", epoch=epoch,
-            start_s=time.time() - upload_s, dur_s=upload_s,
-            committed_epoch=committed_epoch)
-        for name, start, dur, counts in stages:
-            _spans.EPOCH_TRACER.record(
-                name, "upload", epoch=epoch, start_s=start, dur_s=dur,
-                parent=tail, **counts)
+    # the async checkpoint tail (seal→durable commit), overlapped
+    # with younger barriers — traced under the barrier that SEALED
+    # it so the overlap is visible, its stages below it
+    epoch = profs[0].epoch
+    tail = _spans.EPOCH_TRACER.record(
+        "checkpoint.upload", "upload", epoch=epoch,
+        start_s=time.time() - upload_s, dur_s=upload_s,
+        committed_epoch=committed_epoch)
+    for name, start, dur, counts in stages:
+        _spans.EPOCH_TRACER.record(
+            name, "upload", epoch=epoch, start_s=start, dur_s=dur,
+            parent=tail, **counts)
 
 
 class VirtualClock:
@@ -379,7 +377,7 @@ class BarrierLoop:
         # instead of wedging the whole control loop silently.
         self.collect_timeout_s = collect_timeout_s
         # a plane shares ONE stats/profiler across its domain loops so
-        # the aggregate surfaces (bench warm-trim, rw_barrier_latency)
+        # the aggregate surfaces (warm-up trim, rw_barrier_latency)
         # keep working; standalone loops own theirs as before
         self.stats = stats if stats is not None else BarrierStats()
         self.profiler = profiler if profiler is not None \
@@ -503,11 +501,10 @@ class BarrierLoop:
         # here. Dispatch spans recorded between barriers attribute to
         # the newest injected epoch (utils/spans.py docstring).
         _spans.set_current_epoch(curr.value)
-        if _spans.enabled():
-            root = _spans.EPOCH_TRACER.record(
-                "barrier.inject", "barrier", epoch=curr.value,
-                kind=kind.value)
-            _spans.EPOCH_TRACER.set_root(curr.value, root)
+        root = _spans.EPOCH_TRACER.record(
+            "barrier.inject", "barrier", epoch=curr.value,
+            kind=kind.value)
+        _spans.EPOCH_TRACER.set_root(curr.value, root)
         self._inject_times[curr.value] = self.monotonic()
         self._in_flight.append(curr.value)
         STREAMING.barrier_in_flight.set(len(self._in_flight))
@@ -617,7 +614,6 @@ class BarrierLoop:
         t0 = self._inject_times.pop(epoch, None)
         prof = None
         seal_rec = None
-        seal_interval = None
         if t0 is not None:
             lat = self.monotonic() - t0
             self.stats.observe(lat, self.domain)
@@ -631,88 +627,83 @@ class BarrierLoop:
                 in_flight=len(self._in_flight),
                 collect_times=collect_times,
                 domain=self.domain)
-            if _spans.enabled():
-                now = time.time()
-                _spans.EPOCH_TRACER.record(
-                    "barrier.collect", "barrier", epoch=epoch,
-                    start_s=now - prof.total_s,
-                    dur_s=prof.inject_to_collect_s,
-                    in_flight=prof.in_flight,
-                    **({"domain": self.domain} if self.domain else {}))
-                _spans.EPOCH_TRACER.record(
-                    "barrier.commit", "commit", epoch=epoch,
-                    start_s=now - prof.collect_to_commit_s,
-                    dur_s=prof.collect_to_commit_s, kind=prof.kind,
-                    **({"domain": self.domain} if self.domain else {}))
-                if prof.total_s >= self.profiler.slow_threshold_s:
-                    # slow-barrier watchdog: the flight ring rolls in
-                    # EPOCH_WINDOW barriers — promote the outlier's
-                    # full trace into the retained store NOW, with its
-                    # one-line straggler attribution
-                    diag = _spans.EPOCH_TRACER.diagnose(
-                        epoch, prof.total_s)
-                    _spans.EPOCH_TRACER.promote(epoch, diag,
-                                                prof.total_s)
-                    print(f"slow barrier: {diag}", file=sys.stderr)
-            if _ledger.enabled():
-                # seal the epoch's phase books against the measured
-                # interval (residual → unattributed, metrics history
-                # row, Perfetto phase lanes). Virtual-clock loops
-                # DISCARD instead: the simulated interval and the
-                # wall-clock phases live on different clocks, so a
-                # conservation check there would be noise
-                if self.monotonic is time.monotonic:
-                    # the conservation interval ends when the LAST
-                    # actor collected (its wall stamp), not when this
-                    # coroutine got scheduled — the wake gap is event-
-                    # loop time during which actors already run the
-                    # NEXT epoch's pulls, which the ledger rightly
-                    # attributes to the next epoch. It STARTS at the
-                    # previous epoch's collect stamp when that is
-                    # later than this inject (pipelined injection:
-                    # queueing behind an older epoch is that epoch's
-                    # wall time, already on its books).
-                    t_true = max(collect_times.values(),
-                                 default=t_collect)
-                    last = self._last_seal_stamp
-                    start = t0 if last is None else max(t0, last)
-                    interval = max(0.0, t_true - start) \
-                        + prof.collect_to_commit_s
-                    # the next epoch's books open where this one's
-                    # close — AFTER the commit half, which this
-                    # interval already claims (a stall there must not
-                    # land on two epochs' books)
-                    self._last_seal_stamp = \
-                        t_true + prof.collect_to_commit_s
-                    seal_rec = _ledger.LEDGER.seal(
-                        epoch, interval, prof.kind,
-                        # the wake gap is the next epoch's wall time:
-                        # LOOP-phase sections in it wait for its seal
-                        wake_gap=(t_true, t_collect),
-                        # and what held the loop between the last
-                        # books closing and this inject is this epoch's
-                        between=(last, t0)
-                        if last is not None and t0 > last else None,
-                        # remote pseudo-actors ⇒ actor work ran in
-                        # other processes: conservation defers to the
-                        # drain_ledger merge (auto-detected so bare
-                        # coordinator loops in tests behave too)
-                        distributed=self.distributed
-                        or self.local.has_remote_participants(),
-                        # mutation barriers (deploy/stop/reschedule)
-                        # do topology work no phase claims — exempt
-                        warmup=barrier.mutation is not None,
-                        domain=self.domain)
-                    seal_interval = seal_rec.interval_s \
-                        if seal_rec is not None else interval
-                else:
-                    _ledger.LEDGER.discard(epoch)
+            now = time.time()
+            _spans.EPOCH_TRACER.record(
+                "barrier.collect", "barrier", epoch=epoch,
+                start_s=now - prof.total_s,
+                dur_s=prof.inject_to_collect_s,
+                in_flight=prof.in_flight,
+                **({"domain": self.domain} if self.domain else {}))
+            _spans.EPOCH_TRACER.record(
+                "barrier.commit", "commit", epoch=epoch,
+                start_s=now - prof.collect_to_commit_s,
+                dur_s=prof.collect_to_commit_s, kind=prof.kind,
+                **({"domain": self.domain} if self.domain else {}))
+            if prof.total_s >= self.profiler.slow_threshold_s:
+                # slow-barrier watchdog: the flight ring rolls in
+                # EPOCH_WINDOW barriers — promote the outlier's
+                # full trace into the retained store NOW, with its
+                # one-line straggler attribution
+                diag = _spans.EPOCH_TRACER.diagnose(
+                    epoch, prof.total_s)
+                _spans.EPOCH_TRACER.promote(epoch, diag,
+                                            prof.total_s)
+                print(f"slow barrier: {diag}", file=sys.stderr)
+            # seal the epoch's phase books against the measured
+            # interval (residual → unattributed, metrics history
+            # row, Perfetto phase lanes). Virtual-clock loops
+            # DISCARD instead: the simulated interval and the
+            # wall-clock phases live on different clocks, so a
+            # conservation check there would be noise
+            if self.monotonic is time.monotonic:
+                # the conservation interval ends when the LAST
+                # actor collected (its wall stamp), not when this
+                # coroutine got scheduled — the wake gap is event-
+                # loop time during which actors already run the
+                # NEXT epoch's pulls, which the ledger rightly
+                # attributes to the next epoch. It STARTS at the
+                # previous epoch's collect stamp when that is
+                # later than this inject (pipelined injection:
+                # queueing behind an older epoch is that epoch's
+                # wall time, already on its books).
+                t_true = max(collect_times.values(),
+                             default=t_collect)
+                last = self._last_seal_stamp
+                start = t0 if last is None else max(t0, last)
+                interval = max(0.0, t_true - start) \
+                    + prof.collect_to_commit_s
+                # the next epoch's books open where this one's
+                # close — AFTER the commit half, which this
+                # interval already claims (a stall there must not
+                # land on two epochs' books)
+                self._last_seal_stamp = \
+                    t_true + prof.collect_to_commit_s
+                seal_rec = _ledger.LEDGER.seal(
+                    epoch, interval, prof.kind,
+                    # the wake gap is the next epoch's wall time:
+                    # LOOP-phase sections in it wait for its seal
+                    wake_gap=(t_true, t_collect),
+                    # and what held the loop between the last
+                    # books closing and this inject is this epoch's
+                    between=(last, t0)
+                    if last is not None and t0 > last else None,
+                    # remote pseudo-actors ⇒ actor work ran in
+                    # other processes: conservation defers to the
+                    # drain_ledger merge (auto-detected so bare
+                    # coordinator loops in tests behave too)
+                    distributed=self.distributed
+                    or self.local.has_remote_participants(),
+                    # mutation barriers (deploy/stop/reschedule)
+                    # do topology work no phase claims — exempt
+                    warmup=barrier.mutation is not None,
+                    domain=self.domain)
+            else:
+                _ledger.LEDGER.discard(epoch)
             # bottleneck walk (ISSUE 14): one candidate per domain per
             # barrier off the just-published utilization tricolor,
             # cross-checked against the sealed phase record. Wall-clock
             # loops only — virtual-clock ratios would be meaningless.
-            from risingwave_tpu.stream import monitor as _monitor
-            if _monitor.TRICOLOR and barrier.mutation is None \
+            if barrier.mutation is None \
                     and self.monotonic is time.monotonic:
                 # mutation barriers (deploy/stop/reschedule) do
                 # topology work, not epoch work — walking them would
@@ -724,11 +715,8 @@ class BarrierLoop:
                     fragments = set(jobs) if jobs else None
                 BOTTLENECKS.observe(
                     epoch=epoch, domain=self.domain,
-                    interval_s=(seal_interval
-                                if seal_interval is not None
-                                else prof.total_s),
-                    phase_seconds=(seal_rec.seconds
-                                   if seal_rec is not None else None),
+                    interval_s=seal_rec.interval_s,
+                    phase_seconds=seal_rec.seconds,
                     fragments=fragments)
         if prev > 0 and barrier.is_checkpoint:
             if self._plane is not None:

@@ -35,6 +35,7 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Callable, Deque, Dict, List, Optional
 
+from risingwave_tpu.common.errors import PlanError
 from risingwave_tpu.utils.metrics import STORAGE as _METRICS
 
 # -- picker thresholds --------------------------------------------------
@@ -392,7 +393,6 @@ def parse_compaction(spec: str) -> str:
     (PlanError so a typo fails the SET, not a later commit)."""
     s = str(spec).strip().lower()
     if s not in ("inline", "dedicated"):
-        from risingwave_tpu.frontend.planner import PlanError
         raise PlanError(
             f"storage_compaction must be 'inline' or 'dedicated', "
             f"got {spec!r}")

@@ -46,6 +46,7 @@ import time
 from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
 
 from risingwave_tpu.common.epoch import Epoch
+from risingwave_tpu.common.errors import PlanError
 from risingwave_tpu.meta.barrier import (
     BarrierLoop, BarrierStats, EpochProfile, EpochProfiler,
     record_checkpoint_tail,
@@ -61,7 +62,6 @@ def parse_epoch_pipeline(spec: str) -> bool:
         return True
     if s in ("off", "false", "0"):
         return False
-    from risingwave_tpu.frontend.planner import PlanError
     raise PlanError(
         f"stream_epoch_pipeline must be on|off, got {spec!r}")
 
@@ -172,7 +172,7 @@ class BarrierPlane:
 
     Exposes the ``BarrierLoop`` driving surface (``inject_and_collect``
     / ``inject`` / ``collect_next`` / ``stats`` / ``profiler`` /
-    ``uploader`` / ``committed_epoch``) so sessions, benches and tests
+    ``uploader`` / ``committed_epoch``) so sessions and tests
     that held a loop hold a plane unchanged. Plain rounds run every
     domain CONCURRENTLY — a slow domain's collect no longer serializes
     its neighbors' rounds — and every ``checkpoint_frequency``-th round
@@ -585,7 +585,7 @@ class BarrierPlane:
     async def checkpoint(self) -> Barrier:
         return await self.inject_and_collect(force_checkpoint=True)
 
-    # -- pipelined driving (bench/tests) -------------------------------
+    # -- pipelined driving (tests) -------------------------------------
     def _cadence_checkpoint(self, d: _Domain,
                             force_checkpoint: bool) -> bool:
         """Per-domain checkpoint cadence for pipelined injection:
@@ -705,7 +705,7 @@ class BarrierPlane:
         return self.profiler.p99_by_domain()
 
     def describe(self) -> List[dict]:
-        """One dict per domain (bench/result surfaces and tests)."""
+        """One dict per domain."""
         return [{
             "domain": d.name,
             "jobs": sorted(d.jobs),

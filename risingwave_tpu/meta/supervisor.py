@@ -237,24 +237,20 @@ class RecoverySupervisor:
 
 
 def trace_recovery_root(cause: str, action: str, epoch: int,
-                        attempt: int) -> Optional[int]:
+                        attempt: int) -> int:
     """Open the recovery.* span chain under the recovered-to epoch —
     the causal trace a post-mortem walks from rw_recovery into
-    rw_epoch_trace. Returns the root span id (None when tracing is
-    off); phases record children with parent=root."""
-    if not _spans.enabled():
-        return None
+    rw_epoch_trace. Returns the root span id; phases record
+    children with parent=root."""
     return _spans.EPOCH_TRACER.record(
         "recovery.supervised", "recovery", epoch=epoch,
         cause=cause, action=action, attempt=attempt)
 
 
-def trace_recovery_phase(name: str, epoch: int, parent: Optional[int],
+def trace_recovery_phase(name: str, epoch: int, parent: int,
                          start_s: float, dur_s: float, **args) -> None:
     """One recovery phase span (recovery.respawn / recovery.reset /
     recovery.handshake / recovery.redeploy), parented to the root."""
-    if not _spans.enabled():
-        return
     _spans.EPOCH_TRACER.record(
         f"recovery.{name}", "recovery", epoch=epoch, parent=parent,
         start_s=start_s, dur_s=dur_s, **args)

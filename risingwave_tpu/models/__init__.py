@@ -1,9 +1,8 @@
 """Hand-built streaming query plans (the "model zoo" of this framework).
 
 Until the SQL frontend's planner/fragmenter lands, these builders are the
-canonical executable plans for the headline Nexmark queries — shared by
-the e2e tests and bench.py so the benchmarked pipeline is exactly the
-tested pipeline.
+canonical executable plans for the headline Nexmark queries, used by
+the e2e tests.
 """
 
 from risingwave_tpu.models.nexmark import (  # noqa: F401

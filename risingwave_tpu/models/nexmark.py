@@ -4,8 +4,7 @@ device), q8 (windowed join on device).
 Reference parity: e2e_test/streaming/nexmark/q1|q7|q8 semantics; plan
 shapes mirror what the reference's fragmenter produces for these queries
 (src/frontend/src/stream_fragmenter/mod.rs) — hand-assembled here until
-the SQL frontend lands. Used by BOTH tests/test_e2e_q*.py and bench.py:
-the benchmarked pipeline is exactly the tested pipeline.
+the SQL frontend lands. Used by tests/test_e2e_q*.py.
 """
 
 from __future__ import annotations
@@ -68,8 +67,8 @@ def _source(local: LocalBarrierManager, store, actor_id: int,
 def _register_freshness(mat: MaterializeExecutor, fragment: str) -> None:
     """Freshness lineage (stream/freshness.py) for a hand-built
     pipeline: name the MV after its fragment and bind the chain's
-    source executors' ingest frontiers — the benched pipeline reports
-    per-MV lag exactly like a SQL-deployed one."""
+    source executors' ingest frontiers — the hand-built pipeline
+    reports per-MV lag exactly like a SQL-deployed one."""
     from risingwave_tpu.stream.executor import executor_children
     from risingwave_tpu.stream.freshness import FRESHNESS
     mat.mv_name = fragment
@@ -93,8 +92,7 @@ def _finish(local: LocalBarrierManager, store, mat: MaterializeExecutor,
     _register_freshness(mat, fragment)
     if fusion:
         # fragment fusion (frontend/opt/fusion.py): same rule the SQL
-        # sessions apply under SET stream_fusion — the benched
-        # pipeline stays exactly the tested pipeline
+        # sessions apply under SET stream_fusion
         from risingwave_tpu.frontend.opt import rewrite_stream_plan
         mat, _report = rewrite_stream_plan(mat, "none", record=False,
                                            fusion=True)
@@ -192,7 +190,7 @@ def build_q7(store, cfg: NexmarkConfig,
         from risingwave_tpu.stream.coalesce import CoalesceExecutor
         agg_in = CoalesceExecutor(project, coalesce_rows)
     # tier_cap: resident-group cap for the state-tiering oracle tests
-    # and bench parity runs (state/tier.py; single-chip only)
+    # (state/tier.py; single-chip only)
     agg = HashAggExecutor(agg_in, [0], calls, agg_state,
                           append_only=True,
                           output_names=["max_price", "bid_count"],

@@ -1,8 +1,8 @@
 """32-bit lane codecs: how 64-bit logical values live on the TPU.
 
 TPU v5e has no native int64; XLA's x64-rewrite emulates it, and emulated
-64-bit *scatter* is catastrophically slow (measured ~1000x vs int32 on the
-chip this project benches on). The device side of every stateful kernel
+64-bit *scatter* is catastrophically slow (measured ~1000x vs int32 on
+that chip). The device side of every stateful kernel
 therefore speaks int32/float32 exclusively; 64-bit logical values are
 (de)composed on the host with vectorized numpy. Three codecs:
 

@@ -46,7 +46,7 @@ from risingwave_tpu.utils.ledger import LEDGER
 AXIS = "d"
 
 # Compiled SPMD programs shared ACROSS kernel instances (fresh
-# sessions, twin MVs and bench re-runs reuse traces instead of paying
+# sessions and twin MVs reuse traces instead of paying
 # warmup compiles on the p99 tail — the join's _STEP_CACHE scheme).
 # Keyed by (mesh device ids, program kind + statics, key_width,
 # specs); jit shape-keys per state capacity internally. A CompileCache
@@ -167,7 +167,7 @@ class ShardedAggKernel:
     # buffering and the int32 limb math), else once at the barrier
     # flush — O(1) SPMD dispatches per epoch instead of one per chunk
     # (each shard_map host dispatch costs ~100ms through the 4-virtual-
-    # device CPU mesh, BENCH_r09's whole ad-ctr tail). The FIXED batch
+    # device CPU mesh). The FIXED batch
     # shape also means one compiled program instead of per-chunk-shape
     # churn — the RecompileGuard's sharded contract.
     BATCH_ROWS = 1 << 15

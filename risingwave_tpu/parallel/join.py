@@ -578,8 +578,8 @@ class ShardedJoinKernel:
     # kernels locally with PER-ROW sequences (sequence visibility makes
     # the batched application order-equivalent to per-chunk applies).
     # On the 4-virtual-device CPU mesh each shard_map host dispatch
-    # costs ~100ms (BENCH_r09: the whole ad-ctr p99 tail) — this drops
-    # the count by the chunks-per-epoch factor.
+    # costs ~100ms — this drops the count by the chunks-per-epoch
+    # factor.
 
     def _guard_keys_blind(self, n_ins: int) -> None:
         """Conservative key guard when host key lanes are unavailable

@@ -62,8 +62,7 @@ class _Table:
     The key index is LAZILY sorted: puts append (O(1)) and set a dirty
     flag; the first ordered read re-sorts. Timsort on a sorted prefix +
     appended tail is near O(n) — while ``bisect.insort`` per new key is
-    O(n) EACH, which made streaming ingest quadratic in table size (the
-    r3 join benches spent most of their p99 barrier here)."""
+    O(n) EACH, which made streaming ingest quadratic in table size."""
 
     __slots__ = ("keys", "versions", "_dirty")
 

@@ -32,16 +32,6 @@ import threading
 from itertools import repeat
 from typing import Dict, Iterable, List, Optional, Tuple
 
-# one knob for the whole attribution subsystem (SET stream_costs):
-# costs rollup, hot-key sketches and topology upkeep flip together
-ENABLED = True
-
-
-def set_enabled(on: bool) -> None:
-    global ENABLED
-    ENABLED = bool(on)
-
-
 # value-size model (EstimateSize analog): fixed-width physical scalars
 # are 8B + 1B tag; host-typed values charge their length. The model is
 # stable across insert/overwrite of the same schema, which is what
@@ -99,7 +89,7 @@ class StateTopology:
     # -- maintenance (StateTable.commit hot path) -----------------------
     def record(self, table_id: int, keys: List[bytes], vals: List,
                fixed_nbytes: Optional[int] = None) -> None:
-        if not ENABLED or not keys:
+        if not keys:
             return
         with self._lock:
             if table_id not in self._mv_of:
@@ -248,8 +238,8 @@ class StateTopology:
         return out
 
     def imbalance_by_mv(self) -> Dict[str, float]:
-        """Worst per-table hot-vnode ratio per MV (the bench
-        marginal_cost block's aggregate skew signal)."""
+        """Worst per-table hot-vnode ratio per MV (`ctl cost`'s
+        aggregate skew signal)."""
         out: Dict[str, float] = {}
         for _t, mv, _nrows, _nbytes, _vns, imb in self.table_stats():
             out[mv] = max(out.get(mv, 1.0), imb)

@@ -36,8 +36,7 @@ operator that never dispatches kernels is flagged as a mismatch
 over it).
 
 Surfaces: the ``rw_bottlenecks`` system table,
-``stream_bottleneck_streak{domain,operator}``, the bench
-``bottleneck`` block per lane, and ``ctl top``.
+``stream_bottleneck_streak{domain,operator}``, and ``ctl top``.
 """
 
 from __future__ import annotations
@@ -362,7 +361,7 @@ class BottleneckAnalyzer:
                                           r[0]))
 
     def summary(self) -> Dict[str, dict]:
-        """Per-domain block for bench lanes and ctl top."""
+        """Per-domain block for ctl top."""
         out: Dict[str, dict] = {}
         for (domain, op, fragment, actor, node, busy, bp, streak,
              sustained, epoch, diag) in self.rows():

@@ -1,10 +1,10 @@
 """Chunk compaction + adaptive coalescing for the streaming spine.
 
-Motivation (BENCH r5 / VERDICT r5): masked dispatch and per-chunk
-device dispatch drown the hot path in sparse slivers — a parallelism-4
-hash dispatch hands every downstream a full-capacity chunk that is
-~1/4 visible, which then pays full exchange credit, full wire bytes
-and a full ~2ms pjit dispatch per sliver. Hazelcast Jet
+Motivation: masked dispatch and per-chunk device dispatch drown the
+hot path in sparse slivers — a parallelism-4 hash dispatch hands every
+downstream a full-capacity chunk that is ~1/4 visible, which then pays
+full exchange credit, full wire bytes and a full ~2ms pjit dispatch
+per sliver. Hazelcast Jet
 (arXiv:2103.10169) and TiLT (arXiv:2301.12030) both land on the same
 discipline: amortize per-item overheads by keeping every batch dense
 and right-sized. This module is that discipline for StreamChunks:

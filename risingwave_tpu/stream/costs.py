@@ -34,31 +34,6 @@ from collections import deque
 from contextvars import ContextVar
 from typing import Dict, List, Optional, Tuple
 
-# one knob for the whole attribution subsystem (SET stream_costs):
-# per-MV rollup, hot-key sketches and state topology flip together —
-# the q7_costs_off bench arm measures every hook reduced to a
-# predicate check
-ENABLED = True
-
-
-def enabled() -> bool:
-    return ENABLED
-
-
-def set_enabled(on: bool) -> None:
-    global ENABLED
-    ENABLED = bool(on)
-    from risingwave_tpu.state import topology as _topo
-    from risingwave_tpu.stream import hotkeys as _hot
-    _topo.set_enabled(on)
-    _hot.set_enabled(on)
-
-
-def parse_costs(spec: str) -> bool:
-    s = (spec or "").strip().lower()
-    return s not in ("off", "0", "false", "none")
-
-
 # the MV whose executor chain is currently pulling (set by
 # MonitoredExecutor around inner pulls — asyncio-context scoped, so
 # interleaved actors never cross-bill a compile)
@@ -93,13 +68,12 @@ class CompileCache(dict):
 
     def get(self, key, default=None):
         step = super().get(key, default)
-        if step is not None and ENABLED:
+        if step is not None:
             COSTS.note_compile(self.kind, key, hit=True)
         return step
 
     def __setitem__(self, key, step) -> None:
-        if ENABLED:
-            COSTS.note_compile(self.kind, key, hit=False)
+        COSTS.note_compile(self.kind, key, hit=False)
         super().__setitem__(key, step)
 
 
@@ -137,8 +111,6 @@ class MVCosts:
         """One executor cell's device share at barrier flush (called
         by MonitoredExecutor BEFORE the cell commits to the phase
         ledger — same numbers, split by owner)."""
-        if not ENABLED:
-            return
         if device_s <= 0 and not h2d_bytes and not d2h_bytes:
             return
         with self._lock:
@@ -171,8 +143,6 @@ class MVCosts:
         totals, publish the Prometheus families, retain the gate row,
         and return ``mv_device_s.<mv>`` entries for the
         rw_metrics_history row the seal is about to write."""
-        if not ENABLED:
-            return {}
         with self._lock:
             cells = self._pending.pop(rec.epoch, None) or {}
             extra: Dict[str, float] = {}
@@ -210,8 +180,6 @@ class MVCosts:
     def publish_state_bytes(self) -> None:
         """Refresh the stream_mv_state_bytes gauge from the topology
         books (checkpoint cadence — state only moves at checkpoints)."""
-        if not ENABLED:
-            return
         from risingwave_tpu.state.topology import TOPOLOGY
         from risingwave_tpu.utils.metrics import STREAMING
         for mv, nbytes in TOPOLOGY.bytes_by_mv().items():
@@ -270,7 +238,7 @@ class MVCosts:
         return rows
 
     def summary(self) -> Dict[str, dict]:
-        """mv -> totals dict (the bench marginal_cost block)."""
+        """mv -> totals dict."""
         from risingwave_tpu.state.topology import TOPOLOGY
         state = TOPOLOGY.bytes_by_mv()
         with self._lock:
@@ -281,8 +249,8 @@ class MVCosts:
     def coverage(self) -> Tuple[float, float]:
         """(attributed_device_s, ledgered_device_s) summed over the
         sealed-epoch window — BOTH sides windowed identically
-        (``SEALED_WINDOW`` epochs), so the ratio is the bench's
-        attribution-coverage claim. Comparing the cumulative per-MV
+        (``SEALED_WINDOW`` epochs), so the ratio is the share of the
+        ledgered device time an MV owns. Comparing the cumulative per-MV
         totals against the ledger's bounded record deque instead
         would inflate past 1.0 as records age out."""
         with self._lock:

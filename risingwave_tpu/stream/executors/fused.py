@@ -73,8 +73,8 @@ class FusedFragmentExecutor(Executor):
         if host_same is None:
             host_same = np.ones(chunk.capacity, dtype=bool)
         # one jitted chain step per chunk IS a device dispatch — count
-        # it (ISSUE 9 bench honesty: absorbing a run into a keyed
-        # executor's epoch dispatches must show up as a drop here)
+        # it (absorbing a run into a keyed executor's epoch
+        # dispatches must show up as a drop here)
         from risingwave_tpu.utils.metrics import STREAMING
         card = float(chunk.cardinality())
         STREAMING.device_dispatch.inc(1, executor=self.identity)

@@ -529,8 +529,8 @@ class HashAggExecutor(Executor):
             # matrix; filter/project/key-encode/lane-encode all run
             # inside the kernel's jitted apply. Dispatch metrics are
             # counted by the kernel at REAL dispatch sites (one per
-            # backlog flush), not per chunk — that granularity IS the
-            # fusion win the bench compares.
+            # backlog flush), not per chunk: one dispatch per backlog
+            # flush is what fusion buys.
             from risingwave_tpu.ops.fused import encode_raw_chunk
             raw = encode_raw_chunk(chunk, self.fused_stages.ref_cols)
             # when the group keys map to raw input columns, host-side
@@ -542,12 +542,11 @@ class HashAggExecutor(Executor):
                               False)
             raw_keys = self._fused_raw_key_cols
             lanes = None
-            if raw_keys is not None and (sharded or _hotkeys.ENABLED):
+            if raw_keys is not None:
                 lanes = self.key_codec.build(chunk, raw_keys)
-                if _hotkeys.ENABLED:
-                    _hotkeys.HOTKEYS.observe(
-                        self.identity, lanes,
-                        np.asarray(chunk.visibility), self.key_codec)
+                _hotkeys.HOTKEYS.observe(
+                    self.identity, lanes,
+                    np.asarray(chunk.visibility), self.key_codec)
             if sharded:
                 owners = None if lanes is None \
                     else self.kernel.owners_of(lanes)
@@ -559,12 +558,11 @@ class HashAggExecutor(Executor):
         key_lanes = self.key_codec.build(chunk, self.group_indices)
         signs = np.asarray(chunk.signs())
         vis = np.asarray(chunk.visibility)
-        if _hotkeys.ENABLED:
-            # heavy-hitter sketch over the agg's group keys: the lanes
-            # are already built for the kernel — the sketch adds one
-            # hash+unique pass over the visible rows
-            _hotkeys.HOTKEYS.observe(self.identity, key_lanes, vis,
-                                     self.key_codec)
+        # heavy-hitter sketch over the agg's group keys: the lanes
+        # are already built for the kernel — the sketch adds one
+        # hash+unique pass over the visible rows
+        _hotkeys.HOTKEYS.observe(self.identity, key_lanes, vis,
+                                 self.key_codec)
         if self._tier is not None:
             self._tier_touch(key_lanes, vis)
         # one kernel.apply below = one fused device dispatch: the

@@ -1107,17 +1107,16 @@ class HashJoinExecutor(Executor):
         seq = self._seq
         self._seq += 1
         probe_vis = np.asarray(chunk.visibility) & nonnull
-        if _hotkeys.ENABLED:
-            # heavy-hitter sketch per join input ("/0" build, "/1"
-            # probe): unfused sides already built the lanes for the
-            # kernel — the sketch adds one hash+unique pass; a fused
-            # input side derives lanes in-kernel, so the sketch builds
-            # its own host copy from the post-filter chunk
-            sk_lanes = key_lanes if key_lanes is not None \
-                else me.key_codec.build(chunk, me.key_indices)
-            _hotkeys.HOTKEYS.observe(f"{self.identity}/{side_idx}",
-                                     sk_lanes, probe_vis,
-                                     me.key_codec)
+        # heavy-hitter sketch per join input ("/0" build, "/1"
+        # probe): unfused sides already built the lanes for the
+        # kernel — the sketch adds one hash+unique pass; a fused
+        # input side derives lanes in-kernel, so the sketch builds
+        # its own host copy from the post-filter chunk
+        sk_lanes = key_lanes if key_lanes is not None \
+            else me.key_codec.build(chunk, me.key_indices)
+        _hotkeys.HOTKEYS.observe(f"{self.identity}/{side_idx}",
+                                 sk_lanes, probe_vis,
+                                 me.key_codec)
         if self._tier is not None and key_lanes is not None:
             rows = np.flatnonzero(probe_vis)
             if len(rows):

@@ -58,13 +58,12 @@ class MaterializeExecutor(Executor):
                 yield msg
             elif is_barrier(msg):
                 self.table.commit(msg.epoch)
-                if _fresh.enabled():
-                    # everything ingested before this barrier is now
-                    # applied (and commits with its collection): the
-                    # MV's visible event frontier advances to the
-                    # source frontiers recorded at the same barrier
-                    _fresh.FRESHNESS.note_visible(
-                        self.mv_name, msg.epoch.curr.value)
+                # everything ingested before this barrier is now
+                # applied (and commits with its collection): the
+                # MV's visible event frontier advances to the
+                # source frontiers recorded at the same barrier
+                _fresh.FRESHNESS.note_visible(
+                    self.mv_name, msg.epoch.curr.value)
                 yield msg
             else:
                 yield msg

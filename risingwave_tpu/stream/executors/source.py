@@ -69,12 +69,12 @@ class SourceExecutor(Executor):
             reader, "split_id", identity)
         self._event_ts_idx = event_time_index(reader.schema)
         # optional throttle: max chunks generated per barrier interval
-        # (FlowControlExecutor analog, keeps tests/bench deterministic)
+        # (FlowControlExecutor analog, keeps stepped runs deterministic)
         self.rate_limit = rate_limit_chunks_per_barrier
         # optional floor: generate this many chunks per epoch BEFORE
         # letting a waiting barrier win the select. The reference's
         # "barrier always wins" rule assumes barriers arrive on a wall
-        # interval; under back-to-back injection (bench/test driving) it
+        # interval; under back-to-back injection (stepped driving) it
         # starves epochs down to one chunk. The floor restores real
         # epoch sizes deterministically. None = reference behavior.
         self.min_chunks = min_chunks_per_barrier
@@ -172,7 +172,7 @@ class SourceExecutor(Executor):
             # barrier wins the select — except for the FIRST chunk of an
             # epoch, which is generated before looking at the channel.
             # Without that progress guarantee, back-to-back barrier
-            # injection (collect → inject with no interval, the test/bench
+            # injection (collect → inject with no interval, the stepped
             # driving pattern) can starve the stream forever: every
             # try_recv finds the next barrier already waiting.
             barrier: Optional[Barrier] = None
@@ -219,13 +219,12 @@ class SourceExecutor(Executor):
             _METRICS.source_rows.inc(chunk.cardinality(),
                                      source=self.reader.split_id)
             from risingwave_tpu.stream import freshness as _fresh
-            if _fresh.enabled():
-                # ingest high-watermark: one vectorized max over the
-                # chunk's event-time column (arrival-clock fallback
-                # when the schema has none)
-                _fresh.FRESHNESS.note_ingest(
-                    self.freshness_key,
-                    _fresh.chunk_event_hwm(chunk, self._event_ts_idx))
+            # ingest high-watermark: one vectorized max over the
+            # chunk's event-time column (arrival-clock fallback
+            # when the schema has none)
+            _fresh.FRESHNESS.note_ingest(
+                self.freshness_key,
+                _fresh.chunk_event_hwm(chunk, self._event_ts_idx))
             yield chunk
             # yield to the event loop so the barrier injector can run
             await asyncio.sleep(0)

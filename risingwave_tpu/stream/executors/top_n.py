@@ -125,8 +125,8 @@ class GroupTopNExecutor(Executor):
                                        - len(self.order_by)))
         self.groups: Dict[tuple, _SortedRows] = {}
         # fast-key eligibility: native tuples compare in C (an order of
-        # magnitude over _Key.__lt__'s per-column Python loop — the q5
-        # bench's single hottest path); DESC needs numeric negation, so
+        # magnitude over _Key.__lt__'s per-column Python loop, q5's
+        # single hottest path); DESC needs numeric negation, so
         # any DESC column with a non-numeric physical type falls back
         from risingwave_tpu.common.types import DataType
         numeric = {DataType.INT16, DataType.INT32, DataType.INT64,

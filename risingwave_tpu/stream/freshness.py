@@ -35,8 +35,8 @@ produce one coherent per-MV lag series.
 Output surfaces: ``stream_mv_freshness_lag_seconds{mv}`` +
 ``stream_mv_freshness_wall_lag_seconds{mv}`` gauges, the
 ``rw_mv_freshness`` system table, per-barrier ``freshness_lag_s.<mv>``
-rows in ``rw_metrics_history`` (folded in at ledger seal), the bench
-``freshness`` block per lane, and ``ctl top``.
+rows in ``rw_metrics_history`` (folded in at ledger seal), and
+``ctl top``.
 """
 
 from __future__ import annotations
@@ -52,18 +52,6 @@ from typing import Dict, List, Optional, Tuple
 FRONTIER_WINDOW = 512
 SAMPLE_WINDOW = 1024
 PENDING_WINDOW = 256
-
-_ENABLED = True
-
-
-def enabled() -> bool:
-    return _ENABLED
-
-
-def set_enabled(on: bool) -> None:
-    global _ENABLED
-    _ENABLED = bool(on)
-
 
 class _MvState:
     __slots__ = ("sources", "domain", "samples", "last")
@@ -101,8 +89,6 @@ class FreshnessTracker:
         """One chunk ingested: advance the source's event-time high
         watermark (None = no event-time column: arrival wall-clock
         stands in, microseconds)."""
-        if not _ENABLED:
-            return
         now = time.time() if wall_s is None else wall_s
         if hwm_us is None:
             hwm_us = int(now * 1e6)
@@ -118,8 +104,6 @@ class FreshnessTracker:
         so far precedes that barrier. Parallel splits of one source
         each call this — the frontier keeps the MINIMUM hwm (the
         conservative cross-split frontier)."""
-        if not _ENABLED:
-            return
         now = time.time()
         with self._lock:
             hwm = self._hwm.get(source)
@@ -169,8 +153,6 @@ class FreshnessTracker:
         """The MV's materialize executor passed barrier ``epoch``:
         every chunk ingested before that barrier is applied (and
         commits with the barrier's collection)."""
-        if not _ENABLED:
-            return
         now = time.time() if wall_s is None else wall_s
         with self._lock:
             if not self._resolve_locked(mv, epoch, now):
@@ -266,7 +248,7 @@ class FreshnessTracker:
         return out
 
     def summary(self) -> Dict[str, dict]:
-        """Per-MV freshness block (bench lanes, ctl top)."""
+        """Per-MV freshness block (ctl top)."""
         out: Dict[str, dict] = {}
         for (mv, domain, n, _e, lag, wall_lag, p50, p99,
              wall_p99) in self.rows():

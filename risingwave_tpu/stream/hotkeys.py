@@ -28,14 +28,6 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-ENABLED = True
-
-
-def set_enabled(on: bool) -> None:
-    global ENABLED
-    ENABLED = bool(on)
-
-
 # sketch capacity (k) and the per-chunk unique-key merge cap (top-m by
 # chunk count; dropping the chunk's own cold tail below m cannot demote
 # a sustained heavy hitter)
@@ -138,7 +130,7 @@ class HotKeys:
 
     # -- hot path -------------------------------------------------------
     def observe(self, identity: str, key_lanes, vis, codec) -> None:
-        if not ENABLED or key_lanes is None:
+        if key_lanes is None:
             return
         with self._lock:
             sk = self._sketches.get(identity)

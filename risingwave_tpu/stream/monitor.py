@@ -52,29 +52,6 @@ def set_strict_empty_chunks(on: bool) -> None:
     STRICT_EMPTY_CHUNKS = bool(on)
 
 
-# utilization tricolor toggle (ISSUE 14): SET stream_tricolor = off
-# reduces the per-barrier ratio bookkeeping (and the per-pull park-cell
-# context swap) to a predicate check — the observability-tax control
-# arm the bench's q7_tricolor_off lane measures.
-TRICOLOR = True
-
-
-def set_tricolor(on: bool) -> None:
-    global TRICOLOR
-    TRICOLOR = bool(on)
-
-
-def parse_tricolor(spec: str) -> bool:
-    """'on'|'off' → bool (SET stream_tricolor validator)."""
-    s = str(spec).strip().lower()
-    if s in ("on", "true", "1"):
-        return True
-    if s in ("off", "false", "0"):
-        return False
-    from risingwave_tpu.frontend.planner import PlanError
-    raise PlanError(f"stream_tricolor must be on|off, got {spec!r}")
-
-
 class UtilizationTable:
     """Last-barrier utilization tricolor per (fragment, actor, node):
     busy / backpressure / idle shares of the barrier interval — the
@@ -327,71 +304,61 @@ class MonitoredExecutor(Executor):
                 self._mark_meter = meter[0]
         _METRICS.executor_busy.inc(excl, **self.labels)
         _METRICS.executor_epoch_seconds.observe(excl, **self.labels)
-        if TRICOLOR:
-            # utilization tricolor: busy / backpressure / idle shares
-            # of THIS node's barrier-to-barrier interval (its own
-            # flush-to-flush wall clock — all three parts are disjoint
-            # wall time inside it, so the triple sums to ≤ 1)
-            now_pc = time.perf_counter()
-            if self._last_flush_pc is not None:
-                UTILIZATION.observe(
-                    self.labels, epoch,
-                    interval_s=now_pc - self._last_flush_pc,
-                    busy_s=excl, bp_s=park_delta, idle_s=idle_delta)
-            self._last_flush_pc = now_pc
-        if _ledger.enabled():
-            # phase ledger: named phases recorded during this
-            # executor's pulls commit epoch-exactly; the exclusive
-            # residue is host work that is provably NOT pack/transfer/
-            # compute — source decode loops (host_ingest) or downstream
-            # reassembly/state writes/dispatch (host_emit); the barrier
-            # park is barrier_wait
-            named = self._cell.named_total()
-            _ledger.LEDGER.attribute_exec(self._kind, excl, epoch)
-            if _costs.enabled():
-                # per-MV split of the SAME cell the ledger is about to
-                # commit: the fragment label is the MV/job name, and
-                # cells nest exclusively, so summing fragments can
-                # never mint device time the domain didn't ledger
-                _costs.COSTS.observe_cell(
-                    self.labels["fragment"], epoch,
-                    self._cell.seconds.get("device_compute", 0.0),
-                    self._cell.h2d_bytes, self._cell.d2h_bytes)
-            _ledger.LEDGER.commit_cell(epoch, self._cell)
-            resid = excl - named
-            if resid > 0:
-                _ledger.LEDGER.attribute(self._fallback_phase, resid,
-                                         epoch)
-            if park_delta > 0:
-                # credit parks are their own ledger phase: the wall
-                # time subtracted from busy must still be conserved
-                _ledger.LEDGER.attribute("backpressure_wait",
-                                         park_delta, epoch)
-            if idle_delta > 0:
-                # keyed per source: parallel sources park CONCURRENTLY
-                # and the ledger folds the across-source max, not the
-                # sum, into barrier_wait at seal (share > 1.0 was the
-                # BENCH_r10 ad-ctr attribution bug)
-                _ledger.LEDGER.attribute_idle(idle_delta, epoch,
-                                              source=self._who)
-        else:
-            # drain even while off: seconds recorded before a mid-
-            # epoch SET stream_ledger=off must not leak into whatever
-            # epoch is current when the ledger comes back on
-            self._cell.take()
-        if _spans.enabled():
-            # one actor-phase span per (executor, barrier): exclusive
-            # processing time for the epoch this barrier ends, keyed by
-            # the barrier's CURR epoch (the rw_barrier_latency key) and
-            # parented to its inject span — the causal timeline the
-            # straggler diagnosis reads
-            import time as _t
-            _spans.EPOCH_TRACER.record(
-                self.labels["executor"], "actor", epoch=epoch,
-                start_s=_t.time() - excl, dur_s=excl,
-                actor=int(self.labels["actor"]),
-                node=self.labels["node"],
-                fragment=self.labels["fragment"])
+        # utilization tricolor: busy / backpressure / idle shares
+        # of THIS node's barrier-to-barrier interval (its own
+        # flush-to-flush wall clock — all three parts are disjoint
+        # wall time inside it, so the triple sums to ≤ 1)
+        now_pc = time.perf_counter()
+        if self._last_flush_pc is not None:
+            UTILIZATION.observe(
+                self.labels, epoch,
+                interval_s=now_pc - self._last_flush_pc,
+                busy_s=excl, bp_s=park_delta, idle_s=idle_delta)
+        self._last_flush_pc = now_pc
+        # phase ledger: named phases recorded during this
+        # executor's pulls commit epoch-exactly; the exclusive
+        # residue is host work that is provably NOT pack/transfer/
+        # compute — source decode loops (host_ingest) or downstream
+        # reassembly/state writes/dispatch (host_emit); the barrier
+        # park is barrier_wait
+        named = self._cell.named_total()
+        _ledger.LEDGER.attribute_exec(self._kind, excl, epoch)
+        # per-MV split of the SAME cell the ledger is about to
+        # commit: the fragment label is the MV/job name, and
+        # cells nest exclusively, so summing fragments can
+        # never mint device time the domain didn't ledger
+        _costs.COSTS.observe_cell(
+            self.labels["fragment"], epoch,
+            self._cell.seconds.get("device_compute", 0.0),
+            self._cell.h2d_bytes, self._cell.d2h_bytes)
+        _ledger.LEDGER.commit_cell(epoch, self._cell)
+        resid = excl - named
+        if resid > 0:
+            _ledger.LEDGER.attribute(self._fallback_phase, resid,
+                                     epoch)
+        if park_delta > 0:
+            # credit parks are their own ledger phase: the wall
+            # time subtracted from busy must still be conserved
+            _ledger.LEDGER.attribute("backpressure_wait",
+                                     park_delta, epoch)
+        if idle_delta > 0:
+            # keyed per source: parallel sources park CONCURRENTLY
+            # and the ledger folds the across-source max, not the
+            # sum, into barrier_wait at seal (N idle sources must
+            # not claim N times the epoch)
+            _ledger.LEDGER.attribute_idle(idle_delta, epoch,
+                                          source=self._who)
+        # one actor-phase span per (executor, barrier): exclusive
+        # processing time for the epoch this barrier ends, keyed by
+        # the barrier's CURR epoch (the rw_barrier_latency key) and
+        # parented to its inject span — the causal timeline the
+        # straggler diagnosis reads
+        _spans.EPOCH_TRACER.record(
+            self.labels["executor"], "actor", epoch=epoch,
+            start_s=time.time() - excl, dur_s=excl,
+            actor=int(self.labels["actor"]),
+            node=self.labels["node"],
+            fragment=self.labels["fragment"])
         # per-LOGICAL-executor attribution inside fused blocks
         # (ops/fused.py): a fused run is ONE node in the chain, but
         # rw_actor_metrics keeps a row per absorbed stage — visible-row
@@ -422,19 +389,16 @@ class MonitoredExecutor(Executor):
                 # charged to this node — a nested wrapped child swaps
                 # its own cell in for its pulls, mirroring exactly how
                 # exclusive busy time nests
-                ctok = _ledger.LEDGER.push_cell(self._cell) \
-                    if _ledger.enabled() else None
+                ctok = _ledger.LEDGER.push_cell(self._cell)
                 # compile-cache ownership: anything traced while this
                 # pull runs bills the pulling MV (first tracer pays,
                 # later MVs record shared hits — stream/costs.py)
-                mtok = _costs.push_mv(self.labels["fragment"]) \
-                    if _costs.enabled() else None
+                mtok = _costs.push_mv(self.labels["fragment"])
                 # park cell: exchange-credit parks fired while the
                 # inner executor works charge THIS node (a nested
                 # wrapped child swaps its own cell in for its pulls,
                 # mirroring the ledger cells)
-                ptok = _xchg.push_park_cell(self._park_cell) \
-                    if TRICOLOR else None
+                ptok = _xchg.push_park_cell(self._park_cell)
                 atok = _merge.push_align_cell(self._align_cell) \
                     if self.children else None
                 try:
@@ -444,12 +408,9 @@ class MonitoredExecutor(Executor):
                 finally:
                     if atok is not None:
                         _merge.pop_align_cell(atok)
-                    if ptok is not None:
-                        _xchg.pop_park_cell(ptok)
-                    if mtok is not None:
-                        _costs.pop_mv(mtok)
-                    if ctok is not None:
-                        _ledger.LEDGER.pop_cell(ctok)
+                    _xchg.pop_park_cell(ptok)
+                    _costs.pop_mv(mtok)
+                    _ledger.LEDGER.pop_cell(ctok)
                     _AWAITS.exit(self._who)
                     self.total_busy_s += _ledger.actor_clock() - t0
                 if is_chunk(msg):

@@ -341,8 +341,7 @@ class RemoteOutputQueue:
                     "remote exchange peer disconnected")
             await self._q.put(_frame(b"D", encode_chunk(msg)))
         elif is_barrier(msg):
-            # span-context trailer (stream/trace_ctx.py): empty bytes
-            # when tracing is off — the frame stays byte-identical
+            # the span-context trailer follows (stream/trace_ctx.py)
             await self._q.put(_frame(
                 b"B", encode_barrier(msg) + barrier_trailer(msg)))
         elif isinstance(msg, Watermark):

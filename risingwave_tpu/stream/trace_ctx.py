@@ -5,8 +5,7 @@ and kernels stamp device dispatches into the current epoch's trace, and
 remote exchange barriers carry a span context trailer so the receiving
 worker's spans link causally to the coordinator's inject span.
 
-Wire shape (appended to the 'B' barrier frame payload ONLY when
-tracing is enabled — tracing off leaves frames byte-identical):
+Wire shape (appended to every 'B' barrier frame payload):
 
     trailer = magic(2B b"TC") ++ epoch(u64) ++ parent_span(u64)
               ++ send_wall_ts(f64)      — struct ">2sQQd", 26 bytes
@@ -30,10 +29,8 @@ _MAGIC = b"TC"
 
 
 def barrier_trailer(barrier) -> bytes:
-    """Span-context bytes to append to an outgoing 'B' frame payload
-    (empty when tracing is off — the frame stays byte-identical)."""
-    if not _spans.enabled():
-        return b""
+    """Span-context bytes to append to an outgoing 'B' frame
+    payload."""
     epoch = barrier.epoch.curr.value
     parent = _spans.EPOCH_TRACER.root_id(epoch) or 0
     return _TRAILER.pack(_MAGIC, epoch, parent, time.time())
@@ -58,8 +55,6 @@ def record_remote_transfer(payload: bytes, up: int, down: int) -> None:
     SENDER's inject span, so the cross-worker edge links causally —
     and adopt the sender's epoch/root for spans this process records
     next (a pure-executor worker has no barrier loop to set them)."""
-    if not _spans.enabled():
-        return
     ctx = decode_trailer(payload)
     if ctx is None:
         return

@@ -157,7 +157,7 @@ def kernel_cost_rows() -> List[tuple]:
 def publish_kernel_costs() -> int:
     """Refresh the device_kernel_flops/bytes_accessed gauges from the
     registry (lazy by design: cost analysis compiles on first read, so
-    it runs at report points — ctl phases, bench snapshot — not on the
+    it runs at report points — ctl phases — not on the
     hot path). Returns the number of kernels published."""
     from risingwave_tpu.utils.metrics import STREAMING
     rows = kernel_cost_rows()
@@ -228,8 +228,8 @@ def enable_compilation_cache() -> str:
     ``<checkout>/.jax_cache`` (the path is part of the cache key, so a
     directory that moves never hits). Every entry point that runs
     kernels calls this once before its first compile: ``serve`` /
-    ``playground`` / ``serve-cluster``, the cluster worker, bench.py's
-    children, chip_smoke.py and tests/conftest.py."""
+    ``playground`` / ``serve-cluster``, the cluster worker,
+    benchmark/run.py, chip_smoke.py and tests/conftest.py."""
     import jax
 
     cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
@@ -307,9 +307,6 @@ def fetch(*arrays, poll_s: float = 0.002) -> List[np.ndarray]:
     """
     start_fetch(*arrays)
     pending = _not_ready(arrays)
-    if not _ledger.enabled():
-        _wait_ready(pending, poll_s)
-        return [np.asarray(a) for a in arrays]
     if pending:
         with _ledger.LEDGER.phase("device_compute"):
             _wait_ready(pending, poll_s)
@@ -341,8 +338,6 @@ async def fetch_async(*arrays, poll_s: float = 0.001) -> List[np.ndarray]:
         await asyncio.sleep(0 if spins < 50 else poll_s)
         spins += 1
         pending = _not_ready(pending)
-    if not _ledger.enabled():
-        return [np.asarray(a) for a in arrays]
     with _ledger.LEDGER.phase("d2h"):
         out = [np.asarray(a) for a in arrays]
     _ledger_d2h(arrays, out)
@@ -356,9 +351,6 @@ def upload(host, sharding=None, kernel: Optional[str] = None):
     the h2d half of the epoch phase ledger's conservation argument."""
     import jax
 
-    if not _ledger.enabled():
-        return jax.device_put(host) if sharding is None \
-            else jax.device_put(host, sharding)
     with _ledger.LEDGER.phase("h2d", kernel=kernel):
         out = jax.device_put(host) if sharding is None \
             else jax.device_put(host, sharding)

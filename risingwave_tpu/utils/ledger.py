@@ -81,7 +81,7 @@ barrier that ends the epoch, the same CURR-epoch key rw_barrier_latency
 uses); scopes outside any executor attribute to the newest injected
 epoch (the utils/spans approximation).
 
-Output surfaces: ``stream_epoch_phase_seconds{phase,query}`` and
+Output surfaces: ``stream_epoch_phase_seconds{phase}`` and
 ``stream_transfer_bytes_total{dir,kernel}`` Prometheus families; a
 ``phase.<name>`` span where and for as long as a scope ran (from
 ``SPAN_MIN_S`` up) + byte counter tracks in the Perfetto export
@@ -90,8 +90,7 @@ Output surfaces: ``stream_epoch_phase_seconds{phase,query}`` and
 (utils/metrics.HISTORY — the feed the elastic-serving control loop
 reads: ``phase.<name>`` seconds, and ``exec_s.<Kind>``, each executor
 kind's exclusive busy seconds of the epoch, a second cut of the same
-wall time); the per-query ``phase_breakdown`` block in bench rounds,
-and ``ctl phases``.
+wall time); and ``ctl phases``.
 """
 
 from __future__ import annotations
@@ -125,8 +124,6 @@ SPAN_MIN_S = 0.001
 # that never collect, e.g. recovery rollbacks)
 OPEN_WINDOW = 64
 
-_ENABLED = True
-
 # active scope's child-duration accumulator (exclusive-nesting math);
 # ContextVars are asyncio-task aware, so interleaved actors keep
 # separate stacks
@@ -159,27 +156,6 @@ def actor_clock() -> float:
     return time.perf_counter() - _STOLEN[0]
 
 
-def enabled() -> bool:
-    return _ENABLED
-
-
-def set_enabled(on: bool) -> None:
-    global _ENABLED
-    _ENABLED = bool(on)
-
-
-def parse_ledger(spec: str) -> bool:
-    """'on'|'off' → bool (SET stream_ledger validator; PlanError so a
-    typo fails the SET, not a later epoch)."""
-    s = str(spec).strip().lower()
-    if s in ("on", "true", "1"):
-        return True
-    if s in ("off", "false", "0"):
-        return False
-    from risingwave_tpu.frontend.planner import PlanError
-    raise PlanError(f"stream_ledger must be on|off, got {spec!r}")
-
-
 def current_kernel() -> str:
     return _KERNEL.get()
 
@@ -199,8 +175,6 @@ def note_backlog(kernel: str, rows: float) -> None:
     stream_epoch_backlog_rows gauge behind the Perfetto backlog
     counter track) — the ONE copy all four epoch-batching kernels
     call at their backlog flush."""
-    if not _ENABLED:
-        return
     from risingwave_tpu.utils.metrics import STREAMING
     STREAMING.backlog_rows.set(float(rows), kernel=kernel)
 
@@ -245,8 +219,8 @@ class _EpochAcc:
         # per-SOURCE idle seconds (barrier_wait) — kept keyed so the
         # seal can take the across-source MAX instead of the sum:
         # parallel sources park CONCURRENTLY, and summing their idle
-        # against one wall-clock interval double-counts it (the
-        # BENCH_r10 ad-ctr share-1.05 bug)
+        # against one wall-clock interval double-counts it (a share
+        # above 1.0)
         self.idle: Dict[str, float] = {}
 
     def add(self, phase: str, s: float) -> None:
@@ -353,9 +327,6 @@ class PhaseLedger:
         self.window = window
         self._open: "OrderedDict[int, _EpochAcc]" = OrderedDict()
         self.records: Deque[LedgerRecord] = deque(maxlen=window)
-        # label stamped on the stream_epoch_phase_seconds query axis
-        # (bench sets it per lane; sessions leave it "")
-        self.query = ""
         # cell commits race the uploader's worker threads' scopes
         self._lock = threading.Lock()
         # LOOP-phase sections not yet on an epoch's books, with their
@@ -395,9 +366,6 @@ class PhaseLedger:
         duration, and a ``phase.<name>`` span of the epoch trace where
         it lasts ``SPAN_MIN_S`` or more."""
         loop = name in LOOP_PHASES
-        if not _ENABLED and not loop:
-            yield
-            return
         parent = _SCOPE.get()
         mine = [0.0]
         tok = _SCOPE.set(mine)
@@ -418,15 +386,14 @@ class PhaseLedger:
             excl = max(0.0, dur - mine[0])
             if loop:
                 _STOLEN[0] += excl
-                if _ENABLED:
-                    end = time.monotonic()
-                    with self._lock:
-                        self._loop_pending.append(
-                            [end - dur, end, name, excl])
-                        if len(self._loop_pending) > 4096:
-                            # a process that never seals (a cluster
-                            # worker): nothing reads these
-                            del self._loop_pending[:2048]
+                end = time.monotonic()
+                with self._lock:
+                    self._loop_pending.append(
+                        [end - dur, end, name, excl])
+                    if len(self._loop_pending) > 4096:
+                        # a process that never seals (a cluster
+                        # worker): nothing reads these
+                        del self._loop_pending[:2048]
             else:
                 cell = _CELL.get()
                 if cell is not None:
@@ -435,7 +402,7 @@ class PhaseLedger:
                 else:
                     with self._lock:
                         self._acc().add(name, excl)
-            if dur >= SPAN_MIN_S and _ENABLED:
+            if dur >= SPAN_MIN_S:
                 _spans.EPOCH_TRACER.record(
                     "phase." + name, "phase",
                     start_s=time.time() - dur, dur_s=dur,
@@ -445,7 +412,7 @@ class PhaseLedger:
                   epoch: Optional[int] = None) -> None:
         """Direct (non-scoped) attribution — executor residue, source
         barrier_wait, barrier-loop commit work."""
-        if not _ENABLED or seconds <= 0:
+        if seconds <= 0:
             return
         with self._lock:
             self._acc(epoch).add(name, seconds)
@@ -458,7 +425,7 @@ class PhaseLedger:
         MAX (the union approximation) into ``barrier_wait`` instead of
         the sum, so N idle sources can never claim N× the epoch
         (share > 1.0 is definitionally noise)."""
-        if not _ENABLED or seconds <= 0:
+        if seconds <= 0:
             return
         with self._lock:
             self._acc(epoch).add_idle(source, seconds)
@@ -467,7 +434,7 @@ class PhaseLedger:
                        epoch: int) -> None:
         """One executor node's exclusive busy seconds of the epoch,
         folded by executor kind (published as ``exec_s.<Kind>``)."""
-        if not _ENABLED or seconds <= 0:
+        if seconds <= 0:
             return
         with self._lock:
             ex = self._acc(epoch).exec_s
@@ -478,7 +445,7 @@ class PhaseLedger:
         """One host↔device transfer's payload: live Prometheus counter
         (stream_transfer_bytes_total{dir,kernel}) plus the per-epoch
         byte accumulators behind the Perfetto counter tracks."""
-        if not _ENABLED or nbytes <= 0:
+        if nbytes <= 0:
             return
         from risingwave_tpu.utils.metrics import STREAMING
         STREAMING.transfer_bytes.inc(
@@ -502,8 +469,6 @@ class PhaseLedger:
         """A kernel (re)trace marks the epoch warmup: compile stalls
         are expected to blow the conservation budget and are exempt
         from the strict gate (the RecompileGuard polices them)."""
-        if not _ENABLED:
-            return
         with self._lock:
             self._acc().warmup = True
 
@@ -517,9 +482,6 @@ class PhaseLedger:
     def commit_cell(self, epoch: int, cell: AttributionCell) -> None:
         """Fold one executor's cell into the epoch it just finished
         (called at barrier passage with the barrier's CURR epoch)."""
-        if not _ENABLED:
-            cell.take()
-            return
         seconds, h2d, d2h = cell.take()
         if not seconds and not h2d and not d2h:
             return
@@ -537,7 +499,7 @@ class PhaseLedger:
              domain: str = "",
              wake_gap: Optional[Tuple[float, float]] = None,
              between: Optional[Tuple[float, float]] = None
-             ) -> Optional[LedgerRecord]:
+             ) -> LedgerRecord:
         """Close the epoch's books against its measured barrier
         interval: residual → ``unattributed``, publish the Prometheus
         phase family, the trace phase lanes + counter tracks, and the
@@ -557,9 +519,6 @@ class PhaseLedger:
         interval, and the LOOP-phase seconds that ran in it (a
         checkpoint built right after the seal holds up the next
         inject) are added to this interval with their phase."""
-        if not _ENABLED:
-            self.discard(epoch)
-            return None
         with self._lock:
             acc = self._open.pop(epoch, None) or _EpochAcc()
             self._sealed.append(epoch)
@@ -637,9 +596,8 @@ class PhaseLedger:
 
     def _publish(self, rec: LedgerRecord) -> None:
         from risingwave_tpu.utils.metrics import HISTORY, STREAMING
-        q = self.query
         for name, s in rec.seconds.items():
-            STREAMING.epoch_phase_seconds.inc(s, phase=name, query=q)
+            STREAMING.epoch_phase_seconds.inc(s, phase=name)
         extra = {f"phase.{p}": rec.seconds.get(p, 0.0)
                  for p in PHASES + (UNATTRIBUTED,)
                  if p in rec.seconds or p not in MESH_PHASES}
@@ -662,8 +620,6 @@ class PhaseLedger:
         extra.update(_costs.COSTS.history_extra(rec))
         HISTORY.observe(rec.epoch, rec.interval_s, extra=extra,
                         domain=rec.domain)
-        if not _spans.enabled():
-            return
         now = time.time()
         # counter-track sample (export_chrome renders 'C' events)
         _spans.EPOCH_TRACER.record(
@@ -739,7 +695,7 @@ class PhaseLedger:
                     rec.seconds[name] = rec.seconds.get(name, 0.0) \
                         + float(s)
                     STREAMING.epoch_phase_seconds.inc(
-                        float(s), phase=name, query=self.query)
+                        float(s), phase=name)
                 w_idle = float(d.get("idle_max", 0.0))
                 if w_idle > 0:
                     # barrier_wait merges as MAX-then-cap across
@@ -782,16 +738,12 @@ class PhaseLedger:
     # coverage statistics (still counted, still summed into phases)
     MICRO_EPOCH_S = 0.005
 
-    def phase_breakdown(self, steady_only: bool = True,
-                        domain: Optional[str] = None) -> dict:
-        """Aggregate share view over sealed epochs (bench's per-query
-        ``phase_breakdown`` block and the ``ctl phases`` totals).
-        ``steady_only`` drops warmup (compile-bearing) epochs;
-        ``domain`` restricts to one barrier domain's records (the
-        per-domain bench breakdown)."""
+    def phase_breakdown(self, steady_only: bool = True) -> dict:
+        """Aggregate share view over sealed epochs (the ``ctl phases``
+        totals). ``steady_only`` drops warmup (compile-bearing)
+        epochs."""
         recs = [r for r in self.records
-                if not (steady_only and r.warmup)
-                and (domain is None or r.domain == domain)]
+                if not (steady_only and r.warmup)]
         if not recs:
             return {"epochs": 0}
         total = sum(r.interval_s for r in recs)
@@ -814,15 +766,6 @@ class PhaseLedger:
             "h2d_bytes": int(sum(r.h2d_bytes for r in recs)),
             "d2h_bytes": int(sum(r.d2h_bytes for r in recs)),
         }
-
-    def domains_seen(self) -> List[str]:
-        """Distinct barrier domains among the sealed records (bench's
-        per-domain breakdown iterates these)."""
-        seen: List[str] = []
-        for r in self.records:
-            if r.domain not in seen:
-                seen.append(r.domain)
-        return seen
 
     def report(self, last_n: int = 16) -> str:
         """Human-readable per-epoch table (``ctl phases``)."""

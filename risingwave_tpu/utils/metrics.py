@@ -484,7 +484,7 @@ class StreamingMetrics:
         self.kernel_flops = r.gauge(
             "device_kernel_flops",
             "XLA cost-analysis flops of the last-compiled program per "
-            "kernel label (published lazily: ctl phases / bench)")
+            "kernel label (published lazily: ctl phases)")
         self.kernel_bytes_accessed = r.gauge(
             "device_kernel_bytes_accessed",
             "XLA cost-analysis bytes-accessed of the last-compiled "

@@ -14,14 +14,12 @@ Design:
 - **Always on, bounded.** Recording is a dict append; the flight
   recorder keeps the last `EPOCH_WINDOW` epochs, each capped at
   `MAX_SPANS_PER_EPOCH` spans (drops are counted, never silent).
-  ``set_enabled(False)`` (SET stream_trace = off) reduces every hook
-  to one predicate check.
 - **Keyed by the barrier's CURR epoch** — the same key
   rw_barrier_latency rows use, so a profile row and its trace join
   trivially. Spans recorded between barriers (device dispatches)
   attribute to the most recently *injected* epoch; with a deep
   in-flight window that is an approximation, exact under the
-  stepping/bench drivers (in_flight drains before the next inject).
+  stepping drivers (in_flight drains before the next inject).
 - **Wall-clock timestamps** (`time.time()`): spans merge across
   worker processes on one host, where monotonic clocks don't compare.
 - **Promotion.** The slow-barrier watchdog (meta/barrier.py) moves an
@@ -100,31 +98,7 @@ class TraceSpan:
             args=dict(d.get("args") or {}))
 
 
-# -- global switches -------------------------------------------------------
-
-_ENABLED = True           # always-on flight recorder; SET stream_trace
 _CURRENT_EPOCH = 0        # newest INJECTED epoch (see module docstring)
-
-
-def enabled() -> bool:
-    return _ENABLED
-
-
-def set_enabled(on: bool) -> None:
-    global _ENABLED
-    _ENABLED = bool(on)
-
-
-def parse_trace(spec: str) -> bool:
-    """'on'|'off' → bool (SET stream_trace validator; PlanError so a
-    typo fails the SET, not a later epoch)."""
-    s = str(spec).strip().lower()
-    if s in ("on", "true", "1"):
-        return True
-    if s in ("off", "false", "0"):
-        return False
-    from risingwave_tpu.frontend.planner import PlanError
-    raise PlanError(f"stream_trace must be on|off, got {spec!r}")
 
 
 def set_current_epoch(value: int) -> None:
@@ -180,9 +154,7 @@ class EpochTracer:
                parent: Optional[int] = None, actor: Optional[int] = None,
                worker: str = "", span_id: Optional[int] = None,
                **args) -> int:
-        """Append one completed span; returns its id (0 if disabled)."""
-        if not _ENABLED:
-            return 0
+        """Append one completed span; returns its id."""
         e = _CURRENT_EPOCH if epoch is None else int(epoch)
         if parent is None:
             parent = self._roots.get(e)
@@ -428,23 +400,16 @@ def dispatch_span(kernel: str, rows: float, **args):
     """Time one device dispatch (the host-side call: pack + transfer +
     launch enqueue) into the current epoch's trace, stamped with kernel
     identity and row payload. A retrace during the call shows up as a
-    sibling compile span (note_compile). Near-free when tracing is
-    off.
+    sibling compile span (note_compile).
 
     Phase ledger: the span's EXCLUSIVE time (minus nested h2d/d2h
     scopes) is the launch's device_compute share, stamped with the
     kernel label so transfers recorded inside inherit it."""
-    from contextlib import nullcontext
-
-    from risingwave_tpu.utils import ledger as _ledger
-    if not _ENABLED and not _ledger.enabled():
-        yield
-        return
+    from risingwave_tpu.utils.ledger import LEDGER
     t0 = time.time()
     try:
         with annotation(kernel), \
-                _ledger.LEDGER.phase("device_compute", kernel=kernel) \
-                if _ledger.enabled() else nullcontext():
+                LEDGER.phase("device_compute", kernel=kernel):
             # ledger-test seam: a sleep spec here is wall time INSIDE
             # one kernel's dispatch — it must land in the dispatching
             # domain's device_compute books only (the per-domain
@@ -454,10 +419,9 @@ def dispatch_span(kernel: str, rows: float, **args):
                 _failpoint.fail_point(f"ledger.dispatch.{kernel}")
             yield
     finally:
-        if _ENABLED:
-            EPOCH_TRACER.record(kernel, "dispatch", start_s=t0,
-                                dur_s=time.time() - t0,
-                                rows=float(rows), **args)
+        EPOCH_TRACER.record(kernel, "dispatch", start_s=t0,
+                            dur_s=time.time() - t0,
+                            rows=float(rows), **args)
 
 
 def note_compile(label: str) -> None:
@@ -471,6 +435,4 @@ def note_compile(label: str) -> None:
     STREAMING.kernel_recompile.inc(1, kernel=label)
     from risingwave_tpu.utils.ledger import LEDGER
     LEDGER.note_compile()
-    if _ENABLED:
-        EPOCH_TRACER.record(f"compile:{label}", "compile",
-                            kernel=label)
+    EPOCH_TRACER.record(f"compile:{label}", "compile", kernel=label)

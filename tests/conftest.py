@@ -89,12 +89,10 @@ def _conservation_gate():
     gate's interval floor. Sits next to the RecompileGuard and
     DispatchBudget strict-mode guards."""
     from risingwave_tpu.utils import ledger as _ledger
-    _ledger.set_enabled(True)
     _ledger.LEDGER.clear()
     yield
     violations = _ledger.LEDGER.gate_violations()
     _ledger.LEDGER.clear()
-    _ledger.set_enabled(True)
     assert not violations, (
         "epoch phase ledger conservation gate (tier-1 strict mode): "
         "steady-state epochs carried unattributed wall-clock over "
@@ -116,7 +114,6 @@ def _attribution_gate():
     from risingwave_tpu.state import topology as _topology
     from risingwave_tpu.stream import costs as _costs
     from risingwave_tpu.stream import hotkeys as _hotkeys
-    _costs.set_enabled(True)
     _costs.COSTS.clear()
     _topology.TOPOLOGY.clear()
     _hotkeys.HOTKEYS.clear()
@@ -129,7 +126,6 @@ def _attribution_gate():
     _topology.TOPOLOGY.clear()
     _hotkeys.HOTKEYS.clear()
     _topology.TOPOLOGY.arm_checkpoint_verify(False)
-    _costs.set_enabled(True)
     assert not split, (
         "per-MV attribution gate (tier-1 strict mode): the MV split "
         "claims more device time than the domain's phase ledger "
@@ -157,8 +153,6 @@ def _tricolor_freshness_gate():
     from risingwave_tpu.stream import freshness as _fresh
     from risingwave_tpu.stream import monitor as _monitor
     from risingwave_tpu.stream.bottleneck import BOTTLENECKS
-    _monitor.set_tricolor(True)
-    _fresh.set_enabled(True)
     _monitor.UTILIZATION.clear()
     _fresh.FRESHNESS.clear()
     BOTTLENECKS.clear()
@@ -168,8 +162,6 @@ def _tricolor_freshness_gate():
     _monitor.UTILIZATION.clear()
     _fresh.FRESHNESS.clear()
     BOTTLENECKS.clear()
-    _monitor.set_tricolor(True)
-    _fresh.set_enabled(True)
     assert not tri, (
         "utilization tricolor gate (tier-1 strict mode): published "
         "busy+backpressure+idle triples exceed 1.0 + ε — two states "
@@ -324,9 +316,7 @@ class DispatchBudget:
     @staticmethod
     def check_ceiling(d_fused, d_baseline, what="baseline"):
         """Join-query extension (ISSUE 9): a fused join run must not
-        exceed its comparison arm's dispatch count — the test-scale
-        analog of BENCH acceptance 'fused q5-shape dispatches below
-        the r08 unfused count'."""
+        exceed its comparison arm's dispatch count."""
         assert d_fused <= d_baseline, (
             f"fused join run dispatched {d_fused} times, {what} "
             f"{d_baseline} — dispatch-budget guard (tier-1 strict "

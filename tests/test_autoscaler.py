@@ -704,8 +704,7 @@ def test_autoscaler_and_split_rebalance_e2e(tmp_path):
 
 
 def test_mid_rescale_chaos_converges(tmp_path):
-    """ISSUE 15 acceptance (the bench --with-chaos round also runs
-    this continuously): a seeded schedule injecting faults
+    """ISSUE 15 acceptance: a seeded schedule injecting faults
     MID-RESCALE — SIGKILL during cohort redeploy, storage fault during
     the state handoff, straggler across the rescale's stop barrier —
     with the autoscaler enabled converges oracle-bit-identical, and

@@ -40,8 +40,6 @@ STAGES = ("ckpt.queue_s", "ckpt.build_s", "ckpt.put_s", "ckpt.commit_s",
 def _fresh():
     LEDGER.clear()
     HISTORY.clear()
-    ledger_mod.set_enabled(True)
-    spans_mod.set_enabled(True)
     spans_mod.set_current_epoch(0)
     yield
     LEDGER.clear()

@@ -415,8 +415,8 @@ def test_cold_keys_never_fire_skew():
 def test_session_costs_end_to_end():
     """Front door: rw_mv_costs attributes device time and state bytes
     to the MV, rw_state_topology serves per-vnode rows, per-barrier
-    history carries mv_device_s.<mv>, the knob flips the hooks off,
-    and DROP purges every surface."""
+    history carries mv_device_s.<mv>, and DROP purges every
+    surface."""
     from risingwave_tpu.frontend import Frontend
 
     async def run():
@@ -429,19 +429,12 @@ def test_session_costs_end_to_end():
         costs = await fe.execute("SELECT * FROM rw_mv_costs")
         topo = await fe.execute("SELECT * FROM rw_state_topology")
         hist = await fe.execute("SELECT * FROM rw_metrics_history")
-        await fe.execute("SET stream_costs = off")
-        from risingwave_tpu.state import topology as topo_mod
-        from risingwave_tpu.stream import costs as costs_mod
-        from risingwave_tpu.stream import hotkeys as hot_mod
-        flags_off = (costs_mod.ENABLED, topo_mod.ENABLED,
-                     hot_mod.ENABLED)
-        await fe.execute("SET stream_costs = on")
         await fe.execute("DROP MATERIALIZED VIEW cost_mv")
         after = await fe.execute("SELECT * FROM rw_mv_costs")
         await fe.close()
-        return costs, topo, hist, flags_off, after
+        return costs, topo, hist, after
 
-    costs, topo, hist, flags_off, after = asyncio.run(run())
+    costs, topo, hist, after = asyncio.run(run())
     row = next(r for r in costs if r[0] == "cost_mv")
     assert row[2] >= 0.0                       # device_seconds
     assert row[5] > 0                          # state_bytes
@@ -451,7 +444,6 @@ def test_session_costs_end_to_end():
     assert mv_topo and sum(r[4] for r in mv_topo) == row[5]
     names = {r[4] for r in hist}
     assert "mv_device_s.cost_mv" in names
-    assert flags_off == (False, False, False)
     assert all(r[0] != "cost_mv" for r in after)
 
 

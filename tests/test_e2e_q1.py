@@ -6,7 +6,7 @@ q1 (currency conversion, stateless):
 Reference parity: e2e_test/streaming/nexmark/q1 semantics; pipeline shape
 mirrors §3.1-3.2 of SURVEY.md — source → project → materialize driven by
 the barrier loop, results read from the MV's committed snapshot. The plan
-itself lives in risingwave_tpu.models.nexmark (shared with bench.py).
+itself lives in risingwave_tpu.models.nexmark.
 """
 
 import asyncio

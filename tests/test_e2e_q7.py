@@ -10,7 +10,7 @@ baseline config; full q7's self-join lands with HashJoinExecutor):
 Reference parity: e2e_test/streaming/nexmark/q7.slt.part semantics;
 pipeline shape per SURVEY §3.2 — source → project(tumble) → hash-agg
 (device kernel) → materialize, driven by the barrier loop. The plan
-itself lives in risingwave_tpu.models.nexmark (shared with bench.py).
+itself lives in risingwave_tpu.models.nexmark.
 """
 
 import asyncio

@@ -13,7 +13,7 @@ HashJoin (device matcher) → materialize. Reference parity:
 e2e_test/streaming/nexmark/q8 semantics; dedup via GROUP BY matches the
 reference plan (agg update pairs degrade to Delete+Insert through the
 join, leaving the match multiset unchanged). The plan itself lives in
-risingwave_tpu.models.nexmark (shared with bench.py).
+risingwave_tpu.models.nexmark.
 """
 
 import asyncio

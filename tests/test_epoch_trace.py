@@ -16,7 +16,6 @@ import struct
 
 import pytest
 
-from risingwave_tpu.utils import spans as spans_mod
 from risingwave_tpu.utils.spans import EPOCH_TRACER, EpochTracer
 
 EVENTS = 4000
@@ -35,13 +34,11 @@ Q7ISH_MV = (
 
 @pytest.fixture(autouse=True)
 def _fresh_tracer():
-    """Each test starts with an empty flight recorder and tracing ON
-    (the always-on default), and leaves it that way."""
+    """Each test starts with an empty flight recorder and leaves it
+    that way."""
     EPOCH_TRACER.clear()
-    spans_mod.set_enabled(True)
     yield
     EPOCH_TRACER.clear()
-    spans_mod.set_enabled(True)
 
 
 # -- span model / flight recorder -----------------------------------------
@@ -126,8 +123,7 @@ def test_chrome_export_is_valid_and_causal():
 
 def test_p99_breakdown_returns_zeros_on_empty_profiles():
     """Satellite: an empty/fully-warmup-trimmed profile deque yields
-    all-zero phases, never a raise (bench snapshots run right after
-    the warmup trim)."""
+    all-zero phases, never a raise."""
     from risingwave_tpu.meta.barrier import EpochProfiler
     p = EpochProfiler()
     zeros = {"inject_to_collect_s": 0.0, "collect_to_commit_s": 0.0,
@@ -149,7 +145,7 @@ def _mk_barrier(mutation=None):
                    BarrierKind.CHECKPOINT, mutation)
 
 
-def test_barrier_trailer_roundtrip_and_off_byte_identical():
+def test_barrier_trailer_roundtrip():
     from risingwave_tpu.stream.message import StopMutation
     from risingwave_tpu.stream.remote import encode_barrier
     from risingwave_tpu.stream.trace_ctx import (
@@ -169,17 +165,15 @@ def test_barrier_trailer_roundtrip_and_off_byte_identical():
     decoded = decode_barrier(payload)
     assert decoded.mutation.actors == frozenset({7, 9})
     assert decode_trailer(payload)[0] == 0x30000
-    # tracing off ⇒ byte-identical to the bare wire format of today
-    spans_mod.set_enabled(False)
-    payload_off = encode_barrier(b) + barrier_trailer(b)
-    expected = struct.pack(">BQQB", 2, 0x30000, 0x20000, 0)
-    assert payload_off == expected
+    # the trailer follows the bare barrier frame, which is unchanged
+    bare = struct.pack(">BQQB", 2, 0x30000, 0x20000, 0)
+    assert encode_barrier(b) == bare
+    assert decode_trailer(bare) is None
 
 
 def test_remote_exchange_propagates_span_context():
     """Round trip over a real TCP exchange edge: the receiver records
-    an exchange-transfer span parented to the sender's inject span;
-    with tracing off, no span and no trailer."""
+    an exchange-transfer span parented to the sender's inject span."""
     from risingwave_tpu.common.types import DataType, Schema
     from risingwave_tpu.stream.remote import ExchangeServer, RemoteInput
 
@@ -214,40 +208,10 @@ def test_remote_exchange_propagates_span_context():
     assert edges[0].args["edge"] == "11->22"
 
 
-def test_remote_exchange_tracing_off_no_spans():
-    from risingwave_tpu.common.types import DataType, Schema
-    from risingwave_tpu.stream.remote import ExchangeServer, RemoteInput
-
-    spans_mod.set_enabled(False)
-    schema = Schema.of(v=DataType.INT64)
-
-    async def run():
-        srv = ExchangeServer()
-        await srv.serve()
-        out = srv.register_edge(1, 2)
-        inp = RemoteInput("127.0.0.1", srv.port, 1, 2, schema)
-        b = _mk_barrier()
-
-        async def pump():
-            await out.send(b)
-            out.close()
-
-        task = asyncio.ensure_future(pump())
-        got = [m async for m in inp.execute()]
-        await task
-        await srv.close()
-        return got
-
-    got = asyncio.run(run())
-    assert len(got) == 1
-    assert EPOCH_TRACER.epochs() == []
-
-
 # -- end-to-end: one process ----------------------------------------------
 
 
-def _run_q7ish(trace_on: bool, slow_threshold: float = 1.0,
-               failpoints_armed=None):
+def _run_q7ish(slow_threshold: float = 1.0, failpoints_armed=None):
     """Frontend + q7-shaped MV; returns (mv rows, promoted epochs,
     diagnoses, trace rows via SQL)."""
     from risingwave_tpu.frontend.session import Frontend
@@ -255,8 +219,6 @@ def _run_q7ish(trace_on: bool, slow_threshold: float = 1.0,
 
     async def run():
         fe = Frontend(min_chunks=8)
-        await fe.execute(
-            f"SET stream_trace = '{'on' if trace_on else 'off'}'")
         await fe.execute(BID_SOURCE.format(n=EVENTS))
         await fe.execute(Q7ISH_MV)
         fe.loop.profiler.slow_threshold_s = slow_threshold
@@ -277,11 +239,11 @@ def _run_q7ish(trace_on: bool, slow_threshold: float = 1.0,
     return {tuple(r) for r in rows}, retained, diags, trace_rows
 
 
-def test_frontend_trace_end_to_end_and_oracle_unchanged():
-    """Tracing on yields inject→actor→dispatch→commit spans reachable
-    over SQL; tracing off records nothing; MV output is bit-identical
-    either way."""
-    rows_on, _retained, _d, trace_rows = _run_q7ish(True)
+def test_frontend_trace_end_to_end():
+    """A session's barriers leave inject→actor→dispatch→commit spans
+    reachable over SQL."""
+    rows, _retained, _d, trace_rows = _run_q7ish()
+    assert rows
     cats = {r[4] for r in trace_rows}
     assert {"barrier", "actor", "dispatch", "commit"} <= cats, cats
     # warmup compiles are visible events
@@ -300,18 +262,13 @@ def test_frontend_trace_end_to_end_and_oracle_unchanged():
     assert any(json.loads(r[10] or "{}").get("rows", 0) > 0
                for r in disp)
 
-    EPOCH_TRACER.clear()
-    rows_off, _r, _d, trace_rows_off = _run_q7ish(False)
-    assert trace_rows_off == []
-    assert rows_on == rows_off
-
 
 def test_slow_barrier_promotes_trace_with_straggler_diagnosis(capfd):
     """A forced-slow agg (sleep failpoint) trips the watchdog: the
     epoch's full trace lands in the retained store and the one-line
     diagnosis names the laggard executor."""
     _rows, retained, diags, trace_rows = _run_q7ish(
-        True, slow_threshold=0.05,
+        slow_threshold=0.05,
         failpoints_armed={"trace.slow.HashAggExecutor":
                           {"sleep_s": 0.12}})
     assert retained, "no slow barrier was promoted"
@@ -321,105 +278,6 @@ def test_slow_barrier_promotes_trace_with_straggler_diagnosis(capfd):
     # the diagnosis also rides the system table
     assert any(r[4] == "diagnosis" and "HashAggExecutor" in r[3]
                for r in trace_rows)
-
-
-def test_set_stream_trace_rides_ddl_log(tmp_path):
-    """SET stream_trace persists in the DDL log like stream_fusion: a
-    recovered frontend comes back with the operator's setting."""
-    from risingwave_tpu.frontend.session import Frontend
-    from risingwave_tpu.storage.hummock import HummockLite
-    from risingwave_tpu.storage.object_store import LocalFsObjectStore
-
-    async def run():
-        store = HummockLite(LocalFsObjectStore(str(tmp_path)))
-        fe = Frontend(store)
-        await fe.execute("SET stream_trace = 'off'")
-        await fe.execute(BID_SOURCE.format(n=400))
-        await fe.execute(Q7ISH_MV)
-        await fe.step(2)
-        await fe.close()
-        assert not spans_mod.enabled()
-        spans_mod.set_enabled(True)     # recovery must switch it back
-
-        fe2 = Frontend(HummockLite(LocalFsObjectStore(str(tmp_path))))
-        await fe2.recover()
-        on_after_recover = spans_mod.enabled()
-        shown = await fe2.execute("SHOW stream_trace")
-        await fe2.close()
-        return on_after_recover, shown
-
-    on_after, shown = asyncio.run(run())
-    assert on_after is False
-    assert shown == [("off",)]
-
-
-def test_set_stream_trace_validates():
-    from risingwave_tpu.frontend.planner import PlanError
-    from risingwave_tpu.frontend.session import Frontend
-
-    async def run():
-        fe = Frontend()
-        with pytest.raises(PlanError):
-            await fe.execute("SET stream_trace = 'sideways'")
-        # TO DEFAULT restores on
-        await fe.execute("SET stream_trace = 'off'")
-        await fe.execute("SET stream_trace TO DEFAULT")
-        return await fe.execute("SHOW stream_trace")
-
-    assert asyncio.run(run()) == [("on",)]
-    assert spans_mod.enabled()
-
-
-# -- latency-budget bench mode (satellite) ---------------------------------
-
-
-def test_bench_latency_budget_parse_and_verdict():
-    """bench.py --latency-budget: spec parsing (per-query + bare-float
-    default) and the per-query p99-vs-budget verdict, including the
-    over-budget path that fails the round with a non-zero exit."""
-    import bench
-
-    budgets = bench._parse_latency_budgets(
-        ["--latency-budget", "2.0, q5=4, adctr=30"])
-    assert budgets == {"*": 2.0, "q5": 4.0, "adctr": 30.0}
-
-    headline = {
-        "q7": {"p99_barrier_latency_s": 1.1},
-        "q5": {"p99_barrier_latency_s": 3.2},
-        "adctr": {"error": "boom"},           # measured nothing
-        "value": 1234.5,                      # non-dict headline keys
-    }
-    v = bench._latency_verdict(headline, budgets)
-    assert v["verdicts"]["q7"]["verdict"] == "ok"
-    assert v["verdicts"]["q5"]["verdict"] == "ok"        # 3.2 < 4
-    assert v["verdicts"]["adctr"]["verdict"] == "no-measurement"
-    assert v["ok"] is False                   # no-measurement fails
-
-    # a query past its budget flips the round verdict
-    v2 = bench._latency_verdict(
-        {"q7": {"p99_barrier_latency_s": 2.5}}, {"*": 2.0})
-    assert v2["verdicts"]["q7"]["verdict"] == "over-budget"
-    assert v2["ok"] is False
-
-    v3 = bench._latency_verdict(
-        {"q7": {"p99_barrier_latency_s": 0.5}}, {"*": 2.0})
-    assert v3["ok"] is True
-
-    # flag absent -> the DEFAULT budget string arms (ISSUE 9: adctr
-    # and the *_fused twins are gated every round — the bare-float
-    # default covers the twins, adctr/q5 get explicit headroom)
-    d = bench._parse_latency_budgets([])
-    assert d == bench._parse_latency_budgets(
-        ["--latency-budget", bench.DEFAULT_LATENCY_BUDGET])
-    assert "*" in d and "adctr" in d and "q5_fused" in d
-    # the '*' default must not gate entries with no p99 measurement
-    # (the chaos round reports MTTR, not barrier latency)
-    v4 = bench._latency_verdict(
-        {"q7": {"p99_barrier_latency_s": 0.5}, "chaos": {"mttr": 1.3}},
-        {"*": 2.0})
-    assert v4["ok"] is True and "chaos" not in v4["verdicts"]
-    # explicit empty spec -> mode off, nothing recorded
-    assert bench._parse_latency_budgets(["--latency-budget", ""]) == {}
 
 
 # -- steady-state recompile guard (satellite) ------------------------------

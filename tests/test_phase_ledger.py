@@ -11,7 +11,6 @@ import time
 import numpy as np
 import pytest
 
-from risingwave_tpu.utils import ledger as ledger_mod
 from risingwave_tpu.utils import spans as spans_mod
 from risingwave_tpu.utils.ledger import (
     LEDGER, AttributionCell, PhaseLedger, UNATTRIBUTED,
@@ -34,18 +33,15 @@ Q7ISH_MV = (
 
 @pytest.fixture(autouse=True)
 def _fresh_ledger():
-    """Each test starts with an empty ledger/history and the ledger ON
-    (the conftest conservation gate also clears records around every
-    test; this additionally resets the history ring and the epoch
-    key)."""
+    """Each test starts with an empty ledger/history (the conftest
+    conservation gate also clears records around every test; this
+    additionally resets the history ring and the epoch key)."""
     LEDGER.clear()
     HISTORY.clear()
-    ledger_mod.set_enabled(True)
     spans_mod.set_current_epoch(0)
     yield
     LEDGER.clear()
     HISTORY.clear()
-    ledger_mod.set_enabled(True)
 
 
 # -- scoping / nesting units ----------------------------------------------
@@ -113,18 +109,6 @@ def test_conservation_residual_and_gate_exemptions():
     # to the worker-ledger merge)
     led.seal(4, 1.0, distributed=True)
     assert len(led.gate_violations()) == 1
-
-
-def test_ledger_off_records_nothing():
-    led = PhaseLedger()
-    ledger_mod.set_enabled(False)
-    with led.phase("host_pack"):
-        time.sleep(0.005)
-    led.add_bytes("h2d", 999, kernel="off-test")
-    assert led.seal(5, 1.0) is None
-    assert list(led.records) == []
-    assert STREAMING.transfer_bytes.get(dir="h2d",
-                                        kernel="off-test") == 0.0
 
 
 def test_worker_merge_recomputes_residual():
@@ -199,7 +183,6 @@ def test_seal_emits_phase_lanes_and_counter_tracks():
     from risingwave_tpu.utils.spans import EPOCH_TRACER
 
     EPOCH_TRACER.clear()
-    spans_mod.set_enabled(True)
     spans_mod.set_current_epoch(33)
     t_before = time.time()
     with LEDGER.phase("device_compute"):
@@ -262,33 +245,23 @@ def test_sleep_failpoint_surfaces_as_unattributed():
     LEDGER.clear()
 
 
-# -- q7 oracle: ledger on vs off -------------------------------------------
+# -- q7: a session's epochs and bytes reach the books ----------------------
 
 
-def _run_q7(ledger_on: bool):
+def test_q7_ledger_seals_epochs_and_counts_bytes():
     from risingwave_tpu.frontend.session import Frontend
 
     async def run():
         fe = Frontend(min_chunks=8)
-        await fe.execute(
-            f"SET stream_ledger = '{'on' if ledger_on else 'off'}'")
         await fe.execute(BID_SOURCE.format(n=EVENTS))
         await fe.execute(Q7ISH_MV)
-        await fe.step(2)                 # warmup (compiles)
-        t0 = time.perf_counter()
-        await fe.step(8)
-        elapsed = time.perf_counter() - t0
+        await fe.step(10)
         rows = await fe.execute("SELECT * FROM q7")
         await fe.close()
-        return {tuple(r) for r in rows}, elapsed
+        return rows
 
-    return asyncio.run(run())
-
-
-def test_q7_ledger_on_off_oracle_and_overhead():
-    rows_on, t_on = _run_q7(True)
-    n_records = len(LEDGER.records)
-    assert n_records >= 8                # epochs sealed while on
+    assert asyncio.run(run())
+    assert len(LEDGER.records) >= 8
     steady = [r for r in LEDGER.records if not r.warmup]
     assert steady
     # the flagship kernel moved bytes BOTH directions while on
@@ -300,15 +273,6 @@ def test_q7_ledger_on_off_oracle_and_overhead():
                    if l.get("dir") == "d2h"}
     assert any("HashAgg" in k for k in kernels_h2d), kernels_h2d
     assert any("HashAgg" in k for k in kernels_d2h), kernels_d2h
-    LEDGER.clear()
-    rows_off, t_off = _run_q7(False)
-    assert len(LEDGER.records) == 0      # off: nothing sealed
-    # oracle: bit-identical MV content either way
-    assert rows_on == rows_off
-    # throughput within the tracing noise budget (generous: CI jitter
-    # dwarfs the per-scope cost; the 5% bench criterion is enforced on
-    # the real bench rig, this guards pathological overhead only)
-    assert t_on <= t_off * 1.6 + 0.3, (t_on, t_off)
 
 
 # -- rw_metrics_history over SQL -------------------------------------------
@@ -393,7 +357,7 @@ def test_cluster_two_worker_ledger_merge(tmp_path):
 
 
 def test_parallel_idle_sources_cannot_exceed_share_one():
-    """Regression for the BENCH_r10 ad-ctr phase_breakdown: four
+    """Regression for an ad-ctr phase breakdown: four
     sources each parked ~the whole epoch summed to barrier_wait share
     1.05. Idle is keyed per source and the seal folds the MAX (the
     parks are concurrent), capped at the interval — the share can

@@ -103,7 +103,7 @@ def test_kill_with_uploads_in_flight_recovers_and_matches_oracle():
                       min_chunks=4)
         await fe.execute(DDL)
         loop = fe.loop
-        # bench-style pipelined driving: no uploader drain between
+        # pipelined driving: no uploader drain between
         # barriers, so uploads pile up behind the slow store
         for _ in range(6):
             while loop.in_flight_count < 2:

@@ -169,3 +169,71 @@ def test_filter_on_non_aggregate_rejected():
         await fe.close()
 
     _run(run())
+
+
+# -- the recorders' retired on/off options ---------------------------------
+
+RETIRED = ("stream_trace", "stream_ledger", "stream_tricolor",
+           "stream_costs")
+
+
+@pytest.mark.parametrize("name", RETIRED)
+def test_retired_recorder_option_refused_live(name):
+    """The recorders have no switch: a SET or SHOW of a retired name
+    answers like any unknown name, and SHOW ALL does not list it."""
+    async def run():
+        fe = Frontend(min_chunks=4)
+        with pytest.raises(PlanError, match="unrecognized"):
+            await fe.execute(f"SET {name} = 'off'")
+        with pytest.raises(PlanError, match="unrecognized"):
+            await fe.execute(f"SHOW {name}")
+        shown = dict(await fe.execute("SHOW ALL"))
+        await fe.close()
+        return shown
+
+    assert name not in _run(run())
+
+
+@pytest.mark.parametrize("name", RETIRED)
+def test_ddl_log_with_retired_recorder_set_recovers(name, tmp_path):
+    """A data dir written by an earlier build may hold the SET in its
+    DDL log: replay skips it, the views recover and serve, and the
+    recorder it once switched off records."""
+    import json
+
+    from risingwave_tpu.storage.hummock import HummockLite
+    from risingwave_tpu.storage.object_store import LocalFsObjectStore
+    from risingwave_tpu.utils.spans import EPOCH_TRACER
+
+    async def run():
+        obj = LocalFsObjectStore(str(tmp_path))
+        fe = Frontend(HummockLite(obj), min_chunks=4)
+        await fe.execute(
+            "CREATE SOURCE bid WITH (connector='nexmark', "
+            "nexmark.table.type='bid', nexmark.event.num=2000)")
+        await fe.execute(
+            "CREATE MATERIALIZED VIEW m AS SELECT auction, count(*) "
+            "AS c FROM bid GROUP BY auction")
+        await fe.step(3)
+        before = await fe.execute("SELECT * FROM m")
+        await fe.close()
+        log = json.loads(obj.read("meta/ddl.json").decode())
+        obj.upload("meta/ddl.json", json.dumps(
+            [f"SET {name} = 'off'"] + log).encode())
+
+        EPOCH_TRACER.clear()
+        fe2 = Frontend(HummockLite(LocalFsObjectStore(str(tmp_path))),
+                       min_chunks=4)
+        replayed = await fe2.recover()
+        recovered = await fe2.execute("SELECT * FROM m")
+        await fe2.step(2)
+        after = await fe2.execute("SELECT * FROM m")
+        spans = await fe2.execute("SELECT * FROM rw_epoch_trace")
+        await fe2.close()
+        return before, replayed, len(log), recovered, after, spans
+
+    before, replayed, n_log, recovered, after, spans = _run(run())
+    assert before and replayed == n_log + 1
+    assert sorted(recovered) == sorted(before)
+    assert sum(c for _a, c in after) >= sum(c for _a, c in before)
+    assert spans

@@ -251,22 +251,6 @@ def test_actor_dispatch_park_lands_in_root_backpressure():
     assert parked > 0.0
 
 
-def test_tricolor_off_publishes_nothing():
-    from risingwave_tpu.stream import monitor as _monitor
-    _monitor.set_tricolor(False)
-    try:
-        script = [_barrier(0), _chunk(), _barrier(2), _chunk(),
-                  _barrier(4)]
-        feed = IdleFeed(script, idle_s=0.0, ident="MockSource")
-        consumer = install_monitoring(CheapPass(feed),
-                                      fragment="tri-off", actor_id=43)
-        asyncio.run(_drive(consumer, 3))
-        assert not [r for r in UTILIZATION.rows() if r[1] == "tri-off"]
-    finally:
-        _monitor.set_tricolor(True)
-        TOPOLOGY.drop_actor(43)
-
-
 def test_metric_families_sorted_with_help():
     """ctl metrics exposition: families render in sorted order and
     every ISSUE-14 family carries a HELP line, so round-over-round
