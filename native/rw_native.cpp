@@ -10,6 +10,7 @@
 //
 // Build: g++ -O2 -shared -fPIC -o librw_native.so rw_native.cpp
 
+#include <algorithm>
 #include <cstdint>
 #include <cstring>
 
@@ -330,6 +331,154 @@ long rw_merge_gc(int32_t k,
     delete[] heads;
     *entries_in = n_in;
     return rc < 0 ? rc : n_out;
+}
+
+// ---- checkpoint build: imms to one sorted run -----------------------
+//
+// HummockLite._build_ssts (storage/hummock.py) turns the imms it drains
+// into one columnar run with the three passes below and hands it to
+// sst.RunWriter. Their Python twins are the scalar functions the row
+// path calls per entry: sst.full_key, value_codec.encode_row, and the
+// sort of the (full key, ...) tuples.
+
+// Full keys of one (table, epoch) batch of user keys laid back to back:
+// head (the table id, 4 B big-endian) ++ escaped user key (0x00 ->
+// 0x00 0xFF, then the terminator 0x00 0x00) ++ tail (the inverted
+// epoch, 8 B big-endian). Byte for byte sst.full_key, which packs head
+// and tail for it. Returns the bytes written, or -1 if out_cap is too
+// small.
+long rw_full_keys(const uint8_t* users, const int32_t* user_lens, long n,
+                  const uint8_t* head, const uint8_t* tail,
+                  uint8_t* out, long out_cap, int32_t* out_lens) {
+    const uint8_t* up = users;
+    long pos = 0;
+    for (long i = 0; i < n; i++) {
+        long ul = user_lens[i];
+        if (pos + 14 + 2 * ul > out_cap) return -1;
+        long start = pos;
+        memcpy(out + pos, head, 4);
+        pos += 4;
+        for (long j = 0; j < ul; j++) {
+            out[pos++] = up[j];
+            if (up[j] == 0) out[pos++] = 0xFF;
+        }
+        out[pos++] = 0;
+        out[pos++] = 0;
+        memcpy(out + pos, tail, 8);
+        pos += 8;
+        out_lens[i] = (int32_t)(pos - start);
+        up += ul;
+    }
+    return pos;
+}
+
+// Stored values of one table's batch, from its rows held by the
+// column. Entry i is a tombstone where tombs[i] (one byte, 0x01), else
+// the next row: 0x00, the arity varint, then per column the value's
+// tag and payload exactly as value_codec.encode_row writes them.
+// Column c is kinds[c]:
+//   0  every row NULL                   data unused
+//   1  int64      (tag 1, zigzag varint)
+//   2  float64    (tag 2, 8 bytes little-endian)
+//   3  str, 4 bytes (tag 3 / 6, varint length, the bytes): data[c] is
+//      the rows' bytes back to back, lens[c] their int32 lengths
+//   5  bool       (tag 4 true / 5 false), one byte a row
+// valid[c] is null where column c holds no NULL, else one byte a row
+// (0 = NULL, tag 0). Wants 11 bytes free before each entry and before
+// each value whatever its kind, so out_cap is 11 * (n + rows * ncols)
+// + the blobs' bytes (value_codec.encode_values). Returns the bytes
+// written, or -1 if out_cap is too small, -2 on a kind it does not know.
+long rw_encode_rows(long n, const uint8_t* tombs, int32_t ncols,
+                    const int32_t* kinds, const void* const* data,
+                    const uint8_t* const* valid,
+                    const int32_t* const* lens,
+                    uint8_t* out, long out_cap, int32_t* out_lens) {
+    long* blob_at = new long[ncols > 0 ? ncols : 1]();
+    long pos = 0, r = 0, rc = 0;
+    for (long i = 0; i < n && rc == 0; i++) {
+        long start = pos;
+        if (pos + 11 > out_cap) { rc = -1; break; }
+        if (tombs[i]) {
+            out[pos++] = 1;
+            out_lens[i] = 1;
+            continue;
+        }
+        out[pos++] = 0;
+        pos = put_uvarint(out, pos, (uint64_t)ncols);
+        for (int32_t c = 0; c < ncols; c++) {
+            if (pos + 11 > out_cap) { rc = -1; break; }
+            if (kinds[c] == 0 || (valid[c] != nullptr && !valid[c][r])) {
+                // a NULL of a str / bytes column has length 0
+                out[pos++] = 0;
+                continue;
+            }
+            switch (kinds[c]) {
+            case 1: {
+                int64_t v = ((const int64_t*)data[c])[r];
+                out[pos++] = 1;
+                pos = put_uvarint(
+                    out, pos, ((uint64_t)v << 1) ^ (uint64_t)(v >> 63));
+                break;
+            }
+            case 2:
+                // the host is little-endian, as struct's "<d" writes
+                out[pos++] = 2;
+                memcpy(out + pos, (const double*)data[c] + r, 8);
+                pos += 8;
+                break;
+            case 3:
+            case 4: {
+                long ln = lens[c][r];
+                if (pos + 11 + ln > out_cap) { rc = -1; break; }
+                out[pos++] = kinds[c] == 3 ? 3 : 6;
+                pos = put_uvarint(out, pos, (uint64_t)ln);
+                memcpy(out + pos, (const uint8_t*)data[c] + blob_at[c],
+                       (size_t)ln);
+                pos += ln;
+                blob_at[c] += ln;
+                break;
+            }
+            case 5:
+                out[pos++] = ((const uint8_t*)data[c])[r] ? 4 : 5;
+                break;
+            default:
+                rc = -2;
+            }
+            if (rc != 0) break;
+        }
+        out_lens[i] = (int32_t)(pos - start);
+        r++;
+    }
+    delete[] blob_at;
+    return rc < 0 ? rc : pos;
+}
+
+// The order of n keys laid back to back (koff: their n + 1 offsets):
+// perm[i] is the entry that comes i-th in bytewise order, a shorter
+// key before the longer one it is a prefix of. What sorting the keys
+// as Python bytes gives. Returns 0, or -1 if two keys are equal (a
+// drain never holds one full key twice; SstBuilder.add refuses it).
+long rw_argsort_keys(const uint8_t* keys, const int64_t* koff, long n,
+                     int64_t* perm) {
+    for (long i = 0; i < n; i++) perm[i] = i;
+    auto cmp = [&](int64_t a, int64_t b) {
+        long la = (long)(koff[a + 1] - koff[a]);
+        long lb = (long)(koff[b + 1] - koff[b]);
+        return memcmp(keys + koff[a], keys + koff[b],
+                      (size_t)(la < lb ? la : lb));
+    };
+    std::sort(perm, perm + n, [&](int64_t a, int64_t b) {
+        int c = cmp(a, b);
+        if (c != 0) return c < 0;
+        return koff[a + 1] - koff[a] < koff[b + 1] - koff[b];
+    });
+    for (long i = 1; i < n; i++) {
+        int64_t a = perm[i - 1], b = perm[i];
+        if (koff[a + 1] - koff[a] == koff[b + 1] - koff[b]
+                && cmp(a, b) == 0)
+            return -1;
+    }
+    return 0;
 }
 
 }  // extern "C"

@@ -261,12 +261,17 @@ def record_checkpoint_tail(profs: List[EpochProfile],
         values = dict.fromkeys(
             ("ckpt.queue_s", "ckpt.build_s", "ckpt.put_s",
              "ckpt.commit_s", "ckpt.compact_s", "ckpt.sst_bytes",
+             "ckpt.build_columnar_entries", "ckpt.build_row_entries",
              "ckpt.compact_read_bytes", "ckpt.compact_write_bytes"), 0.0)
         for name, _start, dur, counts in stages:
             key = "ckpt." + name.partition(".")[2]
             values[key + "_s"] = values.get(key + "_s", 0.0) + dur
             if name == "checkpoint.build":
                 values["ckpt.sst_bytes"] = float(counts["sst_bytes"])
+                values["ckpt.build_columnar_entries"] = \
+                    float(counts["columnar_entries"])
+                values["ckpt.build_row_entries"] = \
+                    float(counts["row_entries"])
             elif name == "checkpoint.compact":
                 values["ckpt.compact_read_bytes"] = \
                     float(counts["read_bytes"])

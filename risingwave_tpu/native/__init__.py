@@ -19,6 +19,16 @@ line to stderr with the compiler's (or loader's) message.
                                          row-at-a-time loop over
                                          SstBuilder (byte-identical SSTs,
                                          tests/test_compaction_merge.py)
+  rw_full_keys                           the checkpoint build's keys, a
+                                         (table, epoch) batch at a time;
+                                         twin storage/sst.full_key
+  rw_encode_rows                         its stored values, one table's
+                                         rows by the column; twin
+                                         storage/value_codec.encode_row
+  rw_argsort_keys                        its order; twin: sorting the
+                                         full keys as Python bytes
+                                         (the three: byte-identical SSTs,
+                                         tests/test_checkpoint_build.py)
 """
 
 from __future__ import annotations
@@ -118,6 +128,15 @@ def lib() -> Optional[ctypes.CDLL]:
                 vp, ctypes.c_long, ctypes.c_uint64, ctypes.c_int32,
                 vp, ctypes.c_long, vp, vp, ctypes.c_long, vp,
                 ctypes.c_long, ctypes.POINTER(ctypes.c_int64)]
+            l.rw_full_keys.restype = ctypes.c_long
+            l.rw_full_keys.argtypes = [
+                vp, vp, ctypes.c_long, vp, vp, vp, ctypes.c_long, vp]
+            l.rw_encode_rows.restype = ctypes.c_long
+            l.rw_encode_rows.argtypes = [
+                ctypes.c_long, vp, ctypes.c_int32, vp, vp, vp, vp,
+                vp, ctypes.c_long, vp]
+            l.rw_argsort_keys.restype = ctypes.c_long
+            l.rw_argsort_keys.argtypes = [vp, vp, ctypes.c_long, vp]
         except AttributeError as e:
             # a library built from an older source that the mtime
             # check took for current

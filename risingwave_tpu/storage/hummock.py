@@ -43,6 +43,7 @@ import time
 from collections import OrderedDict
 from typing import Dict, Iterable, Iterator, List, Optional, Tuple
 
+from risingwave_tpu import native as _native
 from risingwave_tpu.state.store import StateStore, Value
 from risingwave_tpu.utils import spans as _spans
 from risingwave_tpu.utils.failpoint import fail_point
@@ -51,13 +52,67 @@ from risingwave_tpu.utils.metrics import STORAGE as _METRICS
 from risingwave_tpu.storage.merge import merge_runs
 from risingwave_tpu.storage.object_store import ObjectStore
 from risingwave_tpu.storage.sst import (
-    EPOCH_MASK, LazySst, Sst, build_sst, full_key, split_full_key,
+    EPOCH_MASK, NO_CUT, LazySst, Run, RunWriter, Sst, build_sst,
+    concat_runs, full_key, full_keys, sort_run, split_full_key,
     user_prefix,
 )
-from risingwave_tpu.storage.value_codec import decode_row, encode_row
+from risingwave_tpu.storage.value_codec import (
+    decode_row, encode_row, encode_values,
+)
 
 L0_COMPACT_THRESHOLD = 4
 L1_TARGET_SST_BYTES = 4 * 1024 * 1024
+
+Imm = Tuple[int, Dict[int, Dict[bytes, Value]]]
+
+
+def _build_by_row(take: List[Imm], new_sst_id) -> Optional[tuple]:
+    """The drained imms as one SST, entry by entry: ``full_key`` and
+    ``encode_row`` per entry, a sort of the tuples, ``build_sst``.
+    The reference of the checkpoint build, and the whole of it where
+    the native library cannot be had. Returns (bytes, info, entries
+    by path), or None for an empty drain."""
+    entries: List[Tuple[bytes, bool, bytes]] = []
+    for e, tables in take:
+        for table_id, kv in tables.items():
+            for key, value in kv.items():
+                fk = full_key(table_id, key, e)
+                tomb = value is None
+                entries.append(
+                    (fk, tomb, b"" if tomb else encode_row(value)))
+    if not entries:
+        return None
+    entries.sort(key=lambda t: t[0])
+    data, info = build_sst(new_sst_id(), entries)
+    return data, info, {"columnar": 0, "row": len(entries)}
+
+
+def _build_by_column(nat, take: List[Imm], new_sst_id) -> Optional[tuple]:
+    """What ``_build_by_row`` returns, the same bytes, with no Python
+    object per entry: per (epoch, table) the full keys in one native
+    pass and the values by the column (``value_codec.encode_values``,
+    which sends a table whose columns cannot go as arrays through
+    ``encode_row`` and says so: the ``row`` count), one native sort of
+    the lot, and ``RunWriter`` with no SST cut."""
+    batches: List[Run] = []
+    by_path = {"columnar": 0, "row": 0}
+    for e, tables in take:
+        for table_id, kv in tables.items():
+            if not kv:
+                continue
+            keys, key_lens = full_keys(nat, table_id, kv, e)
+            vals, val_lens, columnar = encode_values(
+                nat, list(kv.values()))
+            batches.append(Run(keys, key_lens, vals, val_lens))
+            by_path["columnar" if columnar else "row"] += len(kv)
+    if not batches:
+        return None
+    out: List[Tuple[bytes, dict]] = []
+    RunWriter(nat, NO_CUT, new_sst_id,
+              lambda data, info: out.append((data, info))
+              ).feed(sort_run(nat, concat_runs(batches)), last=True)
+    (data, info), = out
+    return data, info, by_path
 
 
 class HummockLite(StateStore):
@@ -215,9 +270,20 @@ class HummockLite(StateStore):
         a younger epoch's build would swallow an older epoch's imms) —
         the CheckpointUploader chains them.
 
+        Two builds, one SST: ``_build_by_row`` (``full_key`` and
+        ``encode_row`` per entry, a sort of the tuples, ``build_sst``)
+        is the reference and the whole build where ``native.lib()`` is
+        None; ``_build_by_column`` returns the same bytes and ``info``
+        with no Python object per entry, and falls back to
+        ``encode_row`` for a table whose columns cannot go as arrays
+        (``tests/test_checkpoint_build.py`` holds the two together).
+        Counter ``state_store_sst_build_entries{path}`` says which
+        took how many.
+
         Synchronous on the caller's event loop: ledger phase
-        ``checkpoint``. The payload carries ``entries`` and ``tables``
-        (state tables touched) for the ``checkpoint.build`` span."""
+        ``checkpoint``. The payload carries ``entries``,
+        ``columnar_entries``, ``row_entries`` and ``tables`` (state
+        tables touched) for the ``checkpoint.build`` span."""
         with _LEDGER.phase("checkpoint"):
             return self._build_ssts(epoch)
 
@@ -225,24 +291,28 @@ class HummockLite(StateStore):
         fail_point("hummock.sync")
         take = [im for im in self._imms if im[0] <= epoch]
         self._imms = [im for im in self._imms if im[0] > epoch]
-        entries: List[Tuple[bytes, bool, bytes]] = []
         touched = set()
-        for e, tables in take:
+        for _e, tables in take:
             touched.update(tables)
-            for table_id, kv in tables.items():
-                for key, value in kv.items():
-                    fk = full_key(table_id, key, e)
-                    tomb = value is None
-                    entries.append(
-                        (fk, tomb, b"" if tomb else encode_row(value)))
-        if not entries:
+
+        def new_sst_id() -> int:
+            self._next_sst_id += 1
+            return self._next_sst_id - 1
+
+        nat = _native.lib()
+        if nat is None:
+            built = _build_by_row(take, new_sst_id)
+        else:
+            built = _build_by_column(nat, take, new_sst_id)
+        if built is None:
             return []
-        entries.sort(key=lambda t: t[0])
-        sst_id = self._next_sst_id
-        self._next_sst_id += 1
-        data, info = build_sst(sst_id, entries)
+        data, info, by_path = built
+        for path, n in by_path.items():
+            _METRICS.sst_build_entries.inc(n, path=path)
         payload = {"epoch": epoch, "sst": info, "data": data,
-                   "entries": len(entries), "tables": len(touched)}
+                   "entries": info["count"], "tables": len(touched),
+                   "columnar_entries": by_path["columnar"],
+                   "row_entries": by_path["row"]}
         self._uploading.append(payload)
         return [payload]
 

@@ -20,20 +20,32 @@ Builders take entries pre-sorted (the LSM merge guarantees it);
 everything is write-once (object-store friendly).
 
 Two ways in and out of the format. Row at a time: ``SstBuilder.add``
-and ``iter_from`` (the checkpoint build, the read path, and the
-compaction merge's Python twin). A run at a time, where the native
-library is loaded: ``decode_run`` turns an SST into a columnar ``Run``
-(keys blob, key lengths, values blob, value lengths: the four arrays
-the native block codec reads and writes) and ``RunWriter`` cuts the
-same blocks and SSTs out of ordered ``Run``s that ``SstBuilder`` cuts
-out of the same rows, byte for byte, with no Python object per row.
+and ``iter_from`` (the read path, the compaction merge's Python twin,
+and the checkpoint build where the native library is not loaded). A
+run at a time, where it is: ``decode_run`` turns an SST into a columnar
+``Run`` (keys blob, key lengths, values blob, value lengths: the four
+arrays the native block codec reads and writes) and ``RunWriter`` cuts
+the same blocks and SSTs out of ordered ``Run``s that ``SstBuilder``
+cuts out of the same rows, byte for byte, with no Python object per
+row. The compaction merge feeds it the runs it merged; the checkpoint
+build (``storage/hummock.py``) feeds it the imms it drained, made a
+``Run`` by ``full_keys``, ``value_codec.encode_values`` and
+``sort_run``.
+
+**The row path is the reference.** ``full_key``, ``encode_row`` and
+``build_sst`` over entries sorted as Python tuples define the bytes of
+an SST; every run-at-a-time function here is held to them byte for
+byte (``tests/test_checkpoint_build.py``,
+``tests/test_compaction_merge.py``).
 """
 
 from __future__ import annotations
 
 import struct
 import zlib
-from typing import Callable, Iterator, List, NamedTuple, Optional, Tuple
+from typing import (
+    Callable, Collection, Iterator, List, NamedTuple, Optional, Tuple,
+)
 
 import numpy as np
 
@@ -517,6 +529,47 @@ def concat_runs(runs: List[Run]) -> Run:
     return Run(*(np.concatenate(col) for col in zip(*runs)))
 
 
+def full_keys(nat, table_id: int, user_keys: Collection[bytes],
+              epoch: int) -> Tuple[np.ndarray, np.ndarray]:
+    """``full_key(table_id, k, epoch)`` of every ``k`` in one native
+    pass: the keys laid back to back and their int32 lengths."""
+    n = len(user_keys)
+    users = b"".join(user_keys)
+    user_lens = np.fromiter(map(len, user_keys), dtype=np.int32, count=n)
+    # table id, terminator and epoch: 14 bytes; a 0x00 escapes to two
+    cap = 14 * n + 2 * len(users)
+    keys = np.empty(cap, dtype=np.uint8)
+    key_lens = np.empty(n, dtype=np.int32)
+    size = nat.rw_full_keys(
+        users, user_lens.ctypes.data, n, struct.pack(">I", table_id),
+        struct.pack(">Q", (~epoch) & EPOCH_MASK), keys.ctypes.data, cap,
+        key_lens.ctypes.data)
+    if size < 0:
+        raise RuntimeError("rw_full_keys overran its own bound")
+    return keys[:size], key_lens
+
+
+def sort_run(nat, run: Run) -> Run:
+    """``run``'s entries in bytewise order of their keys (what sorting
+    ``(full key, ...)`` tuples gives): one native argsort over the key
+    blob, one gather of keys and values."""
+    keys, key_lens, vals, val_lens = run
+    n = len(key_lens)
+    koff, voff = offsets(key_lens), offsets(val_lens)
+    perm = np.empty(n, dtype=np.int64)
+    if nat.rw_argsort_keys(keys.ctypes.data, koff.ctypes.data, n,
+                           perm.ctypes.data) < 0:
+        raise ValueError("one full key twice in a run to sort")
+    out = Run(np.empty_like(keys), key_lens[perm],
+              np.empty_like(vals), val_lens[perm])
+    for blob, off, lens, dst in ((keys, koff, out.key_lens, out.keys),
+                                 (vals, voff, out.val_lens, out.vals)):
+        at = off[perm]
+        nat.rw_gather(blob.ctypes.data, at.ctypes.data, lens.ctypes.data,
+                      n, dst.ctypes.data)
+    return out
+
+
 def decode_run(nat, data: bytes) -> Run:
     """Every block of one SST through ``rw_block_decode``, straight
     into the four arrays. A block the native decoder refuses (a key
@@ -568,6 +621,10 @@ class _Columns(NamedTuple):
     tombs: np.ndarray          # bool
     est: np.ndarray            # running size estimate, n + 1
     user_starts: np.ndarray    # entries that open a table ++ user key
+
+
+# a ``target_bytes`` no SST reaches: a ``RunWriter`` that never cuts
+NO_CUT = 1 << 62
 
 
 class RunWriter:

@@ -43,7 +43,9 @@ happens into stages that add up to it, handed to ``on_commit`` as
   ``checkpoint.queue``       waiting behind the older epoch's build and
                              commit (two stretches, one name)
   ``checkpoint.build``       ``build_ssts``, synchronous on the loop;
-                             entries, sst_bytes, tables
+                             entries, columnar_entries, row_entries
+                             (which build path took them:
+                             storage/hummock.py), sst_bytes, tables
   ``checkpoint.put``         the object-store PUTs, off the loop;
                              objects, bytes, retries
   ``checkpoint.sink_stage``  sink staging, where a sink is attached
@@ -59,7 +61,8 @@ into spans under the sealing barrier's ``checkpoint.upload`` and into
 the ``ckpt.*`` names of that barrier's ``rw_metrics_history`` row:
 ``ckpt.queue_s``, ``ckpt.build_s``, ``ckpt.put_s``, ``ckpt.commit_s``,
 ``ckpt.compact_s`` (0 where the commit did not compact),
-``ckpt.sst_bytes``, ``ckpt.compact_read_bytes``,
+``ckpt.sst_bytes``, ``ckpt.build_columnar_entries``,
+``ckpt.build_row_entries``, ``ckpt.compact_read_bytes``,
 ``ckpt.compact_write_bytes``, and ``ckpt.sink_stage_s`` with a sink.
 The synchronous stages are also the ledger's LOOP phases
 (``checkpoint``, ``compaction``: utils/ledger.py, "Stolen loop time"),
@@ -236,10 +239,10 @@ class CheckpointUploader:
                 if not built.done():
                     built.set_result(None)
             nbytes = sum(len(p["data"]) for p in payloads)
-            mark("checkpoint.build",
-                 entries=sum(p.get("entries", 0) for p in payloads),
-                 sst_bytes=nbytes,
-                 tables=sum(p.get("tables", 0) for p in payloads))
+            mark("checkpoint.build", sst_bytes=nbytes,
+                 **{name: sum(p.get(name, 0) for p in payloads)
+                    for name in ("entries", "columnar_entries",
+                                 "row_entries", "tables")})
             retries = 0
             with _spans.annotation("checkpoint.put", epoch):
                 for p in payloads:

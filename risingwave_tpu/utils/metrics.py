@@ -557,6 +557,13 @@ class StorageMetrics:
         self.sst_upload_bytes = r.counter(
             "state_store_sst_upload_bytes",
             "bytes of SST data uploaded")
+        self.sst_build_entries = r.counter(
+            "state_store_sst_build_entries",
+            "entries the checkpoint build put into SSTs, by path "
+            "(columnar: a table's batch encoded by the column inside the "
+            "native library; row: value_codec.encode_row per entry, for "
+            "a batch whose columns cannot go as arrays, and for every "
+            "entry where the native library is not loaded)")
         self.sst_upload_retries = r.counter(
             "state_store_sst_upload_retry_count",
             "checkpoint SST uploads retried after a transient failure")
