@@ -85,6 +85,10 @@ async def serving(data_dir, host: str = "127.0.0.1", port: int = 4566,
         # (jittered-backoff retries) instead of failing a barrier round
         store = HummockLite(
             RetryingObjectStore(LocalFsObjectStore(data_dir)))
+        # this process compacts: one level of allocator behaviour, not
+        # the one its allocation history happens to leave it
+        from risingwave_tpu.utils.memory import keep_freed_heap
+        keep_freed_heap()
     else:
         from risingwave_tpu.state.store import MemoryStateStore
         store = MemoryStateStore()

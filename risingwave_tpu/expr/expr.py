@@ -582,6 +582,30 @@ def _tumble_start(rt: DataType, ts: Column, window: Column) -> Column:
     return Column(rt, out, ts.validity)
 
 
+@register_function("avg_quotient")
+def _avg_quotient(rt: DataType, total: Column, count: Column) -> Column:
+    """AVG over an integer column: the float64 nearest to the exact
+    quotient of the exact integer SUM by the COUNT, which is what
+    Python's ``int / int`` gives. Always on the host, in numpy: the
+    v5e has no float64, so the function is not in ops/fused.py's
+    TRACEABLE_FUNCS and a column that lives on the device is fetched
+    (a handful of groups a barrier). Where both fit in 53 bits the
+    float64 division of the two rounds once and is that quotient;
+    past 2^53 the cast of the sum would round first, so those rows
+    divide as Python ints."""
+    s = np.asarray(total.values).astype(np.int64)
+    c = np.asarray(count.values).astype(np.int64)
+    zero = c == 0
+    c = np.where(zero, np.int64(1), c)
+    out = s.astype(np.float64) / c.astype(np.float64)
+    lim = np.int64(1) << 53
+    for i in np.flatnonzero((s > lim) | (s < -lim) | (c > lim)):
+        out[i] = int(s[i]) / int(c[i])
+    validity = _merge_validity(
+        _merge_validity(total.validity, count.validity), ~zero)
+    return Column(rt, out, validity)
+
+
 @register_function("tumble_end")
 def _tumble_end(rt: DataType, ts: Column, window: Column) -> Column:
     w = _window_usecs(window)

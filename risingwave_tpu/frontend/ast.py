@@ -128,7 +128,9 @@ FromItem = object            # TableRef | Tumble | Hop | TableFn | Subquery
 @dataclass
 class Join:
     item: FromItem
-    on: Expr
+    # None for a comma-separated FROM item: the planner takes the
+    # equalities across the two items out of the WHERE
+    on: Optional[Expr]
     kind: str = "inner"   # inner|left|right|full (OUTER implied)
     # JOIN ... FOR SYSTEM_TIME AS OF PROCTIME(): probe the right side
     # as a versioned table at process time (temporal join)

@@ -482,6 +482,9 @@ class PostAggBinder:
         self.binder = binder
         self.group_reprs = group_reprs
         self.g = len(group_reprs)
+        # where an AVG's division runs, once one is bound: "host" for
+        # avg_quotient, a host function wherever its inputs live
+        self.avg_division: Optional[str] = None
 
     def bind(self, e: ast.Expr):
         from risingwave_tpu.expr.expr import Cast
@@ -493,13 +496,16 @@ class PostAggBinder:
                 return InputRef(self.g + j, self.binder.agg_out_type(j))
             if isinstance(b, tuple) and b[0] == "avg":
                 _tag, sj, cj = b
-                s = Cast(InputRef(self.g + sj,
-                                  self.binder.agg_out_type(sj)),
-                         DataType.FLOAT64)
-                c = Cast(InputRef(self.g + cj,
-                                  self.binder.agg_out_type(cj)),
-                         DataType.FLOAT64)
-                return BinaryOp("/", s, c)
+                total = InputRef(self.g + sj, self.binder.agg_out_type(sj))
+                count = InputRef(self.g + cj, self.binder.agg_out_type(cj))
+                if total.return_type == DataType.INT64:
+                    # an integer sum is exact, and so is its average:
+                    # one rounding, on the host (expr.py avg_quotient)
+                    self.avg_division = "host"
+                    return FuncCall("avg_quotient", [total, count],
+                                    DataType.FLOAT64)
+                return BinaryOp("/", Cast(total, DataType.FLOAT64),
+                                Cast(count, DataType.FLOAT64))
             return b
         # whole expression matches a GROUP BY expression → group col
         try:

@@ -592,6 +592,7 @@ def _prune_join(j, live_full: Set[int], stats) -> tuple:
         state_cap=opts.get("state_cap"),
         device_payload=opts.get("device_payload", True),
         epoch_batch=opts.get("epoch_batch"))
+    new.feeds_agg = j.feeds_agg          # the planner's mark
     mapping = {old: new_i for old, new_i in lmap.items()}
     n_left_new = len(lnew.schema)
     for old, new_i in rmap.items():

@@ -377,6 +377,11 @@ class Parser:
         if self._kw("from"):
             from_item = self._from_item()
             while True:
+                if self._op(","):
+                    # FROM a, b: an inner join whose condition the
+                    # planner takes from the WHERE (on=None)
+                    joins.append(ast.Join(self._from_item(), None))
+                    continue
                 kind = None
                 if self._kw("join") or self._kw("inner", "join"):
                     kind = "inner"

@@ -686,7 +686,8 @@ class StreamPlanner:
                 if getattr(jn, "temporal", False):
                     from risingwave_tpu.stream.executors.temporal_join \
                         import TemporalJoinExecutor
-                    lkeys, rkeys = _equi_keys(jn.on, lscope, rscope)
+                    lkeys, rkeys = _join_keys(jn, conjuncts, lscope,
+                                              rscope)
                     if sorted(rkeys) != sorted(right.pk_indices):
                         raise PlanError(
                             "temporal join ON keys must equal the "
@@ -701,7 +702,7 @@ class StreamPlanner:
                         actor_id=actor_id)
                     lscope = lscope.concat(rscope)
                     continue
-                lkeys, rkeys = _equi_keys(jn.on, lscope, rscope)
+                lkeys, rkeys = _join_keys(jn, conjuncts, lscope, rscope)
                 jt = {"inner": JoinType.INNER,
                       "left": JoinType.LEFT_OUTER,
                       "right": JoinType.RIGHT_OUTER,
@@ -811,7 +812,8 @@ class StreamPlanner:
                 # LogicalFilter over the agg)
                 ex = FilterExecutor(ex, having_pred)
             ex = ProjectExecutor(ex, out_exprs, names,
-                                 watermark_derivations=derivs)
+                                 watermark_derivations=derivs,
+                                 span_args=self._agg_span_args)
             # EOWC window column: the first group key that PROVABLY
             # carries a watermark all the way from the source (a gate
             # with no watermark feed would hold results forever)
@@ -1102,6 +1104,9 @@ class StreamPlanner:
         group_reprs = [repr(g) for g in group_bound]
         pab = PostAggBinder(binder, group_reprs)
         bound = [pab.bind(e) for e, _a in projections]
+        # for the span of the projection these expressions run in
+        self._agg_span_args = {"avg_division": pab.avg_division} \
+            if pab.avg_division else {}
         having_pred = None
         if sel.having is not None:
             having_pred = pab.bind(sel.having)
@@ -1188,6 +1193,14 @@ class StreamPlanner:
                               # evict path)
                               tier_cap=self.state_tier_cap
                               if kernel is None else None)
+        join = ex
+        while isinstance(join, FilterExecutor):
+            join = join.input
+        if isinstance(join, HashJoinExecutor):
+            # a join whose output reaches this aggregate through the
+            # WHERE's residual filters and the projection above only:
+            # both ends book the hand-off (trace_ctx.join_to_agg_handoff)
+            join.feeds_agg = agg.fed_by_join = True
         # bound items are already typed refs over the agg output row
         return agg, bound, having_pred
 
@@ -1328,6 +1341,48 @@ def _equi_keys(on: ast.Expr, lscope: Scope, rscope: Scope
         for tag, idx in sides:
             (lkeys if tag == "l" else rkeys).append(idx)
     return lkeys, rkeys
+
+
+def _join_keys(jn: ast.Join, conjuncts: List[ast.Expr], lscope: Scope,
+               rscope: Scope) -> Tuple[List[int], List[int]]:
+    """The hash keys of one join of the left-deep chain. `JOIN … ON`
+    gives them itself. A comma-separated FROM item (`on` is None) takes
+    every WHERE conjunct `column = column` with one column on each side
+    and removes it from `conjuncts`; what stays there is the residual,
+    filtered above the chain like any other WHERE. A conjunct that
+    names a later item of the list resolves on neither side yet and is
+    left for that item's join."""
+    if jn.on is not None:
+        return _equi_keys(jn.on, lscope, rscope)
+    both, n_left = lscope.concat(rscope), len(lscope.schema)
+    lkeys: List[int] = []
+    rkeys: List[int] = []
+    for c in list(conjuncts):
+        if not (isinstance(c, ast.Bin) and c.op == "="
+                and isinstance(c.left, ast.ColRef)
+                and isinstance(c.right, ast.ColRef)):
+            continue
+        try:
+            idx = sorted(both.find(col.name, col.table)[0]
+                         for col in (c.left, c.right))
+        except BindError:
+            continue
+        if idx[0] < n_left <= idx[1]:
+            lkeys.append(idx[0])
+            rkeys.append(idx[1] - n_left)
+            conjuncts.remove(c)
+    if not lkeys:
+        raise BindError(
+            "a comma-separated FROM list needs a WHERE equality "
+            "column = column between each item and the items before "
+            "it (the hash join's key): none found for "
+            f"{_item_name(jn.item)!r}; a cross product is not planned")
+    return lkeys, rkeys
+
+
+def _item_name(item) -> str:
+    return getattr(item, "alias", None) or getattr(item, "name", None) \
+        or type(item).__name__
 
 
 def _system_catalog_rows(name: str, catalog: Catalog, profiler=None):
@@ -1800,13 +1855,14 @@ def plan_batch(sel: ast.Select, catalog: Catalog, store, epoch: int,
         return BatchValues(sch, [row])
 
     ex, scope = scan(sel.from_item)
+    conjuncts = _flatten_and(sel.where) if sel.where is not None else []
     for jn in sel.joins:
         rex, rscope = scan(jn.item)
-        lkeys, rkeys = _equi_keys(jn.on, scope, rscope)
+        lkeys, rkeys = _join_keys(jn, conjuncts, scope, rscope)
         ex = BatchHashJoin(ex, rex, lkeys, rkeys)
         scope = scope.concat(rscope)
-    if sel.where is not None:
-        ex = BatchFilter(ex, Binder(scope).bind(sel.where))
+    for c in conjuncts:
+        ex = BatchFilter(ex, Binder(scope).bind(c))
     projections = _expand_star(sel.projections, scope)
     from risingwave_tpu.frontend.binder import PostAggBinder, contains_agg
     binder = Binder(scope, allow_aggs=True)

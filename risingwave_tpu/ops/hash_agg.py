@@ -525,7 +525,8 @@ def build_apply(key_width: int, specs: Sequence[AggSpec],
                 prelude=None):
     """Compile the per-chunk step for a fixed agg plan.
 
-    step(state, packed int32[N, W]) → (state, n_inserted int32 scalar).
+    step(state, packed int32[N, W]) → (state, int32[2] = [n_inserted,
+    rounds of probe_insert's loop]).
     The packed matrix comes from ``pack_chunk``; jit-cached per (cap, N).
     The insert counter is the sync-free occupancy feed: the host wrapper
     fetches it asynchronously (jaxtools.fetch) so growth decisions never
@@ -544,7 +545,9 @@ def build_apply(key_width: int, specs: Sequence[AggSpec],
 
     def core(state: AggState, key_lanes, s32, vis, call_inputs):
         cap = state.table.capacity
-        table, slots, ins = ht.probe_insert(state.table, key_lanes, vis)
+        # ins: [n_inserted, rounds of the claim loop] (PendingCounters)
+        table, slots, ins = ht.probe_insert_counted(state.table,
+                                                    key_lanes, vis)
         scat = jnp.where(vis, slots, cap)   # invisible rows dropped
         group_rows = state.group_rows.at[scat].add(s32, mode="drop")
         dirty = state.dirty.at[scat].set(True, mode="drop")
@@ -1019,7 +1022,7 @@ class GroupedAggKernel:
         while at < n:
             room = self.BATCH_ROWS - self._backlog_rows
             if room <= 0:
-                self._dispatch_backlog()
+                self.dispatch_backlog()
                 continue
             take = min(n - at, room)
             self._backlog.append(
@@ -1027,7 +1030,7 @@ class GroupedAggKernel:
             self._backlog_rows += take
             at += take
             if self._backlog_rows >= self.BATCH_ROWS:
-                self._dispatch_backlog()
+                self.dispatch_backlog()
 
     def apply_raw(self, raw: np.ndarray, n_visible: int) -> None:
         """Fused-fragment hot path: backlog one RAW chunk matrix
@@ -1039,16 +1042,16 @@ class GroupedAggKernel:
             "apply_raw needs a fused (prelude) kernel"
         n = raw.shape[0] + 1
         if self._backlog_rows + n > self.BATCH_ROWS:
-            self._dispatch_backlog()
+            self.dispatch_backlog()
         self._backlog.append(raw)
         self._backlog.append(np.zeros((1, raw.shape[1]),
                                       dtype=np.int64))   # separator
         self._backlog_rows += n
         self._backlog_vis += int(n_visible)
         if self._backlog_rows >= self.BATCH_ROWS:
-            self._dispatch_backlog()
+            self.dispatch_backlog()
 
-    def _dispatch_backlog(self) -> None:
+    def dispatch_backlog(self) -> None:
         if not self._backlog:
             return
         mats, n = self._backlog, self._backlog_rows
@@ -1095,6 +1098,12 @@ class GroupedAggKernel:
                     self.state,
                     jaxtools.upload(packed, kernel=self._span_label))
         self._counters.push(ins, n)
+
+    def take_probe_rounds(self) -> tuple:
+        """(rounds of probe_insert's loop, steps) since the last call.
+        After a flush it covers every step of the epoch: the flush
+        adopted the gather's exact count and read the steps' counters."""
+        return self._counters.take_rounds()
 
     def drain_stage_rows(self) -> Optional[np.ndarray]:
         """Sum of per-stage visible-row counts since the last drain
@@ -1194,7 +1203,7 @@ class GroupedAggKernel:
             return
         # the reload must land before any buffered chunk that may touch
         # the same (still-cold-looking) keys could dispatch after it
-        self._dispatch_backlog()
+        self.dispatch_backlog()
         self._reserve(n)
         dev_cols = encode_host_accs(self.specs, acc_cols)
         table, slots, ins = ht._probe_insert_jit(
@@ -1221,7 +1230,7 @@ class GroupedAggKernel:
         """Gather dirty groups to host and decode — ONE device→host
         transfer. Call ``advance`` after consuming (optionally
         ``patch_accs`` in between)."""
-        self._dispatch_backlog()
+        self.dispatch_backlog()
         while True:
             with spans.dispatch_span(f"{self._span_label}.flush",
                                      self._counters.bound()):

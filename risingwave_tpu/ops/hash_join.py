@@ -217,7 +217,9 @@ def epoch_apply(table: ht.TableState, chains: ChainState,
     ins_mask = (flags & FLAG_INS) != 0
     del_mask = (flags & FLAG_DEL) != 0
     seq = aux[:, AUX_SEQ]
-    table2, slots, ins = ht.probe_insert(table, key_lanes, ins_mask)
+    # ins: [n_inserted, rounds of the claim loop] (PendingCounters)
+    table2, slots, ins = ht.probe_insert_counted(table, key_lanes,
+                                                 ins_mask)
     chains2 = link_rows(chains, slots, aux[:, AUX_INS_REF], ins_mask,
                         table2.capacity, seq)
     chains2 = tombstone_rows(chains2, aux[:, AUX_DEL_REF], del_mask, seq)
@@ -778,6 +780,13 @@ class JoinSideKernel:
             self.table.state, self.chains, self.pay, up_dev, aux_dev,
             self.key_width)
         self.table._counters.push(ins, n_rows)
+
+    def take_probe_rounds(self) -> tuple:
+        """(rounds of probe_insert's loop, applies) since the last
+        call, of the applies whose counters have landed: all of them
+        once a probe dispatched after them has been collected."""
+        self.table._counters.drain_ready()
+        return self.table._counters.take_rounds()
 
     def probe_epoch(self, up_dev, aux_dev, with_degrees: bool,
                     sink: "JoinSideKernel" = None, prelude=None,

@@ -96,6 +96,20 @@ def probe_insert(state: TableState, batch_keys: jnp.ndarray,
     enforced by DeviceHashTable) — under that contract the loop terminates
     before ``cap`` steps.
     """
+    table, slots, stat = probe_insert_counted(state, batch_keys, valid)
+    return table, slots, stat[0]
+
+
+def probe_insert_counted(state: TableState, batch_keys: jnp.ndarray,
+                         valid: jnp.ndarray
+                         ) -> Tuple[TableState, jnp.ndarray, jnp.ndarray]:
+    """``probe_insert`` with its loop's length: the third result is
+    int32[2] = [n_inserted, rounds]. A round is one pass of the claim
+    loop over the whole batch; a batch needs as many as its longest
+    probe chain, and a key that many rows of the batch share costs
+    one more where it is new (the losers of its claim match it in the
+    round after). jaxtools.PendingCounters takes the pair wherever it
+    takes the count."""
     assert batch_keys.dtype == jnp.int32, \
         "keys must be int32 lanes (lanes.split_i64 for 64-bit values)"
     cap = state.capacity
@@ -129,9 +143,9 @@ def probe_insert(state: TableState, batch_keys: jnp.ndarray,
         return slot, done, keys, occ, steps + 1, ins
 
     init = (slot0, ~valid, state.keys, state.occ, jnp.int32(0), jnp.int32(0))
-    slot, done, keys, occ, _steps, ins = jax.lax.while_loop(cond, body, init)
+    slot, done, keys, occ, steps, ins = jax.lax.while_loop(cond, body, init)
     slots = jnp.where(valid, slot, jnp.int32(-1))
-    return TableState(keys, occ), slots, ins
+    return TableState(keys, occ), slots, jnp.stack([ins, steps])
 
 
 def lookup(state: TableState, batch_keys: jnp.ndarray,

@@ -465,7 +465,7 @@ class ShardedAggKernel:
                                 np.asarray(vis), inputs)
         n = packed.shape[0]
         if self._backlog_rows + n > self.BATCH_ROWS:
-            self._dispatch_backlog()
+            self.dispatch_backlog()
         self._backlog.append(packed)
         self._backlog_rows += n
         # growth decisions run per buffered chunk (pessimistic bound
@@ -474,7 +474,7 @@ class ShardedAggKernel:
         self._reserve(self._backlog_rows)
         if not self.epoch_batch or \
                 self._backlog_rows >= self.BATCH_ROWS:
-            self._dispatch_backlog()
+            self.dispatch_backlog()
 
     def owners_of(self, key_lanes: np.ndarray) -> np.ndarray:
         """Host twin of the device vnode routing (the executor feeds
@@ -500,7 +500,7 @@ class ShardedAggKernel:
         self._touched = True
         n = raw.shape[0] + 1
         if self._backlog_rows + n > self.BATCH_ROWS:
-            self._dispatch_backlog()
+            self.dispatch_backlog()
         self._backlog.append(raw)
         self._backlog.append(np.zeros((1, raw.shape[1]),
                                       dtype=np.int64))   # separator
@@ -516,9 +516,9 @@ class ShardedAggKernel:
         self._reserve(self._backlog_rows)
         if not self.epoch_batch or \
                 self._backlog_rows >= self.BATCH_ROWS:
-            self._dispatch_backlog()
+            self.dispatch_backlog()
 
-    def _dispatch_backlog(self) -> None:
+    def dispatch_backlog(self) -> None:
         """Ship the buffered epoch rows as ONE SPMD dispatch: pad to
         the fixed batch shape (one compiled program; pad rows are
         invisible and route nowhere), route every row to its vnode
@@ -686,7 +686,7 @@ class ShardedAggKernel:
         logic runs unchanged on it."""
         # the epoch's buffered rows ship as ONE SPMD dispatch here —
         # the barrier IS the sharded batch boundary (ISSUE 10)
-        self._dispatch_backlog()
+        self.dispatch_backlog()
         # drain next: reset() would discard pending bucket-overflow
         # flags, and an overflow MUST surface before this barrier's
         # results are treated as complete
@@ -961,7 +961,7 @@ class ShardedAggKernel:
     # -- host-side full decode (tests + dryrun assertions) ---------------
     def snapshot(self) -> Dict[tuple, tuple]:
         """group key lanes tuple → decoded outputs, across all shards."""
-        self._dispatch_backlog()
+        self.dispatch_backlog()
         self._counters.drain_all()
         st = jax.device_get(self.state)
         out: Dict[tuple, tuple] = {}

@@ -45,6 +45,7 @@ from risingwave_tpu.stream.message import (
     Barrier, Message, Watermark, is_barrier, is_chunk, is_watermark,
 )
 from risingwave_tpu.stream import hotkeys as _hotkeys
+from risingwave_tpu.stream.trace_ctx import join_to_agg_handoff
 from risingwave_tpu.utils.metrics import STREAMING as _METRICS
 
 _SUM_OUT = {
@@ -226,6 +227,12 @@ class HashAggExecutor(Executor):
         self.group_indices = list(group_indices)
         self.agg_calls = list(agg_calls)
         self.table = table
+        # the label of this executor's per-batch books (utils/metrics
+        # MetricsHistory._batch_books)
+        self._books_table = f"t{table.table_id}"
+        # the planner's mark: this aggregate was planned over a hash
+        # join, so its ingest is a leg of the join -> aggregate hand-off
+        self.fed_by_join = False
         self.append_only = append_only
         # fragment fusion (ops/fused.py): when set, `input_` is the RAW
         # upstream and the filter/project run in `fused_stages` inlines
@@ -524,6 +531,12 @@ class HashAggExecutor(Executor):
         return tuple(out)
 
     def _apply_chunk(self, chunk: StreamChunk) -> None:
+        ops = np.asarray(chunk.ops)[np.asarray(chunk.visibility)]
+        for op, n in enumerate(np.bincount(ops, minlength=5)):
+            if n:
+                _METRICS.agg_input_rows.inc(
+                    float(n), table=self._books_table,
+                    op=Op(op).name.lower())
         if self.fused_stages is not None:
             # fused fragment path: the RAW chunk ships as one int64
             # matrix; filter/project/key-encode/lane-encode all run
@@ -1018,6 +1031,10 @@ class HashAggExecutor(Executor):
         return self.key_codec.decode(keys)
 
     def _flush(self) -> Optional[StreamChunk]:
+        # the hand-off's last leg: the backlog packed, uploaded and
+        # dispatched (the kernel's flush below finds it empty)
+        with join_to_agg_handoff(self.fed_by_join):
+            self.kernel.dispatch_backlog()
         own = getattr(self.kernel, "counts_own_dispatches", False)
         if not own:
             _METRICS.device_dispatch.inc(1, executor=self.identity)
@@ -1344,9 +1361,18 @@ class HashAggExecutor(Executor):
         try:
             async for msg in it:
                 if is_chunk(msg):
-                    self._apply_chunk(msg)
+                    # the hand-off's middle leg: the ingest of the
+                    # join's chunks up to the kernel's backlog
+                    with join_to_agg_handoff(self.fed_by_join):
+                        self._apply_chunk(msg)
                 elif is_barrier(msg):
                     out = self._flush()
+                    # the flush's gather ran after every step of the
+                    # epoch: their counters were folded in when it
+                    # adopted the exact count
+                    _hotkeys.note_batch_books(
+                        f"agg.{self._books_table}", self.identity,
+                        getattr(self.kernel, "take_probe_rounds", None))
                     self._clean_state()
                     self._maybe_gc_interner()
                     self.table.commit(msg.epoch)

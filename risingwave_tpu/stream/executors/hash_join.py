@@ -108,7 +108,10 @@ from risingwave_tpu.stream.executors.keys import (
     LANES_PER_KEY, KeyCodec,
 )
 from risingwave_tpu.stream.message import Message, Watermark, is_barrier
-from risingwave_tpu.stream.trace_ctx import dispatch_span
+from risingwave_tpu.stream.trace_ctx import (
+    dispatch_span, join_to_agg_handoff,
+)
+from risingwave_tpu.stream import costs as _costs
 from risingwave_tpu.stream import hotkeys as _hotkeys
 from risingwave_tpu.utils.metrics import STREAMING as _METRICS
 
@@ -853,6 +856,10 @@ class HashJoinExecutor(Executor):
                 "epoch_batch=False is the sharded per-chunk oracle "
                 "arm — the single-chip kernel is epoch-only")
         self._epoch_batch = bool(epoch_batch)
+        # the planner's mark: an aggregate was planned over this join,
+        # so the chunk build below is a leg of the join -> aggregate
+        # hand-off
+        self.feeds_agg = False
         self._tier = None
         self._tier_parts: Tuple = (None, None)
         self._tier_seq = 0
@@ -1406,51 +1413,67 @@ class HashJoinExecutor(Executor):
         order the chunks were applied — on the epoch path it replays
         from the packed matrix's old-degree column (the device array
         is the store; see _emit_one)."""
-        outs: List[StreamChunk] = []
         results = self._dispatch_epoch() if self._epoch_batch \
             and (self._epoch_buf[0] or self._epoch_buf[1]) else {}
-        # per-epoch replay of stored-row degrees, per side: a value
-        # array + written mask indexed by ref (ISSUE 12 — the dict it
-        # replaces cost a python get/set per matched pair), seeded
-        # lazily from the matrix old column, written through by
-        # inserted-row inits and per-chunk transition deltas
-        self._deg_replay = [None, None]
-        for (side_idx, chunk, nonnull, handle, ins_idx,
-             ins_refs, off, dn) in self._pending:
-            n = chunk.capacity
-            deg = None
-            probe_idx = np.zeros(0, dtype=np.int32)
-            refs = np.zeros(0, dtype=np.int32)
-            pay = None
-            old = None
-            if handle is not None:
-                deg_p, probe_idx, refs = handle.collect()
-                deg = np.zeros(n, dtype=np.int64)
-                deg[:len(deg_p)] = deg_p
-            elif side_idx in results:
-                d_s, p_s, r_s, pay_s, old_s = results[side_idx]
-                # the buffered epoch carries only this chunk's dense
-                # prefix (dn rows at offset off); degrees re-pad to
-                # the chunk's capacity for the chunk-relative masks
-                lo = np.searchsorted(p_s, off)
-                hi = np.searchsorted(p_s, off + dn)
-                probe_idx = (p_s[lo:hi] - off).astype(np.int32)
-                refs = r_s[lo:hi]
-                if pay_s is not None:
-                    pay = pay_s[lo:hi]
-                if old_s is not None:
-                    old = old_s[lo:hi].astype(np.int64)
-                if d_s is not None:
+        # the hand-off's first leg: from the probe result on the host
+        # to the chunks the aggregate ingests
+        with join_to_agg_handoff(self.feeds_agg):
+            outs: List[StreamChunk] = []
+            # per-epoch replay of stored-row degrees, per side: a value
+            # array + written mask indexed by ref (ISSUE 12 — the dict it
+            # replaces cost a python get/set per matched pair), seeded
+            # lazily from the matrix old column, written through by
+            # inserted-row inits and per-chunk transition deltas
+            self._deg_replay = [None, None]
+            for (side_idx, chunk, nonnull, handle, ins_idx,
+                 ins_refs, off, dn) in self._pending:
+                n = chunk.capacity
+                deg = None
+                probe_idx = np.zeros(0, dtype=np.int32)
+                refs = np.zeros(0, dtype=np.int32)
+                pay = None
+                old = None
+                if handle is not None:
+                    deg_p, probe_idx, refs = handle.collect()
                     deg = np.zeros(n, dtype=np.int64)
-                    deg[:dn] = d_s[off:off + dn]
-            outs.extend(self._emit_one(side_idx, chunk, nonnull, deg,
-                                       probe_idx, refs, ins_idx,
-                                       ins_refs, pay, old))
-        self._pending.clear()
-        self._epoch_buf = ([], [])
-        self._epoch_rows = [0, 0]
-        self._deg_replay = [None, None]
+                    deg[:len(deg_p)] = deg_p
+                elif side_idx in results:
+                    d_s, p_s, r_s, pay_s, old_s = results[side_idx]
+                    # the buffered epoch carries only this chunk's dense
+                    # prefix (dn rows at offset off); degrees re-pad to
+                    # the chunk's capacity for the chunk-relative masks
+                    lo = np.searchsorted(p_s, off)
+                    hi = np.searchsorted(p_s, off + dn)
+                    probe_idx = (p_s[lo:hi] - off).astype(np.int32)
+                    refs = r_s[lo:hi]
+                    if pay_s is not None:
+                        pay = pay_s[lo:hi]
+                    if old_s is not None:
+                        old = old_s[lo:hi].astype(np.int64)
+                    if d_s is not None:
+                        deg = np.zeros(n, dtype=np.int64)
+                        deg[:dn] = d_s[off:off + dn]
+                outs.extend(self._emit_one(side_idx, chunk, nonnull, deg,
+                                           probe_idx, refs, ins_idx,
+                                           ins_refs, pay, old))
+            self._pending.clear()
+            self._epoch_buf = ([], [])
+            self._epoch_rows = [0, 0]
+            self._deg_replay = [None, None]
+        if self.feeds_agg:
+            _METRICS.join_to_agg_rows.inc(
+                float(sum(c.cardinality() for c in outs)),
+                view=_costs.current_mv() or "")
         return outs
+
+    def _note_batch_books(self) -> None:
+        """Each side's per-epoch books (hotkeys.note_batch_books). This
+        epoch's apply ran before the probe whose result was just
+        collected, so its counter has landed."""
+        for i, side in enumerate(self.sides):
+            _hotkeys.note_batch_books(
+                f"join.t{side.table.table_id}", f"{self.identity}/{i}",
+                getattr(side._kernel, "take_probe_rounds", None))
 
     def _deg_replay_arrays(self, side_idx: int, max_ref: int):
         """(values, written) replay arrays for `side_idx`, grown to
@@ -1738,6 +1761,7 @@ class HashJoinExecutor(Executor):
                     # re-dispatched probe's sequence view
                     for out in self._emit_pending():
                         yield out
+                    self._note_batch_books()
                     self._expire_state()
                     self._tier_seq += 1
                     for i, side in enumerate(self.sides):

@@ -68,7 +68,8 @@ class ProjectExecutor(Executor):
 
     def __init__(self, input_: Executor, exprs: Sequence[Expression],
                  names: Optional[Sequence[str]] = None,
-                 watermark_derivations: Optional[dict] = None):
+                 watermark_derivations: Optional[dict] = None,
+                 span_args: Optional[dict] = None):
         self.input = input_
         self.exprs = list(exprs)
         names = list(names) if names else [
@@ -84,6 +85,9 @@ class ProjectExecutor(Executor):
         # watermark.rs::transform_with_expr — e.g. tumble_start maps a
         # date_time watermark to a window_start watermark)
         self.watermark_derivations = dict(watermark_derivations or {})
+        # attributes for this executor's span of the epoch trace
+        # (stream/monitor.py), from the planner
+        self.span_args = dict(span_args or {})
 
     @staticmethod
     def _drop_noop_updates(cols, vis, ops):

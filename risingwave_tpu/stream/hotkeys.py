@@ -38,11 +38,16 @@ _PER_CHUNK = 128
 # skew verdict threshold lives in stream/bottleneck.py
 TOP_N = 8
 
+# chunks whose unique passes are kept for take_batch (an executor that
+# stages by the epoch drains them at every barrier; q4's epochs are 4)
+_BATCH_CHUNKS = 256
+
 
 class _Sketch:
     """One space-saving sketch over a single executor input."""
 
-    __slots__ = ("counts", "errs", "lanes", "total", "codec", "mult")
+    __slots__ = ("counts", "errs", "lanes", "total", "codec", "mult",
+                 "batch")
 
     def __init__(self) -> None:
         self.counts: Dict[int, int] = {}   # key hash -> est count
@@ -51,6 +56,9 @@ class _Sketch:
         self.total = 0                     # all observed rows
         self.codec = None                  # KeyCodec for display
         self.mult: Optional[np.ndarray] = None
+        # (key hashes, counts) of every chunk since the last
+        # take_batch(): the key skew of the batch a kernel stages
+        self.batch: List[Tuple[np.ndarray, np.ndarray]] = []
 
     def observe(self, key_lanes: np.ndarray, vis: np.ndarray,
                 codec) -> None:
@@ -63,15 +71,20 @@ class _Sketch:
         self.total += n
         if self.mult is None or self.mult.shape[0] != lanes.shape[1]:
             # fixed odd multipliers: a cheap universal-ish hash of the
-            # (hi, lo, valid) lane columns down to one int64 per row
-            with np.errstate(over="ignore"):
-                self.mult = (2 * np.arange(1, lanes.shape[1] + 1,
-                                           dtype=np.int64) - 1) \
-                    * np.uint64(0x9E3779B97F4A7C15).astype(np.int64)
+            # (hi, lo, valid) lane columns down to one int64 per row.
+            # Powers of one odd constant (wrapping): multiples of it,
+            # (2k-1) x G, made two-column keys collide wherever
+            # 3 x the first column's step + 9 x the second's was 0
+            # (q4's (auction id, category): 772 hashes for 1,141 keys)
+            self.mult = np.cumprod(np.full(
+                lanes.shape[1], 0x9E3779B97F4A7C15, dtype=np.uint64)
+            ).astype(np.int64)
         with np.errstate(over="ignore"):
             hashes = lanes.astype(np.int64) @ self.mult
         uniq, first, cnt = np.unique(hashes, return_index=True,
                                      return_counts=True)
+        self.batch.append((uniq, cnt))
+        del self.batch[:-_BATCH_CHUNKS]    # an input nobody drains
         if uniq.shape[0] > _PER_CHUNK:
             top = np.argpartition(cnt, -_PER_CHUNK)[-_PER_CHUNK:]
             uniq, first, cnt = uniq[top], first[top], cnt[top]
@@ -96,6 +109,22 @@ class _Sketch:
             counts[h] = floor + c
             self.errs[h] = floor
             self.lanes[h] = np.array(lanes[idx])
+
+    def take_batch(self) -> Optional[Tuple[int, int, int]]:
+        """(rows, distinct keys, rows of the most frequent key) over
+        the chunks observed since the last call, from the unique
+        passes `observe` made anyway; None where there were none."""
+        parts, self.batch = self.batch, []
+        if not parts:
+            return None
+        if len(parts) == 1:
+            cnt = parts[0][1]
+        else:
+            _uniq, inv = np.unique(np.concatenate([u for u, _c in parts]),
+                                   return_inverse=True)
+            cnt = np.bincount(inv, weights=np.concatenate(
+                [c for _u, c in parts]))
+        return int(cnt.sum()), int(cnt.shape[0]), int(cnt.max())
 
     def top(self, n: int) -> List[Tuple[int, int, int]]:
         """[(hash, est_count, max_err)] by estimated count."""
@@ -137,6 +166,14 @@ class HotKeys:
             if sk is None:
                 sk = self._sketches[identity] = _Sketch()
         sk.observe(np.asarray(key_lanes), vis, codec)
+
+    def take_batch(self, identity: str
+                   ) -> Optional[Tuple[int, int, int]]:
+        """_Sketch.take_batch of one input, None if it was never
+        observed."""
+        with self._lock:
+            sk = self._sketches.get(identity)
+        return None if sk is None else sk.take_batch()
 
     def bind_fragment(self, identity: str, fragment: str) -> None:
         with self._lock:
@@ -232,3 +269,27 @@ class HotKeys:
 
 
 HOTKEYS = HotKeys()
+
+
+def note_batch_books(kernel: str, identity: str, take_rounds) -> None:
+    """One kernel's per-epoch books, written at the barrier from what
+    the host already has (rw_metrics_history ``batch_skew.<kernel>.*``
+    and ``probe_insert.<kernel>.*``): the key skew of the rows staged,
+    from the sketch's unique passes over `identity`'s chunks, and the
+    rounds probe_insert's loop took, which each device step returns
+    with its insert count (`take_rounds`: the single-chip kernel's
+    ``take_probe_rounds``; None for a kernel that has none to read)."""
+    from risingwave_tpu.utils.metrics import STREAMING
+    skew = HOTKEYS.take_batch(identity)
+    if skew is not None:
+        rows, distinct, max_key = skew
+        STREAMING.batch_skew_rows.inc(rows, kernel=kernel)
+        STREAMING.batch_skew_distinct.inc(distinct, kernel=kernel)
+        STREAMING.batch_skew_max_key.set(max_key, kernel=kernel)
+    elif STREAMING.batch_skew_max_key.get(kernel=kernel):
+        STREAMING.batch_skew_max_key.set(0, kernel=kernel)  # none staged
+    if take_rounds is not None:
+        rounds, batches = take_rounds()
+        if batches:
+            STREAMING.probe_insert_rounds.inc(rounds, kernel=kernel)
+            STREAMING.probe_insert_batches.inc(batches, kernel=kernel)

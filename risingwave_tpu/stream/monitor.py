@@ -358,7 +358,8 @@ class MonitoredExecutor(Executor):
             start_s=time.time() - excl, dur_s=excl,
             actor=int(self.labels["actor"]),
             node=self.labels["node"],
-            fragment=self.labels["fragment"])
+            fragment=self.labels["fragment"],
+            **getattr(self.inner, "span_args", {}))
         # per-LOGICAL-executor attribution inside fused blocks
         # (ops/fused.py): a fused run is ONE node in the chain, but
         # rw_actor_metrics keeps a row per absorbed stage — visible-row

@@ -13,16 +13,44 @@ Wire shape (appended to every 'B' barrier frame payload):
 
 from __future__ import annotations
 
+import contextlib
 import struct
 import time
 from typing import Optional, Tuple
 
+from risingwave_tpu.stream import costs as _costs
 from risingwave_tpu.utils import spans as _spans
+from risingwave_tpu.utils.metrics import STREAMING
 from risingwave_tpu.utils.spans import dispatch_span  # noqa: F401
 #                     (re-export: the executors' natural import home)
 
 _TRAILER = struct.Struct(">2sQQd")
 _MAGIC = b"TC"
+
+
+@contextlib.contextmanager
+def join_to_agg_handoff(on: bool):
+    """One leg of the join -> aggregate hand-off, on the host's clock:
+    the join's chunk build from its probe result, the aggregate's
+    ingest of those chunks, the pack, upload and dispatch of its
+    staged batch. `on` is the mark the planner puts on a hash join
+    and the aggregate it plans over it (planner.py _plan_query); no
+    leg is booked without it. The legs add up in counter
+    ``stream_join_to_agg_seconds{view}`` (``join_to_agg.seconds`` of
+    rw_metrics_history) and each is a ``handoff.join_to_agg``
+    annotation on the profiler's clock. A second cut of the wall time
+    the ledger's phases partition (like ``exec_s.<Kind>``): the phases
+    inside a leg keep their seconds."""
+    if not on:
+        yield
+        return
+    t0 = time.perf_counter()
+    try:
+        with _spans.annotation("handoff.join_to_agg"):
+            yield
+    finally:
+        STREAMING.join_to_agg_seconds.inc(
+            time.perf_counter() - t0, view=_costs.current_mv() or "")
 
 
 # -- remote-exchange span context ------------------------------------------

@@ -373,8 +373,28 @@ class PendingCounters:
 
     def __init__(self, initial: int = 0):
         self._count = initial
-        self._pending: List[tuple] = []   # (device scalar, n_rows)
+        self._pending: List[tuple] = []   # (device counter, n_rows)
         self._rows = 0
+        # steps that returned [count, rounds of probe_insert's loop]
+        # (hash_table.probe_insert_counted): the rounds and the steps
+        # folded in since the last take_rounds()
+        self._rounds = 0
+        self._batches = 0
+
+    def _fold(self, ins) -> int:
+        """One landed counter's insert count; a counter that carries
+        the rounds beside it leaves them in the books."""
+        a = np.asarray(ins).reshape(-1)
+        if a.size > 1:
+            self._rounds += int(a[1])
+            self._batches += 1
+        return int(a[0])
+
+    def take_rounds(self) -> tuple:
+        """(rounds, steps) folded in since the last call."""
+        out = (self._rounds, self._batches)
+        self._rounds = self._batches = 0
+        return out
 
     def push(self, ins, n_rows: int) -> None:
         start_fetch(ins)
@@ -395,21 +415,28 @@ class PendingCounters:
         in dispatch order (single device stream)."""
         while self._pending and self._pending[0][0].is_ready():
             ins, n = self._pending.pop(0)
-            self._count += int(ins)
+            self._count += self._fold(ins)
             self._rows -= n
 
     def drain_all(self) -> int:
         """Fold in every counter (blocks; DMAs already in flight)."""
         if self._pending:
             counts = fetch(*[i for i, _n in self._pending])
-            self._count += int(sum(int(c) for c in counts))
+            self._count += sum(self._fold(c) for c in counts)
             self._pending = []
             self._rows = 0
         return self._count
 
     def reset(self, exact: int) -> None:
         """Adopt an externally-observed exact count (flush header,
-        rebuild) that subsumes all in-flight counters."""
+        rebuild) that subsumes all in-flight counters. Their steps ran
+        before the step that gave the exact count, so their DMAs have
+        as a rule landed: the rounds of those that have are read, the
+        rest dropped from the books, and nothing here waits."""
+        for ins, _n in self._pending:
+            # a bare count carries no rounds
+            if getattr(ins, "ndim", 0) and ins.is_ready():
+                self._fold(ins)
         self._count = exact
         self._pending = []
         self._rows = 0

@@ -24,6 +24,30 @@ from typing import Callable, Dict, List, Optional, Tuple
 from risingwave_tpu.utils.metrics import STREAMING as _METRICS
 
 
+def keep_freed_heap() -> bool:
+    """Pin glibc malloc's two dynamic thresholds for this process:
+    requests up to 32 MiB come from the heap, and the heap's free top
+    is not given back. Left to themselves both thresholds follow the
+    largest mmapped chunk freed so far, which differs from run to run:
+    the store's merges and SST builds (4 MiB pieces, some 100 MB a
+    compaction pass, every fourth barrier) then either reuse warm heap
+    or map and fault fresh pages on every pass. On the chip host that
+    was two levels of compaction speed, 107-134 MB/s, and of every
+    compaction barrier's latency (PERF.md section 6, PR 31). Setting
+    either threshold turns glibc's adjustment off. A server keeps what
+    it has touched. False where the C library has no mallopt."""
+    import ctypes
+    try:
+        mallopt = ctypes.CDLL(None).mallopt
+    except (OSError, AttributeError):
+        return False
+    m_trim_threshold, m_top_pad, m_mmap_threshold = -1, -2, -3
+    ok = mallopt(m_mmap_threshold, 32 << 20)
+    ok &= mallopt(m_trim_threshold, 1 << 30)
+    ok &= mallopt(m_top_pad, 64 << 20)
+    return bool(ok)
+
+
 class MemoryContext:
     """Central registry of host-state size reporters + evictors.
 

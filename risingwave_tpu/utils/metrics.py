@@ -291,6 +291,41 @@ class StreamingMetrics:
             "state-table keys encoded, by path (columnar: the bulk "
             "encoder, counted by the batch; row: the scalar codec of "
             "the point operations, one key a call)")
+        self.agg_input_rows = r.counter(
+            "stream_agg_input_rows",
+            "visible rows a HashAggExecutor took in, by op (insert, "
+            "delete, update_delete, update_insert): the retractions an "
+            "upstream aggregate or join sends into it")
+        self.join_to_agg_rows = r.counter(
+            "stream_join_to_agg_rows",
+            "rows a hash join handed to the aggregate it feeds, by view")
+        self.join_to_agg_seconds = r.counter(
+            "stream_join_to_agg_seconds",
+            "host seconds of the join -> aggregate hand-off, by view: "
+            "from the join's probe result on the host (chunk build) "
+            "through the aggregate's ingest to its staged batch (pack, "
+            "upload); a second cut of the wall time the ledger's "
+            "phases partition")
+        self.batch_skew_rows = r.counter(
+            "stream_batch_skew_rows",
+            "visible rows a device kernel staged, by kernel "
+            "(<kind>.t<state table id>)")
+        self.batch_skew_distinct = r.counter(
+            "stream_batch_skew_distinct_keys",
+            "distinct keys among the rows a kernel staged in an epoch, "
+            "summed over epochs")
+        self.batch_skew_max_key = r.gauge(
+            "stream_batch_skew_max_key_rows",
+            "rows of the most frequent key among those a kernel staged "
+            "in its last epoch")
+        self.probe_insert_rounds = r.counter(
+            "stream_probe_insert_rounds",
+            "rounds of hash_table.probe_insert's claim loop, by kernel, "
+            "as the device step returned them")
+        self.probe_insert_batches = r.counter(
+            "stream_probe_insert_batches",
+            "device steps whose probe_insert rounds were read, by "
+            "kernel")
         self.actor_count = r.gauge("stream_actor_count", "live actors")
         self.checkpoint_count = r.counter(
             "meta_checkpoint_count", "committed checkpoints")
@@ -688,13 +723,42 @@ class MetricsHistory:
                 for labels, v in S.mesh_exchange_bucket.series()]
         return out
 
+    @staticmethod
+    def _batch_books():
+        """(series name, value now, kind) of what the executors count
+        by the batch: rows into an aggregate by op, the join ->
+        aggregate hand-off, the key skew of a staged batch and the
+        rounds of probe_insert's loop. A plan with no such executor
+        writes no such series and its rows carry no such column."""
+        S = STREAMING
+        out = [(f"agg_input_rows.{l.get('table', '?')}.{l.get('op', '?')}",
+                v, "counter") for l, v in S.agg_input_rows.series()]
+        for name, metric in (("join_to_agg.rows", S.join_to_agg_rows),
+                             ("join_to_agg.seconds",
+                              S.join_to_agg_seconds)):
+            series = metric.series()
+            if series:
+                out.append((name, sum(v for _l, v in series), "counter"))
+        for field, metric, kind in (
+                ("rows", S.batch_skew_rows, "counter"),
+                ("distinct", S.batch_skew_distinct, "counter"),
+                ("max_key", S.batch_skew_max_key, "gauge")):
+            out += [(f"batch_skew.{l.get('kernel', '?')}.{field}", v, kind)
+                    for l, v in metric.series()]
+        for field, metric in (("rounds", S.probe_insert_rounds),
+                              ("batches", S.probe_insert_batches)):
+            out += [(f"probe_insert.{l.get('kernel', '?')}.{field}", v,
+                     "counter") for l, v in metric.series()]
+        return out
+
     def observe(self, epoch: int, interval_s: float,
                 extra: Optional[Dict[str, float]] = None,
                 domain: str = "") -> None:
         values: Dict[str, float] = {}
         readings = [(name, float(fn()), kind)
                     for name, fn, kind in self._tracked()]
-        for name, v, kind in readings + self._mesh_exchange():
+        for name, v, kind in readings + self._mesh_exchange() \
+                + self._batch_books():
             if kind == "counter":
                 values[name] = v - self._last.get(name, 0.0)
                 self._last[name] = v

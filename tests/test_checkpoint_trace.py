@@ -65,6 +65,9 @@ def _history_by_epoch(rows):
 
 def test_stage_durations_add_up_to_upload_s_and_land_in_history():
     async def run():
+        # the flight recorder is process-wide: a compaction of whatever
+        # test ran before in this worker must not be counted below
+        spans_mod.EPOCH_TRACER.clear()
         fe = _frontend()
         await fe.execute(BID_SOURCE)
         await fe.execute(MV)

@@ -24,7 +24,7 @@ def _mk_inputs(spec, vals, valid):
 
 
 def _single_chip_snapshot(kernel: GroupedAggKernel):
-    kernel._dispatch_backlog()   # applies batch host-side until flush
+    kernel.dispatch_backlog()   # applies batch host-side until flush
     st = jax.device_get(kernel.state)
     out = {}
     live = st.table.occ & (st.group_rows > 0)

@@ -230,7 +230,7 @@ def test_mesh_agg_epoch_vs_perchunk_oracle(four_mesh):
             single.apply(np.stack([hi, lo], axis=1), signs, vis,
                          [(specs[0].encode_input(vals), valid),
                           ((), None)])
-    single._dispatch_backlog()
+    single.dispatch_backlog()
     import jax
     from risingwave_tpu.ops.hash_agg import decode_outputs
     st = jax.device_get(single.state)

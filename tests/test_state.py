@@ -352,6 +352,26 @@ def test_memory_context_accounting_and_eviction():
     assert total <= 100
 
 
+def test_serving_a_data_dir_pins_the_allocators_thresholds(
+        tmp_path, monkeypatch):
+    """The process that compacts keeps its freed heap (PR 31): the
+    served entry point asks once, and glibc says yes."""
+    import asyncio
+    from risingwave_tpu import __main__ as main
+    from risingwave_tpu.utils import memory
+
+    asked = []
+    pin = memory.keep_freed_heap
+    monkeypatch.setattr(memory, "keep_freed_heap",
+                        lambda: asked.append(pin()))
+
+    async def up():
+        async with main.serving(str(tmp_path), port=0):
+            pass
+    asyncio.run(up())
+    assert asked == [True]
+
+
 # -- staged all-insert writes (ISSUE 12 emit path) ---------------------------
 
 
