@@ -69,10 +69,11 @@ async def serving(data_dir, host: str = "127.0.0.1", port: int = 4566,
                   parallelism: int = 1):
     """The single-process server, started: state store (hummock-lite
     on the local FS under ``data_dir``, in-memory when None),
-    Frontend, DDL-log recovery, pgwire listener and the 0.25 s barrier
-    heartbeat. Yields ``(frontend, pg_server, heartbeat_task)``; leaving
-    the block stops the heartbeat and the listener. ``serve`` /
-    ``playground`` and ``chip_smoke.py`` all start through here."""
+    Frontend, DDL-log recovery, pgwire listener and the barrier
+    heartbeat (0.25 s from inject to inject). Yields ``(frontend,
+    pg_server, heartbeat_task)``; leaving the block stops the
+    heartbeat and the listener. ``serve`` / ``playground`` and
+    ``chip_smoke.py`` all start through here."""
     from risingwave_tpu.frontend import Frontend
     from risingwave_tpu.frontend.pgwire import PgServer
 

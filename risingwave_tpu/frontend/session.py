@@ -19,7 +19,7 @@ from risingwave_tpu.frontend.catalog import Catalog, MvCatalog
 from risingwave_tpu.frontend.planner import (
     PlanError, StreamPlanner, plan_batch, source_schema,
 )
-from risingwave_tpu.meta.barrier import BarrierLoop
+from risingwave_tpu.meta.barrier import BarrierLoop, HeartbeatTick
 from risingwave_tpu.state.store import MemoryStateStore, StateStore
 from risingwave_tpu.stream.actor import Actor, LocalBarrierManager
 from risingwave_tpu.stream.message import (
@@ -388,25 +388,45 @@ class Frontend:
 
     async def run_heartbeat(self, interval_s: float = 0.25) -> None:
         """Background barrier heartbeat for server deployments
-        (GlobalBarrierManager::run analog; serialized with DDL). A
-        failure is loud: it propagates out of this task — the server
-        entry point watches it and dies rather than serving a cluster
-        whose checkpoints silently stopped. A cancelled heartbeat ends
-        cancelled, as the cluster's does: whoever owns the task can
-        tell the pause it asked for (``task.cancelled()``) from a
-        heartbeat that stopped by itself."""
+        (GlobalBarrierManager::run analog; serialized with DDL): one
+        barrier round every ``interval_s``, 0.25 s from inject to
+        inject, and the sealed checkpoint commits before the next
+        inject. Where a round and its tail take longer than the tick
+        the next round follows at once; no two injects are closer than
+        ``interval_s`` and the first one is a whole interval after the
+        start (meta/barrier.py ``HeartbeatTick``, which also files the
+        waits in ``rw_metrics_history``). A failure is loud: it
+        propagates out of this task — the server entry point watches
+        it and dies rather than serving a cluster whose checkpoints
+        silently stopped. A cancelled heartbeat ends cancelled, as the
+        cluster's does: whoever owns the task can tell the pause it
+        asked for (``task.cancelled()``) from a heartbeat that stopped
+        by itself. A cancel issued under ``_barrier_lock`` finds the
+        heartbeat waiting or queued on the lock, never inside a round,
+        and leaves the uploader's tasks alone."""
         import sys
         import traceback
         try:
+            tick = HeartbeatTick(interval_s)
             while True:
-                await asyncio.sleep(interval_s)
-                # no uploader drain: the heartbeat is exactly the
-                # driver the async checkpoint pipeline overlaps —
-                # draining every beat would stall barrier cadence on
-                # object-store latency again. Failures still surface
-                # on the next beat's collect; FLUSH/DDL/step() keep
-                # their durable (draining) semantics.
-                await self._barrier(drain_uploader=False)
+                await tick.wait()
+                # the sealed checkpoint's tail goes before the next
+                # inject: an inject ahead of it would put the next
+                # barrier's flush slices on the loop before this
+                # commit's continuation (inject → durable grows by
+                # them). A PUT shorter than the tick hides in it and
+                # this returns at once; a longer one is back-pressure
+                # of one checkpoint behind a saturated heartbeat. A
+                # failed upload surfaces here or on the next collect.
+                await tick.tail(self.loop.uploader)
+                # one real suspension outside the lock, always: a DDL,
+                # a FLUSH or a pause that became ready during the
+                # round gets to queue on the (FIFO) lock ahead of a
+                # saturated heartbeat
+                await asyncio.sleep(0)
+                barrier = await self._barrier(on_inject=tick.injected,
+                                              drain_uploader=False)
+                tick.file(barrier.epoch.curr.value)
         except asyncio.CancelledError:
             raise
         except BaseException:

@@ -329,6 +329,71 @@ class VirtualClock:
             set_clock(prev)
 
 
+class HeartbeatTick:
+    """The serving heartbeat's interval timer and its books
+    (``Frontend.run_heartbeat`` drives one per task).
+
+    A tick is due ``interval_s`` after the previous INJECT, not after
+    the previous collect: upstream's ``barrier_interval_ms``
+    (GlobalBarrierManager::run ticks an interval timer, SURVEY §3.2).
+    A round that overran its tick is followed at once, and the tick
+    after that one is a whole interval from the late inject — a missed
+    tick delays, it never bursts — so no two injects are closer than
+    ``interval_s``. The first tick is one interval from construction.
+
+    What the driver waited before a round goes on the history row of
+    the epoch that round injected (``file``), and each wait is an
+    annotation on the profiler's clock, its ``epoch`` stat the newest
+    epoch injected before it:
+
+      ``heartbeat.wait_s``       seconds waited for the tick
+                                 (annotation ``heartbeat.wait``)
+      ``heartbeat.tail_wait_s``  seconds then waited for the sealed
+                                 checkpoint's commit (``heartbeat.tail``)
+      ``heartbeat.overdue``      1 where the tick was already due when
+                                 the round before collected, else 0
+
+    The waits lie between one epoch's books closing and the next
+    inject, so no ledger phase holds them. The clock and the sleeper
+    are class attributes: a test puts a ``VirtualClock`` under them."""
+
+    monotonic = staticmethod(time.monotonic)
+    sleep = staticmethod(asyncio.sleep)
+
+    def __init__(self, interval_s: float) -> None:
+        self.interval_s = interval_s
+        self.next_at = self.monotonic() + interval_s
+        self._books: Dict[str, float] = {}
+
+    async def wait(self) -> None:
+        """Return once the tick is due."""
+        t0 = self.monotonic()
+        overdue = t0 >= self.next_at
+        with _spans.annotation("heartbeat.wait"):
+            while (left := self.next_at - self.monotonic()) > 0:
+                await self.sleep(left)
+        self._books = {"heartbeat.wait_s": self.monotonic() - t0,
+                       "heartbeat.overdue": float(overdue)}
+
+    async def tail(self, uploader: CheckpointUploader) -> None:
+        """Return once every sealed checkpoint has committed. Waits on
+        the uploader's tasks without owning them: a cancel that lands
+        here leaves them running."""
+        t0 = self.monotonic()
+        with _spans.annotation("heartbeat.tail"):
+            await uploader.drain()
+        self._books["heartbeat.tail_wait_s"] = self.monotonic() - t0
+
+    def injected(self) -> None:
+        """The round's barrier is being injected now (the engine calls
+        this where it stamps the inject; with several domains the last
+        one counts): the next tick counts from here."""
+        self.next_at = self.monotonic() + self.interval_s
+
+    def file(self, epoch: int) -> None:
+        HISTORY.amend(epoch, self._books)
+
+
 class BarrierLoop:
     """GlobalBarrierManager-lite driving one LocalBarrierManager.
 
@@ -475,8 +540,12 @@ class BarrierLoop:
         return BarrierKind.BARRIER
 
     async def inject(self, mutation: Optional[Mutation] = None,
-                     force_checkpoint: bool = False) -> Barrier:
-        """Issue the next epoch and send its barrier to source actors."""
+                     force_checkpoint: bool = False,
+                     on_inject: Optional[Callable[[], None]] = None
+                     ) -> Barrier:
+        """Issue the next epoch and send its barrier to source actors.
+        ``on_inject`` is called where the inject is stamped (the
+        heartbeat's tick counts from there)."""
         kind = self._next_kind(force_checkpoint)
         if self._plane is not None:
             # shared allocator: globally-unique, monotone epochs above
@@ -511,6 +580,8 @@ class BarrierLoop:
             kind=kind.value)
         _spans.EPOCH_TRACER.set_root(curr.value, root)
         self._inject_times[curr.value] = self.monotonic()
+        if on_inject is not None:
+            on_inject()
         self._in_flight.append(curr.value)
         STREAMING.barrier_in_flight.set(len(self._in_flight))
         if kind.is_checkpoint:
@@ -764,8 +835,9 @@ class BarrierLoop:
     async def inject_and_collect(
             self, mutation: Optional[Mutation] = None,
             force_checkpoint: bool = False,
-            drain_uploader: bool = True) -> Barrier:
-        await self.inject(mutation, force_checkpoint)
+            drain_uploader: bool = True,
+            on_inject: Optional[Callable[[], None]] = None) -> Barrier:
+        await self.inject(mutation, force_checkpoint, on_inject)
         # drain everything in flight, oldest first
         barrier = None
         while self._in_flight:
@@ -773,11 +845,11 @@ class BarrierLoop:
         assert barrier is not None
         # explicit stepping keeps its synchronous contract: the barrier
         # this returns is DURABLY committed (tests/DDL read
-        # committed_epoch right after). Background heartbeats pass
-        # drain_uploader=False — a periodic driver that drained every
-        # beat would re-serialize the pipeline it exists to overlap —
-        # and pipelined drivers use inject()/collect_next() directly,
-        # draining only at the end.
+        # committed_epoch right after). The serving heartbeat passes
+        # drain_uploader=False: it waits for the tail itself, outside
+        # the session's barrier lock and inside its tick, before its
+        # next inject (HeartbeatTick.tail). Pipelined drivers use
+        # inject()/collect_next() directly, draining only at the end.
         if drain_uploader:
             await self.uploader.drain()
         return barrier

@@ -523,8 +523,9 @@ class BarrierPlane:
 
     async def _domain_round(self, d: _Domain,
                             mutation: Optional[Mutation],
-                            force_checkpoint: bool) -> Barrier:
-        await d.loop.inject(mutation, force_checkpoint)
+                            force_checkpoint: bool,
+                            on_inject=None) -> Barrier:
+        await d.loop.inject(mutation, force_checkpoint, on_inject)
         barrier = None
         while d.loop.in_flight_count:
             barrier = await d.loop.collect_next()
@@ -533,9 +534,10 @@ class BarrierPlane:
 
     async def _gather_rounds(self, routed: Dict[str,
                                                 Optional[Mutation]],
-                             force_checkpoint: bool) -> Barrier:
+                             force_checkpoint: bool,
+                             on_inject=None) -> Barrier:
         tasks = [self._domain_round(self._domains[n], m,
-                                    force_checkpoint)
+                                    force_checkpoint, on_inject)
                  for n, m in routed.items() if n in self._domains]
         results = await asyncio.gather(*tasks, return_exceptions=True)
         barrier = None
@@ -553,10 +555,13 @@ class BarrierPlane:
     async def inject_and_collect(
             self, mutation: Optional[Mutation] = None,
             force_checkpoint: bool = False,
-            drain_uploader: bool = True) -> Barrier:
+            drain_uploader: bool = True,
+            on_inject=None) -> Barrier:
         """One barrier round. Plain rounds run per-domain concurrently;
         forced/mutation rounds — and every ``checkpoint_frequency``-th
-        plain round — align every domain on a checkpoint."""
+        plain round — align every domain on a checkpoint. ``on_inject``
+        is called as each domain's barrier is injected
+        (``BarrierLoop.inject``)."""
         self._ensure_default()
         checkpoint = force_checkpoint or mutation is not None
         if not checkpoint:
@@ -571,13 +576,13 @@ class BarrierPlane:
                 while d.loop.in_flight_count:
                     await d.loop.collect_next()
             routed = self._route_mutation(mutation)
-            barrier = await self._gather_rounds(routed,
-                                                force_checkpoint=True)
+            barrier = await self._gather_rounds(
+                routed, force_checkpoint=True, on_inject=on_inject)
             await self._maybe_submit()
         else:
             routed = {d.name: None for d in self._domains.values()}
-            barrier = await self._gather_rounds(routed,
-                                                force_checkpoint=False)
+            barrier = await self._gather_rounds(
+                routed, force_checkpoint=False, on_inject=on_inject)
         if drain_uploader:
             await self.uploader.drain()
         return barrier
