@@ -65,7 +65,7 @@ def _as_stage(ex, kinds=BLOCK_KINDS):
     )
     if isinstance(ex, FilterExecutor) and "filter" in kinds:
         return FusedStage("filter", "FilterExecutor",
-                          exprs=(ex.predicate,))
+                          exprs=(ex.predicate,), books=ex.condition_of)
     if isinstance(ex, ProjectExecutor) and "project" in kinds:
         return FusedStage(
             "project", "ProjectExecutor",
@@ -86,7 +86,8 @@ def _as_stage(ex, kinds=BLOCK_KINDS):
     if isinstance(ex, HopWindowExecutor) and "hop_window" in kinds:
         return FusedStage("hop_window", "HopWindowExecutor",
                           time_col=ex.time_col,
-                          slide_usecs=ex.slide, size_usecs=ex.size)
+                          slide_usecs=ex.slide, size_usecs=ex.size,
+                          books=ex.books_table)
     return None
 
 

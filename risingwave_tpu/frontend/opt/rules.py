@@ -246,7 +246,8 @@ def fuse_projects(root) -> Tuple[object, int, str]:
                 # runs before the projection materializes new columns
                 inner = FilterExecutor(p.input,
                                        subst_expr(c2.predicate,
-                                                  p.exprs))
+                                                  p.exprs),
+                                       condition_of=c2.condition_of)
                 return _swap_child(p, "input", None, inner)
         return None
 
@@ -423,7 +424,8 @@ def _prune(ex, live: Optional[Set[int]], stats,
                 return ex, None, False
             return _swap_child(ex, "input", None, child), None, True
         return (FilterExecutor(child,
-                               remap_expr(ex.predicate, cmap)),
+                               remap_expr(ex.predicate, cmap),
+                               condition_of=ex.condition_of),
                 cmap, True)
     if isinstance(ex, CoalesceExecutor):
         child, cmap, changed = _prune(ex.input, live_full, stats)

@@ -686,8 +686,9 @@ class StreamPlanner:
                 if getattr(jn, "temporal", False):
                     from risingwave_tpu.stream.executors.temporal_join \
                         import TemporalJoinExecutor
-                    lkeys, rkeys = _join_keys(jn, conjuncts, lscope,
-                                              rscope)
+                    lkeys, rkeys, cond = _join_keys(jn, conjuncts,
+                                                    lscope, rscope)
+                    _inner_condition(jn, cond)
                     if sorted(rkeys) != sorted(right.pk_indices):
                         raise PlanError(
                             "temporal join ON keys must equal the "
@@ -702,7 +703,9 @@ class StreamPlanner:
                         actor_id=actor_id)
                     lscope = lscope.concat(rscope)
                     continue
-                lkeys, rkeys = _join_keys(jn, conjuncts, lscope, rscope)
+                lkeys, rkeys, cond = _join_keys(jn, conjuncts, lscope,
+                                                rscope)
+                cond = _inner_condition(jn, cond)
                 jt = {"inner": JoinType.INNER,
                       "left": JoinType.LEFT_OUTER,
                       "right": JoinType.RIGHT_OUTER,
@@ -757,6 +760,13 @@ class StreamPlanner:
                                         join_type=jt, mesh=self.mesh,
                                         state_cap=cap)
                 lscope = lscope.concat(rscope)
+                for c in cond:
+                    # the join's own condition, directly above it and
+                    # below any later join of the chain; the mark lets
+                    # the books file its rows under this join
+                    left = FilterExecutor(
+                        left, Binder(lscope).bind(c),
+                        condition_of=f"t{lt.table_id}")
             ex = left
             scope = lscope
             join_pk_cols = list(ex.pk_indices)
@@ -1201,6 +1211,16 @@ class StreamPlanner:
             # WHERE's residual filters and the projection above only:
             # both ends book the hand-off (trace_ctx.join_to_agg_handoff)
             join.feeds_agg = agg.fed_by_join = True
+        from risingwave_tpu.stream.executors.hop_window import (
+            HopWindowExecutor,
+        )
+        hop = pre
+        while isinstance(hop, (FilterExecutor, ProjectExecutor)):
+            hop = hop.input
+        if isinstance(hop, HopWindowExecutor):
+            # an aggregate over a HOP: the expansion's rows in and out
+            # are filed under this aggregate, fused into it or not
+            hop.books_table = f"t{table.table_id}"
         # bound items are already typed refs over the agg output row
         return agg, bound, having_pred
 
@@ -1310,48 +1330,54 @@ def explain_tree(ex, indent: int = 0) -> List[str]:
 
 
 def _equi_keys(on: ast.Expr, lscope: Scope, rscope: Scope
-               ) -> Tuple[List[int], List[int]]:
-    """ON conjunction of col=col → (left key idxs, right key idxs)."""
-    conj: List[ast.Expr] = []
-
-    def flatten(e):
-        if isinstance(e, ast.Bin) and e.op == "and":
-            flatten(e.left)
-            flatten(e.right)
-        else:
-            conj.append(e)
-
-    flatten(on)
-    lkeys, rkeys = [], []
-    for c in conj:
-        if not (isinstance(c, ast.Bin) and c.op == "="
-                and isinstance(c.left, ast.ColRef)
-                and isinstance(c.right, ast.ColRef)):
-            raise PlanError("JOIN ON must be a conjunction of "
-                            "column = column")
+               ) -> Tuple[List[int], List[int], List[ast.Expr]]:
+    """ON conjunction → (left key idxs, right key idxs, condition).
+    A conjunct `column = column` with one column on each side is a
+    hash key; every other conjunct is the join's condition, in the
+    order it was written. No equality across the sides is an error:
+    no cross product is planned."""
+    lkeys: List[int] = []
+    rkeys: List[int] = []
+    condition: List[ast.Expr] = []
+    for c in _flatten_and(on):
         sides = []
-        for col in (c.left, c.right):
-            try:
-                sides.append(("l", lscope.find(col.name, col.table)[0]))
-            except BindError:
-                sides.append(("r", rscope.find(col.name, col.table)[0]))
-        tags = {s[0] for s in sides}
-        if tags != {"l", "r"}:
-            raise PlanError("JOIN ON must compare the two sides")
+        if isinstance(c, ast.Bin) and c.op == "=" \
+                and isinstance(c.left, ast.ColRef) \
+                and isinstance(c.right, ast.ColRef):
+            for col in (c.left, c.right):
+                try:
+                    sides.append(
+                        ("l", lscope.find(col.name, col.table)[0]))
+                except BindError:
+                    sides.append(
+                        ("r", rscope.find(col.name, col.table)[0]))
+        if {s[0] for s in sides} != {"l", "r"}:
+            condition.append(c)
+            continue
         for tag, idx in sides:
             (lkeys if tag == "l" else rkeys).append(idx)
-    return lkeys, rkeys
+    if not lkeys:
+        raise PlanError("JOIN ON needs a column = column between the "
+                        "two sides (the hash join's key): a cross "
+                        "product is not planned")
+    return lkeys, rkeys, condition
 
 
 def _join_keys(jn: ast.Join, conjuncts: List[ast.Expr], lscope: Scope,
-               rscope: Scope) -> Tuple[List[int], List[int]]:
-    """The hash keys of one join of the left-deep chain. `JOIN … ON`
-    gives them itself. A comma-separated FROM item (`on` is None) takes
-    every WHERE conjunct `column = column` with one column on each side
-    and removes it from `conjuncts`; what stays there is the residual,
-    filtered above the chain like any other WHERE. A conjunct that
-    names a later item of the list resolves on neither side yet and is
-    left for that item's join."""
+               rscope: Scope
+               ) -> Tuple[List[int], List[int], List[ast.Expr]]:
+    """The hash keys of one join of the left-deep chain, and the
+    join's own condition. `JOIN … ON` gives both itself: its
+    `column = column` conjuncts across the two sides are the keys, its
+    other conjuncts the condition, which the caller plans as filters
+    directly above this join (the same relation for an inner join,
+    and only for an inner join: `_inner_condition`). A comma-separated
+    FROM item (`on` is None) takes every WHERE conjunct
+    `column = column` with one column on each side and removes it
+    from `conjuncts`; what stays there is the residual, filtered above
+    the chain like any other WHERE, and the condition is empty. A
+    conjunct that names a later item of the list resolves on neither
+    side yet and is left for that item's join."""
     if jn.on is not None:
         return _equi_keys(jn.on, lscope, rscope)
     both, n_left = lscope.concat(rscope), len(lscope.schema)
@@ -1377,7 +1403,26 @@ def _join_keys(jn: ast.Join, conjuncts: List[ast.Expr], lscope: Scope,
             "column = column between each item and the items before "
             "it (the hash join's key): none found for "
             f"{_item_name(jn.item)!r}; a cross product is not planned")
-    return lkeys, rkeys
+    return lkeys, rkeys, []
+
+
+def _inner_condition(jn: ast.Join, condition: List[ast.Expr]
+                     ) -> List[ast.Expr]:
+    """The ON conjuncts that are not hash keys, for a join that may
+    run them as filters above itself. Only an inner join may: an outer
+    join keeps the rows its ON rejects, NULL-padded, so its condition
+    belongs inside the join and is refused until the join takes one."""
+    temporal = getattr(jn, "temporal", False)
+    if condition and (temporal or jn.kind != "inner"):
+        what = "a temporal join" if temporal \
+            else f"a {jn.kind.upper()} OUTER JOIN"
+        raise BindError(
+            f"the ON of {what} takes column = column conjuncts only: "
+            "any other condition there decides which rows are "
+            "NULL-padded, which a filter above the join cannot do, "
+            "and the join itself evaluates no condition yet. Move it "
+            "to the WHERE only if that is the relation you mean")
+    return condition
 
 
 def _item_name(item) -> str:
@@ -1858,9 +1903,11 @@ def plan_batch(sel: ast.Select, catalog: Catalog, store, epoch: int,
     conjuncts = _flatten_and(sel.where) if sel.where is not None else []
     for jn in sel.joins:
         rex, rscope = scan(jn.item)
-        lkeys, rkeys = _join_keys(jn, conjuncts, scope, rscope)
+        lkeys, rkeys, cond = _join_keys(jn, conjuncts, scope, rscope)
         ex = BatchHashJoin(ex, rex, lkeys, rkeys)
         scope = scope.concat(rscope)
+        for c in _inner_condition(jn, cond):
+            ex = BatchFilter(ex, Binder(scope).bind(c))
     for c in conjuncts:
         ex = BatchFilter(ex, Binder(scope).bind(c))
     projections = _expand_star(sel.projections, scope)

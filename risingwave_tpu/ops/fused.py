@@ -274,6 +274,12 @@ class FusedStage:
     slide_usecs: int = 0
     size_usecs: int = 0
     runtime: object = None
+    # the planner's mark, carried over from the executor: a filter
+    # that is the condition of a JOIN ... ON names that join, a
+    # hop_window the aggregate planned over it (`t<state table id>`).
+    # The run files those stages' rows under the name
+    # (note_stage_rows); host-only, not serialized
+    books: str = ""
 
     @property
     def units(self) -> int:
@@ -434,6 +440,13 @@ class FusedStages:
         # per-stage row attribution drained by the monitor at barriers
         self.stage_rows = np.zeros(len(self.stages), dtype=np.int64)
         self.stage_chunks = np.zeros(len(self.stages), dtype=np.int64)
+        # the stages the planner marked (FusedStage.books), and the
+        # visible rows handed to the run since the last stage-row
+        # vector was noted: what a marked first stage's books take as
+        # its rows in
+        self._booked = [(si, st) for si, st in enumerate(self.stages)
+                        if st.books]
+        self._rows_in = 0
 
     # -- eligibility -------------------------------------------------------
     def fusable_reason(self) -> Optional[str]:
@@ -716,9 +729,24 @@ class FusedStages:
             outs = nxt
         return outs
 
+    def note_rows_in(self, rows: int) -> None:
+        """Visible rows of a chunk the run's owner hands it; only the
+        marked stages' books read it."""
+        self._rows_in += int(rows)
+
     def note_stage_rows(self, counts: np.ndarray, chunks: int) -> None:
-        self.stage_rows += counts.astype(np.int64)
+        counts = counts.astype(np.int64)
+        self.stage_rows += counts
         self.stage_chunks += chunks
+        rows_in, self._rows_in = self._rows_in, 0
+        if not self._booked:
+            return
+        from risingwave_tpu.utils import metrics as _m
+        for si, st in self._booked:
+            before = int(counts[si - 1]) if si else rows_in
+            note = _m.note_hop_rows if st.kind == "hop_window" \
+                else _m.note_join_condition
+            note(st.books, before, int(counts[si]))
 
     def drain_stage_metrics(self) -> List[Tuple[str, int, int]]:
         # same-kind stages in one run (e.g. filter→filter after an MV

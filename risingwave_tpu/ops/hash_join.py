@@ -240,15 +240,24 @@ def epoch_probe(table: ht.TableState, chains: ChainState,
                 pay: jnp.ndarray, deg_self: jnp.ndarray,
                 deg_sink: jnp.ndarray, up: jnp.ndarray,
                 aux: jnp.ndarray, key_width: int, out_cap: int,
-                with_degrees: bool):
+                with_degrees: bool, start=0):
     """Probe a whole epoch's rows (each at its own sequence) in one
     dispatch against post-apply state — exact by sequence visibility.
+
+    ``start`` (a traced scalar, not a shape) is the first pair this
+    dispatch writes: pairs [start, start + out_cap) of the epoch's
+    pair sequence land in the buffer, the others are dropped, and the
+    header still carries the total. A probe whose matches outgrow the
+    largest buffer is read in pages of one program instead of a
+    larger program per size (JoinSideKernel.PROBE_CAP_TOP). Degree
+    maintenance reads the buffer, so ``with_degrees`` pages never.
 
     Fused degrees + cumsum + emit + payload gather + degree
     maintenance: ONE kernel, ONE packed d2h matrix of width
     W = 2 + P + (1 if with_degrees). Layout:
 
-      row 0                      header [total_pairs, 0, ...]
+      row 0                      header [total_pairs, longest chain
+                                 walked (rows, visible or not), ...]
       rows 1..1+n (deg only)     per-probe-row match degrees (col 0)
       out_cap pair rows          [probe_row, ref, pay lanes..., old]
 
@@ -289,23 +298,27 @@ def epoch_probe(table: ht.TableState, chains: ChainState,
         return (chains.ins_seq[safe] < seq) & (chains.del_seq[safe] >= seq)
 
     def body1(c):
-        cur, deg = c
+        cur, deg, steps = c
         safe = jnp.maximum(cur, 0)
         m = (cur >= 0) & visible(safe)
         return (jnp.where(cur >= 0, chains.next[safe], jnp.int32(-1)),
-                deg + m.astype(jnp.int32))
+                deg + m.astype(jnp.int32), steps + 1)
 
-    _cur, deg = jax.lax.while_loop(
-        cond, body1, (cur0, jnp.zeros(n, dtype=jnp.int32)))
+    # the loop runs once per row of the longest chain probed
+    _cur, deg, longest = jax.lax.while_loop(
+        cond, body1, (cur0, jnp.zeros(n, dtype=jnp.int32),
+                      jnp.int32(0)))
     offsets = jnp.cumsum(deg, dtype=jnp.int32) - deg
     total = jnp.sum(deg, dtype=jnp.int32)
     row_ids = jnp.arange(n, dtype=jnp.int32)
+    start = jnp.asarray(start, dtype=jnp.int32)
 
     def body2(c):
         cur, wp, op, orf, opay, oold = c
         safe = jnp.maximum(cur, 0)
         m = (cur >= 0) & visible(safe)
-        dest = jnp.where(m, wp, out_cap)
+        at = wp - start
+        dest = jnp.where(m & (at >= 0) & (at < out_cap), at, out_cap)
         op = op.at[dest].set(row_ids, mode="drop")
         orf = orf.at[dest].set(cur, mode="drop")
         if P:
@@ -329,7 +342,8 @@ def epoch_probe(table: ht.TableState, chains: ChainState,
         parts.append(out_old[:, None])
     pairs = jnp.concatenate(parts, axis=1)
     W = pairs.shape[1]
-    header = jnp.zeros((1, W), dtype=jnp.int32).at[0, 0].set(total)
+    header = jnp.zeros((1, W), dtype=jnp.int32).at[0, 0].set(total) \
+        .at[0, 1].set(longest)
     if with_degrees:
         # stored-side transitions: one scatter-add of pair signs
         pair_mask = out_ref >= 0
@@ -401,10 +415,10 @@ def make_prelude_epoch_jits(prelude, label: str):
                            key_width)
 
     def pr(table, chains, pay, deg_self, deg_sink, raw, aux,
-           key_width, out_cap, with_degrees):
+           key_width, out_cap, with_degrees, start=0):
         return epoch_probe(table, chains, pay, deg_self, deg_sink,
                            prelude(raw), aux, key_width, out_cap,
-                           with_degrees)
+                           with_degrees, start)
 
     return (jaxtools.instrumented_jit(
                 ap, f"hash_join.epoch_apply[{label}]",
@@ -520,18 +534,28 @@ class PendingEpochProbe:
     degrees, the pre-epoch degree per ref) and installs the updated
     degree arrays into their owning kernels only once the collect
     succeeds — an overflow redispatch recomputes them from the
-    original arrays, so a retry never double-counts a transition."""
+    original arrays, so a retry never double-counts a transition.
+
+    The pair buffer's size is a shape of the program, so it must not
+    follow the data for ever. A degree-free probe (``top`` given) that
+    overflows takes the buffer to at most ``top`` pairs, the ladder's
+    one further rung, and reads whatever lies beyond in pages of the
+    same program (`redispatch(cap, start)`). A degree-tracking probe
+    keeps doubling: its degree adds read the buffer."""
 
     def __init__(self, mat, n: int, cap: int, redispatch,
-                 pay_width: int, with_degrees: bool, install, bump):
+                 pay_width: int, with_degrees: bool, install, bump,
+                 top: Optional[int] = None, note_chain=None):
         self.mat = mat
         self.n = n
         self.cap = cap
-        self.redispatch = redispatch
+        self.redispatch = redispatch  # (cap, start=0) -> matrix
         self.pay_width = pay_width
         self.with_degrees = with_degrees
         self.install = install        # (deg_self, deg_sink) -> None
         self.bump = bump
+        self.top = None if with_degrees else top
+        self.note_chain = note_chain  # (rows of the longest chain)
         self._degs = None             # latest (deg_self, deg_sink)
 
     def set_degs(self, deg_self, deg_sink) -> None:
@@ -540,19 +564,32 @@ class PendingEpochProbe:
     def collect(self):
         """(degrees | None, probe_idx, refs, pay_rows | None,
         old_deg | None); pairs sorted by probe row index."""
+        from risingwave_tpu.common.chunk import next_pow2
         n = self.n
+        pages, starts = [], ()
         with LEDGER.kernel_scope("hash_join"):
             while True:
                 mat = jaxtools.fetch1(self.mat)
                 total = int(mat[0, 0])
                 if total <= self.cap:
                     break
-                from risingwave_tpu.common.chunk import next_pow2
-                self.cap = max(self.cap * 2, next_pow2(total))
+                if self.top is not None and self.cap >= self.top:
+                    # the last rung: the same program, once per page
+                    starts = range(self.cap, total, self.cap)
+                    later = [self.redispatch(self.cap, start)
+                             for start in starts]
+                    for m in later:
+                        jaxtools.start_fetch(m)
+                    pages = [jaxtools.fetch1(m) for m in later]
+                    break
+                self.cap = self.top if self.top is not None \
+                    else max(self.cap * 2, next_pow2(total))
                 if self.bump is not None:
                     self.bump(self.cap)
                 self.mat = self.redispatch(self.cap)
                 jaxtools.start_fetch(self.mat)
+        if self.note_chain is not None:
+            self.note_chain(int(mat[0, 1]))
         if self.with_degrees and self._degs is not None:
             self.install(*self._degs)
         if self.with_degrees:
@@ -560,7 +597,11 @@ class PendingEpochProbe:
             pairs = mat[1 + n:1 + n + total]
         else:
             deg = None
-            pairs = mat[1:1 + total]
+            pairs = mat[1:1 + min(total, self.cap)]
+        if pages:
+            pairs = np.concatenate(
+                [pairs] + [m[1:1 + min(self.cap, total - start)]
+                           for m, start in zip(pages, starts)])
         P = self.pay_width
         pay = np.ascontiguousarray(pairs[:, 2:2 + P]) if P else None
         old = np.ascontiguousarray(pairs[:, 2 + P]) \
@@ -585,6 +626,12 @@ class JoinSideKernel:
     # occupancy bound drains (a blocking read) whenever an epoch's
     # rows outrun the key table
     DEFAULT_CAPACITY = 1 << 16
+    # the epoch probe's largest pair buffer. The buffer's size is a
+    # shape of the probe program: it starts at `probe_capacity`, an
+    # inner join's first overflow takes it here, and a probe with
+    # still more matches is read in pages (PendingEpochProbe), so no
+    # program is ever compiled for a size the data chose
+    PROBE_CAP_TOP = 1 << 16
 
     def __init__(self, key_width: int,
                  key_capacity: int = DEFAULT_CAPACITY,
@@ -600,9 +647,12 @@ class JoinSideKernel:
         self.payload_width = payload_width
         self.table = ht.DeviceHashTable(key_width, key_capacity)
         self.table.on_grow(self._on_table_grow)
-        # pair-output buffer rows for the fused probe; doubles on
+        # pair-output buffer rows for the fused probe; grows on
         # overflow (kept generous: each size is a fresh XLA compile)
         self._probe_cap = probe_capacity
+        # rows of the longest chain an epoch probe of THIS side walked
+        # since take_longest_chain(), from the probe's own header
+        self._longest_chain = 0
         self.chains = ChainState(
             head=jnp.full(self.table.capacity, -1, dtype=jnp.int32),
             next=jnp.full(row_capacity, -1, dtype=jnp.int32),
@@ -788,6 +838,15 @@ class JoinSideKernel:
         self.table._counters.drain_ready()
         return self.table._counters.take_rounds()
 
+    def take_longest_chain(self) -> int:
+        """Rows of the longest chain the epoch probes collected since
+        the last call walked (0: none collected)."""
+        longest, self._longest_chain = self._longest_chain, 0
+        return longest
+
+    def _note_chain(self, rows: int) -> None:
+        self._longest_chain = max(self._longest_chain, rows)
+
     def probe_epoch(self, up_dev, aux_dev, with_degrees: bool,
                     sink: "JoinSideKernel" = None, prelude=None,
                     prelude_key: str = "",
@@ -809,11 +868,11 @@ class JoinSideKernel:
         # first dispatch's adds are discarded wholesale)
         deg0_self, deg0_sink = self.deg, sink.deg
 
-        def dispatch(cap):
+        def dispatch(cap, start=0):
             out = probe_jit(
                 self.table.state, self.chains, self.pay, deg0_self,
                 deg0_sink, up_dev, aux_dev, self.key_width, cap,
-                with_degrees)
+                with_degrees, np.int32(start))
             return out if with_degrees else (out, None, None)
 
         def install(d_self, d_sink):
@@ -827,15 +886,17 @@ class JoinSideKernel:
             mat, d_self, d_sink = dispatch(out_cap)
         jaxtools.start_fetch(mat)
 
-        def redispatch(cap):
-            m, ds, dk = dispatch(cap)
+        def redispatch(cap, start=0):
+            m, ds, dk = dispatch(cap, start)
             pending.set_degs(ds, dk)
             return m
 
         pending = PendingEpochProbe(
             mat, int(up_dev.shape[0]), out_cap, redispatch,
             pay_width=self.payload_width, with_degrees=with_degrees,
-            install=install, bump=bump)
+            install=install, bump=bump,
+            top=max(self.PROBE_CAP_TOP, out_cap),
+            note_chain=self._note_chain)
         if with_degrees:
             pending.set_degs(d_self, d_sink)
         return pending

@@ -538,6 +538,7 @@ class HashAggExecutor(Executor):
                     float(n), table=self._books_table,
                     op=Op(op).name.lower())
         if self.fused_stages is not None:
+            self.fused_stages.note_rows_in(len(ops))
             # fused fragment path: the RAW chunk ships as one int64
             # matrix; filter/project/key-encode/lane-encode all run
             # inside the kernel's jitted apply. Dispatch metrics are

@@ -40,6 +40,10 @@ import jax.numpy as jnp
 import numpy as np
 
 
+# smallest row capacity of a side's staged epoch (_dispatch_epoch)
+_EPOCH_ROWS_FLOOR = 64
+
+
 class JoinType(enum.Enum):
     """The 8 streaming join types (hash_join.rs:61-71 const generics).
 
@@ -860,6 +864,9 @@ class HashJoinExecutor(Executor):
         # so the chunk build below is a leg of the join -> aggregate
         # hand-off
         self.feeds_agg = False
+        # the join's name in the books (rows in by side and op, rows
+        # out, its condition's rows): its left side's state table
+        self._books_table = f"t{left_table.table_id}"
         self._tier = None
         self._tier_parts: Tuple = (None, None)
         self._tier_seq = 0
@@ -1019,6 +1026,7 @@ class HashJoinExecutor(Executor):
         out_cols, vis2, ops2, stage_rows = fs.chain_body(
             list(aug.columns), np.asarray(aug.visibility),
             np.asarray(aug.ops), np, host_same=host_same)
+        fs.note_rows_in(chunk.cardinality())
         fs.note_stage_rows(np.asarray(stage_rows), 1)
         if not vis2.any():
             return None
@@ -1113,6 +1121,13 @@ class HashJoinExecutor(Executor):
         other = self.sides[1 - side_idx]
         seq = self._seq
         self._seq += 1
+        vis_ops = np.asarray(chunk.ops)[np.asarray(chunk.visibility)]
+        for op, n_op in enumerate(np.bincount(vis_ops, minlength=5)):
+            if n_op:
+                _METRICS.join_input_rows.inc(
+                    float(n_op), table=self._books_table,
+                    side=("left", "right")[side_idx],
+                    op=Op(op).name.lower())
         probe_vis = np.asarray(chunk.visibility) & nonnull
         # heavy-hitter sketch per join input ("/0" build, "/1"
         # probe): unfused sides already built the lanes for the
@@ -1223,7 +1238,10 @@ class HashJoinExecutor(Executor):
             if not buf:
                 continue
             total = self._epoch_rows[s]
-            cap = next_pow2(total)
+            # a side fed by an aggregate's few changed groups stages a
+            # handful of rows, 8 in one epoch and 16 in the next: under
+            # the floor they are one shape, not a program per count
+            cap = next_pow2(total, floor=_EPOCH_ROWS_FLOOR)
             w = buf[0][0].shape[1]
             # fused input sides buffer int64 RAW matrices; direct
             # sides buffer int32 [key | payload] lanes
@@ -1460,10 +1478,12 @@ class HashJoinExecutor(Executor):
             self._epoch_buf = ([], [])
             self._epoch_rows = [0, 0]
             self._deg_replay = [None, None]
+        n_out = float(sum(c.cardinality() for c in outs))
+        if n_out:
+            _METRICS.join_output_rows.inc(n_out, table=self._books_table)
         if self.feeds_agg:
             _METRICS.join_to_agg_rows.inc(
-                float(sum(c.cardinality() for c in outs)),
-                view=_costs.current_mv() or "")
+                n_out, view=_costs.current_mv() or "")
         return outs
 
     def _note_batch_books(self) -> None:
@@ -1474,6 +1494,12 @@ class HashJoinExecutor(Executor):
             _hotkeys.note_batch_books(
                 f"join.t{side.table.table_id}", f"{self.identity}/{i}",
                 getattr(side._kernel, "take_probe_rounds", None))
+            take = getattr(side._kernel, "take_longest_chain", None)
+            if take is not None:
+                # from the header of the probe matrix just collected:
+                # no read of its own
+                _METRICS.join_probe_chain.set(
+                    float(take()), kernel=f"join.t{side.table.table_id}")
 
     def _deg_replay_arrays(self, side_idx: int, max_ref: int):
         """(values, written) replay arrays for `side_idx`, grown to

@@ -44,6 +44,9 @@ class HopWindowExecutor(Executor):
         super().__init__(ExecutorInfo(Schema(fields), list(pk_indices),
                                       "HopWindowExecutor"))
         self.input = input_
+        # the planner's mark: the aggregate it put over this HOP
+        # (`t<state table id>`), the expansion's name in the books
+        self.books_table = ""
 
     async def execute(self) -> AsyncIterator[Message]:
         ws_idx = len(self.input.schema)
@@ -64,6 +67,10 @@ class HopWindowExecutor(Executor):
             if c.validity is not None:
                 vis = vis & np.asarray(c.validity)
             base = (ts.astype(np.int64) // self.slide) * self.slide
+            if self.books_table:
+                from risingwave_tpu.utils.metrics import note_hop_rows
+                rows = int(vis.sum())
+                note_hop_rows(self.books_table, rows, rows * self.units)
             # Batched expansion (ISSUE 12): pow2 GROUPS of copy-major
             # replicas — ⌈log2⌉ chunks per input chunk instead of
             # `units` (5 windows → one 4×-copy chunk + one 1×-copy

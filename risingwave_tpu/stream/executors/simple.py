@@ -153,9 +153,14 @@ class ProjectExecutor(Executor):
 class FilterExecutor(Executor):
     """Visibility-mask filter with update-pair degradation (filter.rs)."""
 
-    def __init__(self, input_: Executor, predicate: Expression):
+    def __init__(self, input_: Executor, predicate: Expression,
+                 condition_of: str = ""):
         self.input = input_
         self.predicate = predicate
+        # the planner's mark: this filter is the condition of a
+        # JOIN ... ON (its conjuncts that are no hash keys), and the
+        # name is that join's in the books (`t<state table id>`)
+        self.condition_of = condition_of
         info = ExecutorInfo(input_.schema, list(input_.pk_indices),
                             "FilterExecutor")
         super().__init__(info)
@@ -164,7 +169,10 @@ class FilterExecutor(Executor):
         import numpy as np
         async for msg in self.input.execute():
             if is_chunk(msg):
-                out = self._apply(msg)
+                if self.condition_of:
+                    out = self._apply_condition(msg)
+                else:
+                    out = self._apply(msg)
                 # a fully-filtered chunk is dead weight downstream
                 # (empty-message suppression, end to end)
                 if np.asarray(out.visibility).any():
@@ -174,6 +182,17 @@ class FilterExecutor(Executor):
 
     def _apply(self, chunk: StreamChunk) -> StreamChunk:
         return self.apply_predicate(chunk, self.predicate)
+
+    def _apply_condition(self, chunk: StreamChunk) -> StreamChunk:
+        """`_apply`, on the books of the join whose condition this
+        filter is."""
+        from risingwave_tpu.stream.trace_ctx import join_condition_span
+        from risingwave_tpu.utils.metrics import note_join_condition
+        with join_condition_span(self.condition_of):
+            out = self._apply(chunk)
+            note_join_condition(self.condition_of, chunk.cardinality(),
+                                out.cardinality())
+        return out
 
     @staticmethod
     def apply_predicate(chunk: StreamChunk,

@@ -53,6 +53,30 @@ def join_to_agg_handoff(on: bool):
             time.perf_counter() - t0, view=_costs.current_mv() or "")
 
 
+@contextlib.contextmanager
+def join_condition_span(join: str):
+    """The host seconds in which the condition of a JOIN ... ON (its
+    conjuncts that are no hash keys) is evaluated, filed under the
+    join the planner marked the filter with: counter
+    ``stream_join_condition_seconds{table}``
+    (``join_condition.<join>.seconds`` of rw_metrics_history) and a
+    ``join.condition`` annotation on the profiler's clock. Where the
+    filter is a stage of a fused block the step evaluates it together
+    with the block's projection, and the seconds are the step's. A
+    second cut of the wall time, like ``exec_s.<Kind>``. No mark, no
+    books."""
+    if not join:
+        yield
+        return
+    t0 = time.perf_counter()
+    try:
+        with _spans.annotation("join.condition"):
+            yield
+    finally:
+        STREAMING.join_condition_seconds.inc(
+            time.perf_counter() - t0, table=join)
+
+
 # -- remote-exchange span context ------------------------------------------
 
 

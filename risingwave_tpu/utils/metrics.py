@@ -306,6 +306,34 @@ class StreamingMetrics:
             "through the aggregate's ingest to its staged batch (pack, "
             "upload); a second cut of the wall time the ledger's "
             "phases partition")
+        self.join_input_rows = r.counter(
+            "stream_join_input_rows",
+            "visible rows a HashJoinExecutor took in, by join "
+            "(t<state table id of its left side>), side (left, right) "
+            "and op: what retracts in a join's inputs")
+        self.join_output_rows = r.counter(
+            "stream_join_output_rows",
+            "rows a HashJoinExecutor emitted, by join, before any "
+            "condition above it")
+        self.join_condition_rows = r.counter(
+            "stream_join_condition_rows",
+            "rows the condition of a JOIN ... ON (its conjuncts that "
+            "are not hash keys) was evaluated on, by join and result "
+            "(kept, dropped), wherever the condition runs")
+        self.join_condition_seconds = r.counter(
+            "stream_join_condition_seconds",
+            "host seconds spent evaluating the condition of a "
+            "JOIN ... ON, by join (trace_ctx.join_condition_span)")
+        self.hop_rows = r.counter(
+            "stream_hop_rows",
+            "rows into and out of a HOP window expansion, by dir (in, "
+            "out) and table (t<state table id> of the aggregate the "
+            "planner put over it), fused into a kernel or not")
+        self.join_probe_chain = r.gauge(
+            "stream_join_probe_longest_chain",
+            "rows of the longest chain the last epoch probe of a join "
+            "side walked, by kernel (join.t<state table id> of the "
+            "probed side), as the device step returned it")
         self.batch_skew_rows = r.counter(
             "stream_batch_skew_rows",
             "visible rows a device kernel staged, by kernel "
@@ -642,6 +670,26 @@ STORAGE = StorageMetrics()
 CLUSTER = ClusterMetrics()
 
 
+def note_join_condition(join: str, rows_in: int, kept: int) -> None:
+    """File the rows a join's condition saw under that join, wherever
+    the condition ran (a FilterExecutor, or a filter stage of a fused
+    run)."""
+    if kept:
+        STREAMING.join_condition_rows.inc(float(kept), table=join,
+                                          result="kept")
+    if rows_in > kept:
+        STREAMING.join_condition_rows.inc(float(rows_in - kept),
+                                          table=join, result="dropped")
+
+
+def note_hop_rows(table: str, rows_in: int, rows_out: int) -> None:
+    """File the rows into and out of one HOP expansion."""
+    if rows_in:
+        STREAMING.hop_rows.inc(float(rows_in), table=table, dir="in")
+    if rows_out:
+        STREAMING.hop_rows.inc(float(rows_out), table=table, dir="out")
+
+
 class MetricsHistory:
     """Bounded per-barrier time series: last N barriers × selected
     counter DELTAS and gauge values (arxiv 1904.03800's concurrent-
@@ -727,8 +775,10 @@ class MetricsHistory:
     def _batch_books():
         """(series name, value now, kind) of what the executors count
         by the batch: rows into an aggregate by op, the join ->
-        aggregate hand-off, the key skew of a staged batch and the
-        rounds of probe_insert's loop. A plan with no such executor
+        aggregate hand-off, rows into and out of a join and through
+        its condition, rows through a HOP, the longest chain a join's
+        probe walked, the key skew of a staged batch and the rounds
+        of probe_insert's loop. A plan with no such executor
         writes no such series and its rows carry no such column."""
         S = STREAMING
         out = [(f"agg_input_rows.{l.get('table', '?')}.{l.get('op', '?')}",
@@ -739,6 +789,20 @@ class MetricsHistory:
             series = metric.series()
             if series:
                 out.append((name, sum(v for _l, v in series), "counter"))
+        for name, metric, kind in (
+                ("join_input_rows.{table}.{side}.{op}",
+                 S.join_input_rows, "counter"),
+                ("join_output.{table}.rows", S.join_output_rows,
+                 "counter"),
+                ("join_condition.{table}.{result}",
+                 S.join_condition_rows, "counter"),
+                ("join_condition.{table}.seconds",
+                 S.join_condition_seconds, "counter"),
+                ("hop_rows.{table}.{dir}", S.hop_rows, "counter"),
+                ("join_probe.{kernel}.longest_chain",
+                 S.join_probe_chain, "gauge")):
+            out += [(name.format(**l), v, kind)
+                    for l, v in metric.series()]
         for field, metric, kind in (
                 ("rows", S.batch_skew_rows, "counter"),
                 ("distinct", S.batch_skew_distinct, "counter"),

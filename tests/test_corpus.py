@@ -345,8 +345,12 @@ def test_tpch_q6_forecast_revenue():
 # own text (comma join, aliases without AS) runs from the benchmark's
 # configuration file in tests/test_nexmark_q4.py, against
 # benchmark/reference/nexmark_q4.py.
-#   q5 (full)   needs a scalar subquery (num >= (SELECT MAX ...));
-#               the hop-window top-1 core runs in test_e2e_q5
+# q5 is no gap either: upstream's q5.slt.part is the JOIN form (two
+# HOP + count subplans, a MAX over one, `JOIN ... ON starttime =
+# starttime_c AND num >= maxn`), not the Beam form with a scalar
+# subquery; it runs from the benchmark's configuration file in
+# tests/test_nexmark_q5.py, against benchmark/reference/nexmark_q5.py
+# (test_e2e_q5 tests models/nexmark.build_q5, the hand-built top-1 core).
 #   q6          per-seller average of last 10 prices: needs
 #               group-top-n-then-agg chaining in one MV
 #   q21         needs regexp_extract (split_part-only form runs as

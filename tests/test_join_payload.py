@@ -312,7 +312,10 @@ def test_join_kernels_steady_state_no_retrace(recompile_guard):
     def phase(b0, nb):
         sl, sr = [], []
         for b in range(b0, b0 + nb):
-            ks = list(range(16))
+            # 80 rows a side: past the floor of a staged epoch (64,
+            # which the small epochs of the tests above all share), so
+            # the warmup compiles this test's own programs
+            ks = list(range(80))
             sl.append(lchunk(ks, [b * 100 + k for k in ks]))
             sl.append(barrier(b))
             sr.append(rchunk(ks, [b * 200 + k for k in ks]))

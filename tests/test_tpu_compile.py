@@ -301,7 +301,8 @@ def test_join_epoch_probe_q7_prelude(served, one_chip):
     _compile(probe_jit, table, chains, pay, deg, deg,
              _sds(one_chip, (EPOCH_ROWS, raw_w), jnp.int64),
              _sds(one_chip, (EPOCH_ROWS, 4), jnp.int32),
-             wmax.kernel.key_width, PROBE_OUT, False)
+             wmax.kernel.key_width, PROBE_OUT, False,
+             _sds(one_chip, (), jnp.int32))   # the page's first pair
 
 
 def test_fused_chain_step(served, one_chip):
@@ -313,6 +314,79 @@ def test_fused_chain_step(served, one_chip):
     assert step.label == "fused.chain_step" and step._args is not None
     assert step._args[2].shape == (CHUNK_ROWS,)
     _compile(step, *_on(one_chip, step._args))
+
+
+# -- q5 at the cell's sizes (PR 33) ------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def served_q5():
+    """The benchmark's `nexmark-q5` view, its text from the
+    configuration file, three barriers deep."""
+    import json
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "nexmark-q5.json")) as f:
+        ddl = json.load(f)["ddl"]
+    yield from _serve_on_cpu([d.format(seed=33) for d in ddl], steps=3)
+
+
+def test_q5_count_aggregate_with_the_hop_prelude(served_q5, one_chip):
+    """hash_agg.apply_fused with the absorbed HOP (five rows a bid,
+    in-trace) and count(*) by (window_start, auction), at the 2^19
+    slots `q5_steady`'s window runs on."""
+    fused = list({id(k): k for ex in _executors(served_q5, "q5")
+                  for k in chip_smoke.kernels_of(ex)
+                  if isinstance(k, hash_agg.GroupedAggKernel)
+                  and k._prelude is not None}.values())
+    assert len(fused) == 2, "q5's two counting aggregates did not fuse"
+    k = fused[0]
+    _compile(k._apply,
+             _agg_state(one_chip, 1 << 19, k.key_width, k.specs),
+             _sds(one_chip, (AGG_BATCH, k._raw_width), jnp.int64))
+
+
+def test_q5_probe_of_the_count_side_in_pages(served_q5, one_chip):
+    """hash_join.epoch_probe: the max side's 64-row epoch probing the
+    count side (2^16 key slots, 2^20 rows), into the pair buffer's
+    last size, the first pair of the page a traced scalar."""
+    counts, maxes = _join_sides(served_q5, "q5")
+    assert maxes.fused_input is not None, "q5's max side did not fuse"
+    _apply, probe_jit = counts.kernel._epoch_jits(
+        maxes.prelude, maxes._prelude_cache_key)
+    kernel = counts.kernel
+    i32 = jnp.int32
+    table = _on(one_chip, jax.eval_shape(
+        lambda: hash_table.make_state(1 << 16, kernel.key_width)))
+    chains = hash_join.ChainState(
+        head=_sds(one_chip, (1 << 16,), i32),
+        next=_sds(one_chip, (1 << 20,), i32),
+        ins_seq=_sds(one_chip, (1 << 20,), i32),
+        del_seq=_sds(one_chip, (1 << 20,), i32))
+    pay = _sds(one_chip, (1 << 20, kernel.payload_width), i32)
+    deg = _sds(one_chip, (1 << 20,), i32)
+    raw_w = probe_jit._args[5].shape[1]
+    assert kernel.PROBE_CAP_TOP == 1 << 16
+    _compile(probe_jit, table, chains, pay, deg, deg,
+             _sds(one_chip, (64, raw_w), jnp.int64),
+             _sds(one_chip, (64, 4), i32),
+             kernel.key_width, kernel.PROBE_CAP_TOP, False,
+             _sds(one_chip, (), i32))
+
+
+def test_q5_chain_above_the_join_at_its_top_rung(served_q5, one_chip):
+    """fused.chain_step: the `>=` and the projection above q5's join,
+    at the ladder's top, 65,536 rows."""
+    from risingwave_tpu.stream.executors.fused import CHAIN_CAP_TOP
+    (step,) = {id(ex._step): ex._step
+               for ex in _executors(served_q5, "q5")
+               if getattr(ex, "_step", None) is not None}.values()
+    assert step.label == "fused.chain_step" and step._args is not None
+
+    def at_top(x):
+        return _sds(one_chip, (CHAIN_CAP_TOP,), x.dtype)
+
+    _compile(step, *jax.tree.map(at_top, step._args))
 
 
 # -- four chips: the sharded steps on a Mesh of the described devices -----------
