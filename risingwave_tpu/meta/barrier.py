@@ -354,8 +354,12 @@ class HeartbeatTick:
                                  the round before collected, else 0
 
     The waits lie between one epoch's books closing and the next
-    inject, so no ledger phase holds them. The clock and the sleeper
-    are class attributes: a test puts a ``VirtualClock`` under them."""
+    inject, so no ledger phase holds them: their sum, less the loop
+    time a checkpoint took inside them, goes on the same row as
+    ``phase.heartbeat_wait``, outside the row's ``interval_s`` and
+    outside the ledger's conservation gate (``ctl phases`` prints
+    it under the epoch's table). The clock and the sleeper are class
+    attributes: a test puts a ``VirtualClock`` under them."""
 
     monotonic = staticmethod(time.monotonic)
     sleep = staticmethod(asyncio.sleep)
@@ -364,25 +368,31 @@ class HeartbeatTick:
         self.interval_s = interval_s
         self.next_at = self.monotonic() + interval_s
         self._books: Dict[str, float] = {}
+        # seconds the ledger's LOOP phases ran inside the round's waits
+        self._stolen = 0.0
 
     async def wait(self) -> None:
         """Return once the tick is due."""
         t0 = self.monotonic()
+        stolen0 = _ledger.stolen_s()
         overdue = t0 >= self.next_at
         with _spans.annotation("heartbeat.wait"):
             while (left := self.next_at - self.monotonic()) > 0:
                 await self.sleep(left)
         self._books = {"heartbeat.wait_s": self.monotonic() - t0,
                        "heartbeat.overdue": float(overdue)}
+        self._stolen = _ledger.stolen_s() - stolen0
 
     async def tail(self, uploader: CheckpointUploader) -> None:
         """Return once every sealed checkpoint has committed. Waits on
         the uploader's tasks without owning them: a cancel that lands
         here leaves them running."""
         t0 = self.monotonic()
+        stolen0 = _ledger.stolen_s()
         with _spans.annotation("heartbeat.tail"):
             await uploader.drain()
         self._books["heartbeat.tail_wait_s"] = self.monotonic() - t0
+        self._stolen += _ledger.stolen_s() - stolen0
 
     def injected(self) -> None:
         """The round's barrier is being injected now (the engine calls
@@ -391,7 +401,19 @@ class HeartbeatTick:
         self.next_at = self.monotonic() + self.interval_s
 
     def file(self, epoch: int) -> None:
-        HISTORY.amend(epoch, self._books)
+        """The round's waits onto `epoch`'s history row, and as
+        ``phase.heartbeat_wait`` their sum less the seconds a LOOP
+        phase held the loop meanwhile (the checkpoint the tail waits
+        for is built and committed inside it; the next epoch's
+        ``phase.checkpoint`` / ``phase.compaction`` hold those): time
+        between two epochs' books that nothing else names, so a reader
+        that sums the ``phase.*`` names over a span of the history's
+        own stamps names each second once."""
+        waited = self._books.get("heartbeat.wait_s", 0.0) \
+            + self._books.get("heartbeat.tail_wait_s", 0.0)
+        HISTORY.amend(epoch, {
+            **self._books,
+            "phase.heartbeat_wait": max(0.0, waited - self._stolen)})
 
 
 class BarrierLoop:

@@ -1247,7 +1247,8 @@ class GroupedAggKernel:
         if p == 0:
             self._flush_idx = np.zeros(0, dtype=np.int32)
             return FlushResult.empty(self.specs, self.key_width)
-        with LEDGER.phase("host_emit", kernel=self._span_label):
+        with LEDGER.phase("host_emit", kernel=self._span_label,
+                          stage="agg.decode"):
             data = mat[1:1 + p]
             self._flush_idx = np.ascontiguousarray(data[:, 0])
             return decode_flush_data(self.specs, self.key_width, data)

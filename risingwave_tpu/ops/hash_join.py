@@ -782,7 +782,8 @@ class JoinSideKernel:
         s = jnp.int32(I32_MAX if seq is None else seq)
         lanes_d = jnp.asarray(key_lanes)
         vis_d = jnp.asarray(vis)
-        with LEDGER.phase("device_compute", kernel="hash_join"):
+        with LEDGER.phase("device_compute", kernel="hash_join",
+                          stage="launch"):
             mat = _probe_pairs_jit(self.table.state, self.chains,
                                    lanes_d, vis_d, s, self._probe_cap,
                                    True)
@@ -883,7 +884,8 @@ class JoinSideKernel:
         def bump(cap):
             self._probe_cap = max(self._probe_cap, cap)
 
-        with LEDGER.phase("device_compute", kernel="hash_join"):
+        with LEDGER.phase("device_compute", kernel="hash_join",
+                          stage="launch"):
             mat, d_self, d_sink = dispatch(out_cap)
         jaxtools.start_fetch(mat)
 

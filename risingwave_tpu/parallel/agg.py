@@ -689,8 +689,10 @@ class ShardedAggKernel:
         self.dispatch_backlog()
         # drain next: reset() would discard pending bucket-overflow
         # flags, and an overflow MUST surface before this barrier's
-        # results are treated as complete
-        self._counters.drain_all()
+        # results are treated as complete. The host waits here for
+        # the queued apply steps: the wait goes by this kernel's label
+        with LEDGER.kernel_scope(f"{self._span_label}.drain"):
+            self._counters.drain_all()
         fc = self._flush_cap
         while True:
             if fc not in self._gather_cache:
@@ -713,7 +715,8 @@ class ShardedAggKernel:
             self._flush_idx = [np.zeros(0, dtype=np.int32)
                                for _ in range(self.n_dev)]
             return FlushResult.empty(self.specs, self.key_width)
-        with LEDGER.phase("host_emit", kernel=self._span_label):
+        with LEDGER.phase("host_emit", kernel=self._span_label,
+                          stage="agg.decode"):
             segs = [mats[d, 1:1 + int(ps[d])]
                     for d in range(self.n_dev)]
             self._flush_idx = [np.ascontiguousarray(s[:, 0])

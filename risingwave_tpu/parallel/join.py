@@ -162,8 +162,8 @@ class ShardedPendingEpochProbe:
         """(degrees | None, probe_idx, refs, None, None) over the
         CONCATENATED epoch row space, pairs sorted by probe row."""
         k = self.kernel
-        k.drain_overflows()
         with LEDGER.kernel_scope("sharded_join"):
+            k.drain_overflows()
             while True:
                 if self.overflow is not None and \
                         bool(np.asarray(jaxtools.fetch1(
@@ -770,7 +770,8 @@ class ShardedJoinKernel:
             prelude=prelude, prelude_key=prelude_key)
         _note_dispatch(m, "sharded_join")
         self._book_staged(aux_dev, 0, bucket)
-        with LEDGER.phase("device_compute", kernel="sharded_join"):
+        with LEDGER.phase("device_compute", kernel="sharded_join",
+                          stage="launch"):
             self.table, self.chains, ovf = step(
                 self.table, self.chains, up_dev, aux_dev,
                 self.owner_map)
@@ -862,7 +863,7 @@ class ShardedJoinKernel:
                 prelude=prelude, prelude_key=prelude_key)
             _note_dispatch(m, "sharded_join")
             (sink or self)._book_staged(aux_dev, 1, bucket)
-            with LEDGER.phase("device_compute",
+            with LEDGER.phase("device_compute", stage="launch",
                               kernel="sharded_join"):
                 mats, ovf = step(self.table, self.chains, up_dev,
                                  aux_dev, self.owner_map)
@@ -921,7 +922,8 @@ class ShardedJoinKernel:
         step = self._build_apply_probe(bucket, out_cap)
         _note_dispatch(m, "sharded_join")
         self._book_host(lanes, pv | im | dm, bucket)
-        with LEDGER.phase("device_compute", kernel="sharded_join"):
+        with LEDGER.phase("device_compute", kernel="sharded_join",
+                          stage="launch"):
             self.table, self.chains, mats, overflow = step(
                 self.table, self.chains, other.table, other.chains,
                 jnp.asarray(lanes), jnp.asarray(rowids),
@@ -939,7 +941,8 @@ class ShardedJoinKernel:
         step = self._build_probe_only(bucket, out_cap)
         _note_dispatch(m, "sharded_join")
         self._book_host(lanes, vis, bucket)
-        with LEDGER.phase("device_compute", kernel="sharded_join"):
+        with LEDGER.phase("device_compute", kernel="sharded_join",
+                          stage="launch"):
             mats, overflow = step(self.table, self.chains,
                                   jnp.asarray(lanes),
                                   jnp.arange(m, dtype=jnp.int32),

@@ -36,6 +36,7 @@ from risingwave_tpu.state.keycodec import (
 from risingwave_tpu.state.mem_table import KeyOp, MemTable
 from risingwave_tpu.state.store import StateStore
 from risingwave_tpu.state import topology as _topology
+from risingwave_tpu.utils.ledger import staged
 from risingwave_tpu.utils.metrics import STREAMING as _METRICS
 
 # which encoder made each state-table key: the columnar one (every bulk
@@ -134,6 +135,7 @@ class StateTable:
         keys, vals = self.mem_table.drain_bulk()
         return keys, vals, self.epoch.curr.value
 
+    @staged("state.commit")
     def commit(self, new_epoch: EpochPair) -> int:
         """Flush buffered ops at the sealed (current) epoch; advance.
 
@@ -246,9 +248,13 @@ class StateTable:
         return deleted
 
     # -- bulk row API (barrier-flush hot path for device operators) -----
+    # Each bulk entry point (and write_chunk) is one `state.write` stage
+    # of host_emit: key encoding, value rows, memtable insert. The
+    # single-row insert/delete/update above are not: row loops call them.
     # ``pk_cols``, where a caller gives it, is the rows' pk columns as it
     # already holds them (one ``KeyColumn`` per pk column, in pk order,
     # equal to the rows' own values); otherwise they are taken from the rows.
+    @staged("state.write")
     def insert_rows(self, rows: Sequence[Sequence],
                     pk_cols: Optional[Sequence[KeyColumn]] = None) -> None:
         """Batch insert: vnodes and keys by the column, whatever the
@@ -263,6 +269,7 @@ class StateTable:
         for key, row in zip(keys, rows_t):
             mt.insert(key, row)
 
+    @staged("state.write")
     def delete_rows(self, rows: Sequence[Sequence],
                     pk_cols: Optional[Sequence[KeyColumn]] = None) -> None:
         self._spill_staged()
@@ -270,6 +277,7 @@ class StateTable:
         for key, row in zip(self._encode_pk_rows(rows, pk_cols), rows):
             mt.delete(key, tuple(row))
 
+    @staged("state.write")
     def update_rows(self, old_rows: Sequence[Sequence],
                     new_rows: Sequence[Sequence],
                     pk_cols: Optional[Sequence[KeyColumn]] = None) -> None:
@@ -326,6 +334,7 @@ class StateTable:
             cols.append((col, valid))
         return cols
 
+    @staged("state.write")
     def write_chunk(self, chunk: StreamChunk,
                     defer: bool = False) -> None:
         """Apply a visible-row StreamChunk — the barrier-flush hot path.

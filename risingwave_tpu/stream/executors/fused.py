@@ -32,6 +32,7 @@ import numpy as np
 from risingwave_tpu.common.chunk import Column, Op, StreamChunk
 from risingwave_tpu.ops.fused import FusedStages, build_chain_step
 from risingwave_tpu.stream.executor import Executor, ExecutorInfo
+from risingwave_tpu.utils.ledger import staged
 from risingwave_tpu.stream.message import (
     Message, Watermark, is_barrier, is_chunk,
 )
@@ -161,12 +162,17 @@ class FusedFragmentExecutor(Executor):
                 tuple(cut(a) for a in flat_ok), cut(vis), cut(ops),
                 stage_rows)
 
+    @staged("fused.chunk")
     def _run_chunk(self, msg: StreamChunk):
         """One chunk through the step: the output chunk, or None where
         no row is left (the empty-suppression contract, end to end:
         the sequential filter/project would have emitted nothing
         either, and an all-late chunk emits no watermark —
-        WatermarkFilterExecutor parity)."""
+        WatermarkFilterExecutor parity). One `fused.chunk` stage of
+        host_emit: the padding, the output chunk, and the blocking read
+        of the step's result (`np.asarray` on device arrays, not
+        `jaxtools.fetch`: the wait for the device is here, in no
+        `device.wait.*`); the launch inside keeps its own phase."""
         fs = self.fused_stages
         out_schema = fs.out_schema
         # synthetic runtime columns (absorbed row_id_gen ids,

@@ -46,6 +46,7 @@ from risingwave_tpu.stream.message import (
 )
 from risingwave_tpu.stream import hotkeys as _hotkeys
 from risingwave_tpu.stream.trace_ctx import join_to_agg_handoff
+from risingwave_tpu.utils.ledger import staged
 from risingwave_tpu.utils.metrics import STREAMING as _METRICS
 
 _SUM_OUT = {
@@ -530,6 +531,7 @@ class HashAggExecutor(Executor):
             out.append((in_lanes, ok))
         return tuple(out)
 
+    @staged("agg.ingest")
     def _apply_chunk(self, chunk: StreamChunk) -> None:
         ops = np.asarray(chunk.ops)[np.asarray(chunk.visibility)]
         for op, n in enumerate(np.bincount(ops, minlength=5)):
@@ -789,6 +791,7 @@ class HashAggExecutor(Executor):
         return new_ok
 
     @staticmethod
+    @staged("agg.persist")
     def _write_multiset_pending(pending: Dict[int, Dict[tuple, int]],
                                 tables: Dict[int, StateTable]) -> None:
         """Write buffered multiset deltas through to the StateTables
@@ -995,7 +998,6 @@ class HashAggExecutor(Executor):
         if self._tier is not None:
             self._tier_forget_expired(phys)
         self._cleaned_wm = wm
-        _METRICS.agg_rows_cleaned.inc(n, executor=self.identity)
 
     INTERNER_GC_MIN = 4096
 
@@ -1026,6 +1028,7 @@ class HashAggExecutor(Executor):
             it.gc(live_cols[j])
 
     # -- barrier path ----------------------------------------------------
+    @staged("agg.decode")
     def _group_key_host(self, keys: np.ndarray
                         ) -> List[Tuple[np.ndarray, np.ndarray]]:
         """Key lanes → per group col (values in col dtype, valid mask)."""
@@ -1084,6 +1087,12 @@ class HashAggExecutor(Executor):
             self._overwrite_hll_outputs(fr, gk)
             self._persist_hll_dirty()
         self._deleted_lanes.clear()
+        return self._emit_changes(fr, gk)
+
+    @staged("agg.emit")
+    def _emit_changes(self, fr, gk) -> Optional[StreamChunk]:
+        """From the flushed groups' changed-mask to the chunk that goes
+        downstream; the state rows are persisted on the way."""
         outs, nulls = fr.outs, fr.nulls
         pouts, pnulls = fr.prev_outs, fr.prev_nulls
         cur_live = fr.group_rows > 0
@@ -1187,6 +1196,7 @@ class HashAggExecutor(Executor):
                 table.insert(gkey + (regs_d[gkey].tobytes(),))
             self._hll_dirty[j].clear()
 
+    @staged("agg.extremes")
     def _recompute_extremes(self, fr, gk) -> None:
         """Correct stale device MIN/MAX for groups that saw deletes by
         scanning their surviving value multiset, then patch the device
@@ -1283,6 +1293,7 @@ class HashAggExecutor(Executor):
                 [raw[k][idx] for k in range(sl.start, sl.stop)]))
         return list(zip(*cols)) if cols else []
 
+    @staged("agg.persist")
     def _persist(self, fr, gk, ins_i, upd_i, del_i) -> None:
         # bulk row APIs, one key-encode pass per flush class; the state
         # table's pk is the group key, which ``gk`` already holds by the

@@ -117,6 +117,7 @@ from risingwave_tpu.stream.trace_ctx import (
 )
 from risingwave_tpu.stream import costs as _costs
 from risingwave_tpu.stream import hotkeys as _hotkeys
+from risingwave_tpu.utils.ledger import LEDGER, staged
 from risingwave_tpu.utils.metrics import STREAMING as _METRICS
 
 
@@ -1042,6 +1043,7 @@ class HashJoinExecutor(Executor):
         post = StreamChunk(fs.out_schema, cols, vis2, ops2)
         return post, encode_raw_chunk(aug, fs.ref_cols)
 
+    @staged("join.pairs")
     def _pairs_chunk(self, side_idx: int, chunk: StreamChunk,
                      probe_idx: np.ndarray, refs: np.ndarray,
                      pay: Optional[np.ndarray] = None) -> StreamChunk:
@@ -1063,6 +1065,7 @@ class HashJoinExecutor(Executor):
             other_cols,
             self._ops_of(chunk, probe_idx), t, cap)
 
+    @staged("join.pairs")
     def _padded_from_chunk(self, side_idx: int, chunk: StreamChunk,
                            idx: np.ndarray) -> StreamChunk:
         """(row, NULLs) for unmatched rows of an outer incoming side."""
@@ -1075,6 +1078,7 @@ class HashJoinExecutor(Executor):
             self._null_cols(other.schema, cap),
             self._ops_of(chunk, idx), t, cap)
 
+    @staged("join.pairs")
     def _padded_from_arena(self, side_idx: int, refs: np.ndarray,
                            op: Op) -> StreamChunk:
         """(stored row, NULLs) for degree transitions of an outer side."""
@@ -1087,6 +1091,7 @@ class HashJoinExecutor(Executor):
             side_idx, me.arena.gather(refs, cap),
             self._null_cols(other.schema, cap), ops, t, cap)
 
+    @staged("join.pairs")
     def _subject_from_chunk(self, chunk: StreamChunk,
                             idx: np.ndarray) -> StreamChunk:
         t = len(idx)
@@ -1099,6 +1104,7 @@ class HashJoinExecutor(Executor):
         ops[:t] = self._ops_of(chunk, idx)
         return StreamChunk(self.schema, cols, vis, ops)
 
+    @staged("join.pairs")
     def _subject_from_arena(self, refs: np.ndarray, op: Op
                             ) -> StreamChunk:
         subj = self.join_type.subject
@@ -1110,6 +1116,7 @@ class HashJoinExecutor(Executor):
         ops = np.full(cap, int(op), dtype=np.int8)
         return StreamChunk(self.schema, cols, vis, ops)
 
+    @staged("join.ingest")
     def _ingest_chunk(self, side_idx: int, chunk: StreamChunk,
                       key_lanes, nonnull: np.ndarray,
                       raw: Optional[np.ndarray] = None) -> None:
@@ -1460,17 +1467,18 @@ class HashJoinExecutor(Executor):
                     # the buffered epoch carries only this chunk's dense
                     # prefix (dn rows at offset off); degrees re-pad to
                     # the chunk's capacity for the chunk-relative masks
-                    lo = np.searchsorted(p_s, off)
-                    hi = np.searchsorted(p_s, off + dn)
-                    probe_idx = (p_s[lo:hi] - off).astype(np.int32)
-                    refs = r_s[lo:hi]
-                    if pay_s is not None:
-                        pay = pay_s[lo:hi]
-                    if old_s is not None:
-                        old = old_s[lo:hi].astype(np.int64)
-                    if d_s is not None:
-                        deg = np.zeros(n, dtype=np.int64)
-                        deg[:dn] = d_s[off:off + dn]
+                    with LEDGER.phase("host_emit", stage="join.split"):
+                        lo = np.searchsorted(p_s, off)
+                        hi = np.searchsorted(p_s, off + dn)
+                        probe_idx = (p_s[lo:hi] - off).astype(np.int32)
+                        refs = r_s[lo:hi]
+                        if pay_s is not None:
+                            pay = pay_s[lo:hi]
+                        if old_s is not None:
+                            old = old_s[lo:hi].astype(np.int64)
+                        if d_s is not None:
+                            deg = np.zeros(n, dtype=np.int64)
+                            deg[:dn] = d_s[off:off + dn]
                 outs.extend(self._emit_one(side_idx, chunk, nonnull, deg,
                                            probe_idx, refs, ins_idx,
                                            ins_refs, pay, old))
@@ -1543,7 +1551,6 @@ class HashJoinExecutor(Executor):
         exactly as the host degrees array used to."""
         jt = self.join_type
         me = self.sides[side_idx]
-        other = self.sides[1 - side_idx]
         vis = np.asarray(chunk.visibility)
         n = chunk.capacity
         if deg is None and jt != JoinType.INNER:
@@ -1569,51 +1576,8 @@ class HashJoinExecutor(Executor):
                 outs.append(self._subject_from_chunk(chunk, sel))
         # 3) stored-row degree transitions on the other side
         if (1 - side_idx) in jt.tracked_sides and len(refs):
-            sgn = np.where(self._ops_of(chunk, probe_idx)
-                           == int(Op.INSERT), 1, -1)
-            uref, inv = np.unique(refs, return_inverse=True)
-            delta = np.zeros(len(uref), dtype=np.int64)
-            np.add.at(delta, inv, sgn)
-            if other.dev_degrees:
-                # seed from the matrix's pre-epoch value on first
-                # touch; later chunks read the replay arrays (exactly
-                # the running value the host array used to hold) —
-                # whole-column gathers/scatters, no per-pair python
-                seed = np.zeros(len(uref), dtype=np.int64)
-                if old is not None and len(old):
-                    first = np.zeros(len(uref), dtype=np.int64)
-                    # inv maps pair → uref slot; any pair of the ref
-                    # carries the same old value
-                    first[inv] = old
-                    seed = first
-                vals, wr = self._deg_replay_arrays(
-                    1 - side_idx, int(uref.max()))
-                cur = np.where(wr[uref], vals[uref], seed)
-                new = cur + delta
-                vals[uref] = new
-                wr[uref] = True
-                old_v = cur
-            else:
-                old_v = other.degrees[uref]
-                new = old_v + delta
-                other.degrees[uref] = new
-            flip_on = uref[(old_v == 0) & (new > 0)]
-            flip_off = uref[(old_v > 0) & (new == 0)]
-            if jt.subject is not None:       # semi/anti subject = other
-                on_op = Op.DELETE if jt.is_anti else Op.INSERT
-                off_op = Op.INSERT if jt.is_anti else Op.DELETE
-                if len(flip_on):
-                    outs.append(self._subject_from_arena(flip_on, on_op))
-                if len(flip_off):
-                    outs.append(self._subject_from_arena(flip_off,
-                                                         off_op))
-            else:                            # outer side: padded flips
-                if len(flip_on):
-                    outs.append(self._padded_from_arena(
-                        1 - side_idx, flip_on, Op.DELETE))
-                if len(flip_off):
-                    outs.append(self._padded_from_arena(
-                        1 - side_idx, flip_off, Op.INSERT))
+            outs.extend(self._degree_transitions(side_idx, chunk,
+                                                 probe_idx, refs, old))
         # 4) initial degrees for the rows this chunk stored (the state
         # apply already ran at dispatch; deg is the probe-time count;
         # the device array already took the same init via the probe's
@@ -1627,6 +1591,64 @@ class HashJoinExecutor(Executor):
             else:
                 # degrees array already grown by apply_chunk at dispatch
                 me.degrees[ins_refs] = deg[ins_idx]
+        return outs
+
+    @staged("join.degrees")
+    def _degree_transitions(self, side_idx: int, chunk: StreamChunk,
+                            probe_idx: np.ndarray, refs: np.ndarray,
+                            old: Optional[np.ndarray]
+                            ) -> List[StreamChunk]:
+        """The other side's stored rows whose match degree crossed zero
+        under this chunk's pairs, as the chunks that flip their
+        NULL-padded (outer) or subject (semi/anti) emission."""
+        jt = self.join_type
+        other = self.sides[1 - side_idx]
+        outs: List[StreamChunk] = []
+        sgn = np.where(self._ops_of(chunk, probe_idx)
+                       == int(Op.INSERT), 1, -1)
+        uref, inv = np.unique(refs, return_inverse=True)
+        delta = np.zeros(len(uref), dtype=np.int64)
+        np.add.at(delta, inv, sgn)
+        if other.dev_degrees:
+            # seed from the matrix's pre-epoch value on first
+            # touch; later chunks read the replay arrays (exactly
+            # the running value the host array used to hold) —
+            # whole-column gathers/scatters, no per-pair python
+            seed = np.zeros(len(uref), dtype=np.int64)
+            if old is not None and len(old):
+                first = np.zeros(len(uref), dtype=np.int64)
+                # inv maps pair → uref slot; any pair of the ref
+                # carries the same old value
+                first[inv] = old
+                seed = first
+            vals, wr = self._deg_replay_arrays(
+                1 - side_idx, int(uref.max()))
+            cur = np.where(wr[uref], vals[uref], seed)
+            new = cur + delta
+            vals[uref] = new
+            wr[uref] = True
+            old_v = cur
+        else:
+            old_v = other.degrees[uref]
+            new = old_v + delta
+            other.degrees[uref] = new
+        flip_on = uref[(old_v == 0) & (new > 0)]
+        flip_off = uref[(old_v > 0) & (new == 0)]
+        if jt.subject is not None:       # semi/anti subject = other
+            on_op = Op.DELETE if jt.is_anti else Op.INSERT
+            off_op = Op.INSERT if jt.is_anti else Op.DELETE
+            if len(flip_on):
+                outs.append(self._subject_from_arena(flip_on, on_op))
+            if len(flip_off):
+                outs.append(self._subject_from_arena(flip_off,
+                                                     off_op))
+        else:                            # outer side: padded flips
+            if len(flip_on):
+                outs.append(self._padded_from_arena(
+                    1 - side_idx, flip_on, Op.DELETE))
+            if len(flip_off):
+                outs.append(self._padded_from_arena(
+                    1 - side_idx, flip_off, Op.INSERT))
         return outs
 
     # -- watermarks -------------------------------------------------------

@@ -19,6 +19,7 @@ from risingwave_tpu.common.chunk import Op, StreamChunk
 from risingwave_tpu.state.state_table import StateTable
 from risingwave_tpu.stream.executor import Executor, ExecutorInfo
 from risingwave_tpu.stream.message import is_barrier, is_chunk, Message
+from risingwave_tpu.utils.ledger import staged
 
 
 class ConflictBehavior(enum.Enum):
@@ -68,6 +69,7 @@ class MaterializeExecutor(Executor):
             else:
                 yield msg
 
+    @staged("mv.write")
     def _apply(self, chunk: StreamChunk) -> None:
         if self.conflict == ConflictBehavior.NO_CHECK:
             # NO_CHECK trusts upstream ops by contract — all-insert

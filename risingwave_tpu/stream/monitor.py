@@ -320,7 +320,9 @@ class MonitoredExecutor(Executor):
         # residue is host work that is provably NOT pack/transfer/
         # compute — source decode loops (host_ingest) or downstream
         # reassembly/state writes/dispatch (host_emit); the barrier
-        # park is barrier_wait
+        # park is barrier_wait. Both are also filed by this
+        # executor's kind (exec_phase.<Kind>.<phase>): over the phases
+        # they add up to exec_s.<Kind>
         named = self._cell.named_total()
         _ledger.LEDGER.attribute_exec(self._kind, excl, epoch)
         # per-MV split of the SAME cell the ledger is about to
@@ -331,11 +333,11 @@ class MonitoredExecutor(Executor):
             self.labels["fragment"], epoch,
             self._cell.seconds.get("device_compute", 0.0),
             self._cell.h2d_bytes, self._cell.d2h_bytes)
-        _ledger.LEDGER.commit_cell(epoch, self._cell)
+        _ledger.LEDGER.commit_cell(epoch, self._cell, kind=self._kind)
         resid = excl - named
         if resid > 0:
             _ledger.LEDGER.attribute(self._fallback_phase, resid,
-                                     epoch)
+                                     epoch, kind=self._kind)
         if park_delta > 0:
             # credit parks are their own ledger phase: the wall
             # time subtracted from busy must still be conserved

@@ -275,11 +275,12 @@ def _ledger_d2h(arrays, out) -> None:
         _ledger.LEDGER.add_bytes("d2h", nbytes)
 
 
-def _wait_ready(pending, poll_s: float) -> None:
+def _wait_ready(pending, poll_s: float) -> int:
     """The ONE copy of the ready-wait ladder: GIL-yield spins first
     (XLA host compute lands in µs — a fixed 2ms quantum was the q8
     hot path's single biggest cost on CPU), then sub-ms naps, then
-    the coarse `poll_s`."""
+    the coarse `poll_s`. Returns how many polls slept the coarse
+    quantum: a wait of few of them is mostly quantisation."""
     import time
 
     spins = 0
@@ -292,6 +293,7 @@ def _wait_ready(pending, poll_s: float) -> None:
             time.sleep(poll_s)
         spins += 1
         pending = _not_ready(pending)
+    return max(0, spins - 80)
 
 
 def fetch(*arrays, poll_s: float = 0.002) -> List[np.ndarray]:
@@ -302,14 +304,18 @@ def fetch(*arrays, poll_s: float = 0.002) -> List[np.ndarray]:
     Host numpy arrays pass through untouched.
 
     Phase ledger: the ready-wait segment is the device's compute tail
-    as the host observes it under async dispatch (device_compute); the
+    as the host observes it under async dispatch (device_compute,
+    stage ``wait``: history ``device.wait.<kernel>`` under the label of
+    the enclosing dispatch, and annotation ``phase.device_compute.wait``
+    with the coarse polls as its ``coarse_polls`` stat); the
     materialization is the d2h transfer, with exact bytes.
     """
     start_fetch(*arrays)
     pending = _not_ready(arrays)
     if pending:
-        with _ledger.LEDGER.phase("device_compute"):
-            _wait_ready(pending, poll_s)
+        with _ledger.LEDGER.phase("device_compute", stage="wait") as ann:
+            ann.set_metadata(
+                coarse_polls=_wait_ready(pending, poll_s))
     with _ledger.LEDGER.phase("d2h"):
         out = [np.asarray(a) for a in arrays]
     _ledger_d2h(arrays, out)

@@ -91,11 +91,44 @@ Output surfaces: ``stream_epoch_phase_seconds{phase}`` and
 reads: ``phase.<name>`` seconds, and ``exec_s.<Kind>``, each executor
 kind's exclusive busy seconds of the epoch, a second cut of the same
 wall time); and ``ctl phases``.
+
+**The second coordinate.** The seconds above keep their phase; some of
+them are filed a second time, never added to the phases:
+
+- ``exec_phase.<Kind>.<phase>`` (``stream_exec_phase_seconds{kind,
+  phase}``): where the two cuts cross. At an executor's barrier flush
+  (stream/monitor.py) its cell's named phases and its residue, under
+  the fallback phase, are filed by its kind: over the phases they add
+  up to ``exec_s.<Kind>``, over the kinds to the part of
+  ``phase.<phase>`` that came through cells.
+- ``stage.<phase>.<stage>`` (``stream_phase_stage_seconds{phase,
+  stage}``): a scope opened with ``stage=`` books its exclusive
+  seconds in its phase as ever and also under (phase, stage); its
+  annotation is ``phase.<name>.<stage>``. The stages of ``host_emit``
+  (``agg.ingest``, ``join.pairs``, ``state.write``, ...) name what was
+  a residue; what no stage holds is ``phase.host_emit`` less their
+  sum, computed by the reader.
+- ``device.<launch|wait>.<kernel>`` (``stream_device_host_seconds{
+  kernel, stage}``): a staged scope of ``device_compute`` is filed by
+  the kernel label in force instead, the enclosing ``dispatch_span``'s:
+  where the host stood, not what the device ran. ``launch`` is the
+  call that enqueues a program, ``wait`` the host standing still in
+  ``jaxtools.fetch``; together they are ``phase.device_compute``.
+
+All three ride the executor's cell and flush with its barrier, so a
+row of ``rw_metrics_history`` holds its own epoch's seconds. A name is
+written only where the epoch has seconds of it.
+
+``phase.heartbeat_wait`` (``HeartbeatTick.file``) is no ledger phase:
+the serving heartbeat's waits lie between one epoch's books closing
+and the next inject, outside every ``interval_s`` and outside the
+conservation gate; ``ctl phases`` prints it under the table.
 """
 
 from __future__ import annotations
 
 import contextlib
+import functools
 import threading
 import time
 from collections import OrderedDict, deque
@@ -184,10 +217,13 @@ class AttributionCell:
     executor's pulls since the last barrier (stream/monitor.py owns
     one per wrapped executor and flushes it epoch-exactly)."""
 
-    __slots__ = ("seconds", "h2d_bytes", "d2h_bytes")
+    __slots__ = ("seconds", "stages", "h2d_bytes", "d2h_bytes")
 
     def __init__(self) -> None:
         self.seconds: Dict[str, float] = {}
+        # the staged scopes' seconds a second time: ("stage", phase,
+        # stage), or ("device", launch|wait, kernel) for device_compute
+        self.stages: Dict[tuple, float] = {}
         self.h2d_bytes = 0
         self.d2h_bytes = 0
 
@@ -196,8 +232,9 @@ class AttributionCell:
 
     def take(self):
         """Pop the accumulated contents (flush-at-barrier)."""
-        out = (self.seconds, self.h2d_bytes, self.d2h_bytes)
+        out = (self.seconds, self.stages, self.h2d_bytes, self.d2h_bytes)
         self.seconds = {}
+        self.stages = {}
         self.h2d_bytes = 0
         self.d2h_bytes = 0
         return out
@@ -207,12 +244,16 @@ class _EpochAcc:
     """Open accumulator for one epoch (pre-seal)."""
 
     __slots__ = ("seconds", "h2d_bytes", "d2h_bytes", "warmup", "idle",
-                 "exec_s")
+                 "exec_s", "second")
 
     def __init__(self) -> None:
         self.seconds: Dict[str, float] = {}
         # exclusive busy seconds by executor kind (stream/monitor.py)
         self.exec_s: Dict[str, float] = {}
+        # the second coordinate (module docstring), by key:
+        # ("exec_phase", kind, phase), ("stage", phase, stage),
+        # ("device", launch|wait, kernel)
+        self.second: Dict[tuple, float] = {}
         self.h2d_bytes = 0
         self.d2h_bytes = 0
         self.warmup = False     # saw a kernel (re)compile this epoch
@@ -227,6 +268,10 @@ class _EpochAcc:
         if s > 0:
             self.seconds[phase] = self.seconds.get(phase, 0.0) + s
 
+    def add_second(self, key: tuple, s: float) -> None:
+        if s > 0:
+            self.second[key] = self.second.get(key, 0.0) + s
+
     def add_idle(self, key: str, s: float) -> None:
         if s > 0:
             self.idle[key] = self.idle.get(key, 0.0) + s
@@ -240,7 +285,7 @@ class LedgerRecord:
 
     __slots__ = ("epoch", "kind", "interval_s", "seconds", "h2d_bytes",
                  "d2h_bytes", "warmup", "distributed", "workers",
-                 "idle_max", "domain", "exec_s")
+                 "idle_max", "domain", "exec_s", "second")
 
     def __init__(self, epoch: int, kind: str, interval_s: float,
                  seconds: Dict[str, float], h2d_bytes: int,
@@ -260,6 +305,11 @@ class LedgerRecord:
         # cut of the same wall time the phases partition, never added
         # to them
         self.exec_s: Dict[str, float] = {}
+        # the same seconds by their second coordinate; a key's parts
+        # joined by dots is the name the history row carries
+        # (`exec_phase.<Kind>.<phase>`, `stage.<phase>.<stage>`,
+        # `device.<launch|wait>.<kernel>`)
+        self.second: Dict[tuple, float] = {}
         self.h2d_bytes = h2d_bytes
         self.d2h_bytes = d2h_bytes
         self.warmup = warmup
@@ -302,6 +352,8 @@ class LedgerRecord:
                 "interval_s": self.interval_s,
                 "seconds": dict(self.seconds),
                 "exec_s": dict(self.exec_s),
+                "second": {".".join(k): s
+                           for k, s in self.second.items()},
                 "h2d_bytes": self.h2d_bytes,
                 "d2h_bytes": self.d2h_bytes,
                 "warmup": self.warmup,
@@ -356,29 +408,43 @@ class PhaseLedger:
         return acc
 
     @contextlib.contextmanager
-    def phase(self, name: str, kernel: Optional[str] = None):
+    def phase(self, name: str, kernel: Optional[str] = None,
+              stage: Optional[str] = None):
         """Scoped timer: the block's EXCLUSIVE wall time (minus nested
         scopes) lands in `name` — in the active executor cell when one
         is set (epoch-exact flush at the barrier), else directly in the
         newest injected epoch's accumulator. A LOOP phase instead adds
         to the stolen-time accumulator and waits for the next seal
-        (module docstring). The scope is a ``TraceAnnotation`` for its
-        duration, and a ``phase.<name>`` span of the epoch trace where
-        it lasts ``SPAN_MIN_S`` or more."""
+        (module docstring), and takes no stage. With a `stage` the same
+        seconds are also filed under (`name`, `stage`), or for
+        ``device_compute`` under (`stage`, the kernel label in force):
+        the second coordinate of the module docstring. Nesting is
+        exclusive between stages as between phases. The scope is a
+        ``TraceAnnotation`` for its duration (``phase.<name>`` or
+        ``phase.<name>.<stage>``; the block gets it, for
+        ``set_metadata``), and a ``phase.<name>`` span of the epoch
+        trace where it lasts ``SPAN_MIN_S`` or more."""
         loop = name in LOOP_PHASES
         parent = _SCOPE.get()
         mine = [0.0]
         tok = _SCOPE.set(mine)
         ktok = _KERNEL.set(kernel) if kernel else None
-        ann = _spans.annotation("phase." + name)
+        ann = _spans.annotation(
+            "phase." + name if stage is None
+            else f"phase.{name}.{stage}")
         ann.__enter__()
         t0 = time.perf_counter()
         try:
-            yield
+            yield ann
         finally:
             dur = time.perf_counter() - t0
             ann.__exit__(None, None, None)
             _SCOPE.reset(tok)
+            key = None
+            if stage is not None and not loop:
+                key = ("device", stage, _KERNEL.get() or "unlabeled") \
+                    if name == "device_compute" \
+                    else ("stage", name, stage)
             if ktok is not None:
                 _KERNEL.reset(ktok)
             if parent is not None:
@@ -399,9 +465,15 @@ class PhaseLedger:
                 if cell is not None:
                     cell.seconds[name] = cell.seconds.get(name, 0.0) \
                         + excl
+                    if key is not None:
+                        cell.stages[key] = cell.stages.get(key, 0.0) \
+                            + excl
                 else:
                     with self._lock:
-                        self._acc().add(name, excl)
+                        acc = self._acc()
+                        acc.add(name, excl)
+                        if key is not None:
+                            acc.add_second(key, excl)
             if dur >= SPAN_MIN_S:
                 _spans.EPOCH_TRACER.record(
                     "phase." + name, "phase",
@@ -409,13 +481,19 @@ class PhaseLedger:
                     exclusive_s=round(excl, 6))
 
     def attribute(self, name: str, seconds: float,
-                  epoch: Optional[int] = None) -> None:
+                  epoch: Optional[int] = None,
+                  kind: Optional[str] = None) -> None:
         """Direct (non-scoped) attribution — executor residue, source
-        barrier_wait, barrier-loop commit work."""
+        barrier_wait, barrier-loop commit work. With the executor's
+        `kind` the seconds are also filed under
+        ``exec_phase.<kind>.<name>``."""
         if seconds <= 0:
             return
         with self._lock:
-            self._acc(epoch).add(name, seconds)
+            acc = self._acc(epoch)
+            acc.add(name, seconds)
+            if kind:
+                acc.add_second(("exec_phase", kind, name), seconds)
 
     def attribute_idle(self, seconds: float,
                        epoch: Optional[int] = None,
@@ -479,16 +557,23 @@ class PhaseLedger:
     def pop_cell(self, token) -> None:
         _CELL.reset(token)
 
-    def commit_cell(self, epoch: int, cell: AttributionCell) -> None:
+    def commit_cell(self, epoch: int, cell: AttributionCell,
+                    kind: Optional[str] = None) -> None:
         """Fold one executor's cell into the epoch it just finished
-        (called at barrier passage with the barrier's CURR epoch)."""
-        seconds, h2d, d2h = cell.take()
+        (called at barrier passage with the barrier's CURR epoch):
+        its named phases, the same by the executor's `kind`
+        (``exec_phase.<kind>.<phase>``), and its staged scopes."""
+        seconds, stages, h2d, d2h = cell.take()
         if not seconds and not h2d and not d2h:
             return
         with self._lock:
             acc = self._acc(epoch)
             for name, s in seconds.items():
                 acc.add(name, s)
+                if kind:
+                    acc.add_second(("exec_phase", kind, name), s)
+            for key, s in stages.items():
+                acc.add_second(key, s)
             acc.h2d_bytes += h2d
             acc.d2h_bytes += d2h
 
@@ -530,6 +615,8 @@ class PhaseLedger:
             acc.add(name, s)
         for key, s in carry.idle.items():
             acc.add_idle(key, s)
+        for key, s in carry.second.items():
+            acc.add_second(key, s)
         acc.h2d_bytes += carry.h2d_bytes
         acc.d2h_bytes += carry.d2h_bytes
         acc.warmup = acc.warmup or carry.warmup
@@ -554,6 +641,7 @@ class PhaseLedger:
                            distributed, domain=domain)
         rec.idle_max = idle
         rec.exec_s = acc.exec_s
+        rec.second = acc.second
         rec.recompute_unattributed()
         self.records.append(rec)
         self._publish(rec)
@@ -603,6 +691,14 @@ class PhaseLedger:
                  if p in rec.seconds or p not in MESH_PHASES}
         for kind, s in rec.exec_s.items():
             extra["exec_s." + kind] = s
+        for (family, a, b), s in rec.second.items():
+            extra[f"{family}.{a}.{b}"] = s
+            if family == "exec_phase":
+                STREAMING.exec_phase_seconds.inc(s, kind=a, phase=b)
+            elif family == "stage":
+                STREAMING.phase_stage_seconds.inc(s, phase=a, stage=b)
+            else:
+                STREAMING.device_host_seconds.inc(s, stage=a, kernel=b)
         extra["coverage"] = rec.coverage()
         extra["epoch_h2d_bytes"] = float(rec.h2d_bytes)
         extra["epoch_d2h_bytes"] = float(rec.d2h_bytes)
@@ -768,7 +864,10 @@ class PhaseLedger:
         }
 
     def report(self, last_n: int = 16) -> str:
-        """Human-readable per-epoch table (``ctl phases``)."""
+        """Human-readable per-epoch table (``ctl phases``); under an
+        epoch's table the serving heartbeat's wait before its inject,
+        where the history row has one (time between epochs)."""
+        from risingwave_tpu.utils.metrics import HISTORY
         lines = []
         for rec in list(self.records)[-last_n:]:
             head = (f"epoch {rec.epoch:#x} ({rec.kind}"
@@ -787,6 +886,10 @@ class PhaseLedger:
             if rec.h2d_bytes or rec.d2h_bytes:
                 lines.append(f"  bytes: h2d={rec.h2d_bytes} "
                              f"d2h={rec.d2h_bytes}")
+            between = HISTORY.value(rec.epoch, "phase.heartbeat_wait")
+            if between:
+                lines.append(f"  before its inject, in no interval: "
+                             f"heartbeat_wait {between * 1e3:.2f}ms")
         return "\n".join(lines)
 
     def clear(self) -> None:
@@ -800,3 +903,16 @@ class PhaseLedger:
 
 # the process-global ledger (worker processes drain to the coordinator)
 LEDGER = PhaseLedger()
+
+
+def staged(stage: str):
+    """Decorator: the function's body is one stage of ``host_emit``,
+    a ``LEDGER.phase("host_emit", stage=stage)`` scope. For a
+    per-chunk or per-barrier function; never one a row loop calls."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kw):
+            with LEDGER.phase("host_emit", stage=stage):
+                return fn(*args, **kw)
+        return scoped
+    return deco

@@ -283,9 +283,6 @@ class StreamingMetrics:
         self.join_rows_evicted = r.counter(
             "stream_join_rows_evicted",
             "join-state rows evicted to the cold (state-table) tier")
-        self.agg_rows_cleaned = r.counter(
-            "stream_agg_state_rows_cleaned",
-            "state rows deleted by watermark cleaning")
         self.state_pk_keys = r.counter(
             "stream_state_pk_keys",
             "state-table keys encoded, by path (columnar: the bulk "
@@ -526,6 +523,24 @@ class StreamingMetrics:
             "(host_ingest/host_pack/h2d/device_compute/d2h/host_emit/"
             "barrier_wait; the conservation residual publishes as "
             "phase=unattributed)")
+        # the ledger's second coordinate: the phases' seconds filed a
+        # second time, never added to them (utils/ledger.py docstring)
+        self.exec_phase_seconds = r.counter(
+            "stream_exec_phase_seconds",
+            "each executor kind's exclusive busy seconds by ledger "
+            "phase, its residue under host_emit/host_ingest "
+            "(rw_metrics_history exec_phase.<Kind>.<phase>)")
+        self.phase_stage_seconds = r.counter(
+            "stream_phase_stage_seconds",
+            "a phase's seconds by the stage that spent them, e.g. "
+            "host_emit by agg.persist / join.pairs / state.write "
+            "(rw_metrics_history stage.<phase>.<stage>)")
+        self.device_host_seconds = r.counter(
+            "stream_device_host_seconds",
+            "device_compute as the host spent it: stage=launch "
+            "(enqueueing a program) or wait (standing still in "
+            "fetch), by the kernel label the host stood under "
+            "(rw_metrics_history device.<stage>.<kernel>)")
         self.transfer_bytes = r.counter(
             "stream_transfer_bytes_total",
             "host<->device transfer payload bytes by direction "
@@ -868,10 +883,21 @@ class MetricsHistory:
         times land with its durable commit). An epoch the ring has
         rolled past is left alone."""
         with self._lock:
-            for row in reversed(self._ring):
-                if row[1] == epoch:     # epochs are unique in the ring
-                    row[4].update(values)
-                    return
+            self._values_of(epoch).update(values)
+
+    def value(self, epoch: int, name: str) -> Optional[float]:
+        """One name of one epoch's row; None where the ring has no
+        such row or the row no such name."""
+        with self._lock:
+            return self._values_of(epoch).get(name)
+
+    def _values_of(self, epoch: int) -> Dict[str, float]:
+        """The values of the epoch's row (epochs are unique in the
+        ring), or a throw-away dict. Caller holds the lock."""
+        for row in reversed(self._ring):
+            if row[1] == epoch:
+                return row[4]
+        return {}
 
     def rows(self) -> List[tuple]:
         """(seq, epoch, ts, interval_s, name, value, domain)

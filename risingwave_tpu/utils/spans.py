@@ -403,13 +403,16 @@ def dispatch_span(kernel: str, rows: float, **args):
     sibling compile span (note_compile).
 
     Phase ledger: the span's EXCLUSIVE time (minus nested h2d/d2h
-    scopes) is the launch's device_compute share, stamped with the
-    kernel label so transfers recorded inside inherit it."""
+    scopes and a fetch's wait) is the launch's device_compute share
+    (stage ``launch``: history ``device.launch.<kernel>``), stamped
+    with the kernel label so transfers and waits recorded inside
+    inherit it."""
     from risingwave_tpu.utils.ledger import LEDGER
     t0 = time.time()
     try:
         with annotation(kernel), \
-                LEDGER.phase("device_compute", kernel=kernel):
+                LEDGER.phase("device_compute", kernel=kernel,
+                             stage="launch"):
             # ledger-test seam: a sleep spec here is wall time INSIDE
             # one kernel's dispatch — it must land in the dispatching
             # domain's device_compute books only (the per-domain

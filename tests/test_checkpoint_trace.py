@@ -410,7 +410,7 @@ def test_program_spans_are_in_the_profilers_host_plane(tmp_path):
             for ev in line.events:
                 by_name.setdefault(ev.name, []).append(
                     (ev.start_ns, ev.duration_ns, dict(ev.stats)))
-    for name in ("phase.host_ingest", "phase.device_compute",
+    for name in ("phase.host_ingest", "phase.device_compute.launch",
                  "phase.checkpoint", "phase.compaction", "barrier.inject",
                  "barrier.collect", "barrier.commit", "checkpoint.queue",
                  "checkpoint.build", "checkpoint.put", "checkpoint.commit",
@@ -432,7 +432,7 @@ def test_program_spans_are_in_the_profilers_host_plane(tmp_path):
               or "Executor" in n]
     assert labels, sorted(by_name)
     assert any(_contains(d, p) for n in labels for d in by_name[n]
-               for p in by_name["phase.device_compute"])
+               for p in by_name["phase.device_compute.launch"])
 
 
 # -- 4. device programs carry their label ------------------------------------
