@@ -41,6 +41,9 @@ from risingwave_tpu.stream.executor import Executor, ExecutorInfo
 from risingwave_tpu.stream.executors.keys import (
     LANES_PER_KEY as _LANES_PER_KEY, KeyCodec,
 )
+from risingwave_tpu.stream.executors.value_multiset import (
+    ValueMultiset, pylist, value_order,
+)
 from risingwave_tpu.stream.message import (
     Barrier, Message, Watermark, is_barrier, is_chunk, is_watermark,
 )
@@ -49,11 +52,29 @@ from risingwave_tpu.stream.trace_ctx import join_to_agg_handoff
 from risingwave_tpu.utils.ledger import staged
 from risingwave_tpu.utils.metrics import STREAMING as _METRICS
 
+_MULTISET_READS = _METRICS.agg_multiset.labeled(event="point_reads")
+_MULTISET_WRITTEN = _METRICS.agg_multiset.labeled(event="rows_written")
+_EXTREME_SCANS = _METRICS.agg_multiset.labeled(event="extreme_scans")
+_VALUES_SCANNED = _METRICS.agg_multiset.labeled(event="values_scanned")
+
 _SUM_OUT = {
     DataType.INT16: DataType.INT64, DataType.INT32: DataType.INT64,
     DataType.INT64: DataType.INT64, DataType.DECIMAL: DataType.DECIMAL,
     DataType.FLOAT32: DataType.FLOAT64, DataType.FLOAT64: DataType.FLOAT64,
 }
+
+
+def _group_rows(mat: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+    """Equal rows of an integer matrix, gathered: ``order`` sorts the
+    rows by the column, first column first, equal rows in their given
+    order; ``starts`` are the positions in ``order`` where a new row
+    value begins. (``np.unique(axis=0)`` sorts records: ten times the
+    time of a sort by the column.)"""
+    order = np.lexsort(mat.T[::-1])
+    by_row = mat[order]
+    new = np.ones(len(order), dtype=bool)
+    new[1:] = (by_row[1:] != by_row[:-1]).any(axis=1)
+    return order, np.flatnonzero(new)
 
 
 def agg_result_type(kind: AggKind,
@@ -250,18 +271,21 @@ class HashAggExecutor(Executor):
         self.key_codec = KeyCodec(self.group_types)
         self.specs = [c.spec(in_schema) for c in self.agg_calls]
         # retractable MIN/MAX: device extremes go stale on deletes; the
-        # materialized-input tables (minput.rs analog) let the flush
-        # recompute and patch them (see _recompute_extremes)
+        # value multisets (minput.rs analog) let the flush recompute
+        # and patch them (see _recompute_extremes). Each multiset lives
+        # in memory (value_multiset.py) and is written through to its
+        # materialized-input table once a barrier
         self.minput: Dict[int, StateTable] = dict(minput_tables or {})
         self._deleted_lanes: set = set()
-        # per-epoch buffered value-multiset deltas: call → key → delta
-        # (written through to the StateTables once per barrier, keeping
-        # store round-trips off the chunk hot path)
+        # per-epoch buffered value-multiset deltas: call → (group,
+        # value) → delta, applied to the multiset where it is written
+        # through (a host agg's PREV output reads the multiset as of
+        # the last barrier)
         self._minput_pending: Dict[int, Dict[tuple, int]] = {}
         # DISTINCT dedup (distinct.rs): ONE durable (group, value, cnt)
-        # table + in-memory multiplicity mirror per distinct INPUT
-        # COLUMN — count(DISTINCT x) and sum(DISTINCT x) share it,
-        # like the reference's per-column dedup tables
+        # table + in-memory multiset per distinct INPUT COLUMN —
+        # count(DISTINCT x) and sum(DISTINCT x) share it, like the
+        # reference's per-column dedup tables
         self.distinct_tables: Dict[int, StateTable] = dict(
             distinct_tables or {})
         self._distinct_cols: Dict[int, List[int]] = {}
@@ -275,7 +299,8 @@ class HashAggExecutor(Executor):
                 f"DISTINCT column(s) {missing_d} need dedup state "
                 "tables — pass distinct_tables keyed by input column "
                 "(minput_state_schema shape)")
-        self._distinct_mult: Dict[int, Dict[tuple, int]] = {}
+        self._distinct_mult: Dict[int, ValueMultiset] = {
+            col: ValueMultiset() for col in self.distinct_tables}
         self._distinct_pending: Dict[int, Dict[tuple, int]] = {}
         # incremental live-group count (gates interner GC cheaply)
         self._live_groups = 0
@@ -293,14 +318,16 @@ class HashAggExecutor(Executor):
                 return 0
             distinct = sum(120 * len(m)
                            for m in s._distinct_mult.values())
+            minput = sum(120 * len(m)
+                         for m in s._minput_mult.values())
             pend = sum(120 * len(m)
                        for m in s._minput_pending.values())
             from risingwave_tpu.ops.hash_agg import HLL_M as _M
             sketches = sum((_M + 120) * len(d)
                            for d in s._hll_regs.values())
             cold = 120 * len(getattr(s, "_cold_groups", ()))
-            return (s.key_codec.interner_nbytes() + distinct + pend
-                    + sketches + cold)
+            return (s.key_codec.interner_nbytes() + distinct + minput
+                    + pend + sketches + cold)
 
         _mem.GLOBAL.register(mem_name, _nbytes)
         # dense-HLL calls: sketch registry host-side, one BYTEA aux
@@ -312,6 +339,8 @@ class HashAggExecutor(Executor):
         self.hll_tables: Dict[int, StateTable] = {
             j: self.minput.pop(j) for j in self._hll_calls
             if j in self.minput}
+        self._minput_mult: Dict[int, ValueMultiset] = {
+            j: ValueMultiset() for j in self.minput}
         missing_s = [j for j in self._hll_calls
                      if j not in self.hll_tables]
         if missing_s:
@@ -657,9 +686,13 @@ class HashAggExecutor(Executor):
                          input_idx: int, vals_override=None):
         """Vectorized grouping of visible rows by (group key, value).
 
-        Returns (rows, inverse, n_uniq, deltas, key_tuple_fn, order,
-        starts) — python work is O(distinct keys), not O(rows)
-        (hash_agg.rs minput/distinct parity without the per-row loop).
+        Returns (rows, deltas, keys_of, order, starts) — python work
+        is O(distinct keys), not O(rows) (hash_agg.rs minput/distinct
+        parity without the per-row loop). ``deltas`` is the net sign of
+        each unique key; ``keys_of(us)`` gives the multiset keys
+        ``(group, value)`` of the unique keys ``us``, by the column;
+        ``rows[order[starts[u]:starts[u + 1]]]`` are key ``u``'s chunk
+        rows in chunk order.
         """
         from risingwave_tpu.stream.executors.keys import to_i64
 
@@ -676,8 +709,8 @@ class HashAggExecutor(Executor):
             # host-typed values (string_agg/array_agg): EXACT local
             # interning for the grouping image only — ids live for this
             # call alone, so nothing accumulates across the stream (a
-            # hash image could merge distinct values; np.unique cannot
-            # sort mixed None/str)
+            # hash image could merge distinct values; a sort cannot
+            # order mixed None/str)
             local: Dict[object, int] = {}
             comp[:, -1] = np.fromiter(
                 (local.setdefault(v, len(local))
@@ -685,40 +718,33 @@ class HashAggExecutor(Executor):
                 dtype=np.int64, count=len(rows))
         else:
             comp[:, -1] = to_i64(vals[rows])
-        _uniq, inverse = np.unique(comp, axis=0, return_inverse=True)
-        n_uniq = int(inverse.max()) + 1
-        deltas = np.zeros(n_uniq, dtype=np.int64)
-        np.add.at(deltas, inverse, signs[rows])
+        order, starts = _group_rows(comp)
+        deltas = np.add.reduceat(
+            signs[rows][order].astype(np.int64), starts)
         # first chunk-row index per unique key (stable order)
-        order = np.argsort(inverse, kind="stable")
-        starts = np.searchsorted(inverse[order],
-                                 np.arange(n_uniq, dtype=np.int64))
         first_rows = rows[order[starts]]
         g_cols = [(np.asarray(chunk.columns[i].values),
                    None if chunk.columns[i].validity is None
                    else np.asarray(chunk.columns[i].validity))
                   for i in self.group_indices]
 
-        def _pyval(x):
-            return x.item() if hasattr(x, "item") else x
+        def keys_of(us: np.ndarray) -> List[tuple]:
+            sel = first_rows[us]
+            return list(zip(self._group_tuples(g_cols, sel),
+                            pylist(vals[sel])))
 
-        def key_tuple(u: int) -> tuple:
-            r = int(first_rows[u])
-            group = tuple(
-                None if (okc is not None and not okc[r])
-                else _pyval(gv[r])
-                for gv, okc in g_cols)
-            return group + (_pyval(vals[r]),)
-
-        return rows, inverse, n_uniq, deltas, key_tuple, order, starts
+        return rows, deltas, keys_of, order, starts
 
     def _apply_minput(self, chunk: StreamChunk, key_lanes: np.ndarray,
                       signs: np.ndarray, vis: np.ndarray) -> None:
         """Maintain the per-call value multisets; remember which groups
         saw deletes (only those can have stale device extremes)."""
         del_rows = np.flatnonzero(vis & (signs < 0))
-        for r in del_rows.tolist():
-            self._deleted_lanes.add(tuple(key_lanes[r].tolist()))
+        if len(del_rows):
+            lanes = key_lanes[del_rows]
+            order, starts = _group_rows(lanes)
+            self._deleted_lanes.update(
+                map(tuple, lanes[order[starts]].tolist()))
         for j in self.minput:
             call = self.agg_calls[j]
             c = chunk.columns[call.input_idx]
@@ -741,11 +767,11 @@ class HashAggExecutor(Executor):
                                        vals_override=vals_override)
             if ms is None:
                 continue
-            _rows, _inv, n_uniq, deltas, key_tuple, _o, _s = ms
+            _rows, deltas, keys_of, _order, _starts = ms
             pend = self._minput_pending.setdefault(j, {})
-            for u in np.flatnonzero(deltas != 0).tolist():
-                key = key_tuple(u)
-                pend[key] = pend.get(key, 0) + int(deltas[u])
+            us = np.flatnonzero(deltas != 0)
+            for key, d in zip(keys_of(us), deltas[us].tolist()):
+                pend[key] = pend.get(key, 0) + d
 
     def _apply_distinct(self, col: int, chunk: StreamChunk,
                         key_lanes: np.ndarray, signs: np.ndarray,
@@ -759,33 +785,26 @@ class HashAggExecutor(Executor):
         ms = self._multiset_groups(chunk, key_lanes, signs, ok, col)
         if ms is None:
             return new_ok
-        rows, inverse, n_uniq, deltas, key_tuple, order, starts = ms
-        mult = self._distinct_mult.setdefault(col, {})
+        rows, deltas, keys_of, order, starts = ms
+        mult = self._distinct_mult[col]
         pend = self._distinct_pending.setdefault(col, {})
-        srt = inverse[order]
-        for u in range(n_uniq):
-            d = int(deltas[u])
-            if d == 0:
-                continue
-            key = key_tuple(u)
-            old = mult.get(key, 0)
+        ends = np.append(starts[1:], len(order))
+        us = np.flatnonzero(deltas != 0)
+        for u, key, d in zip(us.tolist(), keys_of(us),
+                             deltas[us].tolist()):
+            old = mult.count(*key)
             new = old + d
             if new < 0:
                 raise ValueError(
                     f"distinct retract below zero for {key}")
-            if new == 0:
-                del mult[key]
-            else:
-                mult[key] = new
+            mult.put(*key, new)
             pend[key] = pend.get(key, 0) + d
             eff = (1 if new > 0 else 0) - (1 if old > 0 else 0)
             if eff == 0:
                 continue
             # representative row with the matching sign (exists: the
             # net delta moved in that direction)
-            lo = int(starts[u])
-            hi = int(starts[u + 1]) if u + 1 < n_uniq else len(srt)
-            cand = rows[order[lo:hi]]
+            cand = rows[order[starts[u]:ends[u]]]
             match = cand[signs[cand] == eff]
             new_ok[int(match[0])] = True
         return new_ok
@@ -793,28 +812,42 @@ class HashAggExecutor(Executor):
     @staticmethod
     @staged("agg.persist")
     def _write_multiset_pending(pending: Dict[int, Dict[tuple, int]],
-                                tables: Dict[int, StateTable]) -> None:
-        """Write buffered multiset deltas through to the StateTables
-        (once per barrier; reads during recompute then see them)."""
-        for j, deltas in pending.items():
-            table = tables[j]
-            for key, d in deltas.items():
+                                tables: Dict[int, StateTable],
+                                mults: Dict[int, ValueMultiset],
+                                at_ingest: bool) -> None:
+        """Write one barrier's net multiset deltas through to the
+        StateTables: new pairs, pairs whose count changed and pairs
+        that reached zero, one batch call each. No row is read: the
+        old count is the in-memory multiset's, which holds the deltas
+        already where the ingest applied them (``at_ingest``: DISTINCT
+        gates its rows on the live counts) and takes them here where
+        it did not (a recompute after this then sees them)."""
+        for j, table in tables.items():
+            deltas, mult = pending.get(j, {}), mults[j]
+            ins: List[tuple] = []
+            upd_old: List[tuple] = []
+            upd_new: List[tuple] = []
+            dels: List[tuple] = []
+            for (group, value), d in deltas.items():
                 if d == 0:
                     continue
-                cur = table.get_row(key)
-                cnt = (0 if cur is None else cur[-1]) + d
-                row = key + (cnt,)
-                if cur is None:
-                    assert cnt > 0, f"retract of unseen value {key}"
-                    table.insert(row)
-                elif cnt == 0:
-                    table.delete(cur)
+                cnt = mult.count(group, value)
+                old, new = (cnt - d, cnt) if at_ingest else (cnt, cnt + d)
+                key = group + (value,)
+                if old == 0:
+                    assert new > 0, f"retract of unseen value {key}"
+                    ins.append(key + (new,))
+                elif new == 0:
+                    dels.append(key + (old,))
                 else:
-                    table.update(cur, row)
+                    upd_old.append(key + (old,))
+                    upd_new.append(key + (new,))
+                mult.put(group, value, new)
+            table.insert_rows(ins)
+            table.update_rows(upd_old, upd_new)
+            table.delete_rows(dels)
+            _MULTISET_WRITTEN.inc(len(ins) + len(upd_old) + len(dels))
         pending.clear()
-
-    def _write_minput_pending(self) -> None:
-        self._write_multiset_pending(self._minput_pending, self.minput)
 
     # -- cold tier (state/tier.py) ---------------------------------------
     def _tier_register(self) -> None:
@@ -832,8 +865,18 @@ class HashAggExecutor(Executor):
             cap=int(self.tier_cap), nbytes=self._tier_nbytes)
 
     @staticmethod
-    def _pyval(x):
-        return x.item() if hasattr(x, "item") else x
+    def _group_tuples(cols, idx) -> List[tuple]:
+        """Group key tuples (None is NULL) of rows ``idx`` of per-column
+        (values, valid mask or None): the keys of the host mirrors and
+        the pk prefixes of the aux tables."""
+        out = []
+        for vals, ok in cols:
+            col = pylist(vals[idx])
+            if ok is not None and not ok[idx].all():
+                col = [v if o else None
+                       for v, o in zip(col, ok[idx].tolist())]
+            out.append(col)
+        return list(zip(*out))
 
     def _tier_touch(self, key_lanes: np.ndarray,
                     vis: np.ndarray) -> None:
@@ -853,10 +896,12 @@ class HashAggExecutor(Executor):
 
     def _reload_groups(self, lanes_ts: List[tuple]) -> None:
         """Reload evicted groups (the _reload_cold analog): device
-        accumulators from the value-state row, distinct-multiset and
-        HLL-register mirrors from their aux tables. Fully durable state
-        makes this retraction-safe — a delete touching a cold group
-        reloads first, then retracts against exact state."""
+        accumulators from the value-state row, the value multisets
+        (minput and distinct) and the HLL registers from their aux
+        tables, one prefix scan a group: with the init barrier the only
+        place a multiset's table is read. Fully durable state makes
+        this retraction-safe — a delete touching a cold group reloads
+        first, then retracts against exact state."""
         from risingwave_tpu.ops.hash_agg import hll_estimate_dense
         ng = len(self.group_indices)
         rows: List[tuple] = []
@@ -879,11 +924,12 @@ class HashAggExecutor(Executor):
                         for r in rows], dtype=dt)
             for j, dt in enumerate(acc_dtypes(self.specs))]
         self.kernel.load_groups(keys, grows, acc_cols)
-        for col, t in self.distinct_tables.items():
-            mult = self._distinct_mult.setdefault(col, {})
-            for vt in groups:
-                for _pk, row in t.iter_prefix(list(vt)):
-                    mult[tuple(row[:-1])] = int(row[-1])
+        for tables, mults in ((self.minput, self._minput_mult),
+                              (self.distinct_tables, self._distinct_mult)):
+            for j, t in tables.items():
+                for vt in groups:
+                    mults[j].load(
+                        row for _pk, row in t.iter_prefix(list(vt)))
         for j, t in self.hll_tables.items():
             for vt in groups:
                 row = t.get_row(vt)
@@ -905,9 +951,8 @@ class HashAggExecutor(Executor):
         gk = self._group_key_host(mat)
         kept_lanes: List[tuple] = []
         kept_groups: List[tuple] = []
-        for r, lt in enumerate(lanes_ts):
-            vt = tuple(None if not ok[r] else self._pyval(vals[r])
-                       for vals, ok in gk)
+        for lt, vt in zip(lanes_ts, self._group_tuples(
+                gk, np.arange(len(lanes_ts)))):
             if self.table.get_row(vt) is None:
                 continue
             kept_lanes.append(lt)
@@ -918,12 +963,9 @@ class HashAggExecutor(Executor):
         for lt, vt in zip(kept_lanes, kept_groups):
             self._cold_groups[lt] = vt
         gset = set(kept_groups)
-        ng = len(self.group_indices)
-        for col, mult in self._distinct_mult.items():
-            if mult:
-                self._distinct_mult[col] = {
-                    k: v for k, v in mult.items()
-                    if k[:ng] not in gset}
+        for mult in (*self._minput_mult.values(),
+                     *self._distinct_mult.values()):
+            mult.drop_groups(gset)
         for j in self._hll_calls:
             self._hll_regs[j] = {k: v for k, v in
                                  self._hll_regs[j].items()
@@ -978,15 +1020,13 @@ class HashAggExecutor(Executor):
         self.kernel.retire_below(0, phys)
         n = self.table.delete_below_prefix(phys)
         self._live_groups = max(0, self._live_groups - n)
-        for t in self.minput.values():
-            t.delete_below_prefix(phys)
-        for col, t in self.distinct_tables.items():
-            t.delete_below_prefix(phys)
-            mult = self._distinct_mult.get(col)
-            if mult:
-                self._distinct_mult[col] = {
-                    k: v for k, v in mult.items()
-                    if k[0] is None or k[0] >= phys}
+        for tables, mults in ((self.minput, self._minput_mult),
+                              (self.distinct_tables, self._distinct_mult)):
+            for j, t in tables.items():
+                # the range delete reads the rows it deletes (cold
+                # groups' too, which no multiset in memory holds)
+                _MULTISET_READS.inc(t.delete_below_prefix(phys))
+                mults[j].cut_below(phys)
         for j, t in self.hll_tables.items():
             t.delete_below_prefix(phys)
             self._hll_regs[j] = {
@@ -1061,15 +1101,19 @@ class HashAggExecutor(Executor):
         gk = None
         host_prev = None
         if self._host_calls and fr.n:
-            # host-agg PREV outputs come from the multiset tables as
-            # of the LAST barrier — read before this epoch's writes
+            # host-agg PREV outputs come from the value multisets as
+            # of the LAST barrier — read before this epoch's deltas
+            # are applied to them (where they are written through)
             gk = self._group_key_host(fr.keys)
             host_prev = self._host_agg_outputs(fr, gk)
         if self.minput:
-            self._write_minput_pending()
+            self._write_multiset_pending(
+                self._minput_pending, self.minput, self._minput_mult,
+                at_ingest=False)
         if self._distinct_pending:
-            self._write_multiset_pending(self._distinct_pending,
-                                         self.distinct_tables)
+            self._write_multiset_pending(
+                self._distinct_pending, self.distinct_tables,
+                self._distinct_mult, at_ingest=True)
         if fr.n == 0:
             self._deleted_lanes.clear()
             self.kernel.advance()
@@ -1198,35 +1242,35 @@ class HashAggExecutor(Executor):
 
     @staged("agg.extremes")
     def _recompute_extremes(self, fr, gk) -> None:
-        """Correct stale device MIN/MAX for groups that saw deletes by
-        scanning their surviving value multiset, then patch the device
-        accumulators (hash_agg.rs + minput.rs flush semantics)."""
-        need = [r for r in range(fr.n)
-                if tuple(fr.keys[r].tolist()) in self._deleted_lanes]
-        if not need:
+        """Correct stale device MIN/MAX for groups that saw deletes
+        from their surviving values in the in-memory multiset (this
+        epoch's deltas written through and applied before), then patch
+        the device accumulators (hash_agg.rs + minput.rs flush
+        semantics)."""
+        deleted = self._deleted_lanes
+        need = np.asarray(
+            [r for r, lt in enumerate(map(tuple, fr.keys.tolist()))
+             if lt in deleted], dtype=np.int64)
+        if not len(need):
             return
-        for r in need:
-            group = tuple(
-                None if not ok[r]
-                else (vals[r].item() if hasattr(vals[r], "item")
-                      else vals[r])
-                for vals, ok in gk)
-            for j, table in self.minput.items():
-                if self.specs[j].kind in HOST_AGG_KINDS:
-                    continue       # host outputs recompute separately
-                is_max = self.specs[j].kind == AggKind.MAX
-                best = None
-                for _pk, row in table.iter_prefix(group):
-                    v = row[-2]
-                    if best is None or (v > best if is_max else v < best):
-                        best = v
-                nn = fr.nns[j][r]
-                if nn == 0 or best is None:
-                    fr.nulls[j][r] = True
-                    fr.nns[j][r] = 0
+        groups = self._group_tuples(gk, need)
+        scanned = 0
+        for j, mult in self._minput_mult.items():
+            if self.specs[j].kind in HOST_AGG_KINDS:
+                continue       # host outputs recompute separately
+            best_of = max if self.specs[j].kind == AggKind.MAX else min
+            outs, nulls, nns = fr.outs[j], fr.nulls[j], fr.nns[j]
+            for r, group in zip(need.tolist(), groups):
+                vals = mult.values(group)
+                scanned += len(vals)
+                if nns[r] == 0 or not vals:
+                    nulls[r] = True
+                    nns[r] = 0
                 else:
-                    fr.outs[j][r] = best
-                    fr.nulls[j][r] = False
+                    outs[r] = best_of(vals)
+                    nulls[r] = False
+            _EXTREME_SCANS.inc(len(need))
+        _VALUES_SCANNED.inc(scanned)
         decoded = [
             (fr.outs[j], fr.nns[j])
             if j in self.minput
@@ -1236,26 +1280,22 @@ class HashAggExecutor(Executor):
 
     def _host_agg_outputs(self, fr, gk):
         """string_agg/array_agg outputs for the flushed groups, read
-        from the value multisets. Values compose in VALUE order (the
-        multiset has no arrival order and pg leaves the order
-        unspecified without an in-agg ORDER BY; value order is the
-        deterministic, recovery-stable choice)."""
+        from the in-memory value multisets. Values compose in VALUE
+        order, the table's pk order (the multiset has no arrival order
+        and pg leaves the order unspecified without an in-agg ORDER BY;
+        value order is the deterministic, recovery-stable choice)."""
         out: Dict[int, tuple] = {}
+        groups = self._group_tuples(gk, np.arange(fr.n))
         for j in self._host_calls:
             call = self.agg_calls[j]
-            table = self.minput[j]
+            mult = self._minput_mult[j]
             vals_col = np.empty(fr.n, dtype=object)
             nulls_col = np.zeros(fr.n, dtype=bool)
-            for r in range(fr.n):
-                group = tuple(
-                    None if not ok[r] else
-                    (vals[r].item() if hasattr(vals[r], "item")
-                     else vals[r])
-                    for vals, ok in gk)
+            for r, group in enumerate(groups):
+                counts = mult.values(group)
                 items: List = []
-                for _pk, row in table.iter_prefix(group):
-                    v, cnt = row[-2], int(row[-1])
-                    items.extend([v] * cnt)
+                for v in sorted(counts, key=value_order):
+                    items.extend([v] * counts[v])
                 if not items:
                     nulls_col[r] = True
                 elif call.kind == AggKind.STRING_AGG:
@@ -1348,8 +1388,6 @@ class HashAggExecutor(Executor):
         if self._tier is not None:
             self._tier_register()
         self.table.init_epoch(first.epoch)
-        for t in self.minput.values():
-            t.init_epoch(first.epoch)
         from risingwave_tpu.ops.hash_agg import hll_estimate_dense
         for j, t in self.hll_tables.items():
             t.init_epoch(first.epoch)
@@ -1361,13 +1399,11 @@ class HashAggExecutor(Executor):
                 # prev estimates must match them exactly
                 self._hll_prev[j][gkey] = int(
                     hll_estimate_dense(arr)[0])
-        for col, t in self.distinct_tables.items():
-            t.init_epoch(first.epoch)
-            mult = {}
-            for _pk, row in t.iter_rows():
-                mult[tuple(row[:-1])] = int(row[-1])
-            if mult:
-                self._distinct_mult[col] = mult
+        for tables, mults in ((self.minput, self._minput_mult),
+                              (self.distinct_tables, self._distinct_mult)):
+            for j, t in tables.items():
+                t.init_epoch(first.epoch)
+                mults[j].load(row for _pk, row in t.iter_rows())
         self._recover()
         yield first
         try:

@@ -288,6 +288,14 @@ class StreamingMetrics:
             "state-table keys encoded, by path (columnar: the bulk "
             "encoder, counted by the batch; row: the scalar codec of "
             "the point operations, one key a call)")
+        self.agg_multiset = r.counter(
+            "stream_agg_multiset",
+            "the in-memory value multisets of the aggregates, by event "
+            "(rows_written: rows of the once-a-barrier batch writes to "
+            "a minput or distinct table; point_reads: rows read back "
+            "from one outside recovery and cold-tier reload; "
+            "extreme_scans / values_scanned: groups whose MIN/MAX was "
+            "recomputed after a retraction, and the values looked at)")
         self.agg_input_rows = r.counter(
             "stream_agg_input_rows",
             "visible rows a HashAggExecutor took in, by op (insert, "
@@ -768,6 +776,16 @@ class MetricsHistory:
              lambda: S.state_pk_keys.get(path="columnar"), "counter"),
             ("state_pk.row",
              lambda: S.state_pk_keys.get(path="row"), "counter"),
+            ("agg_multiset.point_reads",
+             lambda: S.agg_multiset.get(event="point_reads"), "counter"),
+            ("agg_multiset.rows_written",
+             lambda: S.agg_multiset.get(event="rows_written"), "counter"),
+            ("agg_multiset.extreme_scans",
+             lambda: S.agg_multiset.get(event="extreme_scans"),
+             "counter"),
+            ("agg_multiset.values_scanned",
+             lambda: S.agg_multiset.get(event="values_scanned"),
+             "counter"),
         )
 
     @staticmethod

@@ -754,3 +754,296 @@ def test_approx_count_distinct_varchar_group_key():
     for ch, exact in ((k, len(v)) for k, v in d.items()):
         assert abs(got[ch] - exact) <= max(2, 0.05 * exact), \
             (ch, got[ch], exact)
+
+
+# -- the value multisets in memory (PR 36) ----------------------------------
+#
+# A retractable MIN/MAX, a string_agg / array_agg and a DISTINCT column
+# keep their value multiset in the executor (value_multiset.py) and write
+# it through once a barrier. The loops they replaced read the table for
+# every changed count and for every retracted group; they stay here as
+# the plain reference.
+
+MS_SCHEMA = Schema.of(g=DataType.INT64, v=DataType.INT64,
+                      s=DataType.VARCHAR)
+
+
+def _ms_calls():
+    return [AggCall(AggKind.MAX, 1), AggCall(AggKind.MIN, 1),
+            AggCall(AggKind.STRING_AGG, 2, delimiter="|"),
+            AggCall(AggKind.COUNT), AggCall(AggKind.ARRAY_AGG, 1),
+            AggCall(AggKind.SUM, 1, distinct=True)]
+
+
+class TableLoopAgg(HashAggExecutor):
+    """The loops of the parent commit: the multiset is read back from
+    its table, a point read a changed count and a prefix scan a group."""
+
+    @staticmethod
+    def _write_multiset_pending(pending, tables, mults, at_ingest):
+        for j, deltas in pending.items():
+            table = tables[j]
+            for (group, value), d in deltas.items():
+                if d == 0:
+                    continue
+                key = group + (value,)
+                cur = table.get_row(key)
+                cnt = (0 if cur is None else cur[-1]) + d
+                row = key + (cnt,)
+                if cur is None:
+                    assert cnt > 0, f"retract of unseen value {key}"
+                    table.insert(row)
+                elif cnt == 0:
+                    table.delete(cur)
+                else:
+                    table.update(cur, row)
+        pending.clear()
+
+    @staticmethod
+    def _group(gk, r):
+        return tuple(
+            None if not ok[r]
+            else (vals[r].item() if hasattr(vals[r], "item") else vals[r])
+            for vals, ok in gk)
+
+    def _recompute_extremes(self, fr, gk):
+        from risingwave_tpu.ops.hash_agg import HOST_AGG_KINDS
+        need = [r for r in range(fr.n)
+                if tuple(fr.keys[r].tolist()) in self._deleted_lanes]
+        if not need:
+            return
+        for r in need:
+            group = self._group(gk, r)
+            for j, table in self.minput.items():
+                if self.specs[j].kind in HOST_AGG_KINDS:
+                    continue
+                is_max = self.specs[j].kind == AggKind.MAX
+                best = None
+                for _pk, row in table.iter_prefix(group):
+                    v = row[-2]
+                    if best is None or (v > best if is_max else v < best):
+                        best = v
+                nn = fr.nns[j][r]
+                if nn == 0 or best is None:
+                    fr.nulls[j][r] = True
+                    fr.nns[j][r] = 0
+                else:
+                    fr.outs[j][r] = best
+                    fr.nulls[j][r] = False
+        decoded = [
+            (fr.outs[j], fr.nns[j])
+            if j in self.minput
+            and self.specs[j].kind not in HOST_AGG_KINDS else None
+            for j in range(len(self.specs))]
+        self.kernel.patch_accs(decoded, raw_accs=fr.raw_accs)
+
+    def _host_agg_outputs(self, fr, gk):
+        out = {}
+        for j in self._host_calls:
+            call = self.agg_calls[j]
+            table = self.minput[j]
+            vals_col = np.empty(fr.n, dtype=object)
+            nulls_col = np.zeros(fr.n, dtype=bool)
+            for r in range(fr.n):
+                items = []
+                for _pk, row in table.iter_prefix(self._group(gk, r)):
+                    items.extend([row[-2]] * int(row[-1]))
+                if not items:
+                    nulls_col[r] = True
+                elif call.kind == AggKind.STRING_AGG:
+                    vals_col[r] = call.delimiter.join(
+                        str(v) for v in items if v is not None)
+                else:
+                    vals_col[r] = tuple(items)
+            out[j] = (vals_col, nulls_col)
+        return out
+
+
+def _ms_build(cls, messages, store, calls=None, schema=MS_SCHEMA):
+    from risingwave_tpu.stream.executors.hash_agg import agg_aux_tables
+    calls = calls or _ms_calls()
+    sschema, spk = agg_state_schema(schema, [0], calls)
+    table = StateTable(10, sschema, spk, store, dist_key_indices=[0])
+    distinct, minput = agg_aux_tables(
+        schema, [0], calls, False, store,
+        dedup_table_id=lambda col: 200 + col,
+        minput_table_id=lambda j: 100 + j)
+    return cls(MockSource(schema, messages), [0], calls, table,
+               minput_tables=minput, distinct_tables=distinct)
+
+
+def _ms_script(seed, n_epochs=7, rows=48):
+    """Inserts and retractions over a small value domain (a count goes
+    to zero and comes back), NULL values and a NULL group."""
+    rng = np.random.default_rng(seed)
+    live = []
+    script = [barrier(1)]
+    for e in range(2, 2 + n_epochs):
+        gs, vs, ss, ops = [], [], [], []
+        for _ in range(rows):
+            if live and rng.random() < 0.45:
+                g, v, s = live.pop(int(rng.integers(0, len(live))))
+                ops.append(2)
+            else:
+                g = [None, 1, 2, 3][int(rng.integers(0, 4))]
+                v = [None, 0, 1, 2, 3, -7][int(rng.integers(0, 6))]
+                s = [None, "a", "b", "é", ""][int(rng.integers(0, 5))]
+                live.append((g, v, s))
+                ops.append(1)
+            gs.append(g), vs.append(v), ss.append(s)
+        script.append(StreamChunk.from_pydict(
+            MS_SCHEMA, {"g": gs, "v": vs, "s": ss}, ops=ops))
+        script.append(barrier(e))
+    return script
+
+
+def _table_rows(table):
+    return sorted((row for _pk, row in table.iter_rows()), key=repr)
+
+
+def _mirrors(ex):
+    return [(ex._minput_mult[j], t) for j, t in ex.minput.items()] + [
+        (ex._distinct_mult[c], t) for c, t in ex.distinct_tables.items()]
+
+
+def _drive_checking_mirrors(ex):
+    """Run to the script's end; at every barrier each multiset in memory
+    must be a scan of its table."""
+    async def run():
+        out = []
+        async for msg in ex.execute():
+            out.append(msg)
+            if isinstance(msg, Barrier):
+                for mult, t in _mirrors(ex):
+                    rows = _table_rows(t)
+                    assert sorted(mult.rows(), key=repr) == rows
+                    assert len(mult) == len(rows)
+        return out
+    return asyncio.run(run())
+
+
+def _records(msgs):
+    return [m.to_records() if is_chunk(m) else "barrier" for m in msgs
+            if is_chunk(m) or isinstance(m, Barrier)]
+
+
+@pytest.mark.parametrize("seed", [3, 36, 360])
+def test_multiset_in_memory_is_the_table_and_emits_the_table_loops_chunks(
+        seed):
+    script = _ms_script(seed)
+    ex = _ms_build(HashAggExecutor, script, MemoryStateStore())
+    assert len(_mirrors(ex)) == 5         # MAX, MIN, two host aggs, v
+    got = _drive_checking_mirrors(ex)
+    ref = _ms_build(TableLoopAgg, script, MemoryStateStore())
+    want = asyncio.run(collect_until_n_barriers(ref, 8))
+    assert _records(got) == _records(want)
+    assert any(r != "barrier" for r in _records(got))
+    for t, t_ref in zip(
+            [ex.table, *ex.minput.values(), *ex.distinct_tables.values()],
+            [ref.table, *ref.minput.values(),
+             *ref.distinct_tables.values()]):
+        assert _table_rows(t) == _table_rows(t_ref)
+
+
+def test_multiset_restart_rebuilds_the_mirror_and_emits_the_same():
+    """A restart on the same store fills the multisets from the tables;
+    the next barrier's chunk is the one an executor that never stopped
+    emits."""
+    script = _ms_script(11, n_epochs=5)
+    head, tail = script[:-2], script[-3:]       # tail opens on a barrier
+    store = MemoryStateStore()
+    first = _ms_build(HashAggExecutor, head, store)
+    _drive_checking_mirrors(first)
+    for e in range(1, 5):
+        store.seal_epoch(Epoch.from_physical(e).value, True)
+    store.sync(Epoch.from_physical(4).value)
+    second = _ms_build(HashAggExecutor, tail, store)
+    got = _drive_checking_mirrors(second)
+    whole = _ms_build(HashAggExecutor, script, MemoryStateStore())
+    want = _drive_checking_mirrors(whole)
+    assert _records(got)[1:] == _records(want)[-2:]
+    assert _records(got)[1] != "barrier"
+    for (m1, _t1), (m2, _t2) in zip(_mirrors(second), _mirrors(whole)):
+        assert sorted(m1.rows(), key=repr) == sorted(m2.rows(), key=repr)
+
+
+def test_multiset_restart_reads_the_mirror_from_the_table_at_init():
+    script = _ms_script(12, n_epochs=3)
+    store = MemoryStateStore()
+    first = _ms_build(HashAggExecutor, script, store)
+    _drive_checking_mirrors(first)
+    for e in range(1, 4):
+        store.seal_epoch(Epoch.from_physical(e).value, True)
+    store.sync(Epoch.from_physical(3).value)
+    second = _ms_build(HashAggExecutor, [barrier(4)], store)
+    asyncio.run(collect_until_n_barriers(second, 1))
+    for (m1, _t1), (m2, _t2) in zip(_mirrors(first), _mirrors(second)):
+        assert len(m1) and sorted(m1.rows(), key=repr) \
+            == sorted(m2.rows(), key=repr)
+
+
+def test_retract_of_an_unseen_value_still_raises():
+    script = [barrier(1), chunk([1, 1], [5, 9]), barrier(2),
+              chunk([1], [7], ops=[2]), barrier(3)]
+    ex, _table, _store = build(script, [AggCall(AggKind.MAX, 1)])
+    with pytest.raises(AssertionError, match="retract of unseen value"):
+        asyncio.run(collect_until_n_barriers(ex, 3))
+
+
+def test_a_nan_is_one_value_of_the_multiset():
+    """No two NaN objects are equal; the table's key for them is one."""
+    schema = Schema.of(g=DataType.INT64, v=DataType.FLOAT64)
+    nan = float("nan")
+
+    def fchunk(gs, vs, ops=None):
+        return StreamChunk.from_pydict(schema, {"g": gs, "v": vs}, ops=ops)
+
+    script = [barrier(1), fchunk([1, 1, 1], [nan, 2.5, nan]), barrier(2),
+              fchunk([1, 1], [nan, 2.5], ops=[2, 2]), barrier(3),
+              fchunk([1], [nan], ops=[2]), barrier(4)]
+    calls = [AggCall(AggKind.COUNT), AggCall(AggKind.MAX, 1)]
+    ex = _ms_build(HashAggExecutor, script, MemoryStateStore(),
+                   calls=calls, schema=schema)
+    msgs = _drive_checking_mirrors(ex)
+    assert len(ex._minput_mult[1]) == 0
+    last = [m for m in msgs if is_chunk(m)][-1].to_records()
+    assert [op for op, _row in last] == [Op.DELETE]
+
+
+def test_multiset_counters_in_a_steady_run():
+    """Past the init barrier no multiset table is read: `point_reads`
+    stays 0, and `rows_written` is the rows the three batch calls took."""
+    import collections
+
+    from risingwave_tpu.utils.metrics import STREAMING
+
+    def counts():
+        return {e: STREAMING.agg_multiset.get(event=e)
+                for e in ("point_reads", "rows_written", "extreme_scans",
+                          "values_scanned")}
+
+    ex = _ms_build(HashAggExecutor, _ms_script(5), MemoryStateStore())
+    took = collections.Counter()
+
+    def refuse(*_a, **_k):
+        raise AssertionError("a multiset table was read")
+
+    for _mult, t in _mirrors(ex):
+        for name in ("insert_rows", "update_rows", "delete_rows"):
+            def counted(rows, *a, _real=getattr(t, name), _name=name):
+                took[_name] += len(rows)
+                return _real(rows, *a)
+            setattr(t, name, counted)
+        for name in ("insert", "update", "delete"):
+            setattr(t, name, refuse)     # no single-row write either
+        t.get_row = t.iter_prefix = refuse
+    before = counts()
+    asyncio.run(collect_until_n_barriers(ex, 8))
+    moved = {e: v - before[e] for e, v in counts().items()}
+    assert moved["point_reads"] == 0
+    assert moved["rows_written"] == sum(took.values()) > 0
+    assert all(took[n] for n in ("insert_rows", "update_rows",
+                                 "delete_rows")), took
+    # MAX and MIN rescan the same retracted groups, a few values each
+    assert moved["extreme_scans"] > 0 and moved["extreme_scans"] % 2 == 0
+    assert moved["values_scanned"] >= moved["extreme_scans"]

@@ -409,6 +409,23 @@ def test_launch_and_wait_are_the_cells_device_compute(views, config):
     assert any("Join" in x or x == "hash_join" for x in labels), labels
 
 
+@pytest.mark.parametrize("config", ["nexmark-q8", "nexmark-q5"])
+def test_the_value_multisets_books_are_in_every_row(views, config):
+    """`agg_multiset.*` beside `state_pk.*`: q5's retractable MAX writes
+    its multiset through on every barrier and rescans retracted windows
+    in memory, reading no row back; q8 has no such aggregate."""
+    for h in views[config]["history"].values():
+        books = _family(h, "agg_multiset.")
+        assert set(books) == {"point_reads", "rows_written",
+                              "extreme_scans", "values_scanned"}
+        assert books["point_reads"] == 0
+        if config == "nexmark-q5":
+            assert books["rows_written"] > 0
+            assert 0 < books["extreme_scans"] <= books["values_scanned"]
+        else:
+            assert not any(books.values())
+
+
 def test_the_scopes_a_barrier_do_not_follow_the_rows():
     """The guard against a scope in a row loop: with twice the rows in
     every chunk (and the same chunks a barrier) each stage opens as

@@ -184,6 +184,112 @@ def test_agg_crash_recovery_with_evicted_keys():
         assert got[g] == (g, g + 1, 2)
 
 
+def _build_max_agg(store, msgs, tier_cap):
+    """A retractable MAX and a DISTINCT sum: a value multiset each, in
+    memory and in an aux table."""
+    from risingwave_tpu.stream.executors.hash_agg import agg_aux_tables
+    calls = [AggCall(AggKind.MAX, 1), AggCall(AggKind.COUNT),
+             AggCall(AggKind.SUM, 1, distinct=True)]
+    sch, pk = agg_state_schema(AGG_S, [0], calls)
+    t = StateTable(1, sch, pk, store, dist_key_indices=[0])
+    distinct, minput = agg_aux_tables(
+        AGG_S, [0], calls, False, store,
+        dedup_table_id=lambda col: 30 + col,
+        minput_table_id=lambda j: 20 + j)
+    return HashAggExecutor(MockSource(AGG_S, msgs), [0], calls, t,
+                           minput_tables=minput, distinct_tables=distinct,
+                           tier_cap=tier_cap, kernel_capacity=1 << 10)
+
+
+def _prefix_rows(table, group):
+    return sorted(row for _pk, row in table.iter_prefix([group]))
+
+
+def test_agg_multiset_leaves_with_its_group_and_comes_back():
+    """The value multisets in memory follow the cold tier: an evicted
+    group's values are dropped (its rows stay in the aux tables), and a
+    delete that touches the group reloads them before it retracts, so
+    the MAX that survives is the uncapped run's."""
+    n = 60
+    msgs = [_barrier(1),
+            _chunk(AGG_S, [(g, v) for g in range(n) for v in (g, g + 100)]),
+            _barrier(2),
+            # touch only the upper half: the lower half goes cold
+            _chunk(AGG_S, [(g, 7) for g in range(n // 2, n)]),
+            _barrier(3),
+            # retract the maximum of cold groups 0..9
+            _chunk(AGG_S, [(g, g + 100) for g in range(10)],
+                   ops=[Op.DELETE] * 10),
+            _barrier(4)]
+    capped = _build_max_agg(MemoryStateStore(), msgs, 16)
+    seen = {}
+
+    async def run():
+        out = []
+        async for m in capped.execute():
+            out.append(m)
+            if isinstance(m, Barrier) and m.epoch.curr == \
+                    Epoch.from_physical(3):
+                seen["cold"] = dict(capped._cold_groups)
+                seen["max"] = {g: dict(capped._minput_mult[0].values((g,)))
+                               for g in range(n)}
+                seen["sum"] = {g: dict(capped._distinct_mult[1].values((g,)))
+                               for g in range(n)}
+        return out
+
+    outs_c = asyncio.run(run())
+    cold = {vt[0] for vt in seen["cold"].values()}
+    assert len(cold) >= n - 16 and set(range(10)) <= cold
+    for g in range(n):
+        held = {} if g in cold else {g: 1, g + 100: 1}
+        if g not in cold and g >= n // 2:
+            held[7] = 1
+        assert seen["max"][g] == held and seen["sum"][g] == held
+    # the aux tables kept every group's rows
+    assert 20 in cold and _prefix_rows(capped.minput[0], 20) \
+        == [(20, 20, 1), (20, 120, 1)]
+    # after the reload and the retraction: memory is the table again
+    for g in range(10):
+        assert dict(capped._minput_mult[0].values((g,))) == {g: 1}
+        assert _prefix_rows(capped.minput[0], g) == [(g, g, 1)]
+        assert _prefix_rows(capped.distinct_tables[1], g) == [(g, g, 1)]
+    assert capped._tier_part.reload_total >= 10
+    uncapped = _build_max_agg(MemoryStateStore(), msgs, None)
+    outs_u = asyncio.run(collect_until_n_barriers(uncapped, 4))
+    assert _final_rows(outs_c) == _final_rows(outs_u)
+    assert _final_rows(outs_c)[3] == (3, 3, 1, 3)
+
+
+def test_agg_multiset_is_cut_with_the_tables_rows_below_a_watermark():
+    """Watermark cleaning on the first group column: the groups leave
+    the multisets in memory with their rows in the aux tables, and a
+    late row for a retired group starts it from nothing."""
+    from risingwave_tpu.stream.message import Watermark
+    from risingwave_tpu.utils.metrics import STREAMING
+    msgs = [_barrier(1),
+            _chunk(AGG_S, [(g, v) for g in range(8) for v in (g, g + 10)]),
+            _barrier(2),
+            Watermark(0, DataType.INT64, 5),
+            _chunk(AGG_S, [(6, 16)], ops=[Op.DELETE]),
+            _barrier(3),
+            _chunk(AGG_S, [(2, 1)]),
+            _barrier(4)]
+    ex = _build_max_agg(MemoryStateStore(), msgs, None)
+    reads0 = STREAMING.agg_multiset.get(event="point_reads")
+    outs = asyncio.run(collect_until_n_barriers(ex, 4))
+    for mult, table in ((ex._minput_mult[0], ex.minput[0]),
+                        (ex._distinct_mult[1], ex.distinct_tables[1])):
+        rows = sorted(row for _pk, row in table.iter_rows())
+        assert sorted(mult.rows()) == rows
+        assert {r[0] for r in rows} == {2, 5, 6, 7}
+        assert [r for r in rows if r[0] == 2] == [(2, 1, 1)]
+    # the range deletes read the rows they deleted: five groups of two
+    # values, in two tables
+    assert STREAMING.agg_multiset.get(event="point_reads") - reads0 == 20
+    final = _final_rows(outs)
+    assert final[2] == (2, 1, 1, 1) and final[6] == (6, 6, 1, 6)
+
+
 def test_agg_sql_front_door_with_rw_state_tier():
     """SET state_tier_cap on the session: a GROUP BY with cardinality
     ≫ cap stays bit-identical to the uncapped run, and rw_state_tier
