@@ -266,7 +266,8 @@ class DistFrontend:
         self.last_select_schema = None
         if isinstance(stmt, ast.CreateSource):
             schema = source_schema(stmt.options, stmt.columns)
-            self.catalog.add_source(stmt.name, schema, stmt.options)
+            self.catalog.add_source(stmt.name, schema, stmt.options,
+                                    watermark=stmt.watermark)
             return "CREATE_SOURCE"
         if isinstance(stmt, ast.CreateMaterializedView):
             return await self._create_mv(stmt)

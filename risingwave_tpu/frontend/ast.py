@@ -156,6 +156,8 @@ class CreateSource:
     options: Dict[str, str]            # WITH (connector='nexmark', ...)
     # explicit (col_name, sql_type) list for external connectors
     columns: Optional[List[Tuple[str, str]]] = None
+    # WATERMARK FOR <col> AS <col> - INTERVAL ...: (column, delay µs)
+    watermark: Optional[Tuple[str, int]] = None
 
 
 @dataclass

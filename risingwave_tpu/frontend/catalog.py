@@ -8,9 +8,9 @@ in-process structure for the single-node deployment shape.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
-from risingwave_tpu.common.types import Schema
+from risingwave_tpu.common.types import DataType, Schema
 
 
 @dataclass
@@ -19,6 +19,53 @@ class SourceCatalog:
     source_id: int
     schema: Schema
     options: Dict[str, str]
+    # WATERMARK FOR <col> AS <col> - INTERVAL ...: (column, delay µs).
+    # The one place the planner reads a source's watermark from
+    watermark: Optional[Tuple[str, int]] = None
+
+
+def source_watermark(schema: Schema, options: Dict[str, str],
+                     clause: Optional[Tuple[str, int]]
+                     ) -> Optional[Tuple[str, int]]:
+    """A source's watermark as the catalog keeps it: the DDL's
+    ``WATERMARK FOR`` clause, or its older spelling in the WITH list
+    (``watermark.column`` / ``watermark.delay``, an alias). The column
+    must be a timestamp of the source's schema."""
+    from risingwave_tpu.frontend.binder import BindError
+    opt_col = options.get("watermark.column")
+    if clause is not None and opt_col is not None:
+        raise BindError(
+            "a source takes WATERMARK FOR or the watermark.column "
+            "option, not both")
+    if clause is None:
+        if opt_col is None:
+            return None
+        clause = (opt_col.lower(), _interval_option_usecs(
+            options.get("watermark.delay", "0 seconds")))
+    col, delay = clause
+    types = {f.name: f.data_type for f in schema}
+    if col not in types:
+        raise BindError(
+            f"WATERMARK FOR {col}: the source has no such column")
+    if types[col] not in (DataType.TIMESTAMP, DataType.TIMESTAMPTZ):
+        raise BindError(
+            f"WATERMARK FOR {col}: the column must be a timestamp, "
+            f"not {types[col].name}")
+    return col, int(delay)
+
+
+def _interval_option_usecs(s: str) -> int:
+    """'4 seconds' / '500 milliseconds' / a raw µs number. Shares the
+    SQL parser's unit table (one source of truth)."""
+    from risingwave_tpu.frontend.binder import BindError
+    from risingwave_tpu.frontend.parser import _INTERVAL_UNITS
+    parts = str(s).strip().split()
+    if len(parts) == 2 and parts[0].isdigit() \
+            and parts[1].lower() in _INTERVAL_UNITS:
+        return int(parts[0]) * _INTERVAL_UNITS[parts[1].lower()]
+    if len(parts) == 1 and parts[0].isdigit():
+        return int(parts[0])
+    raise BindError(f"bad interval option {s!r}")
 
 
 @dataclass
@@ -84,9 +131,12 @@ class Catalog:
             raise ValueError(f"catalog object {name!r} already exists")
 
     def add_source(self, name: str, schema: Schema,
-                   options: Dict[str, str]) -> SourceCatalog:
+                   options: Dict[str, str],
+                   watermark: Optional[Tuple[str, int]] = None
+                   ) -> SourceCatalog:
         self._check_free(name)
-        sc = SourceCatalog(name, self.next_id(), schema, options)
+        wm = source_watermark(schema, options, watermark)
+        sc = SourceCatalog(name, self.next_id(), schema, options, wm)
         self.sources[name] = sc
         return sc
 

@@ -321,6 +321,11 @@ class Fragmenter:
                 # coordinator planned
                 "tier_cap": ex.tier_cap,
             }
+            if ex.key_lead:
+                # the group position that leads the state tables' keys
+                # where it is not the first (the planner's: the one
+                # that carries a watermark)
+                node["key_lead"] = ex.key_lead
             if ex.fused_stages is not None:
                 # the agg's index space is the run's OUTPUT schema —
                 # worker rebuild re-composes the prelude from this

@@ -295,7 +295,7 @@ def _verify_node(ex) -> None:
                     f"HashAgg: call input {c.input_idx} out of range")
         sch, pk = agg_state_schema(in_schema,
                                    list(ex.group_indices),
-                                   list(ex.agg_calls))
+                                   list(ex.agg_calls), ex.key_lead)
         if not _same_types(sch, ex.table.schema) or \
                 pk != list(ex.table.pk_indices):
             raise CheckError("HashAgg: state-table schema/pk no longer "

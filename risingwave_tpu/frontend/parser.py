@@ -330,11 +330,20 @@ class Parser:
     def _create_source(self) -> ast.CreateSource:
         name = self._ident()
         columns = None
+        watermark = None
         if self._op("("):
             # explicit schema: (col type, ...) — external connectors
             # cannot infer one (the generators carry fixed schemas)
             columns = []
             while True:
+                if self._watermark_clause_ahead():
+                    if watermark is not None:
+                        raise ParseError(
+                            "a source takes one WATERMARK FOR clause")
+                    watermark = self._watermark_clause()
+                    if not self._op(","):
+                        break
+                    continue
                 col = self._ident()
                 words = [self._next()[1].lower()]
                 while self._peek()[0] in ("ident", "kw") and \
@@ -364,7 +373,36 @@ class Parser:
             if not self._op(","):
                 break
         self._expect_op(")")
-        return ast.CreateSource(name, options, columns=columns)
+        return ast.CreateSource(name, options, columns=columns or None,
+                                watermark=watermark)
+
+    def _watermark_clause_ahead(self) -> bool:
+        """`WATERMARK FOR` next? (`watermark` alone is a column name.)"""
+        kind, text = self._peek()
+        return (kind == "ident" and text.lower() == "watermark"
+                and self._peek(1) == ("kw", "for"))
+
+    def _watermark_clause(self) -> Tuple[str, int]:
+        """``WATERMARK FOR <col> AS <col> [- INTERVAL '<n>' <unit>]``
+        inside a source's column list (upstream's spelling; NEXmark's
+        ddl_gen.sql has the same clause): the event-time column and
+        the delay in microseconds. The generating expression is the
+        column itself less a constant interval, nothing else."""
+        self._next()
+        self._expect_kw("for")
+        col = self._ident()
+        self._expect_kw("as")
+        expr = self._expr()
+        delay = 0
+        if isinstance(expr, ast.Bin) and expr.op == "-" \
+                and isinstance(expr.right, ast.IntervalLit):
+            delay, expr = expr.right.usecs, expr.left
+        if not (isinstance(expr, ast.ColRef) and expr.table is None
+                and expr.name.lower() == col.lower()):
+            raise ParseError(
+                f"WATERMARK FOR {col} AS takes {col} or "
+                f"{col} - INTERVAL '<n>' <unit>")
+        return col, delay
 
     # -- SELECT ----------------------------------------------------------
     def _select(self) -> ast.Select:

@@ -717,15 +717,7 @@ class FusedStages:
                 continue
             nxt: List = []
             for m in outs:
-                d = st.watermark_derivations.get(m.col_idx)
-                for one in (d if isinstance(d, list)
-                            else [] if d is None else [d]):
-                    if isinstance(one, tuple):
-                        oi, fn = one
-                        nxt.append(Watermark(oi, m.data_type,
-                                             fn(m.value)))
-                    else:
-                        nxt.append(m.with_idx(one))
+                nxt.extend(m.derived(st.watermark_derivations))
             outs = nxt
         return outs
 

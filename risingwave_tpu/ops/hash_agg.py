@@ -1163,9 +1163,10 @@ class GroupedAggKernel:
         if self._backlog_rows:
             raise RuntimeError("retire_below with undispatched backlog")
         hi, lo = lanes.split_i64(np.asarray([wm_i64], dtype=np.int64))
-        self.state, _n_live = self._retire(
-            self.state, jnp.int32(hi[0]), jnp.int32(lo[0]),
-            group_pos * 3)
+        with spans.dispatch_span(f"{self._span_label}.retire", 0):
+            self.state, _n_live = self._retire(
+                self.state, jnp.int32(hi[0]), jnp.int32(lo[0]),
+                group_pos * 3)
 
     # -- cold tier (state/tier.py) ---------------------------------------
     def evict_keys(self, key_lanes: np.ndarray) -> None:

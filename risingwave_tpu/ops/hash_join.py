@@ -388,19 +388,45 @@ _masked_scatter_jit = jaxtools.instrumented_jit(
     _masked_scatter, "hash_join.masked_scatter", donate_argnums=(0,))
 
 
-def _pad_scatter_args(refs: np.ndarray, vals: np.ndarray):
-    """Host staging for _masked_scatter: pad refs/vals to the next
-    pow-2 row count (stable jit shapes) with a validity mask."""
-    from risingwave_tpu.common.chunk import next_pow2
-    n = len(refs)
-    cap = next_pow2(max(n, 1))
-    r = np.zeros(cap, dtype=np.int32)
-    r[:n] = refs
-    m = np.zeros(cap, dtype=bool)
-    m[:n] = True
-    v = np.zeros((cap,) + np.shape(vals)[1:], dtype=np.int32)
-    v[:n] = vals
-    return jnp.asarray(r), jnp.asarray(m), jnp.asarray(v)
+class BatchRung:
+    """The row count of a bulk program that must not follow the data:
+    a state rebuilt, compacted or expired behind a watermark works a
+    batch as large as the rows that happen to live or die, and a
+    program per size the data ever takes is a compile at any barrier,
+    for as long as the view runs (a flat state, cleaned every barrier,
+    is the case that shows it). So such a batch runs at the powers of
+    four from FLOOR up to TOP and keeps to the largest rung it has
+    needed: a smaller batch is padded up to it with masked rows, a
+    larger one takes it a rung up, one above TOP is cut into pages of
+    TOP. The fused chain's ladder (stream/executors/fused.py) and the
+    probe's pages (PROBE_CAP_TOP), for the cleaning paths."""
+
+    FLOOR = 1 << 6
+    TOP = 1 << 16
+
+    def __init__(self) -> None:
+        self.rows = self.FLOOR
+
+    def pages(self, n: int):
+        """(lo, hi) runs that cover ``n`` rows, each at most the rung
+        and to be padded up to it (``self.rows``, raised here where
+        ``n`` asks for it)."""
+        while self.rows < min(n, self.TOP):
+            self.rows <<= 2
+        return [(lo, min(lo + self.rows, n))
+                for lo in range(0, n, self.rows)]
+
+    def padded(self, a: np.ndarray, lo: int, hi: int,
+               fill=0) -> np.ndarray:
+        """Rows ``lo:hi`` of ``a``, padded with ``fill`` to the rung."""
+        out = np.full((self.rows,) + a.shape[1:], fill, dtype=a.dtype)
+        out[:hi - lo] = a[lo:hi]
+        return out
+
+    def mask(self, lo: int, hi: int) -> np.ndarray:
+        m = np.zeros(self.rows, dtype=bool)
+        m[:hi - lo] = True
+        return m
 
 
 def make_prelude_epoch_jits(prelude, label: str):
@@ -664,6 +690,9 @@ class JoinSideKernel:
         # bookkeeping): maintained inside the epoch probe dispatches;
         # unallocated refs are 0 by the bump-allocation invariant
         self.deg = jnp.zeros(row_capacity, dtype=jnp.int32)
+        # the batch size of the bulk writes (rebuild's insert, payload
+        # and degree scatters): one program per rung, not per row count
+        self._bulk = BatchRung()
         # fused-input epoch jits, keyed by prelude label: this kernel
         # may serve two preludes (its OWN side's on apply, the PROBING
         # side's on probe)
@@ -909,10 +938,18 @@ class JoinSideKernel:
         """Scatter exact degree values (recovery / cold-tier reload:
         the degree of a stored row is a pure function of both sides'
         state, recomputed by one batch probe of the other side)."""
-        if len(refs) == 0:
-            return
-        self.deg = _masked_scatter_jit(
-            self.deg, *_pad_scatter_args(refs, vals))
+        self.deg = self._scatter_paged(self.deg, refs, vals)
+
+    def _scatter_paged(self, arr, refs: np.ndarray, vals: np.ndarray):
+        """``arr[refs] = vals`` in pages of the bulk rung."""
+        refs = np.asarray(refs, dtype=np.int32)
+        vals = np.asarray(vals, dtype=np.int32)
+        for lo, hi in self._bulk.pages(len(refs)):
+            arr = _masked_scatter_jit(
+                arr, jnp.asarray(self._bulk.padded(refs, lo, hi)),
+                jnp.asarray(self._bulk.mask(lo, hi)),
+                jnp.asarray(self._bulk.padded(vals, lo, hi)))
+        return arr
 
     def read_degrees(self, refs: np.ndarray) -> np.ndarray:
         """Degree values by ref (host fetch; compaction-only path)."""
@@ -933,10 +970,13 @@ class JoinSideKernel:
     # -- recovery ---------------------------------------------------------
     def rebuild(self, key_lanes: np.ndarray, row_refs: np.ndarray,
                 payload: Optional[np.ndarray] = None) -> None:
-        """Reload all live rows (recovery): one batched insert.
-        ``payload`` (int32[n, payload_width]) rebuilds the device
-        payload lanes exactly where the chains rebuild; degrees reset
-        to zero and are recomputed by the caller's batch probe."""
+        """Reload all live rows (recovery, compaction): a batched
+        insert in pages of the bulk rung, the last page first, so that
+        a key's rows stand in its chain in the order they were given,
+        as one batch would link them. ``payload`` (int32[n,
+        payload_width]) rebuilds the device payload lanes exactly
+        where the chains rebuild; degrees reset to zero and are
+        recomputed by the caller's batch probe."""
         n = len(row_refs)
         key_cap = max(self.table.capacity,
                       ht.MIN_CAPACITY if n == 0 else
@@ -953,10 +993,11 @@ class JoinSideKernel:
         self.pay = jnp.zeros((row_cap, self.payload_width),
                              dtype=jnp.int32)
         self.deg = jnp.zeros(row_cap, dtype=jnp.int32)
-        if n == 0:
-            return
-        self.insert(jnp.asarray(key_lanes), row_refs,
-                    jnp.ones(n, dtype=bool), seq=0)
+        key_lanes = np.asarray(key_lanes, dtype=np.int32)
+        row_refs = np.asarray(row_refs, dtype=np.int32)
+        for lo, hi in reversed(self._bulk.pages(n)):
+            self.insert(jnp.asarray(self._bulk.padded(key_lanes, lo, hi)),
+                        self._bulk.padded(row_refs, lo, hi),
+                        jnp.asarray(self._bulk.mask(lo, hi)), seq=0)
         if payload is not None and self.payload_width:
-            self.pay = _masked_scatter_jit(
-                self.pay, *_pad_scatter_args(row_refs, payload))
+            self.pay = self._scatter_paged(self.pay, row_refs, payload)

@@ -126,3 +126,8 @@ class MemTable:
 
     def iter_ops(self):
         return iter(sorted(self._ops.items()))
+
+    def items(self):
+        """(key, (op, old, new)) of every buffered op, in no order: for
+        a reader that looks at each once and sorts nothing."""
+        return self._ops.items()

@@ -168,6 +168,12 @@ class StateTable:
         # reads (rw_state_topology, rescale costing) never scan state
         _topology.TOPOLOGY.record(self.table_id, keys, vals,
                                   self._fixed_row_nbytes)
+        resident = _topology.TOPOLOGY.cleaned_rows_of(self.table_id)
+        if resident is not None:
+            # a table a watermark cleans says at every commit what it
+            # keeps: the books' total, no scan
+            _METRICS.state_resident_rows.set(
+                resident, table=f"t{self.table_id}")
         self.epoch = new_epoch
         return n
 
@@ -230,22 +236,52 @@ class StateTable:
             self.mem_table.delete(ok, old_row)
             self.mem_table.insert(nk, new_row)
 
+    @staged("state.clean")
     def delete_below_prefix(self, watermark) -> int:
         """Watermark state cleaning (state_table.rs:894 update_watermark):
         delete every row whose FIRST pk column is strictly below the
         watermark. Cost is O(deleted) + an ordered seek per owned vnode
         (rows below a watermark on the pk prefix form a contiguous range
-        in memcomparable order). Returns rows deleted."""
-        first_pk_type = self.pk_types[0]
-        end_suffix = encode_memcomparable([watermark], [first_pk_type])
-        deleted = 0
+        in memcomparable order) + one pass over the memtable for all the
+        vnodes: every row is read back from the store and deleted under
+        the key it was read at, and the books say so
+        (``stream_state_clean_reads`` beside ``_cleaned_rows``). One
+        ``state.clean`` stage of host_emit. Returns rows deleted."""
+        end_suffix = encode_memcomparable([watermark], [self.pk_types[0]])
+        self._spill_staged()
+        epoch = self._read_epoch()
+        doomed: dict = {}
         for vnode in self.owned_vnodes():
             start = encode_vnode_prefix(vnode)
-            end = start + end_suffix
-            for _pk, row in self._iter_range(start, end):
-                self.delete(row)
-                deleted += 1
+            doomed.update(self.store.iter(self.table_id, epoch, start,
+                                          start + end_suffix))
+        # a key is its vnode's two bytes, then the pk: below the
+        # watermark where what follows the vnode sorts under it
+        owned = self.vnodes
+        for key, (op, _old, new) in self.mem_table.items():
+            if key[2:] < end_suffix and owned[(key[0] << 8) | key[1]]:
+                if op == KeyOp.DELETE:
+                    doomed.pop(key, None)
+                else:
+                    doomed[key] = new
+        for key, row in doomed.items():
+            self.mem_table.delete(key, row)
+        deleted = len(doomed)
+        label = f"t{self.table_id}"
+        _METRICS.state_cleaned_rows.inc(float(deleted), table=label)
+        _METRICS.state_clean_reads.inc(float(deleted), table=label)
+        self.note_cleaned(watermark)
         return deleted
+
+    def note_cleaned(self, watermark) -> None:
+        """The table holds no row below ``watermark`` any more on the
+        column its operator cleans it on: the leading key column of
+        this range delete, or the join key of a join side's expiry,
+        which deletes its rows itself. ``stream_watermark`` and
+        ``rw_watermarks`` say so."""
+        _METRICS.state_watermark.set(float(watermark),
+                                     table=f"t{self.table_id}")
+        _topology.TOPOLOGY.note_cleaned(self.table_id, watermark)
 
     # -- bulk row API (barrier-flush hot path for device operators) -----
     # Each bulk entry point (and write_chunk) is one `state.write` stage

@@ -78,6 +78,9 @@ class StateTopology:
         # unit it is about to write (old == new → replacement is a
         # totals no-op); this book proves that precondition in O(1)
         self._unit: Dict[int, int] = {}
+        # table_id -> the watermark the table was last cleaned to
+        # (tables a watermark cleans only; rw_watermarks)
+        self._cleaned_to: Dict[int, int] = {}
         # worker -> drained remote rows (coordinator merge; a state
         # table lives in exactly one process, so rows union cleanly)
         self._remote: Dict[str, List[tuple]] = {}
@@ -159,6 +162,7 @@ class StateTopology:
                 self._sizes.pop(t, None)
                 self._totals.pop(t, None)
                 self._unit.pop(t, None)
+                self._cleaned_to.pop(t, None)
             self._remote = {
                 w: [r for r in rows if r[1] != mv]
                 for w, rows in self._remote.items()}
@@ -166,6 +170,29 @@ class StateTopology:
     def mv_of(self, table_id: int) -> str:
         with self._lock:
             return self._mv_of.get(table_id, "")
+
+    # -- watermark cleaning ---------------------------------------------
+    def note_cleaned(self, table_id: int, watermark: int) -> None:
+        with self._lock:
+            self._cleaned_to[table_id] = int(watermark)
+
+    def cleaned_rows_of(self, table_id: int) -> Optional[int]:
+        """The table's rows by the totals, where a watermark cleans
+        it; None for a table no watermark has cleaned."""
+        with self._lock:
+            if table_id not in self._cleaned_to:
+                return None
+            return self._totals.get(table_id, [0, 0])[0]
+
+    def watermark_rows(self) -> List[tuple]:
+        """rw_watermarks payload: (table_id, mv, watermark, rows) of
+        every state table a watermark has cleaned: the value it was
+        last cleaned to and the rows it keeps, O(#tables)."""
+        with self._lock:
+            return sorted(
+                (t, self._mv_of.get(t, ""), wm,
+                 self._totals.get(t, [0, 0])[0])
+                for t, wm in self._cleaned_to.items())
 
     # -- read side (system tables / ctl — off the hot path) -------------
     @staticmethod
@@ -293,6 +320,7 @@ class StateTopology:
             self._totals.clear()
             self._mv_of.clear()
             self._unit.clear()
+            self._cleaned_to.clear()
             self._remote.clear()
             self._violations.clear()
             self._verify_each_checkpoint = False

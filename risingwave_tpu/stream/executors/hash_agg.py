@@ -137,10 +137,30 @@ def agg_output_schema(input_schema: Schema, group_indices: Sequence[int],
     return Schema(fields)
 
 
+def cleanable_type(dt: DataType) -> bool:
+    """Can a watermark on a group column of this type clean state?
+    Integer family only (timestamps included): the device compare runs
+    on the bijective (hi, lo) i64 split, which is order-preserving for
+    ints/timestamps but not for bit-cast floats."""
+    nd = np.dtype(dt.np_dtype)
+    return bool(np.issubdtype(nd, np.integer) or nd == np.dtype(bool))
+
+
+def state_key_order(n_group: int, key_lead: int = 0) -> List[int]:
+    """Group positions in the order the aggregate's state tables key
+    them: ``key_lead`` first, the others as written. The planner puts
+    the group column that carries a watermark there (generic/agg.rs
+    ``window_col_idx``: upstream does the same whatever order GROUP BY
+    was written in), because a watermark's range delete covers the
+    key's first column only; 0, the written order, where none does."""
+    return [key_lead] + [i for i in range(n_group) if i != key_lead]
+
+
 def agg_state_schema(input_schema: Schema, group_indices: Sequence[int],
-                     agg_calls: Sequence[AggCall]
+                     agg_calls: Sequence[AggCall], key_lead: int = 0
                      ) -> Tuple[Schema, List[int]]:
-    """Value-state table schema + pk indices (pk = group keys)."""
+    """Value-state table schema + pk indices (pk = the group keys, in
+    ``state_key_order``; the columns stay in the written order)."""
     fields = [input_schema[i] for i in group_indices]
     fields.append(Field("_group_rows", DataType.INT64))
     specs = [c.spec(input_schema) for c in agg_calls]
@@ -148,27 +168,30 @@ def agg_state_schema(input_schema: Schema, group_indices: Sequence[int],
         lt = DataType.FLOAT64 if np.issubdtype(dt, np.floating) \
             else DataType.INT64
         fields.append(Field(f"_acc{j}", lt))
-    return Schema(fields), list(range(len(group_indices)))
+    return Schema(fields), state_key_order(len(group_indices), key_lead)
 
 
 def minput_state_schema(input_schema: Schema,
-                        group_indices: Sequence[int], call: AggCall
+                        group_indices: Sequence[int], call: AggCall,
+                        key_lead: int = 0
                         ) -> Tuple[Schema, List[int], List[int]]:
     """Materialized-input table for ONE retractable MIN/MAX call
     (aggregation/minput.rs analog, value-multiset form): rows are
-    (group keys..., value, _cnt) with pk = (group keys, value) so a
-    prefix scan over the group yields the surviving values.
+    (group keys..., value, _cnt) with pk = (group keys in
+    ``state_key_order``, value) so a prefix scan over the group yields
+    the surviving values.
 
     Returns (schema, pk_indices, dist_key_indices)."""
     fields = [input_schema[i] for i in group_indices]
     fields.append(Field("_value", input_schema[call.input_idx].data_type))
     fields.append(Field("_cnt", DataType.INT64))
     g = len(group_indices)
-    return Schema(fields), list(range(g + 1)), list(range(g))
+    return (Schema(fields), state_key_order(g, key_lead) + [g],
+            list(range(g)))
 
 
 def hll_state_schema(input_schema: Schema,
-                     group_indices: Sequence[int]
+                     group_indices: Sequence[int], key_lead: int = 0
                      ) -> Tuple[Schema, List[int], List[int]]:
     """Dense-HLL sketch table for ONE approx_count_distinct call:
     (group keys..., _sketch BYTEA) — one packed register file per
@@ -177,13 +200,14 @@ def hll_state_schema(input_schema: Schema,
     fields = [input_schema[i] for i in group_indices]
     fields.append(Field("_sketch", DataType.BYTEA))
     g = len(group_indices)
-    return Schema(fields), list(range(g)), list(range(g))
+    return Schema(fields), state_key_order(g, key_lead), list(range(g))
 
 
 def agg_aux_tables(input_schema: Schema,
                    group_indices: Sequence[int],
                    agg_calls: Sequence["AggCall"], append_only: bool,
-                   store, dedup_table_id, minput_table_id
+                   store, dedup_table_id, minput_table_id,
+                   key_lead: int = 0
                    ) -> Tuple[Dict[int, StateTable],
                               Dict[int, StateTable]]:
     """Build the aux state tables HashAggExecutor needs:
@@ -191,6 +215,8 @@ def agg_aux_tables(input_schema: Schema,
     tables (retractable MIN/MAX + host aggs). The ONE selection rule
     shared by the planner and the shipped-plan factory — both callers
     must agree or the same query gets different state tables.
+    ``key_lead`` is the value-state table's (``state_key_order``): every
+    table of one aggregate is cleaned on the same column.
 
     ``dedup_table_id(input_idx)`` / ``minput_table_id(call_idx)``
     supply ids. Iteration order is dedup tables first (call order,
@@ -203,7 +229,7 @@ def agg_aux_tables(input_schema: Schema,
     for c in agg_calls:
         if c.distinct and c.input_idx not in distinct_tables:
             dsch, dpk, ddk = minput_state_schema(
-                input_schema, group_indices, c)
+                input_schema, group_indices, c, key_lead)
             distinct_tables[c.input_idx] = StateTable(
                 dedup_table_id(c.input_idx), dsch, dpk, store,
                 dist_key_indices=ddk)
@@ -211,7 +237,7 @@ def agg_aux_tables(input_schema: Schema,
     for j, c in enumerate(agg_calls):
         if c.kind == AggKind.APPROX_COUNT_DISTINCT:
             hsch, hpk, hdk = hll_state_schema(input_schema,
-                                              group_indices)
+                                              group_indices, key_lead)
             # sanity off: sketch rows are blind upserts (same pk,
             # newer epoch shadows)
             minput_tables[j] = StateTable(
@@ -223,7 +249,7 @@ def agg_aux_tables(input_schema: Schema,
         if ((c.kind in (AggKind.MIN, AggKind.MAX)
              and not append_only) or c.kind in HOST_AGG_KINDS):
             msch, mpk, mdk = minput_state_schema(
-                input_schema, group_indices, c)
+                input_schema, group_indices, c, key_lead)
             minput_tables[j] = StateTable(
                 minput_table_id(j), msch, mpk, store,
                 dist_key_indices=mdk)
@@ -402,10 +428,14 @@ class HashAggExecutor(Executor):
             # the exchange's books and in rw_mesh_tables
             kernel.table_id = table.table_id
         # watermark-driven state cleaning (state_table.rs:894 analog):
-        # latest watermark seen on the FIRST group column (the state
-        # tables' pk prefix — the only position a range delete covers,
-        # mirroring the reference's prefix rule), and the last value
-        # already applied to the kernel/table
+        # the group positions in the order the state tables key them
+        # (the planner's choice, ``state_key_order``: a group column
+        # that carries a watermark leads, whatever order GROUP BY was
+        # written in), the latest watermark seen on the leading one —
+        # the only column a range delete covers, the reference's prefix
+        # rule — and the last value already applied to kernel and tables
+        self._key_order = list(table.pk_indices)
+        self.key_lead = self._key_order[0]
         self._clean_wm: Optional[int] = None
         self._cleaned_wm: Optional[int] = None
         out_schema = agg_output_schema(in_schema, group_indices, agg_calls,
@@ -909,7 +939,7 @@ class HashAggExecutor(Executor):
         groups: List[tuple] = []
         for lt in lanes_ts:
             vt = self._cold_groups.pop(lt)
-            row = self.table.get_row(vt)
+            row = self.table.get_row(self._state_pk(vt))
             if row is None:
                 continue       # retired under a watermark while cold
             rows.append(row)
@@ -929,10 +959,11 @@ class HashAggExecutor(Executor):
             for j, t in tables.items():
                 for vt in groups:
                     mults[j].load(
-                        row for _pk, row in t.iter_prefix(list(vt)))
+                        row for _pk, row
+                        in t.iter_prefix(self._state_pk(vt)))
         for j, t in self.hll_tables.items():
             for vt in groups:
-                row = t.get_row(vt)
+                row = t.get_row(self._state_pk(vt))
                 if row is not None:
                     arr = np.frombuffer(row[-1], dtype=np.uint8).copy()
                     self._hll_regs[j][vt] = arr
@@ -953,7 +984,7 @@ class HashAggExecutor(Executor):
         kept_groups: List[tuple] = []
         for lt, vt in zip(lanes_ts, self._group_tuples(
                 gk, np.arange(len(lanes_ts)))):
-            if self.table.get_row(vt) is None:
+            if self.table.get_row(self._state_pk(vt)) is None:
                 continue
             kept_lanes.append(lt)
             kept_groups.append(vt)
@@ -980,44 +1011,50 @@ class HashAggExecutor(Executor):
         """Watermark cleaning retired groups below `phys`: drop their
         cold markers (rows already range-deleted) and their resident
         tier entries (retired on device by retire_below)."""
+        lead = self.key_lead
         if self._cold_groups:
             self._cold_groups = {
                 lt: vt for lt, vt in self._cold_groups.items()
-                if vt[0] is None or vt[0] >= phys}
+                if vt[lead] is None or vt[lead] >= phys}
         part = self._tier_part
         if part is None or not part.keys:
             return
         from risingwave_tpu.ops import lanes as _lanes
         keys_list = list(part.keys)
         mat = np.asarray(keys_list, dtype=np.int64)
-        ok = mat[:, 2] != 0
-        v = _lanes.merge_i64(mat[:, 0].astype(np.int32),
-                             mat[:, 1].astype(np.int32))
+        at = _LANES_PER_KEY * lead
+        ok = mat[:, at + 2] != 0
+        v = _lanes.merge_i64(mat[:, at].astype(np.int32),
+                             mat[:, at + 1].astype(np.int32))
         dead = ok & (v < phys)
         if dead.any():
             self._tier.forget(part, [
                 k for k, d in zip(keys_list, dead.tolist()) if d])
 
     # -- watermark state cleaning ----------------------------------------
-    def _cleanable_type(self) -> bool:
-        """Integer-family first group col only: the device compare runs
-        on the bijective (hi, lo) i64 split, which is order-preserving
-        for ints/timestamps but not for bit-cast floats."""
-        dt = np.dtype(self.group_types[0].np_dtype)
-        return np.issubdtype(dt, np.integer) or dt == np.dtype(bool)
+    def _state_pk(self, group: Sequence) -> list:
+        """A group's values in the state tables' key order."""
+        return [group[i] for i in self._key_order]
 
     def _clean_state(self) -> None:
-        """Retire groups below the watermark: device rebuild + ordered
-        range delete from every state table. Runs after flush/advance
-        (a dirty group must emit its last change before retirement);
-        late rows for a retired group restart it from scratch — the
-        same contract as the reference's cleaned state tables."""
+        """Clean to the newest watermark, where it has advanced. Runs
+        after flush/advance (a dirty group must emit its last change
+        before retirement); late rows for a retired group restart it
+        from scratch — the same contract as the reference's cleaned
+        state tables."""
         wm = self._clean_wm
-        if wm is None or (self._cleaned_wm is not None
-                          and wm <= self._cleaned_wm):
-            return
-        phys = int(wm)
-        self.kernel.retire_below(0, phys)
+        if wm is not None and (self._cleaned_wm is None
+                               or wm > self._cleaned_wm):
+            self._clean_to(int(wm))
+            self._cleaned_wm = wm
+
+    @staged("agg.clean")
+    def _clean_to(self, phys: int) -> None:
+        """Retire the groups below ``phys`` on the state key's leading
+        group column (``key_lead``): device rebuild + ordered range
+        delete from every state table."""
+        lead = self.key_lead
+        self.kernel.retire_below(lead, phys)
         n = self.table.delete_below_prefix(phys)
         self._live_groups = max(0, self._live_groups - n)
         for tables, mults in ((self.minput, self._minput_mult),
@@ -1026,18 +1063,17 @@ class HashAggExecutor(Executor):
                 # the range delete reads the rows it deletes (cold
                 # groups' too, which no multiset in memory holds)
                 _MULTISET_READS.inc(t.delete_below_prefix(phys))
-                mults[j].cut_below(phys)
+                mults[j].cut_below(lead, phys)
         for j, t in self.hll_tables.items():
             t.delete_below_prefix(phys)
             self._hll_regs[j] = {
                 k: v for k, v in self._hll_regs[j].items()
-                if k[0] is None or k[0] >= phys}
+                if k[lead] is None or k[lead] >= phys}
             self._hll_prev[j] = {
                 k: v for k, v in self._hll_prev[j].items()
-                if k[0] is None or k[0] >= phys}
+                if k[lead] is None or k[lead] >= phys}
         if self._tier is not None:
             self._tier_forget_expired(phys)
-        self._cleaned_wm = wm
 
     INTERNER_GC_MIN = 4096
 
@@ -1339,7 +1375,8 @@ class HashAggExecutor(Executor):
         # table's pk is the group key, which ``gk`` already holds by the
         # column, so the table need not take the rows apart again
         def pk_cols(idx):
-            return [(vals[idx], ok[idx]) for vals, ok in gk]
+            return [(gk[i][0][idx], gk[i][1][idx])
+                    for i in self._key_order]
 
         self.table.insert_rows(self._state_rows(fr, gk, ins_i, prev=False),
                                pk_cols(ins_i))
@@ -1452,7 +1489,8 @@ class HashAggExecutor(Executor):
                     for m in wms:
                         if m.col_idx in self.group_indices:
                             pos = self.group_indices.index(m.col_idx)
-                            if pos == 0 and self._cleanable_type():
+                            if pos == self.key_lead and cleanable_type(
+                                    self.group_types[pos]):
                                 self._clean_wm = m.value
                             yield m.with_idx(pos)
         finally:

@@ -104,7 +104,7 @@ class JoinType(enum.Enum):
 
 from risingwave_tpu.common.chunk import Column, Op, StreamChunk, next_pow2
 from risingwave_tpu.common.types import Field, Schema
-from risingwave_tpu.ops.hash_join import JoinSideKernel
+from risingwave_tpu.ops.hash_join import BatchRung, JoinSideKernel
 from risingwave_tpu.state.state_table import StateTable
 from risingwave_tpu.stream.executor import Executor, ExecutorInfo
 from risingwave_tpu.stream.merge import barrier_align_2
@@ -246,6 +246,8 @@ class _JoinSide:
         # lanes of keys watermark-expiry dropped (resident AND cold) —
         # the executor drains these into tier.forget after each sweep
         self.expired_lanes: List[tuple] = []
+        # the batch size of a watermark expiry's tombstones
+        self._expire_rung = BatchRung()
         # per-ref match degree (outer/semi/anti bookkeeping; see
         # JoinType docstring). On the single-chip epoch path the
         # AUTHORITATIVE copy is the kernel's device array, maintained
@@ -598,11 +600,6 @@ class _JoinSide:
             self.free.append(ref)
         self.table.delete_rows([self.row_tuple(r)
                                 for r in dead_refs.tolist()])
-        cap = next_pow2(n_dead)
-        del_refs = np.zeros(cap, dtype=np.int32)
-        del_refs[:n_dead] = dead_refs
-        mask = np.zeros(cap, dtype=bool)
-        mask[:n_dead] = True
         # key lanes of the dead refs: the sharded kernel routes the
         # tombstone to the key's owner shard (single-chip ignores them)
         key_cols = [(self.arena.cols[i][dead_refs],
@@ -615,10 +612,15 @@ class _JoinSide:
             # but only tiered sides have anything to forget)
             self.expired_lanes.extend(
                 map(tuple, np.unique(dead_lanes, axis=0).tolist()))
-        lanes_ = np.zeros((cap, LANES_PER_KEY * len(self.key_indices)),
-                          dtype=np.int32)
-        lanes_[:n_dead] = dead_lanes
-        self.kernel.delete(del_refs, mask, seq=seq, key_lanes=lanes_)
+        # the tombstone's batch keeps to a rung: the rows a watermark
+        # closes differ every barrier, its program must not
+        rung = self._expire_rung
+        with LEDGER.phase("device_compute", kernel="hash_join.expire",
+                          stage="launch"):
+            for lo, hi in rung.pages(n_dead):
+                self.kernel.delete(
+                    rung.padded(dead_refs, lo, hi), rung.mask(lo, hi),
+                    seq=seq, key_lanes=rung.padded(dead_lanes, lo, hi))
         return n_dead + n_cold
 
     def evict_keys(self, lanes_ts: Sequence[tuple]
@@ -1684,6 +1686,9 @@ class HashJoinExecutor(Executor):
             yield Watermark(right_col, msg.data_type, combined)
 
     def _expire_state(self) -> None:
+        """Expire both sides to the join-key watermarks that advanced
+        in this epoch (the smaller of the two inputs', per key
+        position), at the barrier that seals it."""
         for pos, wm in self._combined_wm.items():
             done = self._expired_wm.get(pos)
             if done is not None and wm <= done:
@@ -1692,12 +1697,19 @@ class HashJoinExecutor(Executor):
                 self.sides[0].key_types[pos].np_dtype)
             if not np.issubdtype(dt, np.integer):
                 continue       # float keys: no order-safe expiry
-            for side in self.sides:
-                side.expire_below(pos, int(wm), seq=self._seq)
-            # bump: visibility is del_seq >= probe_seq, so the NEXT
-            # chunk's sequence must exceed the tombstones' del_seq
-            self._seq += 1
-            self._expired_wm[pos] = wm
+            self._expire_to(pos, int(wm))
+
+    @staged("join.expire")
+    def _expire_to(self, pos: int, wm: int) -> None:
+        for side in self.sides:
+            side.expire_below(pos, wm, seq=self._seq)
+            # the side now holds no row below the watermark on this
+            # key column
+            side.table.note_cleaned(wm)
+        # bump: visibility is del_seq >= probe_seq, so the NEXT
+        # chunk's sequence must exceed the tombstones' del_seq
+        self._seq += 1
+        self._expired_wm[pos] = wm
 
     # interner GC gate: skip below this many entries, and skip while
     # entries ≤ 2× live refs (GC cost is O(live), so only run it when

@@ -134,17 +134,10 @@ class ProjectExecutor(Executor):
                         continue   # all pairs were noops: emit nothing
                 yield StreamChunk(self.schema, cols, vis, msg.ops)
             elif isinstance(msg, Watermark):
-                d = self.watermark_derivations.get(msg.col_idx)
                 # one input watermark may derive SEVERAL outputs (the
-                # raw column plus a windowed image of it): list form
-                for one in (d if isinstance(d, list)
-                            else [] if d is None else [d]):
-                    if isinstance(one, tuple):
-                        out_idx, fn = one
-                        yield Watermark(out_idx, msg.data_type,
-                                        fn(msg.value))
-                    else:
-                        yield msg.with_idx(one)
+                # raw column plus a windowed image of it)
+                for wm in msg.derived(self.watermark_derivations):
+                    yield wm
                 # underivable watermarks are dropped (reference behavior)
             else:
                 yield msg

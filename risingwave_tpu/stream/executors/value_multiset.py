@@ -89,11 +89,11 @@ class ValueMultiset:
             if vals is not None:
                 self._pairs -= len(vals)
 
-    def cut_below(self, phys) -> None:
-        """Drop the groups a watermark on the first group column
-        retired (NULL sorts outside every watermark and stays)."""
+    def cut_below(self, pos: int, phys) -> None:
+        """Drop the groups a watermark on group column ``pos`` retired
+        (NULL sorts outside every watermark and stays)."""
         self.drop_groups([g for g in self._groups
-                          if g[0] is not None and g[0] < phys])
+                          if g[pos] is not None and g[pos] < phys])
 
     def rows(self) -> Iterator[tuple]:
         """The rows the table holds for the groups in memory."""

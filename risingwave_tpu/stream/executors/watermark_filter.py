@@ -24,6 +24,7 @@ from risingwave_tpu.stream.executor import Executor, ExecutorInfo
 from risingwave_tpu.stream.message import (
     Message, Watermark, is_barrier, is_chunk,
 )
+from risingwave_tpu.utils.metrics import STREAMING as _METRICS
 
 WATERMARK_STATE_SCHEMA = Schema([Field("pk", DataType.INT16),
                                  Field("watermark", DataType.TIMESTAMP)])
@@ -54,7 +55,7 @@ class WatermarkFilterExecutor(WatermarkRuntime, Executor):
     """Event-time watermark generator + late-row filter."""
 
     def __init__(self, input_: Executor, time_col: int, delay: Interval,
-                 state: Optional[StateTable] = None):
+                 state: Optional[StateTable] = None, source: str = ""):
         Executor.__init__(self, ExecutorInfo(
             input_.schema, list(input_.pk_indices),
             "WatermarkFilterExecutor"))
@@ -62,6 +63,9 @@ class WatermarkFilterExecutor(WatermarkRuntime, Executor):
         self.input = input_
         self.time_col = time_col
         self.delay = delay.usecs
+        # rows dropped as late, by the source the planner names
+        self._late_rows = _METRICS.watermark_late_rows.labeled(
+            source=source or "?")
 
     async def execute(self) -> AsyncIterator[Message]:
         first_seen = False
@@ -114,6 +118,7 @@ class WatermarkFilterExecutor(WatermarkRuntime, Executor):
         if prev_wm is None:
             return chunk
         late = ok & (ts < prev_wm)
+        self._late_rows.inc(float(late.sum()))
         if not late.any():
             return chunk
         new_vis = vis & ~late

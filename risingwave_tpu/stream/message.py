@@ -141,8 +141,26 @@ class Watermark:
     def with_idx(self, idx: int) -> "Watermark":
         return Watermark(idx, self.data_type, self.value)
 
+    def derived(self, derivations: dict) -> List["Watermark"]:
+        """This watermark's images under a projection's derivations
+        (input col -> an output col, an (output col, transform) for a
+        monotone expression, or a list of those: the raw column beside
+        a windowed image of it); none where the column is not kept."""
+        return [Watermark(out, self.data_type,
+                          self.value if fn is None else fn(self.value))
+                for out, fn in derivation_images(derivations,
+                                                 self.col_idx)]
+
     def __repr__(self) -> str:
         return f"Watermark(col={self.col_idx}, {self.value})"
+
+
+def derivation_images(derivations: dict, col_idx: int):
+    """(output col, transform or None) of every image ``col_idx`` has
+    under a projection's watermark derivations."""
+    d = derivations.get(col_idx)
+    for one in d if isinstance(d, list) else [] if d is None else [d]:
+        yield one if isinstance(one, tuple) else (one, None)
 
 
 Message = Union[StreamChunk, Barrier, Watermark]

@@ -478,7 +478,9 @@ def build_fragment(nodes: List[dict], store, local,
                                        store=store)
             agg_in_schema = child.schema if fused is None \
                 else fused.out_schema
-            sch, pk = agg_state_schema(agg_in_schema, group, calls)
+            key_lead = int(node.get("key_lead", 0))
+            sch, pk = agg_state_schema(agg_in_schema, group, calls,
+                                       key_lead)
             table = StateTable(int(node["table_id"]), sch, pk, store,
                                dist_key_indices=list(range(len(pk))))
             # default FALSE like HashAggExecutor itself: a silently
@@ -507,7 +509,8 @@ def build_fragment(nodes: List[dict], store, local,
                 dedup_table_id=lambda col: _shipped_id(
                     dedup_ids, "dedup_table_ids", col),
                 minput_table_id=lambda j: _shipped_id(
-                    minput_ids, "minput_table_ids", j))
+                    minput_ids, "minput_table_ids", j),
+                key_lead=key_lead)
             tier_cap = node.get("tier_cap")
             ex = HashAggExecutor(
                 child, group, calls, table,

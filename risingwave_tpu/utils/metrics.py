@@ -296,6 +296,28 @@ class StreamingMetrics:
             "from one outside recovery and cold-tier reload; "
             "extreme_scans / values_scanned: groups whose MIN/MAX was "
             "recomputed after a retraction, and the values looked at)")
+        self.state_cleaned_rows = r.counter(
+            "stream_state_cleaned_rows",
+            "rows a watermark's range delete took out of a state "
+            "table, by table (t<state table id>)")
+        self.state_clean_reads = r.counter(
+            "stream_state_clean_reads",
+            "rows a watermark's range delete read back from the store "
+            "to delete them, by table: one a row while the delete is "
+            "a scan, none once it is a range tombstone")
+        self.state_resident_rows = r.gauge(
+            "stream_state_resident_rows",
+            "rows of a state table that a watermark cleans, as the "
+            "topology's books hold them at the table's commit, by table")
+        self.state_watermark = r.gauge(
+            "stream_watermark",
+            "the watermark a state table was last cleaned to (the "
+            "physical value of the column its operator cleans it on: "
+            "rows below it are gone), by table")
+        self.watermark_late_rows = r.counter(
+            "stream_watermark_late_rows",
+            "rows a source's watermark filter dropped as late, by "
+            "source")
         self.agg_input_rows = r.counter(
             "stream_agg_input_rows",
             "visible rows a HashAggExecutor took in, by op (insert, "
@@ -820,7 +842,9 @@ class MetricsHistory:
         """(series name, value now, kind) of what the executors count
         by the batch: rows into an aggregate by op, the join ->
         aggregate hand-off, rows into and out of a join and through
-        its condition, rows through a HOP, the longest chain a join's
+        its condition, rows through a HOP, what a watermark cleaned out
+        of the state tables and the rows they keep, the rows a
+        watermark filter dropped, the longest chain a join's
         probe walked, the key skew of a staged batch, the rounds of
         probe_insert's loop and the rows they worked. A plan with no
         such executor writes no such series and its rows carry no
@@ -844,6 +868,15 @@ class MetricsHistory:
                 ("join_condition.{table}.seconds",
                  S.join_condition_seconds, "counter"),
                 ("hop_rows.{table}.{dir}", S.hop_rows, "counter"),
+                ("state_clean.{table}.cleaned", S.state_cleaned_rows,
+                 "counter"),
+                ("state_clean.{table}.reads", S.state_clean_reads,
+                 "counter"),
+                ("state_resident.{table}.rows", S.state_resident_rows,
+                 "gauge"),
+                ("watermark.{table}", S.state_watermark, "gauge"),
+                ("watermark_late.{source}.rows", S.watermark_late_rows,
+                 "counter"),
                 ("join_probe.{kernel}.longest_chain",
                  S.join_probe_chain, "gauge")):
             out += [(name.format(**l), v, kind)

@@ -1,0 +1,333 @@
+"""NEXmark q5 under the benchmark's own watermark (ISSUE 37): the DDL of
+the `nexmark-q5-wm` configuration, read from the file, through a SQL
+session, held at EVERY checkpoint to the benchmark's plain reference
+(`benchmark/reference/nexmark_q5_wm.py`): the view as a multiset, and
+each state table of the view (both counting aggregates, the MAX and
+its value multiset, both join sides) to the rows the watermark has not
+closed, in both orders `GROUP BY` can be written in. A table that is
+not cleaned reads above the reference, one cleaned too far below it.
+Then: the state is level and no kernel traces once it is; a crash in
+the middle of the run restores the cleaned state and the watermark
+filter's watermark and continues to the same rows.
+
+Epochs are a fixed number of chunks per reader, so nothing here waits
+on a clock. The source is cut small and stretched in event time: 1,024
+bids a chunk, 1 ms between events, so that a barrier carries 1.1 s of
+event time and a 2 s window closes on every second one. The view's
+text is the file's.
+"""
+
+import asyncio
+import collections
+import json
+import os
+import re
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmark")
+SEED = 3700000037
+CHUNK = 1024
+GAP_NS = 1_000_000
+BARRIERS = 36
+LEVEL_AT = 15           # barriers: 14 s of event time close a window
+
+AS_WRITTEN = ("GROUP BY window_start, bid.auction",
+              "GROUP BY bid.auction, window_start")
+# case -> (the two GROUP BYs, the reader that runs ahead)
+CASES = {
+    "as_written": (AS_WRITTEN, None),
+    "swapped": (AS_WRITTEN[::-1], None),
+    "counts_ahead": (AS_WRITTEN, "left"),
+}
+
+
+def _bench_module(directory: str, name: str):
+    """A module of `benchmark/`, loaded the way `run.py` loads it."""
+    for path in (BENCH, os.path.join(BENCH, "reference")):
+        if path not in sys.path:
+            sys.path.insert(0, path)
+    import run
+    return run.load_module(directory, name)
+
+
+def _config() -> dict:
+    with open(os.path.join(BENCH, "configs", "nexmark-q5-wm.json")) as f:
+        return json.load(f)
+
+
+def _ddl(config: dict, group_bys) -> list:
+    source, view = config["ddl"]
+    source, n = re.subn(r"max\.chunk\.size=\d+",
+                        f"max.chunk.size={CHUNK}, "
+                        f"nexmark.min.event.gap.in.ns={GAP_NS}", source)
+    assert n == 1
+    assert view.count(AS_WRITTEN[0]) == view.count(AS_WRITTEN[1]) == 1
+    view = view.replace(AS_WRITTEN[0], "\0").replace(
+        AS_WRITTEN[1], group_bys[1]).replace("\0", group_bys[0])
+    return [source.format(seed=SEED), view]
+
+
+def _generator(config: dict):
+    return _bench_module("reference", "nexmark_gen").GeneratorConfig(
+        seed=SEED, **{**config["generator"],
+                      "min_event_gap_in_ns": GAP_NS})
+
+
+def _bare(ex):
+    return getattr(ex, "inner", ex)         # under the monitor
+
+
+def _tables(fe, view: str) -> dict:
+    """The state tables of the view by the reference's names."""
+    import run
+    from risingwave_tpu.stream.executors.hash_agg import HashAggExecutor
+    from risingwave_tpu.stream.executors.hash_join import HashJoinExecutor
+    actor = fe.actors[fe.catalog.mvs[view].actor_id]
+    aggs, join = {}, None
+    for path, ex in run.walk_executors(actor.consumer):
+        ex = _bare(ex)
+        if isinstance(ex, HashJoinExecutor):
+            join = ex
+        elif isinstance(ex, HashAggExecutor):
+            side = next(p for p in path.split("/")
+                        if p in ("left_in", "right_in"))
+            aggs.setdefault(side, []).append(ex)
+    (left,), (max_agg, count_agg) = aggs["left_in"], aggs["right_in"]
+    (values,) = max_agg.minput.values()
+    return {"AuctionBids": left.table, "CountBids": count_agg.table,
+            "MaxBids": max_agg.table, "MaxBids.values": values,
+            "join.left": join.sides[0].table,
+            "join.right": join.sides[1].table}
+
+
+def _filters(fe, view: str) -> list:
+    import run
+    from risingwave_tpu.stream.executors.watermark_filter import (
+        WatermarkFilterExecutor,
+    )
+    actor = fe.actors[fe.catalog.mvs[view].actor_id]
+    return [_bare(ex) for _p, ex in run.walk_executors(actor.consumer)
+            if isinstance(_bare(ex), WatermarkFilterExecutor)]
+
+
+async def _checkpoint(fe, view: str, tables: dict) -> dict:
+    """What one checkpoint holds: the view, the readers' offsets, the
+    rows of each state table by the topology's books (what the
+    benchmark reads), the kernels traced so far."""
+    import run
+    by_id = collections.Counter()
+    for table_id, mv, _vnode, n, _bytes in await fe.execute(
+            "SELECT * FROM rw_state_topology"):
+        if mv == view:
+            by_id[table_id] += n
+    return {
+        "view": collections.Counter(
+            tuple(r) for r in await fe.execute(f"SELECT * FROM {view}")),
+        "readers": run.checkpointed_rows(run.source_readers(fe, view)),
+        "rows": {name: by_id[t.table_id] for name, t in tables.items()},
+        "largest": max(by_id.values()),
+        "traces": run.Counts.traces_by_kernel(),
+    }
+
+
+def _pace(fe, view: str, chunks_by_side: dict) -> None:
+    import run
+    for _name, side, source in run.source_readers(fe, view):
+        source = _bare(source)
+        source.rate_limit = source.min_chunks = chunks_by_side[side]
+
+
+async def _start(store, config: dict, case: str):
+    from risingwave_tpu.frontend.session import Frontend
+    fe = Frontend(store, rate_limit=1, min_chunks=1)
+    for ddl in _ddl(config, CASES[case][0]):
+        await fe.execute(ddl)
+    ahead = CASES[case][1]
+    if ahead is not None:
+        # a reader's pace takes hold a barrier or two after it is set:
+        # one side at three chunks a barrier for a while, then both at
+        # one: the lead stays
+        _pace(fe, config["view"], {s: 1 + 2 * (s == ahead)
+                                   for s in ("left", "right")})
+        await fe.step(2)
+        _pace(fe, config["view"], {"left": 1, "right": 1})
+    return fe
+
+
+async def _drive(config: dict, case: str) -> dict:
+    from risingwave_tpu.state.topology import TOPOLOGY
+    TOPOLOGY.clear()            # process-wide books of state rows
+    fe = await _start(None, config, case)
+    try:
+        view = config["view"]
+        tables = _tables(fe, view)
+        checkpoints = []
+        for _ in range(BARRIERS):
+            await fe.step()
+            checkpoints.append(await _checkpoint(fe, view, tables))
+        return {
+            "checkpoints": checkpoints,
+            # the store's own rows against the books', once
+            "stored": {name: sum(1 for _ in t.iter_rows())
+                       for name, t in tables.items()},
+            "key_lead": {name: t.pk_indices[0]
+                         for name, t in tables.items()},
+            "rewrites": await fe.execute(
+                "SELECT job, rule, fired, detail FROM rw_plan_rewrites"),
+            "watermarks": await fe.execute("SELECT * FROM rw_watermarks"),
+            "table_ids": {name: t.table_id for name, t in tables.items()},
+        }
+    finally:
+        await fe.close()
+
+
+@pytest.fixture(scope="module")
+def q5wm():
+    config = _config()
+    _bench_module("reference", "nexmark_gen")
+    return {"config": config,
+            **{case: asyncio.run(_drive(config, case)) for case in CASES}}
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_view_equals_the_reference_at_every_checkpoint(q5wm, case):
+    config = q5wm["config"]
+    ref = _bench_module("reference", config["reference"])
+    gen = _generator(config)
+    for i, cp in enumerate(q5wm[case]["checkpoints"]):
+        want = ref.reference([dict(r) for r in cp["readers"]], gen)
+        assert cp["view"] == want, f"checkpoint {i}"
+    assert sum(want.values()) >= 10          # a row a window, and ties
+    assert not [r for r in q5wm[case]["rewrites"]
+                if str(r[3]).startswith("FALLBACK")]
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_every_state_table_holds_the_reference_s_rows(q5wm, case):
+    """Each of the six, at each of the 36 checkpoints: under-cleaning
+    and over-cleaning both fail it."""
+    config = q5wm["config"]
+    ref = _bench_module("reference", config["reference"])
+    gen = _generator(config)
+    closed = 0
+    for i, cp in enumerate(q5wm[case]["checkpoints"]):
+        readers = [dict(r) for r in cp["readers"]]
+        want = ref.resident_by_table(readers, gen)
+        assert cp["rows"] == want, f"checkpoint {i}"
+        # what the benchmark compares
+        assert cp["largest"] == ref.resident_rows(readers, gen)
+        closed += ref.bound(readers[0]["rows"], gen) > 0
+    assert closed >= BARRIERS - LEVEL_AT     # windows did close
+    assert q5wm[case]["stored"] == cp["rows"]
+    # without the watermark's reference the same run fails the check
+    plain = _bench_module("reference", "nexmark_q5")
+    assert plain.resident_rows(readers, gen) > 2 * cp["largest"]
+
+
+def test_the_watermark_column_leads_every_aggregate_s_key(q5wm):
+    """`window_start` is group column 0 of one counting aggregate and 1
+    of the other as upstream writes q5, and the other way round when
+    the two are swapped; it leads the state key in all four."""
+    assert q5wm["as_written"]["key_lead"] == {
+        "AuctionBids": 0, "CountBids": 1, "MaxBids": 0,
+        "MaxBids.values": 0,
+        **{k: v for k, v in q5wm["as_written"]["key_lead"].items()
+           if k.startswith("join.")}}
+    assert q5wm["swapped"]["key_lead"]["AuctionBids"] == 1
+    assert q5wm["swapped"]["key_lead"]["CountBids"] == 0
+
+
+def test_the_reader_ahead_makes_the_join_wait(q5wm):
+    """With the counts' reader ahead the join keeps the rows of the
+    windows the slower input has not closed: its bound is the smaller
+    of the two, and the test above held it to that."""
+    config = q5wm["config"]
+    ref = _bench_module("reference", config["reference"])
+    gen = _generator(config)
+    cp = q5wm["counts_ahead"]["checkpoints"][-1]
+    rows = {r["side"]: r["rows"] for r in cp["readers"]}
+    assert rows["left"] - rows["right"] >= 2 * CHUNK
+    assert ref.bound(rows["left"], gen) > ref.bound(rows["right"], gen)
+    assert cp["rows"]["join.left"] > cp["rows"]["AuctionBids"]
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_the_state_is_level_and_nothing_traces_once_it_is(q5wm, case):
+    cps = q5wm[case]["checkpoints"]
+    then, now = cps[LEVEL_AT - 1], cps[2 * LEVEL_AT - 1]
+    assert abs(now["largest"] - then["largest"]) <= 0.15 * then["largest"]
+    for name in then["rows"]:
+        assert now["rows"][name] <= 1.15 * then["rows"][name] + 2, name
+    assert cps[-1]["traces"] == then["traces"], \
+        dict(cps[-1]["traces"] - then["traces"])
+
+
+def test_rw_watermarks_names_every_cleaned_table(q5wm):
+    run_ = q5wm["as_written"]
+    got = {r[0]: r for r in run_["watermarks"]}
+    assert set(got) == set(run_["table_ids"].values())
+    last = run_["checkpoints"][-1]["rows"]
+    for name, table_id in run_["table_ids"].items():
+        _tid, mv, watermark, rows = got[table_id]
+        assert mv == q5wm["config"]["view"] and rows == last[name]
+    # one watermark for all: the readers ran in lockstep
+    assert len({r[2] for r in got.values()}) == 1
+
+
+def test_recovery_restores_the_cleaned_state_and_the_watermark():
+    """A crash after 20 barriers (no close, no goodbye): the recovered
+    session's watermark filters announce the watermark they had, every
+    state table holds what the reference says, and twelve barriers
+    later view and tables are the reference's still."""
+    from risingwave_tpu.state.topology import TOPOLOGY
+    from risingwave_tpu.storage.hummock import HummockLite
+    from risingwave_tpu.storage.object_store import MemObjectStore
+
+    config = _config()
+    view = config["view"]
+    ref = _bench_module("reference", config["reference"])
+    gen = _generator(config)
+    obj = MemObjectStore()
+    TOPOLOGY.clear()
+
+    async def before():
+        fe = await _start(HummockLite(obj), config, "as_written")
+        await fe.step(20)
+        cp = await _checkpoint(fe, view, _tables(fe, view))
+        return cp, [f.current for f in _filters(fe, view)]
+
+    async def after():
+        from risingwave_tpu.frontend.session import Frontend
+        fe = Frontend(HummockLite(obj), rate_limit=1, min_chunks=1)
+        await fe.recover()
+        try:
+            tables = _tables(fe, view)
+            restored = [f.current for f in _filters(fe, view)]
+            await fe.step()
+            cps = [await _checkpoint(fe, view, tables)]
+            for _ in range(11):
+                await fe.step()
+                cps.append(await _checkpoint(fe, view, tables))
+            return restored, cps, {
+                name: sum(1 for _ in t.iter_rows())
+                for name, t in tables.items()}
+        finally:
+            await fe.close()
+
+    cp20, wm20 = asyncio.run(before())
+    assert len(wm20) == 2 and all(w is not None for w in wm20)
+    TOPOLOGY.clear()            # the crashed process's books
+    restored, cps, stored = asyncio.run(after())
+    assert restored == wm20
+    assert cps[0]["readers"][0]["rows"] == \
+        cp20["readers"][0]["rows"] + CHUNK
+    for i, cp in enumerate(cps):
+        readers = [dict(r) for r in cp["readers"]]
+        assert cp["view"] == ref.reference(readers, gen), i
+        # the store's rows: the recovered process's books hold only
+        # what it wrote since, so the tables are counted themselves
+    assert stored == ref.resident_by_table(readers, gen)
+    assert cps[-1]["readers"][0]["rows"] == (20 + 12) * CHUNK
