@@ -160,6 +160,14 @@ def decode_value(buf: bytes, pos: int, dt: DataType):
     return _decode_int(buf[pos:pos + 8]), pos + 8
 
 
+def encoded_width(dt: DataType) -> Optional[int]:
+    """Bytes a non-null value of the type takes in a key, tag included;
+    None where the value says it itself (varchar, bytea)."""
+    if dt in (DataType.VARCHAR, DataType.BYTEA):
+        return None
+    return 2 if dt == DataType.BOOLEAN else 9
+
+
 def encode_memcomparable(values: Sequence, types: Sequence[DataType]) -> bytes:
     """Encode a pk tuple → order-preserving bytes."""
     return b"".join(encode_value(v, t) for v, t in zip(values, types))

@@ -19,7 +19,7 @@ users via ``to_logical_row``.
 
 from __future__ import annotations
 
-from typing import Iterator, List, Optional, Sequence, Tuple
+from typing import Dict, Iterator, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
@@ -32,6 +32,7 @@ from risingwave_tpu.common.types import DataType, Schema, scaled_to_decimal
 from risingwave_tpu.state.keycodec import (
     NULL_KEY, decode_memcomparable, encode_fixed_column,
     encode_host_column, encode_memcomparable, encode_vnode_prefix,
+    encoded_width,
 )
 from risingwave_tpu.state.mem_table import KeyOp, MemTable
 from risingwave_tpu.state.store import StateStore
@@ -104,6 +105,18 @@ class StateTable:
         # fields size per value) — lets the topology books take their
         # bulk-update fast path on the staged all-insert flush shape
         self._fixed_row_nbytes = _topology.fixed_row_nbytes(schema)
+        # the clean index: the table's committed keys bucketed by the
+        # encoded bytes of the leading pk column (tag and payload), so
+        # that a watermark's range delete finds its rows without
+        # reading the store. None until the first delete_below_prefix
+        # seeds it; commit keeps it from then on; a table no watermark
+        # cleans never has one
+        self._clean_index: Optional[Dict[bytes, Set[bytes]]] = None
+        # where the leading column's bytes end in a key of a non-null
+        # lead (None for a varchar or bytea lead: no watermark is one,
+        # and such a table is never range-cleaned)
+        width = encoded_width(self.pk_types[0]) if self.pk_types else None
+        self._lead_end = None if width is None else 2 + width
 
     # -- epoch lifecycle ------------------------------------------------
     def init_epoch(self, epoch: EpochPair) -> None:
@@ -168,6 +181,8 @@ class StateTable:
         # reads (rw_state_topology, rescale costing) never scan state
         _topology.TOPOLOGY.record(self.table_id, keys, vals,
                                   self._fixed_row_nbytes)
+        if self._clean_index is not None:
+            self._index_flushed(keys, vals)
         resident = _topology.TOPOLOGY.cleaned_rows_of(self.table_id)
         if resident is not None:
             # a table a watermark cleans says at every commit what it
@@ -237,41 +252,103 @@ class StateTable:
             self.mem_table.insert(nk, new_row)
 
     @staged("state.clean")
-    def delete_below_prefix(self, watermark) -> int:
+    def delete_below_prefix(self, watermark) -> Tuple[int, int]:
         """Watermark state cleaning (state_table.rs:894 update_watermark):
         delete every row whose FIRST pk column is strictly below the
-        watermark. Cost is O(deleted) + an ordered seek per owned vnode
-        (rows below a watermark on the pk prefix form a contiguous range
-        in memcomparable order) + one pass over the memtable for all the
-        vnodes: every row is read back from the store and deleted under
-        the key it was read at, and the books say so
-        (``stream_state_clean_reads`` beside ``_cleaned_rows``). One
-        ``state.clean`` stage of host_emit. Returns rows deleted."""
+        watermark. Returns (rows deleted, rows read from the store).
+
+        The committed rows below the watermark are not read back: they
+        are rows this table committed, and the clean index holds their
+        keys by the leading column's encoded bytes. The buckets that
+        sort under the watermark's encoding (bytewise, as the range
+        end of a scan would: a NULL lead sorts first) give them; one
+        pass over the memtable adds this epoch's inserts below the
+        watermark and takes out its deletes; each doomed key gets its
+        tombstone through the memtable, at this epoch. Cost is the
+        distinct leading values the table holds + O(deleted) + the
+        memtable's pass. The keys leave their buckets at the commit
+        that flushes the tombstones, like any other delete, so the
+        index is the store's keys at the read epoch whatever comes
+        between.
+
+        Only the FIRST clean of a table reads the store: one ordered
+        scan seeds the index (``_seed_clean_index``), and the books say
+        so (``stream_state_clean_reads`` counts what a seeding scan
+        read, ``stream_state_clean_seeds`` the scans, beside
+        ``_cleaned_rows``). One ``state.clean`` stage of host_emit."""
+        assert self._lead_end is not None, \
+            "a range delete needs a fixed-width leading key column"
         end_suffix = encode_memcomparable([watermark], [self.pk_types[0]])
         self._spill_staged()
-        epoch = self._read_epoch()
-        doomed: dict = {}
-        for vnode in self.owned_vnodes():
-            start = encode_vnode_prefix(vnode)
-            doomed.update(self.store.iter(self.table_id, epoch, start,
-                                          start + end_suffix))
+        label = f"t{self.table_id}"
+        read = 0
+        if self._clean_index is None:
+            read = self._seed_clean_index()
+            _METRICS.state_clean_seeds.inc(table=label)
+            _METRICS.state_clean_reads.inc(float(read), table=label)
+        doomed: Set[bytes] = set().union(*(
+            keys for lead, keys in self._clean_index.items()
+            if lead < end_suffix))
         # a key is its vnode's two bytes, then the pk: below the
         # watermark where what follows the vnode sorts under it
         owned = self.vnodes
-        for key, (op, _old, new) in self.mem_table.items():
+        for key, (op, _old, _new) in self.mem_table.items():
             if key[2:] < end_suffix and owned[(key[0] << 8) | key[1]]:
                 if op == KeyOp.DELETE:
-                    doomed.pop(key, None)
+                    doomed.discard(key)
                 else:
-                    doomed[key] = new
-        for key, row in doomed.items():
-            self.mem_table.delete(key, row)
+                    doomed.add(key)
+        mt = self.mem_table
+        for key in doomed:
+            mt.delete(key, None)
         deleted = len(doomed)
-        label = f"t{self.table_id}"
         _METRICS.state_cleaned_rows.inc(float(deleted), table=label)
-        _METRICS.state_clean_reads.inc(float(deleted), table=label)
         self.note_cleaned(watermark)
-        return deleted
+        return deleted, read
+
+    def _seed_clean_index(self) -> int:
+        """Build the clean index from the store: ONE ordered scan over
+        the table's owned vnodes at the read epoch (this epoch's writes
+        are in the memtable and enter at their commit). Returns the
+        rows the scan read."""
+        self._clean_index = {}
+        owned_vnodes = self.owned_vnodes()
+        if not owned_vnodes:
+            return 0
+        last = owned_vnodes[-1] + 1
+        keys = [key for key, _row in self.store.iter(
+            self.table_id, self._read_epoch(),
+            encode_vnode_prefix(owned_vnodes[0]),
+            encode_vnode_prefix(last) if last < VNODE_COUNT else None)]
+        read = len(keys)
+        if len(owned_vnodes) < last - owned_vnodes[0]:
+            owned = self.vnodes
+            keys = [k for k in keys if owned[(k[0] << 8) | k[1]]]
+        self._index_flushed(keys, keys)
+        return read
+
+    def _index_flushed(self, keys: List[bytes], vals: List) -> None:
+        """The clean index follows a flush (and takes the seeding
+        scan's keys the same way): a tombstone leaves its bucket,
+        anything else enters it (the same ``bytes`` the store was
+        handed). Then the gauge says what the index holds: the books'
+        rows for the table."""
+        index = self._clean_index
+        lead_end = self._lead_end
+        for key, val in zip(keys, vals):
+            lead = key[2:lead_end] if key[2] else NULL_KEY
+            bucket = index.get(lead)
+            if val is not None:
+                if bucket is None:
+                    index[lead] = {key}
+                else:
+                    bucket.add(key)
+            elif bucket is not None:
+                bucket.discard(key)
+                if not bucket:
+                    del index[lead]
+        _METRICS.state_clean_index_keys.set(
+            sum(map(len, index.values())), table=f"t{self.table_id}")
 
     def note_cleaned(self, watermark) -> None:
         """The table holds no row below ``watermark`` any more on the
@@ -576,6 +653,9 @@ class StateTable:
             "vnode bitmap swap with dirty memtable"
         prev = self.vnodes
         self.vnodes = np.asarray(new_vnodes, dtype=bool)
+        # the clean index held the old ownership's keys: the next
+        # clean seeds it anew
+        self._clean_index = None
         return prev
 
 

@@ -1051,18 +1051,21 @@ class HashAggExecutor(Executor):
     @staged("agg.clean")
     def _clean_to(self, phys: int) -> None:
         """Retire the groups below ``phys`` on the state key's leading
-        group column (``key_lead``): device rebuild + ordered range
-        delete from every state table."""
+        group column (``key_lead``): device rebuild + range delete
+        from every state table, each by the keys its clean index
+        holds (cold groups' rows too, which no multiset in memory
+        holds)."""
         lead = self.key_lead
         self.kernel.retire_below(lead, phys)
-        n = self.table.delete_below_prefix(phys)
+        n, _read = self.table.delete_below_prefix(phys)
         self._live_groups = max(0, self._live_groups - n)
         for tables, mults in ((self.minput, self._minput_mult),
                               (self.distinct_tables, self._distinct_mult)):
             for j, t in tables.items():
-                # the range delete reads the rows it deletes (cold
-                # groups' too, which no multiset in memory holds)
-                _MULTISET_READS.inc(t.delete_below_prefix(phys))
+                # a table's first range delete reads it once, to seed
+                # its index; the ones after read nothing
+                _n, read = t.delete_below_prefix(phys)
+                _MULTISET_READS.inc(read)
                 mults[j].cut_below(lead, phys)
         for j, t in self.hll_tables.items():
             t.delete_below_prefix(phys)

@@ -302,9 +302,19 @@ class StreamingMetrics:
             "table, by table (t<state table id>)")
         self.state_clean_reads = r.counter(
             "stream_state_clean_reads",
-            "rows a watermark's range delete read back from the store "
-            "to delete them, by table: one a row while the delete is "
-            "a scan, none once it is a range tombstone")
+            "rows a watermark's range delete read from the store, by "
+            "table: what the scan that seeds the table's clean index "
+            "read, at its first clean; a later clean reads none")
+        self.state_clean_seeds = r.counter(
+            "stream_state_clean_seeds",
+            "scans that seeded a state table's clean index (its first "
+            "range delete, and the first after its vnodes changed), "
+            "by table")
+        self.state_clean_index_keys = r.gauge(
+            "stream_state_clean_index_keys",
+            "keys a state table's clean index holds, set when it is "
+            "seeded and at the table's commit: its committed rows, by "
+            "table")
         self.state_resident_rows = r.gauge(
             "stream_state_resident_rows",
             "rows of a state table that a watermark cleans, as the "
@@ -872,6 +882,10 @@ class MetricsHistory:
                  "counter"),
                 ("state_clean.{table}.reads", S.state_clean_reads,
                  "counter"),
+                ("state_clean_index.{table}.seeds", S.state_clean_seeds,
+                 "counter"),
+                ("state_clean_index.{table}.keys",
+                 S.state_clean_index_keys, "gauge"),
                 ("state_resident.{table}.rows", S.state_resident_rows,
                  "gauge"),
                 ("watermark.{table}", S.state_watermark, "gauge"),

@@ -8,7 +8,9 @@ closed, in both orders `GROUP BY` can be written in. A table that is
 not cleaned reads above the reference, one cleaned too far below it.
 Then: the state is level and no kernel traces once it is; a crash in
 the middle of the run restores the cleaned state and the watermark
-filter's watermark and continues to the same rows.
+filter's watermark and continues to the same rows. Since ISSUE 38 also:
+a range-cleaned table reads the store at its first clean (the scan
+that seeds its clean index) and never after, recovery included.
 
 Epochs are a fixed number of chunks per reader, so nothing here waits
 on a clock. The source is cut small and stretched in event time: 1,024
@@ -113,10 +115,51 @@ def _filters(fe, view: str) -> list:
             if isinstance(_bare(ex), WatermarkFilterExecutor)]
 
 
+CLEANED = ("AuctionBids", "CountBids", "MaxBids", "MaxBids.values")
+
+
+def _clean_books() -> dict:
+    """t<table> -> (rows range-deleted, rows read to do so, seeding
+    scans) so far in this process: the counters the history's
+    `state_clean.t<table>.cleaned` / `.reads` and
+    `state_clean_index.t<table>.seeds` are the deltas of."""
+    from risingwave_tpu.utils.metrics import STREAMING
+    books = collections.defaultdict(lambda: [0.0, 0.0, 0.0])
+    for i, metric in enumerate((STREAMING.state_cleaned_rows,
+                                STREAMING.state_clean_reads,
+                                STREAMING.state_clean_seeds)):
+        for labels, value in metric.series():
+            books[labels["table"]][i] = value
+    return {label: tuple(v) for label, v in books.items()}
+
+
+def _reads_by_table(run_: dict) -> dict:
+    """Per range-cleaned table, over the run's checkpoints: the index
+    of the first one that cleaned it, the scans that seeded it, and
+    (rows cleaned, rows read) summed over the checkpoints AFTER that
+    one."""
+    out = {}
+    for name in CLEANED:
+        label = f"t{run_['table_ids'][name]}"
+        books = [b.get(label, (0.0, 0.0, 0.0)) for b in
+                 [run_["clean_books_before"]]
+                 + [cp["clean_books"] for cp in run_["checkpoints"]]]
+        first = next(i for i in range(1, len(books))
+                     if books[i][0] > books[0][0]
+                     or books[i][2] > books[0][2])
+        out[name] = {"first": first - 1,
+                     "seeds": books[-1][2] - books[0][2],
+                     "seed_reads": books[first][1] - books[0][1],
+                     "cleaned_after": books[-1][0] - books[first][0],
+                     "reads_after": books[-1][1] - books[first][1]}
+    return out
+
+
 async def _checkpoint(fe, view: str, tables: dict) -> dict:
     """What one checkpoint holds: the view, the readers' offsets, the
     rows of each state table by the topology's books (what the
-    benchmark reads), the kernels traced so far."""
+    benchmark reads), the kernels traced so far, the range deletes'
+    books."""
     import run
     by_id = collections.Counter()
     for table_id, mv, _vnode, n, _bytes in await fe.execute(
@@ -130,6 +173,7 @@ async def _checkpoint(fe, view: str, tables: dict) -> dict:
         "rows": {name: by_id[t.table_id] for name, t in tables.items()},
         "largest": max(by_id.values()),
         "traces": run.Counts.traces_by_kernel(),
+        "clean_books": _clean_books(),
     }
 
 
@@ -160,6 +204,7 @@ async def _start(store, config: dict, case: str):
 async def _drive(config: dict, case: str) -> dict:
     from risingwave_tpu.state.topology import TOPOLOGY
     TOPOLOGY.clear()            # process-wide books of state rows
+    clean_books_before = _clean_books()
     fe = await _start(None, config, case)
     try:
         view = config["view"]
@@ -170,6 +215,7 @@ async def _drive(config: dict, case: str) -> dict:
             checkpoints.append(await _checkpoint(fe, view, tables))
         return {
             "checkpoints": checkpoints,
+            "clean_books_before": clean_books_before,
             # the store's own rows against the books', once
             "stored": {name: sum(1 for _ in t.iter_rows())
                        for name, t in tables.items()},
@@ -222,6 +268,11 @@ def test_every_state_table_holds_the_reference_s_rows(q5wm, case):
         closed += ref.bound(readers[0]["rows"], gen) > 0
     assert closed >= BARRIERS - LEVEL_AT     # windows did close
     assert q5wm[case]["stored"] == cp["rows"]
+    # ... and were found without reading the store: each table's first
+    # clean seeds its index with one scan, no clean after it reads
+    for name, got in _reads_by_table(q5wm[case]).items():
+        assert got["seeds"] == 1 and got["first"] < LEVEL_AT, (name, got)
+        assert got["reads_after"] == 0 < got["cleaned_after"], (name, got)
     # without the watermark's reference the same run fails the check
     plain = _bench_module("reference", "nexmark_q5")
     assert plain.resident_rows(readers, gen) > 2 * cp["largest"]
@@ -259,6 +310,11 @@ def test_the_state_is_level_and_nothing_traces_once_it_is(q5wm, case):
     cps = q5wm[case]["checkpoints"]
     then, now = cps[LEVEL_AT - 1], cps[2 * LEVEL_AT - 1]
     assert abs(now["largest"] - then["largest"]) <= 0.15 * then["largest"]
+    # level, and no scan seeded an index again once it was
+    for name in CLEANED:
+        label = f"t{q5wm[case]['table_ids'][name]}"
+        assert cps[-1]["clean_books"][label][1:] == \
+            then["clean_books"][label][1:], name
     for name in then["rows"]:
         assert now["rows"][name] <= 1.15 * then["rows"][name] + 2, name
     assert cps[-1]["traces"] == then["traces"], \
@@ -305,6 +361,7 @@ def test_recovery_restores_the_cleaned_state_and_the_watermark():
         await fe.recover()
         try:
             tables = _tables(fe, view)
+            table_ids.update({n: t.table_id for n, t in tables.items()})
             restored = [f.current for f in _filters(fe, view)]
             await fe.step()
             cps = [await _checkpoint(fe, view, tables)]
@@ -320,6 +377,8 @@ def test_recovery_restores_the_cleaned_state_and_the_watermark():
     cp20, wm20 = asyncio.run(before())
     assert len(wm20) == 2 and all(w is not None for w in wm20)
     TOPOLOGY.clear()            # the crashed process's books
+    table_ids = {}
+    clean_books_before = _clean_books()
     restored, cps, stored = asyncio.run(after())
     assert restored == wm20
     assert cps[0]["readers"][0]["rows"] == \
@@ -331,3 +390,10 @@ def test_recovery_restores_the_cleaned_state_and_the_watermark():
         # what it wrote since, so the tables are counted themselves
     assert stored == ref.resident_by_table(readers, gen)
     assert cps[-1]["readers"][0]["rows"] == (20 + 12) * CHUNK
+    # a recovered table starts without an index: its first clean seeds
+    # it with one scan of the recovered rows, the next reads nothing
+    for name, got in _reads_by_table({
+            "table_ids": table_ids, "checkpoints": cps,
+            "clean_books_before": clean_books_before}).items():
+        assert got["seeds"] == 1 and got["seed_reads"] > 0, (name, got)
+        assert got["reads_after"] == 0 < got["cleaned_after"], (name, got)
