@@ -128,6 +128,9 @@ class BatchHashAgg(BatchExecutor):
                 for j, call in enumerate(self.agg_calls):
                     v = None if call.input_idx is None \
                         else row[call.input_idx]
+                    if call.filter_idx is not None \
+                            and not row[call.filter_idx]:
+                        continue       # FILTER of a DISTINCT call
                     if call.distinct and v is not None:
                         s = seen.setdefault((gk, j), set())
                         if v in s:

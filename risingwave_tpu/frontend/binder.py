@@ -71,6 +71,10 @@ class Binder:
         # bound input EXPRESSION per call (None for count(*)) — aggs
         # over arbitrary expressions pre-project through these
         self.agg_inputs: List[Optional[object]] = []
+        # bound FILTER (WHERE c) per call, where the call keeps it as
+        # its own (a DISTINCT count/sum): the planner projects it into
+        # a BOOLEAN column and sets AggCall.filter_idx
+        self.agg_filters: List[Optional[object]] = []
         # bound agg call → position (dedup: COUNT(*) used twice = one)
         self._agg_index: Dict[Tuple, int] = {}
         # window (OVER) calls: all items share ONE window spec in v1
@@ -80,11 +84,14 @@ class Binder:
         self.window_order: Optional[List[Tuple[int, bool]]] = None
 
     def _register(self, call: AggCall, key: Tuple,
-                  input_expr=None) -> int:
+                  input_expr=None, filter_expr=None) -> int:
+        if filter_expr is not None:
+            key = key + (repr(filter_expr),)
         if key not in self._agg_index:
             self._agg_index[key] = len(self.agg_calls)
             self.agg_calls.append(call)
             self.agg_inputs.append(input_expr)
+            self.agg_filters.append(filter_expr)
         return self._agg_index[key]
 
     def agg_out_type(self, j: int) -> DataType:
@@ -229,8 +236,22 @@ class Binder:
         raise BindError(f"unsupported expression {e!r}")
 
     def _bind_call(self, e: ast.Call):
+        flt = None
         if getattr(e, "filter_where", None) is not None:
-            e = _rewrite_filter_clause(e)
+            if e.distinct and e.args and not e.star \
+                    and e.name in ("count", "sum", "avg"):
+                # agg(DISTINCT x) FILTER (WHERE c): the filter stays the
+                # call's own (AggCall.filter_idx), so the calls DISTINCT
+                # on x share one dedup table whatever their filters
+                if not self.allow_aggs:
+                    raise BindError(
+                        f"aggregate {e.name}() not allowed here")
+                flt = self.bind(e.filter_where)
+                if flt.return_type != DataType.BOOLEAN:
+                    raise BindError(
+                        "FILTER (WHERE ...) must be a boolean expression")
+            else:
+                e = _rewrite_filter_clause(e)
         name = e.name
         if name == "avg":
             # AVG rewrites to SUM/COUNT at bind time (the reference's
@@ -246,10 +267,10 @@ class Binder:
             akey = repr(arg)
             sj = self._register(
                 AggCall(AggKind.SUM, None, distinct=d),
-                ("sum", akey, d), input_expr=arg)
+                ("sum", akey, d), input_expr=arg, filter_expr=flt)
             cj = self._register(
                 AggCall(AggKind.COUNT, None, distinct=d),
-                ("count", akey, d), input_expr=arg)
+                ("count", akey, d), input_expr=arg, filter_expr=flt)
             return ("avg", sj, cj)
         if name in ("string_agg", "array_agg"):
             if not self.allow_aggs:
@@ -291,7 +312,8 @@ class Binder:
                 call = AggCall(_AGG_KINDS[name], None,
                                distinct=distinct)
                 return ("agg", self._register(
-                    call, (name, repr(arg), distinct), input_expr=arg))
+                    call, (name, repr(arg), distinct), input_expr=arg,
+                    filter_expr=flt))
             return ("agg", self._register(call, key))
         if name in ("tumble_start", "tumble_end"):
             ts = self.bind(e.args[0])
@@ -344,20 +366,27 @@ def _bind_case(bind, args_ast):
 
 
 def _rewrite_filter_clause(e):
-    """Aggregate FILTER (WHERE c) → CASE rewrite (pg semantics:
-    count(*) counts matches; sum/min/max/avg see NULL for
-    non-matches, so empty matches yield NULL — except count, 0)."""
+    """Aggregate FILTER (WHERE c) of a call that does not dedup → CASE
+    rewrite (pg semantics: count counts the matches; sum/min/max/avg
+    see NULL for non-matches, so empty matches yield NULL — except
+    count, 0). ``min``/``max(DISTINCT x)`` are ``min``/``max(x)``, so
+    they come here too. ``count``/``sum``/``avg(DISTINCT x) FILTER``
+    do not: the binder keeps their filter as the call's own
+    (``Binder._bind_call``), one dedup table a distinct column."""
     fw = e.filter_where
     if e.name == "count" and (e.star or not e.args):
         return ast.Call("sum", [ast.Call(
             "case", [fw, ast.Lit(1, "number"), ast.Lit(0, "number")])])
-    if e.name in ("sum", "min", "max", "avg") and e.args \
-            and not e.distinct:
+    if e.args and not e.star and (
+            e.name in ("min", "max")
+            or (e.name in ("count", "sum", "avg") and not e.distinct)):
         return ast.Call(e.name, [ast.Call(
             "case", [fw, e.args[0], ast.Lit(None, "null")])])
     raise BindError(
-        "FILTER (WHERE ...) is supported for count(*)/sum/min/max/avg"
-        " (without DISTINCT)")
+        f"FILTER (WHERE ...) on {e.name}"
+        f"({'DISTINCT ' if e.distinct else ''}...) is not supported: it "
+        "is taken for count(*), count/sum/avg(x), count/sum/avg("
+        "DISTINCT x) and min/max(x)")
 
 
 # scalar signatures: name → (min args, max args, return type)

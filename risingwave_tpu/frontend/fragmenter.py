@@ -137,6 +137,8 @@ def _agg_call_ir(c) -> dict:
         d["distinct"] = True
     if c.delimiter != ",":
         d["delimiter"] = c.delimiter
+    if c.filter_idx is not None:
+        d["filter_idx"] = c.filter_idx
     return d
 
 

@@ -1133,21 +1133,28 @@ class StreamPlanner:
         pre_names = [f"_g{i}" for i in range(len(group_bound))]
         remapped: List[AggCall] = []
         in_expr_idx: Dict[str, int] = {}
-        for call, in_expr in zip(binder.agg_calls, binder.agg_inputs):
+
+        def pre_col(expr: Expression) -> int:
+            # identical expressions share one projected column —
+            # count(DISTINCT x) + sum(DISTINCT x) then share their
+            # dedup table and per-chunk gating in the executor, and so
+            # do the calls that differ in their FILTER only
+            k = repr(expr)
+            if k not in in_expr_idx:
+                pre_exprs.append(expr)
+                pre_names.append(f"_a{len(pre_exprs) - 1}")
+                in_expr_idx[k] = len(pre_exprs) - 1
+            return in_expr_idx[k]
+
+        for call, in_expr, flt in zip(binder.agg_calls, binder.agg_inputs,
+                                      binder.agg_filters):
             if in_expr is None:            # count(*)
                 remapped.append(call)
                 continue
-            # identical input expressions share one projected column —
-            # count(DISTINCT x) + sum(DISTINCT x) then share their
-            # dedup table and per-chunk gating in the executor
-            k = repr(in_expr)
-            if k not in in_expr_idx:
-                pre_exprs.append(in_expr)
-                pre_names.append(f"_a{len(pre_exprs) - 1}")
-                in_expr_idx[k] = len(pre_exprs) - 1
-            remapped.append(AggCall(call.kind, in_expr_idx[k],
-                                    distinct=call.distinct,
-                                    delimiter=call.delimiter))
+            remapped.append(AggCall(
+                call.kind, pre_col(in_expr), distinct=call.distinct,
+                delimiter=call.delimiter,
+                filter_idx=None if flt is None else pre_col(flt)))
         # plain-column group keys pass their watermarks through the
         # pre-agg projection (EOWC and agg state cleaning need them)
         pre_derivs = {e.index: j for j, e in enumerate(group_bound)
@@ -2002,14 +2009,20 @@ def plan_batch(sel: ast.Select, catalog: Catalog, store, epoch: int,
                 raise PlanError("HAVING must be a boolean expression")
         pre_exprs = list(group_bound)
         remapped = []
-        for call, in_expr in zip(binder.agg_calls, binder.agg_inputs):
+        for call, in_expr, flt in zip(binder.agg_calls, binder.agg_inputs,
+                                      binder.agg_filters):
             if in_expr is None:            # count(*)
                 remapped.append(call)
                 continue
             pre_exprs.append(in_expr)      # agg over any expression
-            remapped.append(AggCall(call.kind, len(pre_exprs) - 1,
+            idx, filter_idx = len(pre_exprs) - 1, None
+            if flt is not None:            # agg(DISTINCT x) FILTER
+                pre_exprs.append(flt)
+                filter_idx = len(pre_exprs) - 1
+            remapped.append(AggCall(call.kind, idx,
                                     distinct=call.distinct,
-                                    delimiter=call.delimiter))
+                                    delimiter=call.delimiter,
+                                    filter_idx=filter_idx))
         pre = BatchProject(ex, pre_exprs)
         g = len(group_bound)
         ex = BatchHashAgg(pre, list(range(g)), remapped)

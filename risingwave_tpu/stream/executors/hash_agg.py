@@ -22,6 +22,7 @@ into the kernel (``GroupedAggKernel.rebuild``).
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass
 from typing import (
     AsyncIterator, Dict, List, Optional, Sequence, Tuple,
@@ -42,7 +43,7 @@ from risingwave_tpu.stream.executors.keys import (
     LANES_PER_KEY as _LANES_PER_KEY, KeyCodec,
 )
 from risingwave_tpu.stream.executors.value_multiset import (
-    ValueMultiset, pylist, value_order,
+    DistinctCounts, ValueMultiset, pylist, value_order,
 )
 from risingwave_tpu.stream.message import (
     Barrier, Message, Watermark, is_barrier, is_chunk, is_watermark,
@@ -109,6 +110,14 @@ class AggCall:
     distinct: bool = False
     # string_agg separator (ignored by other kinds)
     delimiter: str = ","
+    # FILTER (WHERE c) of a DISTINCT call, as the call's own: the
+    # BOOLEAN input column that holds c (NULL counts as false). It is
+    # not folded into the distinct argument, so the calls DISTINCT on
+    # one column share that column's dedup table and the filter decides
+    # only whether a row moves this call's count of its (group, value)
+    # pair. A call without DISTINCT carries none: the binder turns its
+    # FILTER into a CASE over its argument.
+    filter_idx: Optional[int] = None
 
     def out_type(self, input_schema: Schema) -> DataType:
         in_t = None if self.input_idx is None \
@@ -190,6 +199,42 @@ def minput_state_schema(input_schema: Schema,
             list(range(g)))
 
 
+def distinct_calls(agg_calls: Sequence[AggCall]) -> Dict[int, List[int]]:
+    """The calls that dedup, by the input column they are DISTINCT on,
+    each list in call order and the columns by their first call."""
+    out: Dict[int, List[int]] = {}
+    for j, c in enumerate(agg_calls):
+        if c.distinct and c.kind in (AggKind.COUNT, AggKind.SUM):
+            out.setdefault(c.input_idx, []).append(j)
+    return out
+
+
+def distinct_state_schema(input_schema: Schema,
+                          group_indices: Sequence[int],
+                          agg_calls: Sequence[AggCall],
+                          calls_on_col: Sequence[int], key_lead: int = 0
+                          ) -> Tuple[Schema, List[int], List[int]]:
+    """Dedup table of ONE distinct input column
+    (aggregation/distinct.rs): rows are (group keys..., value, one
+    count per DISTINCT call on the column, in call order), pk = (group
+    keys in ``state_key_order``, value). Count column ``_cnt<j>`` is how
+    many of the pair's rows passed call ``j``'s filter; a pair whose
+    counts are all 0 is no row. Where none of the column's calls has a
+    filter their counts are one number, and the table is the
+    ``(..., value, _cnt)`` of ``minput_state_schema``: the layout from
+    before a call carried a filter, so a state written then recovers.
+
+    Returns (schema, pk_indices, dist_key_indices)."""
+    first = agg_calls[calls_on_col[0]]
+    sch, pk, dk = minput_state_schema(input_schema, group_indices, first,
+                                      key_lead)
+    if all(agg_calls[j].filter_idx is None for j in calls_on_col):
+        return sch, pk, dk
+    fields = list(sch)[:-1] + [Field(f"_cnt{j}", DataType.INT64)
+                               for j in calls_on_col]
+    return Schema(fields), pk, dk
+
+
 def hll_state_schema(input_schema: Schema,
                      group_indices: Sequence[int], key_lead: int = 0
                      ) -> Tuple[Schema, List[int], List[int]]:
@@ -222,17 +267,17 @@ def agg_aux_tables(input_schema: Schema,
     supply ids. Iteration order is dedup tables first (call order,
     first DISTINCT occurrence per column), then minput tables in call
     order — the planner's sequential-id replay contract (ALTER
-    PARALLELISM re-plans from a recorded id base) depends on it.
+    PARALLELISM re-plans from a recorded id base) depends on it. A
+    column has ONE dedup table however many calls are DISTINCT on it
+    and whatever their filters (``distinct_state_schema``).
 
     Returns (distinct_tables, minput_tables)."""
     distinct_tables: Dict[int, StateTable] = {}
-    for c in agg_calls:
-        if c.distinct and c.input_idx not in distinct_tables:
-            dsch, dpk, ddk = minput_state_schema(
-                input_schema, group_indices, c, key_lead)
-            distinct_tables[c.input_idx] = StateTable(
-                dedup_table_id(c.input_idx), dsch, dpk, store,
-                dist_key_indices=ddk)
+    for col, js in distinct_calls(agg_calls).items():
+        dsch, dpk, ddk = distinct_state_schema(
+            input_schema, group_indices, agg_calls, js, key_lead)
+        distinct_tables[col] = StateTable(
+            dedup_table_id(col), dsch, dpk, store, dist_key_indices=ddk)
     minput_tables: Dict[int, StateTable] = {}
     for j, c in enumerate(agg_calls):
         if c.kind == AggKind.APPROX_COUNT_DISTINCT:
@@ -308,26 +353,42 @@ class HashAggExecutor(Executor):
         # through (a host agg's PREV output reads the multiset as of
         # the last barrier)
         self._minput_pending: Dict[int, Dict[tuple, int]] = {}
-        # DISTINCT dedup (distinct.rs): ONE durable (group, value, cnt)
-        # table + in-memory multiset per distinct INPUT COLUMN —
-        # count(DISTINCT x) and sum(DISTINCT x) share it, like the
+        # DISTINCT dedup (distinct.rs): ONE durable table of (group,
+        # value, counts) rows + its copy in memory per distinct INPUT
+        # COLUMN — count(DISTINCT x) and sum(DISTINCT x) share it, and
+        # so do the calls that differ in their FILTER only, each with a
+        # count of its own (``distinct_state_schema``), like the
         # reference's per-column dedup tables
         self.distinct_tables: Dict[int, StateTable] = dict(
             distinct_tables or {})
-        self._distinct_cols: Dict[int, List[int]] = {}
-        for j, c in enumerate(self.agg_calls):
-            if c.distinct and c.kind in (AggKind.COUNT, AggKind.SUM):
-                self._distinct_cols.setdefault(c.input_idx, []).append(j)
+        self._distinct_cols = distinct_calls(self.agg_calls)
         missing_d = [col for col in self._distinct_cols
                      if col not in self.distinct_tables]
         if missing_d:
             raise ValueError(
                 f"DISTINCT column(s) {missing_d} need dedup state "
                 "tables — pass distinct_tables keyed by input column "
-                "(minput_state_schema shape)")
-        self._distinct_mult: Dict[int, ValueMultiset] = {
-            col: ValueMultiset() for col in self.distinct_tables}
-        self._distinct_pending: Dict[int, Dict[tuple, int]] = {}
+                "(distinct_state_schema shape)")
+        # per column, the filter column of each count of its table (None:
+        # every row counts) and the count each of its calls reads: the
+        # calls of a column none of whose calls has a filter share one
+        self._distinct_filters: Dict[int, List[Optional[int]]] = {}
+        self._distinct_slot: Dict[int, List[int]] = {}
+        for col, js in self._distinct_cols.items():
+            filters = [self.agg_calls[j].filter_idx for j in js]
+            shared = all(f is None for f in filters)
+            self._distinct_filters[col] = [None] if shared else filters
+            self._distinct_slot[col] = [0] * len(js) if shared \
+                else list(range(len(js)))
+        self._distinct_mult: Dict[int, DistinctCounts] = {
+            col: DistinctCounts(len(self._distinct_filters.get(col, [None])))
+            for col in self.distinct_tables}
+        # the label of each dedup table's books (stream_agg_distinct_*)
+        self._distinct_label = {col: f"t{t.table_id}"
+                                for col, t in self.distinct_tables.items()}
+        # per barrier and column: pair → its counts as of the last
+        # barrier, for the pairs a chunk of this epoch moved
+        self._distinct_pending: Dict[int, Dict[tuple, tuple]] = {}
         # incremental live-group count (gates interner GC cheaply)
         self._live_groups = 0
         # host-state accounting (memory_manager.rs analog)
@@ -654,10 +715,10 @@ class HashAggExecutor(Executor):
             self._apply_minput(chunk, key_lanes, signs, vis)
         for col, js in self._distinct_cols.items():
             _in_lanes0, ok0 = inputs[js[0]]
-            mask = self._apply_distinct(col, chunk, key_lanes, signs,
-                                        vis & ok0)
-            for j in js:
-                inputs[j] = (inputs[j][0], mask)
+            masks = self._apply_distinct(col, chunk, key_lanes, signs,
+                                         vis & ok0)
+            for j, slot in zip(js, self._distinct_slot[col]):
+                inputs[j] = (inputs[j][0], masks[slot])
         self.kernel.apply(key_lanes, signs, vis, tuple(inputs))
         for j in self._hll_calls:
             self._apply_hll(j, chunk, key_lanes, signs, vis)
@@ -803,55 +864,90 @@ class HashAggExecutor(Executor):
             for key, d in zip(keys_of(us), deltas[us].tolist()):
                 pend[key] = pend.get(key, 0) + d
 
+    @staged("agg.distinct")
     def _apply_distinct(self, col: int, chunk: StreamChunk,
                         key_lanes: np.ndarray, signs: np.ndarray,
-                        ok: np.ndarray) -> np.ndarray:
-        """DISTINCT gating (aggregation/distinct.rs): per (group, value)
-        multiset — the device kernel sees ONE representative row only
-        when the value's multiplicity crosses zero, with the chunk sign
-        matching the crossing direction. Returns the call's new valid
-        mask."""
-        new_ok = np.zeros(chunk.capacity, dtype=bool)
+                        ok: np.ndarray) -> List[np.ndarray]:
+        """DISTINCT gating (aggregation/distinct.rs), once per distinct
+        column and chunk. For a row ``(op, g, v)`` with ``v`` not NULL
+        and a count ``s`` of the column's table with filter ``f_s``
+        (``true`` where the call has none): if ``f_s(row)`` then
+        ``cnt_s[g, v] += sign(op)``; the row is visible to the calls
+        that read count ``s`` iff ``cnt_s[g, v]`` crossed between 0 and
+        1 in the direction of ``op``. Over a chunk that is one
+        representative row of the matching sign per pair whose count
+        crossed zero by the chunk's net; a pair whose counts are all 0
+        leaves the table, a negative count is an error. Returns the
+        valid mask per count; ``_distinct_slot`` says which a call
+        takes."""
+        filters = self._distinct_filters[col]
+        masks = [np.zeros(chunk.capacity, dtype=bool) for _ in filters]
         ms = self._multiset_groups(chunk, key_lanes, signs, ok, col)
         if ms is None:
-            return new_ok
-        rows, deltas, keys_of, order, starts = ms
-        mult = self._distinct_mult[col]
-        pend = self._distinct_pending.setdefault(col, {})
-        ends = np.append(starts[1:], len(order))
-        us = np.flatnonzero(deltas != 0)
-        for u, key, d in zip(us.tolist(), keys_of(us),
-                             deltas[us].tolist()):
-            old = mult.count(*key)
-            new = old + d
-            if new < 0:
-                raise ValueError(
-                    f"distinct retract below zero for {key}")
-            mult.put(*key, new)
-            pend[key] = pend.get(key, 0) + d
-            eff = (1 if new > 0 else 0) - (1 if old > 0 else 0)
-            if eff == 0:
+            return masks
+        rows, net, keys_of, order, starts = ms
+        srt = rows[order]          # chunk rows, a pair's rows together
+        sg = signs[srt].astype(np.int64)
+        passes: List[Optional[np.ndarray]] = []     # None: every row
+        for f in filters:
+            if f is None:
+                passes.append(None)
                 continue
-            # representative row with the matching sign (exists: the
-            # net delta moved in that direction)
-            cand = rows[order[starts[u]:ends[u]]]
-            match = cand[signs[cand] == eff]
-            new_ok[int(match[0])] = True
-        return new_ok
+            c = chunk.columns[f]
+            p = np.asarray(c.values).astype(bool)
+            if c.validity is not None:
+                p = p & np.asarray(c.validity)
+            passes.append(p[srt])
+        deltas = np.stack(
+            [net if p is None
+             else np.add.reduceat(np.where(p, sg, 0), starts)
+             for p in passes], axis=1)
+        us = np.flatnonzero(deltas.any(axis=1))
+        if not len(us):
+            return masks
+        keys = keys_of(us)
+        mult = self._distinct_mult[col]
+        old = np.asarray([mult.count(g, v) for g, v in keys],
+                         dtype=np.int64)
+        new = old + deltas[us]
+        if (new < 0).any():
+            bad = int(np.flatnonzero((new < 0).any(axis=1))[0])
+            raise ValueError(
+                f"distinct retract below zero for {keys[bad]}")
+        pend = self._distinct_pending.setdefault(col, {})
+        for (g, v), o, n in zip(keys, old.tolist(), new.tolist()):
+            pend.setdefault((g, v), tuple(o))
+            mult.put(g, v, tuple(n))
+        eff = (new > 0).astype(np.int8) - (old > 0)
+        pos = np.arange(len(srt))
+        for mask, p, e in zip(masks, passes, eff.T):
+            for direction in (1, -1):
+                hit = us[e == direction]
+                if len(hit):
+                    # a row of the pair that passed the filter with the
+                    # crossing's sign (there is one: the net of such
+                    # rows moved the count that way)
+                    cand = sg == direction
+                    if p is not None:
+                        cand &= p
+                    first = np.minimum.reduceat(
+                        np.where(cand, pos, len(srt)), starts)[hit]
+                    mask[srt[first]] = True
+        _METRICS.agg_distinct_crossings.inc(
+            float(sum(int(m.sum()) for m in masks)),
+            table=self._distinct_label[col])
+        return masks
 
     @staticmethod
     @staged("agg.persist")
     def _write_multiset_pending(pending: Dict[int, Dict[tuple, int]],
                                 tables: Dict[int, StateTable],
-                                mults: Dict[int, ValueMultiset],
-                                at_ingest: bool) -> None:
+                                mults: Dict[int, ValueMultiset]) -> None:
         """Write one barrier's net multiset deltas through to the
         StateTables: new pairs, pairs whose count changed and pairs
         that reached zero, one batch call each. No row is read: the
-        old count is the in-memory multiset's, which holds the deltas
-        already where the ingest applied them (``at_ingest``: DISTINCT
-        gates its rows on the live counts) and takes them here where
-        it did not (a recompute after this then sees them)."""
+        old count is the in-memory multiset's, which takes the deltas
+        here (a recompute after this then sees them)."""
         for j, table in tables.items():
             deltas, mult = pending.get(j, {}), mults[j]
             ins: List[tuple] = []
@@ -861,8 +957,8 @@ class HashAggExecutor(Executor):
             for (group, value), d in deltas.items():
                 if d == 0:
                     continue
-                cnt = mult.count(group, value)
-                old, new = (cnt - d, cnt) if at_ingest else (cnt, cnt + d)
+                old = mult.count(group, value)
+                new = old + d
                 key = group + (value,)
                 if old == 0:
                     assert new > 0, f"retract of unseen value {key}"
@@ -878,6 +974,52 @@ class HashAggExecutor(Executor):
             table.delete_rows(dels)
             _MULTISET_WRITTEN.inc(len(ins) + len(upd_old) + len(dels))
         pending.clear()
+
+    @staged("agg.persist")
+    def _write_distinct_pending(self) -> None:
+        """Write the pairs this barrier's chunks moved through to the
+        dedup tables, one row a pair with all its counts: new pairs,
+        pairs of which a count changed and pairs whose counts all
+        reached zero, one batch call each. No row is read: a pair's old
+        row is what ``_apply_distinct`` found in memory at its first
+        touch this epoch, its new one what memory holds now. The
+        seconds are filed by table besides the stage they fall in
+        (``stream_agg_distinct_seconds``): the table's own writes
+        (``state.write`` nested here) and the rest of the pass."""
+        for col, table in self.distinct_tables.items():
+            t0 = time.perf_counter()
+            label = self._distinct_label[col]
+            mult = self._distinct_mult[col]
+            zero = mult.zero
+            ins: List[tuple] = []
+            upd_old: List[tuple] = []
+            upd_new: List[tuple] = []
+            dels: List[tuple] = []
+            for (group, value), old in self._distinct_pending.pop(
+                    col, {}).items():
+                new = mult.count(group, value)
+                if new == old:
+                    continue
+                key = group + (value,)
+                if old == zero:
+                    ins.append(key + new)
+                elif new == zero:
+                    dels.append(key + old)
+                else:
+                    upd_old.append(key + old)
+                    upd_new.append(key + new)
+            t1 = time.perf_counter()
+            table.insert_rows(ins)
+            table.update_rows(upd_old, upd_new)
+            table.delete_rows(dels)
+            t2 = time.perf_counter()
+            changed = len(ins) + len(upd_old) + len(dels)
+            _MULTISET_WRITTEN.inc(changed)
+            _METRICS.agg_distinct_changed.inc(float(changed), table=label)
+            _METRICS.agg_distinct_seconds.inc(t1 - t0, table=label,
+                                              stage="persist")
+            _METRICS.agg_distinct_seconds.inc(t2 - t1, table=label,
+                                              stage="write")
 
     # -- cold tier (state/tier.py) ---------------------------------------
     def _tier_register(self) -> None:
@@ -1147,12 +1289,9 @@ class HashAggExecutor(Executor):
             host_prev = self._host_agg_outputs(fr, gk)
         if self.minput:
             self._write_multiset_pending(
-                self._minput_pending, self.minput, self._minput_mult,
-                at_ingest=False)
-        if self._distinct_pending:
-            self._write_multiset_pending(
-                self._distinct_pending, self.distinct_tables,
-                self._distinct_mult, at_ingest=True)
+                self._minput_pending, self.minput, self._minput_mult)
+        if self.distinct_tables:
+            self._write_distinct_pending()
         if fr.n == 0:
             self._deleted_lanes.clear()
             self.kernel.advance()
@@ -1462,6 +1601,11 @@ class HashAggExecutor(Executor):
                         f"agg.{self._books_table}", self.identity,
                         getattr(self.kernel, "take_probe_rounds", None))
                     self._clean_state()
+                    # resident pairs at the seal, behind the clean
+                    for col, mult in self._distinct_mult.items():
+                        _METRICS.agg_distinct_pairs.set(
+                            float(len(mult)),
+                            table=self._distinct_label[col])
                     self._maybe_gc_interner()
                     self.table.commit(msg.epoch)
                     for t in self.minput.values():
@@ -1500,5 +1644,7 @@ class HashAggExecutor(Executor):
             # executor teardown: release this identity's gauge series
             _METRICS.agg_dirty_groups.remove(executor=self.identity)
             _METRICS.agg_table_capacity.remove(executor=self.identity)
+            for label in self._distinct_label.values():
+                _METRICS.agg_distinct_pairs.remove(table=label)
             if self._tier_part is not None:
                 self._tier.unregister(self._tier_part)

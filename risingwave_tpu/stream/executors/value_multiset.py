@@ -8,6 +8,9 @@ rows (``hash_agg.minput_state_schema``); its only writer is the
 executor, so the executor keeps what it wrote: ``ValueMultiset`` is
 that copy, ``group → {value → count}``. The table is written through
 once a barrier and read only to fill this (recovery, cold-tier reload).
+A DISTINCT column's table may hold a count per call
+(``hash_agg.distinct_state_schema``); ``DistinctCounts`` is its copy,
+``group → {value → counts}``.
 
 Groups and values are the Python objects of the table's rows. A float
 NaN is one SQL value and one key of the table, but no two NaN objects
@@ -46,6 +49,7 @@ class ValueMultiset:
     is not zero."""
 
     __slots__ = ("_groups", "_pairs")
+    zero = 0        # what an absent pair counts
 
     def __init__(self) -> None:
         self._groups: Dict[tuple, Dict[object, int]] = {}
@@ -55,14 +59,15 @@ class ValueMultiset:
         """(group, value) pairs held: what the memory manager sizes."""
         return self._pairs
 
-    def count(self, group: tuple, value) -> int:
+    def count(self, group: tuple, value):
         vals = self._groups.get(group)
-        return 0 if vals is None else vals.get(value, 0)
+        return self.zero if vals is None \
+            else vals.get(value, self.zero)
 
-    def put(self, group: tuple, value, cnt: int) -> None:
+    def put(self, group: tuple, value, cnt) -> None:
         vals = self._groups.get(group)
-        if cnt == 0:
-            if vals is not None and vals.pop(value, 0) != 0:
+        if cnt == self.zero:
+            if vals is not None and vals.pop(value, None) is not None:
                 self._pairs -= 1
                 if not vals:
                     del self._groups[group]
@@ -100,3 +105,31 @@ class ValueMultiset:
         for g, vals in self._groups.items():
             for v, cnt in vals.items():
                 yield g + (v, cnt)
+
+
+class DistinctCounts(ValueMultiset):
+    """The dedup state of one DISTINCT input column
+    (aggregation/distinct.rs): ``group → {value → counts}``, one count
+    per count column of the column's dedup table
+    (``hash_agg.distinct_state_schema``), as a tuple. A pair is present
+    while any of its counts is not zero."""
+
+    __slots__ = ("width", "zero")
+
+    def __init__(self, width: int = 1) -> None:
+        super().__init__()
+        self.width = width
+        self.zero = (0,) * width
+
+    def load(self, rows: Iterable[tuple]) -> None:
+        """Fill from the table's rows ``(group keys..., value,
+        counts...)``."""
+        w = self.width
+        for row in rows:
+            key = tuple(_NAN if v != v else v for v in row[:-w])
+            self.put(key[:-1], key[-1], tuple(int(c) for c in row[-w:]))
+
+    def rows(self) -> Iterator[tuple]:
+        for g, vals in self._groups.items():
+            for v, counts in vals.items():
+                yield g + (v,) + counts

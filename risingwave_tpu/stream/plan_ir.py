@@ -30,7 +30,7 @@ Node shapes (dicts, `op` discriminated):
                                         # kernel's jitted apply
   {"op": "row_id_gen", "input": N}
   {"op": "hash_agg", "input": N, "group": [...],
-   "calls": [{"kind","input_idx","distinct","delimiter"}],
+   "calls": [{"kind","input_idx","distinct","delimiter","filter_idx"}],
    "table_id": n, "append_only": bool, "output_names": [...],
    "dedup_table_ids": {input_idx: n},   # required per DISTINCT column
    "minput_table_ids": {call_idx: n}}   # required per retractable
@@ -465,7 +465,8 @@ def build_fragment(nodes: List[dict], store, local,
             calls = [AggCall(AggKind(c["kind"]),
                              c.get("input_idx"),
                              distinct=bool(c.get("distinct", False)),
-                             delimiter=c.get("delimiter", ","))
+                             delimiter=c.get("delimiter", ","),
+                             filter_idx=c.get("filter_idx"))
                      for c in node["calls"]]
             group = list(node["group"])
             # a fused agg's index space is the absorbed run's OUTPUT

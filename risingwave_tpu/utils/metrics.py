@@ -296,6 +296,27 @@ class StreamingMetrics:
             "from one outside recovery and cold-tier reload; "
             "extreme_scans / values_scanned: groups whose MIN/MAX was "
             "recomputed after a retraction, and the values looked at)")
+        self.agg_distinct_pairs = r.gauge(
+            "stream_agg_distinct_pairs",
+            "(group, value) pairs resident in a DISTINCT column's "
+            "dedup state at the barrier's seal, by dedup table "
+            "(t<state table id>): the rows of that table")
+        self.agg_distinct_changed = r.counter(
+            "stream_agg_distinct_changed",
+            "pairs of which a barrier changed any count: the rows its "
+            "write-through inserted, updated and deleted in the dedup "
+            "table, by dedup table")
+        self.agg_distinct_crossings = r.counter(
+            "stream_agg_distinct_crossings",
+            "rows the DISTINCT gating made visible to some call (a "
+            "count crossed between 0 and 1): what the device kernel "
+            "is shown of the DISTINCT calls, by dedup table")
+        self.agg_distinct_seconds = r.counter(
+            "stream_agg_distinct_seconds",
+            "seconds of a dedup table's write-through at the barrier, "
+            "by dedup table and stage (write: its StateTable batch "
+            "calls, the state.write stages nested there; persist: the "
+            "rest of the pass, its part of agg.persist)")
         self.state_cleaned_rows = r.counter(
             "stream_state_cleaned_rows",
             "rows a watermark's range delete took out of a state "
@@ -852,7 +873,9 @@ class MetricsHistory:
         """(series name, value now, kind) of what the executors count
         by the batch: rows into an aggregate by op, the join ->
         aggregate hand-off, rows into and out of a join and through
-        its condition, rows through a HOP, what a watermark cleaned out
+        its condition, rows through a HOP, the pairs a DISTINCT column's
+        dedup state holds, changed and made visible and the seconds of
+        its write-through, what a watermark cleaned out
         of the state tables and the rows they keep, the rows a
         watermark filter dropped, the longest chain a join's
         probe walked, the key skew of a staged batch, the rounds of
@@ -878,6 +901,14 @@ class MetricsHistory:
                 ("join_condition.{table}.seconds",
                  S.join_condition_seconds, "counter"),
                 ("hop_rows.{table}.{dir}", S.hop_rows, "counter"),
+                ("agg_distinct.{table}.pairs", S.agg_distinct_pairs,
+                 "gauge"),
+                ("agg_distinct.{table}.changed", S.agg_distinct_changed,
+                 "counter"),
+                ("agg_distinct.{table}.crossings",
+                 S.agg_distinct_crossings, "counter"),
+                ("agg_distinct.{table}.{stage}_s",
+                 S.agg_distinct_seconds, "counter"),
                 ("state_clean.{table}.cleaned", S.state_cleaned_rows,
                  "counter"),
                 ("state_clean.{table}.reads", S.state_clean_reads,

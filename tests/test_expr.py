@@ -240,3 +240,41 @@ def test_scalar_function_library_semantics():
                         DataType.VARCHAR))[0] == "2015-07-15"
     ep = run(FuncCall("extract_epoch", [tref], DataType.DECIMAL))[0]
     assert int(ep) == 1_436_918_400 * 10_000   # scaled decimal seconds
+
+
+@pytest.mark.parametrize("fmt,strf", [
+    ("YYYY-MM-DD", "%Y-%m-%d"), ("HH24:MI", "%H:%M"),
+    ("YYYY-MM-DD HH24:MI:SS", "%Y-%m-%d %H:%M:%S"),
+    ("DD/MM HH24", "%d/%m %H"), ("day", "day")])
+def test_to_char_formats_a_row_as_strftime_does(fmt, strf):
+    """`to_char` gives the text a strftime of the row gives, row for
+    row (q15's GROUP BY key, ISSUE 41): rows on both sides of a minute,
+    an hour, a day and the epoch; NULLs stay NULL. It formats once a
+    row today; whatever formats fewer times has to pass this."""
+    import datetime
+
+    import numpy as np
+
+    from risingwave_tpu.common.chunk import DataChunk
+    from risingwave_tpu.common.types import DataType, Schema
+    from risingwave_tpu.expr.expr import FuncCall, InputRef, lit
+
+    base = 1_436_918_400_000_000             # 2015-07-15 00:00:00
+    ts = [base - 1, base, base + 59_999_999, base + 60_000_000,
+          base + 3_599_999_999, base + 3_600_000_000,
+          base + 86_399_999_999, base + 86_400_000_000, -1, 0, 1, None,
+          base + 1_500_000]
+    chunk = DataChunk.from_pydict(Schema.of(ts=DataType.TIMESTAMP),
+                                  {"ts": ts})
+    col = FuncCall("to_char", [InputRef(0, DataType.TIMESTAMP),
+                               lit(fmt, DataType.VARCHAR)],
+                   DataType.VARCHAR).eval(chunk)
+    epoch = datetime.datetime(1970, 1, 1, tzinfo=datetime.timezone.utc)
+    ok = np.ones(len(ts), dtype=bool) if col.validity is None \
+        else np.asarray(col.validity)[:len(ts)]
+    for i, v in enumerate(ts):
+        if v is None:
+            assert not ok[i]
+        else:
+            assert ok[i] and np.asarray(col.values)[i] == (
+                epoch + datetime.timedelta(microseconds=v)).strftime(strf)

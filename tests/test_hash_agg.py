@@ -780,7 +780,7 @@ class TableLoopAgg(HashAggExecutor):
     its table, a point read a changed count and a prefix scan a group."""
 
     @staticmethod
-    def _write_multiset_pending(pending, tables, mults, at_ingest):
+    def _write_multiset_pending(pending, tables, mults):
         for j, deltas in pending.items():
             table = tables[j]
             for (group, value), d in deltas.items():
@@ -798,6 +798,23 @@ class TableLoopAgg(HashAggExecutor):
                 else:
                     table.update(cur, row)
         pending.clear()
+
+    def _write_distinct_pending(self):
+        """A dedup table's old row is read back, a point read a moved
+        pair; its new counts are the gating's."""
+        for col, table in self.distinct_tables.items():
+            mult = self._distinct_mult[col]
+            for group, value in self._distinct_pending.pop(col, {}):
+                key = group + (value,)
+                cur = table.get_row(key)
+                new = mult.count(group, value)
+                if cur is None:
+                    assert any(new), f"retract of unseen value {key}"
+                    table.insert(key + new)
+                elif not any(new):
+                    table.delete(cur)
+                elif tuple(cur) != key + new:
+                    table.update(cur, key + new)
 
     @staticmethod
     def _group(gk, r):

@@ -414,6 +414,19 @@ def test_build_fragment_dynamic_filter_and_dedup():
     assert type(consumer.input).__name__ == "DynamicFilterExecutor"
 
 
+def _find_agg(ex):
+    """The first HashAggExecutor down an executor tree."""
+    from risingwave_tpu.stream.executor import executor_children
+    from risingwave_tpu.stream.executors.hash_agg import HashAggExecutor
+    if isinstance(ex, HashAggExecutor):
+        return ex
+    for _a, _i, child in executor_children(ex):
+        got = _find_agg(child)
+        if got is not None:
+            return got
+    return None
+
+
 def test_fragmenter_ships_hll_sketch_tables():
     """approx_count_distinct's sketch tables ride minput_table_ids
     through the fragmenter (the executor popped them out of minput at
@@ -428,8 +441,6 @@ def test_fragmenter_ships_hll_sketch_tables():
     from risingwave_tpu.state.store import MemoryStateStore
     from risingwave_tpu.stream.actor import LocalBarrierManager
     from risingwave_tpu.stream.exchange import channel_for_test
-    from risingwave_tpu.stream.executor import executor_children
-    from risingwave_tpu.stream.executors.hash_agg import HashAggExecutor
 
     opts = {"connector": "nexmark", "nexmark.table.type": "bid",
             "nexmark.event.num": "1000"}
@@ -451,15 +462,51 @@ def test_fragmenter_ships_hll_sketch_tables():
         graph.fragments[-1].nodes, MemoryStateStore(),
         LocalBarrierManager(), channel_for_test)
 
-    def find_agg(ex):
-        if isinstance(ex, HashAggExecutor):
-            return ex
-        for _a, _i, child in executor_children(ex):
-            got = find_agg(child)
-            if got is not None:
-                return got
-        return None
-
-    agg = find_agg(consumer)
+    agg = _find_agg(consumer)
     assert agg is not None
     assert set(agg.hll_tables) == {0}
+
+
+def test_fragmenter_ships_a_distinct_call_s_filter():
+    """`count(DISTINCT x) FILTER (WHERE c)` through the IR (ISSUE 41):
+    the call's `filter_idx` is shipped, so the worker rebuilds ONE dedup
+    table for the column with a count per call, under the shipped id."""
+    from risingwave_tpu.frontend.catalog import Catalog
+    from risingwave_tpu.frontend.fragmenter import Fragmenter
+    from risingwave_tpu.frontend.parser import parse_many
+    from risingwave_tpu.frontend.planner import (
+        StreamPlanner, source_schema,
+    )
+    from risingwave_tpu.state.store import MemoryStateStore
+    from risingwave_tpu.stream.actor import LocalBarrierManager
+    from risingwave_tpu.stream.exchange import channel_for_test
+
+    opts = {"connector": "nexmark", "nexmark.table.type": "bid",
+            "nexmark.event.num": "1000"}
+    catalog = Catalog()
+    catalog.add_source("bid", source_schema(opts, None), opts)
+    [(_text, stmt)] = parse_many(
+        "CREATE MATERIALIZED VIEW v AS SELECT auction, "
+        "count(DISTINCT bidder) AS d, "
+        "count(DISTINCT bidder) FILTER (WHERE price < 10000) AS d1, "
+        "sum(DISTINCT bidder) FILTER (WHERE price >= 10000) AS s2 "
+        "FROM bid GROUP BY auction")
+    planner = StreamPlanner(catalog, MemoryStateStore(),
+                            LocalBarrierManager(), definition="")
+    plan = planner.plan("v", stmt.select, 7, rate_limit=4)
+    graph = Fragmenter(1).lower(plan.consumer)
+    agg_node = next(n for f in graph.fragments for n in f.nodes
+                    if n["op"] == "hash_agg")
+    filters = [c.get("filter_idx") for c in agg_node["calls"]]
+    assert filters[0] is None and None not in filters[1:]
+    assert len(agg_node["dedup_table_ids"]) == 1
+    _src, consumer = build_fragment(
+        graph.fragments[-1].nodes, MemoryStateStore(),
+        LocalBarrierManager(), channel_for_test)
+
+    agg = _find_agg(consumer)
+    (col, table), = agg.distinct_tables.items()
+    assert table.table_id == list(agg_node["dedup_table_ids"].values())[0]
+    assert [f.name for f in table.schema][-3:] == \
+        ["_cnt0", "_cnt1", "_cnt2"]
+    assert [c.filter_idx for c in agg.agg_calls] == filters

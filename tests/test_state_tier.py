@@ -244,7 +244,9 @@ def test_agg_multiset_leaves_with_its_group_and_comes_back():
         held = {} if g in cold else {g: 1, g + 100: 1}
         if g not in cold and g >= n // 2:
             held[7] = 1
-        assert seen["max"][g] == held and seen["sum"][g] == held
+        assert seen["max"][g] == held
+        # a dedup pair holds a count per count column of its table
+        assert seen["sum"][g] == {v: (c,) for v, c in held.items()}
     # the aux tables kept every group's rows
     assert 20 in cold and _prefix_rows(capped.minput[0], 20) \
         == [(20, 20, 1), (20, 120, 1)]

@@ -369,6 +369,186 @@ def test_a_configuration_without_a_watermark_plans_as_before(name):
                         for lead, pk, written in keys)
 
 
+# -- the six accepted configurations plan as they did (ISSUE 41) ----------
+
+# `walk_executors` of each deployed view and, per aggregate, its calls
+# (kind, input column, DISTINCT) and the id, columns and key of its value
+# state table and of every materialized-input and dedup table, taken on
+# the commit before an `AggCall` could carry a filter and a dedup table
+# more than one count (2ecb2be). None of the six has a DISTINCT or a
+# filtered call, so nothing of that may show in their plans.
+GOLDEN_SIX = {
+    "nexmark-q7": {
+        "chain": [
+            "/ MaterializeExecutor",
+            "/input ProjectExecutor",
+            "/input/input HashJoinExecutor(inner, actor=1000)[fused:L:ProjectExecutor→RowIdGenExecutor; R:ProjectExecutor→join]",
+            "/input/input/left_in SourceExecutor",
+            "/input/input/right_in HashAggExecutor(actor=0)[fused:ProjectExecutor]",
+            "/input/input/right_in/input SourceExecutor",
+        ],
+        "aggs": [
+            {"calls": [['max', 1, False]],
+             "state": [6, ['_g0:TIMESTAMP', '_group_rows:INT64', '_acc0:INT64', '_acc1:INT64'], [0]],
+             "minput": {}, "distinct": {}},
+        ]},
+    "nexmark-q8": {
+        "chain": [
+            "/ MaterializeExecutor",
+            "/input ProjectExecutor",
+            "/input/input HashJoinExecutor(inner, actor=1000)[fused:L:ProjectExecutor; R:ProjectExecutor→join]",
+            "/input/input/left_in HashAggExecutor(actor=0)",
+            "/input/input/left_in/input CoalesceExecutor",
+            "/input/input/left_in/input/input ProjectExecutor",
+            "/input/input/left_in/input/input/input SourceExecutor",
+            "/input/input/right_in HashAggExecutor(actor=0)[fused:ProjectExecutor]",
+            "/input/input/right_in/input SourceExecutor",
+        ],
+        "aggs": [
+            {"calls": [],
+             "state": [5, ['_g0:INT64', '_g1:VARCHAR', '_g2:TIMESTAMP', '_group_rows:INT64'], [0, 1, 2]],
+             "minput": {}, "distinct": {}},
+            {"calls": [],
+             "state": [8, ['_g0:INT64', '_g1:TIMESTAMP', '_group_rows:INT64'], [0, 1]],
+             "minput": {}, "distinct": {}},
+        ]},
+    "nexmark-q8-mesh4": {
+        "chain": [
+            "/ MaterializeExecutor",
+            "/input ProjectExecutor",
+            "/input/input HashJoinExecutor(inner, actor=1000)[fused:L:ProjectExecutor; R:ProjectExecutor→join]",
+            "/input/input/left_in HashAggExecutor(actor=0)",
+            "/input/input/left_in/input CoalesceExecutor",
+            "/input/input/left_in/input/input ProjectExecutor",
+            "/input/input/left_in/input/input/input SourceExecutor",
+            "/input/input/right_in HashAggExecutor(actor=0)[fused:ProjectExecutor]",
+            "/input/input/right_in/input SourceExecutor",
+        ],
+        "aggs": [
+            {"calls": [],
+             "state": [5, ['_g0:INT64', '_g1:VARCHAR', '_g2:TIMESTAMP', '_group_rows:INT64'], [0, 1, 2]],
+             "minput": {}, "distinct": {}},
+            {"calls": [],
+             "state": [8, ['_g0:INT64', '_g1:TIMESTAMP', '_group_rows:INT64'], [0, 1]],
+             "minput": {}, "distinct": {}},
+        ]},
+    "nexmark-q4": {
+        "chain": [
+            "/ MaterializeExecutor",
+            "/input ProjectExecutor",
+            "/input/input HashAggExecutor(actor=0)[fused:ProjectExecutor]",
+            "/input/input/input HashAggExecutor(actor=0)[fused:FilterExecutor→FilterExecutor→ProjectExecutor]",
+            "/input/input/input/input HashJoinExecutor(inner, actor=1000)[fused:L:ProjectExecutor→RowIdGenExecutor; R:ProjectExecutor→RowIdGenExecutor→join]",
+            "/input/input/input/input/left_in SourceExecutor",
+            "/input/input/input/input/right_in SourceExecutor",
+        ],
+        "aggs": [
+            {"calls": [['sum', 1, False], ['count', 1, False]],
+             "state": [10, ['_g0:INT64', '_group_rows:INT64', '_acc0:INT64', '_acc1:INT64', '_acc2:INT64'], [0]],
+             "minput": {}, "distinct": {}},
+            {"calls": [['max', 2, False]],
+             "state": [9, ['_g0:INT64', '_g1:INT64', '_group_rows:INT64', '_acc0:INT64', '_acc1:INT64'], [0, 1]],
+             "minput": {}, "distinct": {}},
+        ]},
+    "nexmark-q5": {
+        "chain": [
+            "/ MaterializeExecutor",
+            "/input FusedFragmentExecutor[FilterExecutor→ProjectExecutor]",
+            "/input/input HashJoinExecutor(inner, actor=1000)[fused:L:ProjectExecutor; R:ProjectExecutor→join]",
+            "/input/input/left_in HashAggExecutor(actor=0)[fused:HopWindowExecutor→ProjectExecutor]",
+            "/input/input/left_in/input SourceExecutor",
+            "/input/input/right_in HashAggExecutor(actor=0)",
+            "/input/input/right_in/input CoalesceExecutor",
+            "/input/input/right_in/input/input ProjectExecutor",
+            "/input/input/right_in/input/input/input HashAggExecutor(actor=0)[fused:HopWindowExecutor→ProjectExecutor]",
+            "/input/input/right_in/input/input/input/input SourceExecutor",
+        ],
+        "aggs": [
+            {"calls": [['count', None, False]],
+             "state": [4, ['_g0:TIMESTAMP', '_g1:INT64', '_group_rows:INT64', '_acc0:INT64'], [0, 1]],
+             "minput": {}, "distinct": {}},
+            {"calls": [['max', 1, False]],
+             "state": [8, ['_g0:TIMESTAMP', '_group_rows:INT64', '_acc0:INT64', '_acc1:INT64'], [0]],
+             "minput": {'0': [9, ['_g0:TIMESTAMP', '_value:INT64', '_cnt:INT64'], [0, 1]]}, "distinct": {}},
+            {"calls": [['count', None, False]],
+             "state": [7, ['_g0:INT64', '_g1:TIMESTAMP', '_group_rows:INT64', '_acc0:INT64'], [0, 1]],
+             "minput": {}, "distinct": {}},
+        ]},
+    "nexmark-q5-wm": {
+        "chain": [
+            "/ MaterializeExecutor",
+            "/input FusedFragmentExecutor[FilterExecutor→ProjectExecutor]",
+            "/input/input HashJoinExecutor(inner, actor=1000)[fused:L:ProjectExecutor; R:ProjectExecutor→join]",
+            "/input/input/left_in HashAggExecutor(actor=0)[fused:HopWindowExecutor→ProjectExecutor]",
+            "/input/input/left_in/input WatermarkFilterExecutor",
+            "/input/input/left_in/input/input SourceExecutor",
+            "/input/input/right_in HashAggExecutor(actor=0)",
+            "/input/input/right_in/input CoalesceExecutor",
+            "/input/input/right_in/input/input ProjectExecutor",
+            "/input/input/right_in/input/input/input HashAggExecutor(actor=0)[fused:HopWindowExecutor→ProjectExecutor]",
+            "/input/input/right_in/input/input/input/input WatermarkFilterExecutor",
+            "/input/input/right_in/input/input/input/input/input SourceExecutor",
+        ],
+        "aggs": [
+            {"calls": [['count', None, False]],
+             "state": [5, ['_g0:TIMESTAMP', '_g1:INT64', '_group_rows:INT64', '_acc0:INT64'], [0, 1]],
+             "minput": {}, "distinct": {}},
+            {"calls": [['max', 1, False]],
+             "state": [10, ['_g0:TIMESTAMP', '_group_rows:INT64', '_acc0:INT64', '_acc1:INT64'], [0]],
+             "minput": {'0': [11, ['_g0:TIMESTAMP', '_value:INT64', '_cnt:INT64'], [0, 1]]}, "distinct": {}},
+            {"calls": [['count', None, False]],
+             "state": [9, ['_g0:INT64', '_g1:TIMESTAMP', '_group_rows:INT64', '_acc0:INT64'], [1, 0]],
+             "minput": {}, "distinct": {}},
+        ]},
+}
+
+
+@pytest.mark.parametrize("name", list(GOLDEN_SIX))
+def test_an_accepted_configuration_plans_and_keeps_state_as_before(name):
+    for path in (BENCH, os.path.join(BENCH, "reference")):
+        if path not in sys.path:
+            sys.path.insert(0, path)
+    import run
+    from risingwave_tpu.frontend.session import Frontend
+    from risingwave_tpu.stream.executors.hash_agg import HashAggExecutor
+    with open(os.path.join(BENCH, "configs", name + ".json")) as f:
+        config = json.load(f)
+
+    def table(t):
+        return [t.table_id,
+                [f"{f.name}:{f.data_type.name}" for f in t.schema],
+                list(t.pk_indices)]
+
+    async def deploy():
+        par = PARALLELISM.get(name, 1)
+        fe = Frontend(parallelism=par) if par > 1 else Frontend()
+        try:
+            for stmt in config["sets"]:
+                await fe.execute(stmt)
+            for ddl in config["ddl"]:
+                await fe.execute(ddl.format(seed=7))
+            actor = fe.actors[fe.catalog.mvs[config["view"]].actor_id]
+            chain, aggs = [], []
+            for path, ex in run.walk_executors(actor.consumer):
+                ex = getattr(ex, "inner", ex)
+                chain.append(f"{path or '/'} {ex.identity}")
+                if isinstance(ex, HashAggExecutor):
+                    assert all(c.filter_idx is None for c in ex.agg_calls)
+                    aggs.append({
+                        "calls": [[c.kind.value, c.input_idx, c.distinct]
+                                  for c in ex.agg_calls],
+                        "state": table(ex.table),
+                        "minput": {str(j): table(t)
+                                   for j, t in ex.minput.items()},
+                        "distinct": {str(j): table(t) for j, t
+                                     in ex.distinct_tables.items()}})
+            return {"chain": chain, "aggs": aggs}
+        finally:
+            await fe.close()
+
+    assert asyncio.run(deploy()) == GOLDEN_SIX[name]
+
+
 # -- the rung of the cleaning paths' batches ------------------------------
 
 def test_a_batch_rung_never_steps_down_and_pages_above_its_top():
