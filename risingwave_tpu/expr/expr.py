@@ -18,6 +18,8 @@ TPU-first notes:
 
 from __future__ import annotations
 
+import datetime
+import functools
 from typing import Callable, Dict, Optional, Sequence
 
 import jax.numpy as jnp
@@ -81,7 +83,6 @@ def _promote_comparison(lt: DataType, rt: DataType) -> DataType:
 
 
 def _parse_timestamp_us(s: str) -> int:
-    import datetime
     s = s.strip().replace("T", " ")
     dt = datetime.datetime.fromisoformat(s)
     if dt.tzinfo is not None:
@@ -105,11 +106,9 @@ def _cast_one_string(v, dst: DataType):
     if dst in (DataType.TIMESTAMP, DataType.TIMESTAMPTZ):
         return _parse_timestamp_us(v)
     if dst == DataType.DATE:
-        import datetime
         return (datetime.date.fromisoformat(v.strip())
                 - datetime.date(1970, 1, 1)).days
     if dst == DataType.TIME:
-        import datetime
         t = datetime.time.fromisoformat(v.strip())
         return ((t.hour * 60 + t.minute) * 60 + t.second) * 1_000_000 \
             + t.microsecond
@@ -118,7 +117,6 @@ def _cast_one_string(v, dst: DataType):
 
 def _format_to_string(v, src: DataType) -> str:
     """pg text-out for physical values (round-trips _cast_one_string)."""
-    import datetime
     if src == DataType.DECIMAL:
         return str(scaled_to_decimal(v))
     if src == DataType.BOOLEAN:
@@ -790,28 +788,79 @@ def _fn_concat(rt, *cols: Column) -> Column:
     return Column(rt, out, None)
 
 
+_TRUNC_US = {"second": 1_000_000, "minute": 60_000_000,
+             "hour": 3_600_000_000, "day": 86_400_000_000}
+
 # to_char format → strftime (the subset the nexmark corpus uses; the
-# reference's to_char lives in expr/src/vector_op/to_char.rs)
-_TO_CHAR_MAP = [("YYYY", "%Y"), ("MM", "%m"), ("DD", "%d"),
-                ("HH24", "%H"), ("MI", "%M"), ("SS", "%S")]
+# reference's to_char lives in expr/src/vector_op/to_char.rs): token,
+# its strftime directive, and the date_trunc field within which what it
+# prints cannot change (UTC, no zone: a month and a year are functions
+# of the day)
+_TO_CHAR_TOKENS = (("YYYY", "%Y", "day"), ("MM", "%m", "day"),
+                   ("DD", "%d", "day"), ("HH24", "%H", "hour"),
+                   ("MI", "%M", "minute"), ("SS", "%S", "second"))
+
+
+@functools.lru_cache(maxsize=256)
+def _to_char_pattern(fmt: str):
+    """(strftime string, unit) of a to_char pattern, read left to
+    right: the text is a function of ``floor(µs / unit)``. ``unit`` is
+    that of the finest token where the pattern proves it, else 1 µs,
+    of which any strftime text is a function."""
+    out, units, proven, i = [], [], True, 0
+    while i < len(fmt):
+        for token, directive, field in _TO_CHAR_TOKENS:
+            if fmt.startswith(token, i):
+                out.append(directive)
+                units.append(_TRUNC_US[field])
+                i += len(token)
+                break
+        else:
+            ch = fmt[i]
+            # a letter or a digit may be part of a field this table
+            # does not know (MS, US, HH12, Mon, ...), a % is strftime's
+            # own (%f): either may print something finer
+            proven = proven and not (
+                ch == "%" or (ch.isascii() and ch.isalnum()))
+            out.append(ch)
+            i += 1
+    return "".join(out), (
+        min(units, default=_TRUNC_US["day"]) if proven else 1)
 
 
 @register_function("to_char")
 def _fn_to_char(rt, ts: Column, fmt: Column) -> Column:
-    import datetime
+    """``to_char(timestamp, pattern)`` through ``strftime``. The tokens
+    are YYYY, MM, DD, HH24, MI and SS; every other character is a
+    literal. A chunk is formatted once a distinct ``floor(µs / unit)``
+    and gathered: ``unit`` is that of the finest token where the
+    pattern is made of tokens and of literals that are neither ASCII
+    letters, digits nor ``%``, which proves its text a function of that
+    field. Every other pattern has the unit 1 µs, so it is formatted
+    once a distinct instant."""
+    from risingwave_tpu.utils.metrics import STREAMING
     f = _scalar_of(fmt)
     if f is None:
         return _host_unary(rt, ts, lambda v: None)
-    sf = str(f)
-    for a, b in _TO_CHAR_MAP:
-        sf = sf.replace(a, b)
-    epoch = datetime.datetime(1970, 1, 1,
-                              tzinfo=datetime.timezone.utc)
-
-    def conv(v):
-        return (epoch + datetime.timedelta(
-            microseconds=int(v))).strftime(sf)
-    return _host_unary(rt, ts, conv)
+    sf, unit = _to_char_pattern(str(f))
+    epoch = datetime.datetime(1970, 1, 1, tzinfo=datetime.timezone.utc)
+    ok = None if ts.validity is None else np.asarray(ts.validity).copy()
+    vals = np.asarray(ts.values)
+    live = (vals if ok is None else vals[ok]).astype(np.int64)
+    # floor, not truncation: -1 µs is 1969-12-31
+    buckets, inverse = np.unique(np.floor_divide(live, unit),
+                                 return_inverse=True)
+    texts = np.array([(epoch + datetime.timedelta(
+        microseconds=b * unit)).strftime(sf) for b in buckets.tolist()],
+        dtype=object)
+    if ok is None:
+        values = texts[inverse]
+    else:
+        values = np.empty(len(vals), dtype=object)
+        values[ok] = texts[inverse]
+    STREAMING.expr_to_char_rows.inc(len(live))
+    STREAMING.expr_to_char_formats.inc(len(buckets))
+    return Column(rt, values, None if ok is None or ok.all() else ok)
 
 
 _DATE_PART_DIV = {
@@ -822,7 +871,6 @@ _DATE_PART_DIV = {
 
 @register_function("date_part")
 def _fn_date_part(rt, field: Column, ts: Column) -> Column:
-    import datetime
     f = _scalar_of(field)
     f = str(f).lower() if f is not None else ""
     vals = np.asarray(ts.values)
@@ -843,10 +891,6 @@ def _fn_date_part(rt, field: Column, ts: Column) -> Column:
         out[i] = getattr(epoch + datetime.timedelta(
             microseconds=int(vals[i])), attr)
     return Column(rt, out, None if ok.all() else np.asarray(ok))
-
-
-_TRUNC_US = {"second": 1_000_000, "minute": 60_000_000,
-             "hour": 3_600_000_000, "day": 86_400_000_000}
 
 
 @register_function("date_trunc")

@@ -317,6 +317,13 @@ class StreamingMetrics:
             "by dedup table and stage (write: its StateTable batch "
             "calls, the state.write stages nested there; persist: the "
             "rest of the pass, its part of agg.persist)")
+        self.expr_to_char_rows = r.counter(
+            "expr_to_char_rows", "valid rows to_char was given")
+        self.expr_to_char_formats = r.counter(
+            "expr_to_char_formats",
+            "strftime calls to_char made: one a distinct value, in a "
+            "chunk, of the finest field its pattern proves the text a "
+            "function of (the microsecond where it proves none)")
         self.state_cleaned_rows = r.counter(
             "stream_state_cleaned_rows",
             "rows a watermark's range delete took out of a state "
@@ -875,7 +882,8 @@ class MetricsHistory:
         aggregate hand-off, rows into and out of a join and through
         its condition, rows through a HOP, the pairs a DISTINCT column's
         dedup state holds, changed and made visible and the seconds of
-        its write-through, what a watermark cleaned out
+        its write-through, the rows to_char was given and the strftime
+        calls it made, what a watermark cleaned out
         of the state tables and the rows they keep, the rows a
         watermark filter dropped, the longest chain a join's
         probe walked, the key skew of a staged batch, the rounds of
@@ -909,6 +917,9 @@ class MetricsHistory:
                  S.agg_distinct_crossings, "counter"),
                 ("agg_distinct.{table}.{stage}_s",
                  S.agg_distinct_seconds, "counter"),
+                ("expr_to_char.rows", S.expr_to_char_rows, "counter"),
+                ("expr_to_char.formats", S.expr_to_char_formats,
+                 "counter"),
                 ("state_clean.{table}.cleaned", S.state_cleaned_rows,
                  "counter"),
                 ("state_clean.{table}.reads", S.state_clean_reads,

@@ -242,39 +242,132 @@ def test_scalar_function_library_semantics():
     assert int(ep) == 1_436_918_400 * 10_000   # scaled decimal seconds
 
 
-@pytest.mark.parametrize("fmt,strf", [
-    ("YYYY-MM-DD", "%Y-%m-%d"), ("HH24:MI", "%H:%M"),
-    ("YYYY-MM-DD HH24:MI:SS", "%Y-%m-%d %H:%M:%S"),
-    ("DD/MM HH24", "%d/%m %H"), ("day", "day")])
-def test_to_char_formats_a_row_as_strftime_does(fmt, strf):
-    """`to_char` gives the text a strftime of the row gives, row for
-    row (q15's GROUP BY key, ISSUE 41): rows on both sides of a minute,
-    an hour, a day and the epoch; NULLs stay NULL. It formats once a
-    row today; whatever formats fewer times has to pass this."""
-    import datetime
+_DAY_US = 86_400_000_000
+# pattern, the strftime string it stands for, and the µs within which
+# its text cannot change: that of the finest token where the pattern
+# proves it (a day where it holds no token), else 1
+TO_CHAR_CASES = [
+    ("YYYY-MM-DD", "%Y-%m-%d", _DAY_US), ("HH24:MI", "%H:%M", 60_000_000),
+    ("YYYY-MM-DD HH24:MI:SS", "%Y-%m-%d %H:%M:%S", 1_000_000),
+    ("DD/MM HH24", "%d/%m %H", 3_600_000_000), ("day", "day", 1),
+    ("SS", "%S", 1_000_000), ("MI", "%M", 60_000_000),
+    ("HH24", "%H", 3_600_000_000), ("DD", "%d", _DAY_US),
+    ("MM", "%m", _DAY_US), ("YYYY", "%Y", _DAY_US),
+    ("YYYY/MM", "%Y/%m", _DAY_US), ("MI:SS", "%M:%S", 1_000_000),
+    ("YYYY, MI", "%Y, %M", 60_000_000),
+    ("SS.MI.HH24 DD-MM-YYYY", "%S.%M.%H %d-%m-%Y", 1_000_000),
+    ("YYYYMMDD", "%Y%m%d", _DAY_US), ("-- : /", "-- : /", _DAY_US),
+    ("", "", _DAY_US),
+    ("MS", "MS", 1), ("US", "US", 1), ("HH12:MI", "HH12:%M", 1),
+    ("Mon DD", "Mon %d", 1), ("DDD", "%dD", 1),
+    ('YYYY-MM-DD"T"HH24', '%Y-%m-%d"T"%H', 1),
+    ("HH24:MI:SS.%f", "%H:%M:%S.%f", 1), ("%S", "%S", 1),
+    ("YYYY-MM-DD 0", "%Y-%m-%d 0", 1)]
 
-    import numpy as np
 
+def _to_char_chunks():
+    """2,000 seeded instants within three days of a day, an hour and a
+    minute boundary and of the epoch, the boundaries' own neighbours,
+    NULLs scattered; an all-NULL chunk; an empty one."""
     from risingwave_tpu.common.chunk import DataChunk
     from risingwave_tpu.common.types import DataType, Schema
-    from risingwave_tpu.expr.expr import FuncCall, InputRef, lit
 
+    rng = np.random.default_rng(42)
     base = 1_436_918_400_000_000             # 2015-07-15 00:00:00
-    ts = [base - 1, base, base + 59_999_999, base + 60_000_000,
-          base + 3_599_999_999, base + 3_600_000_000,
-          base + 86_399_999_999, base + 86_400_000_000, -1, 0, 1, None,
-          base + 1_500_000]
-    chunk = DataChunk.from_pydict(Schema.of(ts=DataType.TIMESTAMP),
-                                  {"ts": ts})
-    col = FuncCall("to_char", [InputRef(0, DataType.TIMESTAMP),
-                               lit(fmt, DataType.VARCHAR)],
-                   DataType.VARCHAR).eval(chunk)
+    centres = [base, base + 13 * 3_600_000_000,
+               base + 13 * 3_600_000_000 + 17 * 60_000_000, 0]
+    ts = [c + d for c in centres
+          for d in (-1_000_001, -1_000_000, -1, 0, 1, 999_999, 1_000_000,
+                    59_999_999, 60_000_000, 3_599_999_999, 3_600_000_000,
+                    _DAY_US - 1, _DAY_US, -_DAY_US, -_DAY_US - 1)]
+    for c in centres:
+        ts += (c + rng.integers(-3 * _DAY_US, 3 * _DAY_US, 400)).tolist()
+        ts += (c + rng.integers(-2_000_000, 2_000_000, 100)).tolist()
+    ts = [None if rng.random() < 0.05 else int(v) for v in ts]
+    schema = Schema.of(ts=DataType.TIMESTAMP)
+    return [DataChunk.from_pydict(schema, {"ts": rows})
+            for rows in (ts, [None] * 5, [])]
+
+
+def _valid_rows(column):
+    """(row index, µs) of a TIMESTAMP column's valid rows, padding
+    included: what to_char is given."""
+    vals = np.asarray(column.values).tolist()
+    ok = np.ones(len(vals), dtype=bool) if column.validity is None \
+        else np.asarray(column.validity)
+    return [(i, vals[i]) for i in np.flatnonzero(ok).tolist()]
+
+
+def _to_char(fmt):
+    from risingwave_tpu.common.types import DataType
+    from risingwave_tpu.expr.expr import FuncCall, InputRef, lit
+    return FuncCall("to_char", [InputRef(0, DataType.TIMESTAMP),
+                                lit(fmt, DataType.VARCHAR)],
+                    DataType.VARCHAR)
+
+
+@pytest.mark.parametrize("fmt,strf", [c[:2] for c in TO_CHAR_CASES])
+def test_to_char_formats_a_row_as_strftime_does(fmt, strf):
+    """`to_char` gives the text a strftime of the row gives, row for
+    row (q15's GROUP BY key, ISSUE 41), whatever unit the pattern
+    proves (ISSUE 42): rows on both sides of a second, a minute, an
+    hour, a day and the epoch; NULLs stay NULL. `strf` is what the
+    chain of `str.replace` before ISSUE 42 made of the pattern."""
+    import datetime
+
+    chain = fmt
+    for token, directive in (("YYYY", "%Y"), ("MM", "%m"), ("DD", "%d"),
+                             ("HH24", "%H"), ("MI", "%M"), ("SS", "%S")):
+        chain = chain.replace(token, directive)
+    assert chain == strf
     epoch = datetime.datetime(1970, 1, 1, tzinfo=datetime.timezone.utc)
-    ok = np.ones(len(ts), dtype=bool) if col.validity is None \
-        else np.asarray(col.validity)[:len(ts)]
-    for i, v in enumerate(ts):
-        if v is None:
-            assert not ok[i]
-        else:
-            assert ok[i] and np.asarray(col.values)[i] == (
-                epoch + datetime.timedelta(microseconds=v)).strftime(strf)
+    chunks = _to_char_chunks()
+    assert sum(c.cardinality() for c in chunks) > 2_000
+    for chunk in chunks:
+        given = chunk.columns[0]
+        col = _to_char(fmt).eval(chunk)
+        valid = _valid_rows(given)
+        ok = np.ones(chunk.capacity, dtype=bool) if col.validity is None \
+            else np.asarray(col.validity)
+        assert np.flatnonzero(ok).tolist() == [i for i, _v in valid]
+        got = np.asarray(col.values)
+        for i, v in valid:
+            assert got[i] == (epoch + datetime.timedelta(
+                microseconds=v)).strftime(strf), (fmt, v)
+
+
+@pytest.mark.parametrize("fmt,unit", [c[::2] for c in TO_CHAR_CASES])
+def test_to_char_counts_its_formats(fmt, unit):
+    """Counters `expr_to_char_rows` / `_formats`: a pattern of the six
+    tokens and of literals that are no letter, digit or `%` formats
+    once a distinct value of the finest field it prints; any other
+    pattern formats once a distinct µs."""
+    from risingwave_tpu.utils.metrics import STREAMING as S
+
+    def books():
+        return (S.expr_to_char_rows.get(), S.expr_to_char_formats.get())
+    for chunk in _to_char_chunks():
+        valid = [v for _i, v in _valid_rows(chunk.columns[0])]
+        rows, formats = books()
+        _to_char(fmt).eval(chunk)
+        assert books() == (rows + len(valid),
+                           formats + len({v // unit for v in valid}))
+
+
+def test_to_char_takes_a_host_column_of_python_ints():
+    """An object-dtype TIMESTAMP column (what a host-side expression
+    may hand on) formats as the int64 one does, and the validity that
+    comes back is not the input's array."""
+    from risingwave_tpu.common.chunk import Column
+    from risingwave_tpu.common.types import DataType
+    from risingwave_tpu.expr.expr import _FUNCTIONS
+
+    ok = np.array([True, False, True, True])
+    ts = Column(DataType.TIMESTAMP,
+                np.array([-1, None, 0, 86_400_000_000], dtype=object), ok)
+    fmt = Column(DataType.VARCHAR, np.array(["YYYY-MM-DD"], dtype=object),
+                 None)
+    out = _FUNCTIONS["to_char"](DataType.VARCHAR, ts, fmt)
+    assert out.values.tolist() == ["1969-12-31", None, "1970-01-01",
+                                   "1970-01-02"]
+    assert out.validity is not ok and out.validity.tolist() == ok.tolist()

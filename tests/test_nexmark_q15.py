@@ -314,6 +314,73 @@ def test_the_books_name_the_dedup_s_pairs_changes_and_seconds(q15):
         assert 2 * pairs <= shown <= 4 * pairs
 
 
+def test_to_char_formats_once_a_live_day_of_a_chunk(q15):
+    """The GROUP BY key's `to_char` (ISSUE 42): a barrier here is one
+    chunk, so it formats once a day the chunk holds, two in the chunks
+    that hold a midnight; the groups are the reference's all the
+    same."""
+    config, run_ = q15["config"], q15["two_days"]
+    nexmark_gen = _bench_module("reference", "nexmark_gen")
+    gen = _generator(config, CASES["two_days"])
+    rows = sorted(run_["history"].values(), key=lambda h: h["ts"])
+    data = [h for h in rows if h.get("source_rows")]
+    assert len(data) >= 20
+    # the history's newest row holds the chunk the source had read past
+    # the last checkpoint
+    n = run_["checkpoints"][-1]["readers"][0]["rows"] + CHUNK
+    day = nexmark_gen.prefix("bid", n, gen)["date_time"] // 86_400_000_000
+    days = [len(set(day[i:i + CHUNK].tolist())) for i in range(0, n, CHUNK)]
+    assert [h["expr_to_char.formats"] for h in data] == days[-len(data):]
+    assert sorted(set(days[-len(data):])) == [1, 2]
+    assert all(h["expr_to_char.rows"] == CHUNK for h in data)
+    ref = _bench_module("reference", config["reference"])
+    last = run_["checkpoints"][-1]
+    assert last["view"] == ref.reference(
+        [dict(r) for r in last["readers"]], gen)
+    assert sorted(row[0] for row in last["view"]) == [
+        "2015-07-15", "2015-07-16", "2015-07-17"]
+
+
+def test_a_chunk_that_straddles_midnight_by_a_microsecond_is_two_days():
+    """One chunk whose rows lie 1 µs before midnight, on it and 1 µs
+    after: two groups, split where the day turns, and two formats."""
+    from risingwave_tpu.frontend.session import Frontend
+    from risingwave_tpu.utils.metrics import STREAMING as S
+    config = _config()
+    view = config["ddl"][-1].replace("FROM bid", "FROM t")
+    rows = [(1, 10, 5, "2015-07-15 23:59:59.999999"),
+            (2, 10, 50000, "2015-07-15 23:59:59.999999"),
+            (1, 11, 5, "2015-07-16 00:00:00.000000"),
+            (3, 12, 5000000, "2015-07-16 00:00:00.000001"),
+            (3, 10, 5, "2015-07-16 00:00:00.000001"),
+            (4, 13, 50000, "2015-07-15 23:59:59.999999"),
+            (4, 13, 50000, "2015-07-16 00:00:00.000000"),
+            (5, 10, 5, "2015-07-16 00:00:00.000001")]   # 8: no padding
+
+    async def drive():
+        fe = Frontend()
+        try:
+            await fe.execute(
+                "CREATE TABLE t (auction BIGINT, bidder BIGINT, "
+                "price BIGINT, date_time TIMESTAMP)")
+            await fe.execute(view)
+            formats = S.expr_to_char_formats.get()
+            values = ", ".join(f"({a}, {b}, {p}, '{ts}')"
+                               for a, b, p, ts in rows)
+            await fe.execute(f"INSERT INTO t VALUES {values}")
+            await fe.execute("FLUSH")
+            return (await fe.execute(f"SELECT * FROM {config['view']}"),
+                    S.expr_to_char_formats.get() - formats)
+        finally:
+            await fe.close()
+
+    got, formats = asyncio.run(drive())
+    assert collections.Counter(tuple(r) for r in got) == _recount(rows)
+    assert sorted((r[0], r[1]) for r in got) == [("2015-07-15", 3),
+                                                 ("2015-07-16", 5)]
+    assert formats == 2
+
+
 def test_recovery_mid_run_continues_to_the_same_rows_and_counts(q15):
     from risingwave_tpu.storage.hummock import HummockLite
     from risingwave_tpu.storage.object_store import MemObjectStore
