@@ -9,10 +9,19 @@ never live in HBM anyway — the device's job is the equality/match
 structure, the host's job is materialization):
 
     table    DeviceHashTable   join-key lanes → key slot
-    head     int32[cap]        key slot → first row ref (-1 end)
-    next     int32[row_cap]    row ref → next row ref in its key chain
-    ins_seq  int32[row_cap]    message sequence that inserted the row
-    del_seq  int32[row_cap]    message sequence that deleted it (MAX=∞)
+    head     int32[cap]        key slot → position of the key's newest
+                               run in `store` (-1: no row)
+    store    int32[row_cap]    row refs in link order, behind a bump
+                               pointer (`fill`): the rows one batch
+                               gave one key are a RUN, contiguous
+    run_len  int32[row_cap]    at a run's first position: its rows
+    run_next int32[row_cap]    at a run's first position: the first
+                               position of the key's next older run
+                               (-1 end)
+    ins_seq  int32[row_cap]    by ref: message sequence that inserted
+                               the row
+    del_seq  int32[row_cap]    by ref: message sequence that deleted
+                               it (MAX=∞)
 
 SEQUENCE-VERSIONED state (the load-bearing TPU design choice): every
 message carries a monotone sequence number, and a probe at sequence s
@@ -27,15 +36,18 @@ buffer overflowed — per-epoch instead of per-chunk synchronization
 CPU lookups and need none of this.)
 
 - ``insert``: whole-batch: one key probe-insert, then one chain-link
-  kernel. Rows of one batch that share a key are chained to each other
-  with one stable sort + shifted compares — no per-row host loop.
-- ``delete``: sets del_seq. Chains keep the node until a rebuild;
+  kernel. A stable sort by key slot puts the rows a batch gives one key
+  side by side; they go into `store` in that order as one run, linked
+  in front of the key's older runs — no per-row host loop.
+- ``delete``: sets del_seq. A run keeps the row until a rebuild;
   probes at later sequences skip it.
-- ``probe``: ONE fused kernel — degree-count walk, device cumsum, emit
-  walk writing (probe_row, matched_ref) pairs at the cumsum offsets,
-  all returned as one packed matrix with a header. ``lax.while_loop``
-  runs exactly max-chain-length iterations (dynamic trip count, static
-  shapes).
+- ``probe``: ONE fused kernel. A ``lax.while_loop`` steps the probed
+  keys' RUNS (its trip count is the most runs any probed key has, i.e.
+  the batches that touched it, not its rows), the rows of the runs met
+  are expanded in parallel into a candidate buffer of static size,
+  each is tested against its probe row's sequence, and one cumsum
+  places the (probe_row, matched_ref) pairs, all returned as one
+  packed matrix with a header.
 
 All lanes int32 (ops/lanes.py rationale).
 """
@@ -59,39 +71,66 @@ I32_MAX = (1 << 31) - 1
 class ChainState(NamedTuple):
     """Functional chain arrays (the non-key half of a join side)."""
 
-    head: jnp.ndarray     # int32[cap]
-    next: jnp.ndarray     # int32[row_cap]
-    ins_seq: jnp.ndarray  # int32[row_cap] (I32_MAX = never inserted)
-    del_seq: jnp.ndarray  # int32[row_cap] (I32_MAX = live)
+    head: jnp.ndarray      # int32[cap]: newest run's position, -1 none
+    store: jnp.ndarray     # int32[row_cap]: row refs in link order
+    run_len: jnp.ndarray   # int32[row_cap], at a run's first position
+    run_next: jnp.ndarray  # int32[row_cap], at a run's first position
+    fill: jnp.ndarray      # int32[]: positions of `store` in use
+    ins_seq: jnp.ndarray   # int32[row_cap] (I32_MAX = never inserted)
+    del_seq: jnp.ndarray   # int32[row_cap] (I32_MAX = live)
+
+
+# the fields of a ChainState that grow with the rows
+ROW_ARRAYS = ("store", "run_len", "run_next", "ins_seq", "del_seq")
+
+
+def empty_chains(key_capacity: int, row_capacity: int) -> ChainState:
+    return ChainState(
+        head=jnp.full(key_capacity, -1, dtype=jnp.int32),
+        store=jnp.full(row_capacity, -1, dtype=jnp.int32),
+        run_len=jnp.zeros(row_capacity, dtype=jnp.int32),
+        run_next=jnp.full(row_capacity, -1, dtype=jnp.int32),
+        fill=jnp.int32(0),
+        ins_seq=jnp.full(row_capacity, I32_MAX, dtype=jnp.int32),
+        del_seq=jnp.full(row_capacity, I32_MAX, dtype=jnp.int32))
 
 
 def link_rows(chains: ChainState, slots: jnp.ndarray,
               row_refs: jnp.ndarray, vis: jnp.ndarray,
               cap: int, seq: jnp.ndarray = None) -> ChainState:
-    """Front-insert a batch of rows into their key chains.
+    """Link a batch of rows in front of their keys' older rows.
 
-    `slots` comes from the key table's probe_insert for the same batch;
-    rows of the batch that share a slot are linked to each other via a
-    stable sort so the whole batch needs one scatter per array.
-
-    Within one batch, rows sharing a key keep BATCH ORDER in the chain
-    via the stable sort; `seq` may be a per-row vector (epoch batching:
-    each row carries its message sequence) or a scalar."""
-    row_cap = int(chains.next.shape[0])
+    `slots` comes from the key table's probe_insert for the same batch.
+    A stable sort by slot puts the rows that share a key side by side,
+    in BATCH ORDER; in that order they take the next positions of
+    `store`, so the rows a batch gives a key are one run, and the run
+    becomes the key's newest. A ref is linked once (refs are bump
+    allocated and never reused before a rebuild), so `store` never
+    holds more than row_cap rows. `seq` may be a per-row vector (epoch
+    batching: each row carries its message sequence) or a scalar."""
+    row_cap = int(chains.ins_seq.shape[0])
+    n = slots.shape[0]
     skey = jnp.where(vis & (slots >= 0), slots, cap)
     order = jnp.argsort(skey, stable=True)
     s = skey[order]
     r = row_refs[order]
-    valid = s < cap
+    valid = s < cap                             # a prefix of the order
     first = jnp.concatenate([jnp.ones(1, bool), s[1:] != s[:-1]])
     last = jnp.concatenate([s[1:] != s[:-1], jnp.ones(1, bool)])
-    succ = jnp.roll(r, -1)                      # r[i+1] (garbage at end)
-    old_head = chains.head[jnp.minimum(s, cap - 1)]
-    nxt_val = jnp.where(last, old_head, succ)
-    nxt = chains.next.at[jnp.where(valid, r, row_cap)].set(
-        nxt_val, mode="drop")
-    head = chains.head.at[jnp.where(valid & first, s, cap)].set(
+    idx = jnp.arange(n, dtype=jnp.int32)
+    pos = chains.fill + idx
+    # a run's rows: from its first row to the next `last` at or after it
+    run_end = jax.lax.cummin(jnp.where(last, idx, jnp.int32(n)),
+                             reverse=True)
+    at_first = jnp.where(valid & first, pos, row_cap)
+    store = chains.store.at[jnp.where(valid, pos, row_cap)].set(
         r, mode="drop")
+    run_len = chains.run_len.at[at_first].set(
+        run_end - idx + 1, mode="drop")
+    run_next = chains.run_next.at[at_first].set(
+        chains.head[jnp.minimum(s, cap - 1)], mode="drop")
+    head = chains.head.at[jnp.where(valid & first, s, cap)].set(
+        pos, mode="drop")
     if seq is None:
         sv = jnp.int32(0)
     elif jnp.ndim(seq) == 0:
@@ -100,31 +139,140 @@ def link_rows(chains: ChainState, slots: jnp.ndarray,
         sv = seq[order]                         # per-row seq follows r
     ins = chains.ins_seq.at[jnp.where(valid, r, row_cap)].set(
         sv, mode="drop")
-    return ChainState(head, nxt, ins, chains.del_seq)
+    return ChainState(head, store, run_len, run_next,
+                      chains.fill + jnp.sum(valid, dtype=jnp.int32),
+                      ins, chains.del_seq)
 
 
 def tombstone_rows(chains: ChainState, row_refs: jnp.ndarray,
                    vis: jnp.ndarray,
                    seq: jnp.ndarray = None) -> ChainState:
     """Tombstone deletes; probes at sequences > seq skip the node."""
-    row_cap = int(chains.next.shape[0])
+    row_cap = int(chains.del_seq.shape[0])
     del_ = chains.del_seq.at[jnp.where(vis, row_refs, row_cap)].set(
         jnp.int32(0) if seq is None else seq, mode="drop")
     return chains._replace(del_seq=del_)
+
+
+# rows of a probe matrix before its degree and pair rows:
+#   [pairs in the buffer, rows of the longest chain probed]
+#   [candidates of the whole probe, steps the walk took]
+HEADER_ROWS = 2
+
+
+class _Matches(NamedTuple):
+    """What `_match_runs` found in one page of a probe's candidates."""
+
+    probe: jnp.ndarray     # int32[cap]: probe row of each pair, -1 pad
+    ref: jnp.ndarray       # int32[cap]: matched ref of each pair
+    deg: jnp.ndarray       # int32[n]: pairs per probe row, in the page
+    header: jnp.ndarray    # int32[HEADER_ROWS, 2]
+
+
+def _match_runs(table: ht.TableState, chains: ChainState,
+                key_lanes: jnp.ndarray, vis: jnp.ndarray,
+                seq: jnp.ndarray, cap: int, start) -> _Matches:
+    """The probe both kernels share: look the keys up, step their runs,
+    expand the runs' rows in parallel, keep the visible ones.
+
+    A probe's CANDIDATES are the rows of the probed keys' runs, visible
+    or not, by probe row and within a row newest run first (a chain,
+    front to back). This call works candidates ``[start, start + cap)``
+    (``start`` a traced scalar, ``cap`` a shape) and packs the pairs
+    among them at the front of the buffer, in candidate order, so pages
+    read one after the other give the probe's pairs in order.
+
+    The walks step a RUN at a time: the first sums each row's
+    candidates, the second writes, at the page position of a run's
+    first candidate, the probe row and the `store` position it stands
+    for. A running maximum over the written positions then tells every
+    candidate of the page its run, and nothing else is a loop."""
+    n = key_lanes.shape[0]
+    row_cap = chains.store.shape[0]
+    slots = ht.lookup(table, key_lanes, vis)
+    cur0 = jnp.where(slots >= 0,
+                     chains.head[jnp.maximum(slots, 0)], jnp.int32(-1))
+    start = jnp.asarray(start, dtype=jnp.int32)
+
+    def cond(c):
+        return jnp.any(c[0] >= 0)
+
+    def run_of(cur):
+        safe = jnp.maximum(cur, 0)
+        live = cur >= 0
+        return (jnp.where(live, chains.run_next[safe], jnp.int32(-1)),
+                jnp.where(live, chains.run_len[safe], jnp.int32(0)))
+
+    def count(c):
+        cur, cnt, steps = c
+        nxt, ln = run_of(cur)
+        return nxt, cnt + ln, steps + 1
+
+    _cur, cnt, steps = jax.lax.while_loop(
+        cond, count, (cur0, jnp.zeros(n, dtype=jnp.int32), jnp.int32(0)))
+    candidates = jnp.sum(cnt, dtype=jnp.int32)
+    row_ids = jnp.arange(n, dtype=jnp.int32)
+
+    def record(c):
+        cur, at, run_row, run_pos = c
+        nxt, ln = run_of(cur)
+        # `at`: the page position of the run's first candidate
+        hit = (ln > 0) & (at < cap) & (at + ln > 0)
+        dest = jnp.where(hit, jnp.maximum(at, 0), cap)
+        run_row = run_row.at[dest].set(row_ids, mode="drop")
+        run_pos = run_pos.at[dest].set(cur - jnp.minimum(at, 0),
+                                       mode="drop")
+        return nxt, at + ln, run_row, run_pos
+
+    _cur, _at, run_row, run_pos = jax.lax.while_loop(
+        cond, record,
+        (cur0, jnp.cumsum(cnt, dtype=jnp.int32) - cnt - start,
+         jnp.full(cap, -1, dtype=jnp.int32),
+         jnp.zeros(cap, dtype=jnp.int32)))
+    j = jnp.arange(cap, dtype=jnp.int32)
+    seg = jax.lax.cummax(jnp.where(run_row >= 0, j, jnp.int32(-1)))
+    real = (seg >= 0) & (j < candidates - start)
+    safe = jnp.maximum(seg, 0)
+    row = jnp.where(real, run_row[safe], jnp.int32(0))
+    ref = chains.store[jnp.clip(run_pos[safe] + j - safe, 0,
+                                row_cap - 1)]
+    ref = jnp.where(real, ref, jnp.int32(0))
+    at_seq = seq if jnp.ndim(seq) == 0 else seq[row]
+    m = real & (chains.ins_seq[ref] < at_seq) \
+        & (chains.del_seq[ref] >= at_seq)
+    kept = jnp.cumsum(m, dtype=jnp.int32)
+    dest = jnp.where(m, kept - 1, cap)
+    out_probe = jnp.full(cap, -1, dtype=jnp.int32).at[dest].set(
+        row, mode="drop")
+    out_ref = jnp.full(cap, -1, dtype=jnp.int32).at[dest].set(
+        ref, mode="drop")
+    deg = jnp.zeros(n, dtype=jnp.int32).at[
+        jnp.where(m, row, n)].add(1, mode="drop")
+    header = jnp.stack([
+        jnp.stack([kept[-1], jnp.max(cnt)]),
+        jnp.stack([candidates, steps])])
+    return _Matches(out_probe, out_ref, deg, header)
+
+
+def _packed(header: jnp.ndarray, width: int, *blocks) -> jnp.ndarray:
+    """[header | blocks...] as one matrix ``width`` columns wide."""
+    head = jnp.zeros((HEADER_ROWS, width), dtype=jnp.int32) \
+        .at[:, :2].set(header)
+    return jnp.concatenate((head,) + blocks, axis=0)
 
 
 def probe_pairs(table: ht.TableState, chains: ChainState,
                 key_lanes: jnp.ndarray, vis: jnp.ndarray,
                 seq: jnp.ndarray, out_cap: int,
                 with_degrees: bool = True) -> jnp.ndarray:
-    """Fused degrees + cumsum + emit: ONE kernel, ONE packed d2h array.
+    """Fused walk + expansion + emit: ONE kernel, ONE packed d2h array.
 
-    Returns int32[1 + n + out_cap, 2]: row 0 header [total_pairs, 0];
-    rows 1..1+n degrees (col 0); remaining rows (probe_row_idx, ref)
-    pairs at device-computed cumsum offsets. A separate degrees fetch
-    + host cumsum + emit fetch would be three round-trips per chunk;
-    this is one (the host retries with a doubled out_cap if the header
-    says the pair buffer overflowed).
+    Returns int32[HEADER_ROWS + n + out_cap, 2]: the header
+    (HEADER_ROWS); rows of degrees (col 0), one a probe row; then
+    (probe_row_idx, ref) pairs, packed. A separate degrees fetch + host
+    cumsum + emit fetch would be three round-trips per chunk; this is
+    one (the host retries with a larger out_cap if the header says the
+    probe's candidates outgrew the buffer).
 
     `seq` may be a per-row vector (epoch batching: every row probes at
     its own message sequence). `with_degrees=False` drops the n degree
@@ -132,49 +280,12 @@ def probe_pairs(table: ht.TableState, chains: ChainState,
     not fetched.
     """
     n = key_lanes.shape[0]
-    slots = ht.lookup(table, key_lanes, vis)
-    cur0 = jnp.where(slots >= 0,
-                     chains.head[jnp.maximum(slots, 0)], jnp.int32(-1))
-
-    def cond(c):
-        return jnp.any(c[0] >= 0)
-
-    def visible(safe):
-        return (chains.ins_seq[safe] < seq) & (chains.del_seq[safe] >= seq)
-
-    def body1(c):
-        cur, deg = c
-        safe = jnp.maximum(cur, 0)
-        m = (cur >= 0) & visible(safe)
-        return (jnp.where(cur >= 0, chains.next[safe], jnp.int32(-1)),
-                deg + m.astype(jnp.int32))
-
-    _cur, deg = jax.lax.while_loop(
-        cond, body1, (cur0, jnp.zeros(n, dtype=jnp.int32)))
-    offsets = jnp.cumsum(deg, dtype=jnp.int32) - deg
-    total = jnp.sum(deg, dtype=jnp.int32)
-    row_ids = jnp.arange(n, dtype=jnp.int32)
-
-    def body2(c):
-        cur, wp, op, orf = c
-        safe = jnp.maximum(cur, 0)
-        m = (cur >= 0) & visible(safe)
-        dest = jnp.where(m, wp, out_cap)
-        op = op.at[dest].set(row_ids, mode="drop")
-        orf = orf.at[dest].set(cur, mode="drop")
-        return (jnp.where(cur >= 0, chains.next[safe], jnp.int32(-1)),
-                wp + m.astype(jnp.int32), op, orf)
-
-    _cur, _wp, out_probe, out_ref = jax.lax.while_loop(
-        cond, body2,
-        (cur0, offsets, jnp.full(out_cap, -1, dtype=jnp.int32),
-         jnp.full(out_cap, -1, dtype=jnp.int32)))
-    pairs = jnp.stack([out_probe, out_ref], axis=1)
-    header = jnp.zeros((1, 2), dtype=jnp.int32).at[0, 0].set(total)
+    m = _match_runs(table, chains, key_lanes, vis, seq, out_cap, 0)
+    pairs = jnp.stack([m.probe, m.ref], axis=1)
     if not with_degrees:
-        return jnp.concatenate([header, pairs], axis=0)
-    degs = jnp.stack([deg, jnp.zeros(n, dtype=jnp.int32)], axis=1)
-    return jnp.concatenate([header, degs, pairs], axis=0)
+        return _packed(m.header, 2, pairs)
+    degs = jnp.stack([m.deg, jnp.zeros(n, dtype=jnp.int32)], axis=1)
+    return _packed(m.header, 2, degs, pairs)
 
 
 _link_jit = jaxtools.instrumented_jit(
@@ -244,25 +355,28 @@ def epoch_probe(table: ht.TableState, chains: ChainState,
     """Probe a whole epoch's rows (each at its own sequence) in one
     dispatch against post-apply state — exact by sequence visibility.
 
-    ``start`` (a traced scalar, not a shape) is the first pair this
-    dispatch writes: pairs [start, start + out_cap) of the epoch's
-    pair sequence land in the buffer, the others are dropped, and the
-    header still carries the total. A probe whose matches outgrow the
-    largest buffer is read in pages of one program instead of a
-    larger program per size (JoinSideKernel.PROBE_CAP_TOP). Degree
-    maintenance reads the buffer, so ``with_degrees`` pages never.
+    ``start`` (a traced scalar, not a shape) is the first CANDIDATE
+    this dispatch works (`_match_runs`): the pairs among candidates
+    [start, start + out_cap) land packed in the buffer, and the header
+    carries the candidates of the whole probe. A probe whose
+    candidates outgrow the largest buffer is read in pages of one
+    program instead of a larger program per size
+    (JoinSideKernel.PROBE_CAP_TOP). Degree maintenance reads the
+    buffer, so ``with_degrees`` pages never.
 
-    Fused degrees + cumsum + emit + payload gather + degree
+    Fused walk + expansion + emit + payload gather + degree
     maintenance: ONE kernel, ONE packed d2h matrix of width
     W = 2 + P + (1 if with_degrees). Layout:
 
-      row 0                      header [total_pairs, longest chain
-                                 walked (rows, visible or not), ...]
-      rows 1..1+n (deg only)     per-probe-row match degrees (col 0)
+      HEADER_ROWS rows           [pairs in the buffer, rows of the
+                                 longest chain probed (visible or
+                                 not)], [candidates, steps the walk
+                                 over the runs took]
+      n rows (deg only)          per-probe-row match degrees (col 0)
       out_cap pair rows          [probe_row, ref, pay lanes..., old]
 
-    ``pay`` is THIS side's payload store: the emit walk gathers each
-    matched ref's lanes ON DEVICE, so the host materializes matched
+    ``pay`` is THIS side's payload store: each matched ref's lanes are
+    gathered ON DEVICE, so the host materializes matched
     rows from the one packed fetch instead of arena-gathering
     column-by-column per chunk. With ``with_degrees``:
 
@@ -283,76 +397,28 @@ def epoch_probe(table: ht.TableState, chains: ChainState,
     key_lanes = up[:, :key_width]
     flags = aux[:, AUX_FLAGS]
     vis = (flags & FLAG_PROBE) != 0
-    seq = aux[:, AUX_SEQ]
     n = key_lanes.shape[0]
     P = pay.shape[1]
-    row_cap = chains.next.shape[0]
-    slots = ht.lookup(table, key_lanes, vis)
-    cur0 = jnp.where(slots >= 0,
-                     chains.head[jnp.maximum(slots, 0)], jnp.int32(-1))
-
-    def cond(c):
-        return jnp.any(c[0] >= 0)
-
-    def visible(safe):
-        return (chains.ins_seq[safe] < seq) & (chains.del_seq[safe] >= seq)
-
-    def body1(c):
-        cur, deg, steps = c
-        safe = jnp.maximum(cur, 0)
-        m = (cur >= 0) & visible(safe)
-        return (jnp.where(cur >= 0, chains.next[safe], jnp.int32(-1)),
-                deg + m.astype(jnp.int32), steps + 1)
-
-    # the loop runs once per row of the longest chain probed
-    _cur, deg, longest = jax.lax.while_loop(
-        cond, body1, (cur0, jnp.zeros(n, dtype=jnp.int32),
-                      jnp.int32(0)))
-    offsets = jnp.cumsum(deg, dtype=jnp.int32) - deg
-    total = jnp.sum(deg, dtype=jnp.int32)
-    row_ids = jnp.arange(n, dtype=jnp.int32)
-    start = jnp.asarray(start, dtype=jnp.int32)
-
-    def body2(c):
-        cur, wp, op, orf, opay, oold = c
-        safe = jnp.maximum(cur, 0)
-        m = (cur >= 0) & visible(safe)
-        at = wp - start
-        dest = jnp.where(m & (at >= 0) & (at < out_cap), at, out_cap)
-        op = op.at[dest].set(row_ids, mode="drop")
-        orf = orf.at[dest].set(cur, mode="drop")
-        if P:
-            opay = opay.at[dest].set(pay[safe], mode="drop")
-        if with_degrees:
-            oold = oold.at[dest].set(deg_self[safe], mode="drop")
-        return (jnp.where(cur >= 0, chains.next[safe], jnp.int32(-1)),
-                wp + m.astype(jnp.int32), op, orf, opay, oold)
-
-    init2 = (cur0, offsets,
-             jnp.full(out_cap, -1, dtype=jnp.int32),
-             jnp.full(out_cap, -1, dtype=jnp.int32),
-             jnp.zeros((out_cap, P), dtype=jnp.int32),
-             jnp.zeros(out_cap, dtype=jnp.int32))
-    (_cur, _wp, out_probe, out_ref, out_pay,
-     out_old) = jax.lax.while_loop(cond, body2, init2)
-    parts = [out_probe[:, None], out_ref[:, None]]
+    row_cap = chains.store.shape[0]
+    m = _match_runs(table, chains, key_lanes, vis, aux[:, AUX_SEQ],
+                    out_cap, start)
+    pair_mask = m.ref >= 0
+    safe = jnp.maximum(m.ref, 0)
+    parts = [m.probe[:, None], m.ref[:, None]]
     if P:
-        parts.append(out_pay)
+        parts.append(jnp.where(pair_mask[:, None], pay[safe], 0))
     if with_degrees:
-        parts.append(out_old[:, None])
+        parts.append(jnp.where(pair_mask, deg_self[safe], 0)[:, None])
     pairs = jnp.concatenate(parts, axis=1)
     W = pairs.shape[1]
-    header = jnp.zeros((1, W), dtype=jnp.int32).at[0, 0].set(total) \
-        .at[0, 1].set(longest)
     if with_degrees:
         # stored-side transitions: one scatter-add of pair signs
-        pair_mask = out_ref >= 0
         sgn_row = jnp.where((flags & FLAG_NEG) != 0,
                             jnp.int32(-1), jnp.int32(1))
         pair_sgn = jnp.where(
-            pair_mask, sgn_row[jnp.maximum(out_probe, 0)], 0)
+            pair_mask, sgn_row[jnp.maximum(m.probe, 0)], 0)
         deg_self = deg_self.at[
-            jnp.where(pair_mask, out_ref, row_cap)].add(
+            jnp.where(pair_mask, m.ref, row_cap)].add(
                 pair_sgn, mode="drop")
         # probing-side initial degrees: probe-time count at each
         # inserted row's ref (add, not set — commutes with the other
@@ -361,13 +427,12 @@ def epoch_probe(table: ht.TableState, chains: ChainState,
         sink_cap = deg_sink.shape[0]
         deg_sink = deg_sink.at[
             jnp.where(ins_mask, aux[:, AUX_INS_REF], sink_cap)].add(
-                jnp.where(ins_mask, deg, 0), mode="drop")
-        degs = jnp.zeros((n, W), dtype=jnp.int32).at[:, 0].set(deg)
-        mat = jnp.concatenate([header, degs, pairs], axis=0)
-        return mat, deg_self, deg_sink
+                jnp.where(ins_mask, m.deg, 0), mode="drop")
+        degs = jnp.zeros((n, W), dtype=jnp.int32).at[:, 0].set(m.deg)
+        return _packed(m.header, W, degs, pairs), deg_self, deg_sink
     # degree-free (inner) probes return only the matrix: passing the
     # untouched deg arrays through would force XLA output copies
-    return jnp.concatenate([header, pairs], axis=0)
+    return _packed(m.header, W, pairs)
 
 
 _epoch_probe_jit = jaxtools.instrumented_jit(
@@ -506,6 +571,17 @@ def _rebase_jit(chains: ChainState) -> ChainState:
 _rebase_jit = jaxtools.instrumented_jit(_rebase_jit, "hash_join.rebase")
 
 
+def _degrees_and_pairs(mat: np.ndarray, n: int, with_degrees: bool):
+    """(degrees | None, the pair rows in use) of a fetched probe
+    matrix over ``n`` probe rows."""
+    at = HEADER_ROWS
+    deg = None
+    if with_degrees:
+        deg = np.ascontiguousarray(mat[at:at + n, 0])
+        at += n
+    return deg, mat[at:at + int(mat[0, 0])]
+
+
 class PendingProbe:
     """An in-flight probe: dispatched, DMA started, not yet read.
 
@@ -528,26 +604,20 @@ class PendingProbe:
     def collect(self) -> Tuple[Optional[np.ndarray], np.ndarray,
                                np.ndarray]:
         """(degrees | None, probe_idx[pairs], refs[pairs]). Pairs are
-        sorted by probe row index (device cumsum offsets)."""
-        n = self.n
+        sorted by probe row index (packed in candidate order)."""
         with LEDGER.kernel_scope("hash_join"):
             while True:
                 mat = jaxtools.fetch1(self.mat)
-                total = int(mat[0, 0])
-                if total <= self.cap:
+                candidates = int(mat[1, 0])
+                if candidates <= self.cap:
                     break
                 from risingwave_tpu.common.chunk import next_pow2
-                self.cap = max(self.cap * 2, next_pow2(total))
+                self.cap = max(self.cap * 2, next_pow2(candidates))
                 if self.bump is not None:
                     self.bump(self.cap)
                 self.mat = self.redispatch(self.cap)
                 jaxtools.start_fetch(self.mat)
-        if self.with_degrees:
-            deg = np.ascontiguousarray(mat[1:1 + n, 0])
-            pairs = mat[1 + n:1 + n + total]
-        else:
-            deg = None
-            pairs = mat[1:1 + total]
+        deg, pairs = _degrees_and_pairs(mat, self.n, self.with_degrees)
         return (deg, np.ascontiguousarray(pairs[:, 0]),
                 np.ascontiguousarray(pairs[:, 1]))
 
@@ -562,16 +632,18 @@ class PendingEpochProbe:
     succeeds — an overflow redispatch recomputes them from the
     original arrays, so a retry never double-counts a transition.
 
-    The pair buffer's size is a shape of the program, so it must not
-    follow the data for ever. A degree-free probe (``top`` given) that
-    overflows takes the buffer to at most ``top`` pairs, the ladder's
-    one further rung, and reads whatever lies beyond in pages of the
-    same program (`redispatch(cap, start)`). A degree-tracking probe
-    keeps doubling: its degree adds read the buffer."""
+    The buffer's size is a shape of the program, so it must not follow
+    the data for ever. It holds a page of the probe's CANDIDATES (the
+    rows of the probed keys' runs, visible or not) and the pairs among
+    them. A degree-free probe (``top`` given) whose candidates overflow
+    it takes the buffer to at most ``top`` rows, the ladder's one
+    further rung, and reads whatever lies beyond in pages of the same
+    program (`redispatch(cap, start)`). A degree-tracking probe keeps
+    doubling: its degree adds read the buffer."""
 
     def __init__(self, mat, n: int, cap: int, redispatch,
                  pay_width: int, with_degrees: bool, install, bump,
-                 top: Optional[int] = None, note_chain=None):
+                 top: Optional[int] = None, note_probe=None):
         self.mat = mat
         self.n = n
         self.cap = cap
@@ -581,7 +653,8 @@ class PendingEpochProbe:
         self.install = install        # (deg_self, deg_sink) -> None
         self.bump = bump
         self.top = None if with_degrees else top
-        self.note_chain = note_chain  # (rows of the longest chain)
+        # (rows of the longest chain, walk steps, candidates, pairs)
+        self.note_probe = note_probe
         self._degs = None             # latest (deg_self, deg_sink)
 
     def set_degs(self, deg_self, deg_sink) -> None:
@@ -591,43 +664,37 @@ class PendingEpochProbe:
         """(degrees | None, probe_idx, refs, pay_rows | None,
         old_deg | None); pairs sorted by probe row index."""
         from risingwave_tpu.common.chunk import next_pow2
-        n = self.n
-        pages, starts = [], ()
+        pages = []
         with LEDGER.kernel_scope("hash_join"):
             while True:
                 mat = jaxtools.fetch1(self.mat)
-                total = int(mat[0, 0])
-                if total <= self.cap:
+                candidates = int(mat[1, 0])
+                if candidates <= self.cap:
                     break
                 if self.top is not None and self.cap >= self.top:
                     # the last rung: the same program, once per page
-                    starts = range(self.cap, total, self.cap)
-                    later = [self.redispatch(self.cap, start)
-                             for start in starts]
+                    later = [self.redispatch(self.cap, start) for start
+                             in range(self.cap, candidates, self.cap)]
                     for m in later:
                         jaxtools.start_fetch(m)
                     pages = [jaxtools.fetch1(m) for m in later]
                     break
                 self.cap = self.top if self.top is not None \
-                    else max(self.cap * 2, next_pow2(total))
+                    else max(self.cap * 2, next_pow2(candidates))
                 if self.bump is not None:
                     self.bump(self.cap)
                 self.mat = self.redispatch(self.cap)
                 jaxtools.start_fetch(self.mat)
-        if self.note_chain is not None:
-            self.note_chain(int(mat[0, 1]))
         if self.with_degrees and self._degs is not None:
             self.install(*self._degs)
-        if self.with_degrees:
-            deg = np.ascontiguousarray(mat[1:1 + n, 0])
-            pairs = mat[1 + n:1 + n + total]
-        else:
-            deg = None
-            pairs = mat[1:1 + min(total, self.cap)]
+        deg, pairs = _degrees_and_pairs(mat, self.n, self.with_degrees)
         if pages:
             pairs = np.concatenate(
-                [pairs] + [m[1:1 + min(self.cap, total - start)]
-                           for m, start in zip(pages, starts)])
+                [pairs] + [_degrees_and_pairs(m, 0, False)[1]
+                           for m in pages])
+        if self.note_probe is not None:
+            self.note_probe(int(mat[0, 1]), int(mat[1, 1]), candidates,
+                            len(pairs))
         P = self.pay_width
         pay = np.ascontiguousarray(pairs[:, 2:2 + P]) if P else None
         old = np.ascontiguousarray(pairs[:, 2 + P]) \
@@ -642,21 +709,22 @@ class JoinSideKernel:
     The key table is a DeviceHashTable (growth, load factor, sync-free
     occupancy bound all live there); on rehash its on_grow hook remaps
     `head` from old slots to new. The executor assigns row refs (host
-    pk→ref map); tombstoned refs are NOT recycled — a dead ref stays
-    linked in its chain, so reuse would splice one node into two chains
-    and create cycles. Dead refs are reclaimed wholesale by `rebuild`
-    (recovery / future compaction)."""
+    pk→ref map); tombstoned refs are NOT recycled — a dead ref keeps
+    its place in its run, so a reused one would stand in two runs and
+    `store` would outgrow the row arrays. Dead refs are reclaimed
+    wholesale by `rebuild` (recovery / compaction)."""
 
     # pre-sized like GroupedAggKernel.DEFAULT_CAPACITY: the growth
     # ladder costs a rehash + retrace per doubling, and the sync-free
     # occupancy bound drains (a blocking read) whenever an epoch's
     # rows outrun the key table
     DEFAULT_CAPACITY = 1 << 16
-    # the epoch probe's largest pair buffer. The buffer's size is a
-    # shape of the probe program: it starts at `probe_capacity`, an
-    # inner join's first overflow takes it here, and a probe with
-    # still more matches is read in pages (PendingEpochProbe), so no
-    # program is ever compiled for a size the data chose
+    # the epoch probe's largest buffer (candidates worked and pairs
+    # returned a dispatch). Its size is a shape of the probe program:
+    # it starts at `probe_capacity`, an inner join's first overflow
+    # takes it here, and a probe with still more candidates is read in
+    # pages (PendingEpochProbe), so no program is ever compiled for a
+    # size the data chose
     PROBE_CAP_TOP = 1 << 16
 
     def __init__(self, key_width: int,
@@ -673,17 +741,15 @@ class JoinSideKernel:
         self.payload_width = payload_width
         self.table = ht.DeviceHashTable(key_width, key_capacity)
         self.table.on_grow(self._on_table_grow)
-        # pair-output buffer rows for the fused probe; grows on
-        # overflow (kept generous: each size is a fresh XLA compile)
+        # buffer rows of the fused probe (candidates and pairs); grows
+        # on overflow (kept generous: each size is a fresh XLA compile)
         self._probe_cap = probe_capacity
-        # rows of the longest chain an epoch probe of THIS side walked
-        # since take_longest_chain(), from the probe's own header
+        # of the epoch probes of THIS side collected since they were
+        # last taken, from the probes' own headers: rows of the longest
+        # chain probed; [most steps a walk took, candidates, pairs]
         self._longest_chain = 0
-        self.chains = ChainState(
-            head=jnp.full(self.table.capacity, -1, dtype=jnp.int32),
-            next=jnp.full(row_capacity, -1, dtype=jnp.int32),
-            ins_seq=jnp.full(row_capacity, I32_MAX, dtype=jnp.int32),
-            del_seq=jnp.full(row_capacity, I32_MAX, dtype=jnp.int32))
+        self._probe_books = [0, 0, 0]
+        self.chains = empty_chains(self.table.capacity, row_capacity)
         self.pay = jnp.zeros((row_capacity, payload_width),
                              dtype=jnp.int32)
         # device-resident per-ref match degrees (outer/semi/anti
@@ -707,7 +773,7 @@ class JoinSideKernel:
 
     @property
     def row_capacity(self) -> int:
-        return int(self.chains.next.shape[0])
+        return int(self.chains.store.shape[0])
 
     @property
     def device_payload_bytes(self) -> int:
@@ -730,19 +796,15 @@ class JoinSideKernel:
         while new_cap <= max_ref:
             # 4x, not 2x: every growth step retraces/recompiles the
             # apply+probe programs at the new row shape (a trace on
-            # the host plus a compile); chains are 3 int32
+            # the host plus a compile); chains are 5 int32
             # arrays, so the overshoot is cheap HBM
             new_cap *= 4
         pad = new_cap - row_cap
-        self.chains = self.chains._replace(
-            next=jnp.concatenate(
-                [self.chains.next, jnp.full(pad, -1, dtype=jnp.int32)]),
-            ins_seq=jnp.concatenate(
-                [self.chains.ins_seq,
-                 jnp.full(pad, I32_MAX, dtype=jnp.int32)]),
-            del_seq=jnp.concatenate(
-                [self.chains.del_seq,
-                 jnp.full(pad, I32_MAX, dtype=jnp.int32)]))
+        fresh = empty_chains(0, pad)
+        self.chains = self.chains._replace(**{
+            f: jnp.concatenate([getattr(self.chains, f),
+                                getattr(fresh, f)])
+            for f in ROW_ARRAYS})
         self.pay = jnp.concatenate(
             [self.pay, jnp.zeros((pad, self.payload_width),
                                  dtype=jnp.int32)])
@@ -871,12 +933,24 @@ class JoinSideKernel:
 
     def take_longest_chain(self) -> int:
         """Rows of the longest chain the epoch probes collected since
-        the last call walked (0: none collected)."""
+        the last call probed, visible or not (0: none collected)."""
         longest, self._longest_chain = self._longest_chain, 0
         return longest
 
-    def _note_chain(self, rows: int) -> None:
-        self._longest_chain = max(self._longest_chain, rows)
+    def take_probe_books(self) -> tuple:
+        """(most steps a walk over the runs took, candidates expanded,
+        pairs kept) of the epoch probes collected since the last
+        call."""
+        books, self._probe_books = self._probe_books, [0, 0, 0]
+        return tuple(books)
+
+    def _note_probe(self, longest: int, steps: int, candidates: int,
+                    pairs: int) -> None:
+        self._longest_chain = max(self._longest_chain, longest)
+        books = self._probe_books
+        books[0] = max(books[0], steps)
+        books[1] += candidates
+        books[2] += pairs
 
     def probe_epoch(self, up_dev, aux_dev, with_degrees: bool,
                     sink: "JoinSideKernel" = None, prelude=None,
@@ -928,7 +1002,7 @@ class JoinSideKernel:
             pay_width=self.payload_width, with_degrees=with_degrees,
             install=install, bump=bump,
             top=max(self.PROBE_CAP_TOP, out_cap),
-            note_chain=self._note_chain)
+            note_probe=self._note_probe)
         if with_degrees:
             pending.set_degs(d_self, d_sink)
         return pending
@@ -973,7 +1047,7 @@ class JoinSideKernel:
         """Reload all live rows (recovery, compaction): a batched
         insert in pages of the bulk rung, the last page first, so that
         a key's rows stand in its chain in the order they were given,
-        as one batch would link them. ``payload`` (int32[n,
+        as one batch would link them (a run a key a page). ``payload`` (int32[n,
         payload_width]) rebuilds the device payload lanes exactly
         where the chains rebuild; degrees reset to zero and are
         recomputed by the caller's batch probe."""
@@ -985,11 +1059,7 @@ class JoinSideKernel:
                       1 << int(np.ceil(np.log2(max(n + 1, 2)))))
         self.table = ht.DeviceHashTable(self.key_width, key_cap)
         self.table.on_grow(self._on_table_grow)
-        self.chains = ChainState(
-            head=jnp.full(self.table.capacity, -1, dtype=jnp.int32),
-            next=jnp.full(row_cap, -1, dtype=jnp.int32),
-            ins_seq=jnp.full(row_cap, I32_MAX, dtype=jnp.int32),
-            del_seq=jnp.full(row_cap, I32_MAX, dtype=jnp.int32))
+        self.chains = empty_chains(self.table.capacity, row_cap)
         self.pay = jnp.zeros((row_cap, self.payload_width),
                              dtype=jnp.int32)
         self.deg = jnp.zeros(row_cap, dtype=jnp.int32)

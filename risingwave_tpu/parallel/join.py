@@ -32,8 +32,8 @@ from risingwave_tpu.common.hash import VNODE_COUNT
 from risingwave_tpu.ops import hash_table as ht
 from risingwave_tpu.ops.hash_join import (
     AUX_DEL_REF, AUX_FLAGS, AUX_INS_REF, AUX_SEQ, FLAG_DEL, FLAG_INS,
-    FLAG_PROBE, I32_MAX, ChainState, _remap_head, link_rows,
-    probe_pairs, tombstone_rows,
+    FLAG_PROBE, HEADER_ROWS, I32_MAX, ROW_ARRAYS, _remap_head,
+    empty_chains, link_rows, probe_pairs, tombstone_rows,
 )
 from risingwave_tpu.parallel.exchange import (
     MESH_KERNELS, bucketize_by_owner, exchange, note_launch,
@@ -106,7 +106,9 @@ class ShardedPendingProbe:
                     raise RuntimeError(
                         "bucket overflow routing join rows")
                 mats = np.asarray(jaxtools.fetch1(self.mats))
-                worst = int(mats[:, 0, 0].max())
+                # a shard's candidates (ops/hash_join._match_runs)
+                # have to fit its buffer
+                worst = int(mats[:, 1, 0].max())
                 if worst <= self.out_cap:
                     break
                 while k.probe_capacity < worst:
@@ -114,16 +116,17 @@ class ShardedPendingProbe:
                 self.out_cap = k.probe_capacity
                 self.mats, self.overflow = k._dispatch_probe(
                     self.key_lanes, self.vis, self.seq, self.out_cap)
-        m = mats.shape[1] - 1 - self.out_cap
+        at = HEADER_ROWS
+        m = mats.shape[1] - at - self.out_cap
         deg = np.zeros(self.n, dtype=np.int32)
         probes, refs = [], []
         for d in range(mats.shape[0]):
-            blk = mats[d, 1:1 + m]
+            blk = mats[d, at:at + m]
             rid, dg = blk[:, 1], blk[:, 0]
             sel = rid >= 0
             deg[rid[sel]] = dg[sel]
             total = int(mats[d, 0, 0])
-            pairs = mats[d, 1 + m:1 + m + total]
+            pairs = mats[d, at + m:at + m + total]
             probes.append(pairs[:, 0])
             refs.append(pairs[:, 1])
         probe_idx = np.concatenate(probes) if probes else \
@@ -137,8 +140,8 @@ class ShardedPendingEpochProbe:
     """In-flight sharded EPOCH probe (ops/hash_join.PendingEpochProbe
     parity over the per-shard packed matrices).
 
-    collect() parses each shard's [1 + (m) + out_cap, 2] block —
-    header, per-routed-row degree rows (with_degrees only), then
+    collect() parses each shard's [HEADER_ROWS + (m) + out_cap, 2]
+    block — header, per-routed-row degree rows (with_degrees only), then
     (global probe row, ref) pairs — scatters degrees back to the
     global epoch row space and concatenates pairs sorted stably by
     probe row. A probe row's key routes to exactly ONE owner shard, so
@@ -171,26 +174,27 @@ class ShardedPendingEpochProbe:
                     raise RuntimeError(
                         "bucket overflow routing epoch join probes")
                 mats = np.asarray(jaxtools.fetch1(self.mats))
-                worst = int(mats[:, 0, 0].max())
+                worst = int(mats[:, 1, 0].max())     # candidates
                 if worst <= self.out_cap:
                     break
                 while k.probe_capacity < worst:
                     k.probe_capacity *= 2
                 self.out_cap = k.probe_capacity
                 self.mats, self.overflow = self.redispatch(self.out_cap)
-        m = mats.shape[1] - 1 - self.out_cap
+        at = HEADER_ROWS
+        m = mats.shape[1] - at - self.out_cap
         deg = None
         if self.with_degrees:
             deg = np.zeros(self.n, dtype=np.int32)
         probes, refs = [], []
         for d in range(mats.shape[0]):
             if self.with_degrees:
-                blk = mats[d, 1:1 + m]
+                blk = mats[d, at:at + m]
                 rid, dg = blk[:, 1], blk[:, 0]
                 sel = rid >= 0
                 deg[rid[sel]] = dg[sel]
             total = int(mats[d, 0, 0])
-            pairs = mats[d, 1 + m:1 + m + total]
+            pairs = mats[d, at + m:at + m + total]
             probes.append(pairs[:, 0])
             refs.append(pairs[:, 1])
         probe_idx = np.concatenate(probes) if probes else \
@@ -286,15 +290,9 @@ class ShardedJoinKernel:
         table = ht.make_state(self.key_capacity, self.key_width)
         self.table = ht.TableState(self._stack(table.keys),
                                    self._stack(table.occ))
-        self.chains = ChainState(
-            head=self._stack(jnp.full(self.key_capacity, -1,
-                                      dtype=jnp.int32)),
-            next=self._stack(jnp.full(self._row_capacity, -1,
-                                      dtype=jnp.int32)),
-            ins_seq=self._stack(jnp.full(self._row_capacity, I32_MAX,
-                                         dtype=jnp.int32)),
-            del_seq=self._stack(jnp.full(self._row_capacity, I32_MAX,
-                                         dtype=jnp.int32)))
+        self.chains = jax.tree.map(
+            self._stack,
+            empty_chains(self.key_capacity, self._row_capacity))
 
     # -- capacity management (state > device: grows, never fatal) ---------
     def _owners_host(self, key_lanes: np.ndarray) -> np.ndarray:
@@ -344,8 +342,7 @@ class ShardedJoinKernel:
             nt, slots, _ins = ht.probe_insert(nt, t.keys, t.occ)
             head = _remap_head(c.head, jnp.where(t.occ, slots, -1),
                                new_capacity)
-            nc = ChainState(head=head, next=c.next,
-                            ins_seq=c.ins_seq, del_seq=c.del_seq)
+            nc = c._replace(head=head)
             return (jax.tree.map(lambda a: a[None], nt),
                     jax.tree.map(lambda a: a[None], nc))
 
@@ -372,17 +369,11 @@ class ShardedJoinKernel:
         new_capacity = max(new_capacity, self._row_capacity * 4)
         pad = new_capacity - self._row_capacity
 
-        def padded(a, fill):
-            p = jax.device_put(
-                jnp.broadcast_to(
-                    jnp.full(pad, fill, dtype=a.dtype)[None],
-                    (self.n_dev, pad)), self._sharding)
-            return jnp.concatenate([a, p], axis=1)
-
-        self.chains = self.chains._replace(
-            next=padded(self.chains.next, -1),
-            ins_seq=padded(self.chains.ins_seq, I32_MAX),
-            del_seq=padded(self.chains.del_seq, I32_MAX))
+        fresh = empty_chains(0, pad)
+        self.chains = self.chains._replace(**{
+            f: jnp.concatenate([getattr(self.chains, f),
+                                self._stack(getattr(fresh, f))], axis=1)
+            for f in ROW_ARRAYS})
         self._row_capacity = new_capacity
 
     def reserve_rows(self, max_ref: int) -> None:
@@ -410,6 +401,25 @@ class ShardedJoinKernel:
         rlanes = recv[0].reshape(m, lanes.shape[1])
         flat = [r.reshape(m) for r in recv[1:]]
         return rlanes, flat, rvalid.reshape(m), overflow
+
+    @staticmethod
+    def _global_rows(mat, m: int, rids, rvalid):
+        """A shard's `probe_pairs` matrix over its ``m`` routed rows
+        (0: it carries no degree rows), in the GLOBAL row ids they were
+        routed with: header; (deg, rid) block; (global probe row, ref)
+        pairs."""
+        at = HEADER_ROWS
+        pairs = mat[at + m:]
+        gprobe = jnp.where(pairs[:, 0] >= 0,
+                           rids[jnp.maximum(pairs[:, 0], 0)],
+                           jnp.int32(-1))
+        parts = [mat[:at]]
+        if m:
+            parts.append(jnp.stack(
+                [mat[at:at + m, 0],
+                 jnp.where(rvalid, rids, jnp.int32(-1))], axis=1))
+        parts.append(jnp.stack([gprobe, pairs[:, 1]], axis=1))
+        return jnp.concatenate(parts, axis=0)
 
     def _statics(self) -> tuple:
         """The closure-baked shape statics every step key carries."""
@@ -445,18 +455,7 @@ class ShardedJoinKernel:
             my_t2, slots, _ins = ht.probe_insert(my_t, rlanes, rim)
             ch = link_rows(my_c, slots, rrefs, rim, cap, seq)
             ch = tombstone_rows(ch, rdrefs, rdm, seq)
-            # output [1 + m + out_cap, 2]: header; (deg, rid) block;
-            # (global probe row, ref) pairs
-            deg_blk = jnp.stack(
-                [mat[1:1 + m, 0],
-                 jnp.where(rvalid, rids, jnp.int32(-1))], axis=1)
-            pairs = mat[1 + m:]
-            safe = jnp.maximum(pairs[:, 0], 0)
-            gprobe = jnp.where(pairs[:, 0] >= 0, rids[safe],
-                               jnp.int32(-1))
-            out = jnp.concatenate(
-                [mat[:1], deg_blk,
-                 jnp.stack([gprobe, pairs[:, 1]], axis=1)], axis=0)
+            out = ShardedJoinKernel._global_rows(mat, m, rids, rvalid)
             return (jax.tree.map(lambda a: a[None], my_t2),
                     jax.tree.map(lambda a: a[None], ch),
                     out[None], ovf[None])
@@ -489,16 +488,7 @@ class ShardedJoinKernel:
                 owner_map, lanes, [rowids], vis, n_dev, bucket)
             m = n_dev * bucket
             mat = probe_pairs(t, c, rlanes, rvalid, seq, out_cap)
-            deg_blk = jnp.stack(
-                [mat[1:1 + m, 0],
-                 jnp.where(rvalid, rids, jnp.int32(-1))], axis=1)
-            pairs = mat[1 + m:]
-            safe = jnp.maximum(pairs[:, 0], 0)
-            gprobe = jnp.where(pairs[:, 0] >= 0, rids[safe],
-                               jnp.int32(-1))
-            out = jnp.concatenate(
-                [mat[:1], deg_blk,
-                 jnp.stack([gprobe, pairs[:, 1]], axis=1)], axis=0)
+            out = ShardedJoinKernel._global_rows(mat, m, rids, rvalid)
             return out[None], ovf[None]
 
         tspec, cspec = self._specs()
@@ -811,21 +801,9 @@ class ShardedJoinKernel:
             m = n_dev * bucket
             mat = probe_pairs(t, c, rlanes, rvalid, rseq, out_cap,
                               with_degrees=with_degrees)
-            if with_degrees:
-                deg_blk = jnp.stack(
-                    [mat[1:1 + m, 0],
-                     jnp.where(rvalid, rids, jnp.int32(-1))], axis=1)
-                pairs = mat[1 + m:]
-            else:
-                deg_blk = None
-                pairs = mat[1:]
-            safe = jnp.maximum(pairs[:, 0], 0)
-            gprobe = jnp.where(pairs[:, 0] >= 0, rids[safe],
-                               jnp.int32(-1))
-            gpairs = jnp.stack([gprobe, pairs[:, 1]], axis=1)
-            parts = [mat[:1], gpairs] if deg_blk is None else \
-                [mat[:1], deg_blk, gpairs]
-            return jnp.concatenate(parts, axis=0)[None], ovf[None]
+            out = ShardedJoinKernel._global_rows(
+                mat, m if with_degrees else 0, rids, rvalid)
+            return out[None], ovf[None]
 
         tspec, cspec = self._specs()
         mapped = jax.shard_map(

@@ -1504,12 +1504,19 @@ class HashJoinExecutor(Executor):
             _hotkeys.note_batch_books(
                 f"join.t{side.table.table_id}", f"{self.identity}/{i}",
                 getattr(side._kernel, "take_probe_rounds", None))
-            take = getattr(side._kernel, "take_longest_chain", None)
-            if take is not None:
+            kernel = side._kernel
+            if hasattr(kernel, "take_probe_books"):
                 # from the header of the probe matrix just collected:
                 # no read of its own
+                label = f"join.t{side.table.table_id}"
+                steps, candidates, pairs = kernel.take_probe_books()
                 _METRICS.join_probe_chain.set(
-                    float(take()), kernel=f"join.t{side.table.table_id}")
+                    float(kernel.take_longest_chain()), kernel=label)
+                _METRICS.join_probe_walk_steps.set(float(steps),
+                                                   kernel=label)
+                _METRICS.join_probe_candidates.inc(float(candidates),
+                                                   kernel=label)
+                _METRICS.join_probe_pairs.inc(float(pairs), kernel=label)
 
     def _deg_replay_arrays(self, side_idx: int, max_ref: int):
         """(values, written) replay arrays for `side_idx`, grown to

@@ -397,8 +397,22 @@ class StreamingMetrics:
         self.join_probe_chain = r.gauge(
             "stream_join_probe_longest_chain",
             "rows of the longest chain the last epoch probe of a join "
-            "side walked, by kernel (join.t<state table id> of the "
-            "probed side), as the device step returned it")
+            "side probed, visible or not, by kernel (join.t<state table "
+            "id> of the probed side), as the device step returned it")
+        self.join_probe_walk_steps = r.gauge(
+            "stream_join_probe_walk_steps",
+            "steps the last epoch probe's walk took, by kernel: a step "
+            "a run (the rows one batch gave one key) of the probed key "
+            "that has the most")
+        self.join_probe_candidates = r.counter(
+            "stream_join_probe_candidates",
+            "rows of the probed keys' chains the epoch probes expanded "
+            "and tested, visible or not, by kernel")
+        self.join_probe_pairs = r.counter(
+            "stream_join_probe_pairs",
+            "rows the epoch probes found visible at their probe row's "
+            "sequence, by kernel: candidates over pairs is what "
+            "tombstones and rows of a later sequence cost")
         self.batch_skew_rows = r.counter(
             "stream_batch_skew_rows",
             "visible rows a device kernel staged, by kernel "
@@ -934,7 +948,13 @@ class MetricsHistory:
                 ("watermark_late.{source}.rows", S.watermark_late_rows,
                  "counter"),
                 ("join_probe.{kernel}.longest_chain",
-                 S.join_probe_chain, "gauge")):
+                 S.join_probe_chain, "gauge"),
+                ("join_probe.{kernel}.walk_steps",
+                 S.join_probe_walk_steps, "gauge"),
+                ("join_probe.{kernel}.candidates",
+                 S.join_probe_candidates, "counter"),
+                ("join_probe.{kernel}.pairs", S.join_probe_pairs,
+                 "counter")):
             out += [(name.format(**l), v, kind)
                     for l, v in metric.series()]
         for field, metric, kind in (

@@ -16,6 +16,7 @@ import pytest
 from risingwave_tpu.common.chunk import Op, StreamChunk
 from risingwave_tpu.common.epoch import Epoch, EpochPair
 from risingwave_tpu.common.types import DataType, Schema
+from risingwave_tpu.ops import hash_join as hj
 from risingwave_tpu.ops.hash_join import JoinSideKernel
 from risingwave_tpu.state.state_table import StateTable
 from risingwave_tpu.state.store import MemoryStateStore
@@ -86,6 +87,280 @@ def test_join_kernel_random_oracle():
             got[int(probe_keys[p, 0])].add(r)
         for q in range(14):
             assert got[q] == oracle[q], f"key {q}"
+
+
+# -- the probe over runs vs a plain reference ------------------------------
+
+
+class ChainModel:
+    """A join side as plain lists: a key's chain is its rows, newest
+    batch first and batch order inside a batch; a probe at sequence s
+    sees a row when ``ins_seq < s <= del_seq``."""
+
+    def __init__(self):
+        self.batches = []            # (keys, refs, seqs), oldest first
+        self.del_seq = {}
+
+    def link(self, keys, refs, seqs):
+        self.batches.append((list(keys), list(refs), list(seqs)))
+
+    def tombstone(self, refs, seqs):
+        self.del_seq.update(zip(refs, seqs))
+
+    def probe(self, keys, seqs):
+        """(probe_idx, refs) of the pairs in order, pairs per probe
+        row, chain rows (visible or not) per probe row."""
+        chains = defaultdict(list)
+        for bkeys, brefs, bseqs in reversed(self.batches):
+            for k, r, s in zip(bkeys, brefs, bseqs):
+                chains[k].append((r, s))
+        pidx, prefs, deg, rows = [], [], [], []
+        for i, (k, s) in enumerate(zip(keys, seqs)):
+            chain = chains.get(k, [])
+            seen = [r for r, ins in chain
+                    if ins < s <= self.del_seq.get(r, (1 << 31) - 1)]
+            pidx += [i] * len(seen)
+            prefs += seen
+            deg.append(len(seen))
+            rows.append(len(chain))
+        return pidx, prefs, deg, rows
+
+
+def _side(**kw):
+    return JoinSideKernel(key_width=1, payload_width=3, **kw)
+
+
+def _epoch(kernel, keys, refs, seqs, flags):
+    """Stage one side's epoch on `kernel`: a key lane, payload lanes
+    that repeat the ref, and the aux columns of ops/hash_join.py (a
+    FLAG_DEL row names its ref in the delete column)."""
+    n = len(keys)
+    flags = np.broadcast_to(np.asarray(flags, dtype=np.int32), (n,))
+    refs = np.asarray(refs, dtype=np.int32)
+    up = np.zeros((n, 4), dtype=np.int32)
+    up[:, 0] = keys
+    up[:, 1:] = refs[:, None]
+    aux = np.zeros((n, 4), dtype=np.int32)
+    aux[:, hj.AUX_INS_REF] = refs
+    aux[:, hj.AUX_DEL_REF] = refs
+    aux[:, hj.AUX_FLAGS] = flags
+    aux[:, hj.AUX_SEQ] = seqs
+    ins = refs[(flags & hj.FLAG_INS) != 0]
+    max_ref = int(ins.max()) if len(ins) else -1
+    up_d, aux_d, _b = kernel.stage_epoch(up, aux, n, max_ref)
+    return up_d, aux_d, n, max_ref
+
+
+def _link(kernel, model, keys, refs, seqs):
+    up_d, aux_d, n, max_ref = _epoch(kernel, keys, refs, seqs,
+                                     hj.FLAG_INS)
+    kernel.apply_epoch(up_d, aux_d, n, max_ref)
+    model.link(keys, refs, seqs)
+
+
+def _tombstone(kernel, model, refs, seqs):
+    up_d, aux_d, n, max_ref = _epoch(kernel, [0] * len(refs), refs, seqs,
+                                     hj.FLAG_DEL)
+    kernel.apply_epoch(up_d, aux_d, n, max_ref)
+    model.tombstone(refs, seqs)
+
+
+def _three_batches(kernel, model, keys_of_batch, first_seq=1):
+    ref = 0
+    for b, keys in enumerate(keys_of_batch):
+        refs = np.arange(ref, ref + len(keys))
+        _link(kernel, model, keys, refs, [first_seq + b] * len(keys))
+        ref += len(keys)
+    return ref
+
+
+def _case_one_key_of_5000_rows():
+    k, m = _side(), ChainModel()
+    _three_batches(k, m, [[7] * 2000, [7] * 2500, [7] * 500])
+    return k, m, [7, 8, 7], [9, 9, 3], dict(steps=3, longest=5000)
+
+
+def _case_4096_keys_of_one_row():
+    k, m = _side(), ChainModel()
+    keys = np.random.default_rng(3).permutation(4096) * 3
+    _link(k, m, keys.tolist(), np.arange(4096), [1] * 4096)
+    probes = np.concatenate([keys[::-1], [1, 2]]).tolist()
+    return k, m, probes, [5] * len(probes), dict(steps=1, longest=1)
+
+
+def _hot_and_quiet(rng, n):
+    return np.where(rng.random(n) < 0.6, 11,
+                    rng.integers(100, 160, n)).tolist()
+
+
+def _case_a_hot_key_among_quiet_ones():
+    k, m = _side(), ChainModel()
+    rng = np.random.default_rng(5)
+    _three_batches(k, m, [_hot_and_quiet(rng, 700) for _ in range(3)])
+    probes = [11, 100, 11, 999] + list(range(100, 160))
+    return k, m, probes, [9] * len(probes), dict(steps=3)
+
+
+def _case_rows_of_a_later_sequence():
+    """q4's case: the probing rows come before the stored rows of
+    their own epoch, so every candidate is invisible."""
+    k, m = _side(), ChainModel()
+    rng = np.random.default_rng(6)
+    keys = _hot_and_quiet(rng, 900)
+    _link(k, m, keys, np.arange(900), np.arange(100, 1000).tolist())
+    return k, m, [11, 100, 101], [50, 60, 100], \
+        dict(steps=1, pairs=0, some_candidates=True)
+
+
+def _case_tombstones_before_and_after_the_probe():
+    k, m = _side(), ChainModel()
+    rng = np.random.default_rng(7)
+    n = _three_batches(
+        k, m, [_hot_and_quiet(rng, 300) for _ in range(3)], first_seq=2)
+    dead = rng.choice(n, size=400, replace=False)
+    _tombstone(k, m, dead.tolist(), rng.integers(3, 12, 400).tolist())
+    probes = [11] * 6 + [100, 120, 140]
+    return k, m, probes, [2, 3, 5, 8, 11, 12, 6, 7, 30], dict(steps=3)
+
+
+def _case_candidates_past_the_buffer():
+    """300 candidates through a buffer of 8: the rung to 32, then
+    pages of 32 by their first candidate."""
+    k, m = _side(probe_capacity=8), ChainModel()
+    k.PROBE_CAP_TOP = 32
+    n = _three_batches(k, m, [[7] * 40, [7, 9] * 20, [7] * 20])
+    _tombstone(k, m, list(range(0, n, 3)), [4] * len(range(0, n, 3)))
+    return k, m, [7, 9, 7, 7, 5], [9, 9, 3, 4, 9], \
+        dict(steps=3, longest=80, cap=32)
+
+
+def _case_a_key_table_growth_between_batches():
+    k, m = _side(key_capacity=1 << 10), ChainModel()
+    rng = np.random.default_rng(8)
+    cap0 = k.table.capacity
+    _three_batches(k, m, [rng.integers(0, 3000, 900).tolist()
+                          for _ in range(3)])
+    assert k.table.capacity > cap0
+    probes = list(range(0, 3000, 7))
+    return k, m, probes, [9] * len(probes), {}
+
+
+def _case_a_row_growth_between_batches():
+    k, m = _side(row_capacity=64), ChainModel()
+    rng = np.random.default_rng(9)
+    _three_batches(k, m, [_hot_and_quiet(rng, 200) for _ in range(3)])
+    assert k.row_capacity >= 600
+    probes = [11] + list(range(100, 160))
+    return k, m, probes, [9] * len(probes), dict(steps=3)
+
+
+def _case_rebuild_then_probe():
+    """A rebuild links its pages last page first: a key's rows stand
+    in the order given, a run a key a page."""
+    k, m = _side(), ChainModel()
+    rng = np.random.default_rng(10)
+    _three_batches(k, m, [_hot_and_quiet(rng, 100) for _ in range(2)])
+    keys = np.asarray(_hot_and_quiet(rng, 150), dtype=np.int32)
+    refs = np.arange(150, dtype=np.int32)
+    k._bulk.TOP = 64                       # pages of 64 rows
+    k.rebuild(keys[:, None], refs, payload=np.repeat(refs[:, None], 3, 1))
+    m = ChainModel()
+    for lo in (128, 64, 0):                # oldest run first
+        m.link(keys[lo:lo + 64].tolist(), refs[lo:lo + 64].tolist(),
+               [0] * len(refs[lo:lo + 64]))
+    probes = [11] + list(range(100, 160))
+    return k, m, probes, [1] * len(probes), dict(steps=3)
+
+
+RUN_CASES = [_case_one_key_of_5000_rows, _case_4096_keys_of_one_row,
+             _case_a_hot_key_among_quiet_ones,
+             _case_rows_of_a_later_sequence,
+             _case_tombstones_before_and_after_the_probe,
+             _case_candidates_past_the_buffer,
+             _case_a_key_table_growth_between_batches,
+             _case_a_row_growth_between_batches, _case_rebuild_then_probe]
+
+
+@pytest.mark.parametrize("with_degrees", [False, True],
+                         ids=["inner", "degrees"])
+@pytest.mark.parametrize("case", RUN_CASES,
+                         ids=[c.__name__[6:] for c in RUN_CASES])
+def test_probe_over_runs_gives_the_reference_pairs_in_order(
+        case, with_degrees):
+    """The epoch probe against `ChainModel`: the same pairs in the same
+    order, the payload and, with degrees, the per-row degrees, each
+    pair's old degree and both sides' degree arrays; the header's books
+    say what the walk did."""
+    kernel, model, keys, seqs, want = case()
+    n = len(keys)
+    sink = _side(row_capacity=max(64, n))
+    # every other probing row retracts; each is stored on the sink
+    flags = hj.FLAG_PROBE | hj.FLAG_INS \
+        | np.where(np.arange(n) % 2 == 1, hj.FLAG_NEG, 0)
+    up_d, aux_d, _n, _max = _epoch(kernel, keys, np.arange(n), seqs,
+                                   flags)
+    deg0 = np.asarray(kernel.deg).copy()
+    deg, pidx, prefs, pay, old = kernel.probe_epoch(
+        up_d, aux_d, with_degrees, sink=sink).collect()
+    w_pidx, w_prefs, w_deg, w_rows = model.probe(keys, seqs)
+    np.testing.assert_array_equal(pidx, w_pidx)
+    np.testing.assert_array_equal(prefs, w_prefs)
+    np.testing.assert_array_equal(pay, np.repeat(prefs[:, None], 3, 1))
+    if with_degrees:
+        np.testing.assert_array_equal(deg, w_deg)
+        sign = np.where(np.arange(n) % 2 == 1, -1, 1)
+        after, w_old = deg0.copy(), []
+        for p, r in zip(w_pidx, w_prefs):     # the host's replay
+            w_old.append(deg0[r])
+            after[r] += sign[p]
+        np.testing.assert_array_equal(old, w_old)
+        np.testing.assert_array_equal(np.asarray(kernel.deg), after)
+        np.testing.assert_array_equal(np.asarray(sink.deg)[:n], w_deg)
+    else:
+        assert deg is None and old is None
+        np.testing.assert_array_equal(np.asarray(kernel.deg), deg0)
+    steps, candidates, pairs = kernel.take_probe_books()
+    assert kernel.take_longest_chain() == max(w_rows)
+    assert (candidates, pairs) == (sum(w_rows), len(w_pidx))
+    assert steps == want.get("steps", steps)
+    assert max(w_rows) == want.get("longest", max(w_rows))
+    assert pairs == want.get("pairs", pairs)
+    if want.get("some_candidates"):
+        assert candidates > 0
+    if "cap" in want and not with_degrees:
+        # one further rung, then pages: never a size the data chose
+        assert kernel._probe_cap == want["cap"] < candidates
+    assert kernel.take_probe_books() == (0, 0, 0)
+
+
+def test_chunk_probe_over_runs_keeps_chain_order():
+    """The per-chunk probe (`probe_pairs`, one sequence a call) walks
+    the same runs: a hot key's pairs come newest batch first, batch
+    order inside a batch, and a buffer of one row doubles until the
+    candidates fit."""
+    k, m = JoinSideKernel(key_width=1, probe_capacity=1), ChainModel()
+    rng = np.random.default_rng(12)
+    ref = 0
+    for seq in (1, 2, 3):
+        keys = _hot_and_quiet(rng, 200)
+        refs = np.arange(ref, ref + 200, dtype=np.int32)
+        ref += 200
+        k.insert(jnp.asarray(keys, dtype=jnp.int32)[:, None], refs,
+                 jnp.ones(200, dtype=bool), seq=seq)
+        m.link(keys, refs.tolist(), [seq] * 200)
+    dead = rng.choice(600, size=150, replace=False).astype(np.int32)
+    k.delete(dead, jnp.ones(150, dtype=bool), seq=3)
+    m.tombstone(dead.tolist(), [3] * 150)
+    probes = [11, 100, 11, 777] + list(range(100, 130))
+    for seq in (2, 3, 4):
+        deg, pidx, prefs = k.probe(
+            jnp.asarray(probes, dtype=jnp.int32)[:, None],
+            jnp.ones(len(probes), dtype=bool), seq=seq)
+        w_pidx, w_prefs, w_deg, _rows = m.probe(probes,
+                                                [seq] * len(probes))
+        np.testing.assert_array_equal(pidx, w_pidx)
+        np.testing.assert_array_equal(prefs, w_prefs)
+        np.testing.assert_array_equal(deg, w_deg)
 
 
 # -- executor-level oracle ----------------------------------------------

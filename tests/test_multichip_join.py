@@ -50,11 +50,55 @@ def test_sharded_join_matches_single_chip(eight_devices):
         assert sum(len(v) for v in got.values()) == int(deg.sum())
 
 
+def test_sharded_join_walks_a_hot_keys_runs_in_chain_order(eight_devices):
+    """A hot key among quiet ones, linked in three batches and partly
+    tombstoned: every shard's probe steps its keys' runs, and the pairs
+    come back as the single-chip kernel gives them, order and all
+    (newest batch first, batch order inside a batch); a buffer too
+    small for a shard's candidates doubles until they fit."""
+    mesh = Mesh(np.asarray(eight_devices), ("d",))
+    sharded = ShardedJoinKernel(mesh, key_width=2, key_capacity=1 << 10,
+                                row_capacity=1 << 10, probe_capacity=8)
+    single = JoinSideKernel(key_width=2)
+    rng = np.random.default_rng(13)
+
+    def lanes_of(keys):
+        hi, lo = lanes.split_i64(np.asarray(keys, dtype=np.int64)
+                                 * 5_000_000_017)
+        return np.stack([hi, lo], axis=1)
+
+    ref = 0
+    for seq in (1, 2, 3):
+        keys = np.where(rng.random(160) < 0.6, 11,
+                        rng.integers(100, 140, 160))
+        refs = np.arange(ref, ref + 160, dtype=np.int32)
+        ref += 160
+        vis = np.ones(160, dtype=bool)
+        sharded.insert(lanes_of(keys), refs, vis, seq=seq)
+        single.insert(jnp.asarray(lanes_of(keys)), refs,
+                      jnp.asarray(vis), seq=seq)
+        dead = rng.choice(160, size=40, replace=False)
+        sharded.delete(refs[dead], np.ones(40, dtype=bool), seq=seq + 1,
+                       key_lanes=lanes_of(keys[dead]))
+        single.delete(refs[dead], jnp.ones(40, dtype=bool), seq=seq + 1)
+    probes = lanes_of([11, 100, 11, 999] + list(range(100, 140)))
+    pvis = np.ones(len(probes), dtype=bool)
+    for seq in (2, 3, 5):
+        gdeg, gp, gr = sharded.probe(probes, pvis, seq=seq)
+        deg, sp, sr = single.probe(jnp.asarray(probes),
+                                   jnp.asarray(pvis), seq=seq)
+        np.testing.assert_array_equal(gp, sp)
+        np.testing.assert_array_equal(gr, sr)
+        np.testing.assert_array_equal(gdeg, deg)
+    assert sharded.probe_capacity > 8
+    assert len(sp) > 100
+
+
 def test_sharded_join_state_is_sharded(eight_devices):
     mesh = Mesh(np.asarray(eight_devices), ("d",))
     s = ShardedJoinKernel(mesh, key_width=2, key_capacity=1 << 10)
     specs = {str(a.sharding.spec) for a in
-             [s.table.keys, s.chains.head, s.chains.next]}
+             [s.table.keys, s.chains.head, s.chains.store]}
     assert all("'d'" in x for x in specs), specs
 
 

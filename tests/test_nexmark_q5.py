@@ -279,6 +279,23 @@ def test_the_longest_chain_is_a_windows_groups(q5):
                     for h in run_["history"].values()) < fullest
 
 
+def test_the_walk_steps_a_windows_runs_not_its_rows(q5):
+    """The probe steps a key's runs (the barriers that brought the
+    window rows), not its rows: where the count side's chain holds 500
+    rows and more, the walk takes a few dozen steps at most; what it
+    expands and what it keeps are counted beside it."""
+    run_ = q5["lockstep"]
+    left, _right = run_["sides"]
+    long_ones = [h for h in run_["history"].values()
+                 if h.get(f"join_probe.{left}.longest_chain", 0) >= 500]
+    assert long_ones
+    for h in long_ones:
+        assert 1 <= h[f"join_probe.{left}.walk_steps"] < 64
+    candidates = _sum(run_, f"join_probe.{left}.candidates")
+    assert candidates >= _sum(run_, f"join_probe.{left}.pairs") > 0
+    assert candidates >= 500
+
+
 def test_readers_of_a_program_without_the_books_read_nothing():
     record = {"history": {1: {"ts": 1.0, "interval_s": 0.5,
                               "source_rows": 10.0,

@@ -128,17 +128,22 @@ def _agg_state(sharding, capacity, key_width, specs):
         lambda: hash_agg.make_agg_state(capacity, key_width, specs)))
 
 
+def _chains(sharding, keys, rows, stack=()):
+    """A join side's chain arrays (head, the runs' store, the
+    sequences) at these capacities, `stack` a mesh's leading axis."""
+    return _on(sharding, jax.eval_shape(
+        lambda: jax.tree.map(
+            lambda a: jnp.broadcast_to(a, stack + a.shape),
+            hash_join.empty_chains(keys, rows))))
+
+
 def _join_state(sharding, kernel):
     """(table, chains, pay, deg) of a one-chip join side at the real
     capacities, with this kernel's key and payload widths."""
     table = _on(sharding, jax.eval_shape(
         lambda: hash_table.make_state(JOIN_KEYS, kernel.key_width)))
     i32 = jnp.int32
-    chains = hash_join.ChainState(
-        head=_sds(sharding, (JOIN_KEYS,), i32),
-        next=_sds(sharding, (JOIN_ROWS,), i32),
-        ins_seq=_sds(sharding, (JOIN_ROWS,), i32),
-        del_seq=_sds(sharding, (JOIN_ROWS,), i32))
+    chains = _chains(sharding, JOIN_KEYS, JOIN_ROWS)
     pay = _sds(sharding, (JOIN_ROWS, kernel.payload_width), i32)
     deg = _sds(sharding, (JOIN_ROWS,), i32)
     return table, chains, pay, deg
@@ -358,11 +363,7 @@ def test_q5_probe_of_the_count_side_in_pages(served_q5, one_chip):
     i32 = jnp.int32
     table = _on(one_chip, jax.eval_shape(
         lambda: hash_table.make_state(1 << 16, kernel.key_width)))
-    chains = hash_join.ChainState(
-        head=_sds(one_chip, (1 << 16,), i32),
-        next=_sds(one_chip, (1 << 20,), i32),
-        ins_seq=_sds(one_chip, (1 << 20,), i32),
-        del_seq=_sds(one_chip, (1 << 20,), i32))
+    chains = _chains(one_chip, 1 << 16, 1 << 20)
     pay = _sds(one_chip, (1 << 20, kernel.payload_width), i32)
     deg = _sds(one_chip, (1 << 20,), i32)
     raw_w = probe_jit._args[5].shape[1]
@@ -440,9 +441,7 @@ def test_parallel_join_epoch_apply(mesh):
 
     table = hash_table.TableState(keys=stacked((keys, kw)),
                                   occ=stacked((keys,), jnp.bool_))
-    chains = hash_join.ChainState(
-        head=stacked((keys,)), next=stacked((rows,)),
-        ins_seq=stacked((rows,)), del_seq=stacked((rows,)))
+    chains = _chains(sharded, keys, rows, stack=(n_dev,))
     k = _bare(ShardedJoinKernel, mesh=mesh, n_dev=n_dev, key_width=kw,
               key_capacity=keys, _row_capacity=rows, table=table,
               chains=chains)
