@@ -148,14 +148,11 @@ def join_side_ineligible_reason(join, side_idx: int) -> Optional[str]:
     """THE join-side eligibility predicate (rule, adopt guard, and
     checker all call it — the checker re-verifies ALREADY-fused sides,
     so `fused_input is not None` is deliberately not a condition).
-    The fused path needs the EPOCH dispatches (the prelude inlines
-    there — since ISSUE 10 the sharded kernels have them too, so the
-    old single-chip-only gate is gone), host-typed keys would need
-    interning inside the trace, and the cold tier reads buffered key
-    lanes the raw matrix no longer carries."""
+    The prelude inlines into the epoch dispatches, which both kernel
+    shapes have; host-typed keys would need interning inside the
+    trace, and the cold tier reads buffered key lanes the raw matrix
+    no longer carries."""
     side = join.sides[side_idx]
-    if not join._epoch_batch:
-        return "per-chunk dispatch path (epoch batching off)"
     if join.rebuild_opts.get("state_cap") is not None:
         return ("cold-tier governed join (reload reads the buffered "
                 "key lanes)")

@@ -592,8 +592,7 @@ def _prune_join(j, live_full: Set[int], stats) -> tuple:
         actor_id=opts.get("actor_id", 0), mesh=opts.get("mesh"),
         shard_opts=opts.get("shard_opts"),
         state_cap=opts.get("state_cap"),
-        device_payload=opts.get("device_payload", True),
-        epoch_batch=opts.get("epoch_batch"))
+        device_payload=opts.get("device_payload", True))
     new.feeds_agg = j.feeds_agg          # the planner's mark
     mapping = {old: new_i for old, new_i in lmap.items()}
     n_left_new = len(lnew.schema)

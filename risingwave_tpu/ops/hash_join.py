@@ -519,37 +519,6 @@ def make_prelude_epoch_jits(prelude, label: str):
                 static_argnums=(7, 8, 9)))
 
 
-def apply_and_probe(my_table: ht.TableState, my_chains: ChainState,
-                    other_table: ht.TableState, other_chains: ChainState,
-                    key_lanes: jnp.ndarray, probe_vis: jnp.ndarray,
-                    ins_refs: jnp.ndarray, ins_mask: jnp.ndarray,
-                    del_refs: jnp.ndarray, del_mask: jnp.ndarray,
-                    seq: jnp.ndarray, out_cap: int):
-    """The whole per-chunk device step as ONE dispatch.
-
-    The hot path's probe(other) + probe_insert(mine) + link +
-    tombstone would be four jit calls per chunk, each with its host
-    dispatch cost (not measured on a local chip). Fused: one call,
-    one d2h array
-    (the packed probe matrix), my-side state updated in place
-    (donated). Probe semantics are unchanged — the probe reads the
-    OTHER side at `seq` while the insert/delete lands on MY side at
-    `seq`, and sequence visibility keeps the two independent."""
-    mat = probe_pairs(other_table, other_chains, key_lanes, probe_vis,
-                      seq, out_cap)
-    my_table2, slots, ins = ht.probe_insert(my_table, key_lanes,
-                                            ins_mask)
-    chains = link_rows(my_chains, slots, ins_refs, ins_mask,
-                       my_table2.capacity, seq)
-    chains = tombstone_rows(chains, del_refs, del_mask, seq)
-    return my_table2, chains, ins, mat
-
-
-_apply_and_probe_jit = jaxtools.instrumented_jit(
-    apply_and_probe, "hash_join.apply_and_probe",
-    donate_argnums=(0, 1), static_argnums=(11,))
-
-
 def _remap_head(head: jnp.ndarray, old_to_new: jnp.ndarray,
                 new_cap: int) -> jnp.ndarray:
     safe = jnp.where(old_to_new >= 0, old_to_new, new_cap)
@@ -592,18 +561,15 @@ class PendingProbe:
     state at a larger pair capacity; `bump(cap)` records the grown
     capacity on the owning kernel."""
 
-    def __init__(self, mat, n: int, cap: int, redispatch,
-                 with_degrees: bool = True, bump=None):
+    def __init__(self, mat, n: int, cap: int, redispatch, bump):
         self.mat = mat
         self.n = n
         self.cap = cap
         self.redispatch = redispatch
-        self.with_degrees = with_degrees
         self.bump = bump
 
-    def collect(self) -> Tuple[Optional[np.ndarray], np.ndarray,
-                               np.ndarray]:
-        """(degrees | None, probe_idx[pairs], refs[pairs]). Pairs are
+    def collect(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """(degrees, probe_idx[pairs], refs[pairs]). Pairs are
         sorted by probe row index (packed in candidate order)."""
         with LEDGER.kernel_scope("hash_join"):
             while True:
@@ -613,11 +579,10 @@ class PendingProbe:
                     break
                 from risingwave_tpu.common.chunk import next_pow2
                 self.cap = max(self.cap * 2, next_pow2(candidates))
-                if self.bump is not None:
-                    self.bump(self.cap)
+                self.bump(self.cap)
                 self.mat = self.redispatch(self.cap)
                 jaxtools.start_fetch(self.mat)
-        deg, pairs = _degrees_and_pairs(mat, self.n, self.with_degrees)
+        deg, pairs = _degrees_and_pairs(mat, self.n, True)
         return (deg, np.ascontiguousarray(pairs[:, 0]),
                 np.ascontiguousarray(pairs[:, 1]))
 
@@ -830,41 +795,6 @@ class JoinSideKernel:
         self.chains = _tombstone_jit(self.chains, jnp.asarray(row_refs),
                                      vis, jnp.int32(seq))
 
-    def apply_and_probe(self, other: "JoinSideKernel",
-                        key_lanes: jnp.ndarray, probe_vis: np.ndarray,
-                        ins_refs: np.ndarray, ins_mask: np.ndarray,
-                        del_refs: np.ndarray, del_mask: np.ndarray,
-                        seq: int) -> "PendingProbe":
-        """One fused dispatch: probe `other` at `seq` + apply this
-        side's inserts/deletes at `seq`. Returns the pending probe
-        (DMA started; collect at the barrier sweep)."""
-        n = int(key_lanes.shape[0])
-        if ins_mask.any():     # ins_refs is the full chunk-width array
-            self.reserve_rows(int(ins_refs.max()))
-        self.table.reserve(n)
-        s = jnp.int32(seq)
-        out_cap = other._probe_cap
-        lanes_d = jnp.asarray(key_lanes)
-        vis_d = jnp.asarray(probe_vis)
-        self.table.state, self.chains, ins, mat = _apply_and_probe_jit(
-            self.table.state, self.chains,
-            other.table.state, other.chains,
-            lanes_d, vis_d,
-            jnp.asarray(ins_refs), jnp.asarray(ins_mask),
-            jnp.asarray(del_refs), jnp.asarray(del_mask),
-            s, out_cap)
-        self.table._counters.push(ins, n)
-        jaxtools.start_fetch(mat)
-
-        def redispatch(cap):
-            return _probe_pairs_jit(other.table.state, other.chains,
-                                    lanes_d, vis_d, s, cap, True)
-
-        def bump(cap):
-            other._probe_cap = max(other._probe_cap, cap)
-
-        return PendingProbe(mat, n, out_cap, redispatch, bump=bump)
-
     def probe_submit(self, key_lanes: jnp.ndarray, vis: jnp.ndarray,
                      seq: Optional[int] = None) -> "PendingProbe":
         """Dispatch the fused probe and kick its DMA; no blocking.
@@ -888,7 +818,7 @@ class JoinSideKernel:
             self._probe_cap = max(self._probe_cap, cap)
 
         return PendingProbe(mat, int(lanes_d.shape[0]),
-                            self._probe_cap, redispatch, bump=bump)
+                            self._probe_cap, redispatch, bump)
 
     # -- epoch batching ---------------------------------------------------
     def stage_epoch(self, up: np.ndarray, aux: np.ndarray, total: int,

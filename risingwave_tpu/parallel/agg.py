@@ -165,27 +165,21 @@ class ShardedAggKernel:
     # epoch batch bound, mirroring GroupedAggKernel.BATCH_ROWS: the
     # backlog dispatches at this many rows mid-epoch (bounds host
     # buffering and the int32 limb math), else once at the barrier
-    # flush — O(1) SPMD dispatches per epoch instead of one per chunk
-    # (each shard_map host dispatch costs ~100ms through the 4-virtual-
-    # device CPU mesh). The FIXED batch
-    # shape also means one compiled program instead of per-chunk-shape
-    # churn — the RecompileGuard's sharded contract.
+    # flush — O(1) SPMD dispatches per epoch, the only dispatch shape.
+    # The FIXED batch shape also means one compiled program instead of
+    # per-chunk-shape churn — the RecompileGuard's sharded contract.
     BATCH_ROWS = 1 << 15
 
     def __init__(self, mesh: Mesh, key_width: int,
                  specs: Sequence[AggSpec], capacity: int = 1 << 12,
                  bucket: Optional[int] = None,
-                 flush_capacity: int = 1 << 10,
-                 epoch_batch: bool = True):
+                 flush_capacity: int = 1 << 10):
         self.mesh = mesh
         self.n_dev = mesh.devices.size
         self.specs = tuple(specs)
         self.key_width = key_width
         self.capacity = capacity
         self.bucket = bucket
-        # epoch_batch=False is the per-chunk oracle arm (one SPMD
-        # dispatch per apply — the pre-ISSUE-10 behavior)
-        self.epoch_batch = bool(epoch_batch)
         self._backlog: List[np.ndarray] = []
         self._backlog_owners: List[Optional[np.ndarray]] = []
         self._backlog_rows = 0
@@ -452,8 +446,6 @@ class ShardedAggKernel:
         commute across the epoch fold (limb/count adds exactly;
         MIN/MAX idempotently), so the batched application equals the
         per-chunk one. `inputs` is per call (value lanes, valid mask).
-        With epoch_batch=False every apply dispatches immediately (the
-        per-chunk oracle arm).
         """
         assert self._prelude is None, \
             "fused kernel takes raw chunks (apply_raw)"
@@ -472,8 +464,7 @@ class ShardedAggKernel:
         # over the whole backlog): the rehash happens off the dispatch
         # path, and a table sized for its stream never re-checks
         self._reserve(self._backlog_rows)
-        if not self.epoch_batch or \
-                self._backlog_rows >= self.BATCH_ROWS:
+        if self._backlog_rows >= self.BATCH_ROWS:
             self.dispatch_backlog()
 
     def owners_of(self, key_lanes: np.ndarray) -> np.ndarray:
@@ -514,8 +505,7 @@ class ShardedAggKernel:
         self._backlog_rows += n
         self._backlog_vis += int(n_visible)
         self._reserve(self._backlog_rows)
-        if not self.epoch_batch or \
-                self._backlog_rows >= self.BATCH_ROWS:
+        if self._backlog_rows >= self.BATCH_ROWS:
             self.dispatch_backlog()
 
     def dispatch_backlog(self) -> None:

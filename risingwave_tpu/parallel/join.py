@@ -14,8 +14,9 @@ Host contract: row refs are GLOBAL (the host arena's); a ref lives
 only on its key's owner shard, so each shard's chain arrays index by
 global ref directly and probe results need no re-translation. The
 executor (stream/executors/hash_join.py) cannot tell this kernel from
-the single-chip JoinSideKernel — same apply_and_probe / probe /
-delete / rebuild / rebase_seq API, same async PendingProbe contract.
+the single-chip JoinSideKernel — same stage_epoch / apply_epoch /
+probe_epoch / probe / insert / delete / rebuild / rebase_seq API, same
+async PendingProbe contract.
 """
 
 from __future__ import annotations
@@ -425,54 +426,6 @@ class ShardedJoinKernel:
         """The closure-baked shape statics every step key carries."""
         return (self.key_width, self.key_capacity, self._row_capacity)
 
-    def _build_apply_probe(self, bucket: int, out_cap: int):
-        key = _step_key(self.mesh, "apply_probe", bucket, out_cap,
-                        *self._statics())
-        step = _STEP_CACHE.get(key)
-        if step is not None:
-            return step
-        n_dev = self.n_dev
-        cap = self.key_capacity
-
-        def local(my_t, my_c, o_t, o_c, lanes, rowids, refs, drefs,
-                  pvis, imask, dmask, seq, owner_map):
-            my_t = jax.tree.map(lambda a: a[0], my_t)
-            my_c = jax.tree.map(lambda a: a[0], my_c)
-            o_t = jax.tree.map(lambda a: a[0], o_t)
-            o_c = jax.tree.map(lambda a: a[0], o_c)
-            valid = pvis | imask | dmask
-            rlanes, (rids, rrefs, rdrefs, rpv, rim, rdm), rvalid, ovf = \
-                ShardedJoinKernel._route(
-                    owner_map, lanes,
-                    [rowids, refs, drefs, pvis.astype(jnp.int32),
-                     imask.astype(jnp.int32), dmask.astype(jnp.int32)],
-                    valid, n_dev, bucket)
-            rpv = rvalid & (rpv == 1)
-            rim = rvalid & (rim == 1)
-            rdm = rvalid & (rdm == 1)
-            m = n_dev * bucket
-            mat = probe_pairs(o_t, o_c, rlanes, rpv, seq, out_cap)
-            my_t2, slots, _ins = ht.probe_insert(my_t, rlanes, rim)
-            ch = link_rows(my_c, slots, rrefs, rim, cap, seq)
-            ch = tombstone_rows(ch, rdrefs, rdm, seq)
-            out = ShardedJoinKernel._global_rows(mat, m, rids, rvalid)
-            return (jax.tree.map(lambda a: a[None], my_t2),
-                    jax.tree.map(lambda a: a[None], ch),
-                    out[None], ovf[None])
-
-        tspec, cspec = self._specs()
-        mapped = jax.shard_map(
-            local, mesh=self.mesh,
-            in_specs=(tspec, cspec, tspec, cspec, P(AXIS), P(AXIS),
-                      P(AXIS), P(AXIS), P(AXIS), P(AXIS), P(AXIS),
-                      P(), P()),
-            out_specs=(tspec, cspec, P(AXIS), P(AXIS)),
-            check_vma=False)
-        step = jaxtools.instrumented_jit(
-            mapped, "parallel_join.apply_probe", donate_argnums=(0, 1))
-        _STEP_CACHE[key] = step
-        return step
-
     def _build_probe_only(self, bucket: int, out_cap: int):
         key = _step_key(self.mesh, "probe_only", bucket, out_cap,
                         *self._statics())
@@ -567,9 +520,6 @@ class ShardedJoinKernel:
     # vnode owners in one all_to_all, then run the exact single-chip
     # kernels locally with PER-ROW sequences (sequence visibility makes
     # the batched application order-equivalent to per-chunk applies).
-    # On the 4-virtual-device CPU mesh each shard_map host dispatch
-    # costs ~100ms — this drops the count by the chunks-per-epoch
-    # factor.
 
     def _guard_keys_blind(self, n_ins: int) -> None:
         """Conservative key guard when host key lanes are unavailable
@@ -679,8 +629,8 @@ class ShardedJoinKernel:
 
     def _book_host(self, lanes: np.ndarray, valid: np.ndarray,
                    bucket: int) -> None:
-        """Book one exchange launch of the per-chunk and recovery
-        paths, whose key lanes are on the host."""
+        """Book one exchange launch of the reload and recovery paths
+        (probe / insert / delete), whose key lanes are on the host."""
         note_launch(self.route_label, self.n_dev, bucket)
         with route_phase("sharded_join"):
             valid = np.asarray(valid, dtype=bool)
@@ -879,39 +829,6 @@ class ShardedJoinKernel:
                 [a, np.zeros(pad_shape, dtype=a.dtype)]))
         return out, m
 
-    def apply_and_probe(self, other: "ShardedJoinKernel",
-                        key_lanes: np.ndarray, probe_vis: np.ndarray,
-                        ins_refs: np.ndarray, ins_mask: np.ndarray,
-                        del_refs: np.ndarray, del_mask: np.ndarray,
-                        seq: int) -> ShardedPendingProbe:
-        """One fused dispatch per chunk (executor hot path). All args
-        are HOST arrays — a device round-trip here would re-serialize
-        the async pipeline this kernel exists to keep non-blocking."""
-        key_lanes = np.asarray(key_lanes)
-        n = int(key_lanes.shape[0])
-        self._guard_keys(key_lanes, ins_mask)
-        self._guard_refs(ins_refs, ins_mask)
-        (lanes, rowids, refs, drefs, pv, im, dm), m = self._pad(
-            [key_lanes, np.arange(n, dtype=np.int32),
-             ins_refs.astype(np.int32), del_refs.astype(np.int32),
-             probe_vis, ins_mask, del_mask], n)
-        bucket = m // self.n_dev
-        out_cap = other.probe_capacity
-        step = self._build_apply_probe(bucket, out_cap)
-        _note_dispatch(m, "sharded_join")
-        self._book_host(lanes, pv | im | dm, bucket)
-        with LEDGER.phase("device_compute", kernel="sharded_join",
-                          stage="launch"):
-            self.table, self.chains, mats, overflow = step(
-                self.table, self.chains, other.table, other.chains,
-                jnp.asarray(lanes), jnp.asarray(rowids),
-                jnp.asarray(refs), jnp.asarray(drefs),
-                jnp.asarray(pv), jnp.asarray(im),
-                jnp.asarray(dm), jnp.int32(seq), self.owner_map)
-        jaxtools.start_fetch(mats)
-        return ShardedPendingProbe(other, mats, lanes, pv, seq,
-                                   out_cap, n, overflow=overflow)
-
     def _dispatch_probe(self, lanes: np.ndarray, vis: np.ndarray,
                         seq: int, out_cap: int):
         m = int(lanes.shape[0])
@@ -946,16 +863,16 @@ class ShardedJoinKernel:
     def probe(self, key_lanes, vis, seq: Optional[int] = None):
         return self.probe_submit(key_lanes, vis, seq).collect()
 
-    def insert(self, key_lanes: np.ndarray, refs: np.ndarray,
+    def insert(self, key_lanes: np.ndarray, row_refs: np.ndarray,
                vis: np.ndarray, seq: int = 0) -> None:
         """Routed batch insert (recovery/rebuild; tests)."""
         key_lanes = np.asarray(key_lanes)
         vis = np.asarray(vis)
         n = int(key_lanes.shape[0])
         self._guard_keys(key_lanes, vis)
-        self._guard_refs(np.asarray(refs), vis)
+        self._guard_refs(np.asarray(row_refs), vis)
         (lanes, refs_, mask), m = self._pad(
-            [key_lanes, np.asarray(refs, np.int32), vis], n)
+            [key_lanes, np.asarray(row_refs, np.int32), vis], n)
         bucket = m // self.n_dev
         step = self._build_insert(bucket)
         _note_dispatch(m, "sharded_join")
@@ -967,7 +884,7 @@ class ShardedJoinKernel:
         if bool(np.asarray(overflow).any()):
             raise RuntimeError("bucket overflow inserting join rows")
 
-    def delete(self, del_refs: np.ndarray, vis,
+    def delete(self, row_refs: np.ndarray, vis,
                seq: int = 0, key_lanes=None) -> None:
         """Tombstone by ref. Sharded routing needs the refs' KEY lanes
         (the owner shard is a function of the key) — callers pass them
@@ -977,7 +894,7 @@ class ShardedJoinKernel:
         vis = np.asarray(vis)
         n = int(np.asarray(key_lanes).shape[0])
         (lanes, drefs, dm), m = self._pad(
-            [np.asarray(key_lanes), np.asarray(del_refs, np.int32),
+            [np.asarray(key_lanes), np.asarray(row_refs, np.int32),
              vis], n)
         bucket = m // self.n_dev
         step = self._build_delete(bucket)

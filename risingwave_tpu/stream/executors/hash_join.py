@@ -9,18 +9,19 @@ row chains probed as whole-batch kernels; the host owns row payloads
 output chunks with vectorized gathers.
 
 Chunk lifecycle on side S (probing side O), mirroring eq_join_oneside
-but ASYNC (sequence-versioned state, see ops/hash_join.py):
-  1. dispatch: submit the fused probe against O at the chunk's message
-     sequence (DMA starts; nothing blocks) and apply the chunk to S's
-     own state at the same sequence (inserts allocate arena refs and
-     front-link; deletes tombstone)
-  2. barrier (or a watermark that must trail the data): collect every
-     in-flight probe in message order — each result is exact for its
-     sequence no matter how much state advanced — and emit: matched
-     pairs (S columns from the chunk, O columns from O's arena), outer
-     NULL-padding, semi/anti rows, and degree-transition flips. Update
-     pairs degrade to Delete+Insert, as the reference degrades split
-     pairs.
+but batched by the EPOCH (sequence-versioned state, see
+ops/hash_join.py):
+  1. ingest: host bookkeeping only — the chunk takes the next message
+     sequence, inserts allocate arena refs, deletes find theirs, and
+     its rows join S's epoch buffer; nothing is dispatched
+  2. barrier (or a watermark that must trail the data): each side's
+     buffer ships as ONE apply to S's state and ONE probe of O, every
+     row at its own sequence — each result is exact for its sequence
+     no matter how much state the epoch applied — and emission runs in
+     message order: matched pairs (S columns from the chunk, O columns
+     from O's payload lanes or arena), outer NULL-padding, semi/anti
+     rows, and degree-transition flips. Update pairs degrade to
+     Delete+Insert, as the reference degrades split pairs.
   3. both sides' StateTables commit; watermark expiry and compaction
      run AFTER the sweep (they rewrite device state that a re-
      dispatched probe would need); recovery rebuilds arena + chains
@@ -206,8 +207,8 @@ class _JoinSide:
         # the same dispatch that links chains and gathered ON DEVICE
         # by the probe's emit walk. Varchar/host-typed columns can
         # never ship to HBM — they stay arena-gathered by ref from the
-        # same packed header. Single-chip epoch path only (the sharded
-        # kernel keeps the per-chunk host-gather shape).
+        # same packed header. Single-chip only (the sharded kernel's
+        # epoch probe returns refs and the host gathers every column).
         self.device_payload = bool(device_payload) and mesh is None
         self.pay_indices: List[int] = [
             i for i, f in enumerate(schema) if f.data_type.is_device
@@ -254,7 +255,7 @@ class _JoinSide:
         # inside the probe dispatches (ops/hash_join.epoch_probe) —
         # this host array then stays empty and emission replays
         # per-chunk transitions from the packed matrix's old-degree
-        # column. The sharded per-chunk path keeps the host array.
+        # column. The sharded kernel keeps the host array.
         self.dev_degrees = mesh is None
         self.track_degrees = False      # set by the executor (tracked
         self.degrees = np.zeros(         # sides only)
@@ -418,9 +419,9 @@ class _JoinSide:
                                     np.ndarray, np.ndarray, np.ndarray]:
         """HOST half of a chunk apply: pk→ref/arena bookkeeping only.
         Returns (ins_idx, ins_refs, full_refs, ins_mask, del_refs,
-        del_mask) for ONE fused device dispatch (ops/hash_join.py
-        apply_and_probe) — per-chunk device calls are the TPU hot-path
-        cost, so the executor batches them all into one.
+        del_mask) for the side's epoch applies (ops/hash_join.py
+        epoch_apply / epoch_probe; parallel/join.py's twins) — the
+        executor buffers every chunk of an epoch into one dispatch.
 
         pk→ref bookkeeping runs in ROW ORDER (a delete refers to the
         latest same-pk version, which may be an insert earlier in this
@@ -780,8 +781,7 @@ class HashJoinExecutor(Executor):
                  join_type: JoinType = JoinType.INNER,
                  mesh=None, shard_opts: Optional[dict] = None,
                  state_cap: Optional[int] = None,
-                 device_payload: bool = True,
-                 epoch_batch: Optional[bool] = None):
+                 device_payload: bool = True):
         assert len(left_keys) == len(right_keys)
         self.left_in, self.right_in = left, right
         self.join_type = join_type
@@ -791,8 +791,7 @@ class HashJoinExecutor(Executor):
         self.rebuild_opts = {"actor_id": actor_id, "mesh": mesh,
                              "shard_opts": shard_opts,
                              "state_cap": state_cap,
-                             "device_payload": device_payload,
-                             "epoch_batch": epoch_batch}
+                             "device_payload": device_payload}
         key_codec = KeyCodec(
             [left.schema[i].data_type for i in left_keys])
         # device_payload=False forces the host-gather emit path (the
@@ -845,24 +844,10 @@ class HashJoinExecutor(Executor):
         # ops/hash_join.py) + per-epoch in-flight probe list
         self._seq = 1
         self._pending: List[tuple] = []
-        # epoch batching (ISSUE 10: now BOTH kernel shapes): chunks
-        # buffer host-side and the whole epoch ships as 2 uploads + 2
-        # dispatches per side at the barrier — O(1) dispatches per
-        # epoch instead of one per chunk (on the virtual CPU mesh a
-        # shard_map host dispatch measured ~100ms; on a local chip it
-        # is not measured) (ops/hash_join.py AUX_*; parallel/join.py epoch
-        # twins). epoch_batch=False is the sharded oracle's per-chunk
-        # off arm — single-chip kernels dropped that path in PR 9
-        # (device degrees live in the epoch dispatches).
-        # derived WITHOUT touching .kernel: the lazy property exists so
-        # plan-only processes never build device state
-        if epoch_batch is None:
-            epoch_batch = True
-        elif not epoch_batch and mesh is None:
-            raise ValueError(
-                "epoch_batch=False is the sharded per-chunk oracle "
-                "arm — the single-chip kernel is epoch-only")
-        self._epoch_batch = bool(epoch_batch)
+        # the epoch is the only dispatch shape, on both kernel shapes:
+        # chunks buffer host-side and the whole epoch ships as 2
+        # uploads + 2 dispatches per side at the barrier (ops/
+        # hash_join.py AUX_*; parallel/join.py epoch twins)
         # the planner's mark: an aggregate was planned over this join,
         # so the chunk build below is a leg of the join -> aggregate
         # hand-off
@@ -874,8 +859,8 @@ class HashJoinExecutor(Executor):
         self._tier_parts: Tuple = (None, None)
         self._tier_seq = 0
         if state_cap is not None:
-            # cold-state tier prerequisites: epoch-batched single-chip
-            # path (reload hooks the epoch dispatch), a non-semi/anti
+            # cold-state tier prerequisites: the single-chip kernel
+            # (reload hooks its epoch dispatch), a non-semi/anti
             # join (semi/anti emission depends on degree TRANSITIONS
             # whose history an eviction would lose; outer degrees are
             # pure functions of both sides' durable state and recompute
@@ -884,7 +869,7 @@ class HashJoinExecutor(Executor):
             if join_type.is_semi_or_anti or mesh is not None:
                 raise ValueError(
                     "state_cap needs an INNER or OUTER join on the "
-                    "single-chip epoch-batched path (semi/anti "
+                    "single-chip kernel (semi/anti "
                     "degree-transition history cannot be evicted)")
             for side in self.sides:
                 k = len(side.key_indices)
@@ -1056,8 +1041,8 @@ class HashJoinExecutor(Executor):
         # matched stored rows: device columns decode from the payload
         # lanes the probe's emit walk gathered ON DEVICE (one packed
         # fetch); only varchar/host columns still gather from the
-        # arena by ref. pay is None on the sharded per-chunk path and
-        # with device_payload off — full arena gather as before.
+        # arena by ref. pay is None on the sharded kernel and with
+        # device_payload off — full arena gather.
         if pay is not None and other.pay_indices:
             other_cols = other.cols_from_payload(pay, refs, cap)
         else:
@@ -1122,12 +1107,10 @@ class HashJoinExecutor(Executor):
     def _ingest_chunk(self, side_idx: int, chunk: StreamChunk,
                       key_lanes, nonnull: np.ndarray,
                       raw: Optional[np.ndarray] = None) -> None:
-        """Ingest side: host bookkeeping per chunk; device work either
-        dispatches per chunk (sharded kernel) or buffers for the ONE
-        epoch dispatch at the barrier (single-chip; sequence versioning
-        makes the batched probes exact per-row)."""
+        """Ingest side: host bookkeeping per chunk; device work
+        buffers for the ONE epoch dispatch at the barrier (sequence
+        versioning makes the batched probes exact per-row)."""
         me = self.sides[side_idx]
-        other = self.sides[1 - side_idx]
         seq = self._seq
         self._seq += 1
         vis_ops = np.asarray(chunk.ops)[np.asarray(chunk.visibility)]
@@ -1162,26 +1145,6 @@ class HashJoinExecutor(Executor):
                                  self._tier_seq, insert=False)
         (ins_idx, ins_refs, full_refs, ins_mask, del_refs,
          del_mask) = me.apply_chunk_host(chunk, nonnull)
-        if not self._epoch_batch:
-            # ins/del entries only exist at storable (= probe-visible)
-            # rows, so one mask decides both dispatch and collect.
-            # key_lanes stay HOST arrays end-to-end: the kernels upload
-            # them once; a jnp round-trip here would be a blocking read.
-            handle = None
-            if probe_vis.any():
-                # one fused apply+probe = one device dispatch; the
-                # sharded kernel counts it at its own jit site under
-                # kernel="sharded_join" (real-launch granularity)
-                with dispatch_span(self.identity,
-                                   float(probe_vis.sum()),
-                                   site="apply_and_probe"):
-                    handle = me.kernel.apply_and_probe(
-                        other.kernel, key_lanes, probe_vis,
-                        full_refs, ins_mask, del_refs, del_mask, seq)
-            self._pending.append(
-                (side_idx, chunk, nonnull, handle, ins_idx, ins_refs,
-                 0, chunk.capacity))
-            return
         from risingwave_tpu.ops.hash_join import (
             FLAG_DEL, FLAG_INS, FLAG_NEG, FLAG_PROBE,
         )
@@ -1204,8 +1167,7 @@ class HashJoinExecutor(Executor):
         aux[:, 3] = seq
         off = self._epoch_rows[side_idx]
         self._pending.append(
-            (side_idx, chunk, nonnull, None, ins_idx, ins_refs, off,
-             dn))
+            (side_idx, chunk, nonnull, ins_idx, ins_refs, off, dn))
         if raw is not None:
             # fused input side: the RAW int64 matrix is the upload —
             # the side's prelude rebuilds [key | payload] lanes inside
@@ -1440,8 +1402,8 @@ class HashJoinExecutor(Executor):
         order the chunks were applied — on the epoch path it replays
         from the packed matrix's old-degree column (the device array
         is the store; see _emit_one)."""
-        results = self._dispatch_epoch() if self._epoch_batch \
-            and (self._epoch_buf[0] or self._epoch_buf[1]) else {}
+        results = self._dispatch_epoch() \
+            if self._epoch_buf[0] or self._epoch_buf[1] else {}
         # the hand-off's first leg: from the probe result on the host
         # to the chunks the aggregate ingests
         with join_to_agg_handoff(self.feeds_agg):
@@ -1452,35 +1414,26 @@ class HashJoinExecutor(Executor):
             # lazily from the matrix old column, written through by
             # inserted-row inits and per-chunk transition deltas
             self._deg_replay = [None, None]
-            for (side_idx, chunk, nonnull, handle, ins_idx,
-                 ins_refs, off, dn) in self._pending:
-                n = chunk.capacity
-                deg = None
-                probe_idx = np.zeros(0, dtype=np.int32)
-                refs = np.zeros(0, dtype=np.int32)
-                pay = None
-                old = None
-                if handle is not None:
-                    deg_p, probe_idx, refs = handle.collect()
-                    deg = np.zeros(n, dtype=np.int64)
-                    deg[:len(deg_p)] = deg_p
-                elif side_idx in results:
-                    d_s, p_s, r_s, pay_s, old_s = results[side_idx]
-                    # the buffered epoch carries only this chunk's dense
-                    # prefix (dn rows at offset off); degrees re-pad to
-                    # the chunk's capacity for the chunk-relative masks
-                    with LEDGER.phase("host_emit", stage="join.split"):
-                        lo = np.searchsorted(p_s, off)
-                        hi = np.searchsorted(p_s, off + dn)
-                        probe_idx = (p_s[lo:hi] - off).astype(np.int32)
-                        refs = r_s[lo:hi]
-                        if pay_s is not None:
-                            pay = pay_s[lo:hi]
-                        if old_s is not None:
-                            old = old_s[lo:hi].astype(np.int64)
-                        if d_s is not None:
-                            deg = np.zeros(n, dtype=np.int64)
-                            deg[:dn] = d_s[off:off + dn]
+            for (side_idx, chunk, nonnull, ins_idx, ins_refs, off,
+                 dn) in self._pending:
+                deg = pay = old = None
+                # a pending chunk's rows are in its side's buffer
+                d_s, p_s, r_s, pay_s, old_s = results[side_idx]
+                # the buffered epoch carries only this chunk's dense
+                # prefix (dn rows at offset off); degrees re-pad to
+                # the chunk's capacity for the chunk-relative masks
+                with LEDGER.phase("host_emit", stage="join.split"):
+                    lo = np.searchsorted(p_s, off)
+                    hi = np.searchsorted(p_s, off + dn)
+                    probe_idx = (p_s[lo:hi] - off).astype(np.int32)
+                    refs = r_s[lo:hi]
+                    if pay_s is not None:
+                        pay = pay_s[lo:hi]
+                    if old_s is not None:
+                        old = old_s[lo:hi].astype(np.int64)
+                    if d_s is not None:
+                        deg = np.zeros(chunk.capacity, dtype=np.int64)
+                        deg[:dn] = d_s[off:off + dn]
                 outs.extend(self._emit_one(side_idx, chunk, nonnull, deg,
                                            probe_idx, refs, ins_idx,
                                            ins_refs, pay, old))
@@ -1561,9 +1514,6 @@ class HashJoinExecutor(Executor):
         jt = self.join_type
         me = self.sides[side_idx]
         vis = np.asarray(chunk.visibility)
-        n = chunk.capacity
-        if deg is None and jt != JoinType.INNER:
-            deg = np.zeros(n, dtype=np.int64)
         outs: List[StreamChunk] = []
         # 1) matched pairs (all types except semi/anti)
         if jt.subject is None and len(probe_idx):

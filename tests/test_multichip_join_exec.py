@@ -163,16 +163,13 @@ def test_sharded_probe_overflow_retries(eight_devices):
     """Tiny per-shard pair buffer forces the double/retry re-dispatch."""
     mesh = Mesh(np.asarray(eight_devices), ("d",))
     k = ShardedJoinKernel(mesh, key_width=1, probe_capacity=1)
-    other = ShardedJoinKernel(mesh, key_width=1, probe_capacity=1)
     lanes = np.asarray([[3]] * 9 + [[4]] * 7, dtype=np.int32)
     refs = np.arange(16, dtype=np.int32)
-    h = k.apply_and_probe(other, lanes, np.zeros(16, dtype=bool),
-                          refs, np.ones(16, dtype=bool),
-                          np.zeros(16, dtype=np.int32),
-                          np.zeros(16, dtype=bool), seq=1)
-    h.collect()
+    k.insert(lanes, refs, np.ones(16, dtype=bool), seq=1)
     probe = np.asarray([[3], [4], [5], [6]], dtype=np.int32)
     deg, pidx, prefs = k.probe(probe, np.ones(4, dtype=bool))
+    # doubled from 1 until the longest chain's candidates fit
+    assert k.probe_capacity == 16
     assert deg.tolist() == [9, 7, 0, 0]
     assert {int(r) for p, r in zip(pidx, prefs) if p == 0} == \
         set(range(9))

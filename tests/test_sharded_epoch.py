@@ -2,10 +2,11 @@
 
 The sharded join/agg kernels buffer a whole epoch's chunks host-side
 and ship ONE SPMD step per kernel at the barrier (parallel/join.py
-apply_epoch/probe_epoch, parallel/agg.py backlog) — the oracle here is
-the per-chunk dispatch path (epoch_batch=False), which must agree
-bit-identically per epoch: update pairs, NULL keys, retractions and
-mid-epoch growth included. Dispatch counts are asserted at the REAL
+apply_epoch/probe_epoch, parallel/agg.py backlog) — the reference here
+is the single-chip executor / kernel on the same input (what q8_steady
+is to q8_mesh4), which must agree per epoch: update pairs, NULL keys,
+retractions and mid-epoch growth included. Dispatch counts are asserted
+at the REAL
 shard_map launch sites (kernel="sharded_*" series) against the
 O(1)-per-epoch ceiling, and the RecompileGuard extends to steady-state
 mesh runs. Fused-mesh plans (fusion_grouping no longer refuses mesh /
@@ -26,6 +27,7 @@ from risingwave_tpu.ops import lanes
 from risingwave_tpu.ops.hash_agg import (
     AggKind, AggSpec, GroupedAggKernel,
 )
+from risingwave_tpu.ops.hash_join import JoinSideKernel
 from risingwave_tpu.parallel.agg import ShardedAggKernel
 from risingwave_tpu.parallel.join import ShardedJoinKernel
 from risingwave_tpu.state.state_table import StateTable
@@ -41,6 +43,7 @@ from risingwave_tpu.stream.message import is_barrier, is_chunk
 from test_hash_join import (  # noqa: F401  (reuse the harness)
     L_SCHEMA, R_SCHEMA, barrier, lchunk, materialize_join, rchunk,
 )
+from test_multichip_agg import _single_chip_snapshot
 
 ALL_JOIN_TYPES = list(JoinType)
 
@@ -52,16 +55,15 @@ def four_mesh(eight_devices):
 
 
 def run_join_mesh(mesh, script_l, script_r, n_barriers,
-                  join_type=JoinType.INNER, epoch_batch=True,
-                  shard_opts=None):
+                  join_type=JoinType.INNER, shard_opts=None):
+    """``mesh=None`` runs the single-chip executor: the reference."""
     store = MemoryStateStore()
     lt = StateTable(21, L_SCHEMA, [1], store, dist_key_indices=[])
     rt = StateTable(22, R_SCHEMA, [1], store, dist_key_indices=[])
     ex = HashJoinExecutor(
         MockSource(L_SCHEMA, script_l), MockSource(R_SCHEMA, script_r),
         left_keys=[0], right_keys=[0], left_table=lt, right_table=rt,
-        join_type=join_type, mesh=mesh, epoch_batch=epoch_batch,
-        shard_opts=shard_opts)
+        join_type=join_type, mesh=mesh, shard_opts=shard_opts)
     msgs = asyncio.run(collect_until_n_barriers(ex, n_barriers))
     return msgs, ex
 
@@ -142,38 +144,34 @@ def _join_scripts(seed: int, epochs: int = 4, per_chunk: int = 12,
 @pytest.mark.parametrize("jt", ALL_JOIN_TYPES,
                          ids=[t.value for t in ALL_JOIN_TYPES])
 def test_epoch_batch_oracle_all_join_types(four_mesh, jt):
-    """Acceptance: batch-on vs per-chunk-off bit-identical per epoch
-    through the mesh join — all 8 types, update pairs, NULL keys and
-    retractions included."""
+    """Acceptance: the mesh join's epochs equal the single-chip
+    executor's on the same scripts, as a multiset per epoch — all 8
+    types, update pairs, NULL keys and retractions included."""
     script_l, script_r, nb = _join_scripts(seed=31 + hash(jt.value) % 7)
-    on, ex_on = run_join_mesh(four_mesh, script_l, script_r, nb,
-                              join_type=jt, epoch_batch=True)
-    off, ex_off = run_join_mesh(four_mesh, script_l, script_r, nb,
-                                join_type=jt, epoch_batch=False)
-    assert isinstance(ex_on.sides[0].kernel, ShardedJoinKernel)
-    assert per_epoch_multisets(on) == per_epoch_multisets(off)
-    assert materialize_join(on) == materialize_join(off)
+    got, ex = run_join_mesh(four_mesh, script_l, script_r, nb,
+                            join_type=jt)
+    want, ex_ref = run_join_mesh(None, script_l, script_r, nb,
+                                 join_type=jt)
+    assert isinstance(ex.sides[0].kernel, ShardedJoinKernel)
+    assert isinstance(ex_ref.sides[0].kernel, JoinSideKernel)
+    want_epochs = per_epoch_multisets(want)
+    assert len(want_epochs) == nb and any(want_epochs[1:])
+    assert per_epoch_multisets(got) == want_epochs
+    assert materialize_join(got) == materialize_join(want)
 
 
 def test_epoch_batch_dispatch_ceiling(four_mesh, dispatch_budget):
-    """The whole point: sharded SPMD dispatches drop from one per
-    chunk to O(1) per kernel per epoch (≤ 2 uploads + 1 apply + 1
-    probe per side), counted at the real shard_map launch sites
-    (kernel="sharded_join")."""
+    """The whole point: sharded SPMD dispatches are O(1) per kernel
+    per epoch (≤ 2 uploads + 1 apply + 1 probe per side) however many
+    chunks the epoch holds (4 a side here), counted at the real
+    shard_map launch sites (kernel="sharded_join")."""
     script_l, script_r, nb = _join_scripts(seed=5, epochs=4,
                                            chunks_per_epoch=4)
-    _off, d_off, _rpd_off = dispatch_budget.measure_sharded(
-        lambda: run_join_mesh(four_mesh, script_l, script_r, nb,
-                              epoch_batch=False))
-    (_on, d_on, rpd_on) = dispatch_budget.measure_sharded(
-        lambda: run_join_mesh(four_mesh, script_l, script_r, nb,
-                              epoch_batch=True))
-    assert d_on > 0 and d_off > 0
+    (_on, d_on, _rpd_on) = dispatch_budget.measure_sharded(
+        lambda: run_join_mesh(four_mesh, script_l, script_r, nb))
+    assert d_on > 0
     # 2 sides × (1 apply + 1 probe) = 4 dispatches per epoch max
     dispatch_budget.check_epoch_ceiling(d_on, nb, 4)
-    # the off arm dispatches per chunk (4 chunks/epoch/side) — the
-    # epoch arm must be strictly cheaper and denser
-    dispatch_budget.check(d_off, 1.0, d_on, max(rpd_on, 1.0))
 
 
 def _agg_stream(seed: int, epochs: int, rows: int, n_keys: int):
@@ -193,7 +191,8 @@ def _agg_stream(seed: int, epochs: int, rows: int, n_keys: int):
     return out
 
 
-def _drive_agg(kernel, stream, specs):
+def _drive_agg(kernel, stream, specs, view):
+    """``view(kernel)`` after every epoch of ``stream``."""
     views = []
     for chunks in stream:
         for gk, vals, signs, vis, valid in chunks:
@@ -202,60 +201,40 @@ def _drive_agg(kernel, stream, specs):
             inputs = [(specs[0].encode_input(vals), valid),
                       ((), None)]
             kernel.apply(key_lanes, signs, vis, inputs)
-        views.append(dict(kernel.snapshot()))
+        views.append(view(kernel))
     return views
 
 
 def test_mesh_agg_epoch_vs_perchunk_oracle(four_mesh):
-    """Mesh agg: epoch-buffered vs per-chunk dispatch bit-identical
-    after every epoch (sign-linear adds commute across the epoch fold
-    exactly — limb/count math), retractions and NULL inputs included,
-    WITH mid-epoch growth (capacity 256 ≪ 2000 keys)."""
+    """Mesh agg: the epoch-buffered sharded kernel equals the
+    single-chip GroupedAggKernel on the same stream after every epoch
+    (sign-linear adds commute across the epoch fold exactly —
+    limb/count math), retractions and NULL inputs included, WITH
+    mid-epoch growth (capacity 256 ≪ 2000 keys)."""
     specs = [AggSpec(AggKind.SUM, np.dtype(np.int64)),
              AggSpec(AggKind.COUNT)]
     stream = _agg_stream(seed=11, epochs=4, rows=512, n_keys=2000)
-    on = ShardedAggKernel(four_mesh, key_width=2, specs=specs,
-                          capacity=256, epoch_batch=True)
-    off = ShardedAggKernel(four_mesh, key_width=2, specs=specs,
-                           capacity=256, epoch_batch=False)
-    v_on = _drive_agg(on, stream, specs)
-    v_off = _drive_agg(off, stream, specs)
-    assert v_on == v_off
-    assert on.capacity > 256      # grew mid-stream, exactly
-    # and both agree with the single-chip kernel
-    single = GroupedAggKernel(key_width=2, specs=specs)
-    for chunks in stream:
-        for gk, vals, signs, vis, valid in chunks:
-            hi, lo = lanes.split_i64(gk)
-            single.apply(np.stack([hi, lo], axis=1), signs, vis,
-                         [(specs[0].encode_input(vals), valid),
-                          ((), None)])
-    single.dispatch_backlog()
-    import jax
-    from risingwave_tpu.ops.hash_agg import decode_outputs
-    st = jax.device_get(single.state)
-    live = st.table.occ & (st.group_rows > 0)
-    idx = np.flatnonzero(live)
-    outs, nulls = decode_outputs(specs, [a[idx] for a in st.accs])
-    want = {}
-    for r in range(len(idx)):
-        want[tuple(st.table.keys[idx][r].tolist())] = tuple(
-            None if nulls[c][r] else outs[c][r].item()
-            for c in range(len(specs)))
-    assert v_on[-1] == want
+    sharded = ShardedAggKernel(four_mesh, key_width=2, specs=specs,
+                               capacity=256)
+    got = _drive_agg(sharded, stream, specs,
+                     lambda k: dict(k.snapshot()))
+    assert sharded.capacity > 256      # grew mid-stream, exactly
+    want = _drive_agg(GroupedAggKernel(key_width=2, specs=specs),
+                      stream, specs, _single_chip_snapshot)
+    assert want[-1] and got == want
 
 
 def test_mesh_agg_epoch_dispatch_count(four_mesh, dispatch_budget):
-    """One routed SPMD step + one gather per epoch (vs one step per
-    chunk on the off arm), at the kernel="sharded_agg" launch sites."""
+    """One routed SPMD step + one gather per epoch, however many
+    chunks it holds (3 here), at the kernel="sharded_agg" launch
+    sites."""
     specs = [AggSpec(AggKind.SUM, np.dtype(np.int64)),
              AggSpec(AggKind.COUNT)]
     stream = _agg_stream(seed=3, epochs=3, rows=256, n_keys=64)
 
-    def run(epoch_batch):
+    def run():
         k = ShardedAggKernel(four_mesh, key_width=2, specs=specs,
-                             capacity=1 << 10,
-                             epoch_batch=epoch_batch)
+                             capacity=1 << 10)
         for chunks in stream:
             for gk, vals, signs, vis, valid in chunks:
                 hi, lo = lanes.split_i64(gk)
@@ -266,13 +245,9 @@ def test_mesh_agg_epoch_dispatch_count(four_mesh, dispatch_budget):
             k.advance()
         return k
 
-    _k_off, d_off, _r = dispatch_budget.measure_sharded(
-        lambda: run(False))
-    _k_on, d_on, _r2 = dispatch_budget.measure_sharded(
-        lambda: run(True))
-    # on: (1 step + 1 gather) per epoch; off adds one step per chunk
+    _k, d_on, _r = dispatch_budget.measure_sharded(run)
+    assert d_on > 0
     dispatch_budget.check_epoch_ceiling(d_on, 3, 2)
-    assert d_off > d_on
 
 
 def test_mesh_join_steady_state_recompile_guard(four_mesh,
