@@ -88,8 +88,13 @@ async def serving(data_dir, host: str = "127.0.0.1", port: int = 4566,
             RetryingObjectStore(LocalFsObjectStore(data_dir)))
         # this process compacts: one level of allocator behaviour, not
         # the one its allocation history happens to leave it
-        from risingwave_tpu.utils.memory import keep_freed_heap
+        from risingwave_tpu.utils.memory import (
+            freeze_startup_heap, keep_freed_heap,
+        )
         keep_freed_heap()
+        # and one length of the collector's full pass: the jobs'
+        # objects, not the imports' beside them
+        freeze_startup_heap()
     else:
         from risingwave_tpu.state.store import MemoryStateStore
         store = MemoryStateStore()

@@ -116,10 +116,10 @@ class TableFn:
 
 @dataclass
 class Subquery:
-    """Derived table: FROM (SELECT ...) alias."""
+    """Derived table: FROM (SELECT ...) [alias]."""
 
     select: "Select"
-    alias: str
+    alias: Optional[str] = None
 
 
 FromItem = object            # TableRef | Tumble | Hop | TableFn | Subquery

@@ -442,14 +442,28 @@ class Fragmenter:
                 "table_id": ex.state.table_id,
                 "group": list(ex.group_indices),
                 "append_only": ex.append_only,
-                "pk": list(ex.pk_indices)}
-            if len(self.graph.fragments[up_fi].nodes) > 1 or \
+                "pk": list(ex.pk_indices),
+                # the state table's own key and vnode distribution:
+                # a top-N the over-window rule planned keys it group |
+                # order | rest of the pk and distributes it by group
+                "state_pk": list(ex.state.pk_indices),
+                "dist_key": list(ex.state.dist_key_indices),
+                "tier_cap": ex.tier_cap}
+            by_group = bool(ex.group_indices) and \
+                ex.state.dist_key_indices == ex.group_indices
+            if self.parallelism > 1 and by_group:
+                # hash exchange on the group key, as the over-window's
+                # on its partition: each actor owns whole groups, and
+                # the state table's vnodes follow the same key
+                fi, xi = self._cut(up_fi, list(ex.group_indices),
+                                   ex.input.schema, self.parallelism)
+                node["input"] = xi
+            elif len(self.graph.fragments[up_fi].nodes) > 1 or \
                     self.parallelism > 1:
-                # TopN is a SINGLETON: a global window cannot split
-                # across actors; grouped top-n would need group ⊆ dist
-                # keys — a singleton fragment is always correct
-                # (DispatcherType::SIMPLE, stream_graph/schedule.rs
-                # singleton placement)
+                # a plain TopN (or a grouped one whose table is not
+                # distributed by its group) is a SINGLETON: a global
+                # window cannot split across actors (DispatcherType::
+                # SIMPLE, stream_graph/schedule.rs singleton placement)
                 keys = list(ex.group_indices)
                 fi, xi = self._cut(up_fi, keys, ex.input.schema, 1)
                 node["input"] = xi

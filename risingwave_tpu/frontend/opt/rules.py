@@ -15,8 +15,8 @@ always fall back to the pre-rule tree. Rules:
 - noop_project_elision identity projections (same columns, same names)
                       drop out of the chain.
 - column_pruning      live lanes are computed top-down; join inputs,
-                      agg feeds and source scans narrow to the columns
-                      actually referenced above — joins rebuild with
+                      agg feeds, top-N feeds and source scans narrow
+                      to the columns actually referenced above — joins rebuild with
                       remapped keys and same-id narrowed state tables,
                       sources grow a narrowing projection.
 """
@@ -466,6 +466,9 @@ def _prune(ex, live: Optional[Set[int]], stats,
         return _prune_join(ex, live_full, stats)
     if isinstance(ex, HashAggExecutor):
         return _prune_agg(ex, stats)
+    from risingwave_tpu.stream.executors.top_n import GroupTopNExecutor
+    if isinstance(ex, GroupTopNExecutor):
+        return _prune_topn(ex, live_full, stats)
     if isinstance(ex, SourceExecutor):
         if not narrow_leaf or len(live_full) >= n_out:
             return ex, None, False
@@ -534,8 +537,17 @@ def _prune_project(p, live_full: Set[int], stats) -> tuple:
     return new, out_map, True
 
 
-def _prune_join(j, live_full: Set[int], stats) -> tuple:
+def _narrowed_table(t, m: Dict[int, int], schema):
+    """State table `t` over a narrowed schema: the same id and store,
+    its key and distribution columns sent through `m`."""
     from risingwave_tpu.state.state_table import StateTable
+    return StateTable(
+        t.table_id, schema, [m[p] for p in t.pk_indices], t.store,
+        dist_key_indices=([m[d] for d in t.dist_key_indices]
+                          if t.dist_key_indices else None))
+
+
+def _prune_join(j, live_full: Set[int], stats) -> tuple:
     from risingwave_tpu.stream.executors.hash_join import (
         HashJoinExecutor, JoinType,
     )
@@ -568,14 +580,8 @@ def _prune_join(j, live_full: Set[int], stats) -> tuple:
     lmap = _identity_or(lmap, len(j.left_in.schema))
     rmap = _identity_or(rmap, len(j.right_in.schema))
 
-    def table_for(t, m, schema):
-        return StateTable(
-            t.table_id, schema, [m[p] for p in t.pk_indices], t.store,
-            dist_key_indices=([m[d] for d in t.dist_key_indices]
-                              if t.dist_key_indices else None))
-
-    lt = table_for(left_side.table, lmap, lnew.schema)
-    rt = table_for(right_side.table, rmap, rnew.schema)
+    lt = _narrowed_table(left_side.table, lmap, lnew.schema)
+    rt = _narrowed_table(right_side.table, rmap, rnew.schema)
     inv_l = {v: k for k, v in lmap.items()}
     inv_r = {v: k for k, v in rmap.items()}
     old_fields = list(j.schema)
@@ -599,6 +605,29 @@ def _prune_join(j, live_full: Set[int], stats) -> tuple:
     for old, new_i in rmap.items():
         mapping[n_left + old] = n_left_new + new_i
     return new, mapping, True
+
+
+def _prune_topn(t, live_full: Set[int], stats) -> tuple:
+    """A top-N hands its input's rows through: it needs the columns
+    read above it, its group and order columns and its state table's
+    key, and nothing else, in its caches or in its table. It rebuilds
+    over the narrowed input with a same-id narrowed state table."""
+    from risingwave_tpu.stream.executors.top_n import GroupTopNExecutor
+    req = (live_full | set(t.group_indices)
+           | {i for i, _d in t.order_by} | set(t.state.pk_indices))
+    child, cmap, changed = _prune(t.input, req, stats)
+    if cmap is None:
+        if not changed:
+            return t, None, False
+        return _swap_child(t, "input", None, child), None, True
+    new = GroupTopNExecutor(
+        child, [(cmap[i], d) for i, d in t.order_by], t.offset,
+        t.limit, _narrowed_table(t.state, cmap, child.schema),
+        group_indices=[cmap[g] for g in t.group_indices],
+        append_only=t.append_only,
+        pk_indices=[cmap[p] for p in t.pk_indices], tier_cap=t.tier_cap)
+    new.plan_note = getattr(t, "plan_note", None)
+    return new, cmap, True
 
 
 def _prune_opaque_2(ex, stats) -> tuple:

@@ -478,6 +478,13 @@ class Parser:
     def _projection(self) -> Tuple[ast.Expr, Optional[str]]:
         if self._op("*"):
             return (ast.ColRef("*"), None)
+        if self._peek()[0] in ("ident", "kw") and \
+                self._peek(1) == ("op", ".") and \
+                self._peek(2) == ("op", "*"):
+            # <qualifier>.*: the columns of one FROM item
+            table = self._ident()
+            self.i += 2
+            return (ast.ColRef("*", table=table), None)
         e = self._expr()
         alias = None
         if self._kw("as"):
@@ -493,13 +500,13 @@ class Parser:
             self._expect_op("(")
             sel = self._select()
             self._expect_op(")")
+            # the alias may be left off, as upstream's nexmark views
+            # leave it: the derived columns then go by their bare names
+            alias = None
             if self._kw("as"):
                 alias = self._ident()
             elif self._peek()[0] == "ident":
                 alias = self._ident()
-            else:
-                raise ParseError(
-                    "subquery in FROM must have an alias")
             return ast.Subquery(sel, alias)
         if self._kw("tumble"):
             self._expect_op("(")

@@ -661,6 +661,14 @@ class StreamPlanner:
         join_pk_cols: Optional[List[int]] = None
         conjuncts = _flatten_and(sel.where) if sel.where is not None \
             else []
+        if isinstance(sel.from_item, ast.Subquery):
+            # `row_number() ... WHERE rn <= N` over a derived table is
+            # a group top-N (frontend/opt/over_window_to_topn.py)
+            from risingwave_tpu.frontend.opt.over_window_to_topn import (
+                over_window_to_topn,
+            )
+            ex, scope, conjuncts = over_window_to_topn(
+                self, ex, scope, sel, conjuncts)
         if sel.joins:
             # Optimizer v0 (multi-way planning, collapsed): a
             # left-deep chain of HashJoins in syntax order. WHERE
@@ -1327,8 +1335,14 @@ def _expand_star(projections, scope: Scope):
     out = []
     for e, a in projections:
         if isinstance(e, ast.ColRef) and e.name == "*":
-            for i, f in enumerate(scope.schema):
-                out.append((ast.ColRef(f.name, scope.qualifiers[i]), None))
+            # `*`: every column in scope; `t.*`: those of FROM item t
+            cols = [i for i in range(len(scope.schema))
+                    if e.table is None or scope.qualifiers[i] == e.table]
+            if not cols:
+                raise PlanError(f"{e.table}.*: no FROM item {e.table!r}")
+            for i in cols:
+                out.append((ast.ColRef(scope.schema[i].name,
+                                       scope.qualifiers[i]), None))
         else:
             out.append((e, a))
     return out

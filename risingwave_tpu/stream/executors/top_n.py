@@ -14,6 +14,17 @@ the reference's CPU btree — nothing here wants the MXU).
 
 NULLS ordering follows PostgreSQL: NULLS LAST for ASC, NULLS FIRST for
 DESC.
+
+A chunk is three stages of ``host_emit`` (utils/ledger.py):
+``topn.apply`` (the walk over its rows and the diff of the touched
+groups' windows), ``topn.state`` (what the diff writes to and deletes
+from the state table; the table's own ``state.write`` nests in it and
+keeps its own name) and ``topn.emit`` (the delta chunk). The books, by state table, in every row of
+``rw_metrics_history``: ``topn.t<id>.rows_in`` / ``.rows_out`` (rows of
+the chunks in and of the deltas out), ``.state_writes`` /
+``.state_deletes`` (rows written to and deleted from the table),
+``.groups`` (groups that hold a row, at the barrier) and
+``.cached_rows`` (rows held in memory, at the barrier).
 """
 
 from __future__ import annotations
@@ -31,6 +42,8 @@ from risingwave_tpu.stream.executor import Executor, ExecutorInfo
 from risingwave_tpu.stream.message import (
     Message, is_barrier, is_chunk, is_watermark,
 )
+from risingwave_tpu.utils.ledger import staged
+from risingwave_tpu.utils.metrics import STREAMING as _METRICS
 
 
 class _Key:
@@ -62,38 +75,35 @@ class _Key:
         return f"_Key({self.vals})"
 
 
-class _SortedRows:
-    """One group's candidates: rows sorted by order key + pk tiebreak."""
-
-    __slots__ = ("entries",)
-
-    def __init__(self):
-        self.entries: List[Tuple[_Key, tuple]] = []
-
-    def insert(self, key: _Key, row: tuple) -> None:
-        bisect.insort(self.entries, (key, row))
-
-    def delete(self, key: _Key, row: tuple) -> None:
-        i = bisect.bisect_left(self.entries, (key, row))
-        if i < len(self.entries) and self.entries[i][1] == row:
-            del self.entries[i]
-
-    def window(self, offset: int, limit: Optional[int]) -> List[tuple]:
-        hi = None if limit is None else offset + limit
-        return [r for _k, r in self.entries[offset:hi]]
-
-    def truncate_beyond(self, n: int) -> List[tuple]:
-        """Drop rows ranked >= n (append-only pruning); returns dropped."""
-        dropped = [r for _k, r in self.entries[n:]]
-        del self.entries[n:]
-        return dropped
+def _delete_entry(entries: list, key, row: tuple) -> bool:
+    i = bisect.bisect_left(entries, (key, row))
+    if i < len(entries) and entries[i][1] == row:
+        del entries[i]
+        return True
+    return False
 
 
 class GroupTopNExecutor(Executor):
     """Streaming [group] top-n (top_n_plain.rs / group_top_n.rs analog).
 
-    `group_indices=[]` gives plain TopN; `append_only=True` prunes
-    managed state beyond the window (top_n_appendonly.rs analog).
+    `group_indices=[]` gives plain TopN. The two arms keep different
+    state:
+
+    - retractable (group_top_n.rs): EVERY input row is a candidate, in
+      the state table and in the group's sorted cache, because a
+      delete of a window row brings the runner-up back. A chunk is one
+      ``write_chunk`` of its rows as they come.
+    - append-only (top_n_appendonly.rs): nothing leaves but by being
+      pushed out, so only the rows ranked ``[0, offset + limit)`` of a
+      group are kept, in memory and in the table alike: a row that
+      does not enter that range is not written, the row it pushes out
+      is deleted, and nothing else touches the table (limit 1: one row
+      a group). A chunk is one ``insert_rows`` of the rows that
+      entered and one ``delete_rows`` of the rows that left, net over
+      the chunk. In memory the range is a tuple of plain tuples, made
+      anew when a row enters: the cyclic collector untracks it, so a
+      hundred thousand groups add nothing to its full pass (a list a
+      group added a third to a pass that stalls a barrier).
     """
 
     def __init__(self, input_: Executor, order_by: Sequence[Tuple[int, bool]],
@@ -116,6 +126,7 @@ class GroupTopNExecutor(Executor):
         self.state = state
         self.group_indices = list(group_indices)
         self.append_only = append_only
+        self.tier_cap = tier_cap
         # sort = order cols, then pk for a total (deterministic) order
         self._sort_cols = [i for i, _ in self.order_by] + [
             i for i in pk
@@ -123,7 +134,11 @@ class GroupTopNExecutor(Executor):
         self._descs = tuple([d for _, d in self.order_by] +
                             [False] * (len(self._sort_cols)
                                        - len(self.order_by)))
-        self.groups: Dict[tuple, _SortedRows] = {}
+        # group → its candidates as (sort key, row), sorted: a list on
+        # the retractable arm, a tuple on the append-only arm
+        self.groups: Dict[tuple, Sequence[Tuple[object, tuple]]] = {}
+        self._cached_rows = 0       # rows in the groups' caches
+        self._label = f"t{state.table_id}"     # the books' table label
         # fast-key eligibility: native tuples compare in C (an order of
         # magnitude over _Key.__lt__'s per-column Python loop, q5's
         # single hottest path); DESC needs numeric negation, so
@@ -152,8 +167,7 @@ class GroupTopNExecutor(Executor):
             if s is None:
                 _mem.GLOBAL.unregister(mem_name)
                 return 0
-            entries = sum(len(sr.entries) for sr in s.groups.values())
-            return row_est * entries + 120 * len(s._cold_groups)
+            return row_est * s._cached_rows + 120 * len(s._cold_groups)
 
         _mem.GLOBAL.register(mem_name, _nbytes)
         # cold tier (state/tier.py): whole GROUP caches evict — the
@@ -203,18 +217,17 @@ class GroupTopNExecutor(Executor):
     def _group_of(self, row: tuple) -> tuple:
         return tuple(row[i] for i in self.group_indices)
 
-    def _window(self, g: tuple) -> List[tuple]:
-        rows = self.groups.get(g)
-        return rows.window(self.offset, self.limit) if rows else []
-
     def _recover(self) -> None:
         # rows are PHYSICAL end to end (DECIMAL = scaled int64): order
         # is preserved under the physical encoding, state-table writes
         # expect it, and chunk rebuild must not lossily convert
         for _pk, row in self.state.iter_rows():
             g = self._group_of(row)
-            self.groups.setdefault(g, _SortedRows()).insert(
-                self._key_of(row), row)
+            bisect.insort(self.groups.setdefault(g, []),
+                          (self._key_of(row), row))
+            self._cached_rows += 1
+        if self.append_only:
+            self.groups = {g: tuple(e) for g, e in self.groups.items()}
         if self._tier is not None and self.groups:
             # everything recovers resident (cold markers do not survive
             # a crash); seed the tier clock so the first checkpoint
@@ -243,7 +256,9 @@ class GroupTopNExecutor(Executor):
         durable in the state table."""
         n = 0
         for g in groups:
-            if self.groups.pop(g, None) is not None:
+            rows = self.groups.pop(g, None)
+            if rows is not None:
+                self._cached_rows -= len(rows)
                 self._cold_groups.add(g)
                 n += 1
         return n
@@ -253,67 +268,139 @@ class GroupTopNExecutor(Executor):
         runs BEFORE the old-window capture, so the emitted delta is
         computed against the true pre-chunk window."""
         self._cold_groups.discard(g)
-        rows = _SortedRows()
-        for _pk, row in self.state.iter_prefix(list(g)):
-            row = tuple(row)
-            rows.insert(self._key_of(row), row)
-        if rows.entries:
-            self.groups[g] = rows
+        rows = sorted((self._key_of(tuple(row)), tuple(row))
+                      for _pk, row in self.state.iter_prefix(list(g)))
+        if rows:
+            self.groups[g] = tuple(rows) if self.append_only else rows
+            self._cached_rows += len(rows)
         self._tier.note_reload(self._tier_part, 1)
 
     # -- chunk path ------------------------------------------------------
     def _apply(self, chunk: StreamChunk) -> Optional[StreamChunk]:
-        touched: Dict[tuple, List[tuple]] = {}
+        n_in, n_ins, deletes, inserts, entered, left = self._walk(chunk)
+        if self.append_only:
+            self._persist_window(entered, left)
+            wrote, deleted = len(entered), len(left)
+        else:
+            # every row is a candidate: the chunk goes to the table as
+            # it came, one vectorized apply
+            self.state.write_chunk(chunk)
+            wrote, deleted = n_ins, n_in - n_ins
+        books = _METRICS.topn_rows
+        books.inc(float(n_in), table=self._label, event="rows_in")
+        books.inc(float(len(deletes) + len(inserts)), table=self._label,
+                  event="rows_out")
+        books.inc(float(wrote), table=self._label, event="state_writes")
+        books.inc(float(deleted), table=self._label,
+                  event="state_deletes")
+        if not deletes and not inserts:
+            return None
+        return self._delta_chunk(deletes, inserts)
+
+    @staged("topn.apply")
+    def _walk(self, chunk: StreamChunk):
+        """A chunk's rows against the groups' sorted caches, then the
+        net delta of every touched group's window. Returns (rows in,
+        inserts among them, window deletes, window inserts, entered,
+        left): the last two are the rows that entered and left the
+        append-only arm's kept range, what its table is to be told
+        (empty on the retractable arm, whose table takes the chunk)."""
         _idx, prows, pops = chunk.to_physical_records()
-        # cold groups this chunk touches reload BEFORE write_chunk:
-        # the reload prefix-scan must see PRE-chunk state only, or the
-        # old-window capture would already contain this chunk's rows
-        # (suppressing deltas) and the loop would double-insert them
+        # cold groups this chunk touches reload BEFORE anything is
+        # applied: the reload prefix-scan must see PRE-chunk state
+        # only, or the old-window capture would already contain this
+        # chunk's rows (suppressing deltas) and the loop would
+        # double-insert them
         if self._cold_groups:
             for row in prows:
                 g = self._group_of(row)
                 if g in self._cold_groups:
                     self._reload_group(g)
-        # state writes batch as ONE vectorized chunk apply (the same
-        # insert/delete multiset the loop below maintains in memory) —
-        # a per-row insert() pays a full pk encode each (the other q5
-        # hot path); only append-only truncation drops need row calls
-        self.state.write_chunk(chunk)
-        for op_i, row in zip(pops.tolist(), prows):
-            is_ins = Op(op_i).is_insert
-            g = self._group_of(row)
-            if g not in touched:
-                touched[g] = self._window(g)
-            rows = self.groups.setdefault(g, _SortedRows())
-            key = self._key_of(row)
-            if is_ins:
-                rows.insert(key, row)
-                if self.append_only and self.limit is not None:
-                    for dropped in rows.truncate_beyond(
-                            self.offset + self.limit):
-                        self.state.delete(dropped)
-            else:
-                if self.append_only:
-                    raise ValueError(
-                        "delete on append-only TopN input")
-                rows.delete(key, row)
+        is_ins = (pops == int(Op.INSERT)) | (pops == int(Op.UPDATE_INSERT))
+        lo = self.offset
+        hi = None if self.limit is None else lo + self.limit
+        # group → the entries ranked [0, offset + limit) it held before
+        # the chunk touched it (append-only: its whole cache)
+        touched: Dict[tuple, List[tuple]] = {}
+        if self.append_only:
+            if not is_ins.all():
+                raise ValueError("delete on append-only TopN input")
+            self._walk_append_only(prows, touched)
+        else:
+            for ins, row in zip(is_ins.tolist(), prows):
+                g = self._group_of(row)
+                rows = self.groups.get(g)
+                if rows is None:
+                    rows = self.groups[g] = []
+                if g not in touched:
+                    touched[g] = rows[:hi]
+                if ins:
+                    bisect.insort(rows, (self._key_of(row), row))
+                    self._cached_rows += 1
+                else:
+                    self._cached_rows -= _delete_entry(
+                        rows, self._key_of(row), row)
         if self._tier is not None and touched:
             self._tier.touch(self._tier_part, list(touched),
                              self._tier_seq)
-        # net window delta per touched group
+        # net window delta per touched group, and append-only the net
+        # change of the kept range [0, offset + limit)
         deletes: List[tuple] = []
         inserts: List[tuple] = []
-        for g, old_window in touched.items():
-            new_window = self._window(g)
-            old_c, new_c = Counter(old_window), Counter(new_window)
+        entered: List[tuple] = []
+        left: List[tuple] = []
+        for g, old in touched.items():
+            new = self.groups[g]
+            if not new:
+                del self.groups[g]
+            old_c = Counter(r for _k, r in old[lo:])
+            new_c = Counter(r for _k, r in new[lo:hi])
             for r, cnt in (old_c - new_c).items():
                 deletes.extend([r] * cnt)
             for r, cnt in (new_c - old_c).items():
                 inserts.extend([r] * cnt)
-        if not deletes and not inserts:
-            return None
-        return self._delta_chunk(deletes, inserts)
+            if self.append_only:
+                was = {r for _k, r in old}
+                now = {r for _k, r in new}
+                entered.extend(r for _k, r in new if r not in was)
+                left.extend(r for _k, r in old if r not in now)
+        self._cached_rows += len(entered) - len(left)
+        return (len(prows), int(is_ins.sum()), deletes, inserts, entered,
+                left)
 
+    def _walk_append_only(self, prows: List[tuple],
+                          touched: Dict[tuple, List[tuple]]) -> None:
+        """top_n_appendonly.rs: a row enters its group's kept range
+        [0, offset + limit) or is dropped where it stands; the row it
+        pushes past the end goes. Only a group whose range changed is
+        touched."""
+        cap = None if self.limit is None else self.offset + self.limit
+        groups = self.groups
+        for row in prows:
+            g = self._group_of(row)
+            rows = groups.get(g, ())
+            entry = (self._key_of(row), row)
+            if cap is not None and len(rows) >= cap \
+                    and not entry < rows[-1]:
+                continue
+            if g not in touched:
+                touched[g] = rows
+            i = bisect.bisect_right(rows, entry)
+            groups[g] = (rows[:i] + (entry,) + rows[i:])[:cap]
+
+    @staged("topn.state")
+    def _persist_window(self, entered: List[tuple],
+                        left: List[tuple]) -> None:
+        """The append-only arm's table writes: the rows that entered
+        the kept range and the rows that left it, one batch call each
+        (their ``state.write`` nests here and is filed under its own
+        name)."""
+        if entered:
+            self.state.insert_rows(entered)
+        if left:
+            self.state.delete_rows(left)
+
+    @staged("topn.emit")
     def _delta_chunk(self, deletes: List[tuple],
                      inserts: List[tuple]) -> StreamChunk:
         rows = deletes + inserts
@@ -349,6 +436,12 @@ class GroupTopNExecutor(Executor):
                     if out is not None:
                         yield out
                 elif is_barrier(msg):
+                    _METRICS.topn_resident.set(
+                        float(len(self.groups) + len(self._cold_groups)),
+                        table=self._label, what="groups")
+                    _METRICS.topn_resident.set(
+                        float(self._cached_rows), table=self._label,
+                        what="cached_rows")
                     self.state.commit(msg.epoch)
                     if self._tier is not None:
                         # sweep at checkpoints, post-commit: evicted
@@ -363,6 +456,10 @@ class GroupTopNExecutor(Executor):
                     if msg.col_idx in self.group_indices:
                         yield msg   # group-key watermarks pass through
         finally:
+            # executor teardown: release this table's gauge series
+            for what in ("groups", "cached_rows"):
+                _METRICS.topn_resident.remove(table=self._label,
+                                              what=what)
             if self._tier_part is not None:
                 self._tier.unregister(self._tier_part)
 

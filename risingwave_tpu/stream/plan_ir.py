@@ -64,7 +64,11 @@ Node shapes (dicts, `op` discriminated):
                                 # rescale can slice state by vnode
   {"op": "top_n", "input": N, "order_by": [[i, desc], ...],
    "offset": n, "limit": n|null, "table_id": n, "group": [...],
-   "append_only": bool, "pk": [...]}
+   "append_only": bool, "pk": [...],
+   "state_pk": [...], "dist_key": [...],   # optional: the state
+                                # table's key and vnode distribution
+                                # (default: "pk", undistributed)
+   "tier_cap": n|null}
   {"op": "over_window", "input": N, "partition": [...],
    "order_by": [[i, desc], ...],
    "calls": [{"kind", "input_idx", "offset"}], "table_id": n,
@@ -527,8 +531,12 @@ def build_fragment(nodes: List[dict], store, local,
             )
             child = built[node["input"]]
             pk = [int(i) for i in node["pk"]]
-            state = StateTable(int(node["table_id"]), child.schema,
-                               pk, store)
+            state = StateTable(
+                int(node["table_id"]), child.schema,
+                [int(i) for i in node.get("state_pk", pk)], store,
+                dist_key_indices=[int(i)
+                                  for i in node.get("dist_key", [])])
+            tier_cap = node.get("tier_cap")
             ex = GroupTopNExecutor(
                 child,
                 [(int(i), bool(d)) for i, d in node["order_by"]],
@@ -537,7 +545,8 @@ def build_fragment(nodes: List[dict], store, local,
                 group_indices=[int(i)
                                for i in node.get("group", [])],
                 append_only=bool(node.get("append_only", False)),
-                pk_indices=pk)
+                pk_indices=pk,
+                tier_cap=None if tier_cap is None else int(tier_cap))
         elif op == "over_window":
             from risingwave_tpu.expr.window import (
                 WindowCall, WindowFuncKind,

@@ -48,6 +48,32 @@ def keep_freed_heap() -> bool:
     return bool(ok)
 
 
+_startup_frozen = False
+
+
+def freeze_startup_heap() -> int:
+    """Move what the process holds when the server starts (the imports:
+    jax, numpy and this package are some 65K tracked objects, none of
+    them ever garbage) into the cyclic collector's permanent
+    generation, once a process. A full pass then walks the jobs' own
+    objects only. It comes wherever an allocation happens to trigger
+    it, one or two in a few seconds of a busy barrier loop, and stops
+    every thread: on the chip host 21-30 ms a pass where it took 52-75
+    with the imports in it, and a window of six barriers swung with
+    each (PERF.md section 6, PR 45). What dies later by its reference
+    count is freed as ever. Returns the objects set aside by this
+    call."""
+    global _startup_frozen
+    if _startup_frozen:
+        return 0
+    import gc
+    _startup_frozen = True
+    gc.collect()
+    before = gc.get_freeze_count()
+    gc.freeze()
+    return gc.get_freeze_count() - before
+
+
 class MemoryContext:
     """Central registry of host-state size reporters + evictors.
 

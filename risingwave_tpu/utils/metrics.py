@@ -317,6 +317,18 @@ class StreamingMetrics:
             "by dedup table and stage (write: its StateTable batch "
             "calls, the state.write stages nested there; persist: the "
             "rest of the pass, its part of agg.persist)")
+        self.topn_rows = r.counter(
+            "stream_topn_rows",
+            "a top-N's rows by its state table (t<state table id>) and "
+            "event: rows_in (rows of the chunks it was given), rows_out "
+            "(rows of the window deltas it emitted), state_writes and "
+            "state_deletes (rows it wrote to and deleted from its "
+            "state table)")
+        self.topn_resident = r.gauge(
+            "stream_topn_resident",
+            "what a top-N holds at the barrier, by state table: groups "
+            "(groups with a row, resident or cold) and cached_rows "
+            "(rows in the groups' sorted caches in memory)")
         self.expr_to_char_rows = r.counter(
             "expr_to_char_rows", "valid rows to_char was given")
         self.expr_to_char_formats = r.counter(
@@ -896,7 +908,8 @@ class MetricsHistory:
         aggregate hand-off, rows into and out of a join and through
         its condition, rows through a HOP, the pairs a DISTINCT column's
         dedup state holds, changed and made visible and the seconds of
-        its write-through, the rows to_char was given and the strftime
+        its write-through, the rows into and out of a top-N with its
+        table's writes and what it keeps, the rows to_char was given and the strftime
         calls it made, what a watermark cleaned out
         of the state tables and the rows they keep, the rows a
         watermark filter dropped, the longest chain a join's
@@ -931,6 +944,8 @@ class MetricsHistory:
                  S.agg_distinct_crossings, "counter"),
                 ("agg_distinct.{table}.{stage}_s",
                  S.agg_distinct_seconds, "counter"),
+                ("topn.{table}.{event}", S.topn_rows, "counter"),
+                ("topn.{table}.{what}", S.topn_resident, "gauge"),
                 ("expr_to_char.rows", S.expr_to_char_rows, "counter"),
                 ("expr_to_char.formats", S.expr_to_char_formats,
                  "counter"),

@@ -60,6 +60,19 @@ def _run(mv_sql, select_sql, sources=NEXMARK_SOURCES, steps=12):
     return asyncio.run(run())
 
 
+def _explain(mv_sql, sources=NEXMARK_SOURCES):
+    """EXPLAIN of a CREATE MATERIALIZED VIEW's select."""
+    async def run():
+        fe = Frontend()
+        for s in sources:
+            await fe.execute(s)
+        rows = await fe.execute("EXPLAIN " + mv_sql.split(" AS ", 1)[1])
+        await fe.close()
+        return "\n".join(r[0] for r in rows)
+
+    return asyncio.run(run())
+
+
 # -- Nexmark ---------------------------------------------------------------
 
 
@@ -143,13 +156,17 @@ def test_nexmark_q9_auction_top_bid_row_number():
     """q9 shape: ROW_NUMBER() OVER (PARTITION BY auction ORDER BY
     price DESC, date_time ASC), filtered to rn = 1 in an outer query
     over the derived table."""
-    rows = _run(
-        "CREATE MATERIALIZED VIEW q9 AS SELECT auction, price, "
-        "date_time FROM ("
-        "  SELECT auction, price, date_time, row_number() OVER ("
-        "    PARTITION BY auction ORDER BY price DESC, date_time ASC"
-        "  ) AS rn FROM bid) AS t WHERE rn = 1",
-        "SELECT * FROM q9")
+    mv = ("CREATE MATERIALIZED VIEW q9 AS SELECT auction, price, "
+          "date_time FROM ("
+          "  SELECT auction, price, date_time, row_number() OVER ("
+          "    PARTITION BY auction ORDER BY price DESC, date_time ASC"
+          "  ) AS rn FROM bid) AS t WHERE rn = 1")
+    rows = _run(mv, "SELECT * FROM q9")
+    # a grouped top-N since PR 45 (frontend/opt/over_window_to_topn.py)
+    plan = _explain(mv)
+    assert "GroupTopNExecutor  -- group: [auction], order: [price DESC, " \
+        "date_time ASC], limit: 1, append_only: true" in plan
+    assert "OverWindow" not in plan
     bids, _a, _p = _gen()
     best = {}
     for a, p, t in zip(bids["auction"].tolist(), bids["price"].tolist(),
@@ -356,8 +373,19 @@ def test_tpch_q6_forecast_revenue():
 # benchmark's configuration file in tests/test_nexmark_q15.py, against
 # benchmark/reference/nexmark_q15.py; test_nexmark_q15_per_minute_stats
 # below keeps the older per-minute shape without filters.
-#   q6          per-seller average of last 10 prices: needs
-#               group-top-n-then-agg chaining in one MV
+# q9 is no gap either: upstream's text (`A.*`, the alias-less derived
+# table, the join feeding `ROW_NUMBER() ... <= 1`) runs from the
+# benchmark's configuration file in tests/test_nexmark_q9.py, against
+# benchmark/reference/nexmark_q9.py, planned as a grouped top-N; the
+# q9, q18 and q19 shapes above (over `bid` alone) are planned as one
+# too since PR 45. Upstream's q19 also selects its rank
+# (`SELECT * ... WHERE rank_number <= 10`): that form stays on the
+# general over-window executor, a top-N does not number its rows.
+#   q6          per-seller average of the last 10 closing prices:
+#               q9's winners feeding AVG(...) OVER (PARTITION BY seller
+#               ORDER BY date_time ROWS BETWEEN 10 PRECEDING AND CURRENT
+#               ROW); needs a window frame, and a window over a
+#               retracting input below an aggregate in one MV
 #   q21         needs regexp_extract (split_part-only form runs as
 #               part of q22's coverage)
 #   q12         processing-time tumble (proctime())
@@ -678,12 +706,15 @@ def test_nexmark_q17_auction_day_stats():
 def test_nexmark_q18_last_bid_per_bidder_auction():
     """q18: each (bidder, auction)'s most recent bid via
     ROW_NUMBER() = 1 over a derived table."""
-    rows = _run(
-        "CREATE MATERIALIZED VIEW q18 AS SELECT auction, bidder, "
-        "price, date_time FROM (SELECT auction, bidder, price, "
-        "date_time, row_number() OVER (PARTITION BY bidder, auction "
-        "ORDER BY date_time DESC) AS rn FROM bid) AS t WHERE rn = 1",
-        "SELECT * FROM q18")
+    mv = ("CREATE MATERIALIZED VIEW q18 AS SELECT auction, bidder, "
+          "price, date_time FROM (SELECT auction, bidder, price, "
+          "date_time, row_number() OVER (PARTITION BY bidder, auction "
+          "ORDER BY date_time DESC) AS rn FROM bid) AS t WHERE rn = 1")
+    rows = _run(mv, "SELECT * FROM q18")
+    plan = _explain(mv)
+    assert "GroupTopNExecutor  -- group: [bidder, auction], order: " \
+        "[date_time DESC], limit: 1, append_only: true" in plan
+    assert "OverWindow" not in plan
     bids, _a, _p = _gen()
     last = {}
     for a, b, p, t in zip(bids["auction"].tolist(),
@@ -707,12 +738,15 @@ def test_nexmark_q18_last_bid_per_bidder_auction():
 def test_nexmark_q19_top10_bids_per_auction():
     """q19: the 10 highest bids per auction via ROW_NUMBER() <= 10
     over a derived table (per-group LIMIT)."""
-    rows = _run(
-        "CREATE MATERIALIZED VIEW q19 AS SELECT auction, bidder, "
-        "price FROM (SELECT auction, bidder, price, row_number() "
-        "OVER (PARTITION BY auction ORDER BY price DESC) AS rn "
-        "FROM bid) AS t WHERE rn <= 10",
-        "SELECT * FROM q19")
+    mv = ("CREATE MATERIALIZED VIEW q19 AS SELECT auction, bidder, "
+          "price FROM (SELECT auction, bidder, price, row_number() "
+          "OVER (PARTITION BY auction ORDER BY price DESC) AS rn "
+          "FROM bid) AS t WHERE rn <= 10")
+    rows = _run(mv, "SELECT * FROM q19")
+    plan = _explain(mv)
+    assert "GroupTopNExecutor  -- group: [auction], order: [price " \
+        "DESC], limit: 10, append_only: true" in plan
+    assert "OverWindow" not in plan
     bids, _a, _p = _gen()
     by_auction = collections.defaultdict(list)
     for a, b, p in zip(bids["auction"].tolist(),

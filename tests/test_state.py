@@ -372,6 +372,28 @@ def test_serving_a_data_dir_pins_the_allocators_thresholds(
     assert asked == [True]
 
 
+def test_serving_a_data_dir_sets_the_imports_aside_once_a_process(
+        tmp_path):
+    """The collector's full pass walks the jobs' objects only (PR 45):
+    the served entry point freezes what the process holds at its first
+    start and nothing at a later one."""
+    import asyncio
+    import gc
+    from risingwave_tpu import __main__ as main
+    from risingwave_tpu.utils import memory
+
+    async def up():
+        async with main.serving(str(tmp_path), port=0):
+            pass
+    asyncio.run(up())
+    frozen = gc.get_freeze_count()
+    assert frozen > 10_000
+    assert memory.freeze_startup_heap() == 0
+    asyncio.run(up())
+    # nothing joins them; what dies by its reference count leaves
+    assert gc.get_freeze_count() <= frozen
+
+
 # -- staged all-insert writes (ISSUE 12 emit path) ---------------------------
 
 
