@@ -675,6 +675,29 @@ class Case(Expression):
         return f"case({self.whens!r}, else={self.else_!r})"
 
 
+def expr_refs(e: Expression) -> set:
+    """Input column indices an expression reads."""
+    if isinstance(e, InputRef):
+        return {e.index}
+    if isinstance(e, Literal):
+        return set()
+    if isinstance(e, BinaryOp):
+        return expr_refs(e.left) | expr_refs(e.right)
+    if isinstance(e, (UnaryOp, Cast)):
+        return expr_refs(e.child)
+    if isinstance(e, Case):
+        out = expr_refs(e.else_)
+        for c, v in e.whens:
+            out |= expr_refs(c) | expr_refs(v)
+        return out
+    if isinstance(e, FuncCall):
+        out = set()
+        for a in e.args:
+            out |= expr_refs(a)
+        return out
+    raise TypeError(f"unknown expression node {type(e).__name__}")
+
+
 # -- scalar function library (vector_op/ analog, host-typed) ---------------
 # VARCHAR columns are host object arrays; these run vectorized python
 # passes (they are projection-side, not kernel-side). TIMESTAMP is µs

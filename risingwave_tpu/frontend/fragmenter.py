@@ -404,6 +404,8 @@ class Fragmenter:
                 # worker rebuilds run the same epoch-batched path
                 "state_cap": left.state_cap,
                 "output_names": [f.name for f in ex.schema]}
+            if ex.condition is not None:
+                node["condition"] = expr_to_ir(ex.condition)
             if left.fused_input is not None:
                 node["left_fused"] = _stages_ir(left.fused_input)
             if right.fused_input is not None:

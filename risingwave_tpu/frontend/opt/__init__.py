@@ -7,7 +7,8 @@ Dataflow with Local Rewrites", arxiv 2306.10585) to fixpoint between
 the StreamPlanner and deployment:
 
 - executor-graph rules (engine.py / rules.py): filter pushdown below
-  joins (the planner's former inline pushdown, now a rule),
+  joins (the planner's former inline pushdown, now a rule) and into an
+  inner join as its own condition,
   project/filter fusion, noop-project elision, and live-lane column
   pruning that narrows join inputs, agg feeds and source scans down to
   the referenced columns;

@@ -25,6 +25,8 @@ from __future__ import annotations
 
 from typing import Iterable, List, Set
 
+from risingwave_tpu.expr.expr import expr_refs  # noqa: F401 (its old home)
+
 _STRICT = False
 
 
@@ -40,32 +42,6 @@ def strict_checker() -> bool:
 
 class CheckError(ValueError):
     """A rewrite broke a plan invariant."""
-
-
-def expr_refs(e) -> Set[int]:
-    """Input column indices an expression reads."""
-    from risingwave_tpu.expr.expr import (
-        BinaryOp, Case, Cast, FuncCall, InputRef, Literal, UnaryOp,
-    )
-    if isinstance(e, InputRef):
-        return {e.index}
-    if isinstance(e, Literal):
-        return set()
-    if isinstance(e, BinaryOp):
-        return expr_refs(e.left) | expr_refs(e.right)
-    if isinstance(e, (UnaryOp, Cast)):
-        return expr_refs(e.child)
-    if isinstance(e, Case):
-        out = expr_refs(e.else_)
-        for c, v in e.whens:
-            out |= expr_refs(c) | expr_refs(v)
-        return out
-    if isinstance(e, FuncCall):
-        out: Set[int] = set()
-        for a in e.args:
-            out |= expr_refs(a)
-        return out
-    raise CheckError(f"unknown expression node {type(e).__name__}")
 
 
 def _check_expr(e, schema, where: str) -> None:
@@ -266,6 +242,10 @@ def _verify_node(ex) -> None:
         if ex.join_type.subject is None and \
                 len(ex.schema) != eff_arity:
             raise CheckError("HashJoin: output arity != left + right")
+        if ex.condition is not None:
+            # boolean, and an inner join's: `adopt_condition` holds
+            # those; a rewrite can only unbind its references
+            _check_expr(ex.condition, ex.schema, "HashJoin condition")
         return
     if isinstance(ex, HashAggExecutor):
         # fused aggs (opt/fusion.py) absorb a filter/project run: the

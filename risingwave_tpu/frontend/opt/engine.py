@@ -24,9 +24,10 @@ from risingwave_tpu.frontend.opt.checker import CheckError
 MAX_ROUNDS = 8
 
 # applied in registry order each round: pushdown first (filters reach
-# their sources before liveness is computed), fusion + elision shrink
-# the chain, pruning runs over the settled shape; fragment fusion LAST
-# (opt/fusion.py) — it freezes the settled chain into traces
+# their sources, or the inner join whose condition they are, before
+# liveness is computed), fusion + elision shrink the chain, pruning
+# runs over the settled shape; fragment fusion LAST (opt/fusion.py) —
+# it freezes the settled chain into traces
 EXECUTOR_RULES = {
     "filter_pushdown": _rules.push_filters,
     "project_fusion": _rules.fuse_projects,

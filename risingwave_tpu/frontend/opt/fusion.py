@@ -65,7 +65,7 @@ def _as_stage(ex, kinds=BLOCK_KINDS):
     )
     if isinstance(ex, FilterExecutor) and "filter" in kinds:
         return FusedStage("filter", "FilterExecutor",
-                          exprs=(ex.predicate,), books=ex.condition_of)
+                          exprs=(ex.predicate,))
     if isinstance(ex, ProjectExecutor) and "project" in kinds:
         return FusedStage(
             "project", "ProjectExecutor",

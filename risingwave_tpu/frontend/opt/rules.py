@@ -8,7 +8,11 @@ always fall back to the pre-rule tree. Rules:
 - filter_pushdown     WHERE filters sink below joins (kind-gated: only
                       past sides the join never null-pads) and through
                       projections of plain column refs — the planner's
-                      former inline pushdown, migrated here.
+                      former inline pushdown, migrated here. A filter
+                      that reads both sides of an INNER join (the
+                      conjuncts of its ON / WHERE that are no hash
+                      keys) sinks INTO the join and becomes its own
+                      condition, run on the matched pairs.
 - project_fusion      Project∘Project composes into one projection
                       (watermark derivations compose too); a Filter
                       over a ref-only Project evaluates before it.
@@ -246,8 +250,7 @@ def fuse_projects(root) -> Tuple[object, int, str]:
                 # runs before the projection materializes new columns
                 inner = FilterExecutor(p.input,
                                        subst_expr(c2.predicate,
-                                                  p.exprs),
-                                       condition_of=c2.condition_of)
+                                                  p.exprs))
                 return _swap_child(p, "input", None, inner)
         return None
 
@@ -292,10 +295,24 @@ def push_filters(root) -> Tuple[object, int, str]:
         TemporalJoinExecutor,
     )
     fired = 0
+    taken: List[str] = []
 
     def try_push(f):
         """Filter f moves one level down (returns the replacement)."""
         j = f.input
+        refs = expr_refs(f.predicate)
+        if isinstance(j, HashJoinExecutor) and refs and \
+                j.join_type is JoinType.INNER and \
+                min(refs) < j.n_left <= max(refs):
+            # it reads both sides of an inner join: for that join a
+            # filter of its output and a condition on its pairs are
+            # the same relation (upstream's `non_equi_condition`), and
+            # the join holds the pairs on the host when it composes
+            # its output chunk, so it sinks INTO the join
+            new_j = copy.copy(j)
+            new_j.adopt_condition(f.predicate)
+            taken.append(f"{f.predicate!r} into {j.identity}")
+            return new_j
         if isinstance(j, HashJoinExecutor) and \
                 any(s.fused_input is not None for s in j.sides):
             # the join's input executors sit in the absorbed run's RAW
@@ -304,7 +321,6 @@ def push_filters(root) -> Tuple[object, int, str]:
         if isinstance(j, HashJoinExecutor) and j.join_type in (
                 JoinType.INNER, JoinType.LEFT_OUTER,
                 JoinType.RIGHT_OUTER, JoinType.FULL_OUTER):
-            refs = expr_refs(f.predicate)
             n_left = j.n_left
             # legality by join kind: a conjunct may move below a side
             # only if that side is NOT null-padded by this join
@@ -325,7 +341,7 @@ def push_filters(root) -> Tuple[object, int, str]:
             # left side is never null-padded (inner and LEFT forms
             # both pad the right side only)
             n_left = len(j.left_in.schema)
-            if expr_refs(f.predicate) <= set(range(n_left)):
+            if refs <= set(range(n_left)):
                 new_j = copy.copy(j)
                 new_j.left_in = _push_into_side(j.left_in, f.predicate)
                 return new_j
@@ -357,7 +373,10 @@ def push_filters(root) -> Tuple[object, int, str]:
         total += fired - before
         if fired == before:
             break
-    return root, total, f"{total} filter(s) pushed below joins"
+    detail = f"{total - len(taken)} filter(s) pushed below joins"
+    if taken:
+        detail += "; the join's own condition took " + "; ".join(taken)
+    return root, total, detail
 
 
 # -- rule: column pruning -------------------------------------------------
@@ -423,9 +442,7 @@ def _prune(ex, live: Optional[Set[int]], stats,
             if not changed:
                 return ex, None, False
             return _swap_child(ex, "input", None, child), None, True
-        return (FilterExecutor(child,
-                               remap_expr(ex.predicate, cmap),
-                               condition_of=ex.condition_of),
+        return (FilterExecutor(child, remap_expr(ex.predicate, cmap)),
                 cmap, True)
     if isinstance(ex, CoalesceExecutor):
         child, cmap, changed = _prune(ex.input, live_full, stats)
@@ -563,6 +580,9 @@ def _prune_join(j, live_full: Set[int], stats) -> tuple:
         return _prune_opaque_2(j, stats)
     left_side, right_side = j.sides
     n_left = j.n_left
+    if j.condition is not None:
+        # what the join's own condition reads is live through it
+        live_full = live_full | expr_refs(j.condition)
     lreq = ({i for i in live_full if i < n_left}
             | set(left_side.key_indices)
             | set(left_side.table.pk_indices))
@@ -590,6 +610,10 @@ def _prune_join(j, live_full: Set[int], stats) -> tuple:
              + [old_fields[n_left + inv_r[p]].name
                 for p in range(len(rnew.schema))])
     opts = getattr(j, "rebuild_opts", {})
+    mapping = {old: new_i for old, new_i in lmap.items()}
+    n_left_new = len(lnew.schema)
+    for old, new_i in rmap.items():
+        mapping[n_left + old] = n_left_new + new_i
     new = HashJoinExecutor(
         lnew, rnew,
         [lmap[k] for k in left_side.key_indices],
@@ -598,12 +622,10 @@ def _prune_join(j, live_full: Set[int], stats) -> tuple:
         actor_id=opts.get("actor_id", 0), mesh=opts.get("mesh"),
         shard_opts=opts.get("shard_opts"),
         state_cap=opts.get("state_cap"),
-        device_payload=opts.get("device_payload", True))
+        device_payload=opts.get("device_payload", True),
+        condition=(None if j.condition is None
+                   else remap_expr(j.condition, mapping)))
     new.feeds_agg = j.feeds_agg          # the planner's mark
-    mapping = {old: new_i for old, new_i in lmap.items()}
-    n_left_new = len(lnew.schema)
-    for old, new_i in rmap.items():
-        mapping[n_left + old] = n_left_new + new_i
     return new, mapping, True
 
 

@@ -11,7 +11,8 @@ stream/ for when the fragmenter lands).
 Supported streaming shapes: MV over one source (optionally TUMBLE) or
 over another MV (backfill chain), WHERE conjuncts as filters over the
 join chain (the frontend/opt filter_pushdown rule sinks them below
-joins, gated by join kind), multi-way left-deep
+joins, gated by join kind, and into an inner join as its own condition
+where they read both of its sides), multi-way left-deep
 INNER/LEFT/RIGHT/FULL joins of sources on equi-keys, GROUP BY with
 count/sum/min/max/avg (+DISTINCT) over arbitrary expressions, ORDER
 BY/LIMIT TopN, EXPLAIN. Batch: scan/filter/project/agg/join/order/
@@ -780,12 +781,11 @@ class StreamPlanner:
                                         state_cap=cap)
                 lscope = lscope.concat(rscope)
                 for c in cond:
-                    # the join's own condition, directly above it and
-                    # below any later join of the chain; the mark lets
-                    # the books file its rows under this join
-                    left = FilterExecutor(
-                        left, Binder(lscope).bind(c),
-                        condition_of=f"t{lt.table_id}")
+                    # the ON's other conjuncts, directly above their
+                    # join and below any later join of the chain: the
+                    # pushdown rule sinks each below a side, or into
+                    # the join as its own condition
+                    left = FilterExecutor(left, Binder(lscope).bind(c))
             ex = left
             scope = lscope
             join_pk_cols = list(ex.pk_indices)
@@ -1492,10 +1492,12 @@ def _join_keys(jn: ast.Join, conjuncts: List[ast.Expr], lscope: Scope,
 
 def _inner_condition(jn: ast.Join, condition: List[ast.Expr]
                      ) -> List[ast.Expr]:
-    """The ON conjuncts that are not hash keys, for a join that may
-    run them as filters above itself. Only an inner join may: an outer
-    join keeps the rows its ON rejects, NULL-padded, so its condition
-    belongs inside the join and is refused until the join takes one."""
+    """The ON conjuncts that are not hash keys, for a join whose
+    condition is a filter of its matched pairs. Only an inner join's
+    is (the pushdown rule hands it to the join): an outer join keeps
+    the rows its ON rejects, NULL-padded, so its condition has to run
+    before the degree bookkeeping and is refused until the join does
+    that."""
     temporal = getattr(jn, "temporal", False)
     if condition and (temporal or jn.kind != "inner"):
         what = "a temporal join" if temporal \
@@ -1503,9 +1505,10 @@ def _inner_condition(jn: ast.Join, condition: List[ast.Expr]
         raise BindError(
             f"the ON of {what} takes column = column conjuncts only: "
             "any other condition there decides which rows are "
-            "NULL-padded, which a filter above the join cannot do, "
-            "and the join itself evaluates no condition yet. Move it "
-            "to the WHERE only if that is the relation you mean")
+            "NULL-padded, which a filter of the matched pairs cannot "
+            "do, and only an inner join evaluates a condition yet. "
+            "Move it to the WHERE only if that is the relation you "
+            "mean")
     return condition
 
 

@@ -274,11 +274,10 @@ class FusedStage:
     slide_usecs: int = 0
     size_usecs: int = 0
     runtime: object = None
-    # the planner's mark, carried over from the executor: a filter
-    # that is the condition of a JOIN ... ON names that join, a
-    # hop_window the aggregate planned over it (`t<state table id>`).
-    # The run files those stages' rows under the name
-    # (note_stage_rows); host-only, not serialized
+    # the planner's mark, carried over from the executor: a
+    # hop_window names the aggregate planned over it
+    # (`t<state table id>`). The run files that stage's rows under
+    # the name (note_stage_rows); host-only, not serialized
     books: str = ""
 
     @property
@@ -440,12 +439,9 @@ class FusedStages:
         # per-stage row attribution drained by the monitor at barriers
         self.stage_rows = np.zeros(len(self.stages), dtype=np.int64)
         self.stage_chunks = np.zeros(len(self.stages), dtype=np.int64)
-        # the stages the planner marked (FusedStage.books), and the
-        # visible rows handed to the run since the last stage-row
-        # vector was noted: what a marked first stage's books take as
-        # its rows in
-        self._booked = [(si, st) for si, st in enumerate(self.stages)
-                        if st.books]
+        # the visible rows handed to the run since the last stage-row
+        # vector was noted: what a marked hop (FusedStage.books; it
+        # heads its run) takes as its rows in
         self._rows_in = 0
 
     # -- eligibility -------------------------------------------------------
@@ -722,8 +718,8 @@ class FusedStages:
         return outs
 
     def note_rows_in(self, rows: int) -> None:
-        """Visible rows of a chunk the run's owner hands it; only the
-        marked stages' books read it."""
+        """Visible rows of a chunk the run's owner hands it; only a
+        marked hop's books read it."""
         self._rows_in += int(rows)
 
     def note_stage_rows(self, counts: np.ndarray, chunks: int) -> None:
@@ -731,14 +727,9 @@ class FusedStages:
         self.stage_rows += counts
         self.stage_chunks += chunks
         rows_in, self._rows_in = self._rows_in, 0
-        if not self._booked:
-            return
-        from risingwave_tpu.utils import metrics as _m
-        for si, st in self._booked:
-            before = int(counts[si - 1]) if si else rows_in
-            note = _m.note_hop_rows if st.kind == "hop_window" \
-                else _m.note_join_condition
-            note(st.books, before, int(counts[si]))
+        if self.hop is not None and self.hop.books:
+            from risingwave_tpu.utils.metrics import note_hop_rows
+            note_hop_rows(self.hop.books, rows_in, int(counts[0]))
 
     def drain_stage_metrics(self) -> List[Tuple[str, int, int]]:
         # same-kind stages in one run (e.g. filter→filter after an MV

@@ -74,10 +74,6 @@ class FusedFragmentExecutor(Executor):
         self._step = None            # lazy: plan-only processes must
         self._ref = list(stages.ref_cols)   # not init a JAX backend
         self._cap = 0                # the rung the step runs at
-        # the join whose ON condition is a filter stage of this run
-        self._condition_of = next(
-            (st.books for st in stages.stages
-             if st.kind == "filter" and st.books), "")
 
     # MonitoredExecutor drains this at each barrier: per-LOGICAL-stage
     # row/chunk attribution inside the fused block
@@ -179,11 +175,8 @@ class FusedFragmentExecutor(Executor):
         # watermark thresholds) append host-side and enter the trace
         # as ordinary device inputs
         aug = fs.augment(msg)
-        from risingwave_tpu.stream.trace_ctx import join_condition_span
-        with join_condition_span(self._condition_of):
-            flat_vals, flat_ok, vis, ops, stage_rows = \
-                self._run_step(aug)
-            vis = np.asarray(vis)
+        flat_vals, flat_ok, vis, ops, stage_rows = self._run_step(aug)
+        vis = np.asarray(vis)
         fs.note_stage_rows(np.asarray(stage_rows), 1)
         if not vis.any():
             return None

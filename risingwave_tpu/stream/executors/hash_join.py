@@ -19,9 +19,12 @@ ops/hash_join.py):
      row at its own sequence — each result is exact for its sequence
      no matter how much state the epoch applied — and emission runs in
      message order: matched pairs (S columns from the chunk, O columns
-     from O's payload lanes or arena), outer NULL-padding, semi/anti
-     rows, and degree-transition flips. Update pairs degrade to
-     Delete+Insert, as the reference degrades split pairs.
+     from O's payload lanes or arena) through the join's own
+     condition where an inner join has one (the conjuncts of its ON /
+     WHERE that are no hash keys: hash_join.rs `cond`), outer
+     NULL-padding, semi/anti rows, and degree-transition flips. Update
+     pairs degrade to Delete+Insert, as the reference degrades split
+     pairs.
   3. both sides' StateTables commit; watermark expiry and compaction
      run AFTER the sweep (they rewrite device state that a re-
      dispatched probe would need); recovery rebuilds arena + chains
@@ -104,7 +107,8 @@ class JoinType(enum.Enum):
             (self == JoinType.RIGHT_OUTER and side == 1)
 
 from risingwave_tpu.common.chunk import Column, Op, StreamChunk, next_pow2
-from risingwave_tpu.common.types import Field, Schema
+from risingwave_tpu.common.types import DataType, Field, Schema
+from risingwave_tpu.expr.expr import BinaryOp, Expression, expr_refs
 from risingwave_tpu.ops.hash_join import BatchRung, JoinSideKernel
 from risingwave_tpu.state.state_table import StateTable
 from risingwave_tpu.stream.executor import Executor, ExecutorInfo
@@ -112,14 +116,16 @@ from risingwave_tpu.stream.merge import barrier_align_2
 from risingwave_tpu.stream.executors.keys import (
     LANES_PER_KEY, KeyCodec,
 )
+from risingwave_tpu.stream.executors.simple import FilterExecutor
 from risingwave_tpu.stream.message import Message, Watermark, is_barrier
 from risingwave_tpu.stream.trace_ctx import (
-    dispatch_span, join_to_agg_handoff,
+    dispatch_span, join_condition_span, join_to_agg_handoff,
 )
 from risingwave_tpu.stream import costs as _costs
 from risingwave_tpu.stream import hotkeys as _hotkeys
 from risingwave_tpu.utils.ledger import LEDGER, staged
 from risingwave_tpu.utils.metrics import STREAMING as _METRICS
+from risingwave_tpu.utils.metrics import note_join_condition
 
 
 class _Arena:
@@ -353,33 +359,30 @@ class _JoinSide:
             [(self.arena.cols[i][refs], self.arena.valid[i][refs])
              for i in self.pay_indices])
 
-    def cols_from_payload(self, pay_rows: np.ndarray,
-                          refs: np.ndarray, out_cap: int
-                          ) -> List[Column]:
-        """Materialize matched rows from the packed probe matrix:
-        device-typed columns decode from the device-gathered payload
-        lanes; varchar/host-typed columns gather from the arena by ref
-        (the only host gathers left on the emit path)."""
+    def matched_col(self, i: int, pay_rows: Optional[np.ndarray],
+                    refs: np.ndarray, out_cap: int) -> Column:
+        """Column `i` of the matched stored rows: a device-typed
+        column decodes from the payload lanes the probe gathered ON
+        DEVICE (the packed probe matrix); a varchar/host-typed column
+        gathers from the arena by ref (the only host gathers left on
+        the emit path), and so does every column where `pay_rows` is
+        None (the sharded kernel, device_payload off)."""
+        k = self.pay_pos.get(i)
+        if pay_rows is None or k is None:
+            return self.arena.gather_col(i, refs, out_cap)
         from risingwave_tpu.ops import lanes as _lanes
+        f = self.schema[i]
         t = len(refs)
-        out: List[Column] = []
-        for i, f in enumerate(self.schema):
-            k = self.pay_pos.get(i)
-            if k is None:
-                out.append(self.arena.gather_col(i, refs, out_cap))
-                continue
-            hi = pay_rows[:, 3 * k].astype(np.int64)
-            lo = pay_rows[:, 3 * k + 1]
-            v64 = (hi << np.int64(32)) | \
-                lo.view(np.uint32).astype(np.int64)
-            dt = np.dtype(f.data_type.np_dtype)
-            vals = np.zeros(out_cap, dtype=dt)
-            vals[:t] = _lanes.decode_payload_i64(v64, dt)
-            ok = np.ones(out_cap, dtype=bool)
-            ok[:t] = pay_rows[:, 3 * k + 2] != 0
-            out.append(Column(f.data_type, vals,
-                              None if ok.all() else ok))
-        return out
+        hi = pay_rows[:, 3 * k].astype(np.int64)
+        lo = pay_rows[:, 3 * k + 1]
+        v64 = (hi << np.int64(32)) | \
+            lo.view(np.uint32).astype(np.int64)
+        dt = np.dtype(f.data_type.np_dtype)
+        vals = np.zeros(out_cap, dtype=dt)
+        vals[:t] = _lanes.decode_payload_i64(v64, dt)
+        ok = np.ones(out_cap, dtype=bool)
+        ok[:t] = pay_rows[:, 3 * k + 2] != 0
+        return Column(f.data_type, vals, None if ok.all() else ok)
 
     def row_tuple(self, ref: int) -> tuple:
         return tuple(
@@ -781,7 +784,8 @@ class HashJoinExecutor(Executor):
                  join_type: JoinType = JoinType.INNER,
                  mesh=None, shard_opts: Optional[dict] = None,
                  state_cap: Optional[int] = None,
-                 device_payload: bool = True):
+                 device_payload: bool = True,
+                 condition: Optional[Expression] = None):
         assert len(left_keys) == len(right_keys)
         self.left_in, self.right_in = left, right
         self.join_type = join_type
@@ -855,6 +859,14 @@ class HashJoinExecutor(Executor):
         # the join's name in the books (rows in by side and op, rows
         # out, its condition's rows): its left side's state table
         self._books_table = f"t{left_table.table_id}"
+        # an inner join's own condition (a boolean expression over
+        # the output schema; hash_join.rs `cond`), the output columns
+        # it reads, and the pairs it dropped this epoch
+        self.condition: Optional[Expression] = None
+        self._condition_cols: Tuple[int, ...] = ()
+        self._condition_dropped = 0
+        if condition is not None:
+            self.adopt_condition(condition)
         self._tier = None
         self._tier_parts: Tuple = (None, None)
         self._tier_seq = 0
@@ -911,22 +923,25 @@ class HashJoinExecutor(Executor):
 
     # -- emission ---------------------------------------------------------
     @staticmethod
-    def _chunk_cols(schema: Schema, chunk: StreamChunk,
+    def _chunk_col(f: Field, c: Column, idx: np.ndarray,
+                   cap: int) -> Column:
+        """One column gathered from incoming-chunk rows `idx`."""
+        t = len(idx)
+        src = np.asarray(c.values)[idx]
+        vals = np.zeros(cap, dtype=src.dtype) if src.dtype != object \
+            else np.empty(cap, dtype=object)
+        vals[:t] = src
+        ok = np.ones(cap, dtype=bool)
+        if c.validity is not None:
+            ok[:t] = np.asarray(c.validity)[idx]
+        return Column(f.data_type, vals, None if ok.all() else ok)
+
+    @classmethod
+    def _chunk_cols(cls, schema: Schema, chunk: StreamChunk,
                     idx: np.ndarray, cap: int) -> List[Column]:
         """Columns gathered from incoming-chunk rows `idx`."""
-        t = len(idx)
-        out: List[Column] = []
-        for f, c in zip(schema, chunk.columns):
-            src = np.asarray(c.values)[idx]
-            vals = np.zeros(cap, dtype=src.dtype) if src.dtype != object \
-                else np.empty(cap, dtype=object)
-            vals[:t] = src
-            ok = np.ones(cap, dtype=bool)
-            if c.validity is not None:
-                ok[:t] = np.asarray(c.validity)[idx]
-            out.append(Column(f.data_type, vals,
-                              None if ok.all() else ok))
-        return out
+        return [cls._chunk_col(f, c, idx, cap)
+                for f, c in zip(schema, chunk.columns)]
 
     @staticmethod
     def _null_cols(schema: Schema, cap: int) -> List[Column]:
@@ -1030,27 +1045,83 @@ class HashJoinExecutor(Executor):
         post = StreamChunk(fs.out_schema, cols, vis2, ops2)
         return post, encode_raw_chunk(aug, fs.ref_cols)
 
+    def adopt_condition(self, predicate: Expression) -> None:
+        """Take `predicate` (boolean, over the output schema) as a
+        conjunct of this join's own condition: every matched pair goes
+        through it before it is emitted (`_pairs_chunk`). An inner
+        join only: an outer, semi or anti join's condition decides
+        which rows are NULL-padded or counted as matched, which
+        masking the pairs cannot do. The pushdown rule calls this
+        on a copy (frontend/opt/rules.py push_filters)."""
+        if self.join_type is not JoinType.INNER:
+            raise ValueError(
+                f"a {self.join_type.value} join evaluates no condition: "
+                "only an INNER join's is a filter of its pairs")
+        if predicate.return_type != DataType.BOOLEAN:
+            raise ValueError("a join's condition is a boolean expression")
+        self.condition = predicate if self.condition is None \
+            else BinaryOp("and", self.condition, predicate)
+        self._condition_cols = tuple(sorted(expr_refs(self.condition)))
+
+    @property
+    def plan_note(self) -> Optional[str]:
+        """EXPLAIN's note on the join's line."""
+        return None if self.condition is None \
+            else f"condition: {self.condition!r}"
+
     @staged("join.pairs")
     def _pairs_chunk(self, side_idx: int, chunk: StreamChunk,
                      probe_idx: np.ndarray, refs: np.ndarray,
-                     pay: Optional[np.ndarray] = None) -> StreamChunk:
+                     pay: Optional[np.ndarray] = None
+                     ) -> Optional[StreamChunk]:
+        """The matched pairs as one chunk: this side's columns from
+        the incoming chunk, the other's from its payload lanes or its
+        arena (`_JoinSide.matched_col`). Where the join has a condition
+        the columns it reads are built first and the pairs go through
+        THE filter transform (`FilterExecutor.apply_predicate`) on
+        them: what a FilterExecutor directly above the join emitted,
+        visibility, ops and the suppressed empty chunk (None) alike;
+        the other columns are built only for a chunk that keeps a
+        row."""
         t = len(probe_idx)
         cap = next_pow2(t)
         me = self.sides[side_idx]
         other = self.sides[1 - side_idx]
-        # matched stored rows: device columns decode from the payload
-        # lanes the probe's emit walk gathered ON DEVICE (one packed
-        # fetch); only varchar/host columns still gather from the
-        # arena by ref. pay is None on the sharded kernel and with
-        # device_payload off — full arena gather.
-        if pay is not None and other.pay_indices:
-            other_cols = other.cols_from_payload(pay, refs, cap)
-        else:
-            other_cols = other.arena.gather(refs, cap)
-        return self._compose(
-            side_idx, self._chunk_cols(me.schema, chunk, probe_idx, cap),
-            other_cols,
-            self._ops_of(chunk, probe_idx), t, cap)
+
+        def column(j: int) -> Column:
+            """Output column `j`: the left side's come first."""
+            side, i = (0, j) if j < self.n_left else (1, j - self.n_left)
+            if side == side_idx:
+                return self._chunk_col(me.schema[i], chunk.columns[i],
+                                       probe_idx, cap)
+            return other.matched_col(i, pay, refs, cap)
+
+        vis = np.zeros(cap, dtype=bool)
+        vis[:t] = True
+        ops = np.full(cap, int(Op.INSERT), dtype=np.int8)
+        ops[:t] = self._ops_of(chunk, probe_idx)
+        cols: Dict[int, Column] = {
+            j: column(j) for j in self._condition_cols}
+        if self.condition is not None:
+            with join_condition_span(self._books_table):
+                # columns the condition does not read are never looked
+                # at: one blank array stands in for all of them
+                blank = np.zeros(cap, dtype=bool)
+                kept = FilterExecutor.apply_predicate(StreamChunk(
+                    self.schema,
+                    [cols[j] if j in cols else Column(f.data_type, blank)
+                     for j, f in enumerate(self.schema)], vis, ops),
+                    self.condition)
+                vis, ops = kept.visibility, kept.ops
+                n_kept = int(np.count_nonzero(vis))
+                note_join_condition(self._books_table, t, n_kept)
+            self._condition_dropped += t - n_kept
+            if not n_kept:
+                return None
+        return StreamChunk(
+            self.schema,
+            [cols[j] if j in cols else column(j)
+             for j in range(len(self.schema))], vis, ops)
 
     @staged("join.pairs")
     def _padded_from_chunk(self, side_idx: int, chunk: StreamChunk,
@@ -1441,12 +1512,15 @@ class HashJoinExecutor(Executor):
             self._epoch_buf = ([], [])
             self._epoch_rows = [0, 0]
             self._deg_replay = [None, None]
-        n_out = float(sum(c.cardinality() for c in outs))
+        handed = float(sum(c.cardinality() for c in outs))
+        # the pairs matched on the keys, before the condition
+        n_out = handed + self._condition_dropped
+        self._condition_dropped = 0
         if n_out:
             _METRICS.join_output_rows.inc(n_out, table=self._books_table)
         if self.feeds_agg:
             _METRICS.join_to_agg_rows.inc(
-                n_out, view=_costs.current_mv() or "")
+                handed, view=_costs.current_mv() or "")
         return outs
 
     def _note_batch_books(self) -> None:
@@ -1517,8 +1591,10 @@ class HashJoinExecutor(Executor):
         outs: List[StreamChunk] = []
         # 1) matched pairs (all types except semi/anti)
         if jt.subject is None and len(probe_idx):
-            outs.append(self._pairs_chunk(side_idx, chunk, probe_idx,
-                                          refs, pay))
+            pairs = self._pairs_chunk(side_idx, chunk, probe_idx,
+                                      refs, pay)
+            if pairs is not None:
+                outs.append(pairs)
         # 2) incoming-row direct emissions
         if jt.outer_on(side_idx):
             # NULL-key rows of an outer side always emit padded

@@ -146,14 +146,9 @@ class ProjectExecutor(Executor):
 class FilterExecutor(Executor):
     """Visibility-mask filter with update-pair degradation (filter.rs)."""
 
-    def __init__(self, input_: Executor, predicate: Expression,
-                 condition_of: str = ""):
+    def __init__(self, input_: Executor, predicate: Expression):
         self.input = input_
         self.predicate = predicate
-        # the planner's mark: this filter is the condition of a
-        # JOIN ... ON (its conjuncts that are no hash keys), and the
-        # name is that join's in the books (`t<state table id>`)
-        self.condition_of = condition_of
         info = ExecutorInfo(input_.schema, list(input_.pk_indices),
                             "FilterExecutor")
         super().__init__(info)
@@ -162,10 +157,7 @@ class FilterExecutor(Executor):
         import numpy as np
         async for msg in self.input.execute():
             if is_chunk(msg):
-                if self.condition_of:
-                    out = self._apply_condition(msg)
-                else:
-                    out = self._apply(msg)
+                out = self._apply(msg)
                 # a fully-filtered chunk is dead weight downstream
                 # (empty-message suppression, end to end)
                 if np.asarray(out.visibility).any():
@@ -176,24 +168,15 @@ class FilterExecutor(Executor):
     def _apply(self, chunk: StreamChunk) -> StreamChunk:
         return self.apply_predicate(chunk, self.predicate)
 
-    def _apply_condition(self, chunk: StreamChunk) -> StreamChunk:
-        """`_apply`, on the books of the join whose condition this
-        filter is."""
-        from risingwave_tpu.stream.trace_ctx import join_condition_span
-        from risingwave_tpu.utils.metrics import note_join_condition
-        with join_condition_span(self.condition_of):
-            out = self._apply(chunk)
-            note_join_condition(self.condition_of, chunk.cardinality(),
-                                out.cardinality())
-        return out
-
     @staticmethod
     def apply_predicate(chunk: StreamChunk,
                         predicate: Expression) -> StreamChunk:
         """THE filter transform — xp-generic, so the interpretive path
-        (numpy) and the fused traced path (jit tracers, ops/fused.py)
-        run the same implementation: visibility mask plus U-/U+ pair
-        degradation by shifted compares."""
+        (numpy), the fused traced path (jit tracers, ops/fused.py) and
+        an inner join's own condition on its matched pairs
+        (HashJoinExecutor._pairs_chunk, numpy) run the same
+        implementation: visibility mask plus U-/U+ pair degradation by
+        shifted compares."""
         pcol = predicate.eval(chunk)
         xp = get_xp(pcol.values, chunk.ops)
         pred = pcol.values.astype(bool)

@@ -55,7 +55,9 @@ Node shapes (dicts, `op` discriminated):
    "right_keys": [...], "left_table_id": n, "right_table_id": n,
    "left_pk": [...], "right_pk": [...], "join_type": "inner",
    "left_dist_key": [...], "right_dist_key": [...],  # optional:
-   "output_names": [...]}   # vnode dist of the join state tables
+   "output_names": [...],   # vnode dist of the join state tables
+   "condition": EXPR}       # optional: an inner join's own condition
+                            # over its output columns
   {"op": "materialize", "input": N, "table_id": n, "pk": [...],
    "dist_key": [...]}           # optional: vnode partitioning of the
                                 # MV rows (must be a pk subset) — set
@@ -423,7 +425,9 @@ def build_fragment(nodes: List[dict], store, local,
                 actor_id=int(actor_id or 0),
                 join_type=JoinType(node.get("join_type", "inner")),
                 output_names=node.get("output_names"),
-                state_cap=None if cap is None else int(cap))
+                state_cap=None if cap is None else int(cap),
+                condition=(expr_from_ir(node["condition"])
+                           if node.get("condition") else None))
             if l_fs is not None:
                 ex.adopt_fused_input(0, l_fs, left)
             if r_fs is not None:

@@ -55,19 +55,14 @@ def join_to_agg_handoff(on: bool):
 
 @contextlib.contextmanager
 def join_condition_span(join: str):
-    """The host seconds in which the condition of a JOIN ... ON (its
-    conjuncts that are no hash keys) is evaluated, filed under the
-    join the planner marked the filter with: counter
+    """The host seconds in which an inner join evaluates its own
+    condition (the conjuncts of its ON / WHERE that are no hash keys)
+    on the pairs it matched, filed under the join: counter
     ``stream_join_condition_seconds{table}``
     (``join_condition.<join>.seconds`` of rw_metrics_history) and a
-    ``join.condition`` annotation on the profiler's clock. Where the
-    filter is a stage of a fused block the step evaluates it together
-    with the block's projection, and the seconds are the step's. A
-    second cut of the wall time, like ``exec_s.<Kind>``. No mark, no
-    books."""
-    if not join:
-        yield
-        return
+    ``join.condition`` annotation on the profiler's clock. They lie
+    inside the join's ``join.pairs`` stage: a second cut of the wall
+    time, like ``exec_s.<Kind>``."""
     t0 = time.perf_counter()
     try:
         with _spans.annotation("join.condition"):

@@ -390,17 +390,18 @@ class StreamingMetrics:
             "and op: what retracts in a join's inputs")
         self.join_output_rows = r.counter(
             "stream_join_output_rows",
-            "rows a HashJoinExecutor emitted, by join, before any "
-            "condition above it")
+            "pairs a HashJoinExecutor matched on its keys, by join, "
+            "before its condition (and its other rows out)")
         self.join_condition_rows = r.counter(
             "stream_join_condition_rows",
-            "rows the condition of a JOIN ... ON (its conjuncts that "
-            "are not hash keys) was evaluated on, by join and result "
-            "(kept, dropped), wherever the condition runs")
+            "matched pairs an inner join's own condition (the "
+            "conjuncts of its ON / WHERE that are no hash keys) was "
+            "evaluated on, by join and result (kept, dropped)")
         self.join_condition_seconds = r.counter(
             "stream_join_condition_seconds",
-            "host seconds spent evaluating the condition of a "
-            "JOIN ... ON, by join (trace_ctx.join_condition_span)")
+            "host seconds an inner join spent evaluating its own "
+            "condition on its matched pairs, by join "
+            "(trace_ctx.join_condition_span)")
         self.hop_rows = r.counter(
             "stream_hop_rows",
             "rows into and out of a HOP window expansion, by dir (in, "
@@ -791,9 +792,8 @@ CLUSTER = ClusterMetrics()
 
 
 def note_join_condition(join: str, rows_in: int, kept: int) -> None:
-    """File the rows a join's condition saw under that join, wherever
-    the condition ran (a FilterExecutor, or a filter stage of a fused
-    run)."""
+    """File the pairs a join's own condition saw under that join
+    (HashJoinExecutor._pairs_chunk)."""
     if kept:
         STREAMING.join_condition_rows.inc(float(kept), table=join,
                                           result="kept")

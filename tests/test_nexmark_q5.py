@@ -6,7 +6,7 @@ of a `JOIN ... ON` into hash keys and condition; the books the query
 keeps on the way (rows into and out of the join, through its
 condition and through the HOP, the longest chain a probe walked); and
 the two places where a program's shape must not follow the join's
-output: the fused chain above it and the probe's pair buffer.
+output: a fused chain above a join and the probe's pair buffer.
 
 Epochs are a fixed number of chunks per reader, so nothing here waits
 on a clock. Only the source's chunk size is rewritten, to cut the
@@ -177,18 +177,26 @@ def test_the_text_is_upstreams_and_plans_on_the_device_path(q5):
     assert text.count("HOP(bid, date_time, INTERVAL '2' SECOND, "
                       "INTERVAL '10' SECOND)") == 2
     run_ = q5["lockstep"]
-    detail = " ".join(str(r[3]) for r in run_["rewrites"])
+    # the registry is the process's: other files' jobs are in it too
+    detail = " ".join(str(r[3]) for r in run_["rewrites"]
+                      if r[0] == "q5")
     # both HOPs fused into their aggregates, the condition into the
-    # block above the join, both join sides on their preludes
+    # join itself, both join sides on their preludes; nothing but the
+    # selection of two columns between the join and the view
     assert detail.count(
         "agg absorbed HopWindowExecutor→ProjectExecutor") == 2
-    assert "block FilterExecutor→ProjectExecutor" in detail
+    assert "the join's own condition took ($1:int64 >= $3:int64) into " \
+        "HashJoinExecutor(inner" in detail
+    assert "block " not in detail
     assert "join side 0 absorbed" in detail
     assert "join side 1 absorbed" in detail
     assert run_["plan"].count("HashAggExecutor") == 3
     assert run_["plan"].count("SourceExecutor") == 2
-    assert "FusedFragmentExecutor" in run_["plan"]
+    assert "FusedFragmentExecutor" not in run_["plan"]
     assert "FilterExecutor" not in run_["plan"]
+    at = run_["plan"].index("HashJoinExecutor")
+    assert run_["plan"][at - 2:at] == ["MaterializeExecutor",
+                                       "ProjectExecutor"]
 
 
 def test_the_readers_stood_as_the_cases_say(q5):
@@ -229,12 +237,19 @@ def test_the_joins_output_and_its_condition_are_on_the_books(q5, case):
     out = _sum(run_, f"join_output.{join}.rows")
     kept = _sum(run_, f"join_condition.{join}.kept")
     dropped = _sum(run_, f"join_condition.{join}.dropped")
-    # every row out of the join meets the condition, which keeps few
+    # the join files the pairs it matched on the keys, and what its
+    # own condition made of each of them: it keeps few
     assert out == kept + dropped
     assert 0 < kept < dropped
     # what the condition kept, inserts less deletes, is the view
     assert kept >= sum(run_["view"].values())
-    assert _sum(run_, f"join_condition.{join}.seconds") > 0
+    # the join evaluates it while it builds its pairs' chunk: the
+    # seconds lie inside its `join.pairs` stage, barrier by barrier
+    seconds = _sum(run_, f"join_condition.{join}.seconds")
+    assert 0 < seconds < _sum(run_, "stage.host_emit.join.pairs")
+    for h in run_["history"].values():
+        assert h.get(f"join_condition.{join}.seconds", 0) <= \
+            h.get("stage.host_emit.join.pairs", 0)
     bids = sum(r["rows"] for r in run_["readers"])
     bench = _bench_module("layer_metrics", "join_out_rows_per_source_row")
     assert bench.read({"history": run_["history"]}) == pytest.approx(
@@ -365,43 +380,61 @@ def _chain(fe, view="m"):
 
 
 ON_CASES = {
-    # ON text: (left keys, right keys, filters marked as the join's)
+    # ON text: (left keys, right keys, conjuncts of the join's condition)
     "l.k = r.k": ([0], [0], 0),
     "l.k = r.k AND l.x >= r.y": ([0], [0], 1),
     "l.x >= r.y AND r.k = l.k": ([0], [0], 1),
+    # a conjunct of one side is sunk below that side
     "l.k = r.k AND l.x >= r.y AND l.s = r.y AND r.y <> 3":
-        ([0, 2], [0, 1], 2),
-    # a column = column of one side is no hash key; the pushdown rule
-    # then sinks it below that side, where it is the join's no more
+        ([0, 2], [0, 1], 1),
+    # a column = column of one side is no hash key either
     "l.k = r.k AND l.x = l.s": ([0], [0], 0),
 }
 
 
+def _conjuncts(e) -> list:
+    from risingwave_tpu.expr.expr import BinaryOp
+    if isinstance(e, BinaryOp) and e.op == "and":
+        return _conjuncts(e.left) + _conjuncts(e.right)
+    return [] if e is None else [e]
+
+
+@pytest.mark.parametrize("fusion", ["on", "off"])
 @pytest.mark.parametrize("on", list(ON_CASES))
-def test_on_splits_into_hash_keys_and_condition(on):
+def test_on_splits_into_hash_keys_and_condition(on, fusion):
+    """The planner takes the ON's hash keys; the pushdown rule hands
+    the join the other conjuncts that read both sides and sinks the
+    rest below the side they read, fusion on or off: no filter is left
+    above the join."""
+    from risingwave_tpu.frontend.opt.checker import expr_refs
+    from risingwave_tpu.stream.executors.fused import (
+        FusedFragmentExecutor,
+    )
     from risingwave_tpu.stream.executors.hash_join import HashJoinExecutor
+    from risingwave_tpu.stream.executors.simple import FilterExecutor
 
     async def planned():
-        # fusion off: the condition's filters stay executors to count
         fe = await _session(
-            "SET stream_fusion = off",
+            f"SET stream_fusion = {fusion}",
             f"CREATE MATERIALIZED VIEW m AS SELECT l.x, r.y "
             f"FROM l JOIN r ON {on}")
         try:
             chain = _chain(fe)
-            join, = [ex for ex in chain
-                     if isinstance(ex, HashJoinExecutor)]
-            marked = [ex for ex in chain
-                      if getattr(ex, "condition_of", "")]
-            assert all(ex.condition_of == join._books_table
-                       for ex in marked)
+            at, = [i for i, ex in enumerate(chain)
+                   if isinstance(ex, HashJoinExecutor)]
+            join = chain[at]
+            assert not [ex for ex in chain[:at] if isinstance(
+                ex, (FilterExecutor, FusedFragmentExecutor))]
+            conjuncts = _conjuncts(join.condition)
+            assert all(min(expr_refs(c)) < join.n_left <= max(
+                expr_refs(c)) for c in conjuncts)
             return ([list(s.key_indices) for s in join.sides],
-                    len(marked))
+                    len(conjuncts))
         finally:
             await fe.close()
 
-    lkeys, rkeys, marked = ON_CASES[on]
-    assert asyncio.run(planned()) == ([lkeys, rkeys], marked)
+    lkeys, rkeys, conjuncts = ON_CASES[on]
+    assert asyncio.run(planned()) == ([lkeys, rkeys], conjuncts)
 
 
 def test_on_with_equalities_alone_plans_as_before():
@@ -476,11 +509,11 @@ def _series(metric, **labels) -> float:
 
 @pytest.mark.parametrize("fusion", ["on", "off"])
 def test_the_joins_books_against_hand_counts(fusion):
-    """Three rows a side: keys 1 (two left rows) and 2 match, three
-    pairs come out of the join, the `>=` keeps (5, 3) only; taking
+    """Three rows a side: keys 1 (two left rows) and 2 match, the
+    join matches three pairs, the `>=` keeps (5, 3) only; taking
     back r's row of key 1 deletes two pairs, one of which the
-    condition had kept. Fused into the block above the join or run by
-    the FilterExecutor, the books read the same."""
+    condition had kept. The join evaluates it, fusion on or off, and
+    the books read the same."""
     from risingwave_tpu.stream.executors.hash_join import HashJoinExecutor
     from risingwave_tpu.utils.metrics import STREAMING as S
 
@@ -492,9 +525,11 @@ def test_the_joins_books_against_hand_counts(fusion):
         try:
             chain = _chain(fe)
             kinds = [type(ex).__name__ for ex in chain]
-            assert ("FusedFragmentExecutor" in kinds) == (fusion == "on")
+            assert "FusedFragmentExecutor" not in kinds
+            assert "FilterExecutor" not in kinds
             join, = [ex for ex in chain
                      if isinstance(ex, HashJoinExecutor)]
+            assert repr(join.condition) == "($1:int64 >= $4:int64)"
             t = join._books_table
 
             def books():
@@ -602,33 +637,35 @@ def _chain_traces() -> float:
 @pytest.mark.parametrize("top", [65_536, 64])
 def test_the_chain_above_a_join_keeps_to_its_largest_rung(
         top, monkeypatch):
-    """The join's output chunks are as large as its matches; the chain
+    """A join's output chunks are as large as its matches; a chain
     above it runs at the ladder's rungs and never steps down, so its
     programs are as many as the rungs it climbed. Above the top a
     chunk is cut into pieces. The rows are those of the executors run
-    one by one."""
+    one by one. (The join is an outer one: an inner join takes such a
+    WHERE as its own condition and leaves no chain above it.)"""
     from risingwave_tpu.stream.executors import fused
 
     monkeypatch.setattr(fused, "CHAIN_CAP_TOP", top)
-    # matches of one r row on key k: 3, 100, 2, 300, 5
+    # matches of l's rows of key k with its one r row: 3, 100, 2, 300, 5
     sizes = {1: 3, 2: 100, 3: 2, 4: 300, 5: 5}
 
     async def run(fusion):
         fe = await _session(
             f"SET stream_fusion = {fusion}",
             "CREATE MATERIALIZED VIEW m AS SELECT l.x + 1 AS x1, r.y "
-            "FROM l JOIN r ON l.k = r.k AND l.x >= r.y")
+            "FROM l LEFT JOIN r ON l.k = r.k WHERE l.x >= r.y")
         try:
             block = [ex for ex in _chain(fe) if isinstance(
                 ex, fused.FusedFragmentExecutor)]
-            await fe.execute("INSERT INTO l VALUES " + ", ".join(
-                f"({k}, {x}, 0)" for k, n in sizes.items()
-                for x in range(n)))
+            assert len(block) == (fusion == "on")
+            await fe.execute("INSERT INTO r VALUES " + ", ".join(
+                f"({k}, 1)" for k in sizes))
             await fe.execute("FLUSH")
             caps, traces = [], []
-            for k in sizes:
+            for k, n in sizes.items():
                 before = _chain_traces()
-                await fe.execute(f"INSERT INTO r VALUES ({k}, 1)")
+                await fe.execute("INSERT INTO l VALUES " + ", ".join(
+                    f"({k}, {x}, 0)" for x in range(n)))
                 await fe.execute("FLUSH")
                 traces.append(_chain_traces() - before)
                 caps.append(block[0]._cap if block else None)

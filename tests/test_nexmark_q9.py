@@ -273,9 +273,18 @@ def test_the_text_is_upstreams_and_plans_a_join_and_a_group_top_n(q9):
         "GroupTopNExecutor  -- group: [id], order: [price DESC, "
         "bid_date_time ASC], limit: 1, append_only: true") == 2
     assert "HashJoinExecutor(inner" in explain
-    # the BETWEEN's two halves, planned as the join's own filters
+    # the BETWEEN's two halves are planned as filters above the join
+    # and sink into it: the join evaluates them on its pairs, and the
+    # top-N stands directly above the join
+    pre, post = explain.split("-- rewritten plan")
     assert re.search(r"GroupTopNExecutor.*\n\s+FilterExecutor\n\s+"
-                     r"FilterExecutor\n\s+HashJoinExecutor", explain)
+                     r"FilterExecutor\n\s+HashJoinExecutor", pre)
+    assert re.search(
+        r"GroupTopNExecutor.*\n\s+HashJoinExecutor\(inner.*  -- "
+        r"condition: \(\(\$13:timestamp >= \$5:timestamp\) and "
+        r"\(\$13:timestamp <= \$6:timestamp\)\)\n", post)
+    assert "FilterExecutor" not in post
+    assert "FusedFragmentExecutor" not in post
     # bid.channel, bid.url and both extra columns are read by nothing:
     # pruning sees through the top-N and the join carries 15 lanes
     assert "rule column_pruning: 4 column lane(s) pruned" in explain

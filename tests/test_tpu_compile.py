@@ -327,13 +327,22 @@ def test_fused_chain_step(served, one_chip):
 @pytest.fixture(scope="module")
 def served_q5():
     """The benchmark's `nexmark-q5` view, its text from the
-    configuration file, three barriers deep."""
+    configuration file, three barriers deep; beside it `q5_outer`,
+    the same text as a LEFT JOIN with the `>=` in a WHERE: an inner
+    join evaluates such a condition itself, an outer join leaves it
+    to a fused chain above it."""
     import json
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmark", "configs",
                            "nexmark-q5.json")) as f:
         ddl = json.load(f)["ddl"]
-    yield from _serve_on_cpu([d.format(seed=33) for d in ddl], steps=3)
+    outer = ddl[-1].replace("VIEW q5 AS", "VIEW q5_outer AS").replace(
+        "\nJOIN (", "\nLEFT JOIN (").replace(
+        " AND AuctionBids.num >=", "\nWHERE AuctionBids.num >=")
+    assert outer.count("q5_outer") == 1 and "LEFT JOIN" in outer \
+        and "WHERE" in outer
+    yield from _serve_on_cpu([d.format(seed=33) for d in ddl + [outer]],
+                             steps=3)
 
 
 def test_q5_count_aggregate_with_the_hop_prelude(served_q5, one_chip):
@@ -376,11 +385,15 @@ def test_q5_probe_of_the_count_side_in_pages(served_q5, one_chip):
 
 
 def test_q5_chain_above_the_join_at_its_top_rung(served_q5, one_chip):
-    """fused.chain_step: the `>=` and the projection above q5's join,
-    at the ladder's top, 65,536 rows."""
+    """fused.chain_step: the `>=` and the projection above q5's join
+    where that join is an outer one, at the ladder's top, 65,536 rows
+    (q5's own inner join takes the `>=` as its condition and has no
+    chain above it)."""
     from risingwave_tpu.stream.executors.fused import CHAIN_CAP_TOP
+    assert not [ex for ex in _executors(served_q5, "q5")
+                if getattr(ex, "_step", None) is not None]
     (step,) = {id(ex._step): ex._step
-               for ex in _executors(served_q5, "q5")
+               for ex in _executors(served_q5, "q5_outer")
                if getattr(ex, "_step", None) is not None}.values()
     assert step.label == "fused.chain_step" and step._args is not None
 

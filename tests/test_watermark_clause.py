@@ -300,14 +300,14 @@ GOLDEN = {
         "/ MaterializeExecutor",
         "/input ProjectExecutor",
         "/input/input HashAggExecutor(actor=0)[fused:ProjectExecutor]",
-        "/input/input/input HashAggExecutor(actor=0)[fused:FilterExecutor→FilterExecutor→ProjectExecutor]",
+        "/input/input/input HashAggExecutor(actor=0)[fused:ProjectExecutor]",
         "/input/input/input/input HashJoinExecutor(inner, actor=1000)[fused:L:ProjectExecutor→RowIdGenExecutor; R:ProjectExecutor→RowIdGenExecutor→join]",
         "/input/input/input/input/left_in SourceExecutor",
         "/input/input/input/input/right_in SourceExecutor"
     ],
     "nexmark-q5": [
         "/ MaterializeExecutor",
-        "/input FusedFragmentExecutor[FilterExecutor→ProjectExecutor]",
+        "/input ProjectExecutor",
         "/input/input HashJoinExecutor(inner, actor=1000)[fused:L:ProjectExecutor; R:ProjectExecutor→join]",
         "/input/input/left_in HashAggExecutor(actor=0)[fused:HopWindowExecutor→ProjectExecutor]",
         "/input/input/left_in/input SourceExecutor",
@@ -437,7 +437,7 @@ GOLDEN_SIX = {
             "/ MaterializeExecutor",
             "/input ProjectExecutor",
             "/input/input HashAggExecutor(actor=0)[fused:ProjectExecutor]",
-            "/input/input/input HashAggExecutor(actor=0)[fused:FilterExecutor→FilterExecutor→ProjectExecutor]",
+            "/input/input/input HashAggExecutor(actor=0)[fused:ProjectExecutor]",
             "/input/input/input/input HashJoinExecutor(inner, actor=1000)[fused:L:ProjectExecutor→RowIdGenExecutor; R:ProjectExecutor→RowIdGenExecutor→join]",
             "/input/input/input/input/left_in SourceExecutor",
             "/input/input/input/input/right_in SourceExecutor",
@@ -453,7 +453,7 @@ GOLDEN_SIX = {
     "nexmark-q5": {
         "chain": [
             "/ MaterializeExecutor",
-            "/input FusedFragmentExecutor[FilterExecutor→ProjectExecutor]",
+            "/input ProjectExecutor",
             "/input/input HashJoinExecutor(inner, actor=1000)[fused:L:ProjectExecutor; R:ProjectExecutor→join]",
             "/input/input/left_in HashAggExecutor(actor=0)[fused:HopWindowExecutor→ProjectExecutor]",
             "/input/input/left_in/input SourceExecutor",
@@ -477,7 +477,7 @@ GOLDEN_SIX = {
     "nexmark-q5-wm": {
         "chain": [
             "/ MaterializeExecutor",
-            "/input FusedFragmentExecutor[FilterExecutor→ProjectExecutor]",
+            "/input ProjectExecutor",
             "/input/input HashJoinExecutor(inner, actor=1000)[fused:L:ProjectExecutor; R:ProjectExecutor→join]",
             "/input/input/left_in HashAggExecutor(actor=0)[fused:HopWindowExecutor→ProjectExecutor]",
             "/input/input/left_in/input WatermarkFilterExecutor",
