@@ -21,6 +21,10 @@ class KeyOp(enum.Enum):
     UPDATE = "update"
 
 
+# what a tombstone with no old row is buffered as: one shared tuple
+_TOMBSTONE = (KeyOp.DELETE, None, None)
+
+
 class MemTable:
     """key → (op, old_value, new_value); op merge rules match mem_table.rs."""
 
@@ -50,6 +54,29 @@ class MemTable:
         self._ops.update(zip(new.keys(),
                              [(ins, None, v) for v in new.values()]))
         return True
+
+    def delete_batch(self, keys) -> None:
+        """A batch of tombstones beside ``insert_batch``, for keys the
+        caller holds no rows of (a watermark's range delete, a join
+        side's expiry): ONE dict merge
+        for the keys that are not buffered, ``delete``'s merge rules
+        for each that is (insert + delete annihilate, a delete over an
+        update keeps its old row, a double delete raises under
+        ``sanity_check``), and for every key where one comes twice.
+        The buffer ends up as ``delete(key, None)`` a key, in order,
+        leaves it: a buffered key keeps its place, the fresh ones
+        follow in the batch's order. Such a tombstone carries no old
+        row: nothing reads one (a flush writes the key and
+        ``None``)."""
+        new = dict.fromkeys(keys, _TOMBSTONE)
+        if len(new) != len(keys):
+            for key in keys:
+                self.delete(key, None)
+            return
+        for key in self._ops.keys() & new.keys():
+            self.delete(key, None)
+            del new[key]
+        self._ops.update(new)
 
     def drain_bulk(self):
         """(keys, values) lists for ingest_keyed; clears. Same content

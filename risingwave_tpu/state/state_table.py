@@ -298,9 +298,7 @@ class StateTable:
                     doomed.discard(key)
                 else:
                     doomed.add(key)
-        mt = self.mem_table
-        for key in doomed:
-            mt.delete(key, None)
+        self.mem_table.delete_batch(doomed)
         deleted = len(doomed)
         _METRICS.state_cleaned_rows.inc(float(deleted), table=label)
         self.note_cleaned(watermark)
@@ -389,6 +387,14 @@ class StateTable:
         mt = self.mem_table
         for key, row in zip(self._encode_pk_rows(rows, pk_cols), rows):
             mt.delete(key, tuple(row))
+
+    @staged("state.write")
+    def delete_keys(self, pk_cols: Sequence[KeyColumn], n: int) -> None:
+        """Batch delete of ``n`` rows by their pk columns alone: the
+        caller holds columns and builds no row (a join side's
+        watermark expiry). The tombstones carry no old row."""
+        self._spill_staged()
+        self.mem_table.delete_batch(self._encode_key_columns(pk_cols, n))
 
     @staged("state.write")
     def update_rows(self, old_rows: Sequence[Sequence],

@@ -38,6 +38,7 @@ non-null-safe is the SQL default).
 from __future__ import annotations
 
 import enum
+from itertools import compress
 from typing import AsyncIterator, Dict, List, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
@@ -384,14 +385,6 @@ class _JoinSide:
         ok[:t] = pay_rows[:, 3 * k + 2] != 0
         return Column(f.data_type, vals, None if ok.all() else ok)
 
-    def row_tuple(self, ref: int) -> tuple:
-        return tuple(
-            None if not self.arena.valid[i][ref]
-            else (self.arena.cols[i][ref].item()
-                  if self.schema[i].data_type.is_device
-                  else self.arena.cols[i][ref])
-            for i in range(len(self.schema)))
-
     def alloc_refs(self, k: int) -> np.ndarray:
         """Bump allocation ONLY: a tombstoned ref stays linked in its
         chain (deletes unlink lazily), so reusing it would splice its
@@ -580,8 +573,13 @@ class _JoinSide:
             for lt, vt in dead_cold:
                 del self.cold_keys[lt]
                 self.expired_lanes.append(lt)
+                # a row that came after its key went cold is resident
+                # (reload_keys skips it likewise): the pass below
+                # deletes it, once
                 dead_rows = [tuple(row) for _pk, row
-                             in self.table.iter_prefix(list(vt))]
+                             in self.table.iter_prefix(list(vt))
+                             if tuple(row[i] for i in self.pk_indices)
+                             not in self.pk_to_ref]
                 if dead_rows:
                     self.table.delete_rows(dead_rows)
                     n_cold += len(dead_rows)
@@ -593,17 +591,23 @@ class _JoinSide:
         vals = self.arena.cols[col][refs]
         ok = self.arena.valid[col][refs]
         dead = ok & (vals.astype(np.int64) < int(wm_physical))
-        n_dead = int(dead.sum())
+        dead_refs = refs[dead]
+        n_dead = len(dead_refs)
         if n_dead == 0:
             return n_cold
-        dead_refs = refs[dead].astype(np.int32)
-        pks = list(self.pk_to_ref.keys())
-        dead_pks = [pks[i] for i in np.flatnonzero(dead).tolist()]
-        for pk, ref in zip(dead_pks, dead_refs.tolist()):
+        # by the column from here to the memtable, and no row is
+        # built. The map loses its dead under its own keys (the arena's
+        # pk columns hold the same values, but a NaN there would not
+        # find itself, and the map's own tuples compare by identity)
+        for pk in list(compress(self.pk_to_ref, dead.tolist())):
             del self.pk_to_ref[pk]
-            self.free.append(ref)
-        self.table.delete_rows([self.row_tuple(r)
-                                for r in dead_refs.tolist()])
+        self.free.extend(dead_refs.tolist())
+        # the state table takes the dead rows' pk columns as the arena
+        # holds them, and encodes its tombstones' keys from those
+        self.table.delete_keys(
+            [(self.arena.cols[i][dead_refs], self.arena.valid[i][dead_refs])
+             for i in self.pk_indices], n_dead)
+        dead_refs = dead_refs.astype(np.int32)
         # key lanes of the dead refs: the sharded kernel routes the
         # tombstone to the key's owner shard (single-chip ignores them)
         key_cols = [(self.arena.cols[i][dead_refs],
@@ -1735,7 +1739,9 @@ class HashJoinExecutor(Executor):
     @staged("join.expire")
     def _expire_to(self, pos: int, wm: int) -> None:
         for side in self.sides:
-            side.expire_below(pos, wm, seq=self._seq)
+            expired = side.expire_below(pos, wm, seq=self._seq)
+            _METRICS.join_expired_rows.inc(
+                float(expired), table=f"t{side.table.table_id}")
             # the side now holds no row below the watermark on this
             # key column
             side.table.note_cleaned(wm)

@@ -340,6 +340,12 @@ class StreamingMetrics:
             "stream_state_cleaned_rows",
             "rows a watermark's range delete took out of a state "
             "table, by table (t<state table id>)")
+        self.join_expired_rows = r.counter(
+            "stream_join_expired_rows",
+            "rows a watermark's expiry took out of a join side, "
+            "resident and cold, by the side's state table (t<state "
+            "table id>): a side deletes its rows itself, so they are "
+            "not among stream_state_cleaned_rows")
         self.state_clean_reads = r.counter(
             "stream_state_clean_reads",
             "rows a watermark's range delete read from the store, by "
@@ -911,7 +917,8 @@ class MetricsHistory:
         its write-through, the rows into and out of a top-N with its
         table's writes and what it keeps, the rows to_char was given and the strftime
         calls it made, what a watermark cleaned out
-        of the state tables and the rows they keep, the rows a
+        of the state tables and expired from the join sides and the
+        rows the tables keep, the rows a
         watermark filter dropped, the longest chain a join's
         probe walked, the key skew of a staged batch, the rounds of
         probe_insert's loop and the rows they worked. A plan with no
@@ -952,6 +959,8 @@ class MetricsHistory:
                 ("state_clean.{table}.cleaned", S.state_cleaned_rows,
                  "counter"),
                 ("state_clean.{table}.reads", S.state_clean_reads,
+                 "counter"),
+                ("join_expire.{table}.rows", S.join_expired_rows,
                  "counter"),
                 ("state_clean_index.{table}.seeds", S.state_clean_seeds,
                  "counter"),

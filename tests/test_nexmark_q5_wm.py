@@ -201,9 +201,22 @@ async def _start(store, config: dict, case: str):
     return fe
 
 
+def _history(rows) -> list:
+    """`rw_metrics_history` as one dict a barrier, oldest first, less
+    the first: the first row after HISTORY.clear() takes the registry's
+    process-wide counter totals, other tests' among them, as its delta
+    (here a DDL round's, before any data)."""
+    out = {}
+    for _seq, epoch, ts, _interval_s, name, value, _dom in rows:
+        out.setdefault(epoch, {"ts": ts})[name] = value
+    return sorted(out.values(), key=lambda h: h["ts"])[1:]
+
+
 async def _drive(config: dict, case: str) -> dict:
     from risingwave_tpu.state.topology import TOPOLOGY
+    from risingwave_tpu.utils.metrics import HISTORY
     TOPOLOGY.clear()            # process-wide books of state rows
+    HISTORY.clear()
     clean_books_before = _clean_books()
     fe = await _start(None, config, case)
     try:
@@ -224,6 +237,8 @@ async def _drive(config: dict, case: str) -> dict:
             "rewrites": await fe.execute(
                 "SELECT job, rule, fired, detail FROM rw_plan_rewrites"),
             "watermarks": await fe.execute("SELECT * FROM rw_watermarks"),
+            "history": _history(
+                await fe.execute("SELECT * FROM rw_metrics_history")),
             "table_ids": {name: t.table_id for name, t in tables.items()},
         }
     finally:
@@ -319,6 +334,35 @@ def test_the_state_is_level_and_nothing_traces_once_it_is(q5wm, case):
         assert now["rows"][name] <= 1.15 * then["rows"][name] + 2, name
     assert cps[-1]["traces"] == then["traces"], \
         dict(cps[-1]["traces"] - then["traces"])
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_the_join_s_expiry_is_counted_by_the_side_in_the_history(
+        q5wm, case):
+    """`join_expire.t<side's table>.rows` (counter
+    `stream_join_expired_rows{table}`) is in the history's row of every
+    barrier from the first on which the join's watermark moved, and
+    over the run it is the rows that left the side's state table: what
+    the side was given, less what its input took back, less what the
+    store still holds."""
+    run_ = q5wm[case]
+    history = run_["history"]
+    join = f"t{run_['table_ids']['join.left']}"
+    for side in ("left", "right"):
+        name = f"join_expire.t{run_['table_ids']['join.' + side]}.rows"
+        rows = [h.get(name) for h in history]
+        first = next(i for i, v in enumerate(rows) if v is not None)
+        assert first < LEVEL_AT and None not in rows[first:], (side, rows)
+        # a window closes on every second barrier
+        assert sum(1 for v in rows[first:] if v) >= (BARRIERS - first) // 3
+        given = {op: sum(h.get(f"join_input_rows.{join}.{side}.{op}", 0)
+                         for h in history)
+                 for op in ("insert", "update_insert", "delete",
+                            "update_delete")}
+        left_the_table = given["insert"] + given["update_insert"] \
+            - given["delete"] - given["update_delete"] \
+            - run_["stored"]["join." + side]
+        assert sum(rows[first:]) == left_the_table > 0, (side, given)
 
 
 def test_rw_watermarks_names_every_cleaned_table(q5wm):

@@ -89,6 +89,95 @@ def test_mem_table_merge_rules():
     assert not mt.is_dirty()
 
 
+def _buffer_with(ops, sanity_check=True):
+    mt = MemTable(sanity_check=sanity_check)
+    for op, *args in ops:
+        getattr(mt, op)(*args)
+    return mt
+
+
+# what the buffer holds before the batch, and the keys the batch deletes
+_TOMBSTONE_BATCHES = {
+    "fresh keys": ([], [b"a", b"b", b"c"]),
+    "fresh keys beside other keys' ops":
+        ([("insert", b"x", (1,)), ("delete", b"y", (2,))],
+         [b"b", b"a"]),
+    "a buffered insert annihilates":
+        ([("insert", b"a", (1,)), ("insert", b"b", (2,))],
+         [b"c", b"a", b"d"]),
+    "a delete over an update keeps the first old row":
+        ([("update", b"a", (1,), (2,)), ("insert", b"z", (0,))],
+         [b"a", b"b"]),
+    "a delete over a delete-then-insert":
+        ([("delete", b"a", (1,)), ("insert", b"a", (3,))],
+         [b"b", b"a"]),
+    "every key buffered":
+        ([("insert", b"a", (1,)), ("update", b"b", (1,), (2,))],
+         [b"b", b"a"]),
+    "no key": ([("insert", b"a", (1,))], []),
+}
+
+
+@pytest.mark.parametrize("case", list(_TOMBSTONE_BATCHES))
+def test_mem_table_batch_tombstone_is_a_delete_a_key(case):
+    """`delete_batch` leaves the buffer as `delete(key, None)` a key, in
+    order, leaves it: the same ops under the same keys in the same
+    places, so the same flush."""
+    before, keys = _TOMBSTONE_BATCHES[case]
+    one, many = _buffer_with(before), _buffer_with(before)
+    for key in keys:
+        one.delete(key, None)
+    many.delete_batch(keys)
+    assert list(many.items()) == list(one.items())
+    assert many.drain_bulk() == one.drain_bulk()
+
+
+@pytest.mark.parametrize("keys, before", [
+    ([b"a", b"b"], [("delete", b"b", (1,))]),       # buffered tombstone
+    ([b"a", b"b", b"a"], []),                       # twice in the batch
+], ids=["over a buffered delete", "twice in one batch"])
+def test_mem_table_batch_tombstone_raises_on_a_double_delete(keys, before):
+    mt = _buffer_with(before)
+    with pytest.raises(MemTableError):
+        mt.delete_batch(keys)
+    # without the sanity check the second delete is dropped, as
+    # `delete` drops it
+    lax, one = _buffer_with(before, False), _buffer_with(before, False)
+    lax.delete_batch(keys)
+    for key in keys:
+        one.delete(key, None)
+    assert list(lax.items()) == list(one.items())
+
+
+def test_state_table_delete_keys_is_delete_rows_less_the_old_rows():
+    """`delete_keys` takes the pk columns and builds no row: the flush
+    is `delete_rows`' of the same rows, key for key."""
+    rows = [(k, None if k == 4 else "s%d" % k, k * 2) for k in range(12)]
+    schema = Schema.of(k=DataType.INT64, s=DataType.VARCHAR,
+                       v=DataType.INT64)
+    flushes = []
+    for by_key in (False, True):
+        t = StateTable(table_id=7, schema=schema, pk_indices=[0, 1],
+                       store=MemoryStateStore(), dist_key_indices=[0])
+        t.init_epoch(EpochPair.new_initial(Epoch.from_physical(1)))
+        t.insert_rows(rows[:8])
+        _advance(t)
+        t.insert_rows(rows[8:])             # buffered: these annihilate
+        doomed = rows[2:6] + rows[9:11]
+        if by_key:
+            t.delete_keys(
+                [(np.asarray([r[0] for r in doomed], dtype=np.int64),
+                  None),
+                 (np.asarray([r[1] for r in doomed], dtype=object),
+                  np.asarray([r[1] is not None for r in doomed]))],
+                len(doomed))
+        else:
+            t.delete_rows(doomed)
+        flushes.append(t.flush()[:2])
+    assert flushes[0] == flushes[1]
+    assert flushes[0][1].count(None) == 4 and len(flushes[0][0]) == 6
+
+
 # -- state store MVCC --------------------------------------------------------
 
 def test_memory_state_store_mvcc():
