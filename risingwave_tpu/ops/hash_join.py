@@ -60,6 +60,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from risingwave_tpu.common.chunk import next_pow2
 from risingwave_tpu.ops import hash_table as ht
 from risingwave_tpu.utils import jaxtools
 from risingwave_tpu.utils.ledger import LEDGER
@@ -620,6 +621,9 @@ class PendingEpochProbe:
         self.top = None if with_degrees else top
         # (rows of the longest chain, walk steps, candidates, pairs)
         self.note_probe = note_probe
+        # times the buffer grew and the program ran again (a probe
+        # that pages runs the same program, and counts none)
+        self.redispatches = 0
         self._degs = None             # latest (deg_self, deg_sink)
 
     def set_degs(self, deg_self, deg_sink) -> None:
@@ -648,6 +652,7 @@ class PendingEpochProbe:
                     else max(self.cap * 2, next_pow2(candidates))
                 if self.bump is not None:
                     self.bump(self.cap)
+                self.redispatches += 1
                 self.mat = self.redispatch(self.cap)
                 jaxtools.start_fetch(self.mat)
         if self.with_degrees and self._degs is not None:
@@ -704,8 +709,7 @@ class JoinSideKernel:
         # the probe's emit walk so matched rows materialize from the
         # one packed fetch instead of a host arena gather per column
         self.payload_width = payload_width
-        self.table = ht.DeviceHashTable(key_width, key_capacity)
-        self.table.on_grow(self._on_table_grow)
+        self._new_table(key_capacity)
         # buffer rows of the fused probe (candidates and pairs); grows
         # on overflow (kept generous: each size is a fresh XLA compile)
         self._probe_cap = probe_capacity
@@ -747,6 +751,20 @@ class JoinSideKernel:
         return int(self.pay.size + self.deg.size) * 4
 
     # -- growth ----------------------------------------------------------
+    def _new_table(self, capacity: int) -> None:
+        self.table = ht.DeviceHashTable(
+            self.key_width, capacity, grow_floor=self._key_floor)
+        self.table.on_grow(self._on_table_grow)
+
+    def _key_floor(self) -> int:
+        """Where the key table goes when it has to grow: to what the
+        row arrays, full of distinct keys, would need. A side whose
+        keys never fill its first table never grows it and pays
+        nothing; a side with a row a key would else cross a rung
+        of its own (a rehash, a retrace and a compile of the apply and
+        probe programs) between every two of the row arrays'."""
+        return next_pow2(int(self.row_capacity / ht.MAX_LOAD) + 1)
+
     def _on_table_grow(self, old_to_new: jnp.ndarray,
                        old_capacity: int) -> None:
         self.chains = self.chains._replace(
@@ -987,8 +1005,7 @@ class JoinSideKernel:
                       1 << int(np.ceil(np.log2(max(n / ht.MAX_LOAD, 1)))))
         row_cap = max(self.row_capacity,
                       1 << int(np.ceil(np.log2(max(n + 1, 2)))))
-        self.table = ht.DeviceHashTable(self.key_width, key_cap)
-        self.table.on_grow(self._on_table_grow)
+        self._new_table(key_cap)
         self.chains = empty_chains(self.table.capacity, row_cap)
         self.pay = jnp.zeros((row_cap, self.payload_width),
                              dtype=jnp.int32)

@@ -294,9 +294,13 @@ class DeviceHashTable:
     the bound to the true count.
     """
 
-    def __init__(self, key_width: int, capacity: int = MIN_CAPACITY):
+    def __init__(self, key_width: int, capacity: int = MIN_CAPACITY,
+                 grow_floor=None):
         self.state = make_state(max(capacity, MIN_CAPACITY), key_width)
         self._counters = jaxtools.PendingCounters()
+        # `grow_floor()`: the least capacity (a power of two) a rehash
+        # may grow to; None: a rehash doubles
+        self._grow_floor = grow_floor
 
     @property
     def capacity(self) -> int:
@@ -337,7 +341,10 @@ class DeviceHashTable:
 
     def _grow(self) -> None:
         old = self.state
-        new = make_state(old.capacity * 2, old.key_width)
+        cap = old.capacity * 2
+        if self._grow_floor is not None:
+            cap = max(cap, self._grow_floor())
+        new = make_state(cap, old.key_width)
         # Rehash: one batched probe_insert of every occupied slot.
         occ = old.occ
         new, slots, ins = _probe_insert_jit(new, old.keys, occ)

@@ -161,6 +161,8 @@ class ShardedPendingEpochProbe:
         self.with_degrees = with_degrees
         self.redispatch = redispatch
         self.overflow = overflow
+        # times the per-shard buffer grew and the program ran again
+        self.redispatches = 0
 
     def collect(self):
         """(degrees | None, probe_idx, refs, None, None) over the
@@ -181,6 +183,7 @@ class ShardedPendingEpochProbe:
                 while k.probe_capacity < worst:
                     k.probe_capacity *= 2
                 self.out_cap = k.probe_capacity
+                self.redispatches += 1
                 self.mats, self.overflow = self.redispatch(self.out_cap)
         at = HEADER_ROWS
         m = mats.shape[1] - at - self.out_cap

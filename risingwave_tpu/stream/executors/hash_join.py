@@ -1127,7 +1127,14 @@ class HashJoinExecutor(Executor):
             [cols[j] if j in cols else column(j)
              for j in range(len(self.schema))], vis, ops)
 
-    @staged("join.pairs")
+    def _note_outer(self, event: str, rows: int) -> None:
+        """File `rows` of the outer half's books under this join
+        (`join_outer.t<join>.<event>`)."""
+        if rows:
+            _METRICS.join_outer_rows.inc(
+                float(rows), table=self._books_table, event=event)
+
+    @staged("join.pad")
     def _padded_from_chunk(self, side_idx: int, chunk: StreamChunk,
                            idx: np.ndarray) -> StreamChunk:
         """(row, NULLs) for unmatched rows of an outer incoming side."""
@@ -1135,12 +1142,15 @@ class HashJoinExecutor(Executor):
         cap = next_pow2(t)
         me = self.sides[side_idx]
         other = self.sides[1 - side_idx]
+        ops = self._ops_of(chunk, idx)
+        inserts = int(np.count_nonzero(ops == int(Op.INSERT)))
+        self._note_outer("padded_insert", inserts)
+        self._note_outer("padded_delete", t - inserts)
         return self._compose(
             side_idx, self._chunk_cols(me.schema, chunk, idx, cap),
-            self._null_cols(other.schema, cap),
-            self._ops_of(chunk, idx), t, cap)
+            self._null_cols(other.schema, cap), ops, t, cap)
 
-    @staged("join.pairs")
+    @staged("join.pad")
     def _padded_from_arena(self, side_idx: int, refs: np.ndarray,
                            op: Op) -> StreamChunk:
         """(stored row, NULLs) for degree transitions of an outer side."""
@@ -1149,6 +1159,8 @@ class HashJoinExecutor(Executor):
         me = self.sides[side_idx]
         other = self.sides[1 - side_idx]
         ops = np.full(cap, int(op), dtype=np.int8)
+        self._note_outer("padded_insert" if op == Op.INSERT
+                         else "padded_delete", t)
         return self._compose(
             side_idx, me.arena.gather(refs, cap),
             self._null_cols(other.schema, cap), ops, t, cap)
@@ -1364,9 +1376,15 @@ class HashJoinExecutor(Executor):
         # arrays. One sync point per epoch, tracked joins only.
         out: Dict[int, tuple] = {}
         for s, (ld, ad, _t, _m, bkt) in devs.items():
-            out[s] = self.sides[1 - s].kernel.probe_epoch(
+            probed = self.sides[1 - s]
+            pending = probed.kernel.probe_epoch(
                 ld, ad, True, sink=self.sides[s].kernel,
-                bucket=bkt, **_prelude_kw(s)).collect()
+                bucket=bkt, **_prelude_kw(s))
+            out[s] = pending.collect()
+            if pending.redispatches:
+                _METRICS.join_degree_redispatches.inc(
+                    float(pending.redispatches),
+                    kernel=f"join.t{probed.table.table_id}")
         return out
 
     def _tier_register(self) -> None:
@@ -1673,6 +1691,8 @@ class HashJoinExecutor(Executor):
             other.degrees[uref] = new
         flip_on = uref[(old_v == 0) & (new > 0)]
         flip_off = uref[(old_v > 0) & (new == 0)]
+        self._note_outer("flip_on", len(flip_on))
+        self._note_outer("flip_off", len(flip_off))
         if jt.subject is not None:       # semi/anti subject = other
             on_op = Op.DELETE if jt.is_anti else Op.INSERT
             off_op = Op.INSERT if jt.is_anti else Op.DELETE

@@ -398,6 +398,21 @@ class StreamingMetrics:
             "stream_join_output_rows",
             "pairs a HashJoinExecutor matched on its keys, by join, "
             "before its condition (and its other rows out)")
+        self.join_outer_rows = r.counter(
+            "stream_join_outer_rows",
+            "what the outer half of a degree-tracked join did, by join "
+            "and event: padded_insert and padded_delete (NULL-padded "
+            "rows it emitted and retracted: unmatched incoming rows of "
+            "an outer side, and stored rows that flipped), flip_on and "
+            "flip_off (stored rows of a tracked side whose match degree "
+            "rose from zero and fell to it). An inner join files none")
+        self.join_degree_redispatches = r.counter(
+            "stream_join_degree_probe_redispatches",
+            "times a degree-tracking epoch probe outgrew its buffer, "
+            "doubled it and ran its program again, by kernel (join.t<"
+            "state table id> of the probed side): each is a program of "
+            "a size the data chose. Filed only by a join that tracks "
+            "degrees")
         self.join_condition_rows = r.counter(
             "stream_join_condition_rows",
             "matched pairs an inner join's own condition (the "
@@ -912,7 +927,9 @@ class MetricsHistory:
         """(series name, value now, kind) of what the executors count
         by the batch: rows into an aggregate by op, the join ->
         aggregate hand-off, rows into and out of a join and through
-        its condition, rows through a HOP, the pairs a DISTINCT column's
+        its condition, the padded rows and degree flips of a join's
+        outer half and the times its tracked probe ran again, rows
+        through a HOP, the pairs a DISTINCT column's
         dedup state holds, changed and made visible and the seconds of
         its write-through, the rows into and out of a top-N with its
         table's writes and what it keeps, the rows to_char was given and the strftime
@@ -942,6 +959,10 @@ class MetricsHistory:
                  S.join_condition_rows, "counter"),
                 ("join_condition.{table}.seconds",
                  S.join_condition_seconds, "counter"),
+                ("join_outer.{table}.{event}", S.join_outer_rows,
+                 "counter"),
+                ("join_degree_probe.{kernel}.redispatches",
+                 S.join_degree_redispatches, "counter"),
                 ("hop_rows.{table}.{dir}", S.hop_rows, "counter"),
                 ("agg_distinct.{table}.pairs", S.agg_distinct_pairs,
                  "gauge"),

@@ -381,6 +381,12 @@ def test_tpch_q6_forecast_revenue():
 # too since PR 45. Upstream's q19 also selects its rank
 # (`SELECT * ... WHERE rank_number <= 10`): that form stays on the
 # general over-window executor, a top-N does not number its rows.
+# q101 is no gap either: upstream's text (`LEFT OUTER JOIN` of auction
+# to a derived `MAX(price) ... GROUP BY auction`, aliases without AS)
+# runs from the benchmark's configuration file in
+# tests/test_nexmark_q101.py, against benchmark/reference/
+# nexmark_q101.py, NULL rows included; test_nexmark_q101_auction_max_bid
+# above keeps the INNER form.
 #   q6          per-seller average of the last 10 closing prices:
 #               q9's winners feeding AVG(...) OVER (PARTITION BY seller
 #               ORDER BY date_time ROWS BETWEEN 10 PRECEDING AND CURRENT

@@ -254,6 +254,37 @@ def _case_a_row_growth_between_batches():
     return k, m, probes, [9] * len(probes), dict(steps=3)
 
 
+@pytest.mark.parametrize("keys_of, grows", [
+    (lambda refs: refs, True),            # a row a key (q101's sides)
+    (lambda refs: refs % 50, False),      # few keys, many rows (q4's)
+])
+def test_a_key_table_that_has_to_grow_goes_to_what_the_row_arrays_need(
+        keys_of, grows):
+    """No rung of the key table's own between two of the row arrays':
+    one growth takes it to where rows, all of distinct keys, fit; a
+    side whose keys are few never grows it."""
+    k, m = _side(key_capacity=1 << 10, row_capacity=1 << 12), ChainModel()
+    caps = []
+    for lo in range(0, 4000, 500):
+        refs = np.arange(lo, lo + 500)
+        _link(k, m, keys_of(refs).tolist(), refs.tolist(), [1] * 500)
+        caps.append(k.table.capacity)
+    assert k.row_capacity == 1 << 12
+    if grows:
+        assert sorted(set(caps)) == [1 << 10, 1 << 13]
+        assert 4000 <= hj.ht.MAX_LOAD * caps[-1]
+    else:
+        assert set(caps) == {1 << 10}
+    probes = keys_of(np.arange(0, 4000, 37)).tolist()
+    want = m.probe(probes, [9] * len(probes))
+    _deg, pidx, prefs = k.probe(
+        jnp.asarray(np.asarray(probes, np.int32)[:, None]),
+        jnp.ones(len(probes), dtype=bool), seq=9)
+    assert Counter(zip(np.asarray(pidx).tolist(),
+                       np.asarray(prefs).tolist())) == \
+        Counter(zip(want[0], want[1]))
+
+
 def _case_rebuild_then_probe():
     """A rebuild links its pages last page first: a key's rows stand
     in the order given, a run a key a page."""

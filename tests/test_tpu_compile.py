@@ -403,6 +403,55 @@ def test_q5_chain_above_the_join_at_its_top_rung(served_q5, one_chip):
     _compile(step, *jax.tree.map(at_top, step._args))
 
 
+# -- q101's degree-tracked probe at the cell's sizes (PR 50) ----------------------
+
+
+@pytest.fixture(scope="module")
+def served_q101():
+    """The benchmark's `nexmark-q101` view, its text from the
+    configuration file, three barriers deep: a LEFT OUTER join, so
+    both epoch probes track degrees."""
+    import json
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "nexmark-q101.json")) as f:
+        ddl = json.load(f)["ddl"]
+    yield from _serve_on_cpu([d.format(seed=50) for d in ddl], steps=3)
+
+
+@pytest.mark.parametrize("probing", [0, 1], ids=["auctions", "max_bids"])
+def test_q101_degree_tracked_probe(served_q101, one_chip, probing):
+    """hash_join.epoch_probe with degrees: one side's epoch probing the
+    other at the 2^19 key slots and 2^18 rows `q101_steady`'s window
+    runs on, both sides' degree arrays updated in the dispatch and
+    returned beside the pair matrix. No older cell's join tracks
+    degrees: this arm compiled for no test before."""
+    sides = _join_sides(served_q101, "nexmark_q101")
+    me, probed = sides[probing], sides[1 - probing]
+    assert sides[0].track_degrees and not sides[1].track_degrees
+    assert me.fused_input is not None, "q101's join side did not fuse"
+    _apply, probe_jit = probed.kernel._epoch_jits(
+        me.prelude, me._prelude_cache_key)
+    kernel = probed.kernel
+    i32 = jnp.int32
+    keys, rows = 1 << 19, 1 << 18
+    table = _on(one_chip, jax.eval_shape(
+        lambda: hash_table.make_state(keys, kernel.key_width)))
+    chains = _chains(one_chip, keys, rows)
+    pay = _sds(one_chip, (rows, kernel.payload_width), i32)
+    deg = _sds(one_chip, (rows,), i32)
+    assert probe_jit._args[9] is True            # it ran with degrees
+    raw_w = probe_jit._args[5].shape[1]
+    compiled = _compile(
+        probe_jit, table, chains, pay, deg, deg,
+        _sds(one_chip, (EPOCH_ROWS, raw_w), jnp.int64),
+        _sds(one_chip, (EPOCH_ROWS, 4), i32),
+        kernel.key_width, PROBE_OUT, True,
+        _sds(one_chip, (), i32))
+    # the matrix and the two degree arrays
+    assert len(jax.tree.leaves(compiled.out_info)) == 3
+
+
 # -- four chips: the sharded steps on a Mesh of the described devices -----------
 
 
