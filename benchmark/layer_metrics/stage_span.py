@@ -3,9 +3,10 @@ the span they divide by and the sum they divide.
 
 The span is `join_to_agg_share`'s: the window's epochs but the closing
 one, from the first one's start (`ts - interval_s`) to the last one's
-seal (`ts`), by `rw_metrics_history`'s own stamps, so that the seconds
-a traced run waits for the profiler after its last heartbeat barrier
-are in neither the numerator nor the denominator. Every row of the
+seal (`ts`), by `rw_metrics_history`'s own stamps: the closing epoch
+is the harness's own `FLUSH`, sealed after its watch has paused the
+heartbeat (until PR 51 a traced run also waited for the profiler
+there, up to 12 s; it no longer does). Every row of the
 span counts, also one that lacks the name: an epoch in which a stage
 did not run has no seconds of it. The names read are
 `exec_phase.<Kind>.<phase>`, `stage.<phase>.<stage>` and

@@ -2,9 +2,11 @@
 rows it deleted: history `state_clean.t<table>.reads` over
 `state_clean.t<table>.cleaned` (counters `stream_state_clean_reads`,
 `stream_state_cleaned_rows{table}`, `StateTable.delete_below_prefix`),
-window sums over every table. 1.0 while the delete is a scan that reads
-each row and deletes it by its key; 0 once it is a range tombstone.
-Nothing to read where no watermark cleaned a row."""
+window sums over every table. 0.0 since PR 38: the table's clean index
+names the keys under the watermark and only the scan that seeds it, at
+a table's first clean, reads (1.0 while the delete was a scan that read
+each row and deleted it by its key). Nothing to read where no watermark
+cleaned a row."""
 
 
 def read(record):

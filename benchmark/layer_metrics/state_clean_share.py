@@ -5,8 +5,8 @@ memory-side cuts; the range deletes nested in it are `state.clean`),
 `stage.host_emit.join.expire` (`HashJoinExecutor._expire_to`: the scan
 of the live refs, the dead pks, the tombstones' launch; the batch
 delete nested in it is `state.write`) and `stage.host_emit.state.clean`
-(`StateTable.delete_below_prefix`: the range scan per owned vnode and
-a delete a row), each exclusive of what nests inside it. A program
+(`StateTable.delete_below_prefix`: since PR 38 the clean index's buckets
+under the watermark and a tombstone a row), each exclusive of what nests inside it. A program
 without a watermark, or from before the names, reads nothing."""
 
 from stage_span import share

@@ -21,7 +21,9 @@ speaks pgwire to it over TCP. A run has three phases:
           have added the cell file's `window_rows` (a fixed amount of
           work sized to take about `--seconds`, scaled with it), FLUSH
           (the closing checkpoint). Rows, barriers and time are counted
-          between the two checkpoints.
+          between the two checkpoints. A `--trace 1` run profiles exactly
+          that: `Profile` starts before the heartbeat resumes and stops
+          after the closing FLUSH has returned.
   check   the view is read back over pgwire and compared, as a multiset,
           with the configuration's plain reference over exactly the
           prefixes the closing checkpoint covers; the rows of the view's
@@ -57,6 +59,9 @@ import tempfile                     # noqa: E402
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 CONTROLS = ("rare_checkpoint", "short_reference")
+# what `correct` compares, each a count with the limit 0 (PERF.md section 2)
+COMPARED = ("differing", "state_rows_off", "not_durable", "behind_manifest",
+            "fallbacks")
 KEEP_ENV = "BENCH_KEEP_DIR"  # by hand: leave the barrier rows and the trace
 
 
@@ -255,9 +260,11 @@ class Heartbeat:
         self.task = asyncio.ensure_future(self.fe.run_heartbeat())
 
     def check(self) -> None:
-        if self.task.done() and not self.task.cancelled():
-            self.task.result()
-            raise RuntimeError("the barrier heartbeat stopped")
+        """Called while the heartbeat runs only: a task that is done has
+        stopped, whatever ended it."""
+        if self.task.done():
+            raise RuntimeError("the barrier heartbeat stopped") from (
+                None if self.task.cancelled() else self.task.exception())
 
 
 async def run_to(heartbeat: Heartbeat, readers, targets: dict,
@@ -332,35 +339,52 @@ def manifest_epoch(data_dir: str):
 # -- the traced span ----------------------------------------------------------
 
 
-async def trace_span(trace_dir: str, start_after_s: float, seconds: float,
-                     heartbeat: Heartbeat) -> dict:
-    """Profile `seconds` of the window with jax.profiler. A host
-    annotation made at a known time of day ties the trace's clock to the
-    clock the program stamps its barriers with."""
-    import jax
-    await asyncio.sleep(start_after_s)
-    options = jax.profiler.ProfileOptions()
-    options.python_tracer_level = 0
-    options.host_tracer_level = 1
-    jax.profiler.start_trace(trace_dir, profiler_options=options)
-    from trace_reduce import MARK
-    mark_wall = time.time()
-    with jax.profiler.TraceAnnotation(MARK):
-        time.sleep(0.001)
-    t_end = time.monotonic() + seconds
-    while time.monotonic() < t_end:
-        heartbeat.check()
-        await asyncio.sleep(min(0.25, max(0.0, t_end - time.monotonic())))
-    end_wall = time.time()
-    jax.profiler.stop_trace()
-    return {"mark_wall": mark_wall, "end_wall": end_wall}
+class Profile:
+    """jax.profiler over the window, whatever its length: started at
+    window open while the heartbeat is still paused, so that the
+    profiler's own start-up is in no barrier, and stopped once the
+    closing FLUSH has returned, so that writing the trace is in no
+    share's denominator. A host annotation made at a known time of day
+    ties the trace's clock to the clock the program stamps its barriers
+    with. `limit_s` (the traffic file's `trace.seconds`) is an upper
+    limit on the profile's length, kept for the size of the trace: a
+    window that outlasts it is profiled up to the limit, and the log
+    says so."""
+
+    def __init__(self, trace_dir: str, limit_s: float):
+        import jax
+        from trace_reduce import MARK
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 1
+        jax.profiler.start_trace(trace_dir, profiler_options=options)
+        self.mark_wall = time.time()
+        with jax.profiler.TraceAnnotation(MARK):
+            time.sleep(0.001)
+        self.end_wall = None
+        self._limit = asyncio.get_running_loop().call_later(
+            limit_s, self.stop, f"profile: stopped at the traffic file's "
+            f"limit of {limit_s:g} s, before the window closed")
+
+    def stop(self, why: str = "") -> None:
+        """Stamps the span's end, then stops the profiler. Idempotent."""
+        import jax
+        if self.end_wall is not None:
+            return
+        self.end_wall = time.time()
+        self._limit.cancel()
+        if why:
+            say(why)
+        jax.profiler.stop_trace()
 
 
 def reduce_span(trace_dir: str, span: dict, history: dict,
                 uploads: dict) -> dict:
-    """The reduced trace the per-layer readers get (`trace_reduce`), with
-    the epochs sealed inside the span and the idle gaps named by the
-    ledger phase that held most of the epoch each fell in."""
+    """The reduced trace the per-layer readers get (`trace_reduce`) over
+    the span (the mark at window open, the return of the closing FLUSH),
+    with the epochs sealed inside it, which are the window's barriers,
+    and the idle gaps named by the ledger phase that held most of the
+    epoch each fell in."""
     import trace_reduce
     xplane = trace_reduce.newest_xplane(trace_dir)
     keep = os.environ.get(KEEP_ENV)
@@ -385,6 +409,15 @@ def reduce_span(trace_dir: str, span: dict, history: dict,
     if not loaded["devices"]:
         say("trace: no device plane (a rehearsal on the CPU has none)")
         return {}
+    return reduce_loaded(loaded, span, history, uploads)
+
+
+def reduce_loaded(loaded: dict, span: dict, history: dict,
+                  uploads: dict) -> dict:
+    """`reduce_span` on a trace as `trace_reduce.load_xplane` gives it,
+    so that `selfcheck/test_trace_reduce.py` can hold it against a
+    synthetic one."""
+    import trace_reduce
     length_ns = (span["end_wall"] - span["mark_wall"]) * 1e9
     mark_ns = loaded["mark_ns"]
     if mark_ns is None:
@@ -406,16 +439,15 @@ def reduce_span(trace_dir: str, span: dict, history: dict,
     inside = [(ns, e, h) for ns, e, h in epochs if lo <= ns <= hi] \
         if mark_ns is not None else []
     reduced["epochs_in_span"] = len(inside)
-    if len(inside) >= 2:
-        # whole epochs only: the device's busy time between the first and
-        # the last seal inside the span, over the rows of the epochs
-        # between
-        cut = trace_reduce.reduce_trace(loaded, (inside[0][0],
-                                                 inside[-1][0]))
+    if inside:
+        # The span opens with the window, so every epoch sealed inside it
+        # is whole: the device's busy time from the span's start to the
+        # last seal, over the source rows of all of them.
+        cut = trace_reduce.reduce_trace(loaded, (lo, inside[-1][0]))
         reduced["whole_epochs"] = {
             "busy_s": cut["busy_s"],
             "source_rows": sum(h.get("source_rows", 0.0)
-                               for _ns, _e, h in inside[1:])}
+                               for _ns, _e, h in inside)}
     # A gap is named by what the host was doing, as far as the program
     # says: the checkpoint's upload and commit where the gap's middle
     # falls between a barrier's seal and seal + upload_s; else the ledger
@@ -502,38 +534,37 @@ async def _drive(args, config, traffic, sizes, counts, fe, pg, heartbeat,
     open_epoch = (await barrier_rows(pg))[-1]["epoch"]
     open_counts = counts.snapshot()
     open_traces = counts.traces_by_kernel()
+    # a traced run profiles the window and nothing else: the profiler
+    # starts while the heartbeat is still paused, and `t_open` is stamped
+    # once it has
+    profile = Profile(trace_dir, traffic["trace"]["seconds"]) \
+        if args.trace else None
     t_open = time.monotonic()
     setup_s = t_open - T0
-    heartbeat.resume()
-    say(f"window open: set-up took {setup_s:.1f} s; checkpoint covers "
-        f"{open_rows}; so far {open_counts}")
+    try:
+        heartbeat.resume()
+        say(f"window open: set-up took {setup_s:.1f} s; checkpoint covers "
+            f"{open_rows}; so far {open_counts}")
 
-    # The window is a fixed amount of work: the rows every reader has to
-    # add, sized in the cell's file to take about `seconds`, and scaled
-    # with --seconds. Every run of the cell closes on the same barrier
-    # of the stream.
-    work = sizes["window_rows"]
-    add = int(work["rows_per_reader"] * args.seconds / work["seconds"])
-    close_targets = {r["table"]: r["rows"] + add for r in open_rows}
-    say(f"the window closes on the first checkpoint that covers {add} "
-        f"more rows of every reader ({close_targets})")
-    tracer = None
-    if args.trace:
-        # beside the watch on the readers' rows, not before it: a watch
-        # that starts late closes the window a barrier late
-        tr = traffic["trace"]
-        length = min(tr["seconds"], max(0.5, args.seconds
-                                        - tr["start_after_s"] - 0.5))
-        tracer = asyncio.ensure_future(trace_span(
-            trace_dir, tr["start_after_s"], length, heartbeat))
-    await run_to(heartbeat, readers, close_targets,
-                 t_open + args.seconds + traffic.get(
-                     "window_deadline_s", 300), "window", counts)
-    span = await tracer if tracer else None
+        # The window is a fixed amount of work: the rows every reader has
+        # to add, sized in the cell's file to take about `seconds`, and
+        # scaled with --seconds. Every run of the cell closes on the same
+        # barrier of the stream.
+        work = sizes["window_rows"]
+        add = int(work["rows_per_reader"] * args.seconds / work["seconds"])
+        close_targets = {r["table"]: r["rows"] + add for r in open_rows}
+        say(f"the window closes on the first checkpoint that covers {add} "
+            f"more rows of every reader ({close_targets})")
+        await run_to(heartbeat, readers, close_targets,
+                     t_open + args.seconds + traffic.get(
+                         "window_deadline_s", 300), "window", counts)
 
-    # -- window close: the closing checkpoint
-    await pg.query("FLUSH")
-    t_close = time.monotonic()
+        # -- window close: the closing checkpoint
+        await pg.query("FLUSH")
+        t_close = time.monotonic()
+    finally:
+        if profile:
+            profile.stop()
     close_rows = checkpointed_rows(readers)
     close_counts = counts.snapshot()
     in_window = [c for c in counts.compiles if t_open <= c[0] <= t_close]
@@ -544,6 +575,17 @@ async def _drive(args, config, traffic, sizes, counts, fe, pg, heartbeat,
     barriers = [b for b in await barrier_rows(pg)
                 if b["epoch"] > open_epoch]
     history = await history_by_epoch(pg)
+    span = None
+    if profile:
+        span = {"mark_wall": profile.mark_wall,
+                "end_wall": profile.end_wall,
+                "epochs_in_span": sum(
+                    profile.mark_wall <= h["ts"] <= profile.end_wall
+                    for h in history.values())}
+        say(f"profile: {span['end_wall'] - span['mark_wall']:.3f} s from "
+            f"the mark at window open to the closing FLUSH's return; "
+            f"{span['epochs_in_span']} epochs sealed inside it, the window "
+            f"has {len(barriers)} barriers")
     rewrites = await pg.query("SELECT job, rule, fired, detail "
                               "FROM rw_plan_rewrites")
     got = collections.Counter(await pg.query(f"SELECT * FROM {view}"))
@@ -637,6 +679,7 @@ async def _drive(args, config, traffic, sizes, counts, fe, pg, heartbeat,
         "compiles_in_window": len(in_window),
         "compile_times": [(t - t_open, secs) for t, secs in
                           counts.compiles],
+        "span": span,
         "trace": reduce_span(
             trace_dir, span, history,
             {b["epoch"]: b["upload_s"] for b in barriers})
@@ -727,11 +770,9 @@ def main(argv=None) -> int:
                 f"{int(time.time())}.json"), "w") as f:
             json.dump({k: record[k] for k in (
                 "setup_s", "window", "barriers", "history", "counters",
-                "compile_times", "check")}, f, indent=1)
+                "compile_times", "check", "span")}, f, indent=1)
     check = record["check"]
-    correct = not any(check[k] for k in (
-        "differing", "state_rows_off", "not_durable", "behind_manifest",
-        "fallbacks"))
+    correct = not any(check[k] for k in COMPARED)
     if args.trace:
         metrics = {}
         for m in metrics_of(bench, "per_layer", cell["name"]):
@@ -761,6 +802,13 @@ def main(argv=None) -> int:
         f"persistent-cache hits {after['cache_hits']}, misses "
         f"{after['cache_misses']}, kernel traces "
         f"{after['kernel_traces']}; check {check['seconds']:.1f} s")
+    # every number compared beside its limit: last on standard error, and
+    # last in the result's line
+    result["compared"] = {k: {"value": check[k], "limit": 0}
+                          for k in COMPARED}
+    print("compared (value / limit): " + ", ".join(
+        f"{k} {check[k]} / 0" for k in COMPARED), file=sys.stderr,
+        flush=True)
     print(json.dumps(result), flush=True)
     return 0
 

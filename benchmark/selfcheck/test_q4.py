@@ -101,7 +101,8 @@ def test_sound_run_is_correct(capsys):
 def test_traced_run_prints_the_three_readers(capsys):
     result, _ = drive(capsys, "--trace", "1")
     assert result["correct"] is True
-    assert set(result["metrics"]) == {"join_to_agg_share",
+    # `>=`: later PRs list this cell on further readers
+    assert set(result["metrics"]) >= {"join_to_agg_share",
                                       "agg_retract_share",
                                       "probe_rounds_per_epoch"}
     assert result["metrics"]["agg_retract_share"]["value"] > 0

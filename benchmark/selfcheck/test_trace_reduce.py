@@ -60,6 +60,56 @@ def test_nested_ops_count_once():
         record) == pytest.approx(2.0)                 # 40 ms / 20,000 rows
 
 
+def span_and_history(seals_ms, end_ms=100.0):
+    """A span of `end_ms` whose mark was made at wall time 5000.0 (1000
+    ms on the synthetic trace's clock), the opening checkpoint's epoch
+    sealed before it, and an epoch of 10,000 rows sealed at each of
+    `seals_ms` after it."""
+    span = {"mark_wall": 5000.0, "end_wall": 5000.0 + end_ms / 1e3}
+    history = {1: {"ts": 4999.9, "interval_s": 0.05, "source_rows": 7.0}}
+    for i, ms in enumerate(seals_ms):
+        history[2 + i] = {"ts": 5000.0 + ms / 1e3, "interval_s": 0.04,
+                          "source_rows": 10000.0,
+                          "phase.device_compute": 0.03,
+                          "phase.host_emit": 0.01}
+    return span, history
+
+
+def test_span_starts_before_the_first_seal():
+    """The span opens with the window: the device's work before the
+    first seal inside it belongs to that seal's epoch, so `whole_epochs`
+    runs from the span's start to the last seal and counts the rows of
+    every epoch sealed inside: two epochs here, not one (PR 51). The
+    last seal at 80 ms cuts the op that starts at 95 ms out."""
+    import run
+    span, history = span_and_history([45.0, 80.0])
+    r = run.reduce_loaded(synthetic(), span, history, {3: 0.015})
+    assert r["epochs_in_span"] == 2
+    assert r["window_s"] == pytest.approx(0.100)
+    assert r["whole_epochs"]["busy_s"] == pytest.approx(0.040)
+    assert r["whole_epochs"]["source_rows"] == 20000.0
+    assert run.load_module("layer_metrics", "kernel_us_per_row").read(
+        {"trace": r}) == pytest.approx(2.0)
+    # the gaps are named and add up to the span's idle time: the tail
+    # after the last seal is the closing checkpoint's, not a paused
+    # program's
+    gaps = dict(r["idle_gaps"])
+    assert sum(gaps.values()) == pytest.approx(0.055)
+    assert gaps["checkpoint_upload_commit"] == pytest.approx(0.025)
+    assert gaps["device_compute"] == pytest.approx(0.030)
+    # a window of one barrier has one whole epoch
+    span, history = span_and_history([80.0])
+    r = run.reduce_loaded(synthetic(), span, history, {})
+    assert r["epochs_in_span"] == 1
+    assert r["whole_epochs"] == {"busy_s": pytest.approx(0.040),
+                                 "source_rows": 10000.0}
+    # no mark on the trace's clock: no epoch is placed, nothing to read
+    loaded = synthetic()
+    loaded["mark_ns"] = None
+    r = run.reduce_loaded(loaded, span, history, {})
+    assert r["epochs_in_span"] == 0 and "whole_epochs" not in r
+
+
 def test_two_devices_average():
     loaded = synthetic()
     loaded["devices"]["/device:TPU:1"] = {
