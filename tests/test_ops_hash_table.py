@@ -214,9 +214,9 @@ def _assert_same_as_single_loop(state, batch, valid):
     return got
 
 
-_FIRST = ht.LADDER_MIN_BATCH                 # smallest n with a rung
+_FIRST = ht.LADDER_FLOOR * ht.LADDER_RATIO   # smallest n with a rung
 _Q5 = 163_840       # q5's staged batch: four rungs under it
-_RUNG = _FIRST      # a batch with three rungs under it
+_RUNG = 65_536      # a batch with three rungs under it
 _CAP = 1 << (2 * _RUNG - 1).bit_length()    # a table that holds two
 
 
@@ -317,7 +317,10 @@ LADDER_CASES = {
     "n_163840_on_the_fourth_rung": _case_sizes(_Q5),
     "n_one_above_the_fourth_rung": _case_sizes(_Q5 + 1),
     "n_69889_rungs_rounded_up_to_tiles": _case_sizes(69_889),
-    "n_32768_keeps_the_single_loop": _case_sizes(32_768),
+    "n_32768_runs_three_rungs": _case_sizes(32_768),
+    "n_16384_a_cells_staged_batch": _case_sizes(16_384),
+    "n_4096_a_mesh_shards_batch": _case_sizes(4_096),
+    "n_65536_on_the_third_rung": _case_sizes(_RUNG),
     "load_0.1": _case_load(0.1),
     "load_0.5": _case_load(0.5),
     "load_0.69": _case_load(0.69),
@@ -330,11 +333,23 @@ LADDER_CASES = {
 }
 
 
+RUNGS_OF_CASE = {
+    "n_32768_runs_three_rungs": 3,
+    "n_16384_a_cells_staged_batch": 3,
+    "n_4096_a_mesh_shards_batch": 2,
+    "n_on_the_first_rung": 2,
+}
+
+
 @pytest.mark.parametrize("case", list(LADDER_CASES))
 def test_ladder_is_the_single_loop_bit_for_bit(case):
     rng = np.random.default_rng(sorted(LADDER_CASES).index(case))
     state, batch, valid = LADDER_CASES[case](rng)
     stat = np.asarray(_assert_same_as_single_loop(state, batch, valid)[2])
+    if case in RUNGS_OF_CASE:
+        # the cells' own sizes step down, and pay for it in fewer rows
+        assert len(ht._ladder(len(batch))) == RUNGS_OF_CASE[case]
+        assert stat[1] > 2 and stat[2] < stat[1] * len(batch)
     if case == "all_rows_invalid":
         assert stat.tolist() == [0, 0, 0, len(batch)]
     if case == "half_padding":
@@ -351,12 +366,15 @@ def test_ladder_is_the_single_loop_bit_for_bit(case):
 
 def test_ladder_sizes_follow_n_alone():
     assert ht._ladder(ht.LADDER_FLOOR - 1) == (ht.LADDER_FLOOR - 1,)
-    assert ht._ladder(_FIRST - 1) == (_FIRST - 1,)
-    assert ht._ladder(32_768) == (32_768,)
-    assert ht._ladder(_FIRST) == (65_536, 16_384, 4_096, 1_024)
+    assert ht._ladder(_FIRST - 1) == (2_559,)
+    assert ht._ladder(_FIRST) == (2_560, 640)
+    assert ht._ladder(4_096) == (4_096, 1_024)
+    assert ht._ladder(16_384) == (16_384, 4_096, 1_024)
+    assert ht._ladder(32_768) == (32_768, 8_192, 2_048)
+    assert ht._ladder(65_536) == (65_536, 16_384, 4_096, 1_024)
     assert ht._ladder(69_889) == (69_889, 17_536, 4_480, 1_152)
     assert ht._ladder(163_840) == (163_840, 40_960, 10_240, 2_560, 640)
-    for n in (163_840, 1 << 19, 69_889, _FIRST + 1):
+    for n in (163_840, 1 << 19, 69_889, _RUNG + 1, _FIRST + 1):
         rungs = ht._ladder(n)
         assert rungs[0] == n and rungs[-1] >= ht.LADDER_FLOOR
         assert all(r % 128 == 0 for r in rungs[1:])

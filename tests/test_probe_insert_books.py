@@ -12,6 +12,7 @@ from risingwave_tpu.ops import hash_table as ht
 from risingwave_tpu.stream import hotkeys
 from risingwave_tpu.utils.jaxtools import PendingCounters
 from risingwave_tpu.utils.metrics import STREAMING, MetricsHistory
+from test_ops_hash_table import _FIRST, _single_loop_jit  # the reference
 
 
 def _keys(rng, rows, kw):
@@ -30,8 +31,7 @@ def _table(cap, kw, load, rng):
     return state, resident
 
 
-@pytest.mark.parametrize("n", [64, ht.LADDER_FLOOR - 1,
-                               ht.LADDER_MIN_BATCH - 1])
+@pytest.mark.parametrize("n", [64, ht.LADDER_FLOOR - 1, _FIRST - 1])
 def test_below_the_first_rung_every_round_works_the_whole_batch(n):
     rng = np.random.default_rng(n)
     state, resident = _table(1 << 18, 2, 0.5, rng)
@@ -42,6 +42,24 @@ def test_below_the_first_rung_every_round_works_the_whole_batch(n):
     _ins, rounds, row_rounds, rows = (int(v) for v in books)
     assert rows == n and rounds > 1
     assert row_rounds == rounds * n
+
+
+def test_a_16384_row_batch_of_new_keys_works_under_a_quarter_of_its_rounds():
+    """q8's person aggregate: 16,384 new keys into 2^18 slots at load
+    0.6. The rounds are the single loop's (the longest probe chain
+    decides them); the rows they work are not: nearly every row is
+    placed before the second rung, so the tens of rounds left run over
+    4,096 and 1,024 rows."""
+    rng = np.random.default_rng(52)
+    n, cap, kw = 16_384, 1 << 18, 3
+    state, _resident = _table(cap, kw, 0.6, rng)
+    batch, valid = jnp.asarray(_keys(rng, n, kw)), jnp.ones(n, bool)
+    _table_ref, _slots_ref, books_ref = _single_loop_jit(state, batch, valid)
+    _state, _slots, books = _counted(state, batch, valid)
+    ins, rounds, row_rounds, rows = (int(v) for v in books)
+    assert (ins, rounds) == tuple(int(v) for v in books_ref)
+    assert rows == n and ins == n and rounds >= 20
+    assert row_rounds < rounds * rows / 4
 
 
 def test_a_q5_shaped_batch_works_under_six_batches_of_rows():
